@@ -38,8 +38,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# The single-chip headline this entry sits next to (BENCH_r04/r05
-# last_measured; bench.py owns re-measuring it on a live chip).
+# The single-chip figure this entry sits next to: builder-measured on
+# an earlier machine through a plug-in that is no longer installed,
+# and older than the code (docs/performance.md). bench.py owns
+# re-measuring it on a live chip.
 SINGLE_CHIP_HEADLINE = {
     "metric": "gpt2_125m_train_mfu_single_chip",
     "mfu": 0.4392,
@@ -221,10 +223,6 @@ def main(argv=None) -> int:
         if applied:
             print(f"[bench_multichip] overlap flags: {applied}",
                   file=sys.stderr)
-    import jax
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-
     entry = bench(args.plan, steps=args.steps, warmup=args.warmup,
                   overlap_flags=not args.no_overlap_flags)
     if args.compare:

@@ -65,10 +65,6 @@ def main(argv=None) -> int:
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_force_host_platform_device_count"
                 f"={args.devices}").strip()
-    import jax
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-
     from distributed_training_tpu.calibration import (microbench,
                                                       save_table)
 

@@ -1,25 +1,17 @@
 #!/usr/bin/env python
-"""Pre-compile the chip-session's measurement programs WITHOUT a chip.
+"""Compile the measurement programs WITHOUT a chip.
 
-Every r5 session point (benchmarks/chip_session.sh) is lowered and
-compiled with the real TPU compiler (libtpu) against a device-less
-v5e topology, with DTT_ASSUME_TPU=1 so the Pallas flash kernels take
-their real (Mosaic-compiled) path. Two payoffs:
-
-1. **De-risk**: a point whose kernels Mosaic rejects or whose program
-   exceeds HBM fails HERE, on a wedged-chip afternoon, not in the
-   scarce healthy window (the r4 window lost its batch-64 and
-   no-remat points to exactly such surprises).
-2. **Cache warm-up**: compiles land in the shared persistent cache
-   (JAX_COMPILATION_CACHE_DIR). If the attached chip's target config
-   matches the topology's, the on-chip session replays them instantly;
-   if not, nothing is lost but CPU time on a day the chip was down.
+Every point below is lowered and compiled with the real TPU compiler
+(libtpu) against a device-less v5e topology, with DTT_ASSUME_TPU=1 so
+the Pallas flash kernels take their real (Mosaic-compiled) path: a
+point whose kernels Mosaic rejects or whose program exceeds HBM fails
+HERE, in the CPU sandbox, not in budgeted chip time. It proves a
+program compiles, not that it runs.
 
 Prints one JSON line per point: {point, ok, compile_s, temp_gib,
 pallas_calls} or {point, ok: false, error}.
 
-    JAX_COMPILATION_CACHE_DIR=benchmarks/state/xla_cache \
-      python benchmarks/precompile_points.py
+    python benchmarks/precompile_points.py
 """
 
 from __future__ import annotations
@@ -34,8 +26,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-# (name, batch, seq_len, model_name, model_kwargs) — mirror of the
-# chip_session.sh phases that run through bench.measure().
+# (name, batch, seq_len, model_name, model_kwargs[, train_overrides])
+# — the configurations bench.measure() and the sweep tools run.
 POINTS = [
     ("headline_b32", 32, 1024, "gpt2_125m",
      dict(remat=True, remat_policy="mlp")),
@@ -55,9 +47,8 @@ POINTS = [
      dict(d_model=4096, n_layers=2, n_heads=32, n_kv_heads=8,
           d_ff=16384, max_seq_len=2048, pos_encoding="rope",
           tie_embeddings=False, remat=True, remat_policy="mlp")),
-    # bench_1b_single_chip.py's primary config (batch 1, adafactor,
-    # full remat) — its compile is the big fixed cost of the bench1b
-    # session phase.
+    # bench_1b_single_chip.py's safety-net config (batch 1, adafactor,
+    # full remat).
     ("bench1b_s1024", 1, 1024, "transformer_1b",
      dict(remat=True, remat_policy="full"),
      dict(optimizer="adafactor")),

@@ -163,10 +163,12 @@ def run_config(name: str, steps: int, warmup: int,
     from distributed_training_tpu.data import build_dataset
     from distributed_training_tpu.data.loader import ShardedDataLoader
     from distributed_training_tpu.models import build_model
-    from distributed_training_tpu.runtime import initialize_runtime
+    from distributed_training_tpu.runtime import (enable_compile_cache,
+                                                  initialize_runtime)
     from distributed_training_tpu.train.trainer import Trainer
     from distributed_training_tpu.utils.metrics import peak_flops_per_chip
 
+    enable_compile_cache()
     if steps < 1:
         raise ValueError(f"steps must be >= 1, got {steps}")
     if warmup < 0:
@@ -280,14 +282,16 @@ def main(argv=None) -> int:
     if names == [None]:
         p.error("pass --config NAME or --all")
     if len(names) > 1:
-        # One subprocess per config: a shared process would leak each
-        # config's compilation cache / device allocations into the
+        # One subprocess per config, run one after another: a shared
+        # process would leak each config's device allocations into the
         # next measurement (and mlp_cpu's cpu-device selection would
-        # poison later TPU configs' backend choice).
+        # poison later TPU configs' backend choice). This parent must
+        # never import jax — a chip belongs to one process, and a
+        # parent that touched the backend would hold it against its
+        # own children.
         import subprocess
         results = []
-        timeout_s = int(os.environ.get("DTT_BENCH_CONFIG_TIMEOUT",
-                                       "1800"))
+        timeout_s = 1800
         for n in names:
             cmd = [sys.executable, os.path.abspath(__file__),
                    "--config", n, "--steps", str(args.steps),
@@ -298,8 +302,8 @@ def main(argv=None) -> int:
                 proc = subprocess.run(cmd, capture_output=True,
                                       text=True, timeout=timeout_s)
             except subprocess.TimeoutExpired:
-                # One hung config (e.g. wedged backend init) must not
-                # hang the suite or discard completed results.
+                # One hung config must not hang the suite or discard
+                # completed results.
                 results.append({"config": n, "error":
                                 f"timeout after {timeout_s}s"})
                 continue

@@ -12,11 +12,33 @@ import argparse
 import json
 import os
 import sys
+import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 
-from bench import run_sweep_point  # noqa: E402  (repo-root bench.py)
+import bench  # noqa: E402  (repo-root bench.py)
+
+
+def run_sweep_point(batch: int, timed_steps: int = 10,
+                    seq_len: int = bench.SEQ_LEN, **model_kwargs) -> dict:
+    """One sweep measurement as a JSON-ready dict. A point that fails
+    (a batch that does not fit is an expected outcome of a sweep)
+    becomes an ``error`` row naming the config it ran; the matrix
+    continues."""
+    t0 = time.perf_counter()
+    try:
+        m = bench.measure(batch, seq_len=seq_len,
+                          timed_steps=timed_steps,
+                          phase=lambda *a, **k: None, **model_kwargs)
+        m["mfu"] = round(m["mfu"], 4)
+    except Exception as e:  # noqa: BLE001 — sweeps survive OOM points
+        m = {"batch": batch, "seq_len": seq_len,
+             "model_kwargs": {**bench.HEADLINE_MODEL_KWARGS,
+                              **model_kwargs},
+             "error": f"{type(e).__name__}: {e}"[:300]}
+    m["point_wall_s"] = round(time.perf_counter() - t0, 1)
+    return m
 
 
 def main() -> None:

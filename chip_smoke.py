@@ -1,0 +1,614 @@
+#!/usr/bin/env python3
+"""Does the system still start on the chip? One command, one process.
+
+    python3 chip_smoke.py          # on a machine with a TPU
+
+Drives the main path once through the entry points a user calls, at the
+full width of gpt2_125m (12 layers, d_model 768, 12 heads of 64, vocab
+50304; random weights from a seed, synthetic data):
+
+1. kernels — every Pallas path a supported config can reach, compiled
+   (not interpreted) and compared with the naive reference at bf16
+   tolerance: flash forward + fused backward at S 1024 / D 64, the
+   two-kernel split backward where the fused one does not fit VMEM, a
+   sliding window, GQA, and the stock paged-attention decode kernel at
+   head_dim 128 — alone and inside a small engine;
+2. trainer — ``distributed_training_tpu.train.cli.main`` takes a few
+   steps at batch 32 / seq 1024 / bf16 / AdamW with the telemetry, the
+   collectives audit and the checkpoint code a user gets;
+3. engine — an ``Engine`` at the same widths behind a ``ServingServer``
+   answers ``POST /generate`` requests of a few hundred prompt tokens,
+   streamed and plain.
+
+It asserts ``platform == "tpu"`` before any work and exits non-zero
+otherwise; every phase's failure is the script's failure. On success
+the LAST line of stdout is one JSON object,
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": N}}``;
+on failure no result line is printed. All work stays in this one
+process (a chip belongs to one process) and every thread it starts is
+stopped. Logs and event streams land in ``chiprun_out/chip_smoke/``
+(the checkpoint is deleted once checked).
+
+Every time printed here is SMOKE OUTPUT — cold compiles included, a
+handful of steps — and never a benchmark result.
+"""
+
+from __future__ import annotations
+
+import gc
+import http.client
+import json
+import logging
+import math
+import os
+import shutil
+import sys
+import threading
+import time
+import traceback
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+OUT = os.path.join(REPO, "chiprun_out", "chip_smoke")
+SEED = 0
+BF16_TOL = 3e-2     # the repo's bf16 kernel tolerance (tests/)
+TIE_TOL = 0.12      # logits: how far apart two bf16 "equal" scores may sit
+BATCH, SEQ_LEN, STEPS = 32, 1024, 6
+
+
+def say(msg: str) -> None:
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+def load_jsonl(path: str) -> list:
+    with open(path) as f:
+        return [json.loads(line) for line in f if line.strip()]
+
+
+def train_argv(out_dir: str, name: str, *overrides: str) -> list:
+    """Overrides for ``python -m distributed_training_tpu.train``: the
+    headline configuration on synthetic data from a seed, a handful of
+    steps, every step logged and HBM-sampled. Shared with
+    benchmarks/chip_multichip.py so one-chip and four-chip runs see the
+    same data."""
+    return ["model=gpt2_125m", "train=gpt2",
+            f"train.global_batch_size={BATCH}",
+            f"train.max_steps_per_epoch={STEPS}",
+            "train.total_epochs=1", "train.log_every=1",
+            "train.hbm_sample_every=1", f"train.seed={SEED}",
+            "train.shuffle=false", f"run.output_dir={out_dir}",
+            f"run.experiment_name={name}", *overrides]
+
+
+# One engine geometry for every smoke engine at gpt2_125m widths: the
+# fast cadence (speculative chunks inside the device-resident loop).
+ENGINE_GEOMETRY = dict(page_size=16, max_seq_len=1024, prefill_chunk=128,
+                       spec_k=4, resident_k=8)
+PAGES_PER_SEQ = 1024 // 16
+NEW_TOKENS = 16
+
+
+def serving_fixture(prompt_lengths):
+    """(model, f32 params, bf16 params, prompts): gpt2_125m at full
+    width with random weights from SEED, and seeded random prompts."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_training_tpu.models import build_model
+
+    model = build_model("gpt2_125m", dtype="bfloat16")
+    params = model.init(jax.random.PRNGKey(SEED))
+    bf16 = jax.tree.map(lambda x: x.astype(jnp.bfloat16), params)
+    rng = np.random.default_rng(SEED)
+    prompts = [[int(t) for t in rng.integers(0, 50257, n)]
+               for n in prompt_lengths]
+    return model, params, bf16, prompts
+
+
+def make_reference(model, params):
+    """``rows(seq, first)``: logits rows ``first..len(seq)-1`` of the
+    training-path forward over ``seq`` (padded to one fixed length, so
+    one compile); row ``i`` scores the token at position ``i + 1``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    apply = jax.jit(lambda p, ids: model.apply(p, ids)[0])
+
+    def rows(seq: list, first: int):
+        ids = np.zeros((1, 512), np.int32)
+        ids[0, :len(seq)] = seq
+        logits = apply(params, jnp.asarray(ids))[0]
+        return np.asarray(logits[first:len(seq)], np.float32)
+
+    return rows
+
+
+# -- phase 1: kernels ---------------------------------------------------------
+
+
+def _close(name: str, got, want) -> float:
+    """Max error of ``got`` against ``want`` in units of the repo's
+    bf16 tolerance band (atol + rtol * |want|); <= 1.0 passes."""
+    import numpy as np
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    if got.shape != want.shape:
+        raise AssertionError(f"{name}: shape {got.shape} != {want.shape}")
+    if not np.isfinite(got).all():
+        raise AssertionError(f"{name}: non-finite values")
+    # Gradients of a sum over S keys grow with S; scale the absolute
+    # term by the reference's own magnitude so one band fits all cases.
+    scale = max(1.0, float(np.abs(want).max()))
+    band = BF16_TOL * scale + BF16_TOL * np.abs(want)
+    return float((np.abs(got - want) / band).max())
+
+
+def flash_case(B, H, Hkv, S, D, window=0, expect_fused=True):
+    """(run, describe) for one flash fwd+bwd comparison, BHSD layout
+    (the model's fast path), bf16, causal."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_training_tpu.ops import flash_attention as fa
+    from distributed_training_tpu.ops.attention import (
+        dot_product_attention)
+
+    bq, bk = fa.default_blocks(S, S, D)
+    fused = fa._fused_bwd_fits(S, D, bq, bk, jnp.bfloat16)
+    if fused != expect_fused:
+        raise AssertionError(
+            f"case meant for the {'fused' if expect_fused else 'split'} "
+            f"backward but _fused_bwd_fits(S={S}, D={D}) is {fused}")
+
+    def inputs():
+        ks = jax.random.split(jax.random.PRNGKey(SEED), 4)
+        q = jax.random.normal(ks[0], (B, H, S, D), jnp.bfloat16)
+        k = jax.random.normal(ks[1], (B, Hkv, S, D), jnp.bfloat16)
+        v = jax.random.normal(ks[2], (B, Hkv, S, D), jnp.bfloat16)
+        g = jax.random.normal(ks[3], (B, H, S, D), jnp.bfloat16)
+        return q, k, v, g
+
+    def make(impl):
+        def f(q, k, v, g):
+            def loss(q, k, v):
+                o = dot_product_attention(q, k, v, causal=True,
+                                          impl=impl, window=window,
+                                          layout="bhsd")
+                return jnp.sum(o.astype(jnp.float32)
+                               * g.astype(jnp.float32)), o
+            (_, o), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+            return (o, *grads)
+        return jax.jit(f)
+
+    label = (f"B{B} H{H}/{Hkv} S{S} D{D} tiles {bq}x{bk}"
+             + (f" window {window}" if window else "")
+             + (" fused-bwd" if fused else " split-bwd (dq + dkv)"))
+    return make("flash"), make("naive"), inputs, label
+
+
+def paged_case(B, H, Hkv, P, hd=128, ps=16, N=64):
+    """Stock Pallas paged-attention decode kernel through
+    ``paged_attention(impl="kernel")`` against ``impl="ref"``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_training_tpu.ops.paged_attention import (
+        paged_attention)
+
+    def inputs():
+        ks = jax.random.split(jax.random.PRNGKey(SEED + 1), 3)
+        q = jax.random.normal(ks[0], (B, H, hd), jnp.bfloat16)
+        kp = jax.random.normal(ks[1], (Hkv, N, ps, hd), jnp.bfloat16)
+        vp = jax.random.normal(ks[2], (Hkv, N, ps, hd), jnp.bfloat16)
+        rng = np.random.default_rng(SEED)
+        # Distinct physical pages per sequence, out of order; lengths
+        # from one token to a full table, plus one inactive slot
+        # (length 0: zero output).
+        tables = np.stack([rng.permutation(N - 1)[:P] + 1
+                           for _ in range(B)]).astype(np.int32)
+        lengths = np.linspace(1, P * ps, B).astype(np.int32)
+        lengths[B // 2] = 0
+        return q, kp, vp, jnp.asarray(lengths), jnp.asarray(tables)
+
+    def make(impl):
+        return jax.jit(lambda *a: (paged_attention(*a, impl=impl),))
+
+    label = (f"B{B} H{H}/{Hkv} head_dim {hd} page_size {ps} "
+             f"pages/seq {P}")
+    return make("kernel"), make("ref"), inputs, label
+
+
+def engine_paged_case() -> dict:
+    """The kernel where the engine reaches it: ``paged_impl: auto`` at
+    head_dim 128 inside the jitted, layer-scanned decode program.
+    Greedy tokens must equal the reference-path engine's."""
+    import jax
+    import numpy as np
+
+    from distributed_training_tpu.models.transformer import (
+        Transformer, TransformerConfig)
+    from distributed_training_tpu.ops.paged_attention import (
+        kernel_supported)
+    from distributed_training_tpu.serving.engine import (
+        Engine, EngineConfig)
+
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=512, n_layers=2, n_heads=4,
+        n_kv_heads=2, max_seq_len=256, dtype="bfloat16",
+        pos_encoding="rope", tie_embeddings=False)
+    model = Transformer(cfg)
+    params = model.init(jax.random.PRNGKey(SEED))
+    prompt = np.random.default_rng(SEED).integers(
+        0, cfg.vocab_size, 70).astype(np.int32)
+    probe = jax.ShapeDtypeStruct((1, cfg.n_heads, cfg.head_dim),
+                                 jax.numpy.bfloat16)
+    if not kernel_supported(probe, None, page_size=16):
+        raise AssertionError("auto dispatch does not choose the kernel")
+    toks = {}
+    for impl in ("auto", "ref"):
+        eng = Engine(model, params, EngineConfig(
+            max_batch=4, page_size=16, num_pages=96, max_seq_len=256,
+            prefill_chunk=32, paged_impl=impl))
+        toks[impl] = [int(t) for t in eng.generate(prompt, 24)]
+    same = sum(a == b for a, b in zip(toks["auto"], toks["ref"]))
+    say(f"  kernel-path tokens {toks['auto'][:8]}... "
+        f"{same}/24 equal to the reference path")
+    if toks["auto"] != toks["ref"]:
+        raise AssertionError(
+            f"engine greedy tokens differ: kernel {toks['auto']} vs "
+            f"ref {toks['ref']}")
+    return {"ok": True, "tokens": toks["auto"]}
+
+
+def compare_case(name: str, run, ref, inputs, label: str) -> dict:
+    """Compile ``run`` for the chip, require a Mosaic kernel in it, and
+    compare what it computes with ``ref``."""
+    import jax
+
+    args = inputs()
+    compiled = run.lower(*args).compile()
+    n_pallas = compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"')
+    if n_pallas < 1:
+        raise AssertionError("no tpu_custom_call in the compiled "
+                             "program")
+    got = jax.block_until_ready(compiled(*args))
+    want = jax.block_until_ready(ref(*args))
+    errs = {n: round(_close(f"{name}.{n}", g, w), 3)
+            for n, g, w in zip(("out", "dq", "dk", "dv"), got, want)}
+    say(f"  {name}: [{label}] pallas_calls={n_pallas} "
+        f"err/band={errs}")
+    if max(errs.values()) > 1.0:
+        raise AssertionError(f"mismatch beyond the bf16 band: {errs}")
+    return {"ok": True, "shape": label, "pallas_calls": n_pallas,
+            "err_over_bf16_band": errs}
+
+
+def phase_kernels() -> dict:
+    def compared(name, build, *args, **kw):
+        return name, lambda: compare_case(name, *build(*args, **kw))
+
+    cases = dict([
+        compared("flash_fused_headline", flash_case, 4, 12, 12, 1024, 64),
+        compared("flash_split_bwd", flash_case, 1, 2, 2, 8192, 128,
+                 expect_fused=False),
+        compared("flash_sliding_window", flash_case, 1, 4, 4, 4096, 64,
+                 window=1024),
+        compared("flash_gqa", flash_case, 2, 8, 2, 1024, 128),
+        compared("paged_decode_groups4", paged_case, 8, 8, 2, P=6),
+        compared("paged_decode_groups8", paged_case, 8, 16, 2, P=8),
+        ("engine_paged_kernel", engine_paged_case),
+    ])
+    results = {}
+    for name, case in cases.items():
+        t0 = time.perf_counter()
+        try:
+            results[name] = case()
+            say(f"  {name}: ok (smoke wall "
+                f"{time.perf_counter() - t0:.1f}s)")
+        except Exception as e:  # noqa: BLE001 — report every kernel
+            traceback.print_exc()
+            results[name] = {"ok": False,
+                             "error": f"{type(e).__name__}: {e}"[:400]}
+            say(f"  {name}: FAILED {results[name]['error']}")
+    failed = [n for n, r in results.items() if not r["ok"]]
+    if failed:
+        raise AssertionError(f"kernel cases failed: {failed}")
+    return results
+
+
+# -- phase 2: trainer ---------------------------------------------------------
+
+
+class _CacheLog(logging.Handler):
+    """Collect JAX's persistent-compile-cache hit/miss lines (module
+    names included) while the trainer runs."""
+
+    def __init__(self):
+        super().__init__(logging.DEBUG)
+        self.hits: list[str] = []
+        self.misses: list[str] = []
+
+    def emit(self, record):
+        msg = record.getMessage()
+        if "Persistent compilation cache hit" in msg:
+            self.hits.append(msg.split("'")[1])
+        elif "PERSISTENT COMPILATION CACHE MISS" in msg:
+            self.misses.append(msg.split("'")[1])
+
+
+def phase_trainer() -> dict:
+    from distributed_training_tpu import native, telemetry
+    from distributed_training_tpu.train import cli
+
+    run_dir = os.path.join(OUT, "train")
+    argv = train_argv(OUT, "train", "train.save_every=1")
+    say(f"  python -m distributed_training_tpu.train {' '.join(argv)}")
+    compiler_log = logging.getLogger("jax._src.compiler")
+    cache_log = _CacheLog()
+    old_level, old_prop = compiler_log.level, compiler_log.propagate
+    compiler_log.addHandler(cache_log)
+    compiler_log.setLevel(logging.DEBUG)
+    compiler_log.propagate = False
+    t0 = time.perf_counter()
+    try:
+        rc = cli.main(argv)
+    finally:
+        compiler_log.removeHandler(cache_log)
+        compiler_log.setLevel(old_level)
+        compiler_log.propagate = old_prop
+        telemetry.uninstall()
+    wall = time.perf_counter() - t0
+    if rc != 0:
+        raise AssertionError(f"train.cli.main returned {rc}")
+
+    rows = [r for r in load_jsonl(os.path.join(run_dir, "metrics.jsonl"))
+            if "loss" in r]
+    losses = [r["loss"] for r in rows]
+    if len(losses) != STEPS or not all(
+            isinstance(x, float) and math.isfinite(x) for x in losses):
+        raise AssertionError(f"want {STEPS} finite losses, got {losses}")
+    # Random weights: the first loss sits at ln(vocab), a wrong one
+    # (bad labels, bad logits) does not.
+    if abs(losses[0] - math.log(50257)) > 0.5:
+        raise AssertionError(
+            f"first loss {losses[0]:.3f} is not near ln(50257) = "
+            f"{math.log(50257):.3f}")
+
+    events = load_jsonl(os.path.join(run_dir, "events.jsonl"))
+    audits = [e for e in events if e.get("kind") == "collectives"]
+    if not audits:
+        raise AssertionError(
+            "no `collectives` event: the post-step-one audit of the "
+            "compiled step failed or never ran (see training.log)")
+    pallas_calls = audits[0]["pallas_calls"]
+    if pallas_calls < 2:
+        raise AssertionError(
+            f"{pallas_calls} tpu_custom_call(s) in the compiled train "
+            f"step, want >= 2 (flash forward + backward): the step "
+            f"took the naive attention path")
+    hbm = [e for e in events if e.get("kind") == "hbm"]
+    peak = max((d["stats"] or {}).get("peak_bytes_in_use", 0)
+               for e in hbm for d in e["devices"]) if hbm else 0
+
+    ckpt_root = os.path.join(run_dir, "checkpoints")
+    steps_saved = sorted(int(d) for d in os.listdir(ckpt_root)
+                         if d.isdigit())
+    if steps_saved != [STEPS]:
+        raise AssertionError(
+            f"want one checkpoint at step {STEPS} under {ckpt_root}, "
+            f"found {steps_saved}")
+    ckpt_bytes = sum(
+        os.path.getsize(os.path.join(dp, f))
+        for dp, _d, fs in os.walk(os.path.join(ckpt_root, str(STEPS)))
+        for f in fs)
+    # 124M params + two AdamW moments in f32 is 1.49e9 bytes before
+    # the store's compression.
+    if ckpt_bytes < 1.0e9:
+        raise AssertionError(
+            f"checkpoint holds {ckpt_bytes} bytes, want >= 1.0e9")
+    # Checked; the payload is too large to carry back from the chip
+    # machine with the logs.
+    shutil.rmtree(ckpt_root)
+
+    # The audit lowers the SAME step again (from abstract inputs): one
+    # backend compile of it means the audit was served from a cache —
+    # JAX's in-memory one when the lowering is identical, else the
+    # persistent one (a hit logged under the step's name).
+    step_compiles = cache_log.misses.count("jit_train_step")
+    audit_reused = (step_compiles == 1 if cache_log.misses else None)
+    say(f"  losses {[round(x, 4) for x in losses]}")
+    say(f"  collectives audit: pallas_calls={pallas_calls} "
+        f"total_collectives={audits[0]['total_collectives']}")
+    say(f"  persistent compile cache: hits {cache_log.hits} misses "
+        f"{cache_log.misses}")
+    say(f"  backend compiles of the train step: {step_compiles} "
+        f"(the audit's second lowering reused the first: "
+        f"{audit_reused})")
+    say(f"  checkpoint step {STEPS}: {ckpt_bytes / 1e9:.2f} GB written "
+        f"(then deleted); memory_stats peak_bytes_in_use "
+        f"{peak / 2**30:.2f} GiB; data loader: "
+        f"{'native (dtt_native.cpp)' if native.available() else 'NumPy fallback'}")
+    say(f"  smoke wall (compile + {STEPS} steps + save) {wall:.1f}s")
+    return {"losses": losses, "pallas_calls": pallas_calls,
+            "train_step_backend_compiles": step_compiles,
+            "cache_hits": cache_log.hits,
+            "cache_misses": cache_log.misses,
+            "checkpoint_bytes": ckpt_bytes,
+            "peak_hbm_bytes": peak,
+            "loader": "native" if native.available() else "numpy"}
+
+
+# -- phase 3: engine ----------------------------------------------------------
+
+
+def _post_generate(port: int, body: dict) -> list:
+    """POST /generate; returns the tokens (streamed: one JSON line per
+    token, then a final record that must repeat them)."""
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=600)
+    try:
+        conn.request("POST", "/generate", json.dumps(body).encode(),
+                     {"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        if resp.status != 200:
+            raise AssertionError(
+                f"/generate -> {resp.status}: {resp.read()[:300]!r}")
+        if not body.get("stream"):
+            return json.loads(resp.read())["tokens"]
+        lines = []
+        while line := resp.readline():
+            lines.append(json.loads(line))
+        toks = [ln["token"] for ln in lines if "token" in ln]
+        if not lines[-1].get("done") or lines[-1]["tokens"] != toks:
+            raise AssertionError(f"bad stream tail: {lines[-1]}")
+        return toks
+    finally:
+        conn.close()
+
+
+def phase_engine() -> dict:
+    from distributed_training_tpu import telemetry
+    from distributed_training_tpu.serving.engine import (
+        Engine, EngineConfig)
+    from distributed_training_tpu.serving.server import ServingServer
+
+    model, params, bf16, prompts = serving_fixture((211, 333, 450))
+    ecfg = EngineConfig(max_batch=8, num_pages=8 * PAGES_PER_SEQ + 1,
+                        **ENGINE_GEOMETRY)
+    tel = telemetry.install(telemetry.Telemetry(
+        events_jsonl=os.path.join(OUT, "serve", "events.jsonl")))
+    t0 = time.perf_counter()
+    eng = Engine(model, bf16, ecfg, mesh=None)
+    counts = eng.warmup()
+    say(f"  engine warm (smoke wall {time.perf_counter() - t0:.1f}s): "
+        f"compile_counts {counts}, weights "
+        f"{eng.weight_bytes / 1e6:.0f} MB bf16")
+    srv = ServingServer(eng, port=0)
+    if srv.start() is None:
+        raise AssertionError("ServingServer did not start")
+    try:
+        t0 = time.perf_counter()
+        streamed = [_post_generate(srv.port, {
+            "prompt_ids": p, "max_NEW_TOKENS": NEW_TOKENS,
+            "stream": True}) for p in prompts]
+        # The plain requests arrive together, so they share launches
+        # (continuous batching) and meet the prefixes the streamed
+        # ones left in the cache.
+        plain: list = [None] * len(prompts)
+
+        def ask(i):
+            plain[i] = _post_generate(srv.port, {
+                "prompt_ids": prompts[i],
+                "max_NEW_TOKENS": NEW_TOKENS})
+
+        threads = [threading.Thread(target=ask, args=(i,))
+                   for i in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(600)
+        wall = time.perf_counter() - t0
+    finally:
+        srv.stop()
+        telemetry.uninstall()
+        tel.close()
+    if srv.leaked_threads:
+        raise AssertionError(f"{srv.leaked_threads} server thread(s) "
+                             f"outlived stop()")
+    for i, (s, p) in enumerate(zip(streamed, plain)):
+        if len(s) != NEW_TOKENS or s != p:
+            raise AssertionError(
+                f"request {i}: streamed {s} != plain {p}")
+    after = eng.compile_counts()
+    if after != counts:
+        raise AssertionError(
+            f"recompiled after warm-up: {counts} -> {after}")
+
+    # Reference: the training-path forward over prompt + generated
+    # tokens (teacher-forced, so one bf16 near-tie cannot cascade).
+    # Each emitted token must sit within bf16 tolerance of the
+    # reference argmax's logit.
+    worst, exact = 0.0, 0
+    reference = make_reference(model, params)
+    for prompt, toks in zip(prompts, streamed):
+        rows = reference(prompt + toks, len(prompt) - 1)
+        for row, tok in zip(rows, toks):
+            worst = max(worst, float(row.max() - row[tok]))
+            exact += int(row.argmax() == tok)
+    n_tok = NEW_TOKENS * len(prompts)
+    say(f"  {len(prompts)} streamed == {len(prompts)} plain requests, "
+        f"prompts {[len(p) for p in prompts]} tokens, {NEW_TOKENS} new "
+        f"each; first answer {streamed[0]}")
+    say(f"  vs full-context forward: {exact}/{n_tok} tokens are its "
+        f"argmax, worst logit gap {worst:.4f} (tolerance {TIE_TOL})")
+    say(f"  no recompile after warm-up: {after}; host syncs "
+        f"{eng.host_syncs}; resident {eng.resident_stats}; prefix "
+        f"{eng.prefix_stats}")
+    say(f"  smoke wall for the {2 * len(prompts)} requests {wall:.1f}s")
+    if worst > TIE_TOL:
+        raise AssertionError(
+            f"an emitted token's reference logit is {worst:.4f} below "
+            f"the reference argmax (tolerance {TIE_TOL})")
+    return {"tokens": streamed, "argmax_agreement": [exact, n_tok],
+            "worst_logit_gap": worst, "compile_counts": after}
+
+
+# -- driver ------------------------------------------------------------------
+
+
+def main() -> int:
+    import jax
+
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": len(devices)}
+    say(f"jax {jax.__version__} device {device}")
+    if device["platform"] != "tpu":
+        print("[chip_smoke] FAILED: JAX found no TPU (platform "
+              f"{device['platform']!r}); nothing was run",
+              file=sys.stderr)
+        return 1
+
+    sys.path.insert(0, REPO)
+    from distributed_training_tpu.runtime import enable_compile_cache
+
+    say(f"compile cache: {enable_compile_cache()}")
+    shutil.rmtree(OUT, ignore_errors=True)
+    os.makedirs(OUT)
+
+    summary: dict = {"device": device, "jax": jax.__version__}
+    failed = []
+    for name, phase in (("kernels", phase_kernels),
+                        ("trainer", phase_trainer),
+                        ("engine", phase_engine)):
+        say(f"phase {name}")
+        t0 = time.perf_counter()
+        try:
+            summary[name] = phase()
+            say(f"phase {name} ok (smoke wall "
+                f"{time.perf_counter() - t0:.1f}s)")
+        except Exception as e:  # noqa: BLE001 — run every phase, fail at the end
+            traceback.print_exc()
+            summary[name] = {"ok": False,
+                             "error": f"{type(e).__name__}: {e}"[:600]}
+            say(f"phase {name} FAILED: {type(e).__name__}: "
+                f"{str(e)[:300]}")
+            failed.append(name)
+        gc.collect()
+        stats = devices[0].memory_stats() or {}
+        say(f"  device memory in use after phase: "
+            f"{stats.get('bytes_in_use', 0) / 2**20:.0f} MiB")
+    with open(os.path.join(OUT, "summary.json"), "w") as f:
+        json.dump(summary, f, indent=1)
+    if failed:
+        print(f"[chip_smoke] FAILED phases: {failed}", file=sys.stderr)
+        return 1
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -55,13 +55,13 @@ def _collective_fns(mesh, n: int):
     equal the requested x (table.py conventions)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
     def sm(f, ins, outs):
         return jax.jit(shard_map(f, mesh=mesh, in_specs=ins,
-                                 out_specs=outs, check_rep=False))
+                                 out_specs=outs, check_vma=False))
 
     def sharded_rows(nbytes):
         rows = max(n, int(nbytes) // 4 // n * n)
@@ -141,7 +141,7 @@ def bench_matmul(sizes=DEFAULT_MATMUL_SIZES, iters: int = 10) -> list:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
 

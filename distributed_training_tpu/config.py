@@ -114,9 +114,11 @@ class TrainConfig:
     # (telemetry/collectives.py): after the first step the coordinator
     # lowers+compiles the same program device-less and emits a
     # `collectives` event (op counts + bytes/step per mesh axis) so
-    # the summarizer can print a comms roofline next to MFU. Costs one
-    # extra (cache-warm trace) compile on the coordinator; only runs
-    # when an event sink is installed.
+    # the summarizer can print a comms roofline next to MFU (and
+    # `pallas_calls`, so the stream says which kernels the step ran).
+    # Costs one extra lowering on the coordinator — the compile is the
+    # first step's, served from JAX's cache; only runs when an event
+    # sink is installed.
     collectives_audit: bool = True
     dataset_size: int = 2048
     learning_rate: float = 1e-3

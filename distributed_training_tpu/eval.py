@@ -57,10 +57,6 @@ def main(argv: list[str] | None = None) -> int:
     args = build_argparser().parse_args(argv)
 
     import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     import numpy as np
 
     from distributed_training_tpu import telemetry as telemetry_lib
@@ -68,8 +64,10 @@ def main(argv: list[str] | None = None) -> int:
                                                build_dataset)
     from distributed_training_tpu.generate import (
         _build_model_from_cfg, _load_run_config, _restore_params)
-    from distributed_training_tpu.runtime import initialize_runtime
+    from distributed_training_tpu.runtime import (enable_compile_cache,
+                                                  initialize_runtime)
 
+    enable_compile_cache()
     if args.events_jsonl:
         # fresh=False: the natural target is the run's own
         # events.jsonl — eval must append after a run_start marker,

@@ -357,8 +357,8 @@ class Transformer:
         remat allow-lists save.
 
         Mirrors ``flash_attention.supported()`` on the EFFECTIVE
-        local-attention shapes rather than just the backend (ADVICE
-        r4): a True here while dispatch demotes to naive per-shape
+        local-attention shapes rather than just the backend: a True
+        here while dispatch demotes to naive per-shape
         saves residual names that never exist in the trace, and the
         backward silently recomputes all attention from the q/k/v tags
         — for ulysses that recompute includes the all-to-alls (always
@@ -609,7 +609,7 @@ class Transformer:
             b_axes = self._active_batch_axes()
             head_ax = AXIS_TP if sizes.get(AXIS_TP, 1) > 1 else None
             if b_axes or head_ax:
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
                 if layout == "bhsd":
                     spec = P(b_axes or None, head_ax, None, None)
@@ -623,7 +623,7 @@ class Transformer:
                         block_k=c.flash_block_k,
                         window=window, layout=layout),
                     mesh=self.mesh, in_specs=(spec, spec, spec),
-                    out_specs=spec, check_rep=False)
+                    out_specs=spec, check_vma=False)
                 return fn(q, k, v)
         impl = c.attention_impl
         if impl in ("auto", "flash") and not self._tp_head_shardable():
@@ -632,6 +632,12 @@ class Transformer:
             # collectives (correct, slower; ring attention is the
             # fast option for such head counts). Matches
             # _flash_active, so the remat allow-lists save attn_out.
+            from distributed_training_tpu.ops import (
+                flash_attention as fa)
+            fa.log_naive_choice(
+                f"tp={self._mesh_axis_sizes().get('tp')} does not "
+                f"divide n_heads={c.n_heads} / n_kv_heads="
+                f"{c.n_kv_heads or c.n_heads}")
             impl = "naive"
         return dot_product_attention(q, k, v, causal=True,
                                      impl=impl,
@@ -1334,8 +1340,8 @@ def _topk_by_argmax(p: jax.Array, k: int):
     custom-call the SPMD partitioner cannot partition, so it
     all-gathered the full (B, G, gs, E) routing probs across data-
     parallel shards before routing (the one activation-scale
-    collective in the otherwise-clean MoE communication contract,
-    BENCH_r04; now pinned to zero by
+    collective in the otherwise-clean MoE communication contract;
+    now pinned to zero by
     tests/test_benchmarks.py::test_fsdp_step_has_no_activation_scale_collectives).
     k is the tiny moe_top_k (1-2 in practice), so the unrolled loop
     costs k cheap (…, E) passes. Values are re-gathered from the

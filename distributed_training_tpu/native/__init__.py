@@ -1,8 +1,8 @@
 """ctypes bindings for the native data-loader kernels (dtt_native.cpp).
 
 Build-on-first-import: compiles ``dtt_native.cpp`` with g++ into a
-shared library cached beside the source (keyed on a source hash, so
-edits rebuild automatically). Everything degrades gracefully — if no
+shared library cached beside the source (keyed on a hash of the source
+and the compiler flags, so edits rebuild automatically). Everything degrades gracefully — if no
 compiler is present or the build fails, ``available()`` is False and
 callers (data/datasets.py) fall back to NumPy. Both entry points are
 **bit-identical** across paths (gather: same fancy-index semantics;
@@ -43,16 +43,24 @@ def _build_dir() -> str:
     return d
 
 
+# Portable flags only (no -march=native): the tree — build directory
+# included — gets copied between machines, and a binary tuned to the
+# builder's CPU must not be what another machine loads.
+_CXX_FLAGS = ["-O3", "-std=c++17", "-shared", "-fPIC", "-pthread"]
+
+
 def _lib_path() -> str:
+    # Keyed on source AND flags: a binary left behind by an older build
+    # recipe never matches, so it is rebuilt rather than trusted.
+    h = hashlib.sha256(" ".join(_CXX_FLAGS).encode())
     with open(_SRC, "rb") as f:
-        tag = hashlib.sha256(f.read()).hexdigest()[:16]
-    return os.path.join(_build_dir(), f"dtt_native_{tag}.so")
+        h.update(f.read())
+    return os.path.join(_build_dir(),
+                        f"dtt_native_{h.hexdigest()[:16]}.so")
 
 
 def _compile(path: str) -> None:
-    # -march=native is safe: the .so is cached per machine, not shipped.
-    cmd = ["g++", "-O3", "-march=native", "-std=c++17", "-shared",
-           "-fPIC", "-pthread", _SRC, "-o", path]
+    cmd = ["g++", *_CXX_FLAGS, _SRC, "-o", path]
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=os.path.dirname(path))
     os.close(fd)  # g++ rewrites the (safely created) file in place
     try:
@@ -147,9 +155,8 @@ def _fill_tokens_numpy(seed: int, vocab: int, n: int) -> np.ndarray:
     This matters on multi-host pods: every host builds the synthetic
     corpus locally and the data path assumes the copies are identical.
     If native build availability differed across hosts and the fallback
-    drew a different stream, per-host corpora would silently diverge
-    (the ADVICE.md round-1 medium finding) — so the fallback is exact,
-    not merely "equally valid".
+    drew a different stream, per-host corpora would silently diverge —
+    so the fallback is exact, not merely "equally valid".
 
     Per 4096-token block ``b``: state ``s0 = seed ^ (STREAM * (b+1))``;
     draw ``i`` mixes ``s0 + (i+1) * GAMMA`` through the SplitMix64

@@ -86,7 +86,7 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         # An EXPLICIT tile override that does not divide the sequence
         # must raise, not silently reroute to naive — otherwise sweep
         # rows measure the wrong kernel under the override's label
-        # (ADVICE r3; mirrors ring_attention's raise-don't-ignore).
+        # (mirrors ring_attention's raise-don't-ignore).
         if impl == "auto" and (block_q or block_k):
             sq, sk = q.shape[seq_axis], k.shape[seq_axis]
             if (block_q and sq % min(block_q, sq)) or (
@@ -96,9 +96,10 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     f"block_k={block_k}) does not divide seq lengths "
                     f"(Sq={sq}, Sk={sk}); fix the override or pass "
                     "impl='naive' explicitly")
-        if fa.supported(q, k, v, block_q=block_q or 0,
-                        block_k=block_k or 0,
-                        layout=layout) or impl == "flash":
+        reason = None if impl == "flash" else fa.unsupported_reason(
+            q, k, v, block_q=block_q or 0, block_k=block_k or 0,
+            layout=layout)
+        if reason is None:
             kw = {}
             if block_q:
                 kw["block_q"] = block_q
@@ -107,6 +108,7 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             return fa.flash_attention(q, k, v, causal=causal,
                                       window=window, layout=layout,
                                       **kw)
+        fa.log_naive_choice(reason)
         impl = "naive"
     if impl == "naive":
         if layout == "bhsd":

@@ -34,6 +34,7 @@ seq lens are powers of two ≥ 128; others fall back to naive).
 from __future__ import annotations
 
 import functools
+import logging
 import os
 
 import jax
@@ -41,6 +42,10 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from distributed_training_tpu.runtime import default_platform
+
+logger = logging.getLogger(__name__)
 
 DEFAULT_BLOCK_Q = 256   # legacy floor — the real default is seq-aware,
 DEFAULT_BLOCK_K = 256   # see default_blocks()
@@ -86,15 +91,11 @@ def _resolve_blocks(block_q: int, block_k: int, seq_q: int, seq_k: int,
     return (min(block_q, seq_q) if block_q else dq,
             min(block_k, seq_k) if block_k else dk)
 
-# jax < 0.4.38 spells it TPUCompilerParams (same fields); resolve the
-# modern name first so this module imports on both vintages.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
 # Every kernel here runs a (B, H, outer, inner) grid where only the
 # innermost dim carries accumulation order (fwd/dq: k-blocks; dkv:
 # q-blocks) — declaring the rest parallel lets Mosaic pipeline them.
-_DIM_SEMANTICS = _CompilerParams(
+_DIM_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel",
                          "arbitrary"))
 
@@ -127,7 +128,11 @@ def _apply_causal_mask(s, q_start, k_start, block_q: int, block_k: int,
 
 
 def _platform_is_tpu() -> bool:
-    """True when tracing targets a TPU backend.
+    """True when tracing targets a TPU backend. On a CPU the caller
+    asked for (JAX_PLATFORMS=cpu / train.device=cpu) the kernels run
+    through the Pallas interpreter; a CPU JAX fell back to, or a
+    backend that fails to start, raises (runtime.default_platform) —
+    neither is read as "use the reference".
 
     DTT_ASSUME_TPU=1 overrides the attached-device check (read
     dynamically, not at import: it exists for DEVICE-LESS topology AOT
@@ -139,16 +144,14 @@ def _platform_is_tpu() -> bool:
     run in compiled (non-interpret) mode on a backend without Mosaic."""
     if os.environ.get("DTT_ASSUME_TPU", "0") not in ("", "0"):
         return True
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    return default_platform() == "tpu"
 
 
-def supported(q: jax.Array, k: jax.Array, v: jax.Array,
-              block_q: int = 0, block_k: int = 0,
-              layout: str = "bshd") -> bool:
-    """Should auto-dispatch route here? (Else: naive fallback.)
+def unsupported_reason(q: jax.Array, k: jax.Array, v: jax.Array,
+                       block_q: int = 0, block_k: int = 0,
+                       layout: str = "bshd") -> str | None:
+    """Why auto-dispatch must NOT route here (→ naive), or None when
+    the kernel takes these shapes.
 
     Conservative by design: off-TPU the interpreter would be orders of
     magnitude slower than XLA's fused naive path, and the kernel's
@@ -160,23 +163,46 @@ def supported(q: jax.Array, k: jax.Array, v: jax.Array,
     """
     del v
     s_ax, h_ax = (2, 1) if layout == "bhsd" else (1, 2)
+    sq, sk, d = q.shape[s_ax], k.shape[s_ax], q.shape[3]
     if not _platform_is_tpu():
-        return False
+        return "platform is not tpu"
     if q.dtype not in (jnp.float32, jnp.bfloat16):
-        return False
-    if q.shape[s_ax] != k.shape[s_ax]:
-        return False
-    if q.shape[s_ax] < 128:
-        return False
-    bq, bk = _resolve_blocks(block_q, block_k, q.shape[s_ax],
-                             k.shape[s_ax], q.shape[3])
-    if not bq or not bk or q.shape[s_ax] % bq or k.shape[s_ax] % bk:
-        return False
-    if q.shape[3] > 256:
-        return False
+        return f"dtype {q.dtype} is neither float32 nor bfloat16"
+    if sq != sk:
+        return f"Sq {sq} != Sk {sk}"
+    if sq < 128:
+        return f"sequence {sq} < 128"
+    bq, bk = _resolve_blocks(block_q, block_k, sq, sk, d)
+    if not bq or not bk or sq % bq or sk % bk:
+        return (f"no tile divides the sequence (S={sq}, "
+                f"block_q={bq}, block_k={bk})")
+    if d > 256:
+        return f"head_dim {d} > 256"
     if q.shape[h_ax] % k.shape[h_ax]:
-        return False
-    return True
+        return (f"n_heads {q.shape[h_ax]} not a multiple of "
+                f"n_kv_heads {k.shape[h_ax]}")
+    return None
+
+
+def supported(q: jax.Array, k: jax.Array, v: jax.Array,
+              block_q: int = 0, block_k: int = 0,
+              layout: str = "bshd") -> bool:
+    """Should auto-dispatch route here? (Else: naive fallback.)"""
+    return unsupported_reason(q, k, v, block_q, block_k, layout) is None
+
+
+@functools.lru_cache(maxsize=None)
+def _warn_naive_once(reason: str) -> None:
+    logger.warning("attention_impl=auto runs the NAIVE path on this "
+                   "TPU, not the Pallas flash kernel: %s", reason)
+
+
+def log_naive_choice(reason: str) -> None:
+    """One log line per distinct reason ``auto`` attention runs the
+    naive path ON A TPU, so a run's log says which kernel it measured.
+    (Off-TPU naive is the expected path and is not logged.)"""
+    if _platform_is_tpu():
+        _warn_naive_once(reason)
 
 
 # ---------------------------------------------------------------------------
@@ -477,8 +503,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # output block (6 MiB) and ignored everything else resident with it —
 # the f32 (block_q, block_k) softmax temporaries, dk/dv scratch, and
 # the double-buffered q/k/v/do tiles — so shapes like S=8192, D=128
-# passed the gate and then blew the ~16 MiB/core VMEM in Mosaic
-# (ADVICE r4, medium). The estimate is conservative-but-calibrated:
+# passed the gate and then blew the ~16 MiB/core VMEM in Mosaic.
+# The estimate is conservative-but-calibrated:
 # the chip-proven split dq kernel runs the same (block_q, block_k)
 # temporaries at 1024x1024 tiles, which bounds how many Mosaic keeps
 # live simultaneously (~2 f32 copies; s/p and dp/ds alias).
@@ -505,13 +531,11 @@ def _fused_bwd_fits(S, D, block_q, block_k, in_dtype, grads_dtype=None):
         g) <= _FUSED_BWD_VMEM_LIMIT_BYTES
 
 
-# DTT_FLASH_SPLIT_BWD=1 forces the two-kernel path — the chip session
-# A/Bs the fused kernel against it on real hardware
-# (benchmarks/chip_session.sh) before the fused default is trusted.
-# Read ONCE at import: the jit cache key does not include env vars, so
-# a mid-process toggle after a shape has compiled would silently reuse
-# the previously chosen kernel and invalidate an in-process A/B
-# (ADVICE r4). The knob is process-start-only by construction.
+# DTT_FLASH_SPLIT_BWD=1 forces the two-kernel path (an on-chip A/B of
+# the fused kernel against it). Read ONCE at import: the jit cache key
+# does not include env vars, so a mid-process toggle after a shape has
+# compiled would silently reuse the previously chosen kernel and
+# invalidate an in-process A/B. Process-start-only by construction.
 _FORCE_SPLIT_BWD = os.environ.get("DTT_FLASH_SPLIT_BWD", "0") not in (
     "", "0")
 
@@ -559,7 +583,7 @@ def _flash_bwd_fused(q, k, v, lse, do, delta, *, causal, block_q,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         # Both trailing dims carry accumulation order here.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=not _platform_is_tpu(),

@@ -40,8 +40,12 @@ the pages a sequence actually owns.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+
+from distributed_training_tpu.runtime import default_platform
 
 
 def kernel_supported(q: jax.Array, k_pages: jax.Array,
@@ -51,11 +55,10 @@ def kernel_supported(q: jax.Array, k_pages: jax.Array,
     Conservative, mirroring ops/flash_attention.supported(): TPU
     platform only (elsewhere the interpreter is orders of magnitude
     slower than XLA's gather), MXU-friendly head_dim, and a page size
-    the kernel's DMA descriptor tiles evenly."""
-    try:
-        if jax.devices()[0].platform != "tpu":
-            return False
-    except RuntimeError:  # pragma: no cover - backend init failure
+    the kernel's DMA descriptor tiles evenly. A backend that fails to
+    start, or a CPU nobody asked for, raises — it is not read as "use
+    the reference" (runtime.default_platform)."""
+    if default_platform() != "tpu":
         return False
     head_dim = q.shape[-1]
     ps = page_size if page_size is not None else k_pages.shape[2]
@@ -149,15 +152,31 @@ def paged_attention(q: jax.Array, k_pages: jax.Array,
     use_kernel = (impl == "kernel"
                   or (impl == "auto"
                       and kernel_supported(q, k_pages)))
-    if use_kernel:  # pragma: no cover - needs a TPU
+    if use_kernel:  # pragma: no cover - needs a TPU (chip_smoke.py)
         from jax.experimental.pallas.ops.tpu.paged_attention import (
             paged_attention as tpu_paged_attention,
         )
         # Kernel layout: q (B, H, hd), pools (Hkv, N, ps, hd),
-        # lengths (B,), page_indices (B, P) — ours verbatim.
-        return tpu_paged_attention(
-            q, k_pages, v_pages, lengths, page_indices,
-            pages_per_compute_block=min(4, page_indices.shape[1]))
+        # lengths (B,), page_indices (B, P) — ours verbatim. Two
+        # things the stock kernel leaves to its caller (both found by
+        # its first run on a chip, chip_smoke.py): it computes q·k
+        # UNSCALED, so q carries the hd**-0.5 (in f32 — the kernel
+        # upcasts q anyway, and a bf16-rounded scale would move
+        # near-tied argmaxes off the reference path's); and it never
+        # writes the output rows of zero-length sequences, whose
+        # uninitialized values would reach the scratch page through
+        # the next layer's KV write and, as NaN, every sequence that
+        # reads a masked slot of it — so inactive rows are zeroed
+        # here, the module's contract. The compute block must divide
+        # the pages per sequence: up to 4 pages (64 tokens at
+        # page_size 16), fewer for a ragged table.
+        out = tpu_paged_attention(
+            q.astype(jnp.float32) * (q.shape[-1] ** -0.5),
+            k_pages, v_pages, lengths, page_indices,
+            pages_per_compute_block=math.gcd(
+                4, page_indices.shape[1]))
+        return jnp.where((lengths > 0)[:, None, None], out,
+                         0).astype(q.dtype)
     out = paged_attention_chunk(
         q[:, None], k_pages, v_pages, page_indices,
         (lengths - 1)[:, None].astype(jnp.int32))

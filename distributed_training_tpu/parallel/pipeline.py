@@ -43,10 +43,9 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from distributed_training_tpu.parallel.compat import axis_size
 from distributed_training_tpu.runtime import AXIS_PP
 
 SCHEDULES = ("gpipe", "interleaved")
@@ -115,7 +114,7 @@ def _gpipe(stage_params, layer_ids, x_mb, aux0, *, body_fn,
     across pp; S_local = S/sp when ``pipeline_apply`` got a
     ``seq_axis`` (the stage body then holds only its sequence slice).
     Returns processed (M, B_mb, S_local, D) + summed aux."""
-    pp = axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     M = num_microbatches
     T = M + pp - 1
@@ -180,7 +179,7 @@ def _interleaved(stage_params, layer_ids, x_mb, aux0, *, body_fn,
     1/v of a GPipe tick and the fill bubble shrinks v-fold.
     x_mb's sequence dim is local (S/sp) when ``pipeline_apply`` got a
     ``seq_axis`` — same contract as ``_gpipe``."""
-    pp = axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     M = num_microbatches
     v = virtual_stages
@@ -331,7 +330,7 @@ def pipeline_apply(body_fn: Callable, stacked_params, x: jax.Array,
         mesh=mesh,
         in_specs=(param_specs, P(AXIS_PP), xspec, P(None)),
         out_specs=(xspec, P(None)),
-        check_rep=False,
+        check_vma=False,
     )
     # The aux accumulator crosses the shard_map boundary as shape (1,)
     # rather than a scalar: when the aux actually carries gradient

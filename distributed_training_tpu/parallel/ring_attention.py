@@ -50,10 +50,9 @@ import functools
 import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from distributed_training_tpu.parallel.compat import axis_size
 from distributed_training_tpu.runtime import AXIS_SP, BATCH_AXES
 
 
@@ -237,7 +236,7 @@ def _ring_fwd_scan(q, k, v, axis_name: str, causal: bool,
     """Full ring cycle of online-softmax accumulation. Returns the
     normalized output (B, S, H, D) in q.dtype and per-row logsumexp
     (B, H, S) fp32."""
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, S, H, D = q.shape
     perm = _ring_perm(sp)
@@ -392,7 +391,7 @@ def _ring_core_bwd(axis_name, causal, block_impl, block_q, block_k,
     accumulates locally. Residuals were O(S_local); so are the carries.
     """
     q, k, v, out, lse = res
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, S, H, D = q.shape
     Hkv = k.shape[2]
@@ -517,7 +516,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 "(the per-block flash kernels don't model the offset "
                 "band mask); use block_impl='auto' or 'naive'")
         _validate_tile_overrides(q, k, block_q, block_k)
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
 
     if sp == 1:
         # Degenerate ring: plain block attention under autodiff (the
@@ -553,7 +552,7 @@ def make_ring_attention(mesh: Mesh, causal: bool = True,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -37,11 +37,10 @@ from __future__ import annotations
 import functools
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distributed_training_tpu.ops.attention import dot_product_attention
-from distributed_training_tpu.parallel.compat import axis_size
 from distributed_training_tpu.runtime import AXIS_SP, BATCH_AXES
 
 
@@ -60,7 +59,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     threaded so the bench sweep tunes every attention layout
     (single-device, Ulysses, and the ring) with one knob.
     """
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     if sp == 1:
         return dot_product_attention(q, k, v, causal=causal,
                                      impl=local_impl, block_q=block_q,
@@ -116,7 +115,7 @@ def make_ulysses_attention(mesh: Mesh, causal: bool = True,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -33,7 +33,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 logger = logging.getLogger(__name__)
@@ -115,7 +115,7 @@ def _broadcast_from_rank0(params, mesh: Mesh):
 
     fn = shard_map(
         lambda t: jax.tree.map(bcast, t),
-        mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
     return fn(params)
 
 
@@ -163,7 +163,7 @@ def train_ddp(world_size: int | None = None, epochs: int = 3,
         mesh=mesh,
         in_specs=(P(), P("dp"), P("dp"), P()),
         out_specs=(P(), metric_specs),
-        check_rep=False,
+        check_vma=False,
     )
     # Donate the params buffer: the caller rebinds ``params`` to the
     # step's output every iteration, so the old copy is dead — same
@@ -243,8 +243,6 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(message)s")
-    from distributed_training_tpu.runtime import apply_env_platforms
-    apply_env_platforms()
     result = train_ddp(
         world_size=args.world_size, epochs=args.epochs,
         batch_size=args.batch_size, lr=args.lr,

@@ -30,7 +30,6 @@ import time
 from dataclasses import dataclass
 
 import jax
-import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -105,19 +104,16 @@ def build_mesh(spec: MeshSpec, devices: list | None = None) -> Mesh:
 
     Uses ``mesh_utils.create_device_mesh`` so logical axes map onto the
     physical ICI torus sensibly (innermost logical axis → nearest
-    neighbours); falls back to a plain reshape for platforms where the
-    topology helper is unsupported (CPU fake devices).
+    neighbours). A topology-helper failure is an error: a mesh that
+    ignores the physical layout must not pass for the planned one.
     """
     devices = list(jax.devices()) if devices is None else list(devices)
     shape = tuple(spec.as_dict()[a] for a in MESH_AXES)
     if math.prod(shape) != len(devices):
         raise RuntimeError_(
             f"mesh shape {shape} != device count {len(devices)}")
-    try:
-        dev_array = mesh_utils.create_device_mesh(
-            shape, devices=devices, allow_split_physical_axes=True)
-    except Exception:  # pragma: no cover - topology helper unavailable
-        dev_array = np.asarray(devices).reshape(shape)
+    dev_array = mesh_utils.create_device_mesh(
+        shape, devices=devices, allow_split_physical_axes=True)
     return Mesh(dev_array, MESH_AXES)
 
 
@@ -235,57 +231,69 @@ def _maybe_init_distributed() -> None:
             raise
 
 
-# Sentinel + saved value for the device=cpu platform force (see
-# initialize_runtime): lets a later auto/tpu call in the same process
-# restore the original platform selection.
-_UNFORCED = object()
-_PLATFORMS_BEFORE_CPU_FORCE: object = _UNFORCED
+# Default persistent compile cache: one fixed, git-ignored directory
+# inside the checkout. The path is part of the cache key's lookup, so
+# it must never move between runs (no tempfile, pid, host or clock).
+_DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
 
-def apply_env_platforms() -> str | None:
-    """Make an explicit ``JAX_PLATFORMS`` env var win over site
-    customizations that pin ``jax_platforms`` at interpreter start
-    (some managed images pin their accelerator plugin, which would
-    silently override the documented env-var contract). Returns the
-    env value, or None if unset. Shared by every entrypoint."""
-    env_platforms = os.environ.get("JAX_PLATFORMS")
-    if env_platforms and jax.config.jax_platforms != env_platforms:
-        jax.config.update("jax_platforms", env_platforms)
-    return env_platforms or None
+def enable_compile_cache() -> str:
+    """Point JAX's persistent compilation cache somewhere stable;
+    every entry point calls this before its first compile. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
+    nothing is set in code; otherwise the cache lives in the checkout's
+    ``.jax_cache/``. Returns the directory in use."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    jax.config.update("jax_compilation_cache_dir",
+                      _DEFAULT_COMPILE_CACHE_DIR)
+    return _DEFAULT_COMPILE_CACHE_DIR
+
+
+def cpu_requested() -> bool:
+    """True when this process selected the CPU platform on purpose:
+    ``JAX_PLATFORMS=cpu`` in the environment or ``train.device=cpu``
+    (both land in ``jax_platforms``, whose first entry is the default
+    backend — a trailing ``,cpu`` as in ``tpu,cpu`` is no request)."""
+    return (jax.config.jax_platforms or "").split(",")[0] == "cpu"
+
+
+def default_platform() -> str:
+    """Platform of JAX's default backend. A CPU that nobody asked for
+    means the accelerator did not answer and JAX fell back silently —
+    that is an error here, never a slow run that looks healthy.
+    Backend start-up errors propagate."""
+    platform = jax.default_backend()
+    if platform == "cpu" and not cpu_requested():
+        raise RuntimeError_(
+            "no accelerator: JAX fell back to the CPU backend. To run "
+            "on the CPU on purpose set JAX_PLATFORMS=cpu or "
+            "train.device=cpu")
+    return platform
 
 
 def initialize_runtime(cfg: Config) -> Runtime:
     """Build the runtime: rendezvous (if multi-host), pick devices per
-    ``cfg.train.device`` ("auto" prefers TPU, parity with reference
-    device="auto" → cuda-if-available, src/distributed_trainer.py:53-58),
-    resolve the mesh shape, and construct the mesh."""
-    global _PLATFORMS_BEFORE_CPU_FORCE
-    env_platforms = apply_env_platforms()
+    ``cfg.train.device``, resolve the mesh shape, and construct the
+    mesh. ``auto`` means "the accelerator" (parity with the reference's
+    device="auto" → cuda-if-available, src/distributed_trainer.py:
+    53-58, minus its silent CPU fallback): it resolves to a CPU only
+    when the CPU was asked for (``cpu_requested``)."""
     device_pref = cfg.train.device
     if device_pref == "cpu":
-        # Hard-select the CPU platform BEFORE anything (including
+        # Select the CPU platform BEFORE anything (including
         # jax.distributed auto-detection below) can initialize a
-        # backend: probing an accelerator plugin can block or fail when
-        # the TPU runtime is present but unhealthy, and `device=cpu`
-        # (the reference's CPU/Gloo fallback, src/distributed_trainer
-        # .py:55-61) must never depend on accelerator health.
-        if _PLATFORMS_BEFORE_CPU_FORCE is _UNFORCED:
-            _PLATFORMS_BEFORE_CPU_FORCE = jax.config.jax_platforms
+        # backend: `device=cpu` (the reference's CPU/Gloo fallback,
+        # src/distributed_trainer.py:55-61) must never depend on
+        # accelerator health.
         jax.config.update("jax_platforms", "cpu")
-    elif _PLATFORMS_BEFORE_CPU_FORCE is not _UNFORCED:
-        # A previous device=cpu call forced the platform; undo it so
-        # "auto"/"tpu" in the same process sees accelerators again
-        # (best effort — backends a prior run already initialized on a
-        # forced-cpu platform set may persist in jax's cache). An
-        # explicit JAX_PLATFORMS env var still wins: never overwrite
-        # the value the block above just applied.
-        if not env_platforms:
-            jax.config.update("jax_platforms",
-                              _PLATFORMS_BEFORE_CPU_FORCE)
-        _PLATFORMS_BEFORE_CPU_FORCE = _UNFORCED
     _maybe_init_distributed()
 
     if device_pref in ("auto", ""):
+        default_platform()
         devices = jax.devices()
     else:
         try:

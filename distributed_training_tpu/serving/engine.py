@@ -427,7 +427,7 @@ def _sharded(body, mesh, dp_axis: str, n_grouped: int,
     outputs are group-batched. Every OTHER mesh axis is an ``auto``
     axis — tp's head shard (params + pool kv-head dim) stays under
     the SPMD partitioner exactly as in the unsharded engine."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     grouped = P(dp_axis)
@@ -436,8 +436,7 @@ def _sharded(body, mesh, dp_axis: str, n_grouped: int,
     out_specs = (grouped,) * n_outs
     return shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-        auto=frozenset(mesh.axis_names) - {dp_axis})
+        axis_names={dp_axis}, check_vma=False)
 
 
 def _out_shardings(model_cfg, ecfg: EngineConfig, mesh):
@@ -625,14 +624,14 @@ def build_cow_fn(model_cfg, ecfg: EngineConfig, mesh=None):
         _grp, pool = _out_shardings(model_cfg, ecfg, mesh)
         kw["out_shardings"] = (pool, pool)
     if _dp_extent(mesh, ecfg.dp_axis) > 1:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         grouped = P(ecfg.dp_axis)
         body = shard_map(
             body, mesh=mesh, in_specs=(grouped,) * 4,
-            out_specs=(grouped,) * 2, check_rep=False,
-            auto=frozenset(mesh.axis_names) - {ecfg.dp_axis})
+            out_specs=(grouped,) * 2,
+            axis_names={ecfg.dp_axis}, check_vma=False)
     return jax.jit(body, donate_argnums=(0, 1), **kw)
 
 

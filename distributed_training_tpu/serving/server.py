@@ -836,13 +836,10 @@ def main(argv=None) -> int:
 
     import os
 
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms",
-                          os.environ["JAX_PLATFORMS"])
+    from distributed_training_tpu.runtime import enable_compile_cache
     from distributed_training_tpu.telemetry import (Telemetry,
                                                     install)
+    enable_compile_cache()
     # The sink must be ENABLED (jsonl-backed) for the observer chain
     # to fire — a disabled Telemetry emits nothing and the gauges
     # would stay empty (telemetry/events.py::_emit's fast path).

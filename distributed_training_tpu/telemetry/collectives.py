@@ -303,9 +303,13 @@ def audit_hlo_text(text: str, mesh=None) -> dict:
     axis combination its replica groups communicate over) and the
     report gains a ``by_axis`` rollup. The stable consumer surface:
     ``schema``, ``total_collectives``, ``bytes_per_step``, ``by_kind``
-    (kind → {count, bytes}), ``by_axis`` (mesh only), ``rows``.
+    (kind → {count, bytes}), ``by_axis`` (mesh only), ``rows``, and
+    ``pallas_calls`` — how many Mosaic (Pallas TPU) kernels the
+    program launches, so a run's own event stream says whether it
+    measured the flash kernels or the naive attention path.
     """
     groupings = mesh_axis_groupings(mesh) if mesh is not None else None
+    pallas_calls = text.count('custom_call_target="tpu_custom_call"')
     rows = []
     counted_rs: set[str] = set()
     # Bodies of called computations, for fused-RS axis attribution:
@@ -363,6 +367,7 @@ def audit_hlo_text(text: str, mesh=None) -> dict:
         "total_collectives": len(rows),
         "bytes_per_step": sum(r["bytes"] for r in rows),
         "by_kind": dict(by_kind),
+        "pallas_calls": pallas_calls,
         "largest": sorted(rows, key=lambda r: -r["bytes"])[:10],
         # Full row list: contract tests must scan EVERY collective —
         # a pathological row ranked 11th would hide from "largest".

@@ -19,10 +19,10 @@ arxiv 2410.06511).
 Ambient use (the ``logging`` model): entrypoints ``install()`` one
 ``Telemetry``; library code calls the module-level ``span()`` /
 ``event()``, which no-op (except the trace annotation) until something
-is installed. BENCH_r05's "backend unresponsive, zero artifacts"
-failure is the motivating counterexample — with this installed, the
-watchdog (telemetry/watchdog.py) can dump the last N events of exactly
-this stream into a postmortem.
+is installed. A run that dies with zero artifacts is the motivating
+counterexample — with this installed, the watchdog
+(telemetry/watchdog.py) can dump the last N events of exactly this
+stream into a postmortem.
 """
 
 from __future__ import annotations

@@ -1,20 +1,12 @@
 """Hang watchdog + postmortem bundles.
 
-BENCH_r05 ended as "accelerator backend unresponsive after 3 probes"
-with zero artifacts explaining where the hang was. This module makes a
-hang produce evidence: a daemon thread is armed before each step and
-disarmed after; if a step exceeds the timeout it writes a postmortem
+A run that hangs with nothing on disk saying where cannot be
+debugged. This module makes a hang produce evidence: a daemon thread
+is armed before each step and disarmed after; if a step exceeds the timeout it writes a postmortem
 directory — faulthandler stacks of ALL threads (works even when the
 main thread is blocked inside an uninterruptible C call, e.g. a wedged
 PJRT collective), per-device ``memory_stats()``, and the tail of the
 telemetry event stream — before optionally aborting the process.
-
-``write_postmortem`` is also callable directly (bench.py's run
-watchdog, probe budget expiry), and ``arm_process_watchdog`` arms a
-pure-faulthandler fallback for subprocesses that may be SIGKILLed from
-outside (benchmarks/probe_loop.sh): the stack dump is scheduled inside
-the interpreter, so it lands on disk before the external ``timeout -k``
-fires.
 
 Dump ordering is deliberate: meta + stacks first (pure host-side,
 cannot hang), device memory stats last (touches the backend, which is
@@ -23,10 +15,6 @@ exactly what may be wedged) — a hang mid-dump still leaves the stacks.
 
 from __future__ import annotations
 
-import atexit
-import faulthandler
-import itertools
-import json
 import logging
 import os
 import sys
@@ -34,11 +22,6 @@ import threading
 import time
 
 logger = logging.getLogger(__name__)
-
-# Monotonic per-process suffix: two postmortems in the same second
-# (e.g. a watchdog firing while a budget timer also fires) must land
-# in distinct bundles, not overwrite each other.
-_SEQ = itertools.count()
 
 
 def _device_memory_stats() -> list[dict]:
@@ -198,52 +181,3 @@ class HangWatchdog:
             # The stacks are on disk; a process wedged in a C call
             # cannot run atexit handlers anyway.
             os._exit(self.EXIT_CODE)
-
-
-def arm_process_watchdog(timeout_s: float, postmortem_dir: str,
-                         reason: str):
-    """Faulthandler-only process watchdog for externally-killed
-    subprocesses (the probe loop's ``timeout -k`` children): schedules
-    an all-thread stack dump into a postmortem bundle at ``timeout_s``.
-    Returns ``cancel()`` — call it on success to cancel the dump and
-    remove the (then-empty) bundle. ``cancel`` is idempotent and also
-    registered atexit, so an error exit that never reaches the success
-    path doesn't litter the postmortem dir with empty decoy bundles; a
-    bundle whose dump actually FIRED (non-empty stacks) is always
-    kept."""
-    stamp = time.strftime("%Y%m%dT%H%M%SZ", time.gmtime())
-    path = os.path.join(
-        postmortem_dir, f"{stamp}_pid{os.getpid()}_{next(_SEQ)}")
-    os.makedirs(path, exist_ok=True)
-    with open(os.path.join(path, "meta.json"), "w") as f:
-        json.dump({"reason": reason, "armed_at_unix": time.time(),
-                   "timeout_s": timeout_s, "pid": os.getpid()}, f,
-                  indent=1)
-    stacks_path = os.path.join(path, "stacks.txt")
-    stacks = open(stacks_path, "w")
-    faulthandler.dump_traceback_later(timeout_s, file=stacks)
-    done = []
-
-    def cancel() -> None:
-        if done:
-            return
-        done.append(True)
-        faulthandler.cancel_dump_traceback_later()
-        stacks.close()
-        try:
-            if os.path.getsize(stacks_path) > 0:
-                return  # the dump fired: the bundle is evidence
-        except OSError:
-            pass
-        for name in ("stacks.txt", "meta.json"):
-            try:
-                os.remove(os.path.join(path, name))
-            except OSError:
-                pass
-        try:
-            os.rmdir(path)
-        except OSError:
-            pass
-
-    atexit.register(cancel)
-    return cancel

@@ -39,9 +39,11 @@ def main(argv: list[str] | None = None) -> int:
     args = build_argparser().parse_args(argv)
 
     from distributed_training_tpu.config import load_config, save_resolved
-    from distributed_training_tpu.runtime import initialize_runtime
+    from distributed_training_tpu.runtime import (enable_compile_cache,
+                                                  initialize_runtime)
     from distributed_training_tpu.utils.logging import setup_logging
 
+    enable_compile_cache()
     cfg = load_config(args.config_dir, args.config_name, args.overrides)
 
     run_dir = os.path.join(cfg.run.output_dir, cfg.run.experiment_name)

@@ -138,7 +138,12 @@ def init_state(model, optimizer, rng: jax.Array, shardings: dict) -> dict:
                      out_shardings=shardings["params"])(rng)
     opt_state = jax.jit(optimizer.init,
                         out_shardings=shardings["opt_state"])(params)
-    step = jnp.zeros((), jnp.int32)
+    # Placed like every other leaf: a step scalar left on the default
+    # device lowers without its sharding annotation, so the program the
+    # first call compiles differs (by one attribute) from the one the
+    # collectives audit lowers from abstract_state — and the audit's
+    # compile then misses the persistent cache instead of hitting it.
+    step = jax.device_put(jnp.zeros((), jnp.int32), shardings["step"])
     return {"params": params, "opt_state": opt_state, "step": step}
 
 

@@ -21,7 +21,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from distributed_training_tpu.runtime import BATCH_AXES
@@ -79,7 +79,7 @@ def _divergence_fn(mesh: Mesh, axes: tuple[str, ...],
 
         fn = jax.jit(shard_map(per_replica, mesh=mesh,
                                in_specs=(in_specs,),
-                               out_specs=out_specs, check_rep=False))
+                               out_specs=out_specs, check_vma=False))
         _DIVERGENCE_FNS[key] = fn
         while len(_DIVERGENCE_FNS) > _DIVERGENCE_CACHE_MAX:
             _DIVERGENCE_FNS.popitem(last=False)

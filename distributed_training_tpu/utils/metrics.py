@@ -37,16 +37,21 @@ TPU_PEAK_FLOPS: dict[str, float] = {
     "v5 lite": 197e12,
     "v5p": 459e12,
     "v6e": 918e12,
-    "cpu": 1e11,  # nominal, keeps MFU finite in tests
+    "cpu": 1e11,  # nominal; read by CPU tests and the CPU-priced planner
 }
 
 
 def peak_flops_per_chip(device_kind: str) -> float:
+    """Peak for ``device_kind`` (free-form, e.g. "TPU v5 lite";
+    substring match). A kind that is not in the table is an error —
+    an MFU against a made-up peak is not a measurement."""
     kind = device_kind.lower()
     for key, flops in TPU_PEAK_FLOPS.items():
         if key in kind:
             return flops
-    return TPU_PEAK_FLOPS["cpu"]
+    raise ValueError(
+        f"no peak FLOP/s recorded for device kind {device_kind!r}; add "
+        f"it (with its source) to TPU_PEAK_FLOPS")
 
 
 def compute_mfu(model_flops_per_sec_per_chip: float,

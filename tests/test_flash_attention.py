@@ -184,3 +184,39 @@ def test_fused_bwd_vmem_gate_budgets_full_residency():
     # Ring callers (f32 grads) inflate dq residency ~1.5x.
     assert not fa._fused_bwd_fits(4096, 128, 1024, 1024, jnp.bfloat16,
                                   jnp.float32)
+
+
+def test_auto_naive_choice_on_tpu_is_logged_once_with_reason(
+        monkeypatch, caplog):
+    """Where ``auto`` attention runs the naive path ON A TPU the log
+    says so, once per reason, naming shape/tile/head count — so a
+    run's log tells which kernel it measured. Off-TPU (every other
+    test here) naive is the expected path and nothing is logged."""
+    import logging
+
+    import distributed_training_tpu.ops.flash_attention as fa
+    from distributed_training_tpu.ops.attention import (
+        dot_product_attention)
+
+    q, k, v = rand_qkv(S=64)
+    assert fa.unsupported_reason(q, k, v) == "platform is not tpu"
+    with caplog.at_level(logging.WARNING, logger=fa.logger.name):
+        dot_product_attention(q, k, v, impl="auto")
+    assert not caplog.records
+
+    monkeypatch.setattr(fa, "_platform_is_tpu", lambda: True)
+    fa._warn_naive_once.cache_clear()
+    assert fa.unsupported_reason(q, k, v) == "sequence 64 < 128"
+    q100, k100, v100 = rand_qkv(S=1100)
+    assert "no tile divides" in fa.unsupported_reason(q100, k100, v100)
+    q5, k3, v3 = rand_qkv(S=256, H=5, Hkv=3)
+    assert "n_heads 5" in fa.unsupported_reason(q5, k3, v3)
+    assert fa.unsupported_reason(*rand_qkv(S=256)) is None
+    with caplog.at_level(logging.WARNING, logger=fa.logger.name):
+        for _ in range(3):
+            out = dot_product_attention(q, k, v, impl="auto")
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_naive_attention(q, k, v)))
+    lines = [r.getMessage() for r in caplog.records]
+    assert len(lines) == 1 and "sequence 64 < 128" in lines[0]
+    fa._warn_naive_once.cache_clear()

@@ -69,3 +69,12 @@ def test_wait_fail_fast(tmp_path):
     )
     code = launch_local_mod.wait(procs, timeout=60)
     assert code == 3
+
+
+def test_refuses_accelerator_platform_with_several_processes():
+    """A CPU simulation launcher: every child inherits the whole host,
+    so N > 1 children on a TPU platform would each claim every chip —
+    refused before anything is spawned. One process is fine."""
+    with pytest.raises(ValueError, match="CPU simulation launcher"):
+        launch_local_mod.launch_local(
+            ["-c", "pass"], 2, env={"JAX_PLATFORMS": "tpu"})

@@ -20,12 +20,12 @@ def _read_jsonl(path):
 
 def test_peak_flops_lookup_substring_matches():
     # device_kind strings are free-form ("TPU v5 lite"); the lookup is
-    # substring-based with a CPU fallback.
+    # substring-based and an unknown kind is an error, not a default.
     assert peak_flops_per_chip("TPU v4") == 275e12
     assert peak_flops_per_chip("TPU v5 lite") == 197e12
     assert peak_flops_per_chip("TPU v5p") == 459e12
-    assert peak_flops_per_chip("weird accelerator") == \
-        peak_flops_per_chip("cpu")
+    with pytest.raises(ValueError, match="weird accelerator"):
+        peak_flops_per_chip("weird accelerator")
 
 
 def test_compute_mfu_hand_computed():

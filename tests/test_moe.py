@@ -193,8 +193,7 @@ def test_moe_composes_with_ulysses(cpu8):
 def test_topk_by_argmax_matches_lax_topk_fwd_and_bwd():
     """Routing selects via _topk_by_argmax (the SPMD partitioner
     cannot partition lax.top_k's TopK custom-call and all-gathered the
-    routing probs across shards — BENCH_r04 contract remainder, fixed
-    r5). Selection, ordering AND gradient must match lax.top_k exactly
+    routing probs across shards). Selection, ordering AND gradient must match lax.top_k exactly
     — including tied probs (a freshly-initialized router ties every
     expert; jnp.max's VJP would split the cotangent across ties,
     leaking gradient onto unselected experts)."""

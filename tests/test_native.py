@@ -145,3 +145,14 @@ def test_fill_tokens_fallback_used_when_disabled(monkeypatch):
     expect = nat.fill_tokens(seed=11, vocab=1000, n=9000)
     assert nat.available()
     np.testing.assert_array_equal(got, expect)
+
+
+def test_library_is_keyed_on_flags_as_well_as_source(monkeypatch):
+    """A binary left in the tree by another build recipe (the old
+    -march=native one, say) must not be the one that loads: the cached
+    file's name covers the compiler flags, which are portable ones."""
+    assert "-march=native" not in native._CXX_FLAGS
+    before = native._lib_path()
+    monkeypatch.setattr(native, "_CXX_FLAGS",
+                        [*native._CXX_FLAGS, "-march=native"])
+    assert native._lib_path() != before

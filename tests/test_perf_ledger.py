@@ -38,9 +38,9 @@ def _edit(root, name, fn):
 def test_committed_ledgers_are_green():
     trajectory, problems = perf_ledger.check(REPO)
     assert problems == []
-    assert len(trajectory) >= 18
+    assert len(trajectory) >= 14
     families = {row["family"] for row in trajectory}
-    assert {"BENCH", "MULTICHIP", "SERVING"} <= families
+    assert {"MULTICHIP", "SERVING"} <= families
 
 
 def test_trajectory_rows_carry_gates():
@@ -105,10 +105,10 @@ def test_red_on_revision_gap(tmp_path):
 
 def test_red_on_unparseable_ledger(tmp_path):
     root = _copy_ledgers(tmp_path)
-    with open(os.path.join(root, "BENCH_r01.json"), "w") as f:
+    with open(os.path.join(root, "MULTICHIP_r01.json"), "w") as f:
         f.write("{not json")
     _, problems = perf_ledger.check(root)
-    assert any("BENCH_r01.json: unreadable" in p for p in problems)
+    assert any("MULTICHIP_r01.json: unreadable" in p for p in problems)
 
 
 def test_cli_by_path_green_and_red(tmp_path):
@@ -136,7 +136,7 @@ def test_json_output():
     assert out.returncode == 0
     payload = json.loads(out.stdout)
     assert payload["problems"] == []
-    assert len(payload["trajectory"]) >= 18
+    assert len(payload["trajectory"]) >= 14
 
 
 def test_close_tolerances():
@@ -147,7 +147,7 @@ def test_close_tolerances():
 
 
 @pytest.mark.parametrize("entry,problem", [
-    ("BENCH_r01.json", "crosses families"),
+    ("MULTICHIP_r01.json", "crosses families"),
     ("SERVING_r09.json", "not an earlier revision"),
     ("nonsense", "not a ledger filename"),
 ])

@@ -19,7 +19,7 @@ from distributed_training_tpu.models import build_model
 from distributed_training_tpu.telemetry.goodput import GoodputLedger
 from distributed_training_tpu.telemetry.hbm import HBMSampler
 from distributed_training_tpu.telemetry.watchdog import (
-    HangWatchdog, arm_process_watchdog, write_postmortem)
+    HangWatchdog, write_postmortem)
 from distributed_training_tpu.train.trainer import Trainer
 
 
@@ -247,26 +247,6 @@ def test_write_postmortem_unique_dirs(tmp_path):
     p2 = write_postmortem(str(tmp_path), "second")
     assert p1 != p2 and _postmortem_complete(p1) \
         and _postmortem_complete(p2)
-
-
-def test_arm_process_watchdog_cancel_removes_bundle(tmp_path):
-    cancel = arm_process_watchdog(30.0, str(tmp_path / "pm"), "probe")
-    assert os.listdir(str(tmp_path / "pm"))
-    cancel()
-    cancel()  # idempotent (also registered atexit — must not double-act)
-    assert os.listdir(str(tmp_path / "pm")) == []
-
-
-def test_arm_process_watchdog_keeps_fired_bundle(tmp_path):
-    """A dump that actually fired is evidence: cancel() (explicit or
-    via atexit) must keep it, not delete it."""
-    cancel = arm_process_watchdog(0.2, str(tmp_path / "pm"), "probe")
-    time.sleep(0.6)  # the faulthandler dump fires
-    cancel()
-    (bundle,) = os.listdir(str(tmp_path / "pm"))
-    stacks = open(os.path.join(str(tmp_path / "pm"), bundle,
-                               "stacks.txt")).read()
-    assert stacks.strip()
 
 
 # -- hbm sampler -----------------------------------------------------------

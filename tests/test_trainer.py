@@ -222,3 +222,23 @@ def test_vocab_mismatch_fails_preflight(cpu8):
     loader = ShardedDataLoader(ds, cpu8, batch_size=1)
     with pytest.raises(ValueError, match="vocab of 50257"):
         Trainer(cfg, cpu8, model, loader)
+
+
+def test_first_step_and_audit_lower_the_same_program(cpu8):
+    """The collectives audit lowers the step again from abstract
+    inputs. Every state leaf — the step scalar included — is placed in
+    its sharding at init, so both lowerings are the SAME module and
+    the audit's compile is the first step's, served from JAX's cache
+    (one backend compile on the chip, not two)."""
+    from distributed_training_tpu.train import state as state_lib
+
+    trainer, _ = make_trainer(cpu8, "ddp")
+    batch = next(iter(trainer.loader.epoch(0)))
+    abstract = state_lib.abstract_state(
+        trainer.model, trainer.optimizer, trainer.init_rng,
+        trainer._device_state_shardings)
+    concrete = trainer._step_fn.lower(trainer.state, batch,
+                                      trainer.step_rng).as_text()
+    audited = trainer._step_fn.lower(abstract, batch,
+                                     trainer.step_rng).as_text()
+    assert concrete == audited

@@ -2,9 +2,8 @@
 """Perf-ledger regression gate: ``perf_ledger.py --check``.
 
 The repo commits one performance ledger per bench revision at the
-root — ``BENCH_r*.json`` (single-chip probe dumps),
-``MULTICHIP_r*.json`` (planned-mesh step-time runs) and
-``SERVING_r*.json`` (serving storm runs). Since SERVING_r02 every
+root — ``MULTICHIP_r*.json`` (raw dry-run dumps, then planned-mesh
+step-time runs) and ``SERVING_r*.json`` (serving storm runs). Since SERVING_r02 every
 structured ledger carries a ``compared_to`` block: the predecessor's
 headline numbers copied in verbatim, plus the speedup gates computed
 against them. Those chains were only ever checked by eyeball. This
@@ -12,7 +11,7 @@ tool parses EVERY committed ``*_r*.json`` into one per-family
 trajectory and goes red when:
 
 - a family's revisions are not contiguous from r01, a ledger fails to
-  parse, or a raw probe dump is missing its shape (``rc``/``tail``);
+  parse, or a raw dump is missing its shape (``rc``/``tail``);
 - a ``compared_to.entry`` is missing, cross-family, or not an earlier
   revision (SERVING also pins ``revision``/``compared_to.revision``
   strings to the filenames);
@@ -245,7 +244,7 @@ def check(root: str) -> tuple[list[dict], list[str]]:
             d = ledgers[rev]
             name = f"{family}_r{rev:02d}.json"
             if "schema" not in d:
-                # Raw probe dump: shape only.
+                # Raw dump: shape only.
                 if "rc" not in d or "tail" not in d:
                     problems.append(f"{name}: raw ledger missing "
                                     f"rc/tail shape")
