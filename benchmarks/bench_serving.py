@@ -183,6 +183,7 @@ def drive_storm(engine, workload, preempt_after_completed=None):
     pending = list(workload)
     max_in_flight = 0
     steps = 0
+    records = []
     while True:
         now = time.monotonic() - t_start
         while pending and pending[0][0] <= now:
@@ -206,7 +207,7 @@ def drive_storm(engine, workload, preempt_after_completed=None):
                             for (_t, p, n, rid) in pending])
             return {"preempted": True, "wasted_tokens": wasted,
                     "wall_s": time.monotonic() - t_start,
-                    "steps": steps,
+                    "steps": steps, "records": records,
                     "max_in_flight": max_in_flight,
                     "completed": list(engine.completed),
                     "lost": remaining}
@@ -215,12 +216,30 @@ def drive_storm(engine, workload, preempt_after_completed=None):
                 break
             time.sleep(min(0.001, pending[0][0] - now))
             continue
-        engine.step()
+        records.append(engine.step())
         steps += 1
     return {"preempted": False,
             "wall_s": time.monotonic() - t_start, "steps": steps,
-            "max_in_flight": max_in_flight,
+            "records": records, "max_in_flight": max_in_flight,
             "completed": list(engine.completed)}
+
+
+def launch_totals(records) -> dict:
+    """Speculative and resident launch accounting, summed from the
+    engine's step records (the engine keeps no totals of its own): a
+    speculative slot-launch is one slot's chunk in one decode step, a
+    resident launch one burst, whose depth is the mean over its dp
+    groups."""
+    decode = [r for r in records if r["op"] == "decode"]
+    spec = [r for r in decode if "spec_accepted_mean" in r]
+    resident = [r for r in decode if "resident_steps_per_launch" in r]
+    return {
+        "spec_launches": sum(r["slots_stepped"] for r in spec),
+        "spec_emitted": sum(r["tokens"] for r in spec),
+        "resident_launches": len(resident),
+        "resident_steps": sum(r["resident_steps_per_launch"]
+                              for r in resident),
+        "resident_emitted": sum(r["tokens"] for r in resident)}
 
 
 def full_context_greedy(model, params, prompt, n, pad_to):
@@ -403,8 +422,7 @@ def main(argv=None) -> int:
             f"engine recompiled mid-storm: warmup {warm_counts} -> "
             f"{post_counts}")
     steady = summarize(stats["completed"], stats["wall_s"])
-    spec = engine.spec_stats
-    res = engine.resident_stats
+    totals = launch_totals(stats["records"])
     steady.update(max_in_flight=stats["max_in_flight"],
                   steps=stats["steps"],
                   compile_counts=warm_counts,
@@ -416,11 +434,13 @@ def main(argv=None) -> int:
                   resident_k=args.resident_k,
                   host_syncs=engine.host_syncs - syncs0,
                   resident_steps_per_launch=round(
-                      res["steps"] / res["launches"], 3)
-                  if res["launches"] else None,
+                      totals["resident_steps"]
+                      / totals["resident_launches"], 3)
+                  if totals["resident_launches"] else None,
                   spec_accepted_mean=round(
-                      spec["emitted"] / spec["launches"], 3)
-                  if spec["launches"] else None)
+                      totals["spec_emitted"]
+                      / totals["spec_launches"], 3)
+                  if totals["spec_launches"] else None)
     tokens_by_id = {r["id"]: r["tokens"] for r in stats["completed"]}
 
     # Greedy parity vs the full-context reference: the dp-sharded
@@ -468,7 +488,10 @@ def main(argv=None) -> int:
             eng.submit(Request(id=rid, prompt=prompt,
                                max_new_tokens=1))
         t0 = time.monotonic()
-        steps = eng.run_until_drained()
+        records = []
+        while not eng.idle:
+            records.append(eng.step())
+        steps = len(records)
         wall = time.monotonic() - t0
         if eng.compile_counts() != warm:
             raise AssertionError("recompiled during prefill drain")
@@ -542,17 +565,17 @@ def main(argv=None) -> int:
                "steps": steps, "host_syncs": eng.host_syncs - h0,
                "completions": len(eng.completed),
                "tokens_per_s": round(toks / wall, 2)}
-        if eng.spec_stats["launches"]:
+        totals = launch_totals(records)
+        if totals["spec_launches"]:
             rec["spec_accepted_mean"] = round(
-                eng.spec_stats["emitted"]
-                / eng.spec_stats["launches"], 3)
-            rec["spec_launches"] = eng.spec_stats["launches"]
-        if eng.resident_stats["launches"]:
-            rs = eng.resident_stats
-            rec["resident_launches"] = rs["launches"]
+                totals["spec_emitted"] / totals["spec_launches"], 3)
+            rec["spec_launches"] = totals["spec_launches"]
+        if totals["resident_launches"]:
+            rec["resident_launches"] = totals["resident_launches"]
             rec["resident_steps_per_launch"] = round(
-                rs["steps"] / rs["launches"], 3)
-            rec["decode_tokens"] = rs["emitted"]
+                totals["resident_steps"]
+                / totals["resident_launches"], 3)
+            rec["decode_tokens"] = totals["resident_emitted"]
         return rec, streams
 
     saturated, _ = saturated_run(

@@ -480,6 +480,10 @@ def phase_engine() -> dict:
                         **ENGINE_GEOMETRY)
     tel = telemetry.install(telemetry.Telemetry(
         events_jsonl=os.path.join(OUT, "serve", "events.jsonl")))
+    bursts: list = []   # the decode step records: the engine's ledger
+    tel.add_observer(lambda r: bursts.append(r)
+                     if r.get("kind") == "serving"
+                     and r.get("op") == "decode" else None)
     t0 = time.perf_counter()
     eng = Engine(model, bf16, ecfg, mesh=None)
     counts = eng.warmup()
@@ -545,8 +549,10 @@ def phase_engine() -> dict:
     say(f"  vs full-context forward: {exact}/{n_tok} tokens are its "
         f"argmax, worst logit gap {worst:.4f} (tolerance {TIE_TOL})")
     say(f"  no recompile after warm-up: {after}; host syncs "
-        f"{eng.host_syncs}; resident {eng.resident_stats}; prefix "
-        f"{eng.prefix_stats}")
+        f"{eng.host_syncs}; resident bursts {len(bursts)}, "
+        f"{sum(r['tokens'] for r in bursts)} tokens in "
+        f"{sum(r['slot_iters'] for r in bursts)} slot iterations; "
+        f"prefix {eng.prefix_stats}")
     say(f"  smoke wall for the {2 * len(prompts)} requests {wall:.1f}s")
     if worst > TIE_TOL:
         raise AssertionError(

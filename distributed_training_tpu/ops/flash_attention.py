@@ -282,6 +282,7 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, out_dtype=None,
 
     out, lse = pl.pallas_call(
         kernel,
+        name="dtt_flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D),
@@ -559,6 +560,7 @@ def _flash_bwd_fused(q, k, v, lse, do, delta, *, causal, block_q,
         functools.partial(_bwd_fused_kernel, scale=scale,
                           block_q=block_q, block_k=block_k,
                           causal=causal, window=window),
+        name="dtt_flash_bwd_fused",
         grid=(B, H, nk, nq),
         in_specs=[qi_spec, kv_spec, kv_spec, qi_spec, row_spec,
                   row_spec],
@@ -626,6 +628,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, block_q, block_k,
         functools.partial(_bwd_dq_kernel, scale=scale, block_q=block_q,
                           block_k=block_k, causal=causal,
                           window=window),
+        name="dtt_flash_bwd_dq",
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D),
@@ -655,6 +658,7 @@ def _flash_bwd(q, k, v, out, lse, do, *, causal, block_q, block_k,
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
                           block_k=block_k, causal=causal,
                           window=window),
+        name="dtt_flash_bwd_dkv",
         grid=(B, H, nk, nq),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D),

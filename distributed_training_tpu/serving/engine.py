@@ -36,8 +36,8 @@ via the ``auto`` axes):
   true prefix (a draft is accepted only when it equals the previous
   position's argmax), so speculation changes launch count, never
   tokens. Launch overhead amortizes by the acceptance length
-  (telemetry: ``spec_accepted_mean`` on step records,
-  ``Engine.spec_stats`` totals).
+  (telemetry: ``spec_accepted_mean``, ``slots_stepped`` and
+  ``slot_iters`` on step records).
 
 Join/evict never change a traced shape: admission fills a slot in ONE
 group and allocates pages from that group's shard; completion frees
@@ -93,11 +93,14 @@ from distributed_training_tpu.serving.kv_cache import (
     PagedCacheConfig,
     PagedKVCache,
 )
-from distributed_training_tpu.telemetry import event
+from distributed_training_tpu.telemetry import event, phase
 
 logger = logging.getLogger(__name__)
 
 _STACKED = ("ln1", "ln2", "attn", "mlp")
+# The parts of a step, in the order a launch passes them: the keys of
+# a step record's ``phase_s`` and the ``serving.<part>`` annotations.
+_PHASES = ("admit", "pack", "launch", "fetch", "emit")
 
 
 @dataclass(frozen=True)
@@ -191,7 +194,10 @@ class Request:
     an exact-history prompt needs zero prefill launches at all).
     ``tenant``: multi-tenant accounting label — threaded from the HTTP
     JSON body into the per-request ``serving_trace`` record and the
-    tenant-labeled latency histograms; never affects scheduling."""
+    tenant-labeled latency histograms; never affects scheduling.
+    ``submitted``: stamped by ``Engine.submit`` — arrival to here is
+    the server's mailbox, here to admission the engine's queue (the
+    ``submitted`` span of the request's trace)."""
 
     id: str
     prompt: np.ndarray
@@ -199,6 +205,7 @@ class Request:
     arrival: float | None = None
     session: str | None = None
     tenant: str = "default"
+    submitted: float | None = None
 
 
 @dataclass
@@ -460,6 +467,19 @@ def _out_shardings(model_cfg, ecfg: EngineConfig, mesh):
     return grp, pool
 
 
+def _named(name: str, body):
+    """``body`` under the function name ``name``, for ``jax.jit``: a
+    jitted ``functools.partial`` (and a ``shard_map`` of one) has no
+    name, so jax calls every engine program ``jit__unknown`` — on the
+    trace's ``XLA Modules`` line, in the compile cache's hit and miss
+    lists and in compiler errors. The jitted program of ``name`` is
+    ``jit_<name>``."""
+    def program(*args):
+        return body(*args)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 def build_decode_fn(model_cfg, ecfg: EngineConfig, mesh=None):
     """The jitted dp-sharded decode program for (model, engine cfg,
     mesh). Signature (all group-batched, G = dp extent, B = group-
@@ -482,7 +502,8 @@ def build_decode_fn(model_cfg, ecfg: EngineConfig, mesh=None):
     if _dp_extent(mesh, ecfg.dp_axis) > 1:
         body = _sharded(body, mesh, ecfg.dp_axis,
                         n_grouped=7, n_replicated=0, n_outs=3)
-    return jax.jit(body, donate_argnums=(1, 2), **kw)
+    return jax.jit(_named("serving_decode", body),
+                   donate_argnums=(1, 2), **kw)
 
 
 def build_prefill_fn(model_cfg, ecfg: EngineConfig, first: bool,
@@ -508,13 +529,18 @@ def build_prefill_fn(model_cfg, ecfg: EngineConfig, first: bool,
     if _dp_extent(mesh, ecfg.dp_axis) > 1:
         body = _sharded(body, mesh, ecfg.dp_axis,
                         n_grouped=4, n_replicated=3, n_outs=3)
-    return jax.jit(body, donate_argnums=(1, 2), **kw)
+    return jax.jit(
+        _named("serving_prefill_first" if first
+               else "serving_prefill_cont", body),
+        donate_argnums=(1, 2), **kw)
 
 
-def _chunk_fn(model_cfg, ecfg: EngineConfig, emit: str, mesh=None):
+def _chunk_fn(model_cfg, ecfg: EngineConfig, emit: str, name: str,
+              mesh=None):
     """Jit the multi-lane chunk program (``_chunk_program``) for
-    (model, engine cfg, mesh). Signature (all group-batched, G = dp
-    extent, S = lanes per group, C = tokens per lane):
+    (model, engine cfg, mesh) as ``jit_<name>``. Signature (all
+    group-batched, G = dp extent, S = lanes per group, C = tokens per
+    lane):
     ``fn(params, k_pages, v_pages, page_rows (G, S, P),
     tokens (G, S, C), start_pos (G, S), n_valid (G, S),
     active (G, S), rng_data (G, 2)) -> (next_tokens, k_pages,
@@ -537,7 +563,7 @@ def _chunk_fn(model_cfg, ecfg: EngineConfig, emit: str, mesh=None):
     if _dp_extent(mesh, ecfg.dp_axis) > 1:
         body = _sharded(body, mesh, ecfg.dp_axis,
                         n_grouped=8, n_replicated=0, n_outs=3)
-    return jax.jit(body, donate_argnums=(1, 2), **kw)
+    return jax.jit(_named(name, body), donate_argnums=(1, 2), **kw)
 
 
 def build_prefill_batch_fn(model_cfg, ecfg: EngineConfig, mesh=None):
@@ -547,7 +573,8 @@ def build_prefill_batch_fn(model_cfg, ecfg: EngineConfig, mesh=None):
     and sampling its next token in-program (the first token of every
     prompt-completing lane — read as one (G, S) int32 block, never a
     vocab-sized logits transfer)."""
-    return _chunk_fn(model_cfg, ecfg, emit="last", mesh=mesh)
+    return _chunk_fn(model_cfg, ecfg, emit="last",
+                     name="serving_prefill_batch", mesh=mesh)
 
 
 def build_spec_decode_fn(model_cfg, ecfg: EngineConfig, mesh=None):
@@ -556,7 +583,8 @@ def build_spec_decode_fn(model_cfg, ecfg: EngineConfig, mesh=None):
     lane c's argmax is the verified next token GIVEN the drafted
     prefix, so the host accepts exactly the prefix whose drafts match
     the chain (greedy-token-identical by construction)."""
-    return _chunk_fn(model_cfg, ecfg, emit="all", mesh=mesh)
+    return _chunk_fn(model_cfg, ecfg, emit="all",
+                     name="serving_spec_decode", mesh=mesh)
 
 
 def build_resident_decode_fn(model_cfg, ecfg: EngineConfig,
@@ -590,7 +618,8 @@ def build_resident_decode_fn(model_cfg, ecfg: EngineConfig,
     if _dp_extent(mesh, ecfg.dp_axis) > 1:
         body = _sharded(body, mesh, ecfg.dp_axis,
                         n_grouped=7, n_replicated=0, n_outs=5)
-    return jax.jit(body, donate_argnums=(1, 2), **kw)
+    return jax.jit(_named("serving_resident_decode", body),
+                   donate_argnums=(1, 2), **kw)
 
 
 def _cow_program(k_pages, v_pages, src, dst):
@@ -632,7 +661,8 @@ def build_cow_fn(model_cfg, ecfg: EngineConfig, mesh=None):
             body, mesh=mesh, in_specs=(grouped,) * 4,
             out_specs=(grouped,) * 2,
             axis_names={ecfg.dp_axis}, check_vma=False)
-    return jax.jit(body, donate_argnums=(0, 1), **kw)
+    return jax.jit(_named("serving_cow", body),
+                   donate_argnums=(0, 1), **kw)
 
 
 class Engine:
@@ -686,18 +716,14 @@ class Engine:
                 f"the {self.dp_groups} dp group(s) — the prefill "
                 "lane table deals exactly like the decode table")
         self.prefill_local = prefill_slots // self.dp_groups
-        # Speculative-decode accounting (the acceptance-length
-        # telemetry the bench ledgers): per-slot-launch totals, plus
-        # the last step's numbers for the step record.
-        self.spec_stats = {"launches": 0, "emitted": 0}
-        self._step_spec: tuple[int, int] | None = None
+        # What the last step's launch path leaves for its step
+        # record (the record is the only ledger: totals are sums over
+        # records): the phases' seconds, and the fields that only its
+        # cadence has (speculative acceptance, resident loop depth,
+        # slots and iterations, first tokens).
         self._last_prefill_lanes: list[int] | None = None
-        # Device-resident decode accounting: program launches and
-        # total in-program loop iterations (the burst depth the
-        # ``dtt_serving_resident_steps_per_launch`` gauge tracks).
-        self.resident_stats = {"launches": 0, "steps": 0,
-                               "emitted": 0}
-        self._step_resident: tuple[float, int] | None = None
+        self._phase_s = dict.fromkeys(_PHASES, 0.0)
+        self._step_counts: dict = {}
         # Prefix sharing + chat sessions (SERVING_r05). ``sessions``
         # maps session key -> retained state (cache id holding the
         # parked pages, the full token history they cover, the owning
@@ -816,75 +842,70 @@ class Engine:
             counts["cow"] = self._cow_fn._cache_size()
         return counts
 
-    def warmup(self) -> dict:
-        """Compile every program against scratch-only page rows and
-        all-dead lanes (zero allocator side effects: every write
-        lands in each group's scratch page). Returns
-        compile_counts()."""
+    def _warmup_calls(self):
+        """``(program, arguments)`` for every program this engine
+        built, against scratch-only page rows and all-dead lanes (zero
+        allocator side effects: every write lands in each group's
+        scratch page). Lazy, because the pools are donated: a call's
+        arguments hold the pools the call before it returned."""
         import jax.numpy as jnp
 
         G, B = self.dp_groups, self.batch_local
         P = self.cache.cfg.pages_per_seq
         C = self.cfg.prefill_chunk
         rng = jnp.zeros((G, 2), jnp.uint32)
+
+        def pools():
+            return (self.params, self.cache.k_pages,
+                    self.cache.v_pages)
+
+        def zeros(*shape, dtype=jnp.int32):
+            return jnp.zeros(shape, dtype)
+
         if self.cfg.resident_k > 1:
             # All-dead burst: zero budgets fail the loop predicate at
             # iteration 0 (the all-slots-complete early exit), but
             # tracing still compiles the full resident body.
-            _o, _n, _s, k, v = self._decode_fn(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                jnp.zeros((G, B, P), jnp.int32),
-                jnp.zeros((G, B, self.cfg.max_seq_len), jnp.int32),
-                jnp.zeros((G, B), jnp.int32),
-                jnp.zeros((G, B), jnp.int32),
-                jnp.zeros((G, B), jnp.bool_))
+            yield self._decode_fn, (
+                *pools(), zeros(G, B, P),
+                zeros(G, B, self.cfg.max_seq_len), zeros(G, B),
+                zeros(G, B), zeros(G, B, dtype=jnp.bool_))
         elif self.cfg.spec_k > 1:
-            _t, k, v = self._decode_fn(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                jnp.zeros((G, B, P), jnp.int32),
-                jnp.zeros((G, B, self.cfg.spec_k), jnp.int32),
-                jnp.zeros((G, B), jnp.int32),
-                jnp.zeros((G, B), jnp.int32),
-                jnp.zeros((G, B), jnp.bool_), rng)
+            yield self._decode_fn, (
+                *pools(), zeros(G, B, P), zeros(G, B, self.cfg.spec_k),
+                zeros(G, B), zeros(G, B),
+                zeros(G, B, dtype=jnp.bool_), rng)
         else:
-            _t, k, v = self._decode_fn(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                jnp.zeros((G, B), jnp.int32),
-                jnp.zeros((G, B), jnp.int32),
-                jnp.zeros((G, B, P), jnp.int32),
-                jnp.zeros((G, B), jnp.bool_), rng)
-        self.cache.update_pools(k, v)
+            yield self._decode_fn, (
+                *pools(), zeros(G, B), zeros(G, B), zeros(G, B, P),
+                zeros(G, B, dtype=jnp.bool_), rng)
         if self.cfg.prefill_mode == "batched":
             Sp = self.prefill_local
-            _t, k, v = self._prefill_batch_fn(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                jnp.zeros((G, Sp, P), jnp.int32),
-                jnp.zeros((G, Sp, C), jnp.int32),
-                jnp.zeros((G, Sp), jnp.int32),
-                jnp.zeros((G, Sp), jnp.int32),
-                jnp.zeros((G, Sp), jnp.bool_), rng)
-            self.cache.update_pools(k, v)
+            yield self._prefill_batch_fn, (
+                *pools(), zeros(G, Sp, P), zeros(G, Sp, C),
+                zeros(G, Sp), zeros(G, Sp),
+                zeros(G, Sp, dtype=jnp.bool_), rng)
         else:
-            ctoks = jnp.zeros((1, C), jnp.int32)
-            row = jnp.zeros((G, P), jnp.int32)
-            live = jnp.zeros((G,), jnp.bool_)
             for fn in (self._prefill_first_fn,
                        self._prefill_cont_fn):
                 # Plain-int scalars, matching the step loop's calls —
                 # a jnp.int32() here would warm a DIFFERENT
                 # (non-weak) jit entry than the one the storm hits.
-                _lg, k, v = fn(self.params, self.cache.k_pages,
-                               self.cache.v_pages, row, live, ctoks,
-                               0, 1)
-                self.cache.update_pools(k, v)
+                yield fn, (*pools(), zeros(G, P),
+                           zeros(G, dtype=jnp.bool_), zeros(1, C),
+                           0, 1)
         if self._sharing:
-            # Scratch-to-scratch identity copies: compiles the COW
-            # program with zero allocator side effects.
+            # Scratch-to-scratch identity copies.
             W = self._cow_width
-            k, v = self._cow_fn(
-                self.cache.k_pages, self.cache.v_pages,
-                jnp.zeros((G, W), jnp.int32),
-                jnp.zeros((G, W), jnp.int32))
+            yield self._cow_fn, (self.cache.k_pages,
+                                 self.cache.v_pages, zeros(G, W),
+                                 zeros(G, W))
+
+    def warmup(self) -> dict:
+        """Compile every program (``_warmup_calls``). Returns
+        compile_counts()."""
+        for fn, args in self._warmup_calls():
+            *_outs, k, v = fn(*args)
             self.cache.update_pools(k, v)
         return self.compile_counts()
 
@@ -904,8 +925,9 @@ class Engine:
                 f"max_seq_len ({self.cfg.max_seq_len})")
 
     def submit(self, req: Request) -> None:
+        req.submitted = time.monotonic()
         if req.arrival is None:
-            req.arrival = time.monotonic()
+            req.arrival = req.submitted
         self._validate(req)
         self.queue.append(req)
 
@@ -919,13 +941,17 @@ class Engine:
     # telemetry/serving_trace.py for the schema the analyzer pins.
 
     def _mark_admitted(self, seq: _Seq, ev: str, **fields) -> None:
-        """Open a sequence's trace: queued at t=0 (arrival), then the
+        """Open a sequence's trace: queued at t=0 (arrival),
+        ``submitted`` where ``submit`` stamped the request (an adopted
+        or staleness-requeued one never passed it), then the
         admission span (``admitted`` / ``resumed`` / ``adopted``).
         ``queue_wait_s`` is fixed here — a resubmitted-after-preempt
         request keeps its ORIGINAL arrival, so its second trace shows
         the full wait including the lost first pass."""
         now = time.monotonic()
         seq.trace.append({"ev": "queued", "t": 0.0})
+        if seq.req.submitted is not None:
+            seq.span("submitted", seq.req.submitted)
         seq.span(ev, now, slot=seq.slot, **fields)
         if seq.req.arrival is not None:
             seq.queue_wait_s = now - seq.req.arrival
@@ -1232,16 +1258,19 @@ class Engine:
         import jax.numpy as jnp
 
         G, W = self.dp_groups, self._cow_width
-        src = np.zeros((G, W), np.int32)
-        dst = np.zeros((G, W), np.int32)
-        fill = [0] * G
-        for g, a, b in pairs:
-            src[g, fill[g]] = a
-            dst[g, fill[g]] = b
-            fill[g] += 1
-        k, v = self._cow_fn(self.cache.k_pages, self.cache.v_pages,
-                            jnp.asarray(src), jnp.asarray(dst))
-        self.cache.update_pools(k, v)
+        with self._phase("pack"):
+            src = np.zeros((G, W), np.int32)
+            dst = np.zeros((G, W), np.int32)
+            fill = [0] * G
+            for g, a, b in pairs:
+                src[g, fill[g]] = a
+                dst[g, fill[g]] = b
+                fill[g] += 1
+        with self._phase("launch"):
+            k, v = self._cow_fn(
+                self.cache.k_pages, self.cache.v_pages,
+                jnp.asarray(src), jnp.asarray(dst))
+            self.cache.update_pools(k, v)
         self.prefix_stats["cow_pages"] += len(pairs)
 
     def _register(self, seq: _Seq) -> None:
@@ -1269,38 +1298,55 @@ class Engine:
         return [s for s in self.slots
                 if s is not None and s.prefill_done and not s.done]
 
+    def _phase(self, key: str) -> phase:
+        """One of the five parts of a step (``_PHASES``): the
+        ``serving.<key>`` trace annotation, its seconds added to the
+        step record's ``phase_s[key]``. The parts never nest, so they
+        sum to at most ``dur_s``."""
+        return phase("serving." + key, self._phase_s, key)
+
     def step(self) -> dict:
         """One scheduling decision + one compiled program launch.
         Returns a record of what ran (``kind``: prefill/decode/idle).
         """
+        with phase("serving.step"):
+            return self._step()
+
+    def _step(self) -> dict:
         t0 = time.monotonic()
-        pending = self._prefill_candidates()
-        can_admit = (not self.draining and self.queue
-                     and self._free_slot() is not None)
-        want_prefill = bool(pending or can_admit)
-        decodable = self._decode_candidates()
-        if self.cfg.policy == "prefill":
-            kind = "prefill" if want_prefill else (
-                "decode" if decodable else "idle")
-        else:
-            kind = "decode" if decodable else (
-                "prefill" if want_prefill else "idle")
-        tokens_out = 0
-        self._step_spec = None
-        self._step_resident = None
-        self._last_prefill_lanes = None
-        self._step_prefix = [0, 0]
-        syncs0 = self.host_syncs
-        if kind == "prefill":
-            if self.cfg.prefill_mode == "batched":
+        self._phase_s = dict.fromkeys(_PHASES, 0.0)
+        with self._phase("admit"):
+            tokens_out = 0
+            self._step_counts = {}
+            self._last_prefill_lanes = None
+            self._step_prefix = [0, 0]
+            syncs0 = self.host_syncs
+            batched = self.cfg.prefill_mode == "batched"
+            seq = None
+            pending = self._prefill_candidates()
+            can_admit = (not self.draining and self.queue
+                         and self._free_slot() is not None)
+            want_prefill = bool(pending or can_admit)
+            decodable = self._decode_candidates()
+            if self.cfg.policy == "prefill":
+                kind = "prefill" if want_prefill else (
+                    "decode" if decodable else "idle")
+            else:
+                kind = "decode" if decodable else (
+                    "prefill" if want_prefill else "idle")
+            if kind == "prefill" and batched:
                 # Admit everything slots+pages allow BEFORE the
                 # launch — one admission per step would starve the
                 # lane table the batched program pays for.
                 while not self.draining and self.queue \
                         and self._admit() is not None:
                     pass
-                tokens_out = self._run_prefill_batch(
-                    self._prefill_candidates())
+                pending = self._prefill_candidates()
+            elif kind == "prefill":
+                seq = pending[0] if pending else self._admit()
+        if kind == "prefill":
+            if batched:
+                tokens_out = self._run_prefill_batch(pending)
                 if tokens_out == 0:
                     # Backpressure (every pending chunk stalled on
                     # pages — the r02 livelock fallback) OR every
@@ -1311,7 +1357,6 @@ class Engine:
                     decodable = self._decode_candidates()
                     kind = "decode" if decodable else "idle"
             else:
-                seq = pending[0] if pending else self._admit()
                 if seq is not None and seq.prefill_done:
                     # Zero-prefill admission (full prefix hit /
                     # exact session resume): nothing to prefill —
@@ -1336,19 +1381,21 @@ class Engine:
         # "tokens" counts NEW tokens for decode steps and PROMPT
         # tokens processed for (batched) prefill steps — the metrics
         # observer splits them into the decode/prefill tok/s gauges
-        # by "op".
+        # by "op". ``phase_s`` splits ``dur_s`` into the five
+        # ``serving.*`` parts; what is left of it is the scheduling
+        # glue between them. A launching step also carries what its
+        # ``_run_*`` path left in ``_step_counts``: ``slots_stepped``
+        # and ``slot_iters`` (decode), with ``spec_k`` and
+        # ``spec_accepted_mean`` or ``resident_k`` and
+        # ``resident_steps_per_launch`` by cadence; ``first_tokens``
+        # (prefill).
         rec = {"op": kind, "dur_s": dur, "tokens": tokens_out,
+               "phase_s": {k: round(v, 6)
+                           for k, v in self._phase_s.items()},
                "in_flight": self.in_flight,
                "queue_depth": len(self.queue),
+               **self._step_counts,
                **self.cache.occupancy()}
-        if self._step_spec is not None:
-            launches, emitted = self._step_spec
-            rec["spec_k"] = self.cfg.spec_k
-            rec["spec_accepted_mean"] = round(emitted / launches, 4)
-        if self._step_resident is not None:
-            mean_steps, _slots = self._step_resident
-            rec["resident_k"] = self.cfg.resident_k
-            rec["resident_steps_per_launch"] = mean_steps
         if self._sharing:
             # Additive sharing fields (schema pinned by test): the
             # metrics observer accumulates the per-step deltas into
@@ -1411,7 +1458,8 @@ class Engine:
         rule DTT010 can flag any round-trip that creeps in anywhere
         else. One call = one sync, however many arrays ride it."""
         self.host_syncs += 1
-        return tuple(np.asarray(a) for a in arrays)
+        with self._phase("fetch"):
+            return tuple(np.asarray(a) for a in arrays)
 
     def _group_row(self, seq_id) -> tuple[np.ndarray, np.ndarray, int]:
         """(G, P) page rows + (G,) live mask for a single sequence:
@@ -1431,61 +1479,71 @@ class Engine:
         up)."""
         import jax.numpy as jnp
 
-        c = self.cfg
-        start = seq.prefilled
-        n_valid = min(c.prefill_chunk, seq.prompt_len - start)
-        if not self.cache.ensure(seq.req.id, start + n_valid):
-            return False
-        if self._sharing:
-            pairs = self._cow_guard(seq.req.id)
+        with self._phase("pack"):
+            c = self.cfg
+            start = seq.prefilled
+            n_valid = min(c.prefill_chunk, seq.prompt_len - start)
+            if not self.cache.ensure(seq.req.id, start + n_valid):
+                return False
+            pairs = self._cow_guard(seq.req.id) if self._sharing \
+                else []
             if pairs is None:
                 return False  # fork stalled on pages — backpressure
-            if pairs:
-                g = self.cache.group_of(seq.req.id)
-                self._apply_cow([(g, a, b) for a, b in pairs])
-        chunk = np.zeros((1, c.prefill_chunk), np.int32)
-        chunk[0, :n_valid] = seq.req.prompt[start:start + n_valid]
-        rows, live, g = self._group_row(seq.req.id)
-        fn = (self._prefill_first_fn if start == 0
-              else self._prefill_cont_fn)
-        # start/n_valid ride as weak-typed scalars: same jit cache
-        # entry for every value, no explicit device_put dispatches.
-        logits, k, v = fn(self.params, self.cache.k_pages,
-                          self.cache.v_pages, jnp.asarray(rows),
-                          jnp.asarray(live), jnp.asarray(chunk),
-                          start, n_valid)
-        self.cache.update_pools(k, v)
-        self.cache.advance(seq.req.id, n_valid)
-        seq.prefilled = start + n_valid
-        self.prefill_tokens_computed += n_valid
-        self.prefill_launches += 1
-        if seq.prefill_done:
+        if pairs:
+            g = self.cache.group_of(seq.req.id)
+            self._apply_cow([(g, a, b) for a, b in pairs])
+        with self._phase("pack"):
+            chunk = np.zeros((1, c.prefill_chunk), np.int32)
+            chunk[0, :n_valid] = seq.req.prompt[start:start + n_valid]
+            rows, live, g = self._group_row(seq.req.id)
+            fn = (self._prefill_first_fn if start == 0
+                  else self._prefill_cont_fn)
+        with self._phase("launch"):
+            # start/n_valid ride as weak-typed scalars: same jit cache
+            # entry for every value, no explicit device_put dispatches.
+            logits, k, v = fn(self.params, self.cache.k_pages,
+                              self.cache.v_pages, jnp.asarray(rows),
+                              jnp.asarray(live), jnp.asarray(chunk),
+                              start, n_valid)
+            self.cache.update_pools(k, v)
+            done = start + n_valid >= seq.prompt_len
+        if done:
             # Slice ON DEVICE before the pull: one (V,) transfer per
             # completed prompt instead of the whole (G, V) block —
             # the r02 dispatch-diet leftover (completion cost must
             # not scale with vocab x dp). The batched prefill path
             # goes further and never moves logits at all (in-program
             # sampling).
-            (lg,) = self._fetch_host(logits[g])
-            tok = self._sample_host(lg)
-            now = time.monotonic()
-            seq.span("prefill", now, tokens=n_valid)
-            seq.first_token_t = now
-            seq.token_times.append(now)
-            seq.generated.append(tok)
-            if self.cfg.eos_id >= 0 and tok == self.cfg.eos_id:
-                seq.eos = True
-            self._emit_token(seq, tok)
+            with self._phase("launch"):
+                row = logits[g]
+            (lg,) = self._fetch_host(row)
+        with self._phase("emit"):
+            self.cache.advance(seq.req.id, n_valid)
+            seq.prefilled = start + n_valid
+            self.prefill_tokens_computed += n_valid
+            self.prefill_launches += 1
+            self._step_counts["first_tokens"] = int(done)
+            if done:
+                tok = self._sample_host(lg)
+                now = time.monotonic()
+                seq.span("prefill", now, tokens=n_valid)
+                seq.first_token_t = now
+                seq.token_times.append(now)
+                seq.generated.append(tok)
+                if self.cfg.eos_id >= 0 and tok == self.cfg.eos_id:
+                    seq.eos = True
+                self._emit_token(seq, tok)
+                self._register(seq)
+                self._maybe_finish(seq)
+                return True
+            # Mid-prompt chunk: no fetch happens, so the span
+            # timestamp is the post-dispatch host clock (launch
+            # enqueue time under async dispatch — the token counts are
+            # the load-bearing fields; the sync-accurate timestamps
+            # are the fetched ones).
+            seq.span("prefill", time.monotonic(), tokens=n_valid)
             self._register(seq)
-            self._maybe_finish(seq)
             return True
-        # Mid-prompt chunk: no fetch happens, so the span timestamp
-        # is the post-dispatch host clock (launch enqueue time under
-        # async dispatch — the token counts are the load-bearing
-        # fields; the sync-accurate timestamps are the fetched ones).
-        seq.span("prefill", time.monotonic(), tokens=n_valid)
-        self._register(seq)
-        return True
 
     def _sample_host(self, logits) -> int:
         """Sample the prefill's first token on host — one token per
@@ -1534,86 +1592,93 @@ class Engine:
         so pages free up)."""
         import jax.numpy as jnp
 
-        c = self.cfg
-        G, Sp, C = self.dp_groups, self.prefill_local, c.prefill_chunk
-        chosen: list[list[_Seq]] = [[] for _ in range(G)]
-        cow: list = []
-        for s in pending:
-            g = self.cache.group_of(s.req.id)
-            if len(chosen[g]) >= Sp:
-                continue
-            n = min(C, s.prompt_len - s.prefilled)
-            if not self.cache.ensure(s.req.id, s.prefilled + n):
-                continue  # this lane stalls; others still launch
-            if self._sharing:
-                pairs = self._cow_guard(s.req.id)
-                if pairs is None:
-                    continue  # lane stalls on fork pages
-                cow += [(g, a, b) for a, b in pairs]
-            chosen[g].append(s)
+        with self._phase("pack"):
+            c = self.cfg
+            G, Sp, C = (self.dp_groups, self.prefill_local,
+                        c.prefill_chunk)
+            chosen: list[list[_Seq]] = [[] for _ in range(G)]
+            cow: list = []
+            for s in pending:
+                g = self.cache.group_of(s.req.id)
+                if len(chosen[g]) >= Sp:
+                    continue
+                n = min(C, s.prompt_len - s.prefilled)
+                if not self.cache.ensure(s.req.id, s.prefilled + n):
+                    continue  # this lane stalls; others still launch
+                if self._sharing:
+                    pairs = self._cow_guard(s.req.id)
+                    if pairs is None:
+                        continue  # lane stalls on fork pages
+                    cow += [(g, a, b) for a, b in pairs]
+                chosen[g].append(s)
         if not any(chosen):
             return 0
         if cow:
             self._apply_cow(cow)
-        tokens = np.zeros((G, Sp, C), np.int32)
-        start_pos = np.zeros((G, Sp), np.int32)
-        n_valid = np.zeros((G, Sp), np.int32)
-        active = np.zeros((G, Sp), bool)
-        for g, seqs in enumerate(chosen):
-            for i, s in enumerate(seqs):
-                start = s.prefilled
-                n = min(C, s.prompt_len - start)
-                tokens[g, i, :n] = s.req.prompt[start:start + n]
-                start_pos[g, i] = start
-                n_valid[g, i] = n
-                active[g, i] = True
-        rows = self.cache.page_rows_grouped(
-            [[s.req.id for s in seqs] for seqs in chosen], width=Sp)
-        nxt, k, v = self._prefill_batch_fn(
-            self.params, self.cache.k_pages, self.cache.v_pages,
-            jnp.asarray(rows), jnp.asarray(tokens),
-            jnp.asarray(start_pos), jnp.asarray(n_valid),
-            jnp.asarray(active),
-            self._rng_grouped(1_000_000 + self._step_counter))
-        self.cache.update_pools(k, v)
-        self._last_prefill_lanes = [len(seqs) for seqs in chosen]
-        self.prefill_launches += 1
-        total = 0
-        fetched = None
-        now = None
-        t_launch = time.monotonic()  # dispatch-time stamp for lanes
-        for g, seqs in enumerate(chosen):  # that trigger no fetch
-            for i, s in enumerate(seqs):
-                n = int(n_valid[g, i])
-                self.cache.advance(s.req.id, n)
-                s.prefilled += n
-                total += n
-                if not s.prefill_done:
-                    s.span("prefill", t_launch, tokens=n)
-                if s.prefill_done:
-                    if fetched is None:
-                        # ONE (G, Sp) int32 pull for the whole
-                        # launch, and only when some prompt
-                        # completed — never a logits block. The
-                        # timestamp is taken AFTER this blocking
-                        # fetch: under async dispatch an earlier
-                        # clock read would exclude the launch's own
-                        # compute from TTFT.
-                        (fetched,) = self._fetch_host(nxt)
-                        now = time.monotonic()
-                    tok = int(fetched[g, i])
-                    s.span("prefill", now, tokens=n)
-                    s.first_token_t = now
-                    s.token_times.append(now)
-                    s.generated.append(tok)
-                    if self.cfg.eos_id >= 0 and \
-                            tok == self.cfg.eos_id:
-                        s.eos = True
-                    self._emit_token(s, tok)
-                self._register(s)
-                if s.prefill_done:
-                    self._maybe_finish(s)
-        self.prefill_tokens_computed += total
+        with self._phase("pack"):
+            tokens = np.zeros((G, Sp, C), np.int32)
+            start_pos = np.zeros((G, Sp), np.int32)
+            n_valid = np.zeros((G, Sp), np.int32)
+            active = np.zeros((G, Sp), bool)
+            completing = False
+            for g, seqs in enumerate(chosen):
+                for i, s in enumerate(seqs):
+                    start = s.prefilled
+                    n = min(C, s.prompt_len - start)
+                    tokens[g, i, :n] = s.req.prompt[start:start + n]
+                    start_pos[g, i] = start
+                    n_valid[g, i] = n
+                    active[g, i] = True
+                    completing |= start + n >= s.prompt_len
+            rows = self.cache.page_rows_grouped(
+                [[s.req.id for s in seqs] for seqs in chosen],
+                width=Sp)
+            rng = self._rng_grouped(1_000_000 + self._step_counter)
+        with self._phase("launch"):
+            nxt, k, v = self._prefill_batch_fn(
+                self.params, self.cache.k_pages, self.cache.v_pages,
+                jnp.asarray(rows), jnp.asarray(tokens),
+                jnp.asarray(start_pos), jnp.asarray(n_valid),
+                jnp.asarray(active), rng)
+            self.cache.update_pools(k, v)
+            self._last_prefill_lanes = [len(seqs) for seqs in chosen]
+            self.prefill_launches += 1
+            t_launch = time.monotonic()  # dispatch-time stamp for
+        fetched = now = None  # lanes that complete no prompt
+        if completing:
+            # ONE (G, Sp) int32 pull for the whole launch, and only
+            # when some prompt completed — never a logits block. The
+            # timestamp is taken AFTER this blocking fetch: under
+            # async dispatch an earlier clock read would exclude the
+            # launch's own compute from TTFT.
+            (fetched,) = self._fetch_host(nxt)
+            now = time.monotonic()
+        with self._phase("emit"):
+            total = first_tokens = 0
+            for g, seqs in enumerate(chosen):
+                for i, s in enumerate(seqs):
+                    n = int(n_valid[g, i])
+                    self.cache.advance(s.req.id, n)
+                    s.prefilled += n
+                    total += n
+                    if not s.prefill_done:
+                        s.span("prefill", t_launch, tokens=n)
+                    else:
+                        tok = int(fetched[g, i])
+                        s.span("prefill", now, tokens=n)
+                        s.first_token_t = now
+                        s.token_times.append(now)
+                        s.generated.append(tok)
+                        if self.cfg.eos_id >= 0 and \
+                                tok == self.cfg.eos_id:
+                            s.eos = True
+                        self._emit_token(s, tok)
+                        first_tokens += 1
+                    self._register(s)
+                    if s.prefill_done:
+                        self._maybe_finish(s)
+            self.prefill_tokens_computed += total
+            self._step_counts["first_tokens"] = first_tokens
         return total
 
     def _draft(self, seq: _Seq, m: int) -> np.ndarray:
@@ -1647,91 +1712,100 @@ class Engine:
         is overwritten by the next launch's writes."""
         import jax.numpy as jnp
 
-        G, B = self.dp_groups, self.batch_local
-        K = self.cfg.spec_k
-        tokens = np.zeros((G, B, K), np.int32)
-        start_pos = np.zeros((G, B), np.int32)
-        n_valid = np.zeros((G, B), np.int32)
-        active = np.zeros((G, B), bool)
-        seq_ids: list[list] = [[None] * B for _ in range(G)]
-        stepped: list[tuple[_Seq, int, np.ndarray]] = []
-        cow: list = []
-        for s in decodable:
-            length = self.cache.length(s.req.id)
-            remaining = s.req.max_new_tokens - len(s.generated)
-            # Clamp the chain to what the sequence can still hold —
-            # positions past max_seq_len or past the request's budget
-            # ride as masked padding (n_valid), never as writes.
-            n = min(K, remaining, self.cfg.max_seq_len - length)
-            if not self.cache.ensure(s.req.id, length + n):
-                # Pages for the full chain are short: fall back to a
-                # one-token launch in the SAME program before
-                # stalling outright.
-                if n == 1 or not self.cache.ensure(s.req.id,
-                                                   length + 1):
-                    continue
-                n = 1
-            g, i = divmod(s.slot, B)
-            if self._sharing:
-                pairs = self._cow_guard(s.req.id)
-                if pairs is None:
-                    continue  # fork stalled on pages; retry next step
-                cow += [(g, a, b) for a, b in pairs]
-            draft = self._draft(s, n - 1)
-            tokens[g, i, 0] = s.last_token
-            if n > 1:
-                tokens[g, i, 1:n] = draft
-            start_pos[g, i] = length
-            n_valid[g, i] = n
-            active[g, i] = True
-            seq_ids[g][i] = s.req.id
-            stepped.append((s, n, draft))
+        with self._phase("pack"):
+            G, B = self.dp_groups, self.batch_local
+            K = self.cfg.spec_k
+            tokens = np.zeros((G, B, K), np.int32)
+            start_pos = np.zeros((G, B), np.int32)
+            n_valid = np.zeros((G, B), np.int32)
+            active = np.zeros((G, B), bool)
+            seq_ids: list[list] = [[None] * B for _ in range(G)]
+            stepped: list[tuple[_Seq, int, np.ndarray]] = []
+            cow: list = []
+            for s in decodable:
+                length = self.cache.length(s.req.id)
+                remaining = s.req.max_new_tokens - len(s.generated)
+                # Clamp the chain to what the sequence can still hold
+                # — positions past max_seq_len or past the request's
+                # budget ride as masked padding (n_valid), never as
+                # writes.
+                n = min(K, remaining, self.cfg.max_seq_len - length)
+                if not self.cache.ensure(s.req.id, length + n):
+                    # Pages for the full chain are short: fall back
+                    # to a one-token launch in the SAME program
+                    # before stalling outright.
+                    if n == 1 or not self.cache.ensure(s.req.id,
+                                                       length + 1):
+                        continue
+                    n = 1
+                g, i = divmod(s.slot, B)
+                if self._sharing:
+                    pairs = self._cow_guard(s.req.id)
+                    if pairs is None:
+                        continue  # fork stalled on pages; retry next
+                    cow += [(g, a, b) for a, b in pairs]
+                draft = self._draft(s, n - 1)
+                tokens[g, i, 0] = s.last_token
+                if n > 1:
+                    tokens[g, i, 1:n] = draft
+                start_pos[g, i] = length
+                n_valid[g, i] = n
+                active[g, i] = True
+                seq_ids[g][i] = s.req.id
+                stepped.append((s, n, draft))
         if not stepped:
             return 0
         if cow:
             self._apply_cow(cow)
-        rows = self.cache.page_rows_grouped(seq_ids)
-        out, k, v = self._decode_fn(
-            self.params, self.cache.k_pages, self.cache.v_pages,
-            jnp.asarray(rows), jnp.asarray(tokens),
-            jnp.asarray(start_pos), jnp.asarray(n_valid),
-            jnp.asarray(active), self._zero_rng)
-        self.cache.update_pools(k, v)
+        with self._phase("pack"):
+            rows = self.cache.page_rows_grouped(seq_ids)
+        with self._phase("launch"):
+            out, k, v = self._decode_fn(
+                self.params, self.cache.k_pages, self.cache.v_pages,
+                jnp.asarray(rows), jnp.asarray(tokens),
+                jnp.asarray(start_pos), jnp.asarray(n_valid),
+                jnp.asarray(active), self._zero_rng)
+            self.cache.update_pools(k, v)
         (out,) = self._fetch_host(out)
         now = time.monotonic()
-        total = 0
-        for s, n, draft in stepped:
-            g, i = divmod(s.slot, B)
-            # out[g, i, j] is the verified argmax AFTER position j.
-            # Accept draft j while it equals the chain's previous
-            # token; every accepted position's argmax is then
-            # conditioned on true tokens only.
-            emit = [int(out[g, i, 0])]
-            j = 1
-            while j < n and int(draft[j - 1]) == emit[-1]:
-                emit.append(int(out[g, i, j]))
-                j += 1
-            if self.cfg.eos_id >= 0 and self.cfg.eos_id in emit:
-                # Stop at the stop token: later accepted positions
-                # are conditioned on a sequence that already ended.
-                emit = emit[:emit.index(self.cfg.eos_id) + 1]
-            self.cache.advance(s.req.id, len(emit))
-            self.spec_stats["launches"] += 1
-            self.spec_stats["emitted"] += len(emit)
-            s.span("decode", now, emitted=len(emit), budget=n)
-            for tok in emit:
-                s.generated.append(tok)
-                if self.cfg.eos_id >= 0 and \
-                        tok == self.cfg.eos_id:
-                    s.eos = True
-                if s.first_token_t is None:
-                    s.first_token_t = now
-                s.token_times.append(now)
-                self._emit_token(s, tok)
-            total += len(emit)
-            self._register(s)
-            self._maybe_finish(s)
-        self._step_spec = (len(stepped), total)
+        with self._phase("emit"):
+            total = 0
+            for s, n, draft in stepped:
+                g, i = divmod(s.slot, B)
+                # out[g, i, j] is the verified argmax AFTER position
+                # j. Accept draft j while it equals the chain's
+                # previous token; every accepted position's argmax is
+                # then conditioned on true tokens only.
+                emit = [int(out[g, i, 0])]
+                j = 1
+                while j < n and int(draft[j - 1]) == emit[-1]:
+                    emit.append(int(out[g, i, j]))
+                    j += 1
+                if self.cfg.eos_id >= 0 and self.cfg.eos_id in emit:
+                    # Stop at the stop token: later accepted
+                    # positions are conditioned on a sequence that
+                    # already ended.
+                    emit = emit[:emit.index(self.cfg.eos_id) + 1]
+                self.cache.advance(s.req.id, len(emit))
+                s.span("decode", now, emitted=len(emit), budget=n)
+                for tok in emit:
+                    s.generated.append(tok)
+                    if self.cfg.eos_id >= 0 and \
+                            tok == self.cfg.eos_id:
+                        s.eos = True
+                    if s.first_token_t is None:
+                        s.first_token_t = now
+                    s.token_times.append(now)
+                    self._emit_token(s, tok)
+                total += len(emit)
+                self._register(s)
+                self._maybe_finish(s)
+            # One verification chunk a stepped slot: ``slot_iters``
+            # is exact.
+            self._step_counts.update(
+                slots_stepped=len(stepped), slot_iters=len(stepped),
+                spec_k=K,
+                spec_accepted_mean=round(total / len(stepped), 4))
         return total
 
     def _run_decode_resident(self, decodable: list[_Seq]) -> int:
@@ -1746,89 +1820,105 @@ class Engine:
         the host spec path would (the same ``_chunk_hidden`` math),
         so K only moves the sync cadence, never tokens. A burst is
         atomic host-side — the cache advances only after the fetch —
-        so a preemption between bursts resubmits cleanly."""
+        so a preemption between bursts resubmits cleanly.
+
+        The step record's ``slot_iters`` is the sum over the stepped
+        slots of the loop iterations each was live in. The program
+        returns tokens a slot and iterations a group, not iterations
+        a slot, so it is ``min(tokens, group iterations)``: exact at
+        ``spec_k == 1``, where a live iteration emits exactly one
+        token, and an upper bound at ``spec_k > 1`` for a slot that
+        stopped before its group did (tokens over ``slot_iters`` then
+        reads low, never high)."""
         import jax.numpy as jnp
 
-        G, B = self.dp_groups, self.batch_local
-        T = self.cfg.resident_k * self.cfg.spec_k
-        L = self.cfg.max_seq_len
-        history = np.zeros((G, B, L), np.int32)
-        kv_len = np.zeros((G, B), np.int32)
-        budget = np.zeros((G, B), np.int32)
-        active = np.zeros((G, B), bool)
-        seq_ids: list[list] = [[None] * B for _ in range(G)]
-        stepped: list[_Seq] = []
-        cow: list = []
-        for s in decodable:
-            length = self.cache.length(s.req.id)
-            remaining = s.req.max_new_tokens - len(s.generated)
-            # The burst budget is clamped to the pages the slot
-            # could actually claim RIGHT NOW (its allocated pages +
-            # its group's free list): a tight pool degrades the
-            # burst toward one token — the all-slots-stall
-            # fallback — instead of stalling the slot outright.
-            cap = self.cache.token_capacity(s.req.id)
-            want = min(remaining, T, cap - length)
-            if want < 1:
-                continue  # zero headroom: wait for frees
-            if not self.cache.ensure(s.req.id, length + want):
-                continue
-            g, i = divmod(s.slot, B)
-            if self._sharing:
-                pairs = self._cow_guard(s.req.id)
-                if pairs is None:
-                    continue  # fork stalled on pages; retry next step
-                cow += [(g, a, b) for a, b in pairs]
-            hist = np.concatenate([
-                np.array(s.req.prompt, np.int32),
-                np.array(s.generated, np.int32)])
-            history[g, i, :hist.shape[0]] = hist
-            kv_len[g, i] = length
-            budget[g, i] = want
-            active[g, i] = True
-            seq_ids[g][i] = s.req.id
-            stepped.append(s)
+        with self._phase("pack"):
+            G, B = self.dp_groups, self.batch_local
+            T = self.cfg.resident_k * self.cfg.spec_k
+            L = self.cfg.max_seq_len
+            history = np.zeros((G, B, L), np.int32)
+            kv_len = np.zeros((G, B), np.int32)
+            budget = np.zeros((G, B), np.int32)
+            active = np.zeros((G, B), bool)
+            seq_ids: list[list] = [[None] * B for _ in range(G)]
+            stepped: list[_Seq] = []
+            cow: list = []
+            for s in decodable:
+                length = self.cache.length(s.req.id)
+                remaining = s.req.max_new_tokens - len(s.generated)
+                # The burst budget is clamped to the pages the slot
+                # could actually claim RIGHT NOW (its allocated pages
+                # + its group's free list): a tight pool degrades the
+                # burst toward one token — the all-slots-stall
+                # fallback — instead of stalling the slot outright.
+                cap = self.cache.token_capacity(s.req.id)
+                want = min(remaining, T, cap - length)
+                if want < 1:
+                    continue  # zero headroom: wait for frees
+                if not self.cache.ensure(s.req.id, length + want):
+                    continue
+                g, i = divmod(s.slot, B)
+                if self._sharing:
+                    pairs = self._cow_guard(s.req.id)
+                    if pairs is None:
+                        continue  # fork stalled on pages; retry next
+                    cow += [(g, a, b) for a, b in pairs]
+                hist = np.concatenate([
+                    np.array(s.req.prompt, np.int32),
+                    np.array(s.generated, np.int32)])
+                history[g, i, :hist.shape[0]] = hist
+                kv_len[g, i] = length
+                budget[g, i] = want
+                active[g, i] = True
+                seq_ids[g][i] = s.req.id
+                stepped.append(s)
         if not stepped:
             return 0
         if cow:
             self._apply_cow(cow)
-        rows = self.cache.page_rows_grouped(seq_ids)
-        out, n_emitted, steps, k, v = self._decode_fn(
-            self.params, self.cache.k_pages, self.cache.v_pages,
-            jnp.asarray(rows), jnp.asarray(history),
-            jnp.asarray(kv_len), jnp.asarray(budget),
-            jnp.asarray(active))
-        self.cache.update_pools(k, v)
+        with self._phase("pack"):
+            rows = self.cache.page_rows_grouped(seq_ids)
+        with self._phase("launch"):
+            out, n_emitted, steps, k, v = self._decode_fn(
+                self.params, self.cache.k_pages, self.cache.v_pages,
+                jnp.asarray(rows), jnp.asarray(history),
+                jnp.asarray(kv_len), jnp.asarray(budget),
+                jnp.asarray(active))
+            self.cache.update_pools(k, v)
         out, n_emitted, steps = self._fetch_host(
             out, n_emitted, steps)
         now = time.monotonic()
-        total = 0
-        for s in stepped:
-            g, i = divmod(s.slot, B)
-            e = int(n_emitted[g, i])
-            self.cache.advance(s.req.id, e)
-            s.span("decode", now, emitted=e,
-                   budget=int(budget[g, i]))
-            for t in range(e):
-                tok = int(out[g, i, t])
-                s.generated.append(tok)
-                if self.cfg.eos_id >= 0 and \
-                        tok == self.cfg.eos_id:
-                    s.eos = True
-                if s.first_token_t is None:
-                    s.first_token_t = now
-                s.token_times.append(now)
-                self._emit_token(s, tok)
-            total += e
-            self._register(s)
-            self._maybe_finish(s)
-        g_steps = [int(steps[g]) for g in range(G)
-                   if active[g].any()]
-        mean_steps = sum(g_steps) / max(1, len(g_steps))
-        self.resident_stats["launches"] += 1
-        self.resident_stats["steps"] += max(g_steps, default=0)
-        self.resident_stats["emitted"] += total
-        self._step_resident = (round(mean_steps, 4), len(stepped))
+        with self._phase("emit"):
+            total = slot_iters = 0
+            for s in stepped:
+                g, i = divmod(s.slot, B)
+                e = int(n_emitted[g, i])
+                self.cache.advance(s.req.id, e)
+                s.span("decode", now, emitted=e,
+                       budget=int(budget[g, i]))
+                for t in range(e):
+                    tok = int(out[g, i, t])
+                    s.generated.append(tok)
+                    if self.cfg.eos_id >= 0 and \
+                            tok == self.cfg.eos_id:
+                        s.eos = True
+                    if s.first_token_t is None:
+                        s.first_token_t = now
+                    s.token_times.append(now)
+                    self._emit_token(s, tok)
+                total += e
+                # A live iteration emits at least one token, and a
+                # slot is live in at most its group's iterations.
+                slot_iters += min(e, int(steps[g]))
+                self._register(s)
+                self._maybe_finish(s)
+            g_steps = [int(steps[g]) for g in range(G)
+                       if active[g].any()]
+            mean_steps = sum(g_steps) / max(1, len(g_steps))
+            self._step_counts.update(
+                slots_stepped=len(stepped), slot_iters=slot_iters,
+                resident_k=self.cfg.resident_k,
+                resident_steps_per_launch=round(mean_steps, 4))
         return total
 
     def _run_decode(self, decodable: list[_Seq]) -> int:
@@ -1838,59 +1928,65 @@ class Engine:
             return self._run_decode_resident(decodable)
         if self.cfg.spec_k > 1:
             return self._run_decode_spec(decodable)
-        G, B = self.dp_groups, self.batch_local
-        tokens = np.zeros((G, B), np.int32)
-        positions = np.zeros((G, B), np.int32)
-        active = np.zeros((G, B), bool)
-        seq_ids: list[list] = [[None] * B for _ in range(G)]
-        stepped: list[_Seq] = []
-        cow: list = []
-        for s in decodable:
-            # The new token's KV lands at position length(seq); make
-            # sure a page covers it. Failure = that group's pool
-            # shard is exhausted: the slot stalls this step and
-            # resumes when pages free.
-            if not self.cache.ensure(s.req.id,
-                                     self.cache.length(s.req.id) + 1):
-                continue
-            g, i = divmod(s.slot, B)
-            if self._sharing:
-                pairs = self._cow_guard(s.req.id)
-                if pairs is None:
-                    continue  # fork stalled on pages; retry next step
-                cow += [(g, a, b) for a, b in pairs]
-            tokens[g, i] = s.last_token
-            positions[g, i] = self.cache.length(s.req.id)
-            active[g, i] = True
-            seq_ids[g][i] = s.req.id
-            stepped.append(s)
+        with self._phase("pack"):
+            G, B = self.dp_groups, self.batch_local
+            tokens = np.zeros((G, B), np.int32)
+            positions = np.zeros((G, B), np.int32)
+            active = np.zeros((G, B), bool)
+            seq_ids: list[list] = [[None] * B for _ in range(G)]
+            stepped: list[_Seq] = []
+            cow: list = []
+            for s in decodable:
+                # The new token's KV lands at position length(seq);
+                # make sure a page covers it. Failure = that group's
+                # pool shard is exhausted: the slot stalls this step
+                # and resumes when pages free.
+                if not self.cache.ensure(
+                        s.req.id, self.cache.length(s.req.id) + 1):
+                    continue
+                g, i = divmod(s.slot, B)
+                if self._sharing:
+                    pairs = self._cow_guard(s.req.id)
+                    if pairs is None:
+                        continue  # fork stalled on pages; retry next
+                    cow += [(g, a, b) for a, b in pairs]
+                tokens[g, i] = s.last_token
+                positions[g, i] = self.cache.length(s.req.id)
+                active[g, i] = True
+                seq_ids[g][i] = s.req.id
+                stepped.append(s)
         if not stepped:
             return 0
         if cow:
             self._apply_cow(cow)
-        rows = self.cache.page_rows_grouped(seq_ids)
-        rng = self._rng_grouped(self._step_counter)
-        nxt, k, v = self._decode_fn(
-            self.params, self.cache.k_pages, self.cache.v_pages,
-            jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(rows), jnp.asarray(active), rng)
-        self.cache.update_pools(k, v)
+        with self._phase("pack"):
+            rows = self.cache.page_rows_grouped(seq_ids)
+            rng = self._rng_grouped(self._step_counter)
+        with self._phase("launch"):
+            nxt, k, v = self._decode_fn(
+                self.params, self.cache.k_pages, self.cache.v_pages,
+                jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(rows), jnp.asarray(active), rng)
+            self.cache.update_pools(k, v)
         (nxt,) = self._fetch_host(nxt)
         now = time.monotonic()
-        for s in stepped:
-            g, i = divmod(s.slot, B)
-            self.cache.advance(s.req.id, 1)
-            s.span("decode", now, emitted=1)
-            tok = int(nxt[g, i])
-            s.generated.append(tok)
-            if self.cfg.eos_id >= 0 and tok == self.cfg.eos_id:
-                s.eos = True
-            if s.first_token_t is None:
-                s.first_token_t = now
-            s.token_times.append(now)
-            self._emit_token(s, tok)
-            self._register(s)
-            self._maybe_finish(s)
+        with self._phase("emit"):
+            for s in stepped:
+                g, i = divmod(s.slot, B)
+                self.cache.advance(s.req.id, 1)
+                s.span("decode", now, emitted=1)
+                tok = int(nxt[g, i])
+                s.generated.append(tok)
+                if self.cfg.eos_id >= 0 and tok == self.cfg.eos_id:
+                    s.eos = True
+                if s.first_token_t is None:
+                    s.first_token_t = now
+                s.token_times.append(now)
+                self._emit_token(s, tok)
+                self._register(s)
+                self._maybe_finish(s)
+            self._step_counts.update(slots_stepped=len(stepped),
+                                     slot_iters=len(stepped))
         return len(stepped)
 
     def _maybe_finish(self, seq: _Seq) -> None:

@@ -50,6 +50,8 @@ import time
 
 import numpy as np
 
+from distributed_training_tpu.telemetry import phase
+
 logger = logging.getLogger(__name__)
 
 
@@ -77,6 +79,7 @@ def debug_requests_snapshot(engine) -> dict:
                 "pages_held":
                     engine.cache.pages_of(s.req.id),
                 "session": s.req.session,
+                "spans": list(s.trace),
                 "weights_versions": [list(p) for p in s.versions]})
         except KeyError:
             continue  # freed between reads
@@ -291,51 +294,63 @@ class ServingServer:
                 done.set()
 
     def _engine_loop_inner(self, eng, Request) -> None:
+        # The loop's parts are ``serving.*`` trace annotations (no
+        # records: an iteration may be 2 ms of sleep), so that a
+        # device idle gap outside ``Engine.step`` has a name too.
         while not self._stop.is_set():
-            self._run_control(eng)
-            with self._lock:
-                incoming, self._mailbox = self._mailbox, []
-            for rid, prompt, n, arrival, session, tenant \
-                    in incoming:
-                with self._lock:
-                    stream_q = self._streams.get(rid)
-                if stream_q is not None:
-                    # Registered BEFORE submit, on the engine thread:
-                    # the first token cannot race its listener.
-                    eng.add_token_listener(
-                        rid,
-                        lambda tok, done, _q=stream_q:
-                            _q.put(("token", tok)))
-                try:
-                    eng.submit(Request(id=rid, prompt=prompt,
-                                       max_new_tokens=n,
-                                       arrival=arrival,
-                                       session=session,
-                                       tenant=tenant))
-                except ValueError as e:
-                    # An invalid request answers ITS caller; it must
-                    # never take down the engine thread (and with it
-                    # every other in-flight request).
-                    eng.remove_token_listener(rid)
-                    with self._lock:
-                        ev = self._events.pop(rid, None)
-                        if ev is not None:
-                            self._done[rid] = {"id": rid,
-                                               "error": str(e)}
-                            ev.set()
-                        sq = self._streams.pop(rid, None)
-                    if sq is not None:
-                        sq.put(("done", {"id": rid,
-                                         "error": str(e)}))
+            with phase("serving.control"):
+                self._run_control(eng)
+            with phase("serving.mailbox"):
+                self._admit_mailbox(eng, Request)
             # Dispatch BEFORE the idle check too: a drain command
             # finishes requests inside _run_control, and their
             # waiting clients must not hang on an idle engine.
-            self._dispatch_completed(eng)
+            with phase("serving.dispatch"):
+                self._dispatch_completed(eng)
             if eng.idle:
-                time.sleep(0.002)
+                with phase("serving.idle_sleep"):
+                    time.sleep(0.002)
                 continue
             eng.step()
-            self._dispatch_completed(eng)
+            with phase("serving.dispatch"):
+                self._dispatch_completed(eng)
+
+    def _admit_mailbox(self, eng, Request) -> None:
+        """Hand the engine what the HTTP threads left in the mailbox:
+        listener first, then ``submit``."""
+        with self._lock:
+            incoming, self._mailbox = self._mailbox, []
+        for rid, prompt, n, arrival, session, tenant in incoming:
+            with self._lock:
+                stream_q = self._streams.get(rid)
+            if stream_q is not None:
+                # Registered BEFORE submit, on the engine thread:
+                # the first token cannot race its listener.
+                eng.add_token_listener(
+                    rid,
+                    lambda tok, done, _q=stream_q:
+                        _q.put(("token", tok)))
+            try:
+                eng.submit(Request(id=rid, prompt=prompt,
+                                   max_new_tokens=n,
+                                   arrival=arrival,
+                                   session=session,
+                                   tenant=tenant))
+            except ValueError as e:
+                # An invalid request answers ITS caller; it must
+                # never take down the engine thread (and with it
+                # every other in-flight request).
+                eng.remove_token_listener(rid)
+                with self._lock:
+                    ev = self._events.pop(rid, None)
+                    if ev is not None:
+                        self._done[rid] = {"id": rid,
+                                           "error": str(e)}
+                        ev.set()
+                    sq = self._streams.pop(rid, None)
+                if sq is not None:
+                    sq.put(("done", {"id": rid,
+                                     "error": str(e)}))
 
     def _dispatch_completed(self, eng) -> None:
         if not eng.completed:

@@ -60,6 +60,7 @@ from distributed_training_tpu.telemetry.events import (  # noqa: F401
     current,
     event,
     install,
+    phase,
     span,
     uninstall,
 )

@@ -14,7 +14,8 @@ Every ``span()`` also opens a ``jax.profiler.TraceAnnotation`` so the
 same region names show up in XProf timelines — one instrumentation
 surface for both the always-on jsonl stream and on-demand traces
 (the TorchTitan stance: metrics/tracing as one first-class subsystem,
-arxiv 2410.06511).
+arxiv 2410.06511). ``phase()`` is the same annotation without a
+record of its own, for regions that would be too many lines.
 
 Ambient use (the ``logging`` model): entrypoints ``install()`` one
 ``Telemetry``; library code calls the module-level ``span()`` /
@@ -175,6 +176,36 @@ class Telemetry:
             self._emit({"kind": "span", "name": name,
                         "t": time.time(), "dur_s": round(dur, 6),
                         "depth": depth, "parent": parent, **attrs})
+
+
+class phase:
+    """A span that writes no record of its own: the XProf trace
+    annotation ``name``, and, where the caller passes ``into``, the
+    region's seconds added to ``into[key]``. For regions too many for
+    a jsonl line each (the five parts of a serving step, whose one
+    ``serving`` record carries the dict as ``phase_s``) and for loops
+    that write no record at all (the server's). Always compiled in: an
+    annotation and two clock reads when no profiler listens."""
+
+    __slots__ = ("_annotation", "_into", "_key", "_t0")
+
+    def __init__(self, name: str, into: dict | None = None,
+                 key: str | None = None):
+        self._annotation = jax.profiler.TraceAnnotation(name)
+        self._into = into
+        self._key = key
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
+        if self._into is not None:
+            self._into[self._key] = (self._into.get(self._key, 0.0)
+                                     + time.perf_counter() - self._t0)
+        return False
 
 
 # A permanently-disabled instance: the ambient default, so library

@@ -33,7 +33,10 @@ envelope's)::
                                      # hot-swap audit trail)
 
 Span events (``SPAN_EVENTS``): ``queued`` (t=0 by construction, the
-request's arrival), ``admitted`` (group/slot/prefix_hit_tokens),
+request's arrival), ``submitted`` (``Engine.submit`` took it: up to
+here the wait was the server's mailbox, from here the engine's queue;
+absent where the request never passed ``submit``), ``admitted``
+(group/slot/prefix_hit_tokens),
 ``resumed`` (session re-attach: group/slot/session/hit_tokens),
 ``adopted`` (disaggregation handoff: group), ``prefill`` (one launch's
 chunk: tokens), ``decode`` (one burst: emitted, plus budget on the
@@ -43,7 +46,10 @@ key), and the terminal ``finished``/``preempted`` (the latter with
 offline math never depends on clock alignment across hosts.
 
 The analyzer (``analyze_traces``) reconstructs per-tenant p50/p95/p99
-TTFT and e2e latency, queue wait, tokens/request, launch occupancy
+TTFT and e2e latency, queue wait and the mailbox's share of it,
+tokens/request, the mean split of a launching step into its
+``phase_s`` parts (from the ``serving`` step records of the same
+stream), launch occupancy
 (tokens per prefill launch, emitted per decode burst), preemption
 retry cost, and prefix-hit rates. ``slo_attainment`` scores each
 finished request against a TTFT deadline + a per-token decode deadline
@@ -66,8 +72,8 @@ TRACE_KEYS = (
 )
 
 SPAN_EVENTS = (
-    "queued", "admitted", "resumed", "adopted", "prefill", "decode",
-    "session_retain", "finished", "preempted",
+    "queued", "submitted", "admitted", "resumed", "adopted", "prefill",
+    "decode", "session_retain", "finished", "preempted",
 )
 
 OUTCOMES = ("finished", "preempted")
@@ -176,6 +182,33 @@ def _span_stats(traces) -> dict:
     return out
 
 
+def _mailbox_waits(traces) -> list[float]:
+    """Arrival to ``Engine.submit`` of every trace that passed it:
+    the ``submitted`` span's time. ``queue_wait_s`` less this is the
+    wait in the engine's own queue."""
+    return [s["t"] for t in traces for s in t.get("spans") or []
+            if s.get("ev") == "submitted"
+            and isinstance(s.get("t"), (int, float))]
+
+
+def step_phases(events) -> dict | None:
+    """Mean split of a launching step (``serving`` records whose
+    ``op`` is not ``idle``) into its ``phase_s`` parts, in seconds;
+    ``other`` is what of ``dur_s`` no part covers. None when the
+    stream holds no such record."""
+    steps = [e for e in events if isinstance(e, dict)
+             and e.get("kind") == "serving" and e.get("op") != "idle"
+             and isinstance(e.get("phase_s"), dict)]
+    if not steps:
+        return None
+    n = len(steps)
+    mean = {k: sum(e["phase_s"].get(k, 0.0) for e in steps) / n
+            for k in steps[0]["phase_s"]}
+    dur = sum(e.get("dur_s") or 0.0 for e in steps) / n
+    mean["other"] = max(0.0, dur - sum(mean.values()))
+    return {"steps": n, "mean_dur_s": dur, "mean_phase_s": mean}
+
+
 def _tenant_report(traces, ttft_deadline_s, per_token_deadline_s
                    ) -> dict:
     done = [t for t in traces if t.get("outcome") == "finished"]
@@ -192,6 +225,7 @@ def _tenant_report(traces, ttft_deadline_s, per_token_deadline_s
         "queue_wait_s": _quantiles(
             [t.get("queue_wait_s") for t in done
              if isinstance(t.get("queue_wait_s"), (int, float))]),
+        "mailbox_wait_s": _quantiles(_mailbox_waits(done)),
         "tokens_per_request": _quantiles(
             [t.get("new_tokens") for t in done
              if isinstance(t.get("new_tokens"), (int, float))]),
@@ -238,6 +272,9 @@ def analyze_traces(events, ttft_deadline_s: float
                 ttft_deadline_s, per_token_deadline_s)
             for name in tenants},
     }
+    phases = step_phases(events)
+    if phases:
+        report["step_phases"] = phases
     return report
 
 
@@ -279,6 +316,9 @@ def render_serving_lines(rep: dict | None) -> list[str]:
         if t.get("queue_wait_s"):
             extra.append(
                 f"queue wait {_fmt_q(t['queue_wait_s'])}")
+        if t.get("mailbox_wait_s"):
+            extra.append("of it in the mailbox "
+                         f"{_fmt_q(t['mailbox_wait_s'])}")
         if t.get("prefix_hit_rate") is not None:
             extra.append(f"prefix hit {t['prefix_hit_rate']:.1%}")
         if t.get("preempt_retry_cost") is not None:
@@ -296,6 +336,13 @@ def render_serving_lines(rep: dict | None) -> list[str]:
                    f"tok/burst x{o['decode_bursts']}")
     if occ:
         lines.append("  launch occupancy: " + ", ".join(occ))
+    ph = rep.get("step_phases")
+    if ph:
+        lines.append(
+            f"  step split ({ph['steps']} launching steps, mean "
+            f"{ph['mean_dur_s'] * 1e3:.2f}ms): " + ", ".join(
+                f"{k} {v * 1e3:.2f}ms"
+                for k, v in ph["mean_phase_s"].items()))
     return lines
 
 

@@ -221,6 +221,62 @@ def test_headline_kernels_compile_under_tpu_compiler(monkeypatch):
     assert rec["temp_gib"] < 14, rec
 
 
+@pytest.mark.parametrize("split", [False, True],
+                         ids=["fused_bwd", "split_bwd"])
+def test_flash_kernels_keep_their_names_under_tpu_compiler(
+        monkeypatch, split):
+    """The TPU compiler names a Pallas custom call after the innermost
+    name-stack entry above it, so an unnamed kernel is called after
+    whichever transform wraps it (``checkpoint.10``, ``closed_call.7``,
+    ``shard_map.223``: ledger, PR 24) and no reader of the device trace
+    can be written against it. With ``name=`` on the
+    ``pl.pallas_call``s the instruction is ``dtt_flash_*.N`` under
+    ``jax.checkpoint`` + ``shard_map`` + the custom VJP alike, and what
+    ``perfbench/trace_reduce.py::short_name`` keeps of it is what
+    ``ops.flash_time_share.train`` looks for."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_training_tpu.ops import flash_attention as fa
+    from perfbench import common, trace_reduce
+
+    monkeypatch.setenv("DTT_ASSUME_TPU", "1")
+    monkeypatch.setattr(fa, "_FORCE_SPLIT_BWD", split)
+    try:
+        from distributed_training_tpu.runtime import topology_runtime
+        mesh = topology_runtime(4, "v5e:2x2").mesh
+    except Exception as e:  # pragma: no cover - no libtpu
+        pytest.skip(f"device-less TPU topology unavailable: {e}")
+
+    def loss(q, k, v):
+        attend = jax.shard_map(
+            lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+            mesh=mesh, in_specs=(P("dp"),) * 3, out_specs=P("dp"),
+            check_vma=False)
+        return jax.checkpoint(attend)(q, k, v).astype(
+            jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((4, 256, 2, 64), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if " custom-call(" in line
+             and 'custom_call_target="tpu_custom_call"' in line]
+    names = sorted(re.match(r"%([\w.]+) = ", c).group(1).rsplit(
+        ".", 1)[0] for c in calls)
+    assert names == (["dtt_flash_bwd_dkv", "dtt_flash_bwd_dq",
+                      "dtt_flash_fwd"] if split
+                     else ["dtt_flash_bwd_fused", "dtt_flash_fwd"])
+    pattern = common.load_file(
+        "layer_metrics", "ops.flash_time_share.train").PATTERN
+    for c in calls:
+        assert pattern.match(trace_reduce.short_name(c)), c
+
+
 def test_collectives_report_counts_pallas_calls():
     """The `collectives` event says which kernels the compiled step
     runs: ``pallas_calls`` counts Mosaic custom calls in the HLO text

@@ -1130,16 +1130,22 @@ def test_spec_decode_token_identity_and_acceptance(tiny_model):
         for i, p in enumerate(prompts):
             eng.submit(Request(id=f"r{i}", prompt=p,
                                max_new_tokens=12))
-        eng.run_until_drained()
+        steps = []
+        while not eng.idle:
+            steps.append(eng.step())
         assert eng.compile_counts() == counts, \
             f"spec_k={k} decode changed a traced shape"
-        return {r["id"]: r["tokens"] for r in eng.completed}, eng
+        return {r["id"]: r["tokens"] for r in eng.completed}, steps
 
     plain, _ = run(1)
     for k in (3, 5):
-        spec, eng = run(k)
+        spec, steps = run(k)
         assert spec == plain, f"spec_k={k} changed tokens"
-        st = eng.spec_stats
+        # The step records are the ledger: a slot-launch is one
+        # slot's verification chunk in one decode step.
+        decode = [r for r in steps if r["op"] == "decode"]
+        st = {"launches": sum(r["slots_stepped"] for r in decode),
+              "emitted": sum(r["tokens"] for r in decode)}
         assert st["launches"] > 0
         # Every request's first token comes from prefill; the rest
         # are decode-emitted.
@@ -1376,28 +1382,33 @@ def test_resident_decode_token_identity(tiny_model):
         for i, p in enumerate(prompts):
             eng.submit(Request(id=f"r{i}", prompt=p,
                                max_new_tokens=12))
-        eng.run_until_drained()
+        steps = []
+        while not eng.idle:
+            steps.append(eng.step())
         assert eng.compile_counts() == counts, \
             f"resident_k={rk} decode changed a traced shape"
         assert eng.cache.pages_used == 0
-        return {r["id"]: r["tokens"] for r in eng.completed}, eng
+        return ({r["id"]: r["tokens"] for r in eng.completed},
+                eng.host_syncs, steps)
 
-    plain, base = run(1)
+    plain, base_syncs, _ = run(1)
     for i, p in enumerate(prompts):
         assert plain[f"r{i}"] == _full_context_greedy(
             model, params, p, 12), f"prompt {i} diverged"
     for rk, sk in ((2, 1), (4, 1), (8, 1), (4, 4), (2, 3)):
-        got, eng = run(rk, sk)
+        got, syncs, steps = run(rk, sk)
         assert got == plain, f"resident_k={rk},spec_k={sk} " \
             "changed tokens"
-        st = eng.resident_stats
-        assert st["launches"] > 0
+        bursts = [r for r in steps if r["op"] == "decode"]
+        assert bursts
         decode_tokens = sum(len(t) - 1 for t in got.values())
-        assert st["emitted"] == decode_tokens
-        assert st["launches"] <= st["steps"] <= st["launches"] * rk
+        assert sum(r["tokens"] for r in bursts) == decode_tokens
+        for r in bursts:
+            assert 1 <= r["resident_steps_per_launch"] <= rk
+            assert r["tokens"] / sk <= r["slot_iters"] <= r["tokens"]
         # The whole point: strictly fewer host syncs than the
         # per-step engine needed for the same stream.
-        assert eng.host_syncs < base.host_syncs
+        assert syncs < base_syncs
 
 
 def test_resident_decode_eos_stops_mid_burst(tiny_model):
@@ -1550,10 +1561,13 @@ def test_resident_sharded_engine_matches_replicated(serving_model):
                .astype(np.int32) for _ in range(8)]
     for i, p in enumerate(prompts):
         eng.submit(Request(id=f"s{i}", prompt=p, max_new_tokens=6))
+    bursts = 0
+    while not eng.idle:
+        bursts += "resident_steps_per_launch" in eng.step()
     sharded = _drain_clean(eng)
     assert eng.compile_counts() == counts, \
         "sharded resident decode changed a traced shape"
-    assert eng.resident_stats["launches"] > 0
+    assert bursts > 0
     ref = Engine(model, params, dataclasses.replace(
         eng.cfg,
         num_pages=eng.dp_groups * (eng.cfg.num_pages - 1) + 1))
@@ -2204,7 +2218,7 @@ def test_trace_lifecycle_preempt_resubmit_finish(tiny_model,
         assert fin["id"] == "tr-1" and fin["tenant"] == "default"
         assert fin["prompt_tokens"] == 4 and fin["new_tokens"] == 6
         evs = [s["ev"] for s in fin["spans"]]
-        assert evs[0] == "queued" and evs[1] == "admitted"
+        assert evs[:3] == ["queued", "submitted", "admitted"]
         assert evs[-1] == "finished"
         assert "prefill" in evs and "decode" in evs
         assert set(evs) <= set(SPAN_EVENTS)
@@ -2212,7 +2226,10 @@ def test_trace_lifecycle_preempt_resubmit_finish(tiny_model,
         # admission happened AFTER the first pass was discarded.
         ts = [s["t"] for s in fin["spans"][1:]]
         assert ts == sorted(ts) and min(ts) >= 0.0
-        assert fin["spans"][1]["t"] >= pre["spans"][-1]["t"]
+        # (its ``submitted`` stamp is the RE-submission's: the queue
+        # share of the wait is the retry's own, the total the whole.)
+        assert fin["spans"][2]["t"] >= fin["spans"][1]["t"] \
+            >= pre["spans"][-1]["t"]
         assert fin["ttft_s"] >= 0 and fin["e2e_s"] >= fin["ttft_s"]
         assert fin["queue_wait_s"] >= 0
         # Schema pin: envelope (kind, t) + exactly TRACE_KEYS.
@@ -2223,13 +2240,24 @@ def test_trace_lifecycle_preempt_resubmit_finish(tiny_model,
         tel.close()
 
 
+# One engine override a cadence: every ``_run_*`` path of ``Engine``
+# (the default prefill is the batched one).
+_CADENCES = {"plain": {}, "spec4": {"spec_k": 4},
+             "resident8": {"resident_k": 8},
+             "sequential": {"prefill_mode": "sequential"}}
+_cadence = pytest.mark.parametrize("cadence", sorted(_CADENCES))
+
+
+@_cadence
 def test_tracing_adds_no_recompiles_and_no_host_syncs(tiny_model,
-                                                      tmp_path):
-    """The DTT010 story as a measured equality: the identical backlog
-    drained with tracing ON (Telemetry installed) and OFF must report
-    the SAME host-sync count and the SAME compile counts — span
-    capture is host-side bookkeeping, never a device sync — and the
-    token streams stay byte-identical."""
+                                                      tmp_path,
+                                                      cadence):
+    """The DTT010 story as a measured equality, in every cadence: the
+    identical backlog drained with tracing ON (Telemetry installed)
+    and OFF must report the SAME host-sync count and the SAME compile
+    counts — span capture, the ``serving.*`` phases and the step
+    record's counters are host-side bookkeeping, never a device sync —
+    and the token streams stay byte-identical."""
     from distributed_training_tpu.telemetry import uninstall
 
     model, params = tiny_model
@@ -2239,7 +2267,7 @@ def test_tracing_adds_no_recompiles_and_no_host_syncs(tiny_model,
                 .astype(np.int32)) for i in range(5)]
 
     def drain(traced):
-        eng = _engine(model, params)
+        eng = _engine(model, params, **_CADENCES[cadence])
         warm = eng.warmup()
         h0 = eng.host_syncs
         for rid, prompt in backlog:
@@ -2263,6 +2291,107 @@ def test_tracing_adds_no_recompiles_and_no_host_syncs(tiny_model,
     assert syncs_on == syncs_off, \
         "tracing changed the host-sync count"
     assert len(recs) == len(backlog)
+
+
+@_cadence
+def test_step_records_carry_phases_and_counts(tiny_model, cadence):
+    """Every launching step's record splits ``dur_s`` into the five
+    ``serving.*`` parts (never nested, so they sum to at most it) and
+    counts where the work happens: a decode step the slots it packed
+    and the loop iterations they were live in, a prefill step the
+    first tokens it handed to completed prompts."""
+    model, params = tiny_model
+    over = _CADENCES[cadence]
+    eng = _engine(model, params, num_pages=96, **over)
+    eng.warmup()
+    prompts = _ragged_prompts()
+    for i, p in enumerate(prompts):
+        eng.submit(Request(id=f"p{i}", prompt=p, max_new_tokens=9))
+    recs = []
+    while not eng.idle:
+        recs.append(eng.step())
+    assert all(r["op"] != "idle" for r in recs)
+    for r in recs:
+        ph = r["phase_s"]
+        assert tuple(ph) == ("admit", "pack", "launch", "fetch",
+                             "emit")
+        assert all(v >= 0.0 for v in ph.values())
+        assert sum(ph.values()) <= r["dur_s"] + 1e-5
+        assert ph["launch"] > 0.0
+    decode = [r for r in recs if r["op"] == "decode"]
+    prefill = [r for r in recs if r["op"] == "prefill"]
+    assert decode and prefill
+    spec_k = over.get("spec_k", 1)
+    for r in decode:
+        assert 1 <= r["slots_stepped"] <= eng.cfg.max_batch
+        # A live iteration emits between one token and spec_k.
+        assert r["tokens"] / spec_k <= r["slot_iters"] <= r["tokens"]
+        assert r["slot_iters"] >= r["slots_stepped"]
+        assert "first_tokens" not in r
+    for r in prefill:
+        assert "slot_iters" not in r and "slots_stepped" not in r
+    # No shared prefix here, so every prompt completes in a prefill
+    # step, and every other token is some decode step's.
+    assert sum(r["first_tokens"] for r in prefill) == len(prompts)
+    assert (sum(r["tokens"] for r in decode) + len(prompts)
+            == sum(len(c["tokens"]) for c in eng.completed)
+            == 9 * len(prompts))
+    assert sum(r["host_syncs"] for r in recs) == eng.host_syncs
+
+
+@_cadence
+def test_engine_programs_have_distinct_stable_names(tiny_model,
+                                                    cadence):
+    """jax names a jitted ``functools.partial`` ``jit__unknown``; the
+    engine names every program it builds, so the trace's module line,
+    the compile cache's lists and compiler errors tell them apart."""
+    import re
+
+    model, params = tiny_model
+    eng = _engine(model, params, **_CADENCES[cadence])
+    names = [re.search(r"module @(\S+)",
+                       fn.lower(*args).as_text()).group(1)
+             for fn, args in eng._warmup_calls()]
+    assert len(names) == len(eng.compile_counts()) >= 3
+    assert len(set(names)) == len(names), names
+    assert all(n.startswith("jit_serving_") for n in names), names
+    decode = {"plain": "jit_serving_decode",
+              "sequential": "jit_serving_decode",
+              "spec4": "jit_serving_spec_decode",
+              "resident8": "jit_serving_resident_decode"}[cadence]
+    assert names[0] == decode and names[-1] == "jit_serving_cow"
+
+
+def test_server_request_trace_splits_mailbox_from_queue(tiny_model,
+                                                        tmp_path):
+    """A request through ``ServingServer`` waits twice before it has a
+    slot: in the server's mailbox until the engine thread hands it to
+    ``Engine.submit``, then in the engine's queue. Its trace shows
+    both: ``queued`` (arrival), ``submitted``, ``admitted``."""
+    from distributed_training_tpu.serving.server import ServingServer
+    from distributed_training_tpu.telemetry import uninstall
+
+    model, params = tiny_model
+    tel, recs = _trace_collector(tmp_path)
+    eng = _engine(model, params)
+    eng.warmup()
+    srv = ServingServer(eng, port=0)
+    assert srv.start() is not None
+    try:
+        out = srv.generate(np.asarray([3, 1, 4, 1, 5], np.int32), 4)
+        assert len(out["tokens"]) == 4
+    finally:
+        srv.stop()
+        uninstall()
+        tel.close()
+    [trace] = recs
+    spans = trace["spans"]
+    assert [s["ev"] for s in spans[:3]] == ["queued", "submitted",
+                                            "admitted"]
+    ts = [s["t"] for s in spans]
+    assert ts == sorted(ts) and ts[0] == 0.0
+    assert trace["queue_wait_s"] == pytest.approx(spans[2]["t"],
+                                                  abs=1e-5)
 
 
 def test_anomaly_detector_adds_no_host_syncs(tiny_model, tmp_path):
@@ -2366,6 +2495,8 @@ def test_debug_requests_endpoint(tiny_model):
         assert row["pages_held"] >= 1
         assert isinstance(row["group"], int)
         assert isinstance(row["slot"], int)
+        assert [s["ev"] for s in row["spans"][:3]] == [
+            "queued", "submitted", "admitted"]
         assert seen["in_flight"] == 1
     finally:
         srv.stop()

@@ -614,8 +614,9 @@ def test_serving_trace_schema_keys_pinned():
         "queue_wait_s", "ttft_s", "e2e_s", "prefix_hit_tokens",
         "tokens_discarded", "spans", "weights_versions")
     assert set(SPAN_EVENTS) == {
-        "queued", "admitted", "resumed", "adopted", "prefill",
-        "decode", "session_retain", "finished", "preempted"}
+        "queued", "submitted", "admitted", "resumed", "adopted",
+        "prefill", "decode", "session_retain", "finished",
+        "preempted"}
     assert OUTCOMES == ("finished", "preempted")
     assert aggregate.SCHEMA == 1
 
