@@ -1,0 +1,69 @@
+#!/usr/bin/env python3
+"""The calibration table of ``ops/paged_attention.py::chunk_form``:
+both forms of ``paged_attention_chunk`` timed on the chip, one layer's
+call, at the shapes the serving engines trace (PERF.md section 6).
+
+    python3 benchmarks/paged_form_table.py     # on a machine with a TPU
+
+Prints one JSON line a shape (``gather_ms``, ``pool_ms``, the form the
+rule takes, the faster form) and writes them to
+``chiprun_out/paged_form_table.json``. Times are of twenty calls after
+one, a layer alone: what decides between two forms, not a benchmark
+result.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+# name: B, S, H, Hkv, P, N. The first five are the calibration table's;
+# the rest bracket where the two forms cross.
+XL = dict(H=25, Hkv=25, P=64, N=385)
+SMALL = dict(H=12, Hkv=12, P=64, N=3073)
+SHAPES = {
+    "xl.resident_16x1": dict(B=16, S=1, **XL),
+    "xl.prefill_batch_4x128": dict(B=4, S=128, **XL),
+    "xl.prefill_cont_1x128": dict(B=1, S=128, **XL),
+    "xl.spec_16x4": dict(B=16, S=4, **XL),
+    "small.resident_64x1": dict(B=64, S=1, **SMALL),
+    "xl.16x8": dict(B=16, S=8, **XL),
+    "xl.16x16": dict(B=16, S=16, **XL),
+    "xl.16x32": dict(B=16, S=32, **XL),
+    "xl.4x32": dict(B=4, S=32, **XL),
+    "small.spec_64x4": dict(B=64, S=4, **SMALL),
+    "small.prefill_batch_8x128": dict(B=8, S=128, **SMALL),
+    "small.16x1": dict(B=16, S=1, **SMALL),
+    "xl.gqa_16x1": dict(B=16, S=1, H=25, Hkv=5, P=64, N=385),
+}
+
+
+def main() -> int:
+    import jax
+
+    import chip_smoke
+
+    if jax.devices()[0].platform != "tpu":
+        print("paged_form_table: no TPU, nothing was timed",
+              file=sys.stderr)
+        return 1
+    rows = []
+    for name, shape in SHAPES.items():
+        row = {"name": name, **chip_smoke.paged_forms_case(**shape)}
+        row["faster"] = ("pool" if row["pool_ms"] < row["gather_ms"]
+                         else "gather")
+        print(json.dumps(row), flush=True)
+        rows.append(row)
+    out = os.path.join(REPO, "chiprun_out")
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "paged_form_table.json"), "w") as f:
+        json.dump(rows, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

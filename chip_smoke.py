@@ -221,6 +221,61 @@ def paged_case(B, H, Hkv, P, hd=128, ps=16, N=64):
     return make("kernel"), make("ref"), inputs, label
 
 
+def paged_forms_case(B, S, H, Hkv, P, N, hd=64, ps=16, reps=20) -> dict:
+    """Both forms of ``paged_attention_chunk`` (gather, pool) on one
+    layer's bf16 pool at an engine's shapes, against each other: the
+    worst absolute difference, each form's smoke time a call, and the
+    form the rule takes. The sequences share the pool as the engine
+    deals it: distinct pages out of order, ragged lengths, the unused
+    tail of every row on scratch page 0, one inactive row."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_training_tpu.ops import paged_attention as pa
+
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 2), 3)
+    q = jax.random.normal(ks[0], (B, S, H, hd), jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (Hkv, N, ps, hd), jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (Hkv, N, ps, hd), jnp.bfloat16)
+    rng = np.random.default_rng(SEED)
+    own = min(P, (N - 1) // B)
+    pages = rng.permutation(N - 1)[:B * own].reshape(B, own) + 1
+    lengths = rng.integers(S, own * ps + 1, B)
+    lengths[B // 2] = 0
+    tables = np.zeros((B, P), np.int32)
+    for b in range(B):
+        used = -(-int(lengths[b]) // ps)
+        tables[b, :used] = pages[b, :used]
+    q_pos = lengths[:, None] - S + np.arange(S)[None, :]
+    q_pos = np.where(lengths[:, None] > 0, q_pos, -1).astype(np.int32)
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(q_pos))
+    out, ms = {}, {}
+    for form, fn in (("gather", pa._gather_attention),
+                     ("pool", pa._pool_attention)):
+        run = jax.jit(fn).lower(*args).compile()
+        out[form] = jax.block_until_ready(run(*args))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            last = run(*args)
+        jax.block_until_ready(last)
+        ms[form] = (time.perf_counter() - t0) / reps * 1e3
+    diff = float(jnp.abs(out["pool"].astype(jnp.float32)
+                         - out["gather"].astype(jnp.float32)).max())
+    band = _close("paged_forms", out["pool"], out["gather"])
+    rule = pa.chunk_form(q.shape, kp.shape, tables.shape,
+                         kp.dtype.itemsize)
+    label = f"{B} x {S}, H{H}/{Hkv} D{hd}, pool {N} x {ps}, P {P}"
+    say(f"  paged forms [{label}]: gather {ms['gather']:.3f} ms, pool "
+        f"{ms['pool']:.3f} ms a call (smoke wall), worst |diff| "
+        f"{diff:.4f} ({band:.3f} of the bf16 band), rule -> {rule}")
+    if band > 1.0:
+        raise AssertionError(
+            f"forms differ beyond the bf16 band: {diff} ({band})")
+    return {"ok": True, "shape": label, "gather_ms": ms["gather"],
+            "pool_ms": ms["pool"], "max_abs_diff": diff, "rule": rule}
+
+
 def engine_paged_case() -> dict:
     """The kernel where the engine reaches it: ``paged_impl: auto`` at
     head_dim 128 inside the jitted, layer-scanned decode program.
@@ -301,6 +356,12 @@ def phase_kernels() -> dict:
         compared("paged_decode_groups4", paged_case, 8, 8, 2, P=6),
         compared("paged_decode_groups8", paged_case, 8, 16, 2, P=8),
         ("engine_paged_kernel", engine_paged_case),
+        # gpt2-xl's engine (perfbench/configs/gpt2-xl.json): resident
+        # decode and spec_k 4.
+        ("paged_forms_xl_16x1",
+         lambda: paged_forms_case(16, 1, 25, 25, P=64, N=385)),
+        ("paged_forms_xl_16x4",
+         lambda: paged_forms_case(16, 4, 25, 25, P=64, N=385)),
     ])
     results = {}
     for name, case in cases.items():
@@ -487,8 +548,9 @@ def phase_engine() -> dict:
     t0 = time.perf_counter()
     eng = Engine(model, bf16, ecfg, mesh=None)
     counts = eng.warmup()
+    forms = eng.paged_forms()
     say(f"  engine warm (smoke wall {time.perf_counter() - t0:.1f}s): "
-        f"compile_counts {counts}, weights "
+        f"compile_counts {counts}, paged forms {forms}, weights "
         f"{eng.weight_bytes / 1e6:.0f} MB bf16")
     srv = ServingServer(eng, port=0)
     if srv.start() is None:
@@ -496,7 +558,7 @@ def phase_engine() -> dict:
     try:
         t0 = time.perf_counter()
         streamed = [_post_generate(srv.port, {
-            "prompt_ids": p, "max_NEW_TOKENS": NEW_TOKENS,
+            "prompt_ids": p, "max_new_tokens": NEW_TOKENS,
             "stream": True}) for p in prompts]
         # The plain requests arrive together, so they share launches
         # (continuous batching) and meet the prefixes the streamed
@@ -506,7 +568,7 @@ def phase_engine() -> dict:
         def ask(i):
             plain[i] = _post_generate(srv.port, {
                 "prompt_ids": prompts[i],
-                "max_NEW_TOKENS": NEW_TOKENS})
+                "max_new_tokens": NEW_TOKENS})
 
         threads = [threading.Thread(target=ask, args=(i,))
                    for i in range(len(prompts))]
@@ -559,7 +621,8 @@ def phase_engine() -> dict:
             f"an emitted token's reference logit is {worst:.4f} below "
             f"the reference argmax (tolerance {TIE_TOL})")
     return {"tokens": streamed, "argmax_agreement": [exact, n_tok],
-            "worst_logit_gap": worst, "compile_counts": after}
+            "worst_logit_gap": worst, "compile_counts": after,
+            "paged_forms": forms}
 
 
 # -- driver ------------------------------------------------------------------

@@ -22,30 +22,74 @@ Two entrypoints:
   against its pages. Dispatches to the TPU Pallas kernel when
   ``kernel_supported`` (one async DMA per non-contiguous page,
   double-buffered — see the Pallas guide's paged-attention walk-
-  through); everywhere else (CPU meshes, odd shapes) the XLA
-  reference path gathers pages dense and masks. Exact same numerics
-  contract as ops/attention.py: fp32 logits/softmax, output in
-  q.dtype, GQA via hkv-major grouping.
-- ``paged_attention_chunk`` — multi-query (prefill-chunk) form: ``S``
-  queries per sequence, each masked to pages at logical positions
-  ``<= its own position``. Used by the engine's chunked prefill for
-  chunks after the first (the first chunk has no prefix and runs the
-  ordinary causal path, flash-eligible, via ops.attention).
+  through; it needs ``head_dim % 128 == 0``, so not GPT-2's 64);
+  everywhere else it is ``paged_attention_chunk`` with ``S = 1``.
+  Exact same numerics contract as ops/attention.py: fp32
+  logits/softmax, output in q.dtype, GQA via hkv-major grouping.
+- ``paged_attention_chunk`` — multi-query form: ``S`` queries per
+  sequence, each masked to logical positions ``<= its own position``.
+  Every engine program but the first prefill chunk calls it once a
+  layer (the first chunk has no prefix and runs the ordinary causal
+  path, flash-eligible, via ops.attention). It has two forms with the
+  same mathematics, and ``chunk_form`` takes the cheaper from the
+  static shapes when the program is traced:
 
-Gather-based reference is O(max_pages * page_size) per query
-regardless of true length — correct everywhere, and on CPU test
-meshes (tiny pools) the gather is cheap. The kernel path reads only
-the pages a sequence actually owns.
+  - *gather form* reads ``B * P * ps`` slots: every sequence's whole
+    table row copied dense in logical order, whatever is live, and
+    transposed out of the pool's head-major order. Right when queries
+    are many and sequences few (prefill chunks: 4 x 128, 1 x 128);
+  - *pool form* reads the layer's ``N * ps`` slots once for all
+    sequences, in place, and scores every query against all of them
+    under a mask made from the page table turned inside out. No
+    gather, no transpose, no copy. Right when queries are few (the
+    resident decode loop 16 x 1, speculative verify 16 x 4, the
+    per-token decode program), where it also keeps the contraction on
+    the MXU in the pool's dtype: with one query a sequence the gather
+    form's per-sequence dot has one row, and the TPU compiler lowers
+    it to a float32 copy of the gathered block and a multiply-reduce.
+
+  The ragged kernel that reads only the pages a sequence owns is the
+  end state (ROADMAP S1); it wants the pool re-laid-out first (S2).
+
+There is no switch between the forms: ``paged_impl`` means kernel or
+reference and nothing else. The form each compiled program took
+(``"pool"``, ``"gather"``, ``"kernel"``) is seen at trace time by
+``observe_forms`` and reported per program by ``Engine.paged_forms()``
+and the ``serving_warmup`` telemetry record (docs/observability.md).
 """
 
 from __future__ import annotations
 
+import contextlib
 import math
 
 import jax
 import jax.numpy as jnp
 
 from distributed_training_tpu.runtime import default_platform
+
+
+_observers: list[list[str]] = []
+
+
+@contextlib.contextmanager
+def observe_forms():
+    """Collect, while open, the form every ``paged_attention`` and
+    ``paged_attention_chunk`` call takes (``"kernel"``, ``"pool"``,
+    ``"gather"``). The form follows from static shapes, so a call is
+    seen when the program around it is TRACED: the engine opens this
+    around each program's body (``serving/engine.py::_named``)."""
+    seen: list[str] = []
+    _observers.append(seen)
+    try:
+        yield seen
+    finally:
+        _observers.remove(seen)
+
+
+def _took(form: str) -> None:
+    for seen in _observers:
+        seen.append(form)
 
 
 def kernel_supported(q: jax.Array, k_pages: jax.Array,
@@ -82,6 +126,18 @@ def _gather_pages(pages: jax.Array, page_indices: jax.Array
     return g.transpose(1, 2, 3, 0, 4).reshape(B, P * ps, Hkv, hd)
 
 
+def _masked_softmax(logits: jax.Array, visible: jax.Array
+                    ) -> jax.Array:
+    """Float32 softmax over the last axis of ``logits`` where
+    ``visible`` (broadcast against them) holds; a row with nothing
+    visible gives zeros, not the uniform weights a softmax of equal
+    ``finfo.min`` would."""
+    logits = jnp.where(visible, logits, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.where(jnp.any(visible, axis=-1, keepdims=True), probs,
+                     0.0)
+
+
 def _masked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       visible: jax.Array) -> jax.Array:
     """GQA attention with an explicit visibility mask.
@@ -100,16 +156,112 @@ def _masked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qg = q.reshape(B, S, Hkv, group, hd)
     logits = jnp.einsum("bshgd,bkhd->bhgsk", qg, k,
                         preferred_element_type=jnp.float32)
-    logits = logits * (hd ** -0.5)
-    neg = jnp.finfo(jnp.float32).min
-    logits = jnp.where(visible[:, None, None], logits, neg)
-    # Guard the all-masked row: subtract a rowwise-safe max and zero
-    # the weights where nothing is visible.
-    probs = jax.nn.softmax(logits, axis=-1)
-    any_visible = jnp.any(visible, axis=-1)          # (B, S)
-    probs = jnp.where(any_visible[:, None, None, :, None], probs, 0.0)
+    probs = _masked_softmax(logits * (hd ** -0.5),
+                            visible[:, None, None])
     out = jnp.einsum("bhgsk,bkhd->bshgd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
+    return out.reshape(B, S, H, hd).astype(q.dtype)
+
+
+# HBM bytes a v5e moves for each nominal byte, fitted to one layer's
+# call timed on the chip in both forms at thirteen engine shapes
+# (benchmarks/paged_form_table.py; the table is in PERF.md section 6).
+# Nominal sizes mislead by these factors, which is why they are here.
+_GATHER_COPY = 9.0       # gathered KV: the gather, the transpose to
+#                          (B, Sk, Hkv, hd), head_dim re-tiled to 128
+_GATHER_COPY_ROW = 32.0  # ... with ONE query row a kv head (S * group
+#                          == 1) the contraction is no dot: a float32
+#                          copy of the block and a multiply-reduce
+_POOL_READ = 5.3         # the pool read in place by an underfed MXU
+_LOGITS = 5.75           # float32 logits: written, masked, softmaxed,
+#                          cast to the values' dtype, read (both forms)
+
+
+def chunk_form(q_shape, pool_shape, table_shape, itemsize: int) -> str:
+    """``"pool"`` or ``"gather"``: the form of ``paged_attention_chunk``
+    that moves fewer bytes for q ``(B, S, H, hd)``, a pool ``(Hkv, N,
+    ps, hd)`` of ``itemsize``-byte elements and a table ``(B, P)``.
+    Shapes are static, so this runs when a program is traced: one
+    algorithm whose cost crosses over with the shape. The gather form
+    copies ``B * P * ps`` slots whatever is live and scores them; the
+    pool form reads ``N * ps`` slots once for all sequences and scores
+    every query against all of them, so it wins while queries are few
+    (decode, speculative verify) and loses by its logits when they are
+    many (prefill chunks)."""
+    B, S, H, hd = q_shape
+    Hkv, N, ps, _ = pool_shape
+    P = table_shape[1]
+    kv_slot = 2 * Hkv * hd * itemsize           # keys and values
+    copy = (_GATHER_COPY_ROW if S * (H // Hkv) == 1 else _GATHER_COPY)
+    gather = B * P * ps * (copy * kv_slot + _LOGITS * S * H * 4)
+    pool = N * ps * (_POOL_READ * kv_slot + _LOGITS * B * S * H * 4)
+    return "pool" if pool < gather else "gather"
+
+
+def _gather_attention(q: jax.Array, k_pages: jax.Array,
+                      v_pages: jax.Array, page_indices: jax.Array,
+                      q_positions: jax.Array) -> jax.Array:
+    """Gather form: each sequence's pages copied dense in logical
+    order (``B * P * ps`` slots, however few are live), then masked
+    attention over the copy."""
+    kd = _gather_pages(k_pages, page_indices)
+    vd = _gather_pages(v_pages, page_indices)
+    Sk = kd.shape[1]
+    slot = jnp.arange(Sk, dtype=jnp.int32)
+    visible = (slot[None, None, :] <= q_positions[:, :, None]) \
+        & (q_positions[:, :, None] >= 0)
+    return _masked_attention(q, kd, vd, visible)
+
+
+def _pool_attention(q: jax.Array, k_pages: jax.Array,
+                    v_pages: jax.Array, page_indices: jax.Array,
+                    q_positions: jax.Array) -> jax.Array:
+    """Pool form: every query against the layer's WHOLE pool where it
+    lies (``N * ps`` slots a head, read once for all sequences), the
+    page table turned inside out into a visibility mask. No gather, no
+    transpose, no copy of the pool; the same keys at the same
+    precisions as the gather form, summed in physical order.
+
+    Physical page ``n`` holds logical page ``j`` of sequence ``b`` iff
+    ``page_indices[b, j] == n``; the LOWEST such ``j`` counts, so the
+    unused tail of a row (all scratch page 0) puts page 0 past the
+    sequence's last used page, where no query position reaches, and a
+    page that copy-on-write sharing put into two rows is visible to
+    both."""
+    B, S, H, hd = q.shape
+    Hkv, N, ps, _ = k_pages.shape
+    P = page_indices.shape[1]
+    if H % Hkv:
+        raise ValueError(f"n_heads {H} not divisible by n_kv_heads "
+                         f"{Hkv}")
+    group = H // Hkv
+    logical = jnp.arange(P, dtype=jnp.int32)[None, :, None]
+    owns = page_indices[:, :, None] \
+        == jnp.arange(N, dtype=page_indices.dtype)[None, None, :]
+    # (B, N): logical page of each physical page; P where not owned,
+    # which is past every position a table of P pages can hold.
+    owner = jnp.min(jnp.where(owns, logical, P), axis=1)
+    slot_pos = (owner[:, :, None] * ps
+                + jnp.arange(ps, dtype=jnp.int32)[None, None, :]
+                ).reshape(B, N * ps)
+    visible = (slot_pos[:, None, :] <= q_positions[:, :, None]) \
+        & (q_positions[:, :, None] >= 0)             # (B, S, N*ps)
+    qg = q.reshape(B, S, Hkv, group, hd)
+    # Batched over the kv head with B*S*group rows a head: an MXU dot
+    # in the pool's dtype also at S = group = 1, where the gather
+    # form's per-sequence contraction has one row and is lowered to a
+    # float32 multiply-reduce.
+    logits = jnp.einsum("bshgd,hkd->hbsgk", qg,
+                        k_pages.reshape(Hkv, N * ps, hd),
+                        preferred_element_type=jnp.float32)
+    probs = _masked_softmax(logits * (hd ** -0.5),
+                            visible[None, :, :, None, :])
+    out = jnp.einsum("hbsgk,hkd->hbsgd", probs.astype(v_pages.dtype),
+                     v_pages.reshape(Hkv, N * ps, hd),
+                     preferred_element_type=jnp.float32)
+    # Transposed apart: with the head moved inside the einsum's own
+    # output, XLA's CPU runtime has no bfloat16 dot to run it with.
+    out = out.transpose(1, 2, 0, 3, 4)
     return out.reshape(B, S, H, hd).astype(q.dtype)
 
 
@@ -124,14 +276,13 @@ def paged_attention_chunk(q: jax.Array, k_pages: jax.Array,
     (b, s) attends logical positions ``<= q_positions[b, s]`` of
     sequence b (the chunk's own KV must already be written to the
     pool). Negative q_positions mark padding queries (zero output).
+    The form (``chunk_form``) follows from the static shapes.
     """
-    kd = _gather_pages(k_pages, page_indices)
-    vd = _gather_pages(v_pages, page_indices)
-    Sk = kd.shape[1]
-    slot = jnp.arange(Sk, dtype=jnp.int32)
-    visible = (slot[None, None, :] <= q_positions[:, :, None]) \
-        & (q_positions[:, :, None] >= 0)
-    return _masked_attention(q, kd, vd, visible)
+    form = chunk_form(q.shape, k_pages.shape, page_indices.shape,
+                      k_pages.dtype.itemsize)
+    _took(form)
+    attend = _pool_attention if form == "pool" else _gather_attention
+    return attend(q, k_pages, v_pages, page_indices, q_positions)
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array,
@@ -153,6 +304,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array,
                   or (impl == "auto"
                       and kernel_supported(q, k_pages)))
     if use_kernel:  # pragma: no cover - needs a TPU (chip_smoke.py)
+        _took("kernel")
         from jax.experimental.pallas.ops.tpu.paged_attention import (
             paged_attention as tpu_paged_attention,
         )

@@ -473,9 +473,17 @@ def _named(name: str, body):
     name, so jax calls every engine program ``jit__unknown`` — on the
     trace's ``XLA Modules`` line, in the compile cache's hit and miss
     lists and in compiler errors. The jitted program of ``name`` is
-    ``jit_<name>``."""
+    ``jit_<name>``. ``program`` runs when jax traces it, which is when
+    the paged attention inside takes its form from the shapes: that
+    form is kept as ``program.paged_form`` (``Engine.paged_forms``)."""
+    from distributed_training_tpu.ops.paged_attention import (
+        observe_forms)
+
     def program(*args):
-        return body(*args)
+        with observe_forms() as seen:
+            out = body(*args)
+        program.paged_form = "+".join(sorted(set(seen))) or None
+        return out
     program.__name__ = program.__qualname__ = name
     return program
 
@@ -826,21 +834,35 @@ class Engine:
         if self._sharing:
             self._cow_fn = build_cow_fn(c, self.cfg, mesh=self.mesh)
 
+    def _programs(self) -> dict:
+        """Every jitted program this engine built, by its role."""
+        fns = {"decode": self._decode_fn}
+        if self.cfg.prefill_mode == "batched":
+            fns["prefill_batch"] = self._prefill_batch_fn
+        else:
+            fns["prefill_first"] = self._prefill_first_fn
+            fns["prefill_cont"] = self._prefill_cont_fn
+        if self._sharing:
+            fns["cow"] = self._cow_fn
+        return fns
+
     def compile_counts(self) -> dict:
         """Jit-cache sizes per program — the bench's zero-recompile
         assertion compares this dict before/after the storm."""
-        counts = {"decode": self._decode_fn._cache_size()}
-        if self.cfg.prefill_mode == "batched":
-            counts["prefill_batch"] = \
-                self._prefill_batch_fn._cache_size()
-        else:
-            counts["prefill_first"] = \
-                self._prefill_first_fn._cache_size()
-            counts["prefill_cont"] = \
-                self._prefill_cont_fn._cache_size()
-        if self._sharing:
-            counts["cow"] = self._cow_fn._cache_size()
-        return counts
+        return {role: fn._cache_size()
+                for role, fn in self._programs().items()}
+
+    def paged_forms(self) -> dict:
+        """``{program: paged_form}`` for every program traced so far,
+        under the names the trace shows less ``jit_``
+        (``serving_resident_decode``, ``serving_prefill_batch``, ...):
+        ``"pool"``, ``"gather"`` or ``"kernel"``
+        (ops/paged_attention.py), fixed by the shapes when the program
+        was traced; ``None`` for a program that reads no pool
+        (``serving_prefill_first``, ``serving_cow``). Read-only."""
+        return {fn.__wrapped__.__name__: fn.__wrapped__.paged_form
+                for fn in self._programs().values()
+                if hasattr(fn.__wrapped__, "paged_form")}
 
     def _warmup_calls(self):
         """``(program, arguments)`` for every program this engine
@@ -902,11 +924,15 @@ class Engine:
                                  zeros(G, W))
 
     def warmup(self) -> dict:
-        """Compile every program (``_warmup_calls``). Returns
-        compile_counts()."""
+        """Compile every program (``_warmup_calls``) and emit one
+        ``serving_warmup`` record with each program's ``paged_form``.
+        Returns compile_counts()."""
         for fn, args in self._warmup_calls():
             *_outs, k, v = fn(*args)
             self.cache.update_pools(k, v)
+        event("serving_warmup",
+              programs=[{"program": name, "paged_form": form}
+                        for name, form in self.paged_forms().items()])
         return self.compile_counts()
 
     # -- admission ---------------------------------------------------------

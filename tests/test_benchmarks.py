@@ -3,6 +3,7 @@ end-to-end and emit the schema the baseline record needs. Heavy configs
 are TPU-targeted; the CPU-runnable one exercises the whole path."""
 
 import json
+import math
 import os
 import sys
 
@@ -275,6 +276,50 @@ def test_flash_kernels_keep_their_names_under_tpu_compiler(
         "layer_metrics", "ops.flash_time_share.train").PATTERN
     for c in calls:
         assert pattern.match(trace_reduce.short_name(c)), c
+
+
+@pytest.mark.parametrize("S", [1, 4], ids=["resident", "spec4"])
+def test_decode_shaped_paged_attention_reads_pool_in_place(S):
+    """The decode-shaped call of ``gpt2xl.serve_decode`` (16 slots x
+    ``S`` queries, 25 heads of 64, a 385-page pool, bfloat16) compiles
+    for a v5e to a program that gathers nothing and holds no float32
+    array the size of the gathered block. The gather form compiled to
+    ``f32[1024,25,16,64]`` converts and multiply-reduces that held 74%
+    of the cell's device time (``convert.66``, ledger, PR 25); a
+    refactor that brings them back fails here, not on the chip."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_training_tpu.ops import paged_attention as pa
+
+    try:
+        from distributed_training_tpu.runtime import topology_runtime
+        chip = SingleDeviceSharding(
+            topology_runtime(1, "v5e:2x2").mesh.devices.flat[0])
+    except Exception as e:  # pragma: no cover - no libtpu
+        pytest.skip(f"device-less TPU topology unavailable: {e}")
+
+    B, H, hd, P, N, ps = 16, 25, 64, 64, 385, 16
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    pool = shape((H, N, ps, hd), jnp.bfloat16)
+    with pa.observe_forms() as seen:
+        text = jax.jit(pa.paged_attention_chunk).lower(
+            shape((B, S, H, hd), jnp.bfloat16), pool, pool,
+            shape((B, P), jnp.int32),
+            shape((B, S), jnp.int32)).compile().as_text()
+    assert seen == ["pool"]
+    assert " gather(" not in text
+    gathered = B * P * ps * H * hd
+    largest = max(math.prod(int(d) for d in dims.split(","))
+                  for dims in re.findall(r"f32\[([0-9,]+)\]", text))
+    assert largest < gathered / 2, (largest, gathered)
+    assert text.count(" convolution(") == 2     # both dots on the MXU
 
 
 def test_collectives_report_counts_pallas_calls():

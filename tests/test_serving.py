@@ -127,6 +127,154 @@ def test_paged_attention_matches_dense_reference():
                                    rtol=1e-5, atol=1e-5)
 
 
+def _paged_chunk_case(S, group, dtype, seed=0):
+    """A pool as the engine deals it, with every hazard of the pool
+    form in it at once: ragged lengths over shuffled physical pages,
+    a first page SHARED by sequences 0 and 1 (copy-on-write sharing
+    before the write), table tails on scratch page 0 while page 0
+    holds large finite garbage, a padding query in a live row and one
+    all-inactive row. Returns the call's arguments and, per sequence,
+    the dense K/V the tables describe."""
+    rng = np.random.default_rng(seed)
+    Hkv, hd, ps = 2, 16, 8
+    H = Hkv * group
+    B = 4
+    P = -(-(S + 40) // ps)
+    N = 1 + B * P
+    # Last query's position + 1: ragged, one ending inside the shared
+    # first page's successor, one filling its table.
+    lengths = np.asarray([S + 3, S + 17, P * ps, S + 9], np.int64)
+    k_pages = np.zeros((Hkv, N, ps, hd), np.float32)
+    v_pages = np.zeros((Hkv, N, ps, hd), np.float32)
+    k_pages[:, 0] = 1e4
+    v_pages[:, 0] = -1e4
+    tables = np.zeros((B, P), np.int32)
+    dense_k = rng.standard_normal((B, P * ps, Hkv, hd)).astype(
+        np.float32)
+    dense_v = rng.standard_normal((B, P * ps, Hkv, hd)).astype(
+        np.float32)
+    dense_k[1, :ps] = dense_k[0, :ps]
+    dense_v[1, :ps] = dense_v[0, :ps]
+    perm = iter(rng.permutation(np.arange(1, N)))
+    for b in range(B):
+        for j in range(-(-int(lengths[b]) // ps)):
+            pid = tables[0, 0] if (b, j) == (1, 0) else int(next(perm))
+            tables[b, j] = pid
+            chunk = slice(j * ps, (j + 1) * ps)
+            k_pages[:, pid] = dense_k[b, chunk].transpose(1, 0, 2)
+            v_pages[:, pid] = dense_v[b, chunk].transpose(1, 0, 2)
+    q_pos = (lengths[:, None] - S + np.arange(S)[None, :]).astype(
+        np.int32)
+    q_pos[3] = -1                       # an inactive slot
+    if S > 1:
+        q_pos[0, -1] = -1               # a padding query
+    q = rng.standard_normal((B, S, H, hd)).astype(np.float32)
+    args = (jnp.asarray(q, dtype), jnp.asarray(k_pages, dtype),
+            jnp.asarray(v_pages, dtype), jnp.asarray(tables),
+            jnp.asarray(q_pos))
+    return args, dense_k, dense_v
+
+
+def _dense_reference(args, dense_k, dense_v):
+    """Naive attention in float64 over the dense K/V, from the inputs
+    as the dtype rounded them: query (b, s) over logical positions
+    ``<= q_pos[b, s]``, zeros where ``q_pos < 0``."""
+    q, k_pages, _v, _tables, q_pos = args
+    q = np.asarray(q.astype(jnp.float32), np.float64)
+    q_pos = np.asarray(q_pos)
+    as_dtype = lambda x: np.asarray(            # noqa: E731
+        jnp.asarray(x, k_pages.dtype).astype(jnp.float32), np.float64)
+    dense_k, dense_v = as_dtype(dense_k), as_dtype(dense_v)
+    B, S, H, hd = q.shape
+    group = H // dense_k.shape[2]
+    out = np.zeros((B, S, H, hd))
+    for b, s, h in np.ndindex(B, S, H):
+        n = int(q_pos[b, s]) + 1
+        if n <= 0:
+            continue
+        logits = dense_k[b, :n, h // group] @ q[b, s, h] * hd ** -0.5
+        w = np.exp(logits - logits.max())
+        out[b, s, h] = (w / w.sum()) @ dense_v[b, :n, h // group]
+    return out
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("S", [1, 4, 128],
+                         ids=["decode", "spec4", "prefill128"])
+def test_paged_chunk_forms_match_dense_reference(S, group, dtype):
+    """Both forms of ``paged_attention_chunk`` are the same attention:
+    each against naive dense attention and against the other, at the
+    decode, speculative and prefill-chunk shapes, with and without
+    GQA. Each form is reached through its private function, as the
+    rule would pick either on a pool this small."""
+    from distributed_training_tpu.ops import paged_attention as pa
+
+    args, dense_k, dense_v = _paged_chunk_case(S, group,
+                                               jnp.dtype(dtype))
+    want = _dense_reference(args, dense_k, dense_v)
+    got = {form: np.asarray(jax.jit(fn)(*args).astype(jnp.float32))
+           for form, fn in (("gather", pa._gather_attention),
+                            ("pool", pa._pool_attention))}
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    q_pos = np.asarray(args[4])
+    for form, out in got.items():
+        assert np.isfinite(out).all(), form
+        # Exact zeros, not small numbers: the garbage on page 0 is
+        # 1e4 and a leak of it would be anything but.
+        assert not out[q_pos < 0].any(), form
+        np.testing.assert_allclose(out, want, rtol=tol, atol=tol,
+                                   err_msg=form)
+    np.testing.assert_allclose(got["pool"], got["gather"], rtol=tol,
+                               atol=tol)
+    public = pa.paged_attention_chunk(*args).astype(jnp.float32)
+    np.testing.assert_allclose(np.asarray(public), want, rtol=tol,
+                               atol=tol)
+
+
+# name: (q (B, S, H, hd), pool (Hkv, N, ps, hd), P), the form PERF.md
+# section 6 (PR 26) records from the chip.
+_CALIBRATION = {
+    "xl.resident_16x1": ((16, 1, 25, 64), (25, 385, 16, 64), 64, "pool"),
+    "xl.prefill_batch_4x128": ((4, 128, 25, 64), (25, 385, 16, 64), 64,
+                               "gather"),
+    "xl.prefill_cont_1x128": ((1, 128, 25, 64), (25, 385, 16, 64), 64,
+                              "gather"),
+    "xl.spec_16x4": ((16, 4, 25, 64), (25, 385, 16, 64), 64, "pool"),
+    "small.resident_64x1": ((64, 1, 12, 64), (12, 3073, 16, 64), 64,
+                            "pool"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_CALIBRATION))
+def test_chunk_form_rule_at_calibration_shapes(name):
+    """The rule alone: the five shapes it was calibrated on give the
+    forms the chip found faster (PERF.md section 6, PR 26)."""
+    from distributed_training_tpu.ops.paged_attention import (
+        chunk_form)
+
+    q_shape, pool_shape, P, want = _CALIBRATION[name]
+    assert chunk_form(q_shape, pool_shape, (q_shape[0], P), 2) == want
+
+
+def test_observe_forms_sees_the_form_when_traced_not_when_run():
+    """``observe_forms`` collects at trace time: a cached program's
+    second call is seen by nobody."""
+    from distributed_training_tpu.ops import paged_attention as pa
+
+    args, _k, _v = _paged_chunk_case(1, 1, jnp.float32)
+    want = pa.chunk_form(args[0].shape, args[1].shape, args[3].shape,
+                         4)
+    fn = jax.jit(pa.paged_attention_chunk)
+    with pa.observe_forms() as seen:
+        fn(*args)
+    assert seen == [want]
+    with pa.observe_forms() as again:
+        fn(*args)
+    assert again == []
+    assert pa._observers == []
+
+
 # ---------------------------------------------------------------------------
 # allocator accounting
 # ---------------------------------------------------------------------------
@@ -1716,6 +1864,67 @@ def test_resident_metrics_gauges(tiny_model, tmp_path):
     finally:
         uninstall()
         tel.close()
+
+
+@pytest.mark.parametrize("over, programs", [
+    (dict(resident_k=4, spec_k=2),
+     ["serving_resident_decode", "serving_prefill_batch",
+      "serving_cow"]),
+    (dict(spec_k=2),
+     ["serving_spec_decode", "serving_prefill_batch", "serving_cow"]),
+    (dict(prefill_mode="sequential", prefix_sharing=False),
+     ["serving_decode", "serving_prefill_first",
+      "serving_prefill_cont"]),
+], ids=["resident", "spec", "per_token_sequential"])
+def test_engine_reports_paged_form_per_program(tiny_model, tmp_path,
+                                               over, programs):
+    """``Engine.paged_forms`` and the ``serving_warmup`` record name
+    the form every compiled program took, under the programs' trace
+    names: what the rule gives for the shapes the program hands
+    ``paged_attention_chunk``, ``None`` where a program reads no pool
+    through it. ``compile_counts`` keeps its keys."""
+    from distributed_training_tpu.ops.paged_attention import (
+        chunk_form)
+    from distributed_training_tpu.telemetry import (
+        Telemetry, install, uninstall)
+
+    model, params = tiny_model
+    c = model.cfg
+    records: list = []
+    tel = install(Telemetry(events_jsonl=str(tmp_path / "ev.jsonl")))
+    tel.add_observer(records.append)
+    try:
+        eng = _engine(model, params, **over)
+        assert eng.paged_forms() == {}      # nothing traced yet
+        counts = eng.warmup()
+    finally:
+        uninstall()
+        tel.close()
+    forms = eng.paged_forms()
+    assert list(forms) == programs
+    assert set(counts) == {p.removeprefix("serving_").replace(
+        "resident_decode", "decode").replace("spec_decode", "decode")
+        for p in programs}
+
+    def rule(B, S):
+        ec = eng.cfg
+        return chunk_form(
+            (B, S, c.n_heads, c.head_dim),
+            (c.n_kv_heads, ec.num_pages, ec.page_size, c.head_dim),
+            (B, ec.max_seq_len // ec.page_size), 4)
+
+    want = {"serving_resident_decode": rule(4, eng.cfg.spec_k),
+            "serving_spec_decode": rule(4, eng.cfg.spec_k),
+            "serving_decode": rule(4, 1),
+            "serving_prefill_batch": rule(eng.prefill_local,
+                                          eng.cfg.prefill_chunk),
+            "serving_prefill_cont": rule(1, eng.cfg.prefill_chunk),
+            "serving_prefill_first": None, "serving_cow": None}
+    assert forms == {p: want[p] for p in programs}
+    warm = [r for r in records if r["kind"] == "serving_warmup"]
+    assert len(warm) == 1
+    assert warm[0]["programs"] == [
+        {"program": p, "paged_form": f} for p, f in forms.items()]
 
 
 def test_serving_r04_ledger_committed_and_coherent():
