@@ -190,7 +190,7 @@ def main(argv: list[str] | None = None) -> int:
         raise ValueError("empty prompt")
 
     paged = (args.decode == "paged" and args.temperature <= 0
-             and hasattr(model, "prefill")
+             and hasattr(model, "serving_block")
              and getattr(model.cfg, "moe_num_experts", 0) == 0)
     if paged:
         # The serving decode path: a one-slot continuous-batching

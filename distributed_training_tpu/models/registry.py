@@ -25,6 +25,11 @@ def build_model(name: str, loss: str = "auto", dtype: str = "float32",
             build_transformer,
         )
         return build_transformer(name, loss=loss, dtype=dtype, **kwargs)
+    if name == "latent_moe":
+        from distributed_training_tpu.models.latent_moe import (
+            build_latent_moe,
+        )
+        return build_latent_moe(loss=loss, dtype=dtype, **kwargs)
     if name in ("resnet", "resnet18"):
         from distributed_training_tpu.models.resnet import ResNet
         return ResNet(dtype=dtype, **kwargs)

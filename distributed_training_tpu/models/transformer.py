@@ -645,6 +645,12 @@ class Transformer:
                                      block_k=c.flash_block_k,
                                      window=window, layout=layout)
 
+    def serving_block(self):
+        """This model's side of the serving engine's programs
+        (serving/blocks.py)."""
+        from distributed_training_tpu.serving.blocks import DenseBlock
+        return DenseBlock(self.cfg)
+
     # -- init --------------------------------------------------------------
 
     def init(self, rng: jax.Array):

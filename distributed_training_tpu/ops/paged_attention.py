@@ -16,7 +16,11 @@ join/evict. This module is the attention math over that layout:
   position ``p`` is slot ``p % page_size`` of logical page
   ``p // page_size``.
 
-Two entrypoints:
+A third entrypoint, ``latent_attention_chunk``, attends over a LATENT
+cache (one shared row a token instead of keys and values a head), in an
+absorbed or an expanded form that ``latent_form`` takes from the shapes.
+
+Two entrypoints over keys and values:
 
 - ``paged_attention`` — single-token decode: one query per sequence
   against its pages. Dispatches to the TPU Pallas kernel when
@@ -283,6 +287,91 @@ def paged_attention_chunk(q: jax.Array, k_pages: jax.Array,
     _took(form)
     attend = _pool_attention if form == "pool" else _gather_attention
     return attend(q, k_pages, v_pages, page_indices, q_positions)
+
+
+# How many of its own multiply-adds ((nope + v) * rank a gathered row a
+# head) the expansion of the latent rows is worth on a v5e before the
+# queries of one call repay it: fitted to one layer's call at the
+# published widths (benchmarks/latent_form_table.py; PERF.md section 6).
+_EXPAND_COST = 2.5
+
+
+def latent_form(q_shape, dims) -> str:
+    """``"expanded"`` or ``"absorbed"`` for ``latent_attention_chunk``
+    at q ``(B, S, H)`` and widths ``dims = (rank, nope, v)``. Both
+    forms gather the same rows. Expanding turns each into ``H`` keys
+    and values (``(nope + v) * rank`` multiply-adds a row a head), after
+    which a query-key pair costs ``nope + v``; absorbed it costs ``2 *
+    rank``. So the expansion pays where queries are many (prompt
+    chunks) and never at decode. On the chip the crossing goes with the
+    queries of the whole call, ``B * S``, not of one sequence (the table:
+    1 x 1024 is a draw, 4 x 256 expands a third faster)."""
+    B, S, _H = q_shape
+    rank, nope, v = dims
+    return ("expanded"
+            if B * S * (2 * rank - nope - v)
+            > _EXPAND_COST * (nope + v) * rank else "absorbed")
+
+
+def latent_attention_chunk(q_nope: jax.Array, q_rope: jax.Array,
+                           c_pages: jax.Array, r_pages: jax.Array,
+                           page_indices: jax.Array,
+                           q_positions: jax.Array, w_uk: jax.Array,
+                           w_uv: jax.Array) -> jax.Array:
+    """Multi-query attention over a LATENT paged cache.
+
+    q_nope (B, S, H, nope), q_rope (B, S, H, rope), RoPE applied;
+    c_pages (1, N, ps, rank): a token's latent row after its norm;
+    r_pages (1, N, ps, rope): its rotary key after RoPE, one for all
+    heads; page_indices (B, P); q_positions (B, S) as in
+    ``paged_attention_chunk``; w_uk (rank, H, nope) and w_uv
+    (rank, H, v) expand a latent row into a head's key and value.
+    Scores are ``(q_nope . k_nope + q_rope . k_rope) / sqrt(nope +
+    rope)``, float32 like the softmax. Returns (B, S, H, v).
+
+    Each sequence's rows are gathered dense in logical order, as
+    ``_gather_attention`` does. *Absorbed*: ``q_nope . (W_uk c) =
+    (W_uk^T q_nope) . c``, so the query is folded into the latent
+    width, every head scores the one shared row, the weighted sum is of
+    latent rows and ``W_uv`` is applied to it: no key or value a head is
+    ever made. *Expanded*: the gathered rows are expanded into keys and
+    values and attended as ordinary heads. Same mathematics, rounded in
+    another order; ``latent_form`` takes one from the shapes."""
+    B, S, H, nope = q_nope.shape
+    rope, v = q_rope.shape[-1], w_uv.shape[-1]
+    form = latent_form((B, S, H), (c_pages.shape[-1], nope, v))
+    _took(form)
+    f32 = jnp.float32
+    cd = _gather_pages(c_pages, page_indices)[:, :, 0]    # (B, Sk, rank)
+    rd = _gather_pages(r_pages, page_indices)[:, :, 0]    # (B, Sk, rope)
+    slot = jnp.arange(cd.shape[1], dtype=jnp.int32)
+    visible = ((slot[None, None, :] <= q_positions[:, :, None])
+               & (q_positions[:, :, None] >= 0))[:, None]
+    scores = jnp.einsum("bshe,bke->bhsk", q_rope, rd,
+                        preferred_element_type=f32)
+    if form == "expanded":
+        scores = scores + jnp.einsum(
+            "bshn,bkhn->bhsk", q_nope,
+            jnp.einsum("bkr,rhn->bkhn", cd, w_uk),
+            preferred_element_type=f32)
+    else:
+        scores = scores + jnp.einsum(
+            "bshr,bkr->bhsk",
+            jnp.einsum("bshn,rhn->bshr", q_nope, w_uk), cd,
+            preferred_element_type=f32)
+    probs = _masked_softmax(scores * (nope + rope) ** -0.5,
+                            visible).astype(cd.dtype)
+    if form == "expanded":
+        return jnp.einsum("bhsk,bkhv->bshv", probs,
+                          jnp.einsum("bkr,rhv->bkhv", cd, w_uv),
+                          preferred_element_type=f32
+                          ).astype(q_nope.dtype)
+    # The heads stay where the softmax left them: with them moved inside
+    # this einsum's own output, XLA's CPU runtime has no bfloat16 dot to
+    # run it with.
+    ctx = jnp.einsum("bhsk,bkr->bhsr", probs, cd,
+                     preferred_element_type=f32).astype(q_nope.dtype)
+    return jnp.einsum("bhsr,rhv->bshv", ctx, w_uv)
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array,

@@ -1,0 +1,207 @@
+"""The block interface: what the engine's programs take from a model.
+
+``serving/engine.py`` owns the programs (one token a slot, one prompt
+chunk, a lane table of chunks, the resident loop), the page
+coordinates, the scatter into the pool and the sampling. What happens
+to a token between the embedding and the logits is the model's, behind
+the calls below. A model hands its block over with
+``model.serving_block()``; the engine asks for nothing else of it.
+
+A block has
+
+- ``cache``: the fields of ``PagedCacheConfig`` that the model decides
+  (``n_layers``, ``n_kv_heads``, ``head_dim``, ``v_head_dim``,
+  ``kind``). The pool is always two arrays ``k_pages`` / ``v_pages`` of
+  ``(G, L, n_kv_heads, N, ps, width)``; what a row of each holds is the
+  block's business (keys and values a head for ``DenseBlock``; the
+  latent row and the rotary key for ``models/latent_moe.py``);
+- ``counters``: names of the int32 sums ``finish`` returns a layer
+  (``()`` for a block that counts nothing). The programs add them up
+  over layers and iterations and return them beside the tokens, so they
+  ride the fetch the tokens ride (step-record fields of these names);
+- ``embed(params, tokens, positions)`` -> ``x (..., D)``;
+- ``segments(params)`` -> the stacked layer parameters in layer order,
+  one pytree a run of like layers (leading axis = layers). The engine
+  scans each run with the pool's matching layers;
+- ``project(layer, x, positions)`` -> ``(q, k_new, v_new)``: the
+  layer's normed input projected; ``k_new`` / ``v_new``
+  ``(..., n_kv_heads, width)`` are the rows this token adds to the two
+  pools, ``q`` whatever the block's ``attend_*`` want;
+- ``attend_decode(layer, q, kp, vp, lengths, page_tables, impl)``: one
+  query a sequence against its pages (``kp`` / ``vp`` already hold the
+  token's own row);
+- ``attend_first(layer, q, k_new, v_new)``: causal attention of a
+  prompt's first chunk over itself, no pool read;
+- ``attend_chunk(layer, q, kp, vp, page_rows, q_pos)``: ``S`` lanes of
+  ``C`` queries, each against its sequence's pages at positions up to
+  its own;
+- ``finish(layer, x, attn, valid)`` -> ``(x, counts)``: the output
+  projection, the residuals and the feed-forward; ``valid`` marks the
+  rows that are real tokens (for counters only);
+- ``logits(params, x)`` -> float32 logits of the final hidden states.
+
+Leading shapes are free: ``(B,)`` rows in the decode program, ``(C,)``
+in a prompt chunk, ``(S, C)`` in the lane table.
+"""
+
+from __future__ import annotations
+
+
+def rope_bhd(x, positions):
+    """RoPE on (..., H, hd) with per-row absolute positions (...) —
+    the same freqs/rotation as models.transformer._rope (parity with
+    the training stack is load-bearing: drift here is silent output
+    corruption, caught by the paged⇄dense test). The leading shape is
+    free: (B,) rows for the one-token decode, (S, C) lanes×positions
+    for the batched chunk program."""
+    import jax.numpy as jnp
+
+    D = x.shape[-1]
+    half = D // 2
+    freqs = 1.0 / (10000 ** (jnp.arange(half, dtype=jnp.float32)
+                             / half))
+    angles = positions[..., None].astype(jnp.float32) * freqs
+    cos = jnp.cos(angles)[..., None, :]
+    sin = jnp.sin(angles)[..., None, :]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin,
+                            x1 * sin + x2 * cos],
+                           axis=-1).astype(x.dtype)
+
+
+def layer_norm(x, scale, bias):
+    import jax
+    import jax.numpy as jnp
+
+    dtype = x.dtype
+    x = x.astype(jnp.float32)
+    mu = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
+    y = (x - mu) * jax.lax.rsqrt(var + 1e-5)
+    return (y * scale + bias).astype(dtype)
+
+
+def weight(leaf, dt):
+    """A weight leaf in compute dtype. An int8 weight-only leaf is a
+    dict ``{"qw": int8, "scale": fp32}`` with per-output-channel
+    scales (serving/disagg.py ``quantize_params_int8``) and is
+    DEQUANTIZED AT COMPUTE — the stored layout (and its tp/fsdp
+    partition specs) stays int8; plain arrays cast exactly as
+    before. Every weight einsum in the serving programs reads its
+    operand through this one helper so the fp32 and int8 paths
+    cannot drift."""
+    if isinstance(leaf, dict):
+        return leaf["qw"].astype(dt) * leaf["scale"].astype(dt)
+    return leaf.astype(dt)
+
+
+class DenseBlock:
+    """GPT-2's block (``models/transformer.py::Transformer``):
+    LayerNorm with bias, one key and one value a kv head in the pool,
+    learned or rotary positions, a GELU MLP or, with
+    ``moe_impl="dense"``, experts that every token passes through."""
+
+    counters: tuple = ()
+
+    def __init__(self, cfg):
+        if cfg.moe_num_experts > 0 and cfg.moe_impl != "dense":
+            raise ValueError(
+                "the serving engine cannot serve moe_impl='routed': "
+                "its experts drop the tokens over moe_capacity_factor "
+                f"({cfg.moe_capacity_factor}) of an even share, and a "
+                "served token may not be dropped (moe_impl='dense' "
+                "computes every expert and is served)")
+        self.cfg = cfg
+        self.cache = dict(n_layers=cfg.n_layers,
+                          n_kv_heads=cfg.n_kv_heads,
+                          head_dim=cfg.head_dim, kind="kv")
+
+    def embed(self, params, tokens, positions):
+        import jax.numpy as jnp
+
+        c = self.cfg
+        x = params["tok_embed"][tokens].astype(jnp.dtype(c.dtype))
+        if c.pos_encoding == "learned":
+            # Clamp padding positions into range; their rows are dead.
+            safe = jnp.minimum(positions, c.max_seq_len - 1)
+            x = x + params["pos_embed"][safe].astype(x.dtype)
+        return x
+
+    def segments(self, params):
+        return ({k: params[k] for k in ("ln1", "ln2", "attn", "mlp")},)
+
+    def project(self, layer, x, positions):
+        import jax.numpy as jnp
+
+        dt = x.dtype
+        a = layer["attn"]
+        h = layer_norm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
+        q = jnp.einsum("...d,dhk->...hk", h, weight(a["wq"], dt))
+        k = jnp.einsum("...d,dhk->...hk", h, weight(a["wk"], dt))
+        v = jnp.einsum("...d,dhk->...hk", h, weight(a["wv"], dt))
+        if self.cfg.pos_encoding == "rope":
+            q = rope_bhd(q, positions)
+            k = rope_bhd(k, positions)
+        return q, k, v
+
+    def attend_decode(self, layer, q, kp, vp, lengths, page_tables,
+                      impl):
+        from distributed_training_tpu.ops.paged_attention import (
+            paged_attention)
+
+        return paged_attention(q, kp, vp, lengths, page_tables,
+                               impl=impl)
+
+    def attend_first(self, layer, q, k_new, v_new):
+        from distributed_training_tpu.ops.attention import (
+            dot_product_attention)
+
+        impl = self.cfg.attention_impl
+        return dot_product_attention(
+            q[None], k_new[None], v_new[None], causal=True,
+            impl=impl if impl in ("auto", "flash", "naive") else "auto",
+            window=0)[0]
+
+    def attend_chunk(self, layer, q, kp, vp, page_rows, q_pos):
+        from distributed_training_tpu.ops.paged_attention import (
+            paged_attention_chunk)
+
+        return paged_attention_chunk(q, kp, vp, page_rows, q_pos)
+
+    def finish(self, layer, x, attn, valid):
+        import jax
+        import jax.numpy as jnp
+
+        del valid
+        c = self.cfg
+        dt = x.dtype
+        x = x + jnp.einsum("...hk,hkd->...d", attn,
+                           weight(layer["attn"]["wo"], dt))
+        h = layer_norm(x, layer["ln2"]["scale"], layer["ln2"]["bias"])
+        m = layer["mlp"]
+        if c.moe_num_experts > 0:
+            from distributed_training_tpu.models.transformer import (
+                _moe_mlp_dense)
+
+            rows = h.reshape(1, -1, h.shape[-1])
+            out, _aux = _moe_mlp_dense(
+                rows, m, c, w=lambda p, d, path=None: weight(p, d))
+            x = x + out.reshape(h.shape)
+        else:
+            u = jax.nn.gelu(jnp.einsum("...d,df->...f", h,
+                                       weight(m["wi"], dt))
+                            + m["bi"].astype(dt))
+            x = x + (jnp.einsum("...f,fd->...d", u, weight(m["wo"], dt))
+                     + m["bo"].astype(dt))
+        return x, jnp.zeros((0,), jnp.int32)
+
+    def logits(self, params, x):
+        import jax.numpy as jnp
+
+        c = self.cfg
+        x = layer_norm(x, params["final_norm"]["scale"],
+                       params["final_norm"]["bias"])
+        head = (params["tok_embed"].T if c.tie_embeddings
+                else params["lm_head"])
+        return jnp.einsum("...d,dv->...v", x,
+                          weight(head, x.dtype)).astype(jnp.float32)

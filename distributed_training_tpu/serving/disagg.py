@@ -320,11 +320,10 @@ def export_kv_batch(cache, seq_ids):
         off += len(p)
         L = cache.cfg.n_layers
         Hkv = cache.cfg.n_kv_heads
-        hd = cache.cfg.head_dim
         k = kseq.transpose(1, 2, 0, 3, 4).reshape(
-            L, Hkv, len(p) * ps, hd)[:, :, :n]
+            L, Hkv, len(p) * ps, cache.cfg.head_dim)[:, :, :n]
         v = vseq.transpose(1, 2, 0, 3, 4).reshape(
-            L, Hkv, len(p) * ps, hd)[:, :, :n]
+            L, Hkv, len(p) * ps, cache.cfg.v_head_dim)[:, :, :n]
         ks.append(k)
         vs.append(v)
     return ks, vs
@@ -371,7 +370,8 @@ def import_kv_batch(cache, items) -> None:
             lo, hi = j * ps, min((j + 1) * ps, n)
             kc = np.zeros((k.shape[0], k.shape[1], ps, k.shape[3]),
                           k.dtype)
-            vc = kc.copy()
+            vc = np.zeros((v.shape[0], v.shape[1], ps, v.shape[3]),
+                          v.dtype)
             kc[:, :, :hi - lo] = k[:, :, lo:hi]
             vc[:, :, :hi - lo] = v[:, :, lo:hi]
             groups.append(g)
@@ -678,7 +678,7 @@ def lower_serving_program(plan, objective: str):
             plan, spec_k=4 if resident else 1,
             resident_k=4 if resident else 1),
         paged_impl="ref")
-    c = model.cfg
+    c = model.serving_block()
     params_shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     if plan.inputs.get("quant", "none") == "int8":
         params_shapes = _quantize_struct(params_shapes)
@@ -694,8 +694,9 @@ def lower_serving_program(plan, objective: str):
     B = ecfg.max_batch // G
     pool_shard = NamedSharding(mesh, P(dp_ax, None, kv_ax))
     pool = jax.ShapeDtypeStruct(
-        (G, c.n_layers, c.n_kv_heads, ecfg.num_pages, ecfg.page_size,
-         c.head_dim), jnp.dtype(c.dtype), sharding=pool_shard)
+        (G, model.cfg.n_layers, model.cfg.n_kv_heads, ecfg.num_pages,
+         ecfg.page_size, model.cfg.head_dim),
+        jnp.dtype(model.cfg.dtype), sharding=pool_shard)
     rep = NamedSharding(mesh, P())
     grp = NamedSharding(mesh, P(dp_ax))
     Ppages = -(-ecfg.max_seq_len // ecfg.page_size)

@@ -76,8 +76,11 @@ sampled — the HTTP server's chunked ``"stream": true`` path rides
 this (serving/server.py); listener failures are isolated from the
 step loop.
 
-MoE models are rejected at construction: expert dispatch has no
-serving decode path yet.
+What a token passes through between its embedding and its logits is
+the MODEL's: every program calls the block that ``model.serving_block()``
+hands over (``serving/blocks.py``: project, then the engine writes the
+cache, attend, finish), and the pool's row widths come from it. A
+model whose experts drop tokens has no block and is refused there.
 """
 
 from __future__ import annotations
@@ -97,7 +100,6 @@ from distributed_training_tpu.telemetry import event, phase
 
 logger = logging.getLogger(__name__)
 
-_STACKED = ("ln1", "ln2", "attn", "mlp")
 # The parts of a step, in the order a launch passes them: the keys of
 # a step record's ``phase_s`` and the ``serving.<part>`` annotations.
 _PHASES = ("admit", "pack", "launch", "fetch", "emit")
@@ -262,54 +264,6 @@ class _Seq:
             len(self.generated) >= self.req.max_new_tokens
 
 
-def _rope_bhd(x, positions):
-    """RoPE on (..., H, hd) with per-row absolute positions (...) —
-    the same freqs/rotation as models.transformer._rope (parity with
-    the training stack is load-bearing: drift here is silent output
-    corruption, caught by the paged⇄dense test). The leading shape is
-    free: (B,) rows for the one-token decode, (S, C) lanes×positions
-    for the batched chunk program."""
-    import jax.numpy as jnp
-
-    D = x.shape[-1]
-    half = D // 2
-    freqs = 1.0 / (10000 ** (jnp.arange(half, dtype=jnp.float32)
-                             / half))
-    angles = positions[..., None].astype(jnp.float32) * freqs
-    cos = jnp.cos(angles)[..., None, :]
-    sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin,
-                            x1 * sin + x2 * cos],
-                           axis=-1).astype(x.dtype)
-
-
-def _layer_norm(x, scale, bias):
-    import jax
-    import jax.numpy as jnp
-
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    mu = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
-    y = (x - mu) * jax.lax.rsqrt(var + 1e-5)
-    return (y * scale + bias).astype(dtype)
-
-
-def _w(leaf, dt):
-    """A weight leaf in compute dtype. An int8 weight-only leaf is a
-    dict ``{"qw": int8, "scale": fp32}`` with per-output-channel
-    scales (serving/disagg.py ``quantize_params_int8``) and is
-    DEQUANTIZED AT COMPUTE — the stored layout (and its tp/fsdp
-    partition specs) stays int8; plain arrays cast exactly as
-    before. Every weight einsum in the serving programs reads its
-    operand through this one helper so the fp32 and int8 paths
-    cannot drift."""
-    if isinstance(leaf, dict):
-        return leaf["qw"].astype(dt) * leaf["scale"].astype(dt)
-    return leaf.astype(dt)
-
-
 def draft_tokens(history: np.ndarray, m: int,
                  ngram_max: int = 3) -> np.ndarray:
     """Prompt-lookup drafting: ``m`` speculative tokens from the
@@ -446,7 +400,7 @@ def _sharded(body, mesh, dp_axis: str, n_grouped: int,
         axis_names={dp_axis}, check_vma=False)
 
 
-def _out_shardings(model_cfg, ecfg: EngineConfig, mesh):
+def _out_shardings(block, ecfg: EngineConfig, mesh):
     """(per-group result sharding, pool sharding) for the jitted
     programs' ``out_shardings``. Pinning these is load-bearing:
     shard_map's out_specs only fix the MANUAL dp axis, so without an
@@ -461,7 +415,7 @@ def _out_shardings(model_cfg, ecfg: EngineConfig, mesh):
     if mesh is None:
         return None, None
     G = _dp_extent(mesh, ecfg.dp_axis)
-    pool = pool_sharding(mesh, model_cfg.n_kv_heads, G,
+    pool = pool_sharding(mesh, block.cache["n_kv_heads"], G,
                          ecfg.kv_axis, ecfg.dp_axis)
     grp = NamedSharding(mesh, P(ecfg.dp_axis if G > 1 else None))
     return grp, pool
@@ -488,62 +442,65 @@ def _named(name: str, body):
     return program
 
 
-def build_decode_fn(model_cfg, ecfg: EngineConfig, mesh=None):
-    """The jitted dp-sharded decode program for (model, engine cfg,
-    mesh). Signature (all group-batched, G = dp extent, B = group-
-    local slots): ``fn(params, k_pages, v_pages, tokens (G, B),
-    positions (G, B), page_tables (G, B, P), active (G, B), rng_data
-    (G, 2)) -> (next_tokens (G, B), k_pages, v_pages)``. Pools are
-    donated (serving HBM's dominant term must not hold two copies)."""
+def build_decode_fn(block, ecfg: EngineConfig, mesh=None):
+    """The jitted dp-sharded decode program for (the model's block,
+    engine cfg, mesh). Signature (all group-batched, G = dp extent,
+    B = group-local slots): ``fn(params, k_pages, v_pages, tokens
+    (G, B), positions (G, B), page_tables (G, B, P), active (G, B),
+    rng_data (G, 2)) -> (next_tokens (G, B), counts (G, n), k_pages,
+    v_pages)``; ``counts`` are the block's ``counters`` summed over the
+    launch (n = 0 for a block that counts nothing), in every program.
+    Pools are donated (serving HBM's dominant term must not hold two
+    copies)."""
     import functools
 
     import jax
 
     body = functools.partial(
-        _decode_program, cfg=model_cfg,
+        _decode_program, block=block,
         temperature=ecfg.temperature, top_k=ecfg.top_k,
         paged_impl=ecfg.paged_impl)
     kw = {}
     if mesh is not None:
-        grp, pool = _out_shardings(model_cfg, ecfg, mesh)
-        kw["out_shardings"] = (grp, pool, pool)
+        grp, pool = _out_shardings(block, ecfg, mesh)
+        kw["out_shardings"] = (grp, grp, pool, pool)
     if _dp_extent(mesh, ecfg.dp_axis) > 1:
         body = _sharded(body, mesh, ecfg.dp_axis,
-                        n_grouped=7, n_replicated=0, n_outs=3)
+                        n_grouped=7, n_replicated=0, n_outs=4)
     return jax.jit(_named("serving_decode", body),
                    donate_argnums=(1, 2), **kw)
 
 
-def build_prefill_fn(model_cfg, ecfg: EngineConfig, first: bool,
+def build_prefill_fn(block, ecfg: EngineConfig, first: bool,
                      mesh=None):
     """The jitted prefill program (first or continuation chunk).
     Signature: ``fn(params, k_pages, v_pages, page_row (G, P),
     live (G,), chunk (1, C), start_pos, n_valid) -> (logits (G, V),
-    k_pages, v_pages)``. The chunk is replicated across groups; only
-    the ``live`` group's pool shard takes real writes (the rest land
-    in scratch) and only its logits row is meaningful for
-    continuation chunks."""
+    counts (G, n), k_pages, v_pages)``. The chunk is replicated across
+    groups; only the ``live`` group's pool shard takes real writes
+    (the rest land in scratch) and only its logits row is meaningful
+    for continuation chunks."""
     import functools
 
     import jax
 
     body = functools.partial(
-        _prefill_program, cfg=model_cfg, first=first,
+        _prefill_program, block=block, first=first,
         paged_impl=ecfg.paged_impl)
     kw = {}
     if mesh is not None:
-        grp, pool = _out_shardings(model_cfg, ecfg, mesh)
-        kw["out_shardings"] = (grp, pool, pool)
+        grp, pool = _out_shardings(block, ecfg, mesh)
+        kw["out_shardings"] = (grp, grp, pool, pool)
     if _dp_extent(mesh, ecfg.dp_axis) > 1:
         body = _sharded(body, mesh, ecfg.dp_axis,
-                        n_grouped=4, n_replicated=3, n_outs=3)
+                        n_grouped=4, n_replicated=3, n_outs=4)
     return jax.jit(
         _named("serving_prefill_first" if first
                else "serving_prefill_cont", body),
         donate_argnums=(1, 2), **kw)
 
 
-def _chunk_fn(model_cfg, ecfg: EngineConfig, emit: str, name: str,
+def _chunk_fn(block, ecfg: EngineConfig, emit: str, name: str,
               mesh=None):
     """Jit the multi-lane chunk program (``_chunk_program``) for
     (model, engine cfg, mesh) as ``jit_<name>``. Signature (all
@@ -551,8 +508,9 @@ def _chunk_fn(model_cfg, ecfg: EngineConfig, emit: str, name: str,
     lane):
     ``fn(params, k_pages, v_pages, page_rows (G, S, P),
     tokens (G, S, C), start_pos (G, S), n_valid (G, S),
-    active (G, S), rng_data (G, 2)) -> (next_tokens, k_pages,
-    v_pages)`` where next_tokens is (G, S) for ``emit="last"`` (the
+    active (G, S), rng_data (G, 2)) -> (next_tokens, counts (G, n),
+    k_pages, v_pages)`` where next_tokens is (G, S) for
+    ``emit="last"`` (the
     batched-prefill first-token sample) and (G, S, C) for
     ``emit="all"`` (the speculative verification chain). Pools are
     donated."""
@@ -561,41 +519,41 @@ def _chunk_fn(model_cfg, ecfg: EngineConfig, emit: str, name: str,
     import jax
 
     body = functools.partial(
-        _chunk_program, cfg=model_cfg,
+        _chunk_program, block=block,
         temperature=ecfg.temperature, top_k=ecfg.top_k,
         paged_impl=ecfg.paged_impl, emit=emit)
     kw = {}
     if mesh is not None:
-        grp, pool = _out_shardings(model_cfg, ecfg, mesh)
-        kw["out_shardings"] = (grp, pool, pool)
+        grp, pool = _out_shardings(block, ecfg, mesh)
+        kw["out_shardings"] = (grp, grp, pool, pool)
     if _dp_extent(mesh, ecfg.dp_axis) > 1:
         body = _sharded(body, mesh, ecfg.dp_axis,
-                        n_grouped=8, n_replicated=0, n_outs=3)
+                        n_grouped=8, n_replicated=0, n_outs=4)
     return jax.jit(_named(name, body), donate_argnums=(1, 2), **kw)
 
 
-def build_prefill_batch_fn(model_cfg, ecfg: EngineConfig, mesh=None):
+def build_prefill_batch_fn(block, ecfg: EngineConfig, mesh=None):
     """The jitted BATCHED multi-sequence prefill program: up to
     ``prefill_slots/dp`` prompt chunks per group in one launch, each
     lane writing its chunk's KV through the batched page-row scatter
     and sampling its next token in-program (the first token of every
     prompt-completing lane — read as one (G, S) int32 block, never a
     vocab-sized logits transfer)."""
-    return _chunk_fn(model_cfg, ecfg, emit="last",
+    return _chunk_fn(block, ecfg, emit="last",
                      name="serving_prefill_batch", mesh=mesh)
 
 
-def build_spec_decode_fn(model_cfg, ecfg: EngineConfig, mesh=None):
+def build_spec_decode_fn(block, ecfg: EngineConfig, mesh=None):
     """The jitted MULTI-TOKEN speculative decode program: ``spec_k``
     tokens per slot across the whole dealt slot table in one launch —
     lane c's argmax is the verified next token GIVEN the drafted
     prefix, so the host accepts exactly the prefix whose drafts match
     the chain (greedy-token-identical by construction)."""
-    return _chunk_fn(model_cfg, ecfg, emit="all",
+    return _chunk_fn(block, ecfg, emit="all",
                      name="serving_spec_decode", mesh=mesh)
 
 
-def build_resident_decode_fn(model_cfg, ecfg: EngineConfig,
+def build_resident_decode_fn(block, ecfg: EngineConfig,
                              mesh=None):
     """The jitted DEVICE-RESIDENT decode program: a
     ``lax.while_loop`` of up to ``resident_k`` chunk iterations
@@ -608,31 +566,32 @@ def build_resident_decode_fn(model_cfg, ecfg: EngineConfig,
     slots, Lmax = max_seq_len, T = resident_k * spec_k):
     ``fn(params, k_pages, v_pages, page_rows (G, B, P), history
     (G, B, Lmax), kv_len (G, B), budget (G, B), active (G, B)) ->
-    (out (G, B, T), n_emitted (G, B), steps (G,), k_pages,
-    v_pages)``. Pools are donated. An all-slots-complete burst
+    (out (G, B, T), n_emitted (G, B), steps (G,), counts (G, n),
+    k_pages, v_pages)``. Pools are donated. An all-slots-complete
+    burst
     returns early via the loop predicate."""
     import functools
 
     import jax
 
     body = functools.partial(
-        _resident_program, cfg=model_cfg, K=ecfg.resident_k,
+        _resident_program, block=block, K=ecfg.resident_k,
         C=ecfg.spec_k, ngram=ecfg.spec_ngram, eos_id=ecfg.eos_id,
         paged_impl=ecfg.paged_impl)
     kw = {}
     if mesh is not None:
-        grp, pool = _out_shardings(model_cfg, ecfg, mesh)
-        kw["out_shardings"] = (grp, grp, grp, pool, pool)
+        grp, pool = _out_shardings(block, ecfg, mesh)
+        kw["out_shardings"] = (grp, grp, grp, grp, pool, pool)
     if _dp_extent(mesh, ecfg.dp_axis) > 1:
         body = _sharded(body, mesh, ecfg.dp_axis,
-                        n_grouped=7, n_replicated=0, n_outs=5)
+                        n_grouped=7, n_replicated=0, n_outs=6)
     return jax.jit(_named("serving_resident_decode", body),
                    donate_argnums=(1, 2), **kw)
 
 
 def _cow_program(k_pages, v_pages, src, dst):
     """Copy-on-write page copy for one dp group's pool shard:
-    ``k/v_pages`` (1, L, Hkv, N, ps, hd), ``src``/``dst`` (1, W)
+    ``k/v_pages`` (1, L, Hkv, N, ps, width), ``src``/``dst`` (1, W)
     int32 page ids. One batched gather + scatter per pool — W page
     copies in ONE launch, no per-token host sync, zero collectives
     (pages never cross a group shard). Unused lanes ride as
@@ -647,7 +606,7 @@ def _cow_program(k_pages, v_pages, src, dst):
     return copy(k_pages), copy(v_pages)
 
 
-def build_cow_fn(model_cfg, ecfg: EngineConfig, mesh=None):
+def build_cow_fn(block, ecfg: EngineConfig, mesh=None):
     """The jitted COW page-copy program. Signature:
     ``fn(k_pages, v_pages, src (G, W), dst (G, W)) -> (k_pages,
     v_pages)`` — pools donated (the copy must not double the serving
@@ -658,7 +617,7 @@ def build_cow_fn(model_cfg, ecfg: EngineConfig, mesh=None):
     body = _cow_program
     kw = {}
     if mesh is not None:
-        _grp, pool = _out_shardings(model_cfg, ecfg, mesh)
+        _grp, pool = _out_shardings(block, ecfg, mesh)
         kw["out_shardings"] = (pool, pool)
     if _dp_extent(mesh, ecfg.dp_axis) > 1:
         from jax import shard_map
@@ -691,10 +650,10 @@ class Engine:
                  weights_provenance: dict | None = None):
         import jax
 
-        if getattr(model.cfg, "moe_num_experts", 0) > 0:
-            raise ValueError(
-                "serving engine has no MoE decode path (expert "
-                "dispatch per single token is unimplemented)")
+        # The model's side of every program (serving/blocks.py). A
+        # model that cannot be served (experts that drop tokens) is
+        # refused by its own ``serving_block``.
+        self.block = model.serving_block()
         if cfg.max_seq_len > model.cfg.max_seq_len:
             raise ValueError(
                 f"engine max_seq_len ({cfg.max_seq_len}) exceeds the "
@@ -761,9 +720,7 @@ class Engine:
             for x in jax.tree.leaves(params)))
         self.cache = PagedKVCache(
             PagedCacheConfig(
-                n_layers=model.cfg.n_layers,
-                n_kv_heads=model.cfg.n_kv_heads,
-                head_dim=model.cfg.head_dim,
+                **self.block.cache,
                 page_size=cfg.page_size,
                 num_pages=cfg.num_pages,
                 max_seq_len=cfg.max_seq_len,
@@ -805,7 +762,7 @@ class Engine:
     # -- jitted programs ---------------------------------------------------
 
     def _build_programs(self) -> None:
-        c = self.model.cfg
+        block = self.block
         if self.cfg.resident_k > 1:
             # The device-resident K-step loop IS the decode program:
             # each loop iteration is one spec_k-wide chunk (spec_k=1
@@ -813,26 +770,27 @@ class Engine:
             # composes inside the burst. One jit entry, one sync per
             # burst.
             self._decode_fn = build_resident_decode_fn(
-                c, self.cfg, self.mesh)
+                block, self.cfg, self.mesh)
         elif self.cfg.spec_k > 1:
             # Multi-token decode IS the chunk program at C = spec_k
             # (even an effective one-token launch — pages tight, or
             # one token remaining — rides it with n_valid = 1: one
             # program, one jit entry, zero recompiles).
-            self._decode_fn = build_spec_decode_fn(c, self.cfg,
+            self._decode_fn = build_spec_decode_fn(block, self.cfg,
                                                    self.mesh)
         else:
-            self._decode_fn = build_decode_fn(c, self.cfg, self.mesh)
+            self._decode_fn = build_decode_fn(block, self.cfg,
+                                              self.mesh)
         if self.cfg.prefill_mode == "batched":
             self._prefill_batch_fn = build_prefill_batch_fn(
-                c, self.cfg, mesh=self.mesh)
+                block, self.cfg, mesh=self.mesh)
         else:
             self._prefill_first_fn = build_prefill_fn(
-                c, self.cfg, first=True, mesh=self.mesh)
+                block, self.cfg, first=True, mesh=self.mesh)
             self._prefill_cont_fn = build_prefill_fn(
-                c, self.cfg, first=False, mesh=self.mesh)
+                block, self.cfg, first=False, mesh=self.mesh)
         if self._sharing:
-            self._cow_fn = build_cow_fn(c, self.cfg, mesh=self.mesh)
+            self._cow_fn = build_cow_fn(block, self.cfg, mesh=self.mesh)
 
     def _programs(self) -> dict:
         """Every jitted program this engine built, by its role."""
@@ -856,9 +814,10 @@ class Engine:
         """``{program: paged_form}`` for every program traced so far,
         under the names the trace shows less ``jit_``
         (``serving_resident_decode``, ``serving_prefill_batch``, ...):
-        ``"pool"``, ``"gather"`` or ``"kernel"``
-        (ops/paged_attention.py), fixed by the shapes when the program
-        was traced; ``None`` for a program that reads no pool
+        ``"pool"``, ``"gather"`` or ``"kernel"``, over a latent cache
+        ``"absorbed"`` or ``"expanded"`` (ops/paged_attention.py),
+        fixed by the shapes when the program was traced; ``None`` for a
+        program that reads no pool
         (``serving_prefill_first``, ``serving_cow``). Read-only."""
         return {fn.__wrapped__.__name__: fn.__wrapped__.paged_form
                 for fn in self._programs().values()
@@ -925,14 +884,17 @@ class Engine:
 
     def warmup(self) -> dict:
         """Compile every program (``_warmup_calls``) and emit one
-        ``serving_warmup`` record with each program's ``paged_form``.
-        Returns compile_counts()."""
+        ``serving_warmup`` record with each program's ``paged_form``,
+        the cache's kind and its bytes a token. Returns
+        compile_counts()."""
         for fn, args in self._warmup_calls():
             *_outs, k, v = fn(*args)
             self.cache.update_pools(k, v)
         event("serving_warmup",
               programs=[{"program": name, "paged_form": form}
-                        for name, form in self.paged_forms().items()])
+                        for name, form in self.paged_forms().items()],
+              cache_kind=self.cache.cfg.kind,
+              cache_bytes_per_token=self.cache.cfg.kv_bytes_per_token())
         return self.compile_counts()
 
     # -- admission ---------------------------------------------------------
@@ -1487,6 +1449,13 @@ class Engine:
         with self._phase("fetch"):
             return tuple(np.asarray(a) for a in arrays)
 
+    def _count(self, counts) -> None:
+        """Add a launch's fetched ``counts`` (G, n), the block's
+        ``counters`` summed in the program, to the step record."""
+        for name, n in zip(self.block.counters, counts.sum(axis=0)):
+            self._step_counts[name] = (
+                self._step_counts.get(name, 0) + int(n))
+
     def _group_row(self, seq_id) -> tuple[np.ndarray, np.ndarray, int]:
         """(G, P) page rows + (G,) live mask for a single sequence:
         the owner group's real row, all-scratch rows elsewhere."""
@@ -1527,10 +1496,10 @@ class Engine:
         with self._phase("launch"):
             # start/n_valid ride as weak-typed scalars: same jit cache
             # entry for every value, no explicit device_put dispatches.
-            logits, k, v = fn(self.params, self.cache.k_pages,
-                              self.cache.v_pages, jnp.asarray(rows),
-                              jnp.asarray(live), jnp.asarray(chunk),
-                              start, n_valid)
+            logits, counts, k, v = fn(
+                self.params, self.cache.k_pages, self.cache.v_pages,
+                jnp.asarray(rows), jnp.asarray(live),
+                jnp.asarray(chunk), start, n_valid)
             self.cache.update_pools(k, v)
             done = start + n_valid >= seq.prompt_len
         if done:
@@ -1542,7 +1511,8 @@ class Engine:
             # sampling).
             with self._phase("launch"):
                 row = logits[g]
-            (lg,) = self._fetch_host(row)
+            lg, counts = self._fetch_host(row, counts)
+            self._count(counts)
         with self._phase("emit"):
             self.cache.advance(seq.req.id, n_valid)
             seq.prefilled = start + n_valid
@@ -1661,7 +1631,7 @@ class Engine:
                 width=Sp)
             rng = self._rng_grouped(1_000_000 + self._step_counter)
         with self._phase("launch"):
-            nxt, k, v = self._prefill_batch_fn(
+            nxt, counts, k, v = self._prefill_batch_fn(
                 self.params, self.cache.k_pages, self.cache.v_pages,
                 jnp.asarray(rows), jnp.asarray(tokens),
                 jnp.asarray(start_pos), jnp.asarray(n_valid),
@@ -1677,7 +1647,8 @@ class Engine:
             # timestamp is taken AFTER this blocking fetch: under
             # async dispatch an earlier clock read would exclude the
             # launch's own compute from TTFT.
-            (fetched,) = self._fetch_host(nxt)
+            fetched, counts = self._fetch_host(nxt, counts)
+            self._count(counts)
             now = time.monotonic()
         with self._phase("emit"):
             total = first_tokens = 0
@@ -1786,13 +1757,14 @@ class Engine:
         with self._phase("pack"):
             rows = self.cache.page_rows_grouped(seq_ids)
         with self._phase("launch"):
-            out, k, v = self._decode_fn(
+            out, counts, k, v = self._decode_fn(
                 self.params, self.cache.k_pages, self.cache.v_pages,
                 jnp.asarray(rows), jnp.asarray(tokens),
                 jnp.asarray(start_pos), jnp.asarray(n_valid),
                 jnp.asarray(active), self._zero_rng)
             self.cache.update_pools(k, v)
-        (out,) = self._fetch_host(out)
+        out, counts = self._fetch_host(out, counts)
+        self._count(counts)
         now = time.monotonic()
         with self._phase("emit"):
             total = 0
@@ -1905,14 +1877,15 @@ class Engine:
         with self._phase("pack"):
             rows = self.cache.page_rows_grouped(seq_ids)
         with self._phase("launch"):
-            out, n_emitted, steps, k, v = self._decode_fn(
+            out, n_emitted, steps, counts, k, v = self._decode_fn(
                 self.params, self.cache.k_pages, self.cache.v_pages,
                 jnp.asarray(rows), jnp.asarray(history),
                 jnp.asarray(kv_len), jnp.asarray(budget),
                 jnp.asarray(active))
             self.cache.update_pools(k, v)
-        out, n_emitted, steps = self._fetch_host(
-            out, n_emitted, steps)
+        out, n_emitted, steps, counts = self._fetch_host(
+            out, n_emitted, steps, counts)
+        self._count(counts)
         now = time.monotonic()
         with self._phase("emit"):
             total = slot_iters = 0
@@ -1989,12 +1962,13 @@ class Engine:
             rows = self.cache.page_rows_grouped(seq_ids)
             rng = self._rng_grouped(self._step_counter)
         with self._phase("launch"):
-            nxt, k, v = self._decode_fn(
+            nxt, counts, k, v = self._decode_fn(
                 self.params, self.cache.k_pages, self.cache.v_pages,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(rows), jnp.asarray(active), rng)
             self.cache.update_pools(k, v)
-        (nxt,) = self._fetch_host(nxt)
+        nxt, counts = self._fetch_host(nxt, counts)
+        self._count(counts)
         now = time.monotonic()
         with self._phase("emit"):
             for s in stepped:
@@ -2471,51 +2445,107 @@ class Engine:
 # ---------------------------------------------------------------------------
 
 
-def _write_kv(k_pages, v_pages, k_new, v_new, page_ids, offsets):
-    """Scatter per-row new KV into the layer's pool.
+def _write_kv(k_pages, v_pages, layer, k_new, v_new, page_ids, offsets):
+    """Scatter per-row new KV into one layer of the group's pool, where
+    the pool lies.
 
-    k_pages/v_pages (Hkv, N, ps, hd); k_new/v_new (B, Hkv, hd);
-    page_ids/offsets (B,) int32 — rows whose write must be dead point
-    at the scratch page (id 0). Live rows never share a (page, slot)
-    pair (pages are owned by exactly one sequence), so scatter order
-    is immaterial; scratch-page collisions write garbage over
-    garbage."""
-    kT = k_new.transpose(1, 0, 2)          # (Hkv, B, hd)
-    vT = v_new.transpose(1, 0, 2)
-    k_pages = k_pages.at[:, page_ids, offsets].set(kT)
-    v_pages = v_pages.at[:, page_ids, offsets].set(vT)
+    k_pages/v_pages (L, Hkv, N, ps, width), each pool its own width;
+    layer () int32; k_new/v_new (B, Hkv, width); page_ids/offsets (B,)
+    int32 — rows whose write must be dead point at the scratch page
+    (id 0). Live rows never share a (page, slot) pair (pages are owned
+    by exactly one sequence), so scatter order is immaterial;
+    scratch-page collisions write garbage over garbage."""
+    k_pages = k_pages.at[layer, :, page_ids, offsets].set(k_new)
+    v_pages = v_pages.at[layer, :, page_ids, offsets].set(v_new)
     return k_pages, v_pages
 
 
-def _decode_program(params, k_pages, v_pages, tokens, positions,
-                    page_tables, active, rng_data, *, cfg,
-                    temperature, top_k, paged_impl):
-    """One token for one dp group's slot table.
-
-    k_pages/v_pages (1, L, Hkv, N, ps, hd) — the group's pool shard;
-    tokens (1, B) int32 — last sampled token per local slot;
-    positions (1, B) — the ABSOLUTE position that token occupies
-    (== kv entries already written); page_tables (1, B, P); active
-    (1, B) bool; rng_data (1, 2) uint32 — the group's folded key.
-    Returns (next_tokens (1, B), k_pages, v_pages). Inactive slots
-    compute garbage into the scratch page and their sampled token
-    is 0.
-    """
+def _scan_layers(block, params, x, k_pages_g, v_pages_g, positions,
+                 page_ids, offsets, valid, attend):
+    """Every layer of the model's block on ``x``, THE layer body of
+    every program: the block projects the layer's input
+    (``positions`` shaped like ``x`` less its width), the new rows go
+    into the layer's pool at ``(page_ids, offsets)`` (shaped like
+    ``positions``: a whole lane table is one scatter, whose live
+    coordinates never collide), ``attend(layer, q, k_new, v_new, kp,
+    vp)`` is the program's own way to the block's attention, and the
+    block finishes the layer; ``valid`` marks real tokens for the
+    block's counters. One ``lax.scan`` a run of like layers
+    (``block.segments``) over the layers' parameters and numbers; the
+    pool (k_pages_g/v_pages_g (L, Hkv, N, ps, width)) is carried whole
+    through all of them, so runs of unlike layers cost no slice and no
+    concatenation of it. Returns ``(x, counts (n,) summed over layers,
+    k_pages_g, v_pages_g)``."""
     import jax
     import jax.numpy as jnp
 
-    from distributed_training_tpu.ops.paged_attention import (
-        paged_attention)
+    def layer_body(carry, inp):
+        x, kg, vg = carry
+        layer, number = inp
+        q, k, v = block.project(layer, x, positions)
+        kg, vg = _write_kv(
+            kg, vg, number,
+            k.reshape((-1,) + k.shape[-2:]).astype(kg.dtype),
+            v.reshape((-1,) + v.shape[-2:]).astype(vg.dtype),
+            page_ids.reshape(-1), offsets.reshape(-1))
+        attn = attend(layer, q, k, v, kg[number], vg[number])
+        x, counts = block.finish(layer, x, attn, valid)
+        return (x, kg, vg), counts
+
+    counts = jnp.zeros((len(block.counters),), jnp.int32)
+    carry, lo = (x, k_pages_g, v_pages_g), 0
+    for run in block.segments(params):
+        hi = lo + jax.tree.leaves(run)[0].shape[0]
+        carry, c = jax.lax.scan(
+            layer_body, carry, (run, jnp.arange(lo, hi, dtype=jnp.int32)))
+        counts = counts + c.sum(axis=0)
+        lo = hi
+    x, k_pages_g, v_pages_g = carry
+    return x, counts, k_pages_g, v_pages_g
+
+
+def _sample(logits, active, rng_data, temperature, top_k):
+    """(n, V) float32 logits -> (n,) int32 tokens: the argmax at
+    temperature 0, else a categorical draw a row from the group's
+    folded key ``rng_data`` (1, 2); rows not ``active`` give 0."""
+    import jax
+    import jax.numpy as jnp
+
+    if temperature <= 0:
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    else:
+        lg = logits / temperature
+        if top_k:
+            kth = jax.lax.top_k(lg, top_k)[0][..., -1:]
+            lg = jnp.where(lg < kth, -jnp.inf, lg)
+        keys = jax.random.split(
+            jax.random.wrap_key_data(rng_data[0]), logits.shape[0])
+        nxt = jax.vmap(jax.random.categorical)(keys, lg).astype(
+            jnp.int32)
+    return jnp.where(active, nxt, 0)
+
+
+def _decode_program(params, k_pages, v_pages, tokens, positions,
+                    page_tables, active, rng_data, *, block,
+                    temperature, top_k, paged_impl):
+    """One token for one dp group's slot table.
+
+    k_pages/v_pages (1, L, Hkv, N, ps, width) — the group's pool
+    shard; tokens (1, B) int32 — last sampled token per local slot;
+    positions (1, B) — the ABSOLUTE position that token occupies
+    (== kv entries already written); page_tables (1, B, P); active
+    (1, B) bool; rng_data (1, 2) uint32 — the group's folded key.
+    Returns (next_tokens (1, B), counts (1, n), k_pages, v_pages).
+    Inactive slots compute garbage into the scratch page and their
+    sampled token is 0.
+    """
+    import jax.numpy as jnp
 
     k_pages_g, v_pages_g = k_pages[0], v_pages[0]
     tokens, positions = tokens[0], positions[0]
     page_tables, active = page_tables[0], active[0]
-    dt = jnp.dtype(cfg.dtype)
-    B = tokens.shape[0]
     ps = k_pages_g.shape[3]
-    x = params["tok_embed"][tokens].astype(dt)            # (B, D)
-    if cfg.pos_encoding == "learned":
-        x = x + params["pos_embed"][positions].astype(dt)
+    x = block.embed(params, tokens, positions)            # (B, D)
     # Dead writes → scratch page 0, offset 0.
     page_ids = jnp.where(
         active,
@@ -2525,66 +2555,22 @@ def _decode_program(params, k_pages, v_pages, tokens, positions,
         0).astype(jnp.int32)
     offsets = jnp.where(active, positions % ps, 0).astype(jnp.int32)
     lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
-    stacked = {k: params[k] for k in _STACKED}
-
-    def layer_body(x, inp):
-        layer, kp, vp = inp
-        h = _layer_norm(x, layer["ln1"]["scale"],
-                        layer["ln1"]["bias"])
-        q = jnp.einsum("bd,dhk->bhk", h,
-                       _w(layer["attn"]["wq"], dt))
-        k = jnp.einsum("bd,dhk->bhk", h,
-                       _w(layer["attn"]["wk"], dt))
-        v = jnp.einsum("bd,dhk->bhk", h,
-                       _w(layer["attn"]["wv"], dt))
-        if cfg.pos_encoding == "rope":
-            q = _rope_bhd(q, positions)
-            k = _rope_bhd(k, positions)
-        kp, vp = _write_kv(kp, vp, k.astype(kp.dtype),
-                           v.astype(vp.dtype), page_ids, offsets)
-        attn = paged_attention(q, kp, vp, lengths, page_tables,
-                               impl=paged_impl)
-        x = x + jnp.einsum("bhk,hkd->bd", attn,
-                           _w(layer["attn"]["wo"], dt))
-        h = _layer_norm(x, layer["ln2"]["scale"],
-                        layer["ln2"]["bias"])
-        m = layer["mlp"]
-        u = jax.nn.gelu(jnp.einsum("bd,df->bf", h,
-                                   _w(m["wi"], dt))
-                        + m["bi"].astype(dt))
-        x = x + (jnp.einsum("bf,fd->bd", u, _w(m["wo"], dt))
-                 + m["bo"].astype(dt))
-        return x, (kp, vp)
-
-    x, (k_pages_g, v_pages_g) = jax.lax.scan(
-        layer_body, x, (stacked, k_pages_g, v_pages_g))
-    x = _layer_norm(x, params["final_norm"]["scale"],
-                    params["final_norm"]["bias"])
-    head = (params["tok_embed"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    logits = jnp.einsum("bd,dv->bv", x,
-                        head.astype(dt)).astype(jnp.float32)
-    if temperature <= 0:
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        lg = logits / temperature
-        if top_k:
-            kth = jax.lax.top_k(lg, top_k)[0][..., -1:]
-            lg = jnp.where(lg < kth, -jnp.inf, lg)
-        keys = jax.random.split(
-            jax.random.wrap_key_data(rng_data[0]), B)
-        nxt = jax.vmap(jax.random.categorical)(keys, lg).astype(
-            jnp.int32)
-    return (jnp.where(active, nxt, 0)[None],
-            k_pages_g[None], v_pages_g[None])
+    x, counts, k_pages_g, v_pages_g = _scan_layers(
+        block, params, x, k_pages_g, v_pages_g, positions, page_ids,
+        offsets, active,
+        lambda layer, q, _k, _v, kp, vp: block.attend_decode(
+            layer, q, kp, vp, lengths, page_tables, paged_impl))
+    nxt = _sample(block.logits(params, x), active, rng_data,
+                  temperature, top_k)
+    return nxt[None], counts[None], k_pages_g[None], v_pages_g[None]
 
 
 def _prefill_program(params, k_pages, v_pages, page_row, live,
-                     chunk_tokens, start_pos, n_valid, *, cfg, first,
+                     chunk_tokens, start_pos, n_valid, *, block, first,
                      paged_impl):
     """One prompt chunk for one sequence, on one dp group's shard.
 
-    k_pages/v_pages (1, L, Hkv, N, ps, hd); page_row (1, P) — the
+    k_pages/v_pages (1, L, Hkv, N, ps, width); page_row (1, P) — the
     sequence's table on its OWNER group, all-scratch elsewhere; live
     (1,) bool — True only on the owner (dead groups' writes land in
     their scratch page and their queries mask out); chunk_tokens
@@ -2592,37 +2578,28 @@ def _prefill_program(params, k_pages, v_pages, page_row, live,
     chunk's first absolute position. Writes the chunk's KV into its
     pages and returns (next-token logits (1, V) fp32 — from the LAST
     VALID position, meaningful on the OWNER group when this is the
-    prompt's final chunk — k_pages, v_pages).
+    prompt's final chunk — counts (1, n), k_pages, v_pages).
 
     ``first=True`` (start_pos == 0, traced as a separate program):
     attention is ordinary causal self-attention over the chunk
-    (ops.attention — the flash path on TPU). Continuation chunks
-    attend the pages written so far plus themselves via the paged
-    chunk form. Both write-then-read the pool identically, so the
-    two programs' caches are interchangeable token-for-token.
+    (the block's ``attend_first`` — the flash path on TPU where the
+    block's widths allow). Continuation chunks attend the pages
+    written so far plus themselves via the paged chunk form. Both
+    write-then-read the pool identically, so the two programs' caches
+    are interchangeable token-for-token.
     """
     import jax
     import jax.numpy as jnp
 
-    from distributed_training_tpu.ops.attention import (
-        dot_product_attention)
-    from distributed_training_tpu.ops.paged_attention import (
-        paged_attention_chunk)
-
     del paged_impl  # chunk form has no kernel path yet
     k_pages_g, v_pages_g = k_pages[0], v_pages[0]
     page_row, live = page_row[0], live[0]
-    dt = jnp.dtype(cfg.dtype)
     C = chunk_tokens.shape[1]
     ps = k_pages_g.shape[3]
     idx = jnp.arange(C, dtype=jnp.int32)
     abs_pos = start_pos + idx                             # (C,)
     valid = (idx < n_valid) & live
-    x = params["tok_embed"][chunk_tokens[0]].astype(dt)   # (C, D)
-    if cfg.pos_encoding == "learned":
-        # Clamp padding positions into range; their rows are dead.
-        safe = jnp.minimum(abs_pos, cfg.max_seq_len - 1)
-        x = x + params["pos_embed"][safe].astype(dt)
+    x = block.embed(params, chunk_tokens[0], abs_pos)     # (C, D)
     page_ids = jnp.where(valid, page_row[abs_pos // ps], 0)
     offsets = jnp.where(valid, abs_pos % ps, 0)
     # Padding queries — and every query on a non-live group — mask
@@ -2631,88 +2608,45 @@ def _prefill_program(params, k_pages, v_pages, page_row, live,
     # (pads sit at higher positions) and never reads the pool, so
     # its logits are identical on every group.
     q_pos = jnp.where(valid, abs_pos, -1)[None, :]        # (1, C)
-    stacked = {k: params[k] for k in _STACKED}
-
-    def layer_body(x, inp):
-        layer, kp, vp = inp
-        h = _layer_norm(x, layer["ln1"]["scale"],
-                        layer["ln1"]["bias"])
-        q = jnp.einsum("cd,dhk->chk", h,
-                       _w(layer["attn"]["wq"], dt))
-        k = jnp.einsum("cd,dhk->chk", h,
-                       _w(layer["attn"]["wk"], dt))
-        v = jnp.einsum("cd,dhk->chk", h,
-                       _w(layer["attn"]["wv"], dt))
-        if cfg.pos_encoding == "rope":
-            q = _rope_bhd(q, abs_pos)
-            k = _rope_bhd(k, abs_pos)
-        kp, vp = _write_kv(kp, vp, k.astype(kp.dtype),
-                           v.astype(vp.dtype), page_ids, offsets)
-        if first:
-            attn = dot_product_attention(
-                q[None], k[None], v[None], causal=True,
-                impl=cfg.attention_impl
-                if cfg.attention_impl in ("auto", "flash", "naive")
-                else "auto",
-                window=0)[0]
-        else:
-            attn = paged_attention_chunk(
-                q[None], kp, vp, page_row[None], q_pos)[0]
-        x = x + jnp.einsum("chk,hkd->cd", attn,
-                           _w(layer["attn"]["wo"], dt))
-        h = _layer_norm(x, layer["ln2"]["scale"],
-                        layer["ln2"]["bias"])
-        m = layer["mlp"]
-        u = jax.nn.gelu(jnp.einsum("cd,df->cf", h,
-                                   _w(m["wi"], dt))
-                        + m["bi"].astype(dt))
-        x = x + (jnp.einsum("cf,fd->cd", u, _w(m["wo"], dt))
-                 + m["bo"].astype(dt))
-        return x, (kp, vp)
-
-    x, (k_pages_g, v_pages_g) = jax.lax.scan(
-        layer_body, x, (stacked, k_pages_g, v_pages_g))
+    if first:
+        def attend(layer, q, k, v, _kp, _vp):
+            return block.attend_first(layer, q, k, v)
+    else:
+        def attend(layer, q, _k, _v, kp, vp):
+            return block.attend_chunk(
+                layer, jax.tree.map(lambda a: a[None], q), kp, vp,
+                page_row[None], q_pos)[0]
+    x, counts, k_pages_g, v_pages_g = _scan_layers(
+        block, params, x, k_pages_g, v_pages_g, abs_pos, page_ids,
+        offsets, valid, attend)
     x_last = jax.lax.dynamic_index_in_dim(
         x, jnp.maximum(n_valid - 1, 0), axis=0, keepdims=False)
-    x_last = _layer_norm(x_last, params["final_norm"]["scale"],
-                         params["final_norm"]["bias"])
-    head = (params["tok_embed"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    logits = jnp.einsum("d,dv->v", x_last,
-                        head.astype(dt)).astype(jnp.float32)
-    return logits[None], k_pages_g[None], v_pages_g[None]
+    return (block.logits(params, x_last)[None], counts[None],
+            k_pages_g[None], v_pages_g[None])
 
 
 def _chunk_hidden(params, k_pages_g, v_pages_g, page_rows, tokens,
-                  start_pos, n_valid, active, *, cfg):
+                  start_pos, n_valid, active, *, block):
     """The multi-lane chunk forward SHARED by ``_chunk_program``
     (batched prefill + speculative verification) and
     ``_resident_program`` (every resident loop iteration) — ONE
     implementation, so the device-resident path cannot drift from
     the host-verified chunk math. Operates on one group's UNPACKED
     block (no leading group dim): k_pages_g/v_pages_g
-    (L, Hkv, N, ps, hd); page_rows (S, P); tokens (S, C); start_pos,
-    n_valid (S,); active (S,) bool. Writes every lane's valid
-    tokens' KV through one batched page-row scatter and returns
-    ``(x (S, C, D) final hidden states, valid (S, C), k_pages_g,
-    v_pages_g)``."""
-    import jax
+    (L, Hkv, N, ps, width); page_rows (S, P); tokens (S, C);
+    start_pos, n_valid (S,); active (S,) bool. Writes every lane's
+    valid tokens' KV through one batched page-row scatter and returns
+    ``(x (S, C, D) final hidden states, valid (S, C), counts (n,),
+    k_pages_g, v_pages_g)``."""
     import jax.numpy as jnp
 
-    from distributed_training_tpu.ops.paged_attention import (
-        paged_attention_chunk)
-
-    dt = jnp.dtype(cfg.dtype)
     S, C = tokens.shape
     P = page_rows.shape[1]
     ps = k_pages_g.shape[3]
     idx = jnp.arange(C, dtype=jnp.int32)
     abs_pos = start_pos[:, None] + idx[None, :]           # (S, C)
     valid = (idx[None, :] < n_valid[:, None]) & active[:, None]
-    x = params["tok_embed"][tokens].astype(dt)            # (S, C, D)
-    if cfg.pos_encoding == "learned":
-        safe = jnp.minimum(abs_pos, cfg.max_seq_len - 1)
-        x = x + params["pos_embed"][safe].astype(dt)
+    x = block.embed(params, tokens, abs_pos)              # (S, C, D)
     # Page coordinates per (lane, position); dead writes → each
     # group's scratch page 0 (page index clamped first: padding
     # positions of a lane near max_seq_len could index past its row).
@@ -2721,68 +2655,27 @@ def _chunk_hidden(params, k_pages_g, v_pages_g, page_rows, tokens,
         valid, jnp.take_along_axis(page_rows, logical, axis=1), 0)
     offsets = jnp.where(valid, abs_pos % ps, 0)
     q_pos = jnp.where(valid, abs_pos, -1)                 # (S, C)
-    stacked = {k: params[k] for k in _STACKED}
-
-    def layer_body(x, inp):
-        layer, kp, vp = inp
-        h = _layer_norm(x, layer["ln1"]["scale"],
-                        layer["ln1"]["bias"])
-        q = jnp.einsum("scd,dhk->schk", h,
-                       _w(layer["attn"]["wq"], dt))
-        k = jnp.einsum("scd,dhk->schk", h,
-                       _w(layer["attn"]["wk"], dt))
-        v = jnp.einsum("scd,dhk->schk", h,
-                       _w(layer["attn"]["wv"], dt))
-        if cfg.pos_encoding == "rope":
-            q = _rope_bhd(q, abs_pos)
-            k = _rope_bhd(k, abs_pos)
-        # One batched scatter for the whole lane table: flatten
-        # (lane, position) — live coordinates never collide (a page
-        # is owned by exactly one sequence and a lane's positions are
-        # distinct); scratch collisions write garbage over garbage.
-        Hkv, hd = k.shape[2], k.shape[3]
-        kp, vp = _write_kv(kp, vp,
-                           k.reshape(S * C, Hkv, hd).astype(kp.dtype),
-                           v.reshape(S * C, Hkv, hd).astype(vp.dtype),
-                           page_ids.reshape(-1), offsets.reshape(-1))
-        attn = paged_attention_chunk(q, kp, vp, page_rows, q_pos)
-        x = x + jnp.einsum("schk,hkd->scd", attn,
-                           _w(layer["attn"]["wo"], dt))
-        h = _layer_norm(x, layer["ln2"]["scale"],
-                        layer["ln2"]["bias"])
-        m = layer["mlp"]
-        u = jax.nn.gelu(jnp.einsum("scd,df->scf", h,
-                                   _w(m["wi"], dt))
-                        + m["bi"].astype(dt))
-        x = x + (jnp.einsum("scf,fd->scd", u, _w(m["wo"], dt))
-                 + m["bo"].astype(dt))
-        return x, (kp, vp)
-
-    x, (k_pages_g, v_pages_g) = jax.lax.scan(
-        layer_body, x, (stacked, k_pages_g, v_pages_g))
-    return x, valid, k_pages_g, v_pages_g
+    x, counts, k_pages_g, v_pages_g = _scan_layers(
+        block, params, x, k_pages_g, v_pages_g, abs_pos, page_ids,
+        offsets, valid,
+        lambda layer, q, _k, _v, kp, vp: block.attend_chunk(
+            layer, q, kp, vp, page_rows, q_pos))
+    return x, valid, counts, k_pages_g, v_pages_g
 
 
-def _argmax_chain(params, x, valid, cfg):
+def _argmax_chain(block, params, x, valid):
     """The verification chain over chunk hidden states: the ARGMAX
     after EVERY position (position c's argmax is the verified next
     token given tokens[:c+1]) — greedy only, by the spec/resident
     config contract. Invalid positions emit 0."""
     import jax.numpy as jnp
 
-    dt = jnp.dtype(cfg.dtype)
-    xs = _layer_norm(x, params["final_norm"]["scale"],
-                     params["final_norm"]["bias"])
-    head = (params["tok_embed"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    logits = jnp.einsum("scd,dv->scv", xs,
-                        _w(head, dt)).astype(jnp.float32)
-    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    nxt = jnp.argmax(block.logits(params, x), axis=-1).astype(jnp.int32)
     return jnp.where(valid, nxt, 0)
 
 
 def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
-                   start_pos, n_valid, active, rng_data, *, cfg,
+                   start_pos, n_valid, active, rng_data, *, block,
                    temperature, top_k, paged_impl, emit):
     """Multi-token chunks for a whole lane table, one dp group.
 
@@ -2795,14 +2688,14 @@ def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
     first chunk that reduces to causal self-attention, for decode it
     verifies the drafted chain exactly as sequential steps would).
 
-    k_pages/v_pages (1, L, Hkv, N, ps, hd) — the group's pool shard;
-    page_rows (1, S, P); tokens (1, S, C) int32 (positions >=
+    k_pages/v_pages (1, L, Hkv, N, ps, width) — the group's pool
+    shard; page_rows (1, S, P); tokens (1, S, C) int32 (positions >=
     n_valid[s] are padding); start_pos (1, S) — each lane's first
     ABSOLUTE position; n_valid (1, S) — valid tokens per lane;
     active (1, S) bool — dead lanes write to the scratch page and
     their queries mask out via q_pos = -1; rng_data (1, 2).
 
-    Returns ``(next_tokens, k_pages, v_pages)``:
+    Returns ``(next_tokens, counts (1, n), k_pages, v_pages)``:
 
     - ``emit="last"``: next_tokens (1, S) int32 — the SAMPLED token
       after each lane's last valid position (argmax at temperature 0,
@@ -2816,51 +2709,34 @@ def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
 
     Inactive lanes' outputs are 0.
     """
-    import jax
     import jax.numpy as jnp
 
     del paged_impl  # chunk form has no kernel path yet
     k_pages_g, v_pages_g = k_pages[0], v_pages[0]
     page_rows, tokens = page_rows[0], tokens[0]
     start_pos, n_valid, active = start_pos[0], n_valid[0], active[0]
-    dt = jnp.dtype(cfg.dtype)
     S = tokens.shape[0]
-    x, valid, k_pages_g, v_pages_g = _chunk_hidden(
+    x, valid, counts, k_pages_g, v_pages_g = _chunk_hidden(
         params, k_pages_g, v_pages_g, page_rows, tokens,
-        start_pos, n_valid, active, cfg=cfg)
+        start_pos, n_valid, active, block=block)
     if emit == "all":
         # The verification chain: logits at EVERY position, argmax
         # only (spec decode is greedy by config contract).
-        return (_argmax_chain(params, x, valid, cfg)[None],
-                k_pages_g[None], v_pages_g[None])
-    # emit == "last": each lane's LAST VALID position only — the
-    # vocab-sized logits never leave the program.
-    head = (params["tok_embed"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    last = jnp.maximum(n_valid - 1, 0)[:, None, None]     # (S, 1, 1)
-    x_last = jnp.take_along_axis(
-        x, jnp.broadcast_to(last, (S, 1, x.shape[-1])), axis=1)[:, 0]
-    x_last = _layer_norm(x_last, params["final_norm"]["scale"],
-                         params["final_norm"]["bias"])
-    logits = jnp.einsum("sd,dv->sv", x_last,
-                        _w(head, dt)).astype(jnp.float32)
-    if temperature <= 0:
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        nxt = _argmax_chain(block, params, x, valid)
     else:
-        lg = logits / temperature
-        if top_k:
-            kth = jax.lax.top_k(lg, top_k)[0][..., -1:]
-            lg = jnp.where(lg < kth, -jnp.inf, lg)
-        keys = jax.random.split(
-            jax.random.wrap_key_data(rng_data[0]), S)
-        nxt = jax.vmap(jax.random.categorical)(keys, lg).astype(
-            jnp.int32)
-    return (jnp.where(active, nxt, 0)[None],
-            k_pages_g[None], v_pages_g[None])
+        # emit == "last": each lane's LAST VALID position only — the
+        # vocab-sized logits never leave the program.
+        last = jnp.maximum(n_valid - 1, 0)[:, None, None]  # (S, 1, 1)
+        x_last = jnp.take_along_axis(
+            x, jnp.broadcast_to(last, (S, 1, x.shape[-1])),
+            axis=1)[:, 0]
+        nxt = _sample(block.logits(params, x_last), active, rng_data,
+                      temperature, top_k)
+    return nxt[None], counts[None], k_pages_g[None], v_pages_g[None]
 
 
 def _resident_program(params, k_pages, v_pages, page_rows, history,
-                      kv_len, budget, active, *, cfg, K, C, ngram,
+                      kv_len, budget, active, *, block, K, C, ngram,
                       eos_id, paged_impl):
     """Device-resident K-step decode for one dp group's slot table.
 
@@ -2877,7 +2753,7 @@ def _resident_program(params, k_pages, v_pages, page_rows, history,
     stopped (EOS or budget), so an all-slots-complete burst costs
     the iterations it used, not ``K``.
 
-    k_pages/v_pages (1, L, Hkv, N, ps, hd); page_rows (1, B, P);
+    k_pages/v_pages (1, L, Hkv, N, ps, width); page_rows (1, B, P);
     history (1, B, Lmax) int32 — prompt + generated so far, with
     ``history[kv_len]`` the last generated token (its KV not yet
     written, exactly the host decode invariant); kv_len (1, B) —
@@ -2888,7 +2764,8 @@ def _resident_program(params, k_pages, v_pages, page_rows, history,
     active (1, B) bool.
 
     Returns ``(out (1, B, K*C) emitted tokens, n_emitted (1, B),
-    steps (1,) loop iterations used, k_pages, v_pages)``.
+    steps (1,) loop iterations used, counts (1, n) the block's
+    counters over all iterations, k_pages, v_pages)``.
     """
     import jax
     import jax.numpy as jnp
@@ -2934,11 +2811,11 @@ def _resident_program(params, k_pages, v_pages, page_rows, history,
         return draft
 
     def cond(carry):
-        j, _out, _n_em, _kvl, _bud, _hist, running, _kp, _vp = carry
+        j, running = carry[0], carry[6]
         return (j < K) & running.any()
 
     def body(carry):
-        j, out, n_em, kvl, bud, hist, running, kp, vp = carry
+        j, out, n_em, kvl, bud, hist, running, counts, kp, vp = carry
         n = jnp.where(running, jnp.minimum(C, bud), 0).astype(
             jnp.int32)
         last = jnp.take_along_axis(hist, kvl[:, None], axis=1)[:, 0]
@@ -2948,10 +2825,10 @@ def _resident_program(params, k_pages, v_pages, page_rows, history,
                 axis=1)
         else:
             tokens = last[:, None]
-        x, valid, kp, vp = _chunk_hidden(
+        x, valid, c, kp, vp = _chunk_hidden(
             params, kp, vp, page_rows_g, tokens, kvl, n, running,
-            cfg=cfg)
-        nxt = _argmax_chain(params, x, valid, cfg)      # (B, C)
+            block=block)
+        nxt = _argmax_chain(block, params, x, valid)    # (B, C)
         if C > 1:
             sl = jnp.arange(C - 1, dtype=jnp.int32)
             match = ((tokens[:, 1:] == nxt[:, :-1])
@@ -2990,14 +2867,16 @@ def _resident_program(params, k_pages, v_pages, page_rows, history,
         kvl = kvl + e
         bud = bud - e
         running = running & (bud > 0) & ~any_eos
-        return (j + 1, out, n_em, kvl, bud, hist, running, kp, vp)
+        return (j + 1, out, n_em, kvl, bud, hist, running, counts + c,
+                kp, vp)
 
     init = (jnp.zeros((), jnp.int32),
             jnp.zeros((B, T), jnp.int32),
             jnp.zeros((B,), jnp.int32),
             kv_len_g, budget_g, history_g,
-            active_g & (budget_g > 0), kp, vp)
-    j, out, n_em, _kvl, _bud, _hist, _run, kp, vp = \
+            active_g & (budget_g > 0),
+            jnp.zeros((len(block.counters),), jnp.int32), kp, vp)
+    j, out, n_em, _kvl, _bud, _hist, _run, counts, kp, vp = \
         jax.lax.while_loop(cond, body, init)
-    return (out[None], n_em[None], jnp.reshape(j, (1,)),
+    return (out[None], n_em[None], jnp.reshape(j, (1,)), counts[None],
             kp[None], vp[None])

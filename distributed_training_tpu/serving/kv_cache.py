@@ -9,6 +9,14 @@ each, per layer, kv-head-major:
     k_pages, v_pages: (dp_groups, n_layers, n_kv_heads, num_pages,
                        page_size, head_dim)
 
+What a row of each pool holds is the model's (``kind``, from its
+serving block, ``serving/blocks.py``): ``"kv"`` a key and a value a kv
+head, both ``head_dim`` wide; ``"latent"`` one row a token shared by
+all heads (``n_kv_heads == 1``), the latent vector (``head_dim`` =
+its rank) in ``k_pages`` and the rotary key (``v_head_dim`` wide) in
+``v_pages``. Everything below the arrays' last axis (tables,
+allocator, prefix index, copy-on-write) is one code path.
+
 A sequence owns an ordered list of physical page ids (its PAGE TABLE)
 inside ONE dp group's shard; logical position ``p`` lives in slot
 ``p % page_size`` of its ``p // page_size``-th page. Join = allocate
@@ -76,11 +84,15 @@ from distributed_training_tpu.telemetry import event
 class PagedCacheConfig:
     """Pool geometry. ``max_seq_len`` bounds pages per sequence;
     ``num_pages`` is PER GROUP (each dp group owns its own shard of
-    ``num_pages`` pages, scratch included)."""
+    ``num_pages`` pages, scratch included). ``head_dim`` is the width
+    of a ``k_pages`` row, ``v_head_dim`` of a ``v_pages`` row (0 = the
+    same)."""
 
     n_layers: int
     n_kv_heads: int
     head_dim: int
+    v_head_dim: int = 0
+    kind: str = "kv"              # what the rows hold: "kv" | "latent"
     page_size: int = 16
     num_pages: int = 128          # per group, scratch page 0 included
     max_seq_len: int = 256
@@ -88,6 +100,8 @@ class PagedCacheConfig:
     dp_groups: int = 1            # leading pool dim / allocator shards
 
     def __post_init__(self):
+        if self.v_head_dim == 0:
+            object.__setattr__(self, "v_head_dim", self.head_dim)
         if self.page_size < 1:
             raise ValueError(f"page_size must be >= 1, got "
                              f"{self.page_size}")
@@ -116,10 +130,13 @@ class PagedCacheConfig:
         return self.dp_groups * self.usable_pages
 
     def kv_bytes_per_token(self) -> int:
-        """HBM cost of one cached token across all layers (k + v)."""
-        itemsize = np.dtype(self.dtype).itemsize
-        return (2 * self.n_layers * self.n_kv_heads * self.head_dim
-                * itemsize)
+        """HBM cost of one cached token across all layers (a row of
+        each pool; lane padding not counted)."""
+        import jax.numpy as jnp  # numpy alone has no bfloat16
+
+        itemsize = jnp.dtype(self.dtype).itemsize
+        return (self.n_layers * self.n_kv_heads
+                * (self.head_dim + self.v_head_dim) * itemsize)
 
 
 def pool_sharding(mesh, n_kv_heads: int, dp_groups: int,
@@ -169,22 +186,22 @@ class PagedKVCache:
         import jax.numpy as jnp
 
         self.cfg = cfg
-        shape = (cfg.dp_groups, cfg.n_layers, cfg.n_kv_heads,
-                 cfg.num_pages, cfg.page_size, cfg.head_dim)
+        lead = (cfg.dp_groups, cfg.n_layers, cfg.n_kv_heads,
+                cfg.num_pages, cfg.page_size)
         dt = jnp.dtype(cfg.dtype)
         self.sharding = sharding = pool_sharding(
             mesh, cfg.n_kv_heads, cfg.dp_groups, kv_axis, dp_axis)
 
-        def pool():
+        def pool(width):
             # Two DISTINCT buffers: k and v are donated separately to
             # the jitted programs, and donating one aliased array
             # twice is an XLA error.
-            z = jnp.zeros(shape, dt)
+            z = jnp.zeros(lead + (width,), dt)
             return jax.device_put(z, sharding) \
                 if sharding is not None else z
 
-        self.k_pages = pool()
-        self.v_pages = pool()
+        self.k_pages = pool(cfg.head_dim)
+        self.v_pages = pool(cfg.v_head_dim)
         # Host allocator state, PER GROUP. Free lists are LIFO:
         # recently-freed pages are re-handed first (warm in cache, and
         # deterministic for the tests' join/evict permutations).
