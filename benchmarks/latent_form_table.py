@@ -37,6 +37,7 @@ def case(B: int, S: int, reps: int = 20) -> dict:
     import numpy as np
 
     from distributed_training_tpu.ops import paged_attention as pa
+    from distributed_training_tpu.serving.kv_cache import as_layer
 
     bf = jnp.bfloat16
     ks = jax.random.split(jax.random.PRNGKey(B * 10007 + S), 6)
@@ -47,8 +48,8 @@ def case(B: int, S: int, reps: int = 20) -> dict:
     q_pos = (lengths[:, None] - S + np.arange(S)[None, :]).astype(np.int32)
     args = (jax.random.normal(ks[0], (B, S, H, NOPE), bf),
             jax.random.normal(ks[1], (B, S, H, ROPE), bf),
-            jax.random.normal(ks[2], (1, N, PS, RANK), bf),
-            jax.random.normal(ks[3], (1, N, PS, ROPE), bf),
+            as_layer(jax.random.normal(ks[2], (1, N, PS, RANK), bf)),
+            as_layer(jax.random.normal(ks[3], (1, N, PS, ROPE), bf)),
             jnp.asarray(tables), jnp.asarray(q_pos),
             jax.random.normal(ks[4], (RANK, H, NOPE), bf) * RANK ** -0.5,
             jax.random.normal(ks[5], (RANK, H, V), bf) * RANK ** -0.5)

@@ -197,12 +197,16 @@ def paged_case(B, H, Hkv, P, hd=128, ps=16, N=64):
 
     from distributed_training_tpu.ops.paged_attention import (
         paged_attention)
+    from distributed_training_tpu.serving.kv_cache import as_layer
 
     def inputs():
         ks = jax.random.split(jax.random.PRNGKey(SEED + 1), 3)
         q = jax.random.normal(ks[0], (B, H, hd), jnp.bfloat16)
-        kp = jax.random.normal(ks[1], (Hkv, N, ps, hd), jnp.bfloat16)
-        vp = jax.random.normal(ks[2], (Hkv, N, ps, hd), jnp.bfloat16)
+        # One layer of a pool as the cache stores it; the kernel call
+        # re-lays it out head-major.
+        kp, vp = (as_layer(jax.random.normal(k, (Hkv, N, ps, hd),
+                                             jnp.bfloat16))
+                  for k in ks[1:])
         rng = np.random.default_rng(SEED)
         # Distinct physical pages per sequence, out of order; lengths
         # from one token to a full table, plus one inactive slot
@@ -233,11 +237,14 @@ def paged_forms_case(B, S, H, Hkv, P, N, hd=64, ps=16, reps=20) -> dict:
     import numpy as np
 
     from distributed_training_tpu.ops import paged_attention as pa
+    from distributed_training_tpu.serving.kv_cache import as_layer
 
     ks = jax.random.split(jax.random.PRNGKey(SEED + 2), 3)
     q = jax.random.normal(ks[0], (B, S, H, hd), jnp.bfloat16)
-    kp = jax.random.normal(ks[1], (Hkv, N, ps, hd), jnp.bfloat16)
-    vp = jax.random.normal(ks[2], (Hkv, N, ps, hd), jnp.bfloat16)
+    # One layer of a pool as the cache stores it.
+    kp, vp = (as_layer(jax.random.normal(k, (Hkv, N, ps, hd),
+                                         jnp.bfloat16))
+              for k in ks[1:])
     rng = np.random.default_rng(SEED)
     own = min(P, (N - 1) // B)
     pages = rng.permutation(N - 1)[:B * own].reshape(B, own) + 1
@@ -263,7 +270,7 @@ def paged_forms_case(B, S, H, Hkv, P, N, hd=64, ps=16, reps=20) -> dict:
     diff = float(jnp.abs(out["pool"].astype(jnp.float32)
                          - out["gather"].astype(jnp.float32)).max())
     band = _close("paged_forms", out["pool"], out["gather"])
-    rule = pa.chunk_form(q.shape, kp.shape, tables.shape,
+    rule = pa.chunk_form(q.shape, (Hkv, N, ps, hd), tables.shape,
                          kp.dtype.itemsize)
     label = f"{B} x {S}, H{H}/{Hkv} D{hd}, pool {N} x {ps}, P {P}"
     say(f"  paged forms [{label}]: gather {ms['gather']:.3f} ms, pool "

@@ -6,11 +6,15 @@ KV, so concurrent sequences of wildly different lengths share one HBM
 reservation with no per-sequence max_len buffers and no copying on
 join/evict. This module is the attention math over that layout:
 
-- pool layout (per layer): ``k_pages``/``v_pages`` of shape
-  ``(n_kv_heads, num_pages, page_size, head_dim)`` — kv-head-major,
-  the canonical layout of the TPU Pallas paged-attention kernel
-  (``jax.experimental.pallas.ops.tpu.paged_attention``), so the
-  kernel path needs zero relayout;
+- the pool: ``k_pages``/``v_pages`` reach this module as one layer of
+  the cache's carried pool, unread (``serving/kv_cache.py::
+  PoolLayer``: the pool and the layer's number). The cache stores a
+  token's row of a layer token-major in whole 128-lane tiles and owns
+  the order of the axes; this module reads through the view's two
+  accessors, ``slots()`` (every slot of the layer in physical order,
+  ``(N * ps, tiles, tile)``) and ``pages(page_indices)`` (a table's
+  pages dense in logical order, ``(B, P * ps, tiles, tile)``), and
+  contracts on the tiles as they lie (``_tile_attention``);
 - per-sequence ``page_indices`` row: logical page ``j`` of the
   sequence lives in physical page ``page_indices[j]``; logical
   position ``p`` is slot ``p % page_size`` of logical page
@@ -26,7 +30,9 @@ Two entrypoints over keys and values:
   against its pages. Dispatches to the TPU Pallas kernel when
   ``kernel_supported`` (one async DMA per non-contiguous page,
   double-buffered — see the Pallas guide's paged-attention walk-
-  through; it needs ``head_dim % 128 == 0``, so not GPT-2's 64);
+  through; it needs ``head_dim % 128 == 0``, so not GPT-2's 64, and
+  it wants ``(Hkv, N, ps, hd)`` verbatim, so its call re-lays the
+  layer out);
   everywhere else it is ``paged_attention_chunk`` with ``S = 1``.
   Exact same numerics contract as ops/attention.py: fp32
   logits/softmax, output in q.dtype, GQA via hkv-major grouping.
@@ -39,13 +45,15 @@ Two entrypoints over keys and values:
   static shapes when the program is traced:
 
   - *gather form* reads ``B * P * ps`` slots: every sequence's whole
-    table row copied dense in logical order, whatever is live, and
-    transposed out of the pool's head-major order. Right when queries
-    are many and sequences few (prefill chunks: 4 x 128, 1 x 128);
+    table row copied dense in logical order, whatever is live,
+    indexed ``(layer, page)`` straight out of the carried pool. Right
+    when queries are many and sequences few (prefill chunks: 4 x 128,
+    1 x 128);
   - *pool form* reads the layer's ``N * ps`` slots once for all
-    sequences, in place, and scores every query against all of them
-    under a mask made from the page table turned inside out. No
-    gather, no transpose, no copy. Right when queries are few (the
+    sequences and scores every query against all of them under a mask
+    made from the page table turned inside out. No gather; what XLA
+    still copies is the layer, once, to put the slots on the lanes
+    for the contraction (ROADMAP S1). Right when queries are few (the
     resident decode loop 16 x 1, speculative verify 16 x 4, the
     per-token decode program), where it also keeps the contraction on
     the MXU in the pool's dtype: with one query a sequence the gather
@@ -53,7 +61,7 @@ Two entrypoints over keys and values:
     it to a float32 copy of the gathered block and a multiply-reduce.
 
   The ragged kernel that reads only the pages a sequence owns is the
-  end state (ROADMAP S1); it wants the pool re-laid-out first (S2).
+  end state (ROADMAP S1).
 
 There is no switch between the forms: ``paged_impl`` means kernel or
 reference and nothing else. The form each compiled program took
@@ -96,7 +104,7 @@ def _took(form: str) -> None:
         seen.append(form)
 
 
-def kernel_supported(q: jax.Array, k_pages: jax.Array,
+def kernel_supported(q: jax.Array, k_pages,
                      page_size: int | None = None) -> bool:
     """Should single-token decode dispatch to the TPU Pallas kernel?
 
@@ -109,7 +117,7 @@ def kernel_supported(q: jax.Array, k_pages: jax.Array,
     if default_platform() != "tpu":
         return False
     head_dim = q.shape[-1]
-    ps = page_size if page_size is not None else k_pages.shape[2]
+    ps = page_size if page_size is not None else k_pages.page_size
     if head_dim % 128:
         return False
     if ps % 16:
@@ -117,17 +125,6 @@ def kernel_supported(q: jax.Array, k_pages: jax.Array,
     if q.dtype not in (jnp.float32, jnp.bfloat16):
         return False
     return True
-
-
-def _gather_pages(pages: jax.Array, page_indices: jax.Array
-                  ) -> jax.Array:
-    """(Hkv, N, ps, hd) pool + (B, P) tables → (B, P*ps, Hkv, hd)
-    dense per-sequence KV, logical order. Slot ``s`` of the result is
-    logical position ``s`` of the sequence."""
-    Hkv, _N, ps, hd = pages.shape
-    B, P = page_indices.shape
-    g = pages[:, page_indices]              # (Hkv, B, P, ps, hd)
-    return g.transpose(1, 2, 3, 0, 4).reshape(B, P * ps, Hkv, hd)
 
 
 def _masked_softmax(logits: jax.Array, visible: jax.Array
@@ -142,49 +139,62 @@ def _masked_softmax(logits: jax.Array, visible: jax.Array
                      0.0)
 
 
-def _masked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                      visible: jax.Array) -> jax.Array:
-    """GQA attention with an explicit visibility mask.
+def _tile_attention(layout, q: jax.Array, k: jax.Array,
+                    v: jax.Array, visible: jax.Array) -> jax.Array:
+    """GQA attention with an explicit visibility mask, on the pool's
+    tiles as they lie (``serving/kv_cache.py::PoolLayout``).
 
-    q (B, S, H, hd); k/v (B, Sk, Hkv, hd); visible (B, S, Sk) bool.
-    fp32 logits/softmax (ops/attention.py numerics contract), output
-    in q.dtype. Rows with zero visible keys (inactive batch slots)
+    q (B, S, H, hd); k/v ``(B, Sk, tiles, tile)``, or ``(Sk, tiles,
+    tile)`` shared by every sequence (the pool form); visible (B, S,
+    Sk) bool. The queries are spread onto the lanes of their kv heads
+    (zeros on their tile-mates'), so both contractions run over whole
+    128-lane tiles with no copy of the keys or values into a layout a
+    head at a time; the zeros cost MXU rows and change no sum. fp32
+    logits/softmax (ops/attention.py numerics contract), output in
+    q.dtype. Rows with zero visible keys (inactive batch slots)
     produce zeros, not NaN — the engine masks their outputs anyway,
     but NaN would poison debugging."""
-    B, S, H, hd = q.shape
-    Hkv = k.shape[2]
-    if H % Hkv:
-        raise ValueError(f"n_heads {H} not divisible by n_kv_heads "
-                         f"{Hkv}")
-    group = H // Hkv
-    qg = q.reshape(B, S, Hkv, group, hd)
-    logits = jnp.einsum("bshgd,bkhd->bhgsk", qg, k,
+    qt = layout.spread(q)                     # (B, S, tiles, J, tile)
+    kv = "bktl" if k.ndim == 4 else "ktl"
+    # Batched over the tile with B*S*J rows a tile: an MXU dot in the
+    # pool's dtype also at S = J = 1, where a per-sequence contraction
+    # has one row and is lowered to a float32 multiply-reduce.
+    logits = jnp.einsum(f"bstjl,{kv}->tbsjk", qt, k,
                         preferred_element_type=jnp.float32)
-    probs = _masked_softmax(logits * (hd ** -0.5),
-                            visible[:, None, None])
-    out = jnp.einsum("bhgsk,bkhd->bshgd", probs.astype(v.dtype), v,
+    probs = _masked_softmax(logits * (q.shape[-1] ** -0.5),
+                            visible[None, :, :, None, :])
+    out = jnp.einsum(f"tbsjk,{kv}->tbsjl", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(B, S, H, hd).astype(q.dtype)
+    # Transposed apart: with the tile moved inside the einsum's own
+    # output, XLA's CPU runtime has no bfloat16 dot to run it with.
+    return layout.collect(out.transpose(1, 2, 0, 3, 4)).astype(q.dtype)
 
 
 # HBM bytes a v5e moves for each nominal byte, fitted to one layer's
 # call timed on the chip in both forms at thirteen engine shapes
-# (benchmarks/paged_form_table.py; the table is in PERF.md section 6).
+# (benchmarks/paged_form_table.py; the table is in PERF.md section 6),
+# again since the pool is stored in whole 128-lane tiles (PR 29: the
+# copies fell from 9.0 and 5.3, no row being re-tiled from 64 lanes).
 # Nominal sizes mislead by these factors, which is why they are here.
-_GATHER_COPY = 9.0       # gathered KV: the gather, the transpose to
-#                          (B, Sk, Hkv, hd), head_dim re-tiled to 128
-_GATHER_COPY_ROW = 32.0  # ... with ONE query row a kv head (S * group
-#                          == 1) the contraction is no dot: a float32
-#                          copy of the block and a multiply-reduce
-_POOL_READ = 5.3         # the pool read in place by an underfed MXU
-_LOGITS = 5.75           # float32 logits: written, masked, softmaxed,
+_GATHER_COPY = 3.8       # gathered KV: the gather out of the carried
+#                          pool and the copy that puts the tiles first
+_GATHER_COPY_ROW = 32.0  # ... with ONE query row a tile (S * group *
+#                          heads a tile == 1: heads of 128 or wider) the
+#                          contraction is no dot: a float32 copy of the
+#                          block and a multiply-reduce (PR 26's reading;
+#                          no shape of the table has one row any more)
+_POOL_READ = 1.9         # the layer sliced out of the carried pool and
+#                          copied tiles first, then read by the dots
+_LOGITS = 2.8            # float32 logits: written, masked, softmaxed,
 #                          cast to the values' dtype, read (both forms)
 
 
 def chunk_form(q_shape, pool_shape, table_shape, itemsize: int) -> str:
     """``"pool"`` or ``"gather"``: the form of ``paged_attention_chunk``
-    that moves fewer bytes for q ``(B, S, H, hd)``, a pool ``(Hkv, N,
-    ps, hd)`` of ``itemsize``-byte elements and a table ``(B, P)``.
+    that moves fewer bytes for q ``(B, S, H, hd)``, a layer of the
+    pool of ``pool_shape = (Hkv, N, ps, hd)`` (kv heads, pages, slots a
+    page, a head's width: what it holds, not how it is stored) in
+    ``itemsize``-byte elements and a table ``(B, P)``.
     Shapes are static, so this runs when a program is traced: one
     algorithm whose cost crosses over with the shape. The gather form
     copies ``B * P * ps`` slots whatever is live and scores them; the
@@ -196,35 +206,35 @@ def chunk_form(q_shape, pool_shape, table_shape, itemsize: int) -> str:
     Hkv, N, ps, _ = pool_shape
     P = table_shape[1]
     kv_slot = 2 * Hkv * hd * itemsize           # keys and values
-    copy = (_GATHER_COPY_ROW if S * (H // Hkv) == 1 else _GATHER_COPY)
+    rows = S * (H // Hkv) * max(1, 128 // hd)   # query rows a tile
+    copy = _GATHER_COPY_ROW if rows == 1 else _GATHER_COPY
     gather = B * P * ps * (copy * kv_slot + _LOGITS * S * H * 4)
     pool = N * ps * (_POOL_READ * kv_slot + _LOGITS * B * S * H * 4)
     return "pool" if pool < gather else "gather"
 
 
-def _gather_attention(q: jax.Array, k_pages: jax.Array,
-                      v_pages: jax.Array, page_indices: jax.Array,
+def _gather_attention(q: jax.Array, k_pages, v_pages,
+                      page_indices: jax.Array,
                       q_positions: jax.Array) -> jax.Array:
     """Gather form: each sequence's pages copied dense in logical
     order (``B * P * ps`` slots, however few are live), then masked
     attention over the copy."""
-    kd = _gather_pages(k_pages, page_indices)
-    vd = _gather_pages(v_pages, page_indices)
-    Sk = kd.shape[1]
-    slot = jnp.arange(Sk, dtype=jnp.int32)
+    kd = k_pages.pages(page_indices)
+    vd = v_pages.pages(page_indices)
+    slot = jnp.arange(kd.shape[1], dtype=jnp.int32)
     visible = (slot[None, None, :] <= q_positions[:, :, None]) \
         & (q_positions[:, :, None] >= 0)
-    return _masked_attention(q, kd, vd, visible)
+    return _tile_attention(k_pages.layout, q, kd, vd, visible)
 
 
-def _pool_attention(q: jax.Array, k_pages: jax.Array,
-                    v_pages: jax.Array, page_indices: jax.Array,
+def _pool_attention(q: jax.Array, k_pages, v_pages,
+                    page_indices: jax.Array,
                     q_positions: jax.Array) -> jax.Array:
-    """Pool form: every query against the layer's WHOLE pool where it
-    lies (``N * ps`` slots a head, read once for all sequences), the
-    page table turned inside out into a visibility mask. No gather, no
-    transpose, no copy of the pool; the same keys at the same
-    precisions as the gather form, summed in physical order.
+    """Pool form: every query against the layer's WHOLE pool
+    (``N * ps`` slots a head, read once for all sequences), the page
+    table turned inside out into a visibility mask. No gather; the
+    same keys at the same precisions as the gather form, summed in
+    physical order.
 
     Physical page ``n`` holds logical page ``j`` of sequence ``b`` iff
     ``page_indices[b, j] == n``; the LOWEST such ``j`` counts, so the
@@ -232,13 +242,9 @@ def _pool_attention(q: jax.Array, k_pages: jax.Array,
     sequence's last used page, where no query position reaches, and a
     page that copy-on-write sharing put into two rows is visible to
     both."""
-    B, S, H, hd = q.shape
-    Hkv, N, ps, _ = k_pages.shape
+    B = q.shape[0]
+    N, ps = k_pages.num_pages, k_pages.page_size
     P = page_indices.shape[1]
-    if H % Hkv:
-        raise ValueError(f"n_heads {H} not divisible by n_kv_heads "
-                         f"{Hkv}")
-    group = H // Hkv
     logical = jnp.arange(P, dtype=jnp.int32)[None, :, None]
     owns = page_indices[:, :, None] \
         == jnp.arange(N, dtype=page_indices.dtype)[None, None, :]
@@ -250,39 +256,31 @@ def _pool_attention(q: jax.Array, k_pages: jax.Array,
                 ).reshape(B, N * ps)
     visible = (slot_pos[:, None, :] <= q_positions[:, :, None]) \
         & (q_positions[:, :, None] >= 0)             # (B, S, N*ps)
-    qg = q.reshape(B, S, Hkv, group, hd)
-    # Batched over the kv head with B*S*group rows a head: an MXU dot
-    # in the pool's dtype also at S = group = 1, where the gather
-    # form's per-sequence contraction has one row and is lowered to a
-    # float32 multiply-reduce.
-    logits = jnp.einsum("bshgd,hkd->hbsgk", qg,
-                        k_pages.reshape(Hkv, N * ps, hd),
-                        preferred_element_type=jnp.float32)
-    probs = _masked_softmax(logits * (hd ** -0.5),
-                            visible[None, :, :, None, :])
-    out = jnp.einsum("hbsgk,hkd->hbsgd", probs.astype(v_pages.dtype),
-                     v_pages.reshape(Hkv, N * ps, hd),
-                     preferred_element_type=jnp.float32)
-    # Transposed apart: with the head moved inside the einsum's own
-    # output, XLA's CPU runtime has no bfloat16 dot to run it with.
-    out = out.transpose(1, 2, 0, 3, 4)
-    return out.reshape(B, S, H, hd).astype(q.dtype)
+    return _tile_attention(k_pages.layout, q, k_pages.slots(),
+                           v_pages.slots(), visible)
 
 
-def paged_attention_chunk(q: jax.Array, k_pages: jax.Array,
-                          v_pages: jax.Array,
+def _held(pages) -> tuple:
+    """``(Hkv, N, ps, hd)`` of a layer's view: what ``chunk_form``
+    reasons from."""
+    return (pages.layout.heads, pages.num_pages, pages.page_size,
+            pages.layout.width)
+
+
+def paged_attention_chunk(q: jax.Array, k_pages, v_pages,
                           page_indices: jax.Array,
                           q_positions: jax.Array) -> jax.Array:
     """Multi-query paged attention (prefill chunks, reference path).
 
-    q (B, S, H, hd); pools (Hkv, N, ps, hd); page_indices (B, P);
+    q (B, S, H, hd); k_pages/v_pages a layer of the two pools
+    (``PoolLayer``); page_indices (B, P);
     q_positions (B, S) int32 — each query's ABSOLUTE position. Query
     (b, s) attends logical positions ``<= q_positions[b, s]`` of
     sequence b (the chunk's own KV must already be written to the
     pool). Negative q_positions mark padding queries (zero output).
     The form (``chunk_form``) follows from the static shapes.
     """
-    form = chunk_form(q.shape, k_pages.shape, page_indices.shape,
+    form = chunk_form(q.shape, _held(k_pages), page_indices.shape,
                       k_pages.dtype.itemsize)
     _took(form)
     attend = _pool_attention if form == "pool" else _gather_attention
@@ -314,15 +312,16 @@ def latent_form(q_shape, dims) -> str:
 
 
 def latent_attention_chunk(q_nope: jax.Array, q_rope: jax.Array,
-                           c_pages: jax.Array, r_pages: jax.Array,
+                           c_pages, r_pages,
                            page_indices: jax.Array,
                            q_positions: jax.Array, w_uk: jax.Array,
                            w_uv: jax.Array) -> jax.Array:
     """Multi-query attention over a LATENT paged cache.
 
     q_nope (B, S, H, nope), q_rope (B, S, H, rope), RoPE applied;
-    c_pages (1, N, ps, rank): a token's latent row after its norm;
-    r_pages (1, N, ps, rope): its rotary key after RoPE, one for all
+    c_pages, a layer of the pool of one ``rank``-wide head
+    (``PoolLayer``): a token's latent row after its norm; r_pages,
+    likewise ``rope`` wide: its rotary key after RoPE, one for all
     heads; page_indices (B, P); q_positions (B, S) as in
     ``paged_attention_chunk``; w_uk (rank, H, nope) and w_uv
     (rank, H, v) expand a latent row into a head's key and value.
@@ -339,11 +338,11 @@ def latent_attention_chunk(q_nope: jax.Array, q_rope: jax.Array,
     another order; ``latent_form`` takes one from the shapes."""
     B, S, H, nope = q_nope.shape
     rope, v = q_rope.shape[-1], w_uv.shape[-1]
-    form = latent_form((B, S, H), (c_pages.shape[-1], nope, v))
+    form = latent_form((B, S, H), (c_pages.layout.width, nope, v))
     _took(form)
     f32 = jnp.float32
-    cd = _gather_pages(c_pages, page_indices)[:, :, 0]    # (B, Sk, rank)
-    rd = _gather_pages(r_pages, page_indices)[:, :, 0]    # (B, Sk, rope)
+    cd, rd = (p.layout.unpack(p.pages(page_indices))[:, :, 0]
+              for p in (c_pages, r_pages))   # (B, Sk, rank), (.., rope)
     slot = jnp.arange(cd.shape[1], dtype=jnp.int32)
     visible = ((slot[None, None, :] <= q_positions[:, :, None])
                & (q_positions[:, :, None] >= 0))[:, None]
@@ -374,16 +373,23 @@ def latent_attention_chunk(q_nope: jax.Array, q_rope: jax.Array,
     return jnp.einsum("bhsr,rhv->bshv", ctx, w_uv)
 
 
-def paged_attention(q: jax.Array, k_pages: jax.Array,
-                    v_pages: jax.Array, lengths: jax.Array,
-                    page_indices: jax.Array,
+def _head_major(pages) -> jax.Array:
+    """A layer as the stock kernel wants it, ``(Hkv, N, ps, hd)``."""
+    slots = pages.layout.unpack(pages.slots())
+    return slots.reshape((pages.num_pages, pages.page_size)
+                         + slots.shape[1:]).transpose(2, 0, 1, 3)
+
+
+def paged_attention(q: jax.Array, k_pages, v_pages,
+                    lengths: jax.Array, page_indices: jax.Array,
                     impl: str = "auto") -> jax.Array:
     """Single-token decode attention against the paged pool.
 
-    q (B, H, hd) — the current token's query per sequence; pools
-    (Hkv, N, ps, hd); lengths (B,) int32 — VALID kv entries per
-    sequence, current token's k/v included (attends logical positions
-    ``[0, lengths)``; 0 = inactive slot, zero output); page_indices
+    q (B, H, hd) — the current token's query per sequence; k_pages/
+    v_pages a layer of the two pools (``PoolLayer``); lengths (B,)
+    int32 — VALID kv entries per sequence, current token's k/v
+    included (attends logical positions ``[0, lengths)``; 0 =
+    inactive slot, zero output); page_indices
     (B, P). ``impl``: "auto" (TPU kernel when supported, else
     reference), "kernel", "ref".
     """
@@ -397,8 +403,11 @@ def paged_attention(q: jax.Array, k_pages: jax.Array,
         from jax.experimental.pallas.ops.tpu.paged_attention import (
             paged_attention as tpu_paged_attention,
         )
-        # Kernel layout: q (B, H, hd), pools (Hkv, N, ps, hd),
-        # lengths (B,), page_indices (B, P) — ours verbatim. Two
+        # Kernel layout: q (B, H, hd), lengths (B,), page_indices
+        # (B, P) — ours verbatim — and pools (Hkv, N, ps, hd), which
+        # the cache does not store: the layer is re-laid-out for the
+        # call, one transposing copy of it (at head_dim % 128 == 0
+        # alone, which no benchmark cell reaches). Two
         # things the stock kernel leaves to its caller (both found by
         # its first run on a chip, chip_smoke.py): it computes q·k
         # UNSCALED, so q carries the hd**-0.5 (in f32 — the kernel
@@ -413,7 +422,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array,
         # page_size 16), fewer for a ragged table.
         out = tpu_paged_attention(
             q.astype(jnp.float32) * (q.shape[-1] ** -0.5),
-            k_pages, v_pages, lengths, page_indices,
+            _head_major(k_pages), _head_major(v_pages), lengths,
+            page_indices,
             pages_per_compute_block=math.gcd(
                 4, page_indices.shape[1]))
         return jnp.where((lengths > 0)[:, None, None], out,

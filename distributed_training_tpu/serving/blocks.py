@@ -11,10 +11,11 @@ A block has
 
 - ``cache``: the fields of ``PagedCacheConfig`` that the model decides
   (``n_layers``, ``n_kv_heads``, ``head_dim``, ``v_head_dim``,
-  ``kind``). The pool is always two arrays ``k_pages`` / ``v_pages`` of
-  ``(G, L, n_kv_heads, N, ps, width)``; what a row of each holds is the
-  block's business (keys and values a head for ``DenseBlock``; the
-  latent row and the rotary key for ``models/latent_moe.py``);
+  ``kind``). The pool is always two arrays ``k_pages`` / ``v_pages``;
+  how they are stored is the cache's business
+  (``serving/kv_cache.py::PoolLayout``), what a row of each holds the
+  block's (keys and values a head for ``DenseBlock``; the latent row
+  and the rotary key for ``models/latent_moe.py``);
 - ``counters``: names of the int32 sums ``finish`` returns a layer
   (``()`` for a block that counts nothing). The programs add them up
   over layers and iterations and return them beside the tokens, so they
@@ -28,8 +29,10 @@ A block has
   ``(..., n_kv_heads, width)`` are the rows this token adds to the two
   pools, ``q`` whatever the block's ``attend_*`` want;
 - ``attend_decode(layer, q, kp, vp, lengths, page_tables, impl)``: one
-  query a sequence against its pages (``kp`` / ``vp`` already hold the
-  token's own row);
+  query a sequence against its pages. ``kp`` / ``vp`` are the layer of
+  the two carried pools, unread (``kv_cache.PoolLayer``: read through
+  its ``slots()`` / ``pages(tables)``), and already hold the token's
+  own row;
 - ``attend_first(layer, q, k_new, v_new)``: causal attention of a
   prompt's first chunk over itself, no pool read;
 - ``attend_chunk(layer, q, kp, vp, page_rows, q_pos)``: ``S`` lanes of

@@ -309,8 +309,7 @@ def export_kv_batch(cache, seq_ids):
         if any(len(p) for _g, p in pages_of) else np.zeros(0, np.int32)
     pages = np.concatenate([p for _g, p in pages_of]) \
         if groups.size else np.zeros(0, np.int32)
-    k_all = np.asarray(cache.k_pages[groups, :, :, pages])
-    v_all = np.asarray(cache.v_pages[groups, :, :, pages])
+    k_all, v_all = map(np.asarray, cache.read_pages(groups, pages))
     ks, vs = [], []
     off = 0
     ps = cache.cfg.page_size
@@ -378,11 +377,9 @@ def import_kv_batch(cache, items) -> None:
             pages.append(pid)
             k_chunks.append(kc)
             v_chunks.append(vc)
-    gi = np.asarray(groups, np.int32)
-    pi = np.asarray(pages, np.int32)
-    kp = cache.k_pages.at[gi, :, :, pi].set(np.stack(k_chunks))
-    vp = cache.v_pages.at[gi, :, :, pi].set(np.stack(v_chunks))
-    cache.update_pools(kp, vp)
+    cache.write_pages(np.asarray(groups, np.int32),
+                      np.asarray(pages, np.int32),
+                      np.stack(k_chunks), np.stack(v_chunks))
     for seq_id, _k, _v, n in todo:
         cache.advance(seq_id, n)
 
@@ -665,6 +662,8 @@ def lower_serving_program(plan, objective: str):
     from distributed_training_tpu.runtime import fake_cpu_runtime
     from distributed_training_tpu.serving.engine import (
         build_prefill_batch_fn, build_resident_decode_fn)
+    from distributed_training_tpu.serving.kv_cache import (
+        PagedCacheConfig, PagedKVCache, kv_shards, pool_sharding)
 
     jax.config.update("jax_platforms", "cpu")
     model = model_for_plan(plan)
@@ -692,11 +691,16 @@ def lower_serving_program(plan, objective: str):
     dp_ax = "dp" if sizes.get("dp", 1) > 1 else None
     G = sizes.get("dp", 1)
     B = ecfg.max_batch // G
-    pool_shard = NamedSharding(mesh, P(dp_ax, None, kv_ax))
+    # GPT-2's two pools are one shape: its keys and values are as wide.
     pool = jax.ShapeDtypeStruct(
-        (G, model.cfg.n_layers, model.cfg.n_kv_heads, ecfg.num_pages,
-         ecfg.page_size, model.cfg.head_dim),
-        jnp.dtype(model.cfg.dtype), sharding=pool_shard)
+        PagedKVCache.pool_shapes(
+            PagedCacheConfig(**c.cache, page_size=ecfg.page_size,
+                             num_pages=ecfg.num_pages,
+                             max_seq_len=ecfg.max_seq_len, dp_groups=G),
+            kv_shards(mesh, kv_ax))[0],
+        jnp.dtype(model.cfg.dtype),
+        sharding=pool_sharding(mesh, model.cfg.n_kv_heads, G, kv_ax,
+                               dp_ax))
     rep = NamedSharding(mesh, P())
     grp = NamedSharding(mesh, P(dp_ax))
     Ppages = -(-ecfg.max_seq_len // ecfg.page_size)

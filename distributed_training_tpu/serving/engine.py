@@ -95,8 +95,10 @@ import numpy as np
 from distributed_training_tpu.serving.kv_cache import (
     PagedCacheConfig,
     PagedKVCache,
+    copy_pages,
+    kv_shards,
 )
-from distributed_training_tpu.telemetry import event, phase
+from distributed_training_tpu.telemetry import current, event, phase
 
 logger = logging.getLogger(__name__)
 
@@ -421,6 +423,14 @@ def _out_shardings(block, ecfg: EngineConfig, mesh):
     return grp, pool
 
 
+def _layouts(block, ecfg: EngineConfig, mesh):
+    """``(k_layout, v_layout)``: how the pools the programs of (block,
+    engine cfg, mesh) take are stored — the cache's own choice
+    (``PagedKVCache.layouts``), static in every program."""
+    return PagedKVCache.layouts(PagedCacheConfig(**block.cache),
+                                kv_shards(mesh, ecfg.kv_axis))
+
+
 def _named(name: str, body):
     """``body`` under the function name ``name``, for ``jax.jit``: a
     jitted ``functools.partial`` (and a ``shard_map`` of one) has no
@@ -458,6 +468,7 @@ def build_decode_fn(block, ecfg: EngineConfig, mesh=None):
 
     body = functools.partial(
         _decode_program, block=block,
+        layouts=_layouts(block, ecfg, mesh),
         temperature=ecfg.temperature, top_k=ecfg.top_k,
         paged_impl=ecfg.paged_impl)
     kw = {}
@@ -485,7 +496,8 @@ def build_prefill_fn(block, ecfg: EngineConfig, first: bool,
     import jax
 
     body = functools.partial(
-        _prefill_program, block=block, first=first,
+        _prefill_program, block=block,
+        layouts=_layouts(block, ecfg, mesh), first=first,
         paged_impl=ecfg.paged_impl)
     kw = {}
     if mesh is not None:
@@ -520,6 +532,7 @@ def _chunk_fn(block, ecfg: EngineConfig, emit: str, name: str,
 
     body = functools.partial(
         _chunk_program, block=block,
+        layouts=_layouts(block, ecfg, mesh),
         temperature=ecfg.temperature, top_k=ecfg.top_k,
         paged_impl=ecfg.paged_impl, emit=emit)
     kw = {}
@@ -575,7 +588,8 @@ def build_resident_decode_fn(block, ecfg: EngineConfig,
     import jax
 
     body = functools.partial(
-        _resident_program, block=block, K=ecfg.resident_k,
+        _resident_program, block=block,
+        layouts=_layouts(block, ecfg, mesh), K=ecfg.resident_k,
         C=ecfg.spec_k, ngram=ecfg.spec_ngram, eos_id=ecfg.eos_id,
         paged_impl=ecfg.paged_impl)
     kw = {}
@@ -591,19 +605,15 @@ def build_resident_decode_fn(block, ecfg: EngineConfig,
 
 def _cow_program(k_pages, v_pages, src, dst):
     """Copy-on-write page copy for one dp group's pool shard:
-    ``k/v_pages`` (1, L, Hkv, N, ps, width), ``src``/``dst`` (1, W)
-    int32 page ids. One batched gather + scatter per pool — W page
-    copies in ONE launch, no per-token host sync, zero collectives
-    (pages never cross a group shard). Unused lanes ride as
-    (0 -> 0): a scratch-to-scratch identity copy, the same dead-write
-    trick as the decode program's inactive slots."""
+    ``k/v_pages`` the group's pools (leading dim 1), ``src``/``dst``
+    (1, W) int32 page ids. One batched gather + scatter per pool — W
+    page copies in ONE launch, no per-token host sync, zero
+    collectives (pages never cross a group shard). Unused lanes ride
+    as (0 -> 0): a scratch-to-scratch identity copy, the same
+    dead-write trick as the decode program's inactive slots."""
     s, d = src[0], dst[0]
-
-    def copy(pages):
-        g = pages[0]                       # (L, Hkv, N, ps, hd)
-        return g.at[:, :, d].set(g[:, :, s])[None]
-
-    return copy(k_pages), copy(v_pages)
+    return (copy_pages(k_pages[0], s, d)[None],
+            copy_pages(v_pages[0], s, d)[None])
 
 
 def build_cow_fn(block, ecfg: EngineConfig, mesh=None):
@@ -885,16 +895,34 @@ class Engine:
     def warmup(self) -> dict:
         """Compile every program (``_warmup_calls``) and emit one
         ``serving_warmup`` record with each program's ``paged_form``,
-        the cache's kind and its bytes a token. Returns
-        compile_counts()."""
+        the cache's kind, its bytes a token and what the pools take as
+        stored (``PagedKVCache.footprint``). Where a sink records it, each
+        program is compiled once more ahead of time for its
+        ``temp_bytes`` (``compiled.memory_analysis()``: a program whose
+        temporaries reach the pool's bytes holds a copy of it); with no
+        sink nothing is compiled twice. Returns compile_counts()."""
+        import jax
+
+        temp_bytes = {}
         for fn, args in self._warmup_calls():
+            if current().enabled:
+                # Shapes, taken before the call donates the pools.
+                shapes = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype, sharding=a.sharding)
+                    if isinstance(a, jax.Array) else a, args)
+                analysis = fn.lower(*shapes).compile().memory_analysis()
+                temp_bytes[fn.__wrapped__.__name__] = getattr(
+                    analysis, "temp_size_in_bytes", None)
             *_outs, k, v = fn(*args)
             self.cache.update_pools(k, v)
         event("serving_warmup",
-              programs=[{"program": name, "paged_form": form}
+              programs=[{"program": name, "paged_form": form,
+                         "temp_bytes": temp_bytes.get(name)}
                         for name, form in self.paged_forms().items()],
               cache_kind=self.cache.cfg.kind,
-              cache_bytes_per_token=self.cache.cfg.kv_bytes_per_token())
+              cache_bytes_per_token=self.cache.cfg.kv_bytes_per_token(),
+              **self.cache.footprint())
         return self.compile_counts()
 
     # -- admission ---------------------------------------------------------
@@ -2439,29 +2467,33 @@ class Engine:
 
 # ---------------------------------------------------------------------------
 # The compiled programs (pure functions of arrays + static model cfg).
-# Each body sees ONE dp group's block: pools (1, L, Hkv, N, ps, hd),
-# batch arrays with a leading group dim of 1 — under shard_map that is
+# Each body sees ONE dp group's block: pools (1, L, N, ps, lanes) as
+# the cache stores them (kv_cache.PoolLayout: ``layouts`` is the pair
+# of the two pools', the only thing that indexes them), batch arrays
+# with a leading group dim of 1 — under shard_map that is
 # the per-group shard; without a dp mesh it is the whole (only) group.
 # ---------------------------------------------------------------------------
 
 
-def _write_kv(k_pages, v_pages, layer, k_new, v_new, page_ids, offsets):
+def _write_kv(layouts, k_pages, v_pages, layer, k_new, v_new, page_ids,
+              offsets):
     """Scatter per-row new KV into one layer of the group's pool, where
     the pool lies.
 
-    k_pages/v_pages (L, Hkv, N, ps, width), each pool its own width;
-    layer () int32; k_new/v_new (B, Hkv, width); page_ids/offsets (B,)
-    int32 — rows whose write must be dead point at the scratch page
-    (id 0). Live rows never share a (page, slot) pair (pages are owned
-    by exactly one sequence), so scatter order is immaterial;
-    scratch-page collisions write garbage over garbage."""
-    k_pages = k_pages.at[layer, :, page_ids, offsets].set(k_new)
-    v_pages = v_pages.at[layer, :, page_ids, offsets].set(v_new)
-    return k_pages, v_pages
+    k_pages/v_pages the group's pools as stored, each with its layout
+    of ``layouts``; layer () int32; k_new/v_new (B, Hkv, width), each
+    pool its own width; page_ids/offsets (B,) int32 — rows whose write
+    must be dead point at the scratch page (id 0). Live rows never
+    share a (page, slot) pair (pages are owned by exactly one
+    sequence), so scatter order is immaterial; scratch-page collisions
+    write garbage over garbage."""
+    kl, vl = layouts
+    return (kl.write(k_pages, layer, page_ids, offsets, k_new),
+            vl.write(v_pages, layer, page_ids, offsets, v_new))
 
 
-def _scan_layers(block, params, x, k_pages_g, v_pages_g, positions,
-                 page_ids, offsets, valid, attend):
+def _scan_layers(block, layouts, params, x, k_pages_g, v_pages_g,
+                 positions, page_ids, offsets, valid, attend):
     """Every layer of the model's block on ``x``, THE layer body of
     every program: the block projects the layer's input
     (``positions`` shaped like ``x`` less its width), the new rows go
@@ -2472,23 +2504,28 @@ def _scan_layers(block, params, x, k_pages_g, v_pages_g, positions,
     block finishes the layer; ``valid`` marks real tokens for the
     block's counters. One ``lax.scan`` a run of like layers
     (``block.segments``) over the layers' parameters and numbers; the
-    pool (k_pages_g/v_pages_g (L, Hkv, N, ps, width)) is carried whole
-    through all of them, so runs of unlike layers cost no slice and no
-    concatenation of it. Returns ``(x, counts (n,) summed over layers,
-    k_pages_g, v_pages_g)``."""
+    pool (k_pages_g/v_pages_g, one group's, stored by ``layouts``) is
+    carried whole through all of them, so runs of unlike layers cost
+    no slice and no concatenation of it, and ``kp`` / ``vp`` are the
+    carried pool with the layer's number (``PoolLayer``), so attention
+    reads its layer where it lies. Returns ``(x, counts (n,) summed
+    over layers, k_pages_g, v_pages_g)``."""
     import jax
     import jax.numpy as jnp
+
+    kl, vl = layouts
 
     def layer_body(carry, inp):
         x, kg, vg = carry
         layer, number = inp
         q, k, v = block.project(layer, x, positions)
         kg, vg = _write_kv(
-            kg, vg, number,
+            layouts, kg, vg, number,
             k.reshape((-1,) + k.shape[-2:]).astype(kg.dtype),
             v.reshape((-1,) + v.shape[-2:]).astype(vg.dtype),
             page_ids.reshape(-1), offsets.reshape(-1))
-        attn = attend(layer, q, k, v, kg[number], vg[number])
+        attn = attend(layer, q, k, v, kl.layer(kg, number),
+                      vl.layer(vg, number))
         x, counts = block.finish(layer, x, attn, valid)
         return (x, kg, vg), counts
 
@@ -2526,11 +2563,11 @@ def _sample(logits, active, rng_data, temperature, top_k):
 
 
 def _decode_program(params, k_pages, v_pages, tokens, positions,
-                    page_tables, active, rng_data, *, block,
+                    page_tables, active, rng_data, *, block, layouts,
                     temperature, top_k, paged_impl):
     """One token for one dp group's slot table.
 
-    k_pages/v_pages (1, L, Hkv, N, ps, width) — the group's pool
+    k_pages/v_pages (1, L, N, ps, lanes) — the group's pool
     shard; tokens (1, B) int32 — last sampled token per local slot;
     positions (1, B) — the ABSOLUTE position that token occupies
     (== kv entries already written); page_tables (1, B, P); active
@@ -2544,7 +2581,7 @@ def _decode_program(params, k_pages, v_pages, tokens, positions,
     k_pages_g, v_pages_g = k_pages[0], v_pages[0]
     tokens, positions = tokens[0], positions[0]
     page_tables, active = page_tables[0], active[0]
-    ps = k_pages_g.shape[3]
+    ps = layouts[0].page_size(k_pages_g)
     x = block.embed(params, tokens, positions)            # (B, D)
     # Dead writes → scratch page 0, offset 0.
     page_ids = jnp.where(
@@ -2556,7 +2593,7 @@ def _decode_program(params, k_pages, v_pages, tokens, positions,
     offsets = jnp.where(active, positions % ps, 0).astype(jnp.int32)
     lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
     x, counts, k_pages_g, v_pages_g = _scan_layers(
-        block, params, x, k_pages_g, v_pages_g, positions, page_ids,
+        block, layouts, params, x, k_pages_g, v_pages_g, positions, page_ids,
         offsets, active,
         lambda layer, q, _k, _v, kp, vp: block.attend_decode(
             layer, q, kp, vp, lengths, page_tables, paged_impl))
@@ -2566,11 +2603,11 @@ def _decode_program(params, k_pages, v_pages, tokens, positions,
 
 
 def _prefill_program(params, k_pages, v_pages, page_row, live,
-                     chunk_tokens, start_pos, n_valid, *, block, first,
-                     paged_impl):
+                     chunk_tokens, start_pos, n_valid, *, block, layouts,
+                     first, paged_impl):
     """One prompt chunk for one sequence, on one dp group's shard.
 
-    k_pages/v_pages (1, L, Hkv, N, ps, width); page_row (1, P) — the
+    k_pages/v_pages (1, L, N, ps, lanes); page_row (1, P) — the
     sequence's table on its OWNER group, all-scratch elsewhere; live
     (1,) bool — True only on the owner (dead groups' writes land in
     their scratch page and their queries mask out); chunk_tokens
@@ -2595,7 +2632,7 @@ def _prefill_program(params, k_pages, v_pages, page_row, live,
     k_pages_g, v_pages_g = k_pages[0], v_pages[0]
     page_row, live = page_row[0], live[0]
     C = chunk_tokens.shape[1]
-    ps = k_pages_g.shape[3]
+    ps = layouts[0].page_size(k_pages_g)
     idx = jnp.arange(C, dtype=jnp.int32)
     abs_pos = start_pos + idx                             # (C,)
     valid = (idx < n_valid) & live
@@ -2617,7 +2654,7 @@ def _prefill_program(params, k_pages, v_pages, page_row, live,
                 layer, jax.tree.map(lambda a: a[None], q), kp, vp,
                 page_row[None], q_pos)[0]
     x, counts, k_pages_g, v_pages_g = _scan_layers(
-        block, params, x, k_pages_g, v_pages_g, abs_pos, page_ids,
+        block, layouts, params, x, k_pages_g, v_pages_g, abs_pos, page_ids,
         offsets, valid, attend)
     x_last = jax.lax.dynamic_index_in_dim(
         x, jnp.maximum(n_valid - 1, 0), axis=0, keepdims=False)
@@ -2626,14 +2663,14 @@ def _prefill_program(params, k_pages, v_pages, page_row, live,
 
 
 def _chunk_hidden(params, k_pages_g, v_pages_g, page_rows, tokens,
-                  start_pos, n_valid, active, *, block):
+                  start_pos, n_valid, active, *, block, layouts):
     """The multi-lane chunk forward SHARED by ``_chunk_program``
     (batched prefill + speculative verification) and
     ``_resident_program`` (every resident loop iteration) — ONE
     implementation, so the device-resident path cannot drift from
     the host-verified chunk math. Operates on one group's UNPACKED
     block (no leading group dim): k_pages_g/v_pages_g
-    (L, Hkv, N, ps, width); page_rows (S, P); tokens (S, C);
+    (L, N, ps, lanes); page_rows (S, P); tokens (S, C);
     start_pos, n_valid (S,); active (S,) bool. Writes every lane's
     valid tokens' KV through one batched page-row scatter and returns
     ``(x (S, C, D) final hidden states, valid (S, C), counts (n,),
@@ -2642,7 +2679,7 @@ def _chunk_hidden(params, k_pages_g, v_pages_g, page_rows, tokens,
 
     S, C = tokens.shape
     P = page_rows.shape[1]
-    ps = k_pages_g.shape[3]
+    ps = layouts[0].page_size(k_pages_g)
     idx = jnp.arange(C, dtype=jnp.int32)
     abs_pos = start_pos[:, None] + idx[None, :]           # (S, C)
     valid = (idx[None, :] < n_valid[:, None]) & active[:, None]
@@ -2656,7 +2693,7 @@ def _chunk_hidden(params, k_pages_g, v_pages_g, page_rows, tokens,
     offsets = jnp.where(valid, abs_pos % ps, 0)
     q_pos = jnp.where(valid, abs_pos, -1)                 # (S, C)
     x, counts, k_pages_g, v_pages_g = _scan_layers(
-        block, params, x, k_pages_g, v_pages_g, abs_pos, page_ids,
+        block, layouts, params, x, k_pages_g, v_pages_g, abs_pos, page_ids,
         offsets, valid,
         lambda layer, q, _k, _v, kp, vp: block.attend_chunk(
             layer, q, kp, vp, page_rows, q_pos))
@@ -2676,7 +2713,7 @@ def _argmax_chain(block, params, x, valid):
 
 def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
                    start_pos, n_valid, active, rng_data, *, block,
-                   temperature, top_k, paged_impl, emit):
+                   layouts, temperature, top_k, paged_impl, emit):
     """Multi-token chunks for a whole lane table, one dp group.
 
     The ONE program body behind both batched prefill (``emit="last"``,
@@ -2688,7 +2725,7 @@ def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
     first chunk that reduces to causal self-attention, for decode it
     verifies the drafted chain exactly as sequential steps would).
 
-    k_pages/v_pages (1, L, Hkv, N, ps, width) — the group's pool
+    k_pages/v_pages (1, L, N, ps, lanes) — the group's pool
     shard; page_rows (1, S, P); tokens (1, S, C) int32 (positions >=
     n_valid[s] are padding); start_pos (1, S) — each lane's first
     ABSOLUTE position; n_valid (1, S) — valid tokens per lane;
@@ -2718,7 +2755,7 @@ def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
     S = tokens.shape[0]
     x, valid, counts, k_pages_g, v_pages_g = _chunk_hidden(
         params, k_pages_g, v_pages_g, page_rows, tokens,
-        start_pos, n_valid, active, block=block)
+        start_pos, n_valid, active, block=block, layouts=layouts)
     if emit == "all":
         # The verification chain: logits at EVERY position, argmax
         # only (spec decode is greedy by config contract).
@@ -2736,8 +2773,8 @@ def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
 
 
 def _resident_program(params, k_pages, v_pages, page_rows, history,
-                      kv_len, budget, active, *, block, K, C, ngram,
-                      eos_id, paged_impl):
+                      kv_len, budget, active, *, block, layouts, K, C,
+                      ngram, eos_id, paged_impl):
     """Device-resident K-step decode for one dp group's slot table.
 
     A ``lax.while_loop`` of up to ``K`` iterations; each iteration
@@ -2753,7 +2790,7 @@ def _resident_program(params, k_pages, v_pages, page_rows, history,
     stopped (EOS or budget), so an all-slots-complete burst costs
     the iterations it used, not ``K``.
 
-    k_pages/v_pages (1, L, Hkv, N, ps, width); page_rows (1, B, P);
+    k_pages/v_pages (1, L, N, ps, lanes); page_rows (1, B, P);
     history (1, B, Lmax) int32 — prompt + generated so far, with
     ``history[kv_len]`` the last generated token (its KV not yet
     written, exactly the host decode invariant); kv_len (1, B) —
@@ -2827,7 +2864,7 @@ def _resident_program(params, k_pages, v_pages, page_rows, history,
             tokens = last[:, None]
         x, valid, c, kp, vp = _chunk_hidden(
             params, kp, vp, page_rows_g, tokens, kvl, n, running,
-            block=block)
+            block=block, layouts=layouts)
         nxt = _argmax_chain(block, params, x, valid)    # (B, C)
         if C > 1:
             sl = jnp.arange(C - 1, dtype=jnp.int32)

@@ -4,18 +4,25 @@ Per-request max_len buffers waste HBM quadratically under continuous
 batching (every slot reserves the worst case); the paged layout is
 virtual memory for KV instead. One reservation of ``dp_groups``
 independent pool shards of ``num_pages`` pages of ``page_size`` tokens
-each, per layer, kv-head-major:
+each, per layer, stored the way the chip tiles it (``PoolLayout``):
 
-    k_pages, v_pages: (dp_groups, n_layers, n_kv_heads, num_pages,
-                       page_size, head_dim)
+    k_pages, v_pages: (dp_groups, n_layers, num_pages, page_size,
+                       lanes)
+
+token-major, a token's row of a layer being its kv heads side by side
+in a whole number of 128-lane tiles. ``PoolLayout`` and the views it
+hands out (``PoolLayer``) are the ONLY code that knows this order:
+every writer and reader of the pool (the engine's scatter and its
+hand-over to attention, both forms of paged attention, copy-on-write,
+the KV hand-off's export and import) goes through them.
 
 What a row of each pool holds is the model's (``kind``, from its
 serving block, ``serving/blocks.py``): ``"kv"`` a key and a value a kv
 head, both ``head_dim`` wide; ``"latent"`` one row a token shared by
 all heads (``n_kv_heads == 1``), the latent vector (``head_dim`` =
 its rank) in ``k_pages`` and the rotary key (``v_head_dim`` wide) in
-``v_pages``. Everything below the arrays' last axis (tables,
-allocator, prefix index, copy-on-write) is one code path.
+``v_pages``. Everything but the width of a row (tables, allocator,
+prefix index, copy-on-write) is one code path.
 
 A sequence owns an ordered list of physical page ids (its PAGE TABLE)
 inside ONE dp group's shard; logical position ``p`` lives in slot
@@ -36,9 +43,9 @@ read into a softmax.
 LEADING dp-group axis over the plan's ``dp`` mesh axis (the decode
 engine's batch-parallel slot shard — each dp group decodes only its
 own slots against its own pool shard, serving/engine.py) and along the
-kv-head axis over the plan's ``tp`` axis (the decode plan's head
-currency), replicated elsewhere. Page tables/lengths are tiny int32
-rows and stay host-side.
+lanes, a shard's kv heads in whole tiles of their own, over the plan's
+``tp`` axis (the decode plan's head currency), replicated elsewhere.
+Page tables/lengths are tiny int32 rows and stay host-side.
 
 **Accounting**: the allocator is host-side (plain Python — allocation
 decisions are control flow, not math), PER GROUP, and every alloc/free
@@ -75,9 +82,237 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import numpy as np
 
 from distributed_training_tpu.telemetry import event
+
+LANES = 128     # the minor dimension of a TPU tile, whatever the dtype
+
+
+def _padded(xp, x, axis: int, size: int):
+    """``x`` with zeros appended along ``axis`` up to ``size``."""
+    short = size - x.shape[axis]
+    if not short:
+        return x
+    zeros = x.shape[:axis] + (short,) + x.shape[axis + 1:]
+    return xp.concatenate([x, xp.zeros(zeros, x.dtype)], axis=axis)
+
+
+@dataclass(frozen=True)
+class PoolLayout:
+    """How a pool stores a token's row of a layer, and every index
+    into it: a function of the widths the cache can observe.
+
+    One group's pool is ``(n_layers, num_pages, page_size, lanes)``.
+    Token-major, because the scatter that writes it decides the order
+    XLA carries it in whatever order it is declared in. A token's row
+    is cut into TILES of whole 128-lane multiples, because a minor
+    dimension under 128 costs 128 lanes of HBM in every read and
+    write: ``per`` kv heads of ``width`` side by side a tile (heads of
+    64 go two a tile, 25 of them as 13 tiles with the last half tile
+    zero and never read into a result; a head of 128 or a 512-wide
+    latent row is one lane-full tile as it is). Declared in that
+    order the entry parameter, a loop's carry and the donated result
+    have one layout and no program re-lays the pool out. With the
+    heads sharded over ``shards`` devices each shard's heads fill
+    tiles of their own, so a shard holds whole rows.
+
+    The contractions of paged attention run on the tiles as they lie
+    (``PoolLayer.slots`` / ``.pages``): ``spread`` puts each query
+    head on the lanes its kv head has in its tile, zeros on the
+    others', and ``collect`` takes each head's own lanes of the
+    result. The zeros cost MXU rows, not bytes, and change no sum.
+    """
+
+    heads: int
+    width: int
+    shards: int = 1
+
+    @property
+    def per(self) -> int:
+        """kv heads a tile."""
+        return max(1, LANES // self.width)
+
+    @property
+    def tile(self) -> int:
+        """Lanes a tile."""
+        return -(-self.per * self.width // LANES) * LANES
+
+    @property
+    def tiles(self) -> int:
+        """Tiles a token's row of a layer, over all shards."""
+        return self.shards * -(-self.heads // self.shards // self.per)
+
+    @property
+    def lanes(self) -> int:
+        """Lanes a token's row of a layer takes, padding included."""
+        return self.tiles * self.tile
+
+    def shape(self, n_layers: int, num_pages: int,
+              page_size: int) -> tuple:
+        """One group's pool."""
+        return (n_layers, num_pages, page_size, self.lanes)
+
+    def _to_tiles(self, x, axis: int):
+        """``x`` with its kv-head axis ``(heads,)`` made ``(tiles,
+        per)``: each shard's heads dealt to its own tiles, zeros where
+        a shard's last tile is short of heads."""
+        xp = np if isinstance(x, np.ndarray) else jax.numpy
+        axis %= x.ndim
+        lead, rest = x.shape[:axis], x.shape[axis + 1:]
+        x = x.reshape(lead + (self.shards, -1) + rest)
+        x = _padded(xp, x, axis + 1,
+                    self.tiles // self.shards * self.per)
+        return x.reshape(lead + (self.tiles, self.per) + rest)
+
+    def _from_tiles(self, x, axis: int):
+        """``_to_tiles`` the other way, the padding heads dropped."""
+        axis %= x.ndim
+        lead, rest = x.shape[:axis], x.shape[axis + 2:]
+        x = x.reshape(lead + (self.shards, -1) + rest)
+        x = x[(slice(None),) * (axis + 1)
+              + (slice(self.heads // self.shards),)]
+        return x.reshape(lead + (self.heads,) + rest)
+
+    def pack(self, rows):
+        """``(..., heads, width)`` rows cut into tiles, ``(..., tiles,
+        tile)``; numpy in, numpy out."""
+        xp = np if isinstance(rows, np.ndarray) else jax.numpy
+        rows = self._to_tiles(rows, -2)
+        rows = rows.reshape(rows.shape[:-2] + (self.per * self.width,))
+        return _padded(xp, rows, rows.ndim - 1, self.tile)
+
+    def stored(self, rows):
+        """``(..., heads, width)`` rows as the pool's last axis holds
+        them, ``(..., lanes)``."""
+        tiles = self.pack(rows)
+        return tiles.reshape(tiles.shape[:-2] + (self.lanes,))
+
+    def unpack(self, tiles):
+        """``pack`` the other way, the padding dropped: ``(...,
+        tiles, tile)`` to ``(..., heads, width)``."""
+        rows = tiles[..., :self.per * self.width].reshape(
+            tiles.shape[:-1] + (self.per, self.width))
+        return self._from_tiles(rows, -3)
+
+    def spread(self, q):
+        """Queries ``(B, S, H, width)``, ``H`` a multiple of the kv
+        heads, onto the tiles: ``(B, S, tiles, per * group, tile)``,
+        each query head on the lanes of its kv head and zero on its
+        tile-mates', so that a contraction over a tile's lanes is the
+        head's own."""
+        jnp = jax.numpy
+        B, S, H, _ = q.shape
+        if H % self.heads:
+            raise ValueError(f"n_heads {H} not divisible by "
+                             f"n_kv_heads {self.heads}")
+        q = self._to_tiles(
+            q.reshape(B, S, self.heads, H // self.heads, self.width), 2)
+        q = jnp.einsum("bstjgw,jk->bstjgkw", q,
+                       jnp.eye(self.per, dtype=q.dtype))
+        return _padded(jnp, q.reshape(q.shape[:3] + (
+            -1, self.per * self.width)), 4, self.tile)
+
+    def collect(self, out):
+        """What ``spread`` queries gave, ``(B, S, tiles, per * group,
+        tile)``, back to ``(B, S, H, width)``: of each query head's
+        tile the lanes of its own kv head."""
+        B, S = out.shape[:2]
+        out = out[..., :self.per * self.width].reshape(
+            B, S, self.tiles, self.per, -1, self.per, self.width)
+        out = jax.numpy.einsum("bstjgjw->bstjgw", out)
+        return self._from_tiles(out, 2).reshape(B, S, -1, self.width)
+
+    def tiled(self, stored):
+        """``(..., lanes)`` as the pool holds it, cut into its tiles:
+        ``(..., tiles, tile)``."""
+        return stored.reshape(stored.shape[:-1]
+                              + (self.tiles, self.tile))
+
+    def page_size(self, pool) -> int:
+        """Slots a page of a group's pool."""
+        return pool.shape[2]
+
+    def write(self, pool, layer, page_ids, offsets, rows):
+        """Scatter ``rows (B, heads, width)`` into ``layer`` of a
+        group's pool at ``(page_ids, offsets)`` (each ``(B,)``), where
+        the pool lies."""
+        return pool.at[layer, page_ids, offsets].set(self.stored(rows))
+
+    def layer(self, pool, number) -> "PoolLayer":
+        """Layer ``number`` of a group's carried pool, to be read."""
+        return PoolLayer(self, pool, number)
+
+    def take_pages(self, pools, groups, pages):
+        """Pages ``(groups[i], pages[i])`` of the whole (grouped)
+        pool, every layer: ``(n, n_layers, heads, page_size, width)``
+        on the device."""
+        got = self.unpack(self.tiled(pools[groups, :, pages]))
+        return got.transpose(0, 1, 3, 2, 4)          # from (n, L, ps, ..)
+
+    def put_pages(self, pools, groups, pages, chunks):
+        """``take_pages`` the other way: the whole pool with ``chunks
+        (n, n_layers, heads, page_size, width)`` written over pages
+        ``(groups[i], pages[i])``."""
+        return pools.at[groups, :, pages].set(
+            self.stored(chunks.transpose(0, 1, 3, 2, 4)))
+
+
+def copy_pages(pool, src, dst):
+    """A group's pool with pages ``src`` copied over pages ``dst``
+    (each ``(W,)``), every layer."""
+    return pool.at[:, dst].set(pool[:, src])
+
+
+class PoolLayer:
+    """One layer of a group's carried pool, handed to attention
+    unread: the carried pool and the layer's number, so that a reader
+    indexes what it needs straight out of the pool with no slice of
+    the layer first. Both reads give tiles as they are stored
+    (``PoolLayout``: ``layout.unpack`` makes heads of them, ``spread``
+    and ``collect`` contract on them as they lie). A pytree (the pool
+    and the number its leaves), so it crosses ``jax.jit`` like the
+    arrays it stands for."""
+
+    def __init__(self, layout: PoolLayout, pool, number):
+        self.layout, self.pool, self.number = layout, pool, number
+
+    num_pages = property(lambda self: self.pool.shape[1])
+    page_size = property(lambda self: self.layout.page_size(self.pool))
+    dtype = property(lambda self: self.pool.dtype)
+
+    def slots(self):
+        """Every slot of the layer in physical order, ``(num_pages *
+        page_size, tiles, tile)``: the pool form's read."""
+        tiles = self.layout.tiled(self.pool[self.number])
+        return tiles.reshape((-1,) + tiles.shape[2:])
+
+    def pages(self, page_indices):
+        """The pages of ``page_indices (B, P)`` dense in table order,
+        ``(B, P * page_size, tiles, tile)``: the gather form's read,
+        indexed ``(layer, page)`` out of the carried pool. Slot ``s``
+        of row ``b`` is logical position ``s`` of the sequence."""
+        tiles = self.layout.tiled(self.pool[self.number, page_indices])
+        return tiles.reshape(tiles.shape[:1] + (-1,) + tiles.shape[3:])
+
+
+def as_layer(pages) -> PoolLayer:
+    """Head-major keys or values ``(heads, num_pages, page_size,
+    width)``, the way a test or a calibration table writes a layer
+    down, as the one layer of a pool the cache would store."""
+    heads, _num_pages, _page_size, width = pages.shape
+    layout = PoolLayout(heads, width)
+    # The number on the device like the pool: as a Python int it would
+    # be sent along with every call of a jitted reader.
+    return layout.layer(
+        layout.stored(pages.transpose(1, 2, 0, 3))[None],
+        jax.numpy.zeros((), jax.numpy.int32))
+
+
+jax.tree_util.register_pytree_node(
+    PoolLayer, lambda v: ((v.pool, v.number), v.layout),
+    lambda layout, leaves: PoolLayer(layout, *leaves))
 
 
 @dataclass(frozen=True)
@@ -132,27 +367,36 @@ class PagedCacheConfig:
     def kv_bytes_per_token(self) -> int:
         """HBM cost of one cached token across all layers (a row of
         each pool; lane padding not counted)."""
-        import jax.numpy as jnp  # numpy alone has no bfloat16
-
-        itemsize = jnp.dtype(self.dtype).itemsize
+        # numpy alone has no bfloat16
+        itemsize = jax.numpy.dtype(self.dtype).itemsize
         return (self.n_layers * self.n_kv_heads
                 * (self.head_dim + self.v_head_dim) * itemsize)
+
+
+def kv_shards(mesh, kv_axis: str | None) -> int:
+    """Devices the pool's kv heads are sharded over: the extent of
+    ``kv_axis`` on ``mesh``, 1 without either."""
+    if mesh is None or not kv_axis:
+        return 1
+    return dict(zip(mesh.axis_names, mesh.devices.shape)).get(
+        kv_axis, 1)
 
 
 def pool_sharding(mesh, n_kv_heads: int, dp_groups: int,
                   kv_axis: str | None, dp_axis: str | None):
     """The pool's NamedSharding on ``mesh`` (None when no mesh):
-    leading group dim over ``dp_axis``, kv-head dim over ``kv_axis``,
-    each when its extent > 1. ONE resolution shared by the cache's
-    device_put and the engine's program ``out_shardings``
-    (serving/engine.py) — if they disagreed, every step's donated
-    pool would come back in a different layout and the decode program
-    would recompile mid-storm."""
+    leading group dim over ``dp_axis``, the lanes (a shard's kv heads
+    in whole rows, ``PoolLayout``) over ``kv_axis``, each when its
+    extent > 1. ONE resolution shared by the cache's device_put and
+    the engine's program ``out_shardings`` (serving/engine.py) — if
+    they disagreed, every step's donated pool would come back in a
+    different layout and the decode program would recompile
+    mid-storm."""
     if mesh is None:
         return None
     from jax.sharding import NamedSharding, PartitionSpec as P
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    kv_ax = kv_axis if kv_axis and sizes.get(kv_axis, 1) > 1 else None
+    kv_ax = kv_axis if kv_shards(mesh, kv_axis) > 1 else None
     if kv_ax is not None and n_kv_heads % sizes[kv_ax]:
         raise ValueError(
             f"kv pool cannot shard {n_kv_heads} kv heads over "
@@ -163,13 +407,13 @@ def pool_sharding(mesh, n_kv_heads: int, dp_groups: int,
             f"pool has {dp_groups} dp group(s) but mesh axis "
             f"'{dp_axis}' has extent {sizes[dp_ax]} — the allocator "
             "groups must be the mesh's dp groups")
-    return NamedSharding(mesh, P(dp_ax, None, kv_ax))
+    return NamedSharding(mesh, P(dp_ax, None, None, None, kv_ax))
 
 
 class PagedKVCache:
     """The pool + its per-group host-side allocators and page tables.
 
-    ``mesh``/``kv_axis``/``dp_axis``: shard the pools' kv-head dim
+    ``mesh``/``kv_axis``/``dp_axis``: shard the pools' kv heads
     over ``kv_axis`` and the leading group dim over ``dp_axis``
     (either skipped when its axis has extent 1 or no mesh is given).
     ``cfg.dp_groups`` must equal the ``dp_axis`` extent when that axis
@@ -182,26 +426,24 @@ class PagedKVCache:
     def __init__(self, cfg: PagedCacheConfig, mesh=None,
                  kv_axis: str | None = None,
                  dp_axis: str | None = "dp"):
-        import jax
         import jax.numpy as jnp
 
         self.cfg = cfg
-        lead = (cfg.dp_groups, cfg.n_layers, cfg.n_kv_heads,
-                cfg.num_pages, cfg.page_size)
-        dt = jnp.dtype(cfg.dtype)
         self.sharding = sharding = pool_sharding(
             mesh, cfg.n_kv_heads, cfg.dp_groups, kv_axis, dp_axis)
+        shards = kv_shards(mesh, kv_axis)
+        self.k_layout, self.v_layout = self.layouts(cfg, shards)
 
-        def pool(width):
+        def pool(shape):
             # Two DISTINCT buffers: k and v are donated separately to
             # the jitted programs, and donating one aliased array
             # twice is an XLA error.
-            z = jnp.zeros(lead + (width,), dt)
+            z = jnp.zeros(shape, jnp.dtype(cfg.dtype))
             return jax.device_put(z, sharding) \
                 if sharding is not None else z
 
-        self.k_pages = pool(cfg.head_dim)
-        self.v_pages = pool(cfg.v_head_dim)
+        self.k_pages, self.v_pages = map(
+            pool, self.pool_shapes(cfg, shards))
         # Host allocator state, PER GROUP. Free lists are LIFO:
         # recently-freed pages are re-handed first (warm in cache, and
         # deterministic for the tests' join/evict permutations).
@@ -227,6 +469,58 @@ class PagedKVCache:
         self._page_keys: list[dict[int, set]] = [
             {} for _ in range(cfg.dp_groups)]
         self._registered: dict[object, int] = {}
+
+    # -- the stored layout -------------------------------------------------
+
+    @staticmethod
+    def layouts(cfg: PagedCacheConfig, shards: int = 1) -> tuple:
+        """``(k_layout, v_layout)``: how the two pools of ``cfg``
+        store a row, their heads over ``shards`` devices. The one
+        place a layout is chosen, from the widths alone; the engine's
+        programs take theirs from here as the cache does."""
+        return (PoolLayout(cfg.n_kv_heads, cfg.head_dim, shards),
+                PoolLayout(cfg.n_kv_heads, cfg.v_head_dim, shards))
+
+    @classmethod
+    def pool_shapes(cls, cfg: PagedCacheConfig,
+                    shards: int = 1) -> tuple:
+        """The shapes of ``k_pages`` and ``v_pages``, nothing
+        allocated (what an abstract lowering needs)."""
+        return tuple(
+            (cfg.dp_groups,) + lay.shape(cfg.n_layers, cfg.num_pages,
+                                         cfg.page_size)
+            for lay in cls.layouts(cfg, shards))
+
+    def footprint(self) -> dict:
+        """What the pools take: their stored shapes, the bytes of the
+        rows alone (``pool_bytes``) and as stored, every row in whole
+        128-lane tiles (``pool_bytes_tiled``). A program whose
+        temporaries reach these holds a copy of the pool."""
+        itemsize = self.k_pages.dtype.itemsize
+        slots = (self.cfg.dp_groups * self.cfg.num_pages
+                 * self.cfg.page_size)
+        return {
+            "pool_shapes": [list(self.k_pages.shape),
+                            list(self.v_pages.shape)],
+            "pool_bytes": slots * self.cfg.kv_bytes_per_token(),
+            "pool_bytes_tiled": slots * self.cfg.n_layers * itemsize
+            * (self.k_layout.lanes + self.v_layout.lanes)}
+
+    def read_pages(self, groups, pages) -> tuple:
+        """Pages ``(groups[i], pages[i])`` of both pools, each ``(n,
+        n_layers, n_kv_heads, page_size, width)``, still on the
+        device: ONE slice a pool, so that the caller's fetch moves only
+        the named pages."""
+        return (self.k_layout.take_pages(self.k_pages, groups, pages),
+                self.v_layout.take_pages(self.v_pages, groups, pages))
+
+    def write_pages(self, groups, pages, k_chunks, v_chunks) -> None:
+        """``read_pages`` the other way: one scatter a pool."""
+        self.update_pools(
+            self.k_layout.put_pages(self.k_pages, groups, pages,
+                                    k_chunks),
+            self.v_layout.put_pages(self.v_pages, groups, pages,
+                                    v_chunks))
 
     # -- allocator ---------------------------------------------------------
 

@@ -294,6 +294,7 @@ def test_decode_shaped_paged_attention_reads_pool_in_place(S):
     from jax.sharding import SingleDeviceSharding
 
     from distributed_training_tpu.ops import paged_attention as pa
+    from distributed_training_tpu.serving.kv_cache import PoolLayout
 
     try:
         from distributed_training_tpu.runtime import topology_runtime
@@ -307,7 +308,8 @@ def test_decode_shaped_paged_attention_reads_pool_in_place(S):
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
 
-    pool = shape((H, N, ps, hd), jnp.bfloat16)
+    layout = PoolLayout(H, hd)
+    pool = layout.layer(shape(layout.shape(1, N, ps), jnp.bfloat16), 0)
     with pa.observe_forms() as seen:
         text = jax.jit(pa.paged_attention_chunk).lower(
             shape((B, S, H, hd), jnp.bfloat16), pool, pool,
@@ -320,6 +322,73 @@ def test_decode_shaped_paged_attention_reads_pool_in_place(S):
                   for dims in re.findall(r"f32\[([0-9,]+)\]", text))
     assert largest < gathered / 2, (largest, gathered)
     assert text.count(" convolution(") == 2     # both dots on the MXU
+
+
+def test_resident_decode_holds_no_copy_of_the_pool():
+    """``jit_serving_resident_decode`` at ``gpt2-xl``'s widths (25
+    heads of 64, 16 slots, 385 pages, bfloat16; 4 layers of 48)
+    compiles for a v5e with no ``copy`` whose shape is the whole pool
+    and with temporaries under half the pool's bytes. Stored ``(L,
+    Hkv, N, ps, 64)`` every program held the pool again re-laid-out,
+    heads padded 25 -> 32 and 64 lanes -> 128: 2.56 times its bytes
+    in temporaries (``device.reserved_hbm_gb.decode`` 4.85: ledger,
+    PR 28) and four whole-pool copies an iteration. A layout that
+    brings them back fails here, not on the chip. The entry
+    parameters are given the default layouts the engine's arrays
+    have: left free, the compiler picks others and the count
+    misleads."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_training_tpu.models import build_model
+    from distributed_training_tpu.serving import engine as E
+    from distributed_training_tpu.serving.kv_cache import (
+        PagedCacheConfig, PagedKVCache)
+
+    try:
+        from distributed_training_tpu.runtime import topology_runtime
+        chip = SingleDeviceSharding(
+            topology_runtime(1, "v5e:2x2").mesh.devices.flat[0])
+    except Exception as e:  # pragma: no cover - no libtpu
+        pytest.skip(f"device-less TPU topology unavailable: {e}")
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=Format(
+            Layout(major_to_minor=tuple(range(len(dims)))), chip))
+
+    model = build_model(
+        "gpt2", dtype="bfloat16", vocab_size=50304, d_model=1600,
+        n_layers=4, n_heads=25, max_seq_len=1024,
+        pos_encoding="learned", tie_embeddings=True)
+    ecfg = E.EngineConfig(
+        max_batch=16, num_pages=385, page_size=16, max_seq_len=1024,
+        prefill_chunk=128, resident_k=8, prefill_slots=4)
+    block = model.serving_block()
+    pools = [shape(dims, jnp.bfloat16)
+             for dims in PagedKVCache.pool_shapes(PagedCacheConfig(
+                 **block.cache, page_size=16, num_pages=385,
+                 max_seq_len=1024))]
+    params = jax.tree.map(
+        lambda a: shape(a.shape, jnp.bfloat16),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    B, P = 16, 64
+    compiled = E.build_resident_decode_fn(block, ecfg).lower(
+        params, *pools, shape((1, B, P), jnp.int32),
+        shape((1, B, 1024), jnp.int32), shape((1, B), jnp.int32),
+        shape((1, B), jnp.int32), shape((1, B), jnp.bool_)).compile()
+    pool_elems = math.prod(pools[0].shape)
+    whole = [line.strip()[:120] for line in compiled.as_text().splitlines()
+             if (m := re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([0-9,]+)\]"
+                               r"\S* copy\(", line))
+             and math.prod(map(int, m.group(1).split(","))) == pool_elems]
+    assert not whole, whole
+    pool_bytes = sum(2 * math.prod(p.shape) for p in pools)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes / 2, (temp, pool_bytes)
 
 
 def test_collectives_report_counts_pallas_calls():

@@ -19,6 +19,7 @@ from distributed_training_tpu.ops import paged_attention as pa
 from distributed_training_tpu.serving.engine import (Engine,
                                                      EngineConfig,
                                                      Request)
+from distributed_training_tpu.serving.kv_cache import as_layer
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KW = dict(vocab_size=96, d_model=32, n_layers=3, n_dense_layers=1,
@@ -188,8 +189,9 @@ def test_engine_matches_the_reference(ref, cadence):
                for f in forms.values()), forms
     assert eng.cache.cfg.kind == "latent"
     assert eng.cache.cfg.kv_bytes_per_token() == 3 * (16 + 4) * 4
-    assert eng.cache.k_pages.shape == (1, 3, 1, 80, 4, 16)
-    assert eng.cache.v_pages.shape == (1, 3, 1, 80, 4, 4)
+    # A token's row of a layer in whole 128-lane tiles, token-major.
+    assert eng.cache.k_pages.shape == (1, 3, 80, 4, 128)
+    assert eng.cache.v_pages.shape == (1, 3, 80, 4, 128)
 
 
 def latent_case(B, S, P, H=4, seed=0):
@@ -206,15 +208,16 @@ def latent_case(B, S, P, H=4, seed=0):
     w_uv = jax.random.normal(ks[5], (16, H, 8), jnp.float32)
     q_pos = (P * 4 - S + jnp.arange(S, dtype=jnp.int32))[None].repeat(B, 0)
     q_pos = q_pos.at[0, 0].set(-1)     # a padding query
-    return q_nope, q_rope, c_pages, r_pages, rows, q_pos, w_uk, w_uv
+    return (q_nope, q_rope, as_layer(c_pages), as_layer(r_pages), rows,
+            q_pos, w_uk, w_uv, c_pages, r_pages)
 
 
 @pytest.mark.parametrize("form", ["absorbed", "expanded"])
 def test_latent_attention_forms_agree(monkeypatch, form):
     """Each form against attention written out over the expanded keys
     and values, on a case the rule would give to another form too."""
-    args = latent_case(B=3, S=5, P=6)
-    q_nope, q_rope, c_pages, r_pages, rows, q_pos, w_uk, w_uv = args
+    *args, c_pages, r_pages = latent_case(B=3, S=5, P=6)
+    q_nope, q_rope, _c, _r, rows, q_pos, w_uk, w_uv = args
     monkeypatch.setattr(pa, "latent_form", lambda *a, **k: form)
     with pa.observe_forms() as seen:
         got = pa.latent_attention_chunk(*args)

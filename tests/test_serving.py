@@ -18,6 +18,8 @@ The correctness contracts the subsystem ships on:
   (SPMD001 == 0, the serving_decode_planned pin).
 """
 
+import dataclasses
+import functools
 import json
 import time
 
@@ -39,6 +41,7 @@ from distributed_training_tpu.serving.engine import (  # noqa: E402
 from distributed_training_tpu.serving.kv_cache import (  # noqa: E402
     PagedCacheConfig,
     PagedKVCache,
+    PoolLayout,
 )
 
 
@@ -81,6 +84,19 @@ def _full_context_greedy(model, params, prompt, n):
 # ---------------------------------------------------------------------------
 
 
+def _as_layer(pages, dtype=jnp.float32):
+    """Head-major ``(Hkv, N, ps, hd)`` keys or values as paged
+    attention is handed them: the middle layer of a pool the cache
+    would store (``PoolLayout``), the layers around it NaN, so a read
+    of any layer but the one named shows."""
+    Hkv, _N, _ps, hd = pages.shape
+    layout = PoolLayout(Hkv, hd)
+    stored = layout.stored(np.asarray(pages).transpose(1, 2, 0, 3))
+    pool = np.full((3,) + stored.shape, np.nan, np.float32)
+    pool[1] = stored
+    return layout.layer(jnp.asarray(pool, dtype), jnp.int32(1))
+
+
 def test_paged_attention_matches_dense_reference():
     """paged_attention over scattered pages == naive attention over
     the equivalent dense K/V, exactly (same fp32 softmax path)."""
@@ -112,8 +128,8 @@ def test_paged_attention_matches_dense_reference():
             k_pages[:, pid] = dense_k[b, chunk].transpose(1, 0, 2)
             v_pages[:, pid] = dense_v[b, chunk].transpose(1, 0, 2)
     q = rng.standard_normal((B, H, hd)).astype(np.float32)
-    got = paged_attention(jnp.asarray(q), jnp.asarray(k_pages),
-                          jnp.asarray(v_pages),
+    got = paged_attention(jnp.asarray(q), _as_layer(k_pages),
+                          _as_layer(v_pages),
                           jnp.asarray(lengths),
                           jnp.asarray(tables), impl="ref")
     for b in range(B):
@@ -169,8 +185,8 @@ def _paged_chunk_case(S, group, dtype, seed=0):
     if S > 1:
         q_pos[0, -1] = -1               # a padding query
     q = rng.standard_normal((B, S, H, hd)).astype(np.float32)
-    args = (jnp.asarray(q, dtype), jnp.asarray(k_pages, dtype),
-            jnp.asarray(v_pages, dtype), jnp.asarray(tables),
+    args = (jnp.asarray(q, dtype), _as_layer(k_pages, dtype),
+            _as_layer(v_pages, dtype), jnp.asarray(tables),
             jnp.asarray(q_pos))
     return args, dense_k, dense_v
 
@@ -233,23 +249,31 @@ def test_paged_chunk_forms_match_dense_reference(S, group, dtype):
 
 
 # name: (q (B, S, H, hd), pool (Hkv, N, ps, hd), P), the form PERF.md
-# section 6 (PR 26) records from the chip.
+# section 6 (PR 29) records as the faster on the chip: the thirteen
+# shapes of benchmarks/paged_form_table.py.
+_XL, _SMALL = (25, 385, 16, 64), (12, 3073, 16, 64)
 _CALIBRATION = {
-    "xl.resident_16x1": ((16, 1, 25, 64), (25, 385, 16, 64), 64, "pool"),
-    "xl.prefill_batch_4x128": ((4, 128, 25, 64), (25, 385, 16, 64), 64,
-                               "gather"),
-    "xl.prefill_cont_1x128": ((1, 128, 25, 64), (25, 385, 16, 64), 64,
-                              "gather"),
-    "xl.spec_16x4": ((16, 4, 25, 64), (25, 385, 16, 64), 64, "pool"),
-    "small.resident_64x1": ((64, 1, 12, 64), (12, 3073, 16, 64), 64,
-                            "pool"),
+    "xl.resident_16x1": ((16, 1, 25, 64), _XL, 64, "pool"),
+    "xl.prefill_batch_4x128": ((4, 128, 25, 64), _XL, 64, "gather"),
+    "xl.prefill_cont_1x128": ((1, 128, 25, 64), _XL, 64, "gather"),
+    "xl.spec_16x4": ((16, 4, 25, 64), _XL, 64, "pool"),
+    "small.resident_64x1": ((64, 1, 12, 64), _SMALL, 64, "pool"),
+    "xl.16x8": ((16, 8, 25, 64), _XL, 64, "pool"),
+    "xl.16x16": ((16, 16, 25, 64), _XL, 64, "gather"),
+    "xl.16x32": ((16, 32, 25, 64), _XL, 64, "gather"),
+    "xl.4x32": ((4, 32, 25, 64), _XL, 64, "gather"),
+    "small.spec_64x4": ((64, 4, 12, 64), _SMALL, 64, "gather"),
+    "small.prefill_batch_8x128": ((8, 128, 12, 64), _SMALL, 64,
+                                  "gather"),
+    "small.16x1": ((16, 1, 12, 64), _SMALL, 64, "gather"),
+    "xl.gqa_16x1": ((16, 1, 25, 64), (5, 385, 16, 64), 64, "pool"),
 }
 
 
 @pytest.mark.parametrize("name", sorted(_CALIBRATION))
 def test_chunk_form_rule_at_calibration_shapes(name):
-    """The rule alone: the five shapes it was calibrated on give the
-    forms the chip found faster (PERF.md section 6, PR 26)."""
+    """The rule alone: the thirteen shapes it was calibrated on give
+    the forms the chip found faster (PERF.md section 6, PR 29)."""
     from distributed_training_tpu.ops.paged_attention import (
         chunk_form)
 
@@ -263,8 +287,8 @@ def test_observe_forms_sees_the_form_when_traced_not_when_run():
     from distributed_training_tpu.ops import paged_attention as pa
 
     args, _k, _v = _paged_chunk_case(1, 1, jnp.float32)
-    want = pa.chunk_form(args[0].shape, args[1].shape, args[3].shape,
-                         4)
+    want = pa.chunk_form(args[0].shape, pa._held(args[1]),
+                         args[3].shape, 4)
     fn = jax.jit(pa.paged_attention_chunk)
     with pa.observe_forms() as seen:
         fn(*args)
@@ -340,16 +364,81 @@ def test_pool_exhaustion_is_backpressure_not_corruption(tiny_model):
 # ---------------------------------------------------------------------------
 
 
-def test_paged_engine_matches_full_context_greedy(tiny_model):
+# The widths the pool's stored layout (kv_cache.PoolLayout) has to
+# serve, by what they do to a token's row of 128-lane tiles.
+_LAYOUT_MODELS = {
+    # 2 kv heads of 16 under 4 query heads: a quarter of one tile.
+    "tiny_gqa16": dict(d_model=64, n_heads=4, n_kv_heads=2,
+                       dtype="float32"),
+    # 3 heads of 64: two tiles, the last half empty (gpt2-xl's 25).
+    "odd64": dict(d_model=192, n_heads=3, n_kv_heads=3,
+                  dtype="float32"),
+    "odd64_bf16": dict(d_model=192, n_heads=3, n_kv_heads=3,
+                       dtype="bfloat16"),
+    # 4 heads of 64: two full tiles (gpt2-small's 12).
+    "even64_bf16": dict(d_model=256, n_heads=4, n_kv_heads=4,
+                        dtype="bfloat16"),
+    # 3 kv heads of 64 under 6 query heads: tile-mates AND a group.
+    "gqa64": dict(d_model=384, n_heads=6, n_kv_heads=3,
+                  dtype="float32"),
+    # Heads of 128: a head is a tile, nothing is packed.
+    "h128_bf16": dict(d_model=256, n_heads=2, n_kv_heads=2,
+                      dtype="bfloat16"),
+}
+_LAYOUT_CADENCES = {
+    "plain": dict(),
+    "spec4": dict(spec_k=4),
+    "resident8": dict(resident_k=8),
+    "sequential": dict(prefill_mode="sequential"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _layout_model(shape):
+    cfg = TransformerConfig(
+        vocab_size=256, n_layers=2, max_seq_len=128,
+        param_dtype="float32", pos_encoding="rope",
+        tie_embeddings=False, **_LAYOUT_MODELS[shape])
+    model = Transformer(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    # The dense reference: the same weights through ``model.apply`` in
+    # float32, the full context at once.
+    dense = Transformer(dataclasses.replace(cfg, dtype="float32"))
+    return model, params, jax.jit(
+        lambda ids: dense.apply(params, ids)[0])
+
+
+@pytest.mark.parametrize("cadence", list(_LAYOUT_CADENCES))
+@pytest.mark.parametrize("shape", list(_LAYOUT_MODELS))
+def test_paged_engine_matches_full_context_greedy(shape, cadence):
     """The satellite pin: the serving KV-cache decode produces
     token-for-token what re-running the full context per token
-    produces (greedy)."""
-    model, params = tiny_model
-    prompt = np.asarray([5, 7, 11, 13, 17, 19, 23, 29, 31, 37],
-                        np.int32)  # 10 tokens: crosses the 8-chunk
-    eng = _engine(model, params)
-    got = eng.generate(prompt, 12)
-    assert got == _full_context_greedy(model, params, prompt, 12)
+    produces (greedy), whatever the kv heads' widths make of a
+    token's row in the pool (``_LAYOUT_MODELS``) and through every
+    cadence. In float32 every streamed token IS the dense reference's
+    argmax over what came before it; a bfloat16 engine's token may be
+    another where two logits lie nearer than its rounding, so it is
+    held to the reference's top logit by a gap."""
+    model, params, dense = _layout_model(shape)
+    eng = _engine(model, params, **_LAYOUT_CADENCES[cadence])
+    # 10 tokens cross the 8-chunk; 3 and 20 end inside a page.
+    prompts = _ragged_prompts()
+    for i, p in enumerate(prompts):
+        eng.submit(Request(id=f"r{i}", prompt=p, max_new_tokens=12))
+    eng.run_until_drained()
+    done = {r["id"]: r["tokens"] for r in eng.completed}
+    exact = model.cfg.dtype == "float32"
+    for i, p in enumerate(prompts):
+        got = done[f"r{i}"]
+        assert len(got) == 12
+        ids = np.concatenate([p, np.asarray(got[:-1], np.int32)])
+        rows = np.asarray(dense(jnp.asarray(ids[None], jnp.int32)))[
+            0, len(p) - 1:]
+        if exact:
+            assert got == [int(t) for t in rows.argmax(-1)], f"r{i}"
+        else:
+            gaps = rows.max(-1) - rows[np.arange(12), got]
+            assert gaps.max() < 0.05, (f"r{i}", gaps)
 
 
 def test_batch_composition_independence(tiny_model):
@@ -1923,8 +2012,22 @@ def test_engine_reports_paged_form_per_program(tiny_model, tmp_path,
     assert forms == {p: want[p] for p in programs}
     warm = [r for r in records if r["kind"] == "serving_warmup"]
     assert len(warm) == 1
-    assert warm[0]["programs"] == [
-        {"program": p, "paged_form": f} for p, f in forms.items()]
+    assert [(p["program"], p["paged_form"])
+            for p in warm[0]["programs"]] == list(forms.items())
+    # What says that the stored layout engaged: the pools' shapes and
+    # bytes, and each program's temporaries beside them. Two kv heads
+    # of 16 are a quarter of one 128-lane tile.
+    ec = eng.cfg
+    assert warm[0]["pool_shapes"] == [
+        [1, c.n_layers, ec.num_pages, ec.page_size, 128]] * 2
+    assert warm[0]["pool_bytes"] == (
+        ec.num_pages * ec.page_size * c.n_layers * 2 * 2 * 16 * 4)
+    assert warm[0]["pool_bytes_tiled"] == 4 * warm[0]["pool_bytes"]
+    assert all(isinstance(p["temp_bytes"], int)
+               for p in warm[0]["programs"])
+    # (XLA's CPU copy-on-write program does hold a copy.)
+    assert all(p["temp_bytes"] < warm[0]["pool_bytes_tiled"]
+               for p in warm[0]["programs"] if p["paged_form"])
 
 
 def test_serving_r04_ledger_committed_and_coherent():
@@ -2113,15 +2216,18 @@ def test_prefix_index_is_dp_group_local():
     assert cache.pages_used == 0
 
 
-def test_cow_fork_token_parity_diverging_mid_page(tiny_model):
+@pytest.mark.parametrize("shape", ["tiny_gqa16", "odd64"])
+def test_cow_fork_token_parity_diverging_mid_page(shape):
     """Two requests share a prompt header and diverge MID-PAGE: the
     follower attaches the shared full pages, prefills only its tail,
     and both streams are token-identical to fully independent
     prefill (the full-context reference). The page-aligned twin then
     pins the actual copy-on-write: a full-prefix match admits with
     zero prefill tokens and forks the shared boundary page on its
-    first decode write."""
-    model, params = tiny_model
+    first decode write. Also where a token's row of the pool ends in
+    a half-empty tile (``odd64``): a page copy moves rows as they are
+    stored."""
+    model, params, _dense = _layout_model(shape)
     eng = _engine(model, params)
     eng.warmup()
     rng = np.random.default_rng(47)
@@ -2990,12 +3096,21 @@ def test_drain_finishes_in_flight_and_reports(tiny_model):
         ["d0", "d1", "d2", "d3"]
 
 
-def test_drain_deadline_persists_kv_for_adoption(tiny_model):
+@pytest.mark.parametrize("shape", ["tiny_gqa16", "odd64",
+                                   "h128_bf16"])
+def test_drain_deadline_persists_kv_for_adoption(shape):
     """A drain that hits its deadline exports still-in-flight
     sequences' exact KV + token history; a successor engine adopts
     them and finishes token-identically with no re-prefill — and the
-    pool accounting on BOTH engines returns to zero."""
-    model, params = tiny_model
+    pool accounting on BOTH engines returns to zero. The exchanged
+    format is dense KV a sequence, ``(L, Hkv, len, hd)``, whatever
+    the pools' stored layout: what the successor holds after the
+    adoption exports again bit for bit."""
+    from distributed_training_tpu.serving.disagg import (
+        export_kv_batch)
+
+    model, params, _dense = _layout_model(shape)
+    c = model.cfg
     rng = np.random.default_rng(59)
     p = rng.integers(1, 255, size=5).astype(np.int32)
     ref = _greedy_reference(model, params, [p], 10)["r0"]
@@ -3010,11 +3125,19 @@ def test_drain_deadline_persists_kv_for_adoption(tiny_model):
     assert rep["finished"] == []
     assert eng.cache.pages_used == 0
     (item,) = rep["export"]["adoptable"]
-    req, toks, _k, _v = item
+    req, toks, k, v = item
     assert req.id == "k0" and len(toks) >= 1
+    held = len(p) + len(toks) - 1           # the decode invariant
+    assert k.shape == v.shape == (c.n_layers, c.n_kv_heads, held,
+                                  c.head_dim)
+    assert np.abs(k.astype(np.float32)).min() > 0   # no padding in it
 
     succ = _engine(model, params)
     succ.adopt_batch(rep["export"]["adoptable"])
+    (k2,), (v2,) = export_kv_batch(succ.cache, ["k0"])
+    assert k2.dtype == k.dtype and v2.dtype == v.dtype
+    np.testing.assert_array_equal(k2, k)
+    np.testing.assert_array_equal(v2, v)
     for r in rep["export"]["requests"]:
         succ.submit(r)
     succ.run_until_drained()
