@@ -11,8 +11,8 @@ full width of gpt2_125m (12 layers, d_model 768, 12 heads of 64, vocab
    (not interpreted) and compared with the naive reference at bf16
    tolerance: flash forward + fused backward at S 1024 / D 64, the
    two-kernel split backward where the fused one does not fit VMEM, a
-   sliding window, GQA, and the stock paged-attention decode kernel at
-   head_dim 128 — alone and inside a small engine;
+   sliding window, GQA; and both forms of paged attention at two of
+   gpt2-xl's engine shapes against each other;
 2. trainer — ``distributed_training_tpu.train.cli.main`` takes a few
    steps at batch 32 / seq 1024 / bf16 / AdamW with the telemetry, the
    collectives audit and the checkpoint code a user gets;
@@ -188,43 +188,6 @@ def flash_case(B, H, Hkv, S, D, window=0, expect_fused=True):
     return make("flash"), make("naive"), inputs, label
 
 
-def paged_case(B, H, Hkv, P, hd=128, ps=16, N=64):
-    """Stock Pallas paged-attention decode kernel through
-    ``paged_attention(impl="kernel")`` against ``impl="ref"``."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from distributed_training_tpu.ops.paged_attention import (
-        paged_attention)
-    from distributed_training_tpu.serving.kv_cache import as_layer
-
-    def inputs():
-        ks = jax.random.split(jax.random.PRNGKey(SEED + 1), 3)
-        q = jax.random.normal(ks[0], (B, H, hd), jnp.bfloat16)
-        # One layer of a pool as the cache stores it; the kernel call
-        # re-lays it out head-major.
-        kp, vp = (as_layer(jax.random.normal(k, (Hkv, N, ps, hd),
-                                             jnp.bfloat16))
-                  for k in ks[1:])
-        rng = np.random.default_rng(SEED)
-        # Distinct physical pages per sequence, out of order; lengths
-        # from one token to a full table, plus one inactive slot
-        # (length 0: zero output).
-        tables = np.stack([rng.permutation(N - 1)[:P] + 1
-                           for _ in range(B)]).astype(np.int32)
-        lengths = np.linspace(1, P * ps, B).astype(np.int32)
-        lengths[B // 2] = 0
-        return q, kp, vp, jnp.asarray(lengths), jnp.asarray(tables)
-
-    def make(impl):
-        return jax.jit(lambda *a: (paged_attention(*a, impl=impl),))
-
-    label = (f"B{B} H{H}/{Hkv} head_dim {hd} page_size {ps} "
-             f"pages/seq {P}")
-    return make("kernel"), make("ref"), inputs, label
-
-
 def paged_forms_case(B, S, H, Hkv, P, N, hd=64, ps=16, reps=20) -> dict:
     """Both forms of ``paged_attention_chunk`` (gather, pool) on one
     layer's bf16 pool at an engine's shapes, against each other: the
@@ -283,48 +246,6 @@ def paged_forms_case(B, S, H, Hkv, P, N, hd=64, ps=16, reps=20) -> dict:
             "pool_ms": ms["pool"], "max_abs_diff": diff, "rule": rule}
 
 
-def engine_paged_case() -> dict:
-    """The kernel where the engine reaches it: ``paged_impl: auto`` at
-    head_dim 128 inside the jitted, layer-scanned decode program.
-    Greedy tokens must equal the reference-path engine's."""
-    import jax
-    import numpy as np
-
-    from distributed_training_tpu.models.transformer import (
-        Transformer, TransformerConfig)
-    from distributed_training_tpu.ops.paged_attention import (
-        kernel_supported)
-    from distributed_training_tpu.serving.engine import (
-        Engine, EngineConfig)
-
-    cfg = TransformerConfig(
-        vocab_size=512, d_model=512, n_layers=2, n_heads=4,
-        n_kv_heads=2, max_seq_len=256, dtype="bfloat16",
-        pos_encoding="rope", tie_embeddings=False)
-    model = Transformer(cfg)
-    params = model.init(jax.random.PRNGKey(SEED))
-    prompt = np.random.default_rng(SEED).integers(
-        0, cfg.vocab_size, 70).astype(np.int32)
-    probe = jax.ShapeDtypeStruct((1, cfg.n_heads, cfg.head_dim),
-                                 jax.numpy.bfloat16)
-    if not kernel_supported(probe, None, page_size=16):
-        raise AssertionError("auto dispatch does not choose the kernel")
-    toks = {}
-    for impl in ("auto", "ref"):
-        eng = Engine(model, params, EngineConfig(
-            max_batch=4, page_size=16, num_pages=96, max_seq_len=256,
-            prefill_chunk=32, paged_impl=impl))
-        toks[impl] = [int(t) for t in eng.generate(prompt, 24)]
-    same = sum(a == b for a, b in zip(toks["auto"], toks["ref"]))
-    say(f"  kernel-path tokens {toks['auto'][:8]}... "
-        f"{same}/24 equal to the reference path")
-    if toks["auto"] != toks["ref"]:
-        raise AssertionError(
-            f"engine greedy tokens differ: kernel {toks['auto']} vs "
-            f"ref {toks['ref']}")
-    return {"ok": True, "tokens": toks["auto"]}
-
-
 def compare_case(name: str, run, ref, inputs, label: str) -> dict:
     """Compile ``run`` for the chip, require a Mosaic kernel in it, and
     compare what it computes with ``ref``."""
@@ -360,9 +281,6 @@ def phase_kernels() -> dict:
         compared("flash_sliding_window", flash_case, 1, 4, 4, 4096, 64,
                  window=1024),
         compared("flash_gqa", flash_case, 2, 8, 2, 1024, 128),
-        compared("paged_decode_groups4", paged_case, 8, 8, 2, P=6),
-        compared("paged_decode_groups8", paged_case, 8, 16, 2, P=8),
-        ("engine_paged_kernel", engine_paged_case),
         # gpt2-xl's engine (perfbench/configs/gpt2-xl.json): resident
         # decode and spec_k 4.
         ("paged_forms_xl_16x1",

@@ -435,11 +435,6 @@ class LatentBlock:
         n = self.cfg.qk_nope_head_dim
         return wukv[..., :n], wukv[..., n:]
 
-    def attend_first(self, layer, q, k_new, v_new):
-        return expanded_attention(
-            q[0][None], q[1][None], k_new[None, :, 0],
-            v_new[None, :, 0], layer["attn"], self.cfg, self._w)[0]
-
     def attend_chunk(self, layer, q, kp, vp, page_rows, q_pos):
         from distributed_training_tpu.ops.paged_attention import (
             latent_attention_chunk)
@@ -447,13 +442,6 @@ class LatentBlock:
         w_uk, w_uv = self._uk_uv(layer, q[0].dtype)
         return latent_attention_chunk(q[0], q[1], kp, vp, page_rows,
                                       q_pos, w_uk, w_uv)
-
-    def attend_decode(self, layer, q, kp, vp, lengths, page_tables,
-                      impl):
-        del impl  # no kernel reads a latent pool yet
-        q_pos = (lengths - 1)[:, None].astype(jnp.int32)
-        return self.attend_chunk(layer, (q[0][:, None], q[1][:, None]),
-                                 kp, vp, page_tables, q_pos)[:, 0]
 
     def finish(self, layer, x, attn, valid):
         c = self.cfg

@@ -20,27 +20,17 @@ join/evict. This module is the attention math over that layout:
   position ``p`` is slot ``p % page_size`` of logical page
   ``p // page_size``.
 
-A third entrypoint, ``latent_attention_chunk``, attends over a LATENT
+A second entrypoint, ``latent_attention_chunk``, attends over a LATENT
 cache (one shared row a token instead of keys and values a head), in an
 absorbed or an expanded form that ``latent_form`` takes from the shapes.
 
-Two entrypoints over keys and values:
+One entrypoint over keys and values:
 
-- ``paged_attention`` — single-token decode: one query per sequence
-  against its pages. Dispatches to the TPU Pallas kernel when
-  ``kernel_supported`` (one async DMA per non-contiguous page,
-  double-buffered — see the Pallas guide's paged-attention walk-
-  through; it needs ``head_dim % 128 == 0``, so not GPT-2's 64, and
-  it wants ``(Hkv, N, ps, hd)`` verbatim, so its call re-lays the
-  layer out);
-  everywhere else it is ``paged_attention_chunk`` with ``S = 1``.
-  Exact same numerics contract as ops/attention.py: fp32
-  logits/softmax, output in q.dtype, GQA via hkv-major grouping.
-- ``paged_attention_chunk`` — multi-query form: ``S`` queries per
-  sequence, each masked to logical positions ``<= its own position``.
-  Every engine program but the first prefill chunk calls it once a
-  layer (the first chunk has no prefix and runs the ordinary causal
-  path, flash-eligible, via ops.attention). It has two forms with the
+- ``paged_attention_chunk`` — ``S`` queries per sequence, each masked
+  to logical positions ``<= its own position``; single-token decode is
+  ``S = 1``. Every engine program calls it once a layer. Exact same
+  numerics contract as ops/attention.py: fp32 logits/softmax, output
+  in q.dtype, GQA via hkv-major grouping. It has two forms with the
   same mathematics, and ``chunk_form`` takes the cheaper from the
   static shapes when the program is traced:
 
@@ -63,9 +53,8 @@ Two entrypoints over keys and values:
   The ragged kernel that reads only the pages a sequence owns is the
   end state (ROADMAP S1).
 
-There is no switch between the forms: ``paged_impl`` means kernel or
-reference and nothing else. The form each compiled program took
-(``"pool"``, ``"gather"``, ``"kernel"``) is seen at trace time by
+There is no switch between the forms. The form each compiled program
+took (``"pool"``, ``"gather"``) is seen at trace time by
 ``observe_forms`` and reported per program by ``Engine.paged_forms()``
 and the ``serving_warmup`` telemetry record (docs/observability.md).
 """
@@ -73,12 +62,9 @@ and the ``serving_warmup`` telemetry record (docs/observability.md).
 from __future__ import annotations
 
 import contextlib
-import math
 
 import jax
 import jax.numpy as jnp
-
-from distributed_training_tpu.runtime import default_platform
 
 
 _observers: list[list[str]] = []
@@ -86,11 +72,12 @@ _observers: list[list[str]] = []
 
 @contextlib.contextmanager
 def observe_forms():
-    """Collect, while open, the form every ``paged_attention`` and
-    ``paged_attention_chunk`` call takes (``"kernel"``, ``"pool"``,
-    ``"gather"``). The form follows from static shapes, so a call is
-    seen when the program around it is TRACED: the engine opens this
-    around each program's body (``serving/engine.py::_named``)."""
+    """Collect, while open, the form every ``paged_attention_chunk``
+    (``"pool"``, ``"gather"``) and ``latent_attention_chunk``
+    (``"absorbed"``, ``"expanded"``) call takes. The form follows from
+    static shapes, so a call is seen when the program around it is
+    TRACED: the engine opens this around each program's body
+    (``serving/engine.py::_named``)."""
     seen: list[str] = []
     _observers.append(seen)
     try:
@@ -102,29 +89,6 @@ def observe_forms():
 def _took(form: str) -> None:
     for seen in _observers:
         seen.append(form)
-
-
-def kernel_supported(q: jax.Array, k_pages,
-                     page_size: int | None = None) -> bool:
-    """Should single-token decode dispatch to the TPU Pallas kernel?
-
-    Conservative, mirroring ops/flash_attention.supported(): TPU
-    platform only (elsewhere the interpreter is orders of magnitude
-    slower than XLA's gather), MXU-friendly head_dim, and a page size
-    the kernel's DMA descriptor tiles evenly. A backend that fails to
-    start, or a CPU nobody asked for, raises — it is not read as "use
-    the reference" (runtime.default_platform)."""
-    if default_platform() != "tpu":
-        return False
-    head_dim = q.shape[-1]
-    ps = page_size if page_size is not None else k_pages.page_size
-    if head_dim % 128:
-        return False
-    if ps % 16:
-        return False
-    if q.dtype not in (jnp.float32, jnp.bfloat16):
-        return False
-    return True
 
 
 def _masked_softmax(logits: jax.Array, visible: jax.Array
@@ -270,7 +234,8 @@ def _held(pages) -> tuple:
 def paged_attention_chunk(q: jax.Array, k_pages, v_pages,
                           page_indices: jax.Array,
                           q_positions: jax.Array) -> jax.Array:
-    """Multi-query paged attention (prefill chunks, reference path).
+    """Multi-query paged attention (every engine program's, decode
+    at ``S = 1``).
 
     q (B, S, H, hd); k_pages/v_pages a layer of the two pools
     (``PoolLayer``); page_indices (B, P);
@@ -371,64 +336,3 @@ def latent_attention_chunk(q_nope: jax.Array, q_rope: jax.Array,
     ctx = jnp.einsum("bhsk,bkr->bhsr", probs, cd,
                      preferred_element_type=f32).astype(q_nope.dtype)
     return jnp.einsum("bhsr,rhv->bshv", ctx, w_uv)
-
-
-def _head_major(pages) -> jax.Array:
-    """A layer as the stock kernel wants it, ``(Hkv, N, ps, hd)``."""
-    slots = pages.layout.unpack(pages.slots())
-    return slots.reshape((pages.num_pages, pages.page_size)
-                         + slots.shape[1:]).transpose(2, 0, 1, 3)
-
-
-def paged_attention(q: jax.Array, k_pages, v_pages,
-                    lengths: jax.Array, page_indices: jax.Array,
-                    impl: str = "auto") -> jax.Array:
-    """Single-token decode attention against the paged pool.
-
-    q (B, H, hd) — the current token's query per sequence; k_pages/
-    v_pages a layer of the two pools (``PoolLayer``); lengths (B,)
-    int32 — VALID kv entries per sequence, current token's k/v
-    included (attends logical positions ``[0, lengths)``; 0 =
-    inactive slot, zero output); page_indices
-    (B, P). ``impl``: "auto" (TPU kernel when supported, else
-    reference), "kernel", "ref".
-    """
-    if impl not in ("auto", "kernel", "ref"):
-        raise ValueError(f"unknown paged-attention impl '{impl}'")
-    use_kernel = (impl == "kernel"
-                  or (impl == "auto"
-                      and kernel_supported(q, k_pages)))
-    if use_kernel:  # pragma: no cover - needs a TPU (chip_smoke.py)
-        _took("kernel")
-        from jax.experimental.pallas.ops.tpu.paged_attention import (
-            paged_attention as tpu_paged_attention,
-        )
-        # Kernel layout: q (B, H, hd), lengths (B,), page_indices
-        # (B, P) — ours verbatim — and pools (Hkv, N, ps, hd), which
-        # the cache does not store: the layer is re-laid-out for the
-        # call, one transposing copy of it (at head_dim % 128 == 0
-        # alone, which no benchmark cell reaches). Two
-        # things the stock kernel leaves to its caller (both found by
-        # its first run on a chip, chip_smoke.py): it computes q·k
-        # UNSCALED, so q carries the hd**-0.5 (in f32 — the kernel
-        # upcasts q anyway, and a bf16-rounded scale would move
-        # near-tied argmaxes off the reference path's); and it never
-        # writes the output rows of zero-length sequences, whose
-        # uninitialized values would reach the scratch page through
-        # the next layer's KV write and, as NaN, every sequence that
-        # reads a masked slot of it — so inactive rows are zeroed
-        # here, the module's contract. The compute block must divide
-        # the pages per sequence: up to 4 pages (64 tokens at
-        # page_size 16), fewer for a ragged table.
-        out = tpu_paged_attention(
-            q.astype(jnp.float32) * (q.shape[-1] ** -0.5),
-            _head_major(k_pages), _head_major(v_pages), lengths,
-            page_indices,
-            pages_per_compute_block=math.gcd(
-                4, page_indices.shape[1]))
-        return jnp.where((lengths > 0)[:, None, None], out,
-                         0).astype(q.dtype)
-    out = paged_attention_chunk(
-        q[:, None], k_pages, v_pages, page_indices,
-        (lengths - 1)[:, None].astype(jnp.int32))
-    return out[:, 0]

@@ -1,7 +1,7 @@
 """The block interface: what the engine's programs take from a model.
 
-``serving/engine.py`` owns the programs (one token a slot, one prompt
-chunk, a lane table of chunks, the resident loop), the page
+``serving/engine.py`` owns the programs (one token a slot, a lane
+table of chunks, the resident loop), the page
 coordinates, the scatter into the pool and the sampling. What happens
 to a token between the embedding and the logits is the model's, behind
 the calls below. A model hands its block over with
@@ -27,24 +27,22 @@ A block has
 - ``project(layer, x, positions)`` -> ``(q, k_new, v_new)``: the
   layer's normed input projected; ``k_new`` / ``v_new``
   ``(..., n_kv_heads, width)`` are the rows this token adds to the two
-  pools, ``q`` whatever the block's ``attend_*`` want;
-- ``attend_decode(layer, q, kp, vp, lengths, page_tables, impl)``: one
-  query a sequence against its pages. ``kp`` / ``vp`` are the layer of
-  the two carried pools, unread (``kv_cache.PoolLayer``: read through
-  its ``slots()`` / ``pages(tables)``), and already hold the token's
-  own row;
-- ``attend_first(layer, q, k_new, v_new)``: causal attention of a
-  prompt's first chunk over itself, no pool read;
+  pools, ``q`` whatever the block's ``attend_chunk`` wants (an array
+  or a tuple of arrays, leading shapes as ``x``'s);
 - ``attend_chunk(layer, q, kp, vp, page_rows, q_pos)``: ``S`` lanes of
   ``C`` queries, each against its sequence's pages at positions up to
-  its own;
+  its own (``q_pos`` ``(S, C)``, negative = a dead query, zero output).
+  ``kp`` / ``vp`` are the layer of the two carried pools, unread
+  (``kv_cache.PoolLayer``: read through its ``slots()`` /
+  ``pages(tables)``), and already hold the queries' own rows. The one
+  attention entry: the one-token decode program calls it at ``C = 1``;
 - ``finish(layer, x, attn, valid)`` -> ``(x, counts)``: the output
   projection, the residuals and the feed-forward; ``valid`` marks the
   rows that are real tokens (for counters only);
 - ``logits(params, x)`` -> float32 logits of the final hidden states.
 
-Leading shapes are free: ``(B,)`` rows in the decode program, ``(C,)``
-in a prompt chunk, ``(S, C)`` in the lane table.
+Leading shapes are free: ``(B,)`` rows in the decode program,
+``(S, C)`` in the lane table.
 """
 
 from __future__ import annotations
@@ -146,24 +144,6 @@ class DenseBlock:
             q = rope_bhd(q, positions)
             k = rope_bhd(k, positions)
         return q, k, v
-
-    def attend_decode(self, layer, q, kp, vp, lengths, page_tables,
-                      impl):
-        from distributed_training_tpu.ops.paged_attention import (
-            paged_attention)
-
-        return paged_attention(q, kp, vp, lengths, page_tables,
-                               impl=impl)
-
-    def attend_first(self, layer, q, k_new, v_new):
-        from distributed_training_tpu.ops.attention import (
-            dot_product_attention)
-
-        impl = self.cfg.attention_impl
-        return dot_product_attention(
-            q[None], k_new[None], v_new[None], causal=True,
-            impl=impl if impl in ("auto", "flash", "naive") else "auto",
-            window=0)[0]
 
     def attend_chunk(self, layer, q, kp, vp, page_rows, q_pos):
         from distributed_training_tpu.ops.paged_attention import (

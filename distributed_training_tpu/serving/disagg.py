@@ -391,7 +391,6 @@ def import_kv_batch(cache, items) -> None:
 
 def engine_config_for_plan(plan, page_size: int = 16,
                            prefill_chunk: int = 16,
-                           prefill_mode: str = "batched",
                            spec_k: int = 1,
                            resident_k: int = 1) -> EngineConfig:
     """The ONE engine geometry a plan implies — shared by the bench,
@@ -403,8 +402,8 @@ def engine_config_for_plan(plan, page_size: int = 16,
     table); ``num_pages`` is each group's pool shard, sized so its
     own slots fit at full length — the whole-pool total is the same
     HBM the replicated-table engine reserved, now batch-sharded.
-    ``prefill_mode``/``spec_k`` select the batched-prefill and
-    speculative-decode programs (SERVING_r03); the plan's layout is
+    ``spec_k``/``resident_k`` select the decode program
+    (speculative, device-resident); the plan's layout is
     program-agnostic — dp deals lanes, tp shards heads, either way.
 
     Pool sizing (SERVING_r05): when the plan's provenance carries
@@ -433,7 +432,6 @@ def engine_config_for_plan(plan, page_size: int = 16,
         num_pages=num_pages,
         max_seq_len=plan.seq_len,
         prefill_chunk=prefill_chunk,
-        prefill_mode=prefill_mode,
         spec_k=spec_k,
         resident_k=resident_k,
         kv_axis="tp",
@@ -651,8 +649,6 @@ def lower_serving_program(plan, objective: str):
     plan carrying ``inputs["quant"] == "int8"`` lowers against the
     quantized param structs, so the dequant-at-compute einsums are
     in the verified HLO."""
-    import dataclasses
-
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -672,11 +668,9 @@ def lower_serving_program(plan, objective: str):
                              if s > 1})
     mesh = rt.mesh
     resident = objective == "resident"
-    ecfg = dataclasses.replace(
-        engine_config_for_plan(
-            plan, spec_k=4 if resident else 1,
-            resident_k=4 if resident else 1),
-        paged_impl="ref")
+    ecfg = engine_config_for_plan(
+        plan, spec_k=4 if resident else 1,
+        resident_k=4 if resident else 1)
     c = model.serving_block()
     params_shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     if plan.inputs.get("quant", "none") == "int8":

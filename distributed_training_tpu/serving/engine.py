@@ -16,12 +16,7 @@ via the ``auto`` axes):
   whole lane table) and writes their KV through one batched page-row
   scatter; the next token of every prompt-completing lane is sampled
   IN-PROGRAM, so completion reads a ``(G, slots)`` int32 block
-  instead of a vocab-sized logits block per prompt. This replaces the
-  one-sequence-per-launch prefill (which replicated a single chunk
-  across dp groups with the dead groups masked — the launch-bound
-  cost SERVING_r02's ledger recorded); that path survives as
-  ``prefill_mode="sequential"`` for same-run comparison benches and
-  the parity tests.
+  instead of a vocab-sized logits block per prompt.
 - **decode** — the ``max_batch`` slot table dealt into ``dp`` groups
   of ``max_batch/dp``, each group decoding only its own slots against
   its own KV pool shard; dp adds ZERO new collectives (rows are
@@ -52,16 +47,13 @@ arrival burst). Under batched prefill a prefill step admits as many
 queued requests as slots+pages allow before launching (one admission
 per step would starve the lane table it just paid for).
 
-Scheduling policy (``EngineConfig.policy``):
+Scheduling: pending prompt work runs before decode (lowest TTFT;
+decode tokens wait behind a prompt storm), and a prefill step that
+makes no progress falls through to decode so pages free up.
 
-- ``"prefill"`` (default): pending prompt work runs before decode —
-  lowest TTFT, decode tokens stall behind prompt storms;
-- ``"decode"``: the active batch decodes first; prompts admit only
-  when no sequence can decode — best per-token latency, TTFT suffers.
-
-``prefill_chunk`` is the per-step prefill token budget (one chunk per
-step); decode emits up to ``max_batch`` tokens per step (all groups
-fire in one program launch).
+``prefill_chunk`` is the per-lane prefill token budget of a step;
+decode emits up to ``max_batch`` tokens per step (all groups fire in
+one program launch).
 
 Sampling is greedy at ``temperature == 0`` (the parity-tested path —
 token-for-token equal to full-context argmax); ``temperature > 0``
@@ -128,19 +120,15 @@ class EngineConfig:
     max_seq_len: int = 256        # per-sequence cap (prompt + new)
     prefill_chunk: int = 32       # tokens per prefill lane per step
     prefill_slots: int = 0        # batched-prefill lanes (0 = max_batch)
-    prefill_mode: str = "batched"  # "batched" | "sequential" (r02 path)
     spec_k: int = 1               # decode tokens per launch (1 = off)
-    spec_ngram: int = 3           # longest prompt-lookup n-gram tried
     resident_k: int = 1           # device-resident decode steps (1 = off)
     prefix_sharing: bool = True   # refcounted prefix reuse + sessions
     eos_id: int = -1              # stop token (< 0 = disabled)
-    policy: str = "prefill"       # "prefill" | "decode" priority
     temperature: float = 0.0
     top_k: int = 0
     seed: int = 0
     kv_axis: str = "tp"           # pool kv-head shard axis
     dp_axis: str = "dp"           # slot-table / pool batch shard axis
-    paged_impl: str = "auto"      # ops/paged_attention dispatch
     swap_staleness_tokens: int = -1  # hot-swap bound (-1 = unbounded)
 
     def __post_init__(self):
@@ -149,14 +137,6 @@ class EngineConfig:
                 "swap_staleness_tokens must be >= -1 (-1 disables "
                 "the bound; 0 resubmits every in-flight request with "
                 "emitted tokens at swap time)")
-        if self.policy not in ("prefill", "decode"):
-            raise ValueError(
-                f"unknown scheduling policy '{self.policy}' "
-                "(expected 'prefill' or 'decode')")
-        if self.prefill_mode not in ("batched", "sequential"):
-            raise ValueError(
-                f"unknown prefill_mode '{self.prefill_mode}' "
-                "(expected 'batched' or 'sequential')")
         if self.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
         if self.max_batch < 1:
@@ -165,8 +145,6 @@ class EngineConfig:
             raise ValueError("prefill_slots must be >= 0")
         if self.spec_k < 1:
             raise ValueError("spec_k must be >= 1")
-        if self.spec_ngram < 1:
-            raise ValueError("spec_ngram must be >= 1")
         if self.spec_k > 1 and self.temperature > 0:
             raise ValueError(
                 "speculative decode (spec_k > 1) requires greedy "
@@ -180,12 +158,6 @@ class EngineConfig:
                 "device-resident decode (resident_k > 1) requires "
                 "greedy temperature == 0 — the in-program accept/"
                 "stop logic is exact only for the argmax chain")
-        if self.resident_k > 1 and self.prefill_mode != "batched":
-            raise ValueError(
-                "device-resident decode (resident_k > 1) requires "
-                "prefill_mode='batched' — the sequential r02 prefill "
-                "pulls a logits block per chunk, defeating the "
-                "burst's one-sync contract")
 
 
 @dataclass
@@ -266,8 +238,13 @@ class _Seq:
             len(self.generated) >= self.req.max_new_tokens
 
 
+# The longest trailing n-gram the prompt-lookup draft tries first, on
+# the host (``NgramIndex``) and in the resident loop alike.
+SPEC_NGRAM = 3
+
+
 def draft_tokens(history: np.ndarray, m: int,
-                 ngram_max: int = 3) -> np.ndarray:
+                 ngram_max: int = SPEC_NGRAM) -> np.ndarray:
     """Prompt-lookup drafting: ``m`` speculative tokens from the
     sequence's OWN history (prompt + generated) — no second model.
 
@@ -316,7 +293,7 @@ class NgramIndex:
     moves acceptance length, but the pin keeps the ledgers
     comparable across revisions)."""
 
-    def __init__(self, ngram_max: int = 3):
+    def __init__(self, ngram_max: int = SPEC_NGRAM):
         self.ngram_max = ngram_max
         self.hist: list[int] = []
         # maps[n-1]: gram tuple -> most recent start index;
@@ -381,27 +358,6 @@ def _dp_extent(mesh, dp_axis: str) -> int:
     return sizes.get(dp_axis, 1)
 
 
-def _sharded(body, mesh, dp_axis: str, n_grouped: int,
-             n_replicated: int, n_outs: int):
-    """Wrap a group-local program body in a shard_map manual over the
-    dp axis. Argument order contract: ``params`` first, then
-    ``n_grouped`` group-batched arrays (leading dp-group dim, spec
-    P(dp)), then ``n_replicated`` replicated args; all ``n_outs``
-    outputs are group-batched. Every OTHER mesh axis is an ``auto``
-    axis — tp's head shard (params + pool kv-head dim) stays under
-    the SPMD partitioner exactly as in the unsharded engine."""
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    grouped = P(dp_axis)
-    in_specs = ((P(),) + (grouped,) * n_grouped
-                + (P(),) * n_replicated)
-    out_specs = (grouped,) * n_outs
-    return shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        axis_names={dp_axis}, check_vma=False)
-
-
 def _out_shardings(block, ecfg: EngineConfig, mesh):
     """(per-group result sharding, pool sharding) for the jitted
     programs' ``out_shardings``. Pinning these is load-bearing:
@@ -452,6 +408,40 @@ def _named(name: str, body):
     return program
 
 
+def _jit_program(name: str, body, block, ecfg: EngineConfig, mesh,
+                 n_grouped: int, n_results: int, params: bool = True):
+    """``body`` jitted as ``jit_<name>``. ``body`` is a group-local
+    program: ``params`` first (unless ``params=False``), then
+    ``n_grouped`` group-batched arrays (leading dp-group dim), the two
+    pools the first of them and donated (serving HBM's dominant term
+    must not hold two copies); it returns ``n_results`` group-batched
+    results and the two pools. Where the mesh has a dp axis the body
+    runs under a shard_map manual over it (specs ``P()`` for the
+    params, ``P(dp)`` for the rest); every OTHER mesh axis is an
+    ``auto`` axis — tp's head shard (params + pool kv-head dim) stays
+    under the SPMD partitioner exactly as in the unsharded engine. The
+    out shardings are pinned (``_out_shardings``)."""
+    import jax
+
+    first = int(params)    # where the pools are among the arguments
+    kw = {}
+    if mesh is not None:
+        grp, pool = _out_shardings(block, ecfg, mesh)
+        kw["out_shardings"] = (grp,) * n_results + (pool, pool)
+    if _dp_extent(mesh, ecfg.dp_axis) > 1:
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        grouped = P(ecfg.dp_axis)
+        body = shard_map(
+            body, mesh=mesh,
+            in_specs=(P(),) * first + (grouped,) * n_grouped,
+            out_specs=(grouped,) * (n_results + 2),
+            axis_names={ecfg.dp_axis}, check_vma=False)
+    return jax.jit(_named(name, body),
+                   donate_argnums=(first, first + 1), **kw)
+
+
 def build_decode_fn(block, ecfg: EngineConfig, mesh=None):
     """The jitted dp-sharded decode program for (the model's block,
     engine cfg, mesh). Signature (all group-batched, G = dp extent,
@@ -459,57 +449,16 @@ def build_decode_fn(block, ecfg: EngineConfig, mesh=None):
     (G, B), positions (G, B), page_tables (G, B, P), active (G, B),
     rng_data (G, 2)) -> (next_tokens (G, B), counts (G, n), k_pages,
     v_pages)``; ``counts`` are the block's ``counters`` summed over the
-    launch (n = 0 for a block that counts nothing), in every program.
-    Pools are donated (serving HBM's dominant term must not hold two
-    copies)."""
+    launch (n = 0 for a block that counts nothing), in every
+    program."""
     import functools
-
-    import jax
 
     body = functools.partial(
         _decode_program, block=block,
         layouts=_layouts(block, ecfg, mesh),
-        temperature=ecfg.temperature, top_k=ecfg.top_k,
-        paged_impl=ecfg.paged_impl)
-    kw = {}
-    if mesh is not None:
-        grp, pool = _out_shardings(block, ecfg, mesh)
-        kw["out_shardings"] = (grp, grp, pool, pool)
-    if _dp_extent(mesh, ecfg.dp_axis) > 1:
-        body = _sharded(body, mesh, ecfg.dp_axis,
-                        n_grouped=7, n_replicated=0, n_outs=4)
-    return jax.jit(_named("serving_decode", body),
-                   donate_argnums=(1, 2), **kw)
-
-
-def build_prefill_fn(block, ecfg: EngineConfig, first: bool,
-                     mesh=None):
-    """The jitted prefill program (first or continuation chunk).
-    Signature: ``fn(params, k_pages, v_pages, page_row (G, P),
-    live (G,), chunk (1, C), start_pos, n_valid) -> (logits (G, V),
-    counts (G, n), k_pages, v_pages)``. The chunk is replicated across
-    groups; only the ``live`` group's pool shard takes real writes
-    (the rest land in scratch) and only its logits row is meaningful
-    for continuation chunks."""
-    import functools
-
-    import jax
-
-    body = functools.partial(
-        _prefill_program, block=block,
-        layouts=_layouts(block, ecfg, mesh), first=first,
-        paged_impl=ecfg.paged_impl)
-    kw = {}
-    if mesh is not None:
-        grp, pool = _out_shardings(block, ecfg, mesh)
-        kw["out_shardings"] = (grp, grp, pool, pool)
-    if _dp_extent(mesh, ecfg.dp_axis) > 1:
-        body = _sharded(body, mesh, ecfg.dp_axis,
-                        n_grouped=4, n_replicated=3, n_outs=4)
-    return jax.jit(
-        _named("serving_prefill_first" if first
-               else "serving_prefill_cont", body),
-        donate_argnums=(1, 2), **kw)
+        temperature=ecfg.temperature, top_k=ecfg.top_k)
+    return _jit_program("serving_decode", body, block, ecfg, mesh,
+                        n_grouped=7, n_results=2)
 
 
 def _chunk_fn(block, ecfg: EngineConfig, emit: str, name: str,
@@ -524,25 +473,15 @@ def _chunk_fn(block, ecfg: EngineConfig, emit: str, name: str,
     k_pages, v_pages)`` where next_tokens is (G, S) for
     ``emit="last"`` (the
     batched-prefill first-token sample) and (G, S, C) for
-    ``emit="all"`` (the speculative verification chain). Pools are
-    donated."""
+    ``emit="all"`` (the speculative verification chain)."""
     import functools
-
-    import jax
 
     body = functools.partial(
         _chunk_program, block=block,
         layouts=_layouts(block, ecfg, mesh),
-        temperature=ecfg.temperature, top_k=ecfg.top_k,
-        paged_impl=ecfg.paged_impl, emit=emit)
-    kw = {}
-    if mesh is not None:
-        grp, pool = _out_shardings(block, ecfg, mesh)
-        kw["out_shardings"] = (grp, grp, pool, pool)
-    if _dp_extent(mesh, ecfg.dp_axis) > 1:
-        body = _sharded(body, mesh, ecfg.dp_axis,
-                        n_grouped=8, n_replicated=0, n_outs=4)
-    return jax.jit(_named(name, body), donate_argnums=(1, 2), **kw)
+        temperature=ecfg.temperature, top_k=ecfg.top_k, emit=emit)
+    return _jit_program(name, body, block, ecfg, mesh, n_grouped=8,
+                        n_results=2)
 
 
 def build_prefill_batch_fn(block, ecfg: EngineConfig, mesh=None):
@@ -580,27 +519,16 @@ def build_resident_decode_fn(block, ecfg: EngineConfig,
     ``fn(params, k_pages, v_pages, page_rows (G, B, P), history
     (G, B, Lmax), kv_len (G, B), budget (G, B), active (G, B)) ->
     (out (G, B, T), n_emitted (G, B), steps (G,), counts (G, n),
-    k_pages, v_pages)``. Pools are donated. An all-slots-complete
-    burst
-    returns early via the loop predicate."""
+    k_pages, v_pages)``. An all-slots-complete burst returns early
+    via the loop predicate."""
     import functools
-
-    import jax
 
     body = functools.partial(
         _resident_program, block=block,
         layouts=_layouts(block, ecfg, mesh), K=ecfg.resident_k,
-        C=ecfg.spec_k, ngram=ecfg.spec_ngram, eos_id=ecfg.eos_id,
-        paged_impl=ecfg.paged_impl)
-    kw = {}
-    if mesh is not None:
-        grp, pool = _out_shardings(block, ecfg, mesh)
-        kw["out_shardings"] = (grp, grp, grp, grp, pool, pool)
-    if _dp_extent(mesh, ecfg.dp_axis) > 1:
-        body = _sharded(body, mesh, ecfg.dp_axis,
-                        n_grouped=7, n_replicated=0, n_outs=6)
-    return jax.jit(_named("serving_resident_decode", body),
-                   donate_argnums=(1, 2), **kw)
+        C=ecfg.spec_k, ngram=SPEC_NGRAM, eos_id=ecfg.eos_id)
+    return _jit_program("serving_resident_decode", body, block, ecfg,
+                        mesh, n_grouped=7, n_results=4)
 
 
 def _cow_program(k_pages, v_pages, src, dst):
@@ -619,27 +547,10 @@ def _cow_program(k_pages, v_pages, src, dst):
 def build_cow_fn(block, ecfg: EngineConfig, mesh=None):
     """The jitted COW page-copy program. Signature:
     ``fn(k_pages, v_pages, src (G, W), dst (G, W)) -> (k_pages,
-    v_pages)`` — pools donated (the copy must not double the serving
-    HBM's dominant term), fixed W so a storm's forks never change a
-    traced shape."""
-    import jax
-
-    body = _cow_program
-    kw = {}
-    if mesh is not None:
-        _grp, pool = _out_shardings(block, ecfg, mesh)
-        kw["out_shardings"] = (pool, pool)
-    if _dp_extent(mesh, ecfg.dp_axis) > 1:
-        from jax import shard_map
-        from jax.sharding import PartitionSpec as P
-
-        grouped = P(ecfg.dp_axis)
-        body = shard_map(
-            body, mesh=mesh, in_specs=(grouped,) * 4,
-            out_specs=(grouped,) * 2,
-            axis_names={ecfg.dp_axis}, check_vma=False)
-    return jax.jit(_named("serving_cow", body),
-                   donate_argnums=(0, 1), **kw)
+    v_pages)``, fixed W so a storm's forks never change a traced
+    shape."""
+    return _jit_program("serving_cow", _cow_program, block, ecfg, mesh,
+                        n_grouped=4, n_results=0, params=False)
 
 
 class Engine:
@@ -781,6 +692,7 @@ class Engine:
             # burst.
             self._decode_fn = build_resident_decode_fn(
                 block, self.cfg, self.mesh)
+            self._run_decode = self._run_decode_resident
         elif self.cfg.spec_k > 1:
             # Multi-token decode IS the chunk program at C = spec_k
             # (even an effective one-token launch — pages tight, or
@@ -788,28 +700,22 @@ class Engine:
             # program, one jit entry, zero recompiles).
             self._decode_fn = build_spec_decode_fn(block, self.cfg,
                                                    self.mesh)
+            self._run_decode = self._run_decode_spec
         else:
+            # One token a slot a launch: the one cadence that samples
+            # (``temperature > 0``).
             self._decode_fn = build_decode_fn(block, self.cfg,
                                               self.mesh)
-        if self.cfg.prefill_mode == "batched":
-            self._prefill_batch_fn = build_prefill_batch_fn(
-                block, self.cfg, mesh=self.mesh)
-        else:
-            self._prefill_first_fn = build_prefill_fn(
-                block, self.cfg, first=True, mesh=self.mesh)
-            self._prefill_cont_fn = build_prefill_fn(
-                block, self.cfg, first=False, mesh=self.mesh)
+            self._run_decode = self._run_decode_token
+        self._prefill_batch_fn = build_prefill_batch_fn(
+            block, self.cfg, mesh=self.mesh)
         if self._sharing:
             self._cow_fn = build_cow_fn(block, self.cfg, mesh=self.mesh)
 
     def _programs(self) -> dict:
         """Every jitted program this engine built, by its role."""
-        fns = {"decode": self._decode_fn}
-        if self.cfg.prefill_mode == "batched":
-            fns["prefill_batch"] = self._prefill_batch_fn
-        else:
-            fns["prefill_first"] = self._prefill_first_fn
-            fns["prefill_cont"] = self._prefill_cont_fn
+        fns = {"decode": self._decode_fn,
+               "prefill_batch": self._prefill_batch_fn}
         if self._sharing:
             fns["cow"] = self._cow_fn
         return fns
@@ -824,11 +730,10 @@ class Engine:
         """``{program: paged_form}`` for every program traced so far,
         under the names the trace shows less ``jit_``
         (``serving_resident_decode``, ``serving_prefill_batch``, ...):
-        ``"pool"``, ``"gather"`` or ``"kernel"``, over a latent cache
-        ``"absorbed"`` or ``"expanded"`` (ops/paged_attention.py),
-        fixed by the shapes when the program was traced; ``None`` for a
-        program that reads no pool
-        (``serving_prefill_first``, ``serving_cow``). Read-only."""
+        ``"pool"`` or ``"gather"``, over a latent cache ``"absorbed"``
+        or ``"expanded"`` (ops/paged_attention.py), fixed by the shapes
+        when the program was traced; ``None`` for a program that reads
+        no pool (``serving_cow``). Read-only."""
         return {fn.__wrapped__.__name__: fn.__wrapped__.paged_form
                 for fn in self._programs().values()
                 if hasattr(fn.__wrapped__, "paged_form")}
@@ -870,21 +775,11 @@ class Engine:
             yield self._decode_fn, (
                 *pools(), zeros(G, B), zeros(G, B), zeros(G, B, P),
                 zeros(G, B, dtype=jnp.bool_), rng)
-        if self.cfg.prefill_mode == "batched":
-            Sp = self.prefill_local
-            yield self._prefill_batch_fn, (
-                *pools(), zeros(G, Sp, P), zeros(G, Sp, C),
-                zeros(G, Sp), zeros(G, Sp),
-                zeros(G, Sp, dtype=jnp.bool_), rng)
-        else:
-            for fn in (self._prefill_first_fn,
-                       self._prefill_cont_fn):
-                # Plain-int scalars, matching the step loop's calls —
-                # a jnp.int32() here would warm a DIFFERENT
-                # (non-weak) jit entry than the one the storm hits.
-                yield fn, (*pools(), zeros(G, P),
-                           zeros(G, dtype=jnp.bool_), zeros(1, C),
-                           0, 1)
+        Sp = self.prefill_local
+        yield self._prefill_batch_fn, (
+            *pools(), zeros(G, Sp, P), zeros(G, Sp, C),
+            zeros(G, Sp), zeros(G, Sp),
+            zeros(G, Sp, dtype=jnp.bool_), rng)
         if self._sharing:
             # Scratch-to-scratch identity copies.
             W = self._cow_width
@@ -1337,20 +1232,14 @@ class Engine:
             self._last_prefill_lanes = None
             self._step_prefix = [0, 0]
             syncs0 = self.host_syncs
-            batched = self.cfg.prefill_mode == "batched"
-            seq = None
             pending = self._prefill_candidates()
             can_admit = (not self.draining and self.queue
                          and self._free_slot() is not None)
-            want_prefill = bool(pending or can_admit)
             decodable = self._decode_candidates()
-            if self.cfg.policy == "prefill":
-                kind = "prefill" if want_prefill else (
-                    "decode" if decodable else "idle")
-            else:
-                kind = "decode" if decodable else (
-                    "prefill" if want_prefill else "idle")
-            if kind == "prefill" and batched:
+            # Pending prompt work runs before decode.
+            kind = "prefill" if pending or can_admit else (
+                "decode" if decodable else "idle")
+            if kind == "prefill":
                 # Admit everything slots+pages allow BEFORE the
                 # launch — one admission per step would starve the
                 # lane table the batched program pays for.
@@ -1358,36 +1247,22 @@ class Engine:
                         and self._admit() is not None:
                     pass
                 pending = self._prefill_candidates()
-            elif kind == "prefill":
-                seq = pending[0] if pending else self._admit()
         if kind == "prefill":
-            if batched:
-                tokens_out = self._run_prefill_batch(pending)
-                if tokens_out == 0:
-                    # Backpressure (every pending chunk stalled on
-                    # pages — the r02 livelock fallback) OR every
-                    # admission was a zero-prefill attach: decode.
-                    # Recompute decodable — a full prefix hit or an
-                    # exact session resume admits straight into the
-                    # decodable set.
-                    decodable = self._decode_candidates()
-                    kind = "decode" if decodable else "idle"
-            else:
-                if seq is not None and seq.prefill_done:
-                    # Zero-prefill admission (full prefix hit /
-                    # exact session resume): nothing to prefill —
-                    # the fresh slot decodes this very step.
-                    decodable = self._decode_candidates()
-                    kind = "decode" if decodable else "idle"
+            tokens_out = self._run_prefill_batch(pending)
+            if tokens_out == 0:
                 # Backpressure fallback: when admission OR a
-                # mid-prompt page allocation fails (pool exhausted),
-                # decode instead — decoding sequences finish and
-                # free the pages the prefill is waiting for. Without
-                # the second fallback a prefill-priority engine
-                # livelocks (regression-pinned in
-                # tests/test_serving.py).
-                elif seq is None or not self._run_prefill_chunk(seq):
-                    kind = "decode" if decodable else "idle"
+                # mid-prompt page allocation fails (pool exhausted,
+                # every pending chunk stalled), decode instead —
+                # decoding sequences finish and free the pages the
+                # prefill is waiting for. Without it a
+                # prefill-priority engine livelocks
+                # (regression-pinned in tests/test_serving.py).
+                # Zero-prefill admissions (full prefix hit / exact
+                # session resume) land here too: nothing to prefill,
+                # and the fresh slot decodes this very step — so
+                # decodable is recomputed.
+                decodable = self._decode_candidates()
+                kind = "decode" if decodable else "idle"
         if kind == "decode":
             tokens_out = self._run_decode(decodable)
         dur = time.monotonic() - t0
@@ -1484,112 +1359,6 @@ class Engine:
             self._step_counts[name] = (
                 self._step_counts.get(name, 0) + int(n))
 
-    def _group_row(self, seq_id) -> tuple[np.ndarray, np.ndarray, int]:
-        """(G, P) page rows + (G,) live mask for a single sequence:
-        the owner group's real row, all-scratch rows elsewhere."""
-        G = self.dp_groups
-        g = self.cache.group_of(seq_id)
-        rows = np.zeros((G, self.cache.cfg.pages_per_seq), np.int32)
-        rows[g] = self.cache.page_row(seq_id)
-        live = np.zeros((G,), bool)
-        live[g] = True
-        return rows, live, g
-
-    def _run_prefill_chunk(self, seq: _Seq) -> bool:
-        """One chunk of ``seq``'s prompt. False = no progress (the
-        owning group's pool could not cover the chunk's pages —
-        backpressure; the caller must let decode run so pages free
-        up)."""
-        import jax.numpy as jnp
-
-        with self._phase("pack"):
-            c = self.cfg
-            start = seq.prefilled
-            n_valid = min(c.prefill_chunk, seq.prompt_len - start)
-            if not self.cache.ensure(seq.req.id, start + n_valid):
-                return False
-            pairs = self._cow_guard(seq.req.id) if self._sharing \
-                else []
-            if pairs is None:
-                return False  # fork stalled on pages — backpressure
-        if pairs:
-            g = self.cache.group_of(seq.req.id)
-            self._apply_cow([(g, a, b) for a, b in pairs])
-        with self._phase("pack"):
-            chunk = np.zeros((1, c.prefill_chunk), np.int32)
-            chunk[0, :n_valid] = seq.req.prompt[start:start + n_valid]
-            rows, live, g = self._group_row(seq.req.id)
-            fn = (self._prefill_first_fn if start == 0
-                  else self._prefill_cont_fn)
-        with self._phase("launch"):
-            # start/n_valid ride as weak-typed scalars: same jit cache
-            # entry for every value, no explicit device_put dispatches.
-            logits, counts, k, v = fn(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                jnp.asarray(rows), jnp.asarray(live),
-                jnp.asarray(chunk), start, n_valid)
-            self.cache.update_pools(k, v)
-            done = start + n_valid >= seq.prompt_len
-        if done:
-            # Slice ON DEVICE before the pull: one (V,) transfer per
-            # completed prompt instead of the whole (G, V) block —
-            # the r02 dispatch-diet leftover (completion cost must
-            # not scale with vocab x dp). The batched prefill path
-            # goes further and never moves logits at all (in-program
-            # sampling).
-            with self._phase("launch"):
-                row = logits[g]
-            lg, counts = self._fetch_host(row, counts)
-            self._count(counts)
-        with self._phase("emit"):
-            self.cache.advance(seq.req.id, n_valid)
-            seq.prefilled = start + n_valid
-            self.prefill_tokens_computed += n_valid
-            self.prefill_launches += 1
-            self._step_counts["first_tokens"] = int(done)
-            if done:
-                tok = self._sample_host(lg)
-                now = time.monotonic()
-                seq.span("prefill", now, tokens=n_valid)
-                seq.first_token_t = now
-                seq.token_times.append(now)
-                seq.generated.append(tok)
-                if self.cfg.eos_id >= 0 and tok == self.cfg.eos_id:
-                    seq.eos = True
-                self._emit_token(seq, tok)
-                self._register(seq)
-                self._maybe_finish(seq)
-                return True
-            # Mid-prompt chunk: no fetch happens, so the span
-            # timestamp is the post-dispatch host clock (launch
-            # enqueue time under async dispatch — the token counts are
-            # the load-bearing fields; the sync-accurate timestamps
-            # are the fetched ones).
-            seq.span("prefill", time.monotonic(), tokens=n_valid)
-            self._register(seq)
-            return True
-
-    def _sample_host(self, logits) -> int:
-        """Sample the prefill's first token on host — one token per
-        request lifetime; the decode program samples the rest
-        in-compiled. ``logits`` is a HOST array (the caller already
-        pulled it through ``_fetch_host``)."""
-        import jax
-        import jax.numpy as jnp
-
-        if self.cfg.temperature <= 0:
-            # Host argmax: one V-sized transfer instead of a device
-            # argmax dispatch + sync — on the dispatch-bound CPU
-            # mesh the extra launch was ~30% of a prefill step.
-            return int(logits.argmax())
-        rng = jax.random.fold_in(self._base_rng,
-                                 1_000_000 + self._step_counter)
-        lg = logits / self.cfg.temperature
-        if self.cfg.top_k:
-            kth = jax.lax.top_k(lg, self.cfg.top_k)[0][-1]
-            lg = jnp.where(lg < kth, -jnp.inf, lg)
-        return int(jax.random.categorical(rng, lg))
-
     def _rng_grouped(self, salt: int):
         """(G, 2) uint32 per-group key data for the compiled
         programs' sampling tail. Greedy returns the cached zero key
@@ -1605,103 +1374,145 @@ class Engine:
                 jax.random.fold_in(base, g)))  # path only; greedy
             for g in range(self.dp_groups)]))  # rides _zero_rng
 
+    # -- the front and the back of every launch -----------------------------
+
+    def _claim(self, s: _Seq, upto: int, table: list, cow: list,
+               lane: int | None = None) -> tuple[int, int] | None:
+        """Claim what the next launch writes of ``s``: pages for
+        ``upto`` tokens, a private copy of any shared page the write
+        would touch (the (group, src, dst) pairs go onto ``cow``, for
+        ``_page_rows`` to copy), and its place ``(g, i)`` in the
+        launch's ``table`` ((G, lanes) sequence ids, None = a dead
+        lane): its slot of its group's table, or ``lane`` where the
+        launch packs lanes of its own (batched prefill). None = the
+        group's pool shard is short, also after evicting an idle
+        session: the sequence skips this launch and resumes when pages
+        free."""
+        if not self.cache.ensure(s.req.id, upto):
+            return None
+        g, i = divmod(s.slot, self.batch_local)
+        if self._sharing:
+            pairs = self._cow_guard(s.req.id)
+            if pairs is None:
+                return None
+            cow += [(g, a, b) for a, b in pairs]
+        if lane is not None:
+            i = lane
+        table[g][i] = s.req.id
+        return g, i
+
+    def _page_rows(self, table: list, cow: list) -> np.ndarray:
+        """The page rows of a launch's claimed ``table``, once the
+        pages ``_claim`` forked are copied (one launch of their own)."""
+        if cow:
+            self._apply_cow(cow)
+        with self._phase("pack"):
+            return self.cache.page_rows_grouped(table)
+
+    def _launch(self, fn, *args, fetch: bool = True) -> tuple:
+        """One launch of ``fn`` on the params, the pools (donated; the
+        returned ones are adopted) and ``args``, then ONE sync for
+        everything else it returns, the block's counts last (added to
+        the step record). Returns the fetched results and the clock
+        AFTER the blocking fetch: under async dispatch an earlier read
+        would leave the launch's own compute out of a request's
+        latencies. ``fetch=False`` reads nothing (a prefill launch that
+        ends no prompt) and the clock is the dispatch's."""
+        import jax.numpy as jnp
+
+        with self._phase("launch"):
+            *outs, k, v = fn(
+                self.params, self.cache.k_pages, self.cache.v_pages,
+                *(jnp.asarray(a) for a in args))
+            self.cache.update_pools(k, v)
+        if not fetch:
+            return (None,) * (len(outs) - 1) + (time.monotonic(),)
+        *outs, counts = self._fetch_host(*outs)
+        self._count(counts)
+        return (*outs, time.monotonic())
+
+    def _emit(self, s: _Seq, toks, now: float, ev: str, advance: int,
+              **fields) -> None:
+        """The back of every launch, for one sequence: the cache
+        advances by what the launch wrote of it, the ``ev`` span,
+        then each of ``toks`` (the tokens fetched for it, none for a
+        prompt chunk that did not end its prompt) is appended, tested
+        for the stop token, stamped and streamed; then the prefix
+        index and completion."""
+        self.cache.advance(s.req.id, advance)
+        s.span(ev, now, **fields)
+        eos = self.cfg.eos_id
+        for tok in toks:
+            s.generated.append(tok)
+            if eos >= 0 and tok == eos:
+                s.eos = True
+            if s.first_token_t is None:
+                s.first_token_t = now
+            s.token_times.append(now)
+            self._emit_token(s, tok)
+        self._register(s)
+        self._maybe_finish(s)
+
     def _run_prefill_batch(self, pending: list[_Seq]) -> int:
         """One launch of the batched prefill program: pack up to
         ``prefill_local`` pending sequences PER GROUP (each lane is
-        one sequence's current chunk, pages ensured first), write all
+        one sequence's current chunk, pages claimed first), write all
         their KV through one batched scatter, and read the in-program
         sample for every lane whose chunk completed its prompt.
         Returns the prompt tokens processed (0 = every pending chunk
         stalled on pages — backpressure; the caller lets decode run
         so pages free up)."""
-        import jax.numpy as jnp
-
         with self._phase("pack"):
-            c = self.cfg
             G, Sp, C = (self.dp_groups, self.prefill_local,
-                        c.prefill_chunk)
-            chosen: list[list[_Seq]] = [[] for _ in range(G)]
-            cow: list = []
-            for s in pending:
-                g = self.cache.group_of(s.req.id)
-                if len(chosen[g]) >= Sp:
-                    continue
-                n = min(C, s.prompt_len - s.prefilled)
-                if not self.cache.ensure(s.req.id, s.prefilled + n):
-                    continue  # this lane stalls; others still launch
-                if self._sharing:
-                    pairs = self._cow_guard(s.req.id)
-                    if pairs is None:
-                        continue  # lane stalls on fork pages
-                    cow += [(g, a, b) for a, b in pairs]
-                chosen[g].append(s)
-        if not any(chosen):
-            return 0
-        if cow:
-            self._apply_cow(cow)
-        with self._phase("pack"):
+                        self.cfg.prefill_chunk)
             tokens = np.zeros((G, Sp, C), np.int32)
             start_pos = np.zeros((G, Sp), np.int32)
             n_valid = np.zeros((G, Sp), np.int32)
             active = np.zeros((G, Sp), bool)
+            seq_ids: list[list] = [[None] * Sp for _ in range(G)]
+            lanes = [0] * G
+            chosen: list[tuple[_Seq, int, int, int]] = []
+            cow: list = []
             completing = False
-            for g, seqs in enumerate(chosen):
-                for i, s in enumerate(seqs):
-                    start = s.prefilled
-                    n = min(C, s.prompt_len - start)
-                    tokens[g, i, :n] = s.req.prompt[start:start + n]
-                    start_pos[g, i] = start
-                    n_valid[g, i] = n
-                    active[g, i] = True
-                    completing |= start + n >= s.prompt_len
-            rows = self.cache.page_rows_grouped(
-                [[s.req.id for s in seqs] for seqs in chosen],
-                width=Sp)
-            rng = self._rng_grouped(1_000_000 + self._step_counter)
-        with self._phase("launch"):
-            nxt, counts, k, v = self._prefill_batch_fn(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                jnp.asarray(rows), jnp.asarray(tokens),
-                jnp.asarray(start_pos), jnp.asarray(n_valid),
-                jnp.asarray(active), rng)
-            self.cache.update_pools(k, v)
-            self._last_prefill_lanes = [len(seqs) for seqs in chosen]
-            self.prefill_launches += 1
-            t_launch = time.monotonic()  # dispatch-time stamp for
-        fetched = now = None  # lanes that complete no prompt
-        if completing:
-            # ONE (G, Sp) int32 pull for the whole launch, and only
-            # when some prompt completed — never a logits block. The
-            # timestamp is taken AFTER this blocking fetch: under
-            # async dispatch an earlier clock read would exclude the
-            # launch's own compute from TTFT.
-            fetched, counts = self._fetch_host(nxt, counts)
-            self._count(counts)
-            now = time.monotonic()
+            for s in pending:
+                g = self.group_of_slot(s.slot)
+                if lanes[g] >= Sp:
+                    continue
+                start = s.prefilled
+                n = min(C, s.prompt_len - start)
+                # A lane that stalls on pages leaves the others to
+                # launch.
+                at = self._claim(s, start + n, seq_ids, cow,
+                                 lane=lanes[g])
+                if at is None:
+                    continue
+                lanes[g] += 1
+                tokens[at][:n] = s.req.prompt[start:start + n]
+                start_pos[at] = start
+                n_valid[at] = n
+                active[at] = True
+                completing |= start + n >= s.prompt_len
+                chosen.append((s, *at, n))
+        if not chosen:
+            return 0
+        # ONE (G, Sp) int32 pull for the whole launch, and only when
+        # some prompt completed — never a logits block.
+        fetched, now = self._launch(
+            self._prefill_batch_fn, self._page_rows(seq_ids, cow),
+            tokens, start_pos, n_valid, active,
+            self._rng_grouped(1_000_000 + self._step_counter),
+            fetch=completing)
         with self._phase("emit"):
             total = first_tokens = 0
-            for g, seqs in enumerate(chosen):
-                for i, s in enumerate(seqs):
-                    n = int(n_valid[g, i])
-                    self.cache.advance(s.req.id, n)
-                    s.prefilled += n
-                    total += n
-                    if not s.prefill_done:
-                        s.span("prefill", t_launch, tokens=n)
-                    else:
-                        tok = int(fetched[g, i])
-                        s.span("prefill", now, tokens=n)
-                        s.first_token_t = now
-                        s.token_times.append(now)
-                        s.generated.append(tok)
-                        if self.cfg.eos_id >= 0 and \
-                                tok == self.cfg.eos_id:
-                            s.eos = True
-                        self._emit_token(s, tok)
-                        first_tokens += 1
-                    self._register(s)
-                    if s.prefill_done:
-                        self._maybe_finish(s)
+            for s, g, i, n in chosen:
+                s.prefilled += n
+                total += n
+                done = s.prefill_done
+                self._emit(s, (int(fetched[g, i]),) if done else (),
+                           now, "prefill", n, tokens=n)
+                first_tokens += done
+            self._last_prefill_lanes = lanes
+            self.prefill_launches += 1
             self.prefill_tokens_computed += total
             self._step_counts["first_tokens"] = first_tokens
         return total
@@ -1717,7 +1528,7 @@ class Engine:
             return np.zeros((0,), np.int32)
         idx = seq.ngram
         if idx is None:
-            idx = seq.ngram = NgramIndex(self.cfg.spec_ngram)
+            idx = seq.ngram = NgramIndex()
             idx.extend(seq.req.prompt.tolist())
             idx.extend(seq.generated)
         else:
@@ -1735,8 +1546,6 @@ class Engine:
         advances only by the accepted length; rejected positions'
         stale KV sits beyond ``length`` (masked out of attention) and
         is overwritten by the next launch's writes."""
-        import jax.numpy as jnp
-
         with self._phase("pack"):
             G, B = self.dp_groups, self.batch_local
             K = self.cfg.spec_k
@@ -1745,7 +1554,7 @@ class Engine:
             n_valid = np.zeros((G, B), np.int32)
             active = np.zeros((G, B), bool)
             seq_ids: list[list] = [[None] * B for _ in range(G)]
-            stepped: list[tuple[_Seq, int, np.ndarray]] = []
+            stepped: list[tuple[_Seq, tuple, int, np.ndarray]] = []
             cow: list = []
             for s in decodable:
                 length = self.cache.length(s.req.id)
@@ -1755,77 +1564,49 @@ class Engine:
                 # budget ride as masked padding (n_valid), never as
                 # writes.
                 n = min(K, remaining, self.cfg.max_seq_len - length)
-                if not self.cache.ensure(s.req.id, length + n):
+                if n > 1 and not self.cache.ensure(s.req.id,
+                                                   length + n):
                     # Pages for the full chain are short: fall back
                     # to a one-token launch in the SAME program
                     # before stalling outright.
-                    if n == 1 or not self.cache.ensure(s.req.id,
-                                                       length + 1):
-                        continue
                     n = 1
-                g, i = divmod(s.slot, B)
-                if self._sharing:
-                    pairs = self._cow_guard(s.req.id)
-                    if pairs is None:
-                        continue  # fork stalled on pages; retry next
-                    cow += [(g, a, b) for a, b in pairs]
+                at = self._claim(s, length + n, seq_ids, cow)
+                if at is None:
+                    continue
                 draft = self._draft(s, n - 1)
-                tokens[g, i, 0] = s.last_token
+                tokens[at][0] = s.last_token
                 if n > 1:
-                    tokens[g, i, 1:n] = draft
-                start_pos[g, i] = length
-                n_valid[g, i] = n
-                active[g, i] = True
-                seq_ids[g][i] = s.req.id
-                stepped.append((s, n, draft))
+                    tokens[at][1:n] = draft
+                start_pos[at] = length
+                n_valid[at] = n
+                active[at] = True
+                stepped.append((s, at, n, draft))
         if not stepped:
             return 0
-        if cow:
-            self._apply_cow(cow)
-        with self._phase("pack"):
-            rows = self.cache.page_rows_grouped(seq_ids)
-        with self._phase("launch"):
-            out, counts, k, v = self._decode_fn(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                jnp.asarray(rows), jnp.asarray(tokens),
-                jnp.asarray(start_pos), jnp.asarray(n_valid),
-                jnp.asarray(active), self._zero_rng)
-            self.cache.update_pools(k, v)
-        out, counts = self._fetch_host(out, counts)
-        self._count(counts)
-        now = time.monotonic()
+        out, now = self._launch(
+            self._decode_fn, self._page_rows(seq_ids, cow), tokens,
+            start_pos, n_valid, active, self._zero_rng)
         with self._phase("emit"):
             total = 0
-            for s, n, draft in stepped:
-                g, i = divmod(s.slot, B)
-                # out[g, i, j] is the verified argmax AFTER position
+            for s, at, n, draft in stepped:
+                # out[at][j] is the verified argmax AFTER position
                 # j. Accept draft j while it equals the chain's
                 # previous token; every accepted position's argmax is
                 # then conditioned on true tokens only.
-                emit = [int(out[g, i, 0])]
+                chain = out[at].tolist()
+                emit = chain[:1]
                 j = 1
                 while j < n and int(draft[j - 1]) == emit[-1]:
-                    emit.append(int(out[g, i, j]))
+                    emit.append(chain[j])
                     j += 1
                 if self.cfg.eos_id >= 0 and self.cfg.eos_id in emit:
                     # Stop at the stop token: later accepted
                     # positions are conditioned on a sequence that
                     # already ended.
                     emit = emit[:emit.index(self.cfg.eos_id) + 1]
-                self.cache.advance(s.req.id, len(emit))
-                s.span("decode", now, emitted=len(emit), budget=n)
-                for tok in emit:
-                    s.generated.append(tok)
-                    if self.cfg.eos_id >= 0 and \
-                            tok == self.cfg.eos_id:
-                        s.eos = True
-                    if s.first_token_t is None:
-                        s.first_token_t = now
-                    s.token_times.append(now)
-                    self._emit_token(s, tok)
+                self._emit(s, emit, now, "decode", len(emit),
+                           emitted=len(emit), budget=n)
                 total += len(emit)
-                self._register(s)
-                self._maybe_finish(s)
             # One verification chunk a stepped slot: ``slot_iters``
             # is exact.
             self._step_counts.update(
@@ -1856,8 +1637,6 @@ class Engine:
         token, and an upper bound at ``spec_k > 1`` for a slot that
         stopped before its group did (tokens over ``slot_iters`` then
         reads low, never high)."""
-        import jax.numpy as jnp
-
         with self._phase("pack"):
             G, B = self.dp_groups, self.batch_local
             T = self.cfg.resident_k * self.cfg.spec_k
@@ -1867,7 +1646,7 @@ class Engine:
             budget = np.zeros((G, B), np.int32)
             active = np.zeros((G, B), bool)
             seq_ids: list[list] = [[None] * B for _ in range(G)]
-            stepped: list[_Seq] = []
+            stepped: list[tuple[_Seq, tuple]] = []
             cow: list = []
             for s in decodable:
                 length = self.cache.length(s.req.id)
@@ -1881,64 +1660,32 @@ class Engine:
                 want = min(remaining, T, cap - length)
                 if want < 1:
                     continue  # zero headroom: wait for frees
-                if not self.cache.ensure(s.req.id, length + want):
+                at = self._claim(s, length + want, seq_ids, cow)
+                if at is None:
                     continue
-                g, i = divmod(s.slot, B)
-                if self._sharing:
-                    pairs = self._cow_guard(s.req.id)
-                    if pairs is None:
-                        continue  # fork stalled on pages; retry next
-                    cow += [(g, a, b) for a, b in pairs]
                 hist = np.concatenate([
                     np.array(s.req.prompt, np.int32),
                     np.array(s.generated, np.int32)])
-                history[g, i, :hist.shape[0]] = hist
-                kv_len[g, i] = length
-                budget[g, i] = want
-                active[g, i] = True
-                seq_ids[g][i] = s.req.id
-                stepped.append(s)
+                history[at][:hist.shape[0]] = hist
+                kv_len[at] = length
+                budget[at] = want
+                active[at] = True
+                stepped.append((s, at))
         if not stepped:
             return 0
-        if cow:
-            self._apply_cow(cow)
-        with self._phase("pack"):
-            rows = self.cache.page_rows_grouped(seq_ids)
-        with self._phase("launch"):
-            out, n_emitted, steps, counts, k, v = self._decode_fn(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                jnp.asarray(rows), jnp.asarray(history),
-                jnp.asarray(kv_len), jnp.asarray(budget),
-                jnp.asarray(active))
-            self.cache.update_pools(k, v)
-        out, n_emitted, steps, counts = self._fetch_host(
-            out, n_emitted, steps, counts)
-        self._count(counts)
-        now = time.monotonic()
+        out, n_emitted, steps, now = self._launch(
+            self._decode_fn, self._page_rows(seq_ids, cow), history,
+            kv_len, budget, active)
         with self._phase("emit"):
             total = slot_iters = 0
-            for s in stepped:
-                g, i = divmod(s.slot, B)
-                e = int(n_emitted[g, i])
-                self.cache.advance(s.req.id, e)
-                s.span("decode", now, emitted=e,
-                       budget=int(budget[g, i]))
-                for t in range(e):
-                    tok = int(out[g, i, t])
-                    s.generated.append(tok)
-                    if self.cfg.eos_id >= 0 and \
-                            tok == self.cfg.eos_id:
-                        s.eos = True
-                    if s.first_token_t is None:
-                        s.first_token_t = now
-                    s.token_times.append(now)
-                    self._emit_token(s, tok)
+            for s, at in stepped:
+                e = int(n_emitted[at])
+                self._emit(s, out[at][:e].tolist(), now, "decode", e,
+                           emitted=e, budget=int(budget[at]))
                 total += e
                 # A live iteration emits at least one token, and a
                 # slot is live in at most its group's iterations.
-                slot_iters += min(e, int(steps[g]))
-                self._register(s)
-                self._maybe_finish(s)
+                slot_iters += min(e, int(steps[at[0]]))
             g_steps = [int(steps[g]) for g in range(G)
                        if active[g].any()]
             mean_steps = sum(g_steps) / max(1, len(g_steps))
@@ -1948,71 +1695,40 @@ class Engine:
                 resident_steps_per_launch=round(mean_steps, 4))
         return total
 
-    def _run_decode(self, decodable: list[_Seq]) -> int:
-        import jax.numpy as jnp
-
-        if self.cfg.resident_k > 1:
-            return self._run_decode_resident(decodable)
-        if self.cfg.spec_k > 1:
-            return self._run_decode_spec(decodable)
+    def _run_decode_token(self, decodable: list[_Seq]) -> int:
+        """One launch of the one-token decode program: every decodable
+        slot feeds its last token and reads the next, sampled in the
+        program (the argmax at ``temperature == 0``, else a draw from
+        the step's folded key)."""
         with self._phase("pack"):
             G, B = self.dp_groups, self.batch_local
             tokens = np.zeros((G, B), np.int32)
             positions = np.zeros((G, B), np.int32)
             active = np.zeros((G, B), bool)
             seq_ids: list[list] = [[None] * B for _ in range(G)]
-            stepped: list[_Seq] = []
+            stepped: list[tuple[_Seq, tuple]] = []
             cow: list = []
             for s in decodable:
                 # The new token's KV lands at position length(seq);
-                # make sure a page covers it. Failure = that group's
-                # pool shard is exhausted: the slot stalls this step
-                # and resumes when pages free.
-                if not self.cache.ensure(
-                        s.req.id, self.cache.length(s.req.id) + 1):
+                # a page must cover it.
+                length = self.cache.length(s.req.id)
+                at = self._claim(s, length + 1, seq_ids, cow)
+                if at is None:
                     continue
-                g, i = divmod(s.slot, B)
-                if self._sharing:
-                    pairs = self._cow_guard(s.req.id)
-                    if pairs is None:
-                        continue  # fork stalled on pages; retry next
-                    cow += [(g, a, b) for a, b in pairs]
-                tokens[g, i] = s.last_token
-                positions[g, i] = self.cache.length(s.req.id)
-                active[g, i] = True
-                seq_ids[g][i] = s.req.id
-                stepped.append(s)
+                tokens[at] = s.last_token
+                positions[at] = length
+                active[at] = True
+                stepped.append((s, at))
         if not stepped:
             return 0
-        if cow:
-            self._apply_cow(cow)
-        with self._phase("pack"):
-            rows = self.cache.page_rows_grouped(seq_ids)
-            rng = self._rng_grouped(self._step_counter)
-        with self._phase("launch"):
-            nxt, counts, k, v = self._decode_fn(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(rows), jnp.asarray(active), rng)
-            self.cache.update_pools(k, v)
-        nxt, counts = self._fetch_host(nxt, counts)
-        self._count(counts)
-        now = time.monotonic()
+        rows = self._page_rows(seq_ids, cow)
+        nxt, now = self._launch(
+            self._decode_fn, tokens, positions, rows, active,
+            self._rng_grouped(self._step_counter))
         with self._phase("emit"):
-            for s in stepped:
-                g, i = divmod(s.slot, B)
-                self.cache.advance(s.req.id, 1)
-                s.span("decode", now, emitted=1)
-                tok = int(nxt[g, i])
-                s.generated.append(tok)
-                if self.cfg.eos_id >= 0 and tok == self.cfg.eos_id:
-                    s.eos = True
-                if s.first_token_t is None:
-                    s.first_token_t = now
-                s.token_times.append(now)
-                self._emit_token(s, tok)
-                self._register(s)
-                self._maybe_finish(s)
+            for s, at in stepped:
+                self._emit(s, (int(nxt[at]),), now, "decode", 1,
+                           emitted=1)
             self._step_counts.update(slots_stepped=len(stepped),
                                      slot_iters=len(stepped))
         return len(stepped)
@@ -2564,8 +2280,9 @@ def _sample(logits, active, rng_data, temperature, top_k):
 
 def _decode_program(params, k_pages, v_pages, tokens, positions,
                     page_tables, active, rng_data, *, block, layouts,
-                    temperature, top_k, paged_impl):
-    """One token for one dp group's slot table.
+                    temperature, top_k):
+    """One token for one dp group's slot table: a chunk of one a slot
+    through ``_chunk_hidden``, then sampled.
 
     k_pages/v_pages (1, L, N, ps, lanes) — the group's pool
     shard; tokens (1, B) int32 — last sampled token per local slot;
@@ -2578,88 +2295,15 @@ def _decode_program(params, k_pages, v_pages, tokens, positions,
     """
     import jax.numpy as jnp
 
-    k_pages_g, v_pages_g = k_pages[0], v_pages[0]
-    tokens, positions = tokens[0], positions[0]
-    page_tables, active = page_tables[0], active[0]
-    ps = layouts[0].page_size(k_pages_g)
-    x = block.embed(params, tokens, positions)            # (B, D)
-    # Dead writes → scratch page 0, offset 0.
-    page_ids = jnp.where(
-        active,
-        jnp.take_along_axis(page_tables,
-                            (positions // ps)[:, None],
-                            axis=1)[:, 0],
-        0).astype(jnp.int32)
-    offsets = jnp.where(active, positions % ps, 0).astype(jnp.int32)
-    lengths = jnp.where(active, positions + 1, 0).astype(jnp.int32)
-    x, counts, k_pages_g, v_pages_g = _scan_layers(
-        block, layouts, params, x, k_pages_g, v_pages_g, positions, page_ids,
-        offsets, active,
-        lambda layer, q, _k, _v, kp, vp: block.attend_decode(
-            layer, q, kp, vp, lengths, page_tables, paged_impl))
-    nxt = _sample(block.logits(params, x), active, rng_data,
+    active = active[0]
+    x, _valid, counts, k_pages_g, v_pages_g = _chunk_hidden(
+        params, k_pages[0], v_pages[0], page_tables[0],
+        tokens[0][:, None], positions[0],
+        jnp.ones_like(positions[0]), active, block=block,
+        layouts=layouts)
+    nxt = _sample(block.logits(params, x[:, 0]), active, rng_data,
                   temperature, top_k)
     return nxt[None], counts[None], k_pages_g[None], v_pages_g[None]
-
-
-def _prefill_program(params, k_pages, v_pages, page_row, live,
-                     chunk_tokens, start_pos, n_valid, *, block, layouts,
-                     first, paged_impl):
-    """One prompt chunk for one sequence, on one dp group's shard.
-
-    k_pages/v_pages (1, L, N, ps, lanes); page_row (1, P) — the
-    sequence's table on its OWNER group, all-scratch elsewhere; live
-    (1,) bool — True only on the owner (dead groups' writes land in
-    their scratch page and their queries mask out); chunk_tokens
-    (1, C) int32 (positions >= n_valid are padding); start_pos — the
-    chunk's first absolute position. Writes the chunk's KV into its
-    pages and returns (next-token logits (1, V) fp32 — from the LAST
-    VALID position, meaningful on the OWNER group when this is the
-    prompt's final chunk — counts (1, n), k_pages, v_pages).
-
-    ``first=True`` (start_pos == 0, traced as a separate program):
-    attention is ordinary causal self-attention over the chunk
-    (the block's ``attend_first`` — the flash path on TPU where the
-    block's widths allow). Continuation chunks attend the pages
-    written so far plus themselves via the paged chunk form. Both
-    write-then-read the pool identically, so the two programs' caches
-    are interchangeable token-for-token.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    del paged_impl  # chunk form has no kernel path yet
-    k_pages_g, v_pages_g = k_pages[0], v_pages[0]
-    page_row, live = page_row[0], live[0]
-    C = chunk_tokens.shape[1]
-    ps = layouts[0].page_size(k_pages_g)
-    idx = jnp.arange(C, dtype=jnp.int32)
-    abs_pos = start_pos + idx                             # (C,)
-    valid = (idx < n_valid) & live
-    x = block.embed(params, chunk_tokens[0], abs_pos)     # (C, D)
-    page_ids = jnp.where(valid, page_row[abs_pos // ps], 0)
-    offsets = jnp.where(valid, abs_pos % ps, 0)
-    # Padding queries — and every query on a non-live group — mask
-    # out of the paged form via negative positions; the causal
-    # first-chunk form never lets a valid query see a padding key
-    # (pads sit at higher positions) and never reads the pool, so
-    # its logits are identical on every group.
-    q_pos = jnp.where(valid, abs_pos, -1)[None, :]        # (1, C)
-    if first:
-        def attend(layer, q, k, v, _kp, _vp):
-            return block.attend_first(layer, q, k, v)
-    else:
-        def attend(layer, q, _k, _v, kp, vp):
-            return block.attend_chunk(
-                layer, jax.tree.map(lambda a: a[None], q), kp, vp,
-                page_row[None], q_pos)[0]
-    x, counts, k_pages_g, v_pages_g = _scan_layers(
-        block, layouts, params, x, k_pages_g, v_pages_g, abs_pos, page_ids,
-        offsets, valid, attend)
-    x_last = jax.lax.dynamic_index_in_dim(
-        x, jnp.maximum(n_valid - 1, 0), axis=0, keepdims=False)
-    return (block.logits(params, x_last)[None], counts[None],
-            k_pages_g[None], v_pages_g[None])
 
 
 def _chunk_hidden(params, k_pages_g, v_pages_g, page_rows, tokens,
@@ -2713,7 +2357,7 @@ def _argmax_chain(block, params, x, valid):
 
 def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
                    start_pos, n_valid, active, rng_data, *, block,
-                   layouts, temperature, top_k, paged_impl, emit):
+                   layouts, temperature, top_k, emit):
     """Multi-token chunks for a whole lane table, one dp group.
 
     The ONE program body behind both batched prefill (``emit="last"``,
@@ -2748,7 +2392,6 @@ def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
     """
     import jax.numpy as jnp
 
-    del paged_impl  # chunk form has no kernel path yet
     k_pages_g, v_pages_g = k_pages[0], v_pages[0]
     page_rows, tokens = page_rows[0], tokens[0]
     start_pos, n_valid, active = start_pos[0], n_valid[0], active[0]
@@ -2774,7 +2417,7 @@ def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
 
 def _resident_program(params, k_pages, v_pages, page_rows, history,
                       kv_len, budget, active, *, block, layouts, K, C,
-                      ngram, eos_id, paged_impl):
+                      ngram, eos_id):
     """Device-resident K-step decode for one dp group's slot table.
 
     A ``lax.while_loop`` of up to ``K`` iterations; each iteration
@@ -2807,7 +2450,6 @@ def _resident_program(params, k_pages, v_pages, page_rows, history,
     import jax
     import jax.numpy as jnp
 
-    del paged_impl  # chunk form has no kernel path yet
     kp, vp = k_pages[0], v_pages[0]
     page_rows_g = page_rows[0]
     history_g, kv_len_g = history[0], kv_len[0]

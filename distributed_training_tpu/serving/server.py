@@ -758,9 +758,8 @@ def engine_config_from_yaml(plan, engine_block: dict):
     # harmlessly when set).
     over = {k: v for k, v in engine_block.items()
             if k in ("max_batch", "num_pages", "max_seq_len",
-                     "policy", "temperature", "top_k",
-                     "prefill_slots", "prefill_mode", "spec_k",
-                     "spec_ngram", "resident_k", "eos_id")
+                     "temperature", "top_k", "prefill_slots",
+                     "spec_k", "resident_k", "eos_id")
             and v not in (0, 0.0, None, "")}
     # prefix_sharing is a REAL boolean: False == 0 would fall into
     # the "keep default" filter above and silently re-enable it.
@@ -826,7 +825,7 @@ def main(argv=None) -> int:
                          "default: the --config file's plan")
     ap.add_argument("--config", default=None,
                     help="serving YAML (conf/serving/default.yaml): "
-                         "engine geometry, scheduling policy, ports; "
+                         "engine geometry, ports; "
                          "explicit flags win per key")
     ap.add_argument("--port", type=int, default=None)
     ap.add_argument("--metrics-port", type=int, default=None)

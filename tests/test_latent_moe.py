@@ -137,8 +137,10 @@ def test_no_token_dropped_when_all_pick_one_expert():
 
 
 CADENCES = {
-    "plain": dict(prefill_mode="sequential"),
     "batched": dict(),
+    # The sampled per-launch path: a draw over one candidate is the
+    # argmax.
+    "sampled_top1": dict(temperature=0.7, top_k=1),
     "spec": dict(spec_k=3),
     "resident": dict(resident_k=4),
     "resident_spec": dict(resident_k=3, spec_k=2),

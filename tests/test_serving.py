@@ -98,12 +98,14 @@ def _as_layer(pages, dtype=jnp.float32):
 
 
 def test_paged_attention_matches_dense_reference():
-    """paged_attention over scattered pages == naive attention over
-    the equivalent dense K/V, exactly (same fp32 softmax path)."""
+    """paged_attention_chunk at one query a sequence (the decode
+    program's call) over scattered pages, ragged lengths == naive
+    attention over the equivalent dense K/V, exactly (same fp32
+    softmax path)."""
     from distributed_training_tpu.ops.attention import (
         _naive_attention)
     from distributed_training_tpu.ops.paged_attention import (
-        paged_attention)
+        paged_attention_chunk)
 
     rng = np.random.default_rng(0)
     B, H, Hkv, hd, ps, P = 3, 4, 2, 16, 8, 4
@@ -128,10 +130,10 @@ def test_paged_attention_matches_dense_reference():
             k_pages[:, pid] = dense_k[b, chunk].transpose(1, 0, 2)
             v_pages[:, pid] = dense_v[b, chunk].transpose(1, 0, 2)
     q = rng.standard_normal((B, H, hd)).astype(np.float32)
-    got = paged_attention(jnp.asarray(q), _as_layer(k_pages),
-                          _as_layer(v_pages),
-                          jnp.asarray(lengths),
-                          jnp.asarray(tables), impl="ref")
+    got = paged_attention_chunk(
+        jnp.asarray(q[:, None]), _as_layer(k_pages),
+        _as_layer(v_pages), jnp.asarray(tables),
+        jnp.asarray(lengths - 1)[:, None])[:, 0]
     for b in range(B):
         n = int(lengths[b])
         ref = _naive_attention(
@@ -389,7 +391,9 @@ _LAYOUT_CADENCES = {
     "plain": dict(),
     "spec4": dict(spec_k=4),
     "resident8": dict(resident_k=8),
-    "sequential": dict(prefill_mode="sequential"),
+    # A categorical draw over ONE candidate is the argmax: the sampled
+    # per-launch path, held to the same greedy reference.
+    "sampled_top1": dict(temperature=0.7, top_k=1),
 }
 
 
@@ -474,25 +478,6 @@ def test_no_recompiles_across_join_evict_storm(tiny_model):
     assert len(eng.completed) == 7
     assert eng.compile_counts() == counts, \
         "join/evict changed a traced shape"
-
-
-def test_scheduling_policies_same_tokens_different_order(tiny_model):
-    model, params = tiny_model
-    rng = np.random.default_rng(11)
-    prompts = [rng.integers(0, 256, size=6).astype(np.int32)
-               for _ in range(4)]
-
-    def run(policy):
-        eng = _engine(model, params, policy=policy, num_pages=96)
-        for i, p in enumerate(prompts):
-            eng.submit(Request(id=f"r{i}", prompt=p,
-                               max_new_tokens=6))
-        eng.run_until_drained()
-        return {r["id"]: r["tokens"] for r in eng.completed}
-
-    assert run("prefill") == run("decode")
-    with pytest.raises(ValueError, match="scheduling policy"):
-        EngineConfig(policy="fifo")
 
 
 def test_preempt_resume_is_token_transparent(tiny_model):
@@ -1278,28 +1263,21 @@ def _ragged_prompts():
             np.asarray(([3, 9, 27] * 7)[:20], np.int32)]
 
 
-def test_batched_prefill_matches_sequential_and_full_context(
-        tiny_model):
+def test_batched_prefill_matches_full_context(tiny_model):
     """The tentpole prefill pin: the batched lane program (many
     prompts' chunks per launch, ragged tails included) produces
-    token-for-token what BOTH the r02 sequential path and the
-    full-context ``model.apply`` reference produce."""
+    token-for-token what the full-context ``model.apply`` reference
+    produces, and changes no traced shape."""
     model, params = tiny_model
     prompts = _ragged_prompts()
-
-    def run(mode):
-        eng = _engine(model, params, prefill_mode=mode, num_pages=96)
-        counts = eng.warmup()
-        for i, p in enumerate(prompts):
-            eng.submit(Request(id=f"r{i}", prompt=p,
-                               max_new_tokens=10))
-        eng.run_until_drained()
-        assert eng.compile_counts() == counts, \
-            f"{mode} prefill changed a traced shape"
-        return {r["id"]: r["tokens"] for r in eng.completed}
-
-    batched = run("batched")
-    assert batched == run("sequential")
+    eng = _engine(model, params, num_pages=96)
+    counts = eng.warmup()
+    for i, p in enumerate(prompts):
+        eng.submit(Request(id=f"r{i}", prompt=p, max_new_tokens=10))
+    eng.run_until_drained()
+    assert eng.compile_counts() == counts, \
+        "prefill changed a traced shape"
+    batched = {r["id"]: r["tokens"] for r in eng.completed}
     for i, p in enumerate(prompts):
         assert batched[f"r{i}"] == _full_context_greedy(
             model, params, p, 10), f"prompt {i} diverged"
@@ -1307,8 +1285,7 @@ def test_batched_prefill_matches_sequential_and_full_context(
 
 def test_batched_prefill_packs_many_prompts_per_launch(tiny_model):
     """The launch-amortization mechanism itself: once admitted, ONE
-    prefill step advances EVERY pending single-chunk prompt (the
-    sequential path needed one launch each)."""
+    prefill step advances EVERY pending single-chunk prompt."""
     model, params = tiny_model
     eng = _engine(model, params, max_batch=6, num_pages=96)
     eng.warmup()
@@ -1422,10 +1399,16 @@ def test_spec_decode_respects_budget_and_seq_cap(tiny_model):
 def test_spec_requires_greedy():
     with pytest.raises(ValueError, match="greedy"):
         EngineConfig(spec_k=2, temperature=0.7)
-    with pytest.raises(ValueError, match="prefill_mode"):
-        EngineConfig(prefill_mode="eager")
     with pytest.raises(ValueError, match="spec_k"):
         EngineConfig(spec_k=0)
+
+
+@pytest.mark.parametrize("gone", ["prefill_mode", "policy", "paged_impl"])
+def test_removed_engine_options_are_unknown_fields(gone):
+    """One prefill path, one scheduling order, one attention entry: the
+    options that chose among others are no fields any more."""
+    with pytest.raises(TypeError, match=gone):
+        EngineConfig(**{gone: "x"})
 
 
 def test_prompt_lookup_draft():
@@ -1554,10 +1537,10 @@ def test_prefill_plan_objective_and_lane_feasibility():
 
 def test_serving_r03_ledger_committed_and_coherent():
     """SERVING_r03.json: the batched-prefill and speculative-decode
-    acceptance gates stay machine-checked — >= 2x one-seq-per-launch
-    prefill same-run, spec decode above per-token launches same-run
-    with the mean acceptance length recorded, zero recompiles, and
-    greedy parity against the full-context reference."""
+    acceptance gates stay machine-checked — spec decode above
+    per-token launches same-run with the mean acceptance length
+    recorded, zero recompiles, and greedy parity against the
+    full-context reference."""
     import os
 
     root = os.path.dirname(os.path.dirname(
@@ -1572,16 +1555,7 @@ def test_serving_r03_ledger_committed_and_coherent():
                                              "prefill_batch"}
     assert steady["greedy_matches_full_context"] is True
     assert steady["spec_k"] > 1
-    # THE prefill acceptance number: aggregate prompt tok/s of the
-    # batched lane table >= 2x the r02-style one-seq-per-launch
-    # path measured on the same mesh in the same run.
-    pf = doc["prefill"]
-    assert pf["speedup_vs_sequential_same_run"] >= 2.0
-    assert pf["batched"]["prefill_tokens_per_s"] > \
-        pf["sequential_same_mesh"]["prefill_tokens_per_s"]
-    assert pf["batched"]["steps"] < \
-        pf["sequential_same_mesh"]["steps"]
-    assert pf["first_tokens_match_sequential"] is True
+    assert doc["prefill"]["batched"]["prefill_tokens_per_s"] > 0
     # THE decode acceptance number: speculative launches beat
     # per-token launches same-run, acceptance recorded honestly.
     sat = doc["saturated"]
@@ -1737,13 +1711,11 @@ def test_resident_preempt_mid_storm_resubmit_parity(tiny_model):
     assert {r["id"]: r["tokens"] for r in eng.completed} == want
 
 
-def test_resident_requires_greedy_and_batched():
+def test_resident_requires_greedy():
     with pytest.raises(ValueError, match="resident_k"):
         EngineConfig(resident_k=0)
     with pytest.raises(ValueError, match="greedy"):
         EngineConfig(resident_k=2, temperature=0.5)
-    with pytest.raises(ValueError, match="batched"):
-        EngineConfig(resident_k=2, prefill_mode="sequential")
 
 
 def test_ngram_index_matches_rescan_draft():
@@ -1961,10 +1933,9 @@ def test_resident_metrics_gauges(tiny_model, tmp_path):
       "serving_cow"]),
     (dict(spec_k=2),
      ["serving_spec_decode", "serving_prefill_batch", "serving_cow"]),
-    (dict(prefill_mode="sequential", prefix_sharing=False),
-     ["serving_decode", "serving_prefill_first",
-      "serving_prefill_cont"]),
-], ids=["resident", "spec", "per_token_sequential"])
+    (dict(prefix_sharing=False),
+     ["serving_decode", "serving_prefill_batch"]),
+], ids=["resident", "spec", "per_token"])
 def test_engine_reports_paged_form_per_program(tiny_model, tmp_path,
                                                over, programs):
     """``Engine.paged_forms`` and the ``serving_warmup`` record name
@@ -2007,8 +1978,7 @@ def test_engine_reports_paged_form_per_program(tiny_model, tmp_path,
             "serving_decode": rule(4, 1),
             "serving_prefill_batch": rule(eng.prefill_local,
                                           eng.cfg.prefill_chunk),
-            "serving_prefill_cont": rule(1, eng.cfg.prefill_chunk),
-            "serving_prefill_first": None, "serving_cow": None}
+            "serving_cow": None}
     assert forms == {p: want[p] for p in programs}
     warm = [r for r in records if r["kind"] == "serving_warmup"]
     assert len(warm) == 1
@@ -2555,11 +2525,12 @@ def test_trace_lifecycle_preempt_resubmit_finish(tiny_model,
         tel.close()
 
 
-# One engine override a cadence: every ``_run_*`` path of ``Engine``
-# (the default prefill is the batched one).
+# One engine override a cadence: every ``_run_decode_*`` path of
+# ``Engine``, the one-token path greedy and sampled (a categorical draw
+# over one candidate is the argmax, so its tokens are the greedy ones).
 _CADENCES = {"plain": {}, "spec4": {"spec_k": 4},
              "resident8": {"resident_k": 8},
-             "sequential": {"prefill_mode": "sequential"}}
+             "sampled_top1": {"temperature": 0.7, "top_k": 1}}
 _cadence = pytest.mark.parametrize("cadence", sorted(_CADENCES))
 
 
@@ -2671,10 +2642,49 @@ def test_engine_programs_have_distinct_stable_names(tiny_model,
     assert len(set(names)) == len(names), names
     assert all(n.startswith("jit_serving_") for n in names), names
     decode = {"plain": "jit_serving_decode",
-              "sequential": "jit_serving_decode",
+              "sampled_top1": "jit_serving_decode",
               "spec4": "jit_serving_spec_decode",
               "resident8": "jit_serving_resident_decode"}[cadence]
     assert names[0] == decode and names[-1] == "jit_serving_cow"
+
+
+@_cadence
+def test_stop_token_ends_a_request_in_every_cadence(tiny_model,
+                                                    cadence):
+    """The stop token is emitted once and nothing after it, to the
+    record and to the stream alike, and the request's pages are freed:
+    mid-stream, and when it is the FIRST token out of prefill (the
+    request then never decodes). Every cadence ends a request through
+    the one ``Engine._emit``."""
+    model, params = tiny_model
+    prompts = _ragged_prompts()
+
+    def run(eos):
+        eng = _engine(model, params, num_pages=96, eos_id=eos,
+                      **_CADENCES[cadence])
+        streams = {f"s{i}": [] for i in range(len(prompts))}
+        for i, p in enumerate(prompts):
+            eng.submit(Request(id=f"s{i}", prompt=p,
+                               max_new_tokens=12))
+            eng.add_token_listener(
+                f"s{i}", lambda tok, done, out=streams[f"s{i}"]:
+                out.append((tok, done)))
+        eng.run_until_drained()
+        assert eng.cache.pages_used == 0
+        done = {r["id"]: r["tokens"] for r in eng.completed}
+        for rid, toks in done.items():
+            assert [t for t, _d in streams[rid]] == toks
+            assert [d for _t, d in streams[rid]] == \
+                [False] * (len(toks) - 1) + [True]
+        return [done[f"s{i}"] for i in range(len(prompts))]
+
+    free = run(-1)
+    assert all(len(t) == 12 for t in free)
+    for eos in (free[0][5], free[1][0]):
+        want = [t[:t.index(eos) + 1] if eos in t else t for t in free]
+        assert run(eos) == want, eos
+        assert any(len(t) < 12 and t[-1] == eos for t in want)
+    assert run(free[1][0])[1] == [free[1][0]]
 
 
 def test_server_request_trace_splits_mailbox_from_queue(tiny_model,
