@@ -711,13 +711,15 @@ def lower_serving_program(plan, objective: str):
                 arr((G, 2), jnp.uint32, grp))
     elif objective == "resident":
         # The device-resident burst program at the r04 bench shape
-        # (resident_k=4, spec_k=4) — page rows, history, cursors and
-        # stop flags all group-batched; no rng (greedy by contract).
+        # (resident_k=4, spec_k=4) — the carried slot table (history,
+        # cursors, tokens left), page rows, budgets and live flags all
+        # group-batched; no rng (greedy by contract).
         fn = build_resident_decode_fn(c, ecfg, mesh=mesh)
         args = (params, pool, pool,
-                arr((G, B, Ppages), jnp.int32, grp),
                 arr((G, B, ecfg.max_seq_len), jnp.int32, grp),
                 arr((G, B), jnp.int32, grp),
+                arr((G, B), jnp.int32, grp),
+                arr((G, B, Ppages), jnp.int32, grp),
                 arr((G, B), jnp.int32, grp),
                 arr((G, B), jnp.bool_, grp))
     else:

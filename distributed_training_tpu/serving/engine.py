@@ -201,6 +201,16 @@ class _Seq:
     # ``[version, count]`` pairs in emission order — a sequence that
     # straddles a hot-swap shows both versions; most show one.
     versions: list = field(default_factory=list)
+    # What the launches dispatched and not yet retired may still emit
+    # of it: exact at ``spec_k`` 1 with no stop token, else an upper
+    # bound (the device's slot table holds the truth). ``prefilled``
+    # counts a chunk from its dispatch, ``generated`` a token from its
+    # retire; ``pending`` is what lies between.
+    pending: int = 0
+    # Its row of the device's slot table holds its history (run-ahead
+    # engines): written by its own prefill chunks, or uploaded once
+    # where part of it was never prefilled here (``Engine._seed``).
+    on_device: bool = False
 
     def span(self, ev: str, t: float, **fields) -> None:
         """Append a lifecycle span. ``t`` is an absolute monotonic
@@ -236,6 +246,37 @@ class _Seq:
     def done(self) -> bool:
         return self.eos or \
             len(self.generated) >= self.req.max_new_tokens
+
+    @property
+    def left(self) -> int:
+        """Tokens it may still be given a budget for: what the request
+        asked for less what was emitted and what launches in flight
+        may emit (a lower bound where ``pending`` is an upper one)."""
+        return (self.req.max_new_tokens - len(self.generated)
+                - self.pending)
+
+    @property
+    def kv_ahead(self) -> int:
+        """Positions written once every launch in flight has landed,
+        for a sequence past its prompt: the newest token's KV is
+        written by the launch that feeds it, so one less than its
+        tokens. An upper bound where ``pending`` is."""
+        return (self.prompt_len + len(self.generated) + self.pending
+                - 1)
+
+
+@dataclass
+class _Launch:
+    """One dispatched launch until ``Engine._retire`` lands it: what
+    to fetch (None: nothing, a prefill launch that ends no prompt),
+    the cadence's own back half ``emit(now, *fetched) -> tokens``, and
+    whether another launch was un-retired when it was dispatched."""
+
+    op: str
+    outs: tuple | None
+    emit: object
+    ran_ahead: int
+    lanes: list | None = None     # prefill lanes a group (dp records)
 
 
 # The longest trailing n-gram the prompt-lookup draft tries first, on
@@ -408,26 +449,48 @@ def _named(name: str, body):
     return program
 
 
+# The slot table a run-ahead engine carries on the device between
+# launches (``Engine._slot_state``): history rows (G, B, max_seq_len),
+# committed lengths (G, B), tokens each request has left (G, B).
+_CARRIED = 3
+
+
+def carries_slots(ecfg: EngineConfig) -> bool:
+    """Do the programs of ``ecfg`` carry the slots' decode state on the
+    device? The resident burst does (it drafts from, appends to and
+    stops by it in-program), and then the prefill program writes it;
+    the one-token and speculative launches are fed from the host."""
+    return ecfg.resident_k > 1
+
+
 def _jit_program(name: str, body, block, ecfg: EngineConfig, mesh,
-                 n_grouped: int, n_results: int, params: bool = True):
+                 n_grouped: int, n_results: int, params: bool = True,
+                 pools: bool = True, carried: bool = False):
     """``body`` jitted as ``jit_<name>``. ``body`` is a group-local
     program: ``params`` first (unless ``params=False``), then
-    ``n_grouped`` group-batched arrays (leading dp-group dim), the two
-    pools the first of them and donated (serving HBM's dominant term
-    must not hold two copies); it returns ``n_results`` group-batched
-    results and the two pools. Where the mesh has a dp axis the body
-    runs under a shard_map manual over it (specs ``P()`` for the
-    params, ``P(dp)`` for the rest); every OTHER mesh axis is an
-    ``auto`` axis — tp's head shard (params + pool kv-head dim) stays
-    under the SPMD partitioner exactly as in the unsharded engine. The
-    out shardings are pinned (``_out_shardings``)."""
+    ``n_grouped`` group-batched arrays (leading dp-group dim): the two
+    pools the first of them (unless ``pools=False``), then the carried
+    slot table (``carried``: ``_CARRIED`` arrays), all of these donated
+    (serving HBM's dominant term must not hold two copies, and the
+    table is updated where it lies); it returns ``n_results``
+    group-batched results, then the carried table, then the two pools.
+    Where the mesh has a dp axis the body runs under a shard_map
+    manual over it (specs ``P()`` for the params, ``P(dp)`` for the
+    rest); every OTHER mesh axis is an ``auto`` axis — tp's head shard
+    (params + pool kv-head dim) stays under the SPMD partitioner
+    exactly as in the unsharded engine. The out shardings are pinned
+    (``_out_shardings``). What the program takes and returns of the
+    engine's state is kept on it (``Engine._call``)."""
     import jax
 
-    first = int(params)    # where the pools are among the arguments
+    first = int(params)    # where the donated state starts
+    n_carried = _CARRIED * carried
+    n_out = n_results + n_carried + 2 * pools
     kw = {}
     if mesh is not None:
         grp, pool = _out_shardings(block, ecfg, mesh)
-        kw["out_shardings"] = (grp,) * n_results + (pool, pool)
+        kw["out_shardings"] = ((grp,) * (n_results + n_carried)
+                               + (pool, pool) * pools)
     if _dp_extent(mesh, ecfg.dp_axis) > 1:
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
@@ -436,10 +499,12 @@ def _jit_program(name: str, body, block, ecfg: EngineConfig, mesh,
         body = shard_map(
             body, mesh=mesh,
             in_specs=(P(),) * first + (grouped,) * n_grouped,
-            out_specs=(grouped,) * (n_results + 2),
+            out_specs=(grouped,) * n_out,
             axis_names={ecfg.dp_axis}, check_vma=False)
-    return jax.jit(_named(name, body),
-                   donate_argnums=(first, first + 1), **kw)
+    program = _named(name, body)
+    program.takes = (params, pools, carried)
+    return jax.jit(program, donate_argnums=tuple(
+        range(first, first + 2 * pools + n_carried)), **kw)
 
 
 def build_decode_fn(block, ecfg: EngineConfig, mesh=None):
@@ -490,9 +555,34 @@ def build_prefill_batch_fn(block, ecfg: EngineConfig, mesh=None):
     lane writing its chunk's KV through the batched page-row scatter
     and sampling its next token in-program (the first token of every
     prompt-completing lane — read as one (G, S) int32 block, never a
-    vocab-sized logits transfer)."""
-    return _chunk_fn(block, ecfg, emit="last",
-                     name="serving_prefill_batch", mesh=mesh)
+    vocab-sized logits transfer).
+
+    Where the engine's programs carry the slot table
+    (``carries_slots``) this program writes it too, in the same
+    launch: the lane's chunk into its decode slot's history row and,
+    where the chunk ends the prompt, the sampled token after it, the
+    slot's committed length and the tokens the request has left
+    (``_prefill_slots_program``), so the burst dispatched next reads
+    the first token where it was made. In the prefill program and not
+    in one of its own beside it: the chunk and the sample are already
+    here, so nothing more is dispatched and no sampled token is handed
+    from one program to the next. Signature then:
+    ``fn(params, k_pages, v_pages, history (G, B, Lmax), kv_len (G, B),
+    left (G, B), <the chunk program's arguments>, slot (G, S),
+    max_new (G, S)) -> (next_tokens, counts, history, kv_len, left,
+    k_pages, v_pages)``."""
+    if not carries_slots(ecfg):
+        return _chunk_fn(block, ecfg, emit="last",
+                         name="serving_prefill_batch", mesh=mesh)
+    import functools
+
+    body = functools.partial(
+        _prefill_slots_program, block=block,
+        layouts=_layouts(block, ecfg, mesh),
+        temperature=ecfg.temperature, top_k=ecfg.top_k,
+        eos_id=ecfg.eos_id)
+    return _jit_program("serving_prefill_batch", body, block, ecfg,
+                        mesh, n_grouped=13, n_results=2, carried=True)
 
 
 def build_spec_decode_fn(block, ecfg: EngineConfig, mesh=None):
@@ -512,15 +602,17 @@ def build_resident_decode_fn(block, ecfg: EngineConfig,
     (each one a ``spec_k``-wide speculative step — the same
     ``_chunk_hidden`` math as the host-driven paths), drafting,
     verifying, stop-detecting (EOS / budget) and advancing each
-    slot's page cursor IN-PROGRAM. The host syncs once per burst.
+    slot's page cursor IN-PROGRAM, on the slot table it takes donated
+    and hands back. The host syncs once per burst, and packs the next
+    burst without that sync.
 
     Signature (all group-batched, G = dp extent, B = group-local
     slots, Lmax = max_seq_len, T = resident_k * spec_k):
-    ``fn(params, k_pages, v_pages, page_rows (G, B, P), history
-    (G, B, Lmax), kv_len (G, B), budget (G, B), active (G, B)) ->
+    ``fn(params, k_pages, v_pages, history (G, B, Lmax), kv_len (G, B),
+    left (G, B), page_rows (G, B, P), budget (G, B), active (G, B)) ->
     (out (G, B, T), n_emitted (G, B), steps (G,), counts (G, n),
-    k_pages, v_pages)``. An all-slots-complete burst returns early
-    via the loop predicate."""
+    history, kv_len, left, k_pages, v_pages)``. An
+    all-slots-complete burst returns early via the loop predicate."""
     import functools
 
     body = functools.partial(
@@ -528,7 +620,30 @@ def build_resident_decode_fn(block, ecfg: EngineConfig,
         layouts=_layouts(block, ecfg, mesh), K=ecfg.resident_k,
         C=ecfg.spec_k, ngram=SPEC_NGRAM, eos_id=ecfg.eos_id)
     return _jit_program("serving_resident_decode", body, block, ecfg,
-                        mesh, n_grouped=7, n_results=4)
+                        mesh, n_grouped=8, n_results=4, carried=True)
+
+
+def _seed_program(history, kv_len, left, rows, kv, left_new, live):
+    """Overwrite the ``live`` slots of the carried slot table with what
+    the host uploads: ``rows`` (G, B, Lmax) their histories, ``kv`` /
+    ``left_new`` (G, B) their committed lengths and tokens left. Every
+    other slot passes through."""
+    import jax.numpy as jnp
+
+    return (jnp.where(live[..., None], rows, history),
+            jnp.where(live, kv, kv_len),
+            jnp.where(live, left_new, left))
+
+
+def build_seed_fn(block, ecfg: EngineConfig, mesh=None):
+    """The jitted upload into the carried slot table, for a slot that
+    starts with tokens no prefill launch of this engine wrote.
+    Signature: ``fn(history, kv_len, left, rows (G, B, Lmax),
+    kv (G, B), left_new (G, B), live (G, B)) -> (history, kv_len,
+    left)``."""
+    return _jit_program("serving_seed", _seed_program, block, ecfg,
+                        mesh, n_grouped=7, n_results=0, params=False,
+                        pools=False, carried=True)
 
 
 def _cow_program(k_pages, v_pages, src, dst):
@@ -604,14 +719,22 @@ class Engine:
                 f"the {self.dp_groups} dp group(s) — the prefill "
                 "lane table deals exactly like the decode table")
         self.prefill_local = prefill_slots // self.dp_groups
-        # What the last step's launch path leaves for its step
-        # record (the record is the only ledger: totals are sums over
-        # records): the phases' seconds, and the fields that only its
-        # cadence has (speculative acceptance, resident loop depth,
-        # slots and iterations, first tokens).
-        self._last_prefill_lanes: list[int] | None = None
+        # What the engine's time since the last retire leaves for the
+        # next step record (the record is the only ledger: totals are
+        # sums over records): when that stretch began, the phases'
+        # seconds and the syncs in it, and the fields that only the
+        # retired launch's cadence has (speculative acceptance,
+        # resident loop depth, slots and iterations, first tokens).
+        self._tile_t0 = time.monotonic()
+        self._tile_syncs0 = 0
         self._phase_s = dict.fromkeys(_PHASES, 0.0)
         self._step_counts: dict = {}
+        # The launch dispatched and not yet retired (run-ahead engines;
+        # None between the steps of every other), and the sequences
+        # whose rows the slot table lacks, for ``_page_rows`` to upload
+        # before the launch that claimed them.
+        self._flying: _Launch | None = None
+        self._unseeded: list[_Seq] = []
         # Prefix sharing + chat sessions (SERVING_r05). ``sessions``
         # maps session key -> retained state (cache id holding the
         # parked pages, the full token history they cover, the owning
@@ -672,6 +795,7 @@ class Engine:
         self.launch_count = 0
         self.faults = None
         self._build_programs()
+        self._slot_state = self._new_slot_state()
         # Greedy decode never reads the rng operand — fold_in/
         # key_data are ~5 device dispatches PER STEP, and on the CPU
         # mesh that was ~40% of the decode step's wall clock
@@ -684,6 +808,12 @@ class Engine:
 
     def _build_programs(self) -> None:
         block = self.block
+        # How far the engine runs ahead follows from the programs it
+        # builds: one launch where they carry the slots' state on the
+        # device (launch n+1 is dispatched before launch n is fetched,
+        # ``_step``), none where the host must read a token to pack
+        # the next launch.
+        self._run_ahead = carries_slots(self.cfg)
         if self.cfg.resident_k > 1:
             # The device-resident K-step loop IS the decode program:
             # each loop iteration is one spec_k-wide chunk (spec_k=1
@@ -709,6 +839,8 @@ class Engine:
             self._run_decode = self._run_decode_token
         self._prefill_batch_fn = build_prefill_batch_fn(
             block, self.cfg, mesh=self.mesh)
+        if self._run_ahead:
+            self._seed_fn = build_seed_fn(block, self.cfg, self.mesh)
         if self._sharing:
             self._cow_fn = build_cow_fn(block, self.cfg, mesh=self.mesh)
 
@@ -716,6 +848,8 @@ class Engine:
         """Every jitted program this engine built, by its role."""
         fns = {"decode": self._decode_fn,
                "prefill_batch": self._prefill_batch_fn}
+        if self._run_ahead:
+            fns["seed"] = self._seed_fn
         if self._sharing:
             fns["cow"] = self._cow_fn
         return fns
@@ -738,22 +872,63 @@ class Engine:
                 for fn in self._programs().values()
                 if hasattr(fn.__wrapped__, "paged_form")}
 
+    def _new_slot_state(self) -> tuple:
+        """The carried slot table, empty (``_CARRIED`` distinct
+        buffers: each is donated on its own), laid out as the programs
+        return it; nothing where no program carries it."""
+        import jax
+        import jax.numpy as jnp
+
+        if not self._run_ahead:
+            return ()
+        G, B = self.dp_groups, self.batch_local
+        grp, _pool = _out_shardings(self.block, self.cfg, self.mesh)
+        return tuple(
+            jax.device_put(z, grp) if grp is not None else z
+            for z in (jnp.zeros((G, B, self.cfg.max_seq_len), jnp.int32),
+                      jnp.zeros((G, B), jnp.int32),
+                      jnp.zeros((G, B), jnp.int32)))
+
+    def _state_of(self, fn) -> tuple:
+        """What ``fn`` takes of the engine's state, in its order: the
+        params, the two pools, the carried slot table (the last two
+        donated: ``_call`` adopts what comes back)."""
+        params, pools, carried = fn.__wrapped__.takes
+        state = (self.params,) if params else ()
+        if pools:
+            state += (self.cache.k_pages, self.cache.v_pages)
+        if carried:
+            state += self._slot_state
+        return state
+
+    def _call(self, fn, *args) -> list:
+        """One dispatch of ``fn`` on the state it takes and ``args``.
+        The pools and the slot table it returns are adopted; the rest,
+        its results, come back as they are: device arrays, not
+        fetched."""
+        outs = list(fn(*self._state_of(fn), *args))
+        _params, pools, carried = fn.__wrapped__.takes
+        if pools:
+            self.cache.update_pools(*outs[-2:])
+            del outs[-2:]
+        if carried:
+            self._slot_state = tuple(outs[-_CARRIED:])
+            del outs[-_CARRIED:]
+        return outs
+
     def _warmup_calls(self):
         """``(program, arguments)`` for every program this engine
         built, against scratch-only page rows and all-dead lanes (zero
         allocator side effects: every write lands in each group's
-        scratch page). Lazy, because the pools are donated: a call's
-        arguments hold the pools the call before it returned."""
+        scratch page, and no slot of the carried table is live). The
+        arguments past the engine's own state (``_state_of``), which
+        ``warmup`` threads from call to call."""
         import jax.numpy as jnp
 
         G, B = self.dp_groups, self.batch_local
         P = self.cache.cfg.pages_per_seq
         C = self.cfg.prefill_chunk
         rng = jnp.zeros((G, 2), jnp.uint32)
-
-        def pools():
-            return (self.params, self.cache.k_pages,
-                    self.cache.v_pages)
 
         def zeros(*shape, dtype=jnp.int32):
             return jnp.zeros(shape, dtype)
@@ -763,29 +938,30 @@ class Engine:
             # iteration 0 (the all-slots-complete early exit), but
             # tracing still compiles the full resident body.
             yield self._decode_fn, (
-                *pools(), zeros(G, B, P),
-                zeros(G, B, self.cfg.max_seq_len), zeros(G, B),
-                zeros(G, B), zeros(G, B, dtype=jnp.bool_))
+                zeros(G, B, P), zeros(G, B),
+                zeros(G, B, dtype=jnp.bool_))
         elif self.cfg.spec_k > 1:
             yield self._decode_fn, (
-                *pools(), zeros(G, B, P), zeros(G, B, self.cfg.spec_k),
+                zeros(G, B, P), zeros(G, B, self.cfg.spec_k),
                 zeros(G, B), zeros(G, B),
                 zeros(G, B, dtype=jnp.bool_), rng)
         else:
             yield self._decode_fn, (
-                *pools(), zeros(G, B), zeros(G, B), zeros(G, B, P),
+                zeros(G, B), zeros(G, B), zeros(G, B, P),
                 zeros(G, B, dtype=jnp.bool_), rng)
         Sp = self.prefill_local
         yield self._prefill_batch_fn, (
-            *pools(), zeros(G, Sp, P), zeros(G, Sp, C),
-            zeros(G, Sp), zeros(G, Sp),
-            zeros(G, Sp, dtype=jnp.bool_), rng)
+            zeros(G, Sp, P), zeros(G, Sp, C), zeros(G, Sp),
+            zeros(G, Sp), zeros(G, Sp, dtype=jnp.bool_), rng,
+            *((zeros(G, Sp), zeros(G, Sp)) if self._run_ahead else ()))
+        if self._run_ahead:
+            yield self._seed_fn, (
+                zeros(G, B, self.cfg.max_seq_len), zeros(G, B),
+                zeros(G, B), zeros(G, B, dtype=jnp.bool_))
         if self._sharing:
             # Scratch-to-scratch identity copies.
             W = self._cow_width
-            yield self._cow_fn, (self.cache.k_pages,
-                                 self.cache.v_pages, zeros(G, W),
-                                 zeros(G, W))
+            yield self._cow_fn, (zeros(G, W), zeros(G, W))
 
     def warmup(self) -> dict:
         """Compile every program (``_warmup_calls``) and emit one
@@ -801,16 +977,16 @@ class Engine:
         temp_bytes = {}
         for fn, args in self._warmup_calls():
             if current().enabled:
-                # Shapes, taken before the call donates the pools.
+                # Shapes, taken before the call donates the state.
                 shapes = jax.tree.map(
                     lambda a: jax.ShapeDtypeStruct(
                         a.shape, a.dtype, sharding=a.sharding)
-                    if isinstance(a, jax.Array) else a, args)
+                    if isinstance(a, jax.Array) else a,
+                    (*self._state_of(fn), *args))
                 analysis = fn.lower(*shapes).compile().memory_analysis()
                 temp_bytes[fn.__wrapped__.__name__] = getattr(
                     analysis, "temp_size_in_bytes", None)
-            *_outs, k, v = fn(*args)
-            self.cache.update_pools(k, v)
+            self._call(fn, *args)
         event("serving_warmup",
               programs=[{"program": name, "paged_form": form,
                          "temp_bytes": temp_bytes.get(name)}
@@ -940,7 +1116,10 @@ class Engine:
 
     @property
     def idle(self) -> bool:
-        return not self.queue and self.in_flight == 0
+        """Nothing queued, no slot held and no launch in flight (a
+        launch not yet retired still owes its tokens)."""
+        return (not self.queue and self.in_flight == 0
+                and self._flying is None)
 
     def group_of_slot(self, slot: int) -> int:
         return slot // self.batch_local
@@ -1178,11 +1357,38 @@ class Engine:
                 dst[g, fill[g]] = b
                 fill[g] += 1
         with self._phase("launch"):
-            k, v = self._cow_fn(
-                self.cache.k_pages, self.cache.v_pages,
-                jnp.asarray(src), jnp.asarray(dst))
-            self.cache.update_pools(k, v)
+            self._call(self._cow_fn, jnp.asarray(src), jnp.asarray(dst))
         self.prefix_stats["cow_pages"] += len(pairs)
+
+    def _seed(self, seqs: list) -> None:
+        """Upload into the carried slot table the rows of ``seqs``,
+        sequences that begin with tokens no prefill launch of this
+        engine wrote (a prefix hit, a resumed session, an adopted or
+        re-adopted sequence): the whole history the host knows, the
+        committed length and the tokens left. Once a sequence, before
+        the first launch that packs it (``_claim``), so none of its
+        own is in flight and the host's numbers are exact; from then
+        on its prefill chunks and bursts keep the row on the device."""
+        import jax.numpy as jnp
+
+        with self._phase("pack"):
+            G, B = self.dp_groups, self.batch_local
+            rows = np.zeros((G, B, self.cfg.max_seq_len), np.int32)
+            kv = np.zeros((G, B), np.int32)
+            left = np.zeros((G, B), np.int32)
+            live = np.zeros((G, B), bool)
+            for s in seqs:
+                at = divmod(s.slot, B)
+                hist = np.concatenate([
+                    np.array(s.req.prompt, np.int32),
+                    np.array(s.generated, np.int32)])
+                rows[at][:hist.shape[0]] = hist
+                kv[at] = self.cache.length(s.req.id)
+                left[at] = s.req.max_new_tokens - len(s.generated)
+                live[at] = True
+        with self._phase("launch"):
+            self._call(self._seed_fn, *(jnp.asarray(a) for a in
+                                        (rows, kv, left, live)))
 
     def _register(self, seq: _Seq) -> None:
         """Index the sequence's newly committed page-aligned
@@ -1206,8 +1412,13 @@ class Engine:
                 if s is not None and not s.prefill_done]
 
     def _decode_candidates(self) -> list[_Seq]:
+        """Slots past their prompt that may be given a budget: not
+        ended, and with tokens left beyond what launches in flight may
+        emit (the projection; nothing is in flight where the engine
+        does not run ahead)."""
         return [s for s in self.slots
-                if s is not None and s.prefill_done and not s.done]
+                if s is not None and s.prefill_done and not s.eos
+                and s.left > 0]
 
     def _phase(self, key: str) -> phase:
         """One of the five parts of a step (``_PHASES``): the
@@ -1217,21 +1428,41 @@ class Engine:
         return phase("serving." + key, self._phase_s, key)
 
     def step(self) -> dict:
-        """One scheduling decision + one compiled program launch.
-        Returns a record of what ran (``kind``: prefill/decode/idle).
-        """
+        """One scheduling decision, one compiled program launch
+        dispatched and one retired. Returns the record of the launch
+        it retired (``op``: prefill/decode; idle when there was
+        none)."""
         with phase("serving.step"):
             return self._step()
 
     def _step(self) -> dict:
-        t0 = time.monotonic()
-        self._phase_s = dict.fromkeys(_PHASES, 0.0)
+        """Admit, pack and dispatch the next launch, then retire the
+        launch before it: fetch it (the one sync), emit its tokens.
+        Where the programs carry the slots' state (``_run_ahead``) the
+        launch just dispatched stays in flight while the one before is
+        fetched and emitted, so the device has the next launch queued
+        when a launch ends and the host's bookkeeping of a burst runs
+        beside the next burst; a step that finds nothing in flight
+        fills the pipeline first (dispatches twice), and one with
+        nothing to dispatch retires what is in flight. Everywhere
+        else the launch is retired at once: the host needs its tokens
+        to pack the next."""
+        if self._flying is None:
+            self._tile_t0 = time.monotonic()
+        launch = self._dispatch()
+        if self._run_ahead:
+            if self._flying is None and launch is not None:
+                self._flying = launch
+                launch = self._dispatch()
+            launch, self._flying = self._flying, launch
+        return self._retire(launch)
+
+    def _dispatch(self) -> _Launch | None:
+        """The front half of a step: admission, the scheduling
+        decision, and the launch it leads to, dispatched and not
+        fetched. None = nothing to launch (idle, or every candidate
+        stalled on pages)."""
         with self._phase("admit"):
-            tokens_out = 0
-            self._step_counts = {}
-            self._last_prefill_lanes = None
-            self._step_prefix = [0, 0]
-            syncs0 = self.host_syncs
             pending = self._prefill_candidates()
             can_admit = (not self.draining and self.queue
                          and self._free_slot() is not None)
@@ -1247,9 +1478,10 @@ class Engine:
                         and self._admit() is not None:
                     pass
                 pending = self._prefill_candidates()
+        launch = None
         if kind == "prefill":
-            tokens_out = self._run_prefill_batch(pending)
-            if tokens_out == 0:
+            launch = self._run_prefill_batch(pending)
+            if launch is None:
                 # Backpressure fallback: when admission OR a
                 # mid-prompt page allocation fails (pool exhausted,
                 # every pending chunk stalled), decode instead —
@@ -1264,8 +1496,43 @@ class Engine:
                 decodable = self._decode_candidates()
                 kind = "decode" if decodable else "idle"
         if kind == "decode":
-            tokens_out = self._run_decode(decodable)
-        dur = time.monotonic() - t0
+            launch = self._run_decode(decodable)
+        return launch
+
+    def _retire(self, launch: _Launch | None) -> dict:
+        """The back half of a step, for one launch: the ONE
+        ``_fetch_host`` of what it returned (none for a prefill launch
+        that ended no prompt), the block's counts (``_count``), the
+        cadence's emit loop, and the step record. The clock is read
+        AFTER the blocking fetch: under async dispatch an earlier read
+        would leave the launch's own compute out of a request's
+        latencies.
+
+        The record describes ONE launch, this one (``op``, ``tokens``,
+        what its cadence left in ``_step_counts``, ``ran_ahead``), and
+        the records tile the engine's time: ``dur_s`` runs from the
+        end of the retire before (from the start of the step, where
+        nothing was in flight then) to the end of this one, and
+        ``phase_s`` and ``host_syncs`` are what fell into that
+        stretch, the dispatch of the launch after this one
+        included."""
+        tokens_out = 0
+        if launch is not None:
+            fetched = ()
+            try:
+                if launch.outs is not None:
+                    *fetched, counts = self._fetch_host(*launch.outs)
+                    self._count(counts)
+                now = time.monotonic()
+                with self._phase("emit"):
+                    tokens_out = launch.emit(now, *fetched)
+            except BaseException:
+                # The launch dispatched behind this one continues from
+                # tokens the host now never had: it goes with it.
+                self._flying = None
+                raise
+        kind = launch.op if launch is not None else "idle"
+        end = time.monotonic()
         # "op", not "kind": telemetry's record envelope owns "kind"
         # (the event name), and a colliding field would silently
         # relabel the whole record past the metrics observer.
@@ -1279,14 +1546,18 @@ class Engine:
         # and ``slot_iters`` (decode), with ``spec_k`` and
         # ``spec_accepted_mean`` or ``resident_k`` and
         # ``resident_steps_per_launch`` by cadence; ``first_tokens``
-        # (prefill).
-        rec = {"op": kind, "dur_s": dur, "tokens": tokens_out,
+        # (prefill); and ``ran_ahead``: 1 when the launch was
+        # dispatched while another was un-retired.
+        rec = {"op": kind, "dur_s": end - self._tile_t0,
+               "tokens": tokens_out,
                "phase_s": {k: round(v, 6)
                            for k, v in self._phase_s.items()},
                "in_flight": self.in_flight,
                "queue_depth": len(self.queue),
                **self._step_counts,
                **self.cache.occupancy()}
+        if launch is not None:
+            rec["ran_ahead"] = launch.ran_ahead
         if self._sharing:
             # Additive sharing fields (schema pinned by test): the
             # metrics observer accumulates the per-step deltas into
@@ -1298,7 +1569,7 @@ class Engine:
             rec["kv_pages_shared"] = [
                 self.cache.shared_pages_in(g)
                 for g in range(self.dp_groups)]
-        syncs = self.host_syncs - syncs0
+        syncs = self.host_syncs - self._tile_syncs0
         rec["host_syncs"] = syncs
         if tokens_out:
             rec["host_syncs_per_token"] = round(
@@ -1306,9 +1577,14 @@ class Engine:
         rec["weight_bytes"] = self.weight_bytes
         if self.dp_groups > 1:
             rec["group_slots_active"] = self.slots_active_by_group()
-            if self._last_prefill_lanes is not None:
-                rec["group_prefill_slots_active"] = \
-                    self._last_prefill_lanes
+            if launch is not None and launch.lanes is not None:
+                rec["group_prefill_slots_active"] = launch.lanes
+        # The next record's stretch starts here.
+        self._tile_t0 = end
+        self._tile_syncs0 = self.host_syncs
+        self._phase_s = dict.fromkeys(_PHASES, 0.0)
+        self._step_counts = {}
+        self._step_prefix = [0, 0]
         event("serving", **rec)
         self._step_counter += 1
         if kind != "idle":
@@ -1316,6 +1592,18 @@ class Engine:
             if self.faults is not None:
                 self._run_faults()
         return rec
+
+    def _settle(self) -> None:
+        """Retire the launch in flight, if any. Whatever reads or moves
+        the sequences' host-side state from outside a step (``preempt``,
+        ``drain``, ``swap_weights``, ``export_in_flight``,
+        ``export_emission_state``, ``adopt_batch``, the fault hook)
+        calls this first: until then ``generated`` and the cache's
+        lengths lag what the device has done. Its step record is
+        emitted like any other."""
+        if self._flying is not None:
+            launch, self._flying = self._flying, None
+            self._retire(launch)
 
     def _run_faults(self) -> None:
         """Serving fault hook, fired AFTER the step record is emitted
@@ -1331,6 +1619,10 @@ class Engine:
             InjectedCrash)
 
         fired = self.faults.on_launch(self.launch_count)
+        if "client_disconnect" in fired or "engine_crash" in fired:
+            # Both act on the streams' host-side state: the launch in
+            # flight lands first (its own record may fire faults too).
+            self._settle()
         if "client_disconnect" in fired and self._token_listeners:
             rid = next(iter(self._token_listeners))
             self._token_listeners.pop(rid, None)
@@ -1396,6 +1688,12 @@ class Engine:
             if pairs is None:
                 return None
             cow += [(g, a, b) for a, b in pairs]
+        if self._run_ahead and not s.on_device:
+            # Its first launch here. A prompt prefilled from its first
+            # token writes its own row; anything else is uploaded.
+            s.on_device = True
+            if s.prefilled:
+                self._unseeded.append(s)
         if lane is not None:
             i = lane
         table[g][i] = s.req.id
@@ -1403,33 +1701,32 @@ class Engine:
 
     def _page_rows(self, table: list, cow: list) -> np.ndarray:
         """The page rows of a launch's claimed ``table``, once the
-        pages ``_claim`` forked are copied (one launch of their own)."""
+        pages ``_claim`` forked are copied and the rows the slot table
+        lacks are uploaded (one launch of their own each)."""
         if cow:
             self._apply_cow(cow)
+        if self._unseeded:
+            self._seed(self._unseeded)
+            self._unseeded = []
         with self._phase("pack"):
             return self.cache.page_rows_grouped(table)
 
-    def _launch(self, fn, *args, fetch: bool = True) -> tuple:
-        """One launch of ``fn`` on the params, the pools (donated; the
-        returned ones are adopted) and ``args``, then ONE sync for
-        everything else it returns, the block's counts last (added to
-        the step record). Returns the fetched results and the clock
-        AFTER the blocking fetch: under async dispatch an earlier read
-        would leave the launch's own compute out of a request's
-        latencies. ``fetch=False`` reads nothing (a prefill launch that
-        ends no prompt) and the clock is the dispatch's."""
+    def _launch(self, fn, op: str, emit, *args, fetch: bool = True,
+                lanes: list | None = None) -> _Launch:
+        """Dispatch one launch of ``fn`` on the engine's state (the
+        params; the pools and, where ``fn`` carries it, the slot table,
+        donated and adopted as returned: ``_call``) and ``args``, and
+        record what is in flight: the results to fetch, the block's
+        counts last (``fetch=False`` reads nothing: a prefill launch
+        that ends no prompt), and the cadence's ``emit`` for
+        ``_retire`` to run on them. Nothing is fetched here."""
         import jax.numpy as jnp
 
         with self._phase("launch"):
-            *outs, k, v = fn(
-                self.params, self.cache.k_pages, self.cache.v_pages,
-                *(jnp.asarray(a) for a in args))
-            self.cache.update_pools(k, v)
-        if not fetch:
-            return (None,) * (len(outs) - 1) + (time.monotonic(),)
-        *outs, counts = self._fetch_host(*outs)
-        self._count(counts)
-        return (*outs, time.monotonic())
+            outs = self._call(fn, *(jnp.asarray(a) for a in args))
+        return _Launch(op, tuple(outs) if fetch else None, emit,
+                       ran_ahead=int(self._flying is not None),
+                       lanes=lanes)
 
     def _emit(self, s: _Seq, toks, now: float, ev: str, advance: int,
               **fields) -> None:
@@ -1453,15 +1750,20 @@ class Engine:
         self._register(s)
         self._maybe_finish(s)
 
-    def _run_prefill_batch(self, pending: list[_Seq]) -> int:
+    def _run_prefill_batch(self, pending: list[_Seq]
+                           ) -> _Launch | None:
         """One launch of the batched prefill program: pack up to
         ``prefill_local`` pending sequences PER GROUP (each lane is
         one sequence's current chunk, pages claimed first), write all
-        their KV through one batched scatter, and read the in-program
-        sample for every lane whose chunk completed its prompt.
-        Returns the prompt tokens processed (0 = every pending chunk
-        stalled on pages — backpressure; the caller lets decode run
-        so pages free up)."""
+        their KV through one batched scatter, and at its retire read
+        the in-program sample for every lane whose chunk completed its
+        prompt. A chunk counts as prefilled from its dispatch (the
+        next launch may pack the chunk after it, or the sequence's
+        first burst, while this one is in flight); where the programs
+        carry the slot table the launch writes the lane's row of it
+        (``build_prefill_batch_fn``). None = every pending chunk
+        stalled on pages — backpressure; the caller lets decode run so
+        pages free up."""
         with self._phase("pack"):
             G, Sp, C = (self.dp_groups, self.prefill_local,
                         self.cfg.prefill_chunk)
@@ -1469,11 +1771,12 @@ class Engine:
             start_pos = np.zeros((G, Sp), np.int32)
             n_valid = np.zeros((G, Sp), np.int32)
             active = np.zeros((G, Sp), bool)
+            slot = np.zeros((G, Sp), np.int32)
+            max_new = np.zeros((G, Sp), np.int32)
             seq_ids: list[list] = [[None] * Sp for _ in range(G)]
             lanes = [0] * G
-            chosen: list[tuple[_Seq, int, int, int]] = []
+            chosen: list[tuple[_Seq, int, int, int, bool]] = []
             cow: list = []
-            completing = False
             for s in pending:
                 g = self.group_of_slot(s.slot)
                 if lanes[g] >= Sp:
@@ -1491,31 +1794,37 @@ class Engine:
                 start_pos[at] = start
                 n_valid[at] = n
                 active[at] = True
-                completing |= start + n >= s.prompt_len
-                chosen.append((s, *at, n))
-        if not chosen:
-            return 0
-        # ONE (G, Sp) int32 pull for the whole launch, and only when
-        # some prompt completed — never a logits block.
-        fetched, now = self._launch(
-            self._prefill_batch_fn, self._page_rows(seq_ids, cow),
-            tokens, start_pos, n_valid, active,
-            self._rng_grouped(1_000_000 + self._step_counter),
-            fetch=completing)
-        with self._phase("emit"):
-            total = first_tokens = 0
-            for s, g, i, n in chosen:
+                slot[at] = s.slot % self.batch_local
                 s.prefilled += n
+                ends = s.prefill_done
+                if ends:
+                    max_new[at] = s.req.max_new_tokens
+                    s.pending += 1
+                chosen.append((s, *at, n, ends))
+        if not chosen:
+            return None
+
+        def emit(now, fetched=None):
+            total = first_tokens = 0
+            for s, g, i, n, ends in chosen:
+                s.pending -= ends
                 total += n
-                done = s.prefill_done
-                self._emit(s, (int(fetched[g, i]),) if done else (),
+                self._emit(s, (int(fetched[g, i]),) if ends else (),
                            now, "prefill", n, tokens=n)
-                first_tokens += done
-            self._last_prefill_lanes = lanes
+                first_tokens += ends
             self.prefill_launches += 1
             self.prefill_tokens_computed += total
             self._step_counts["first_tokens"] = first_tokens
-        return total
+            return total
+
+        # ONE (G, Sp) int32 pull for the whole launch, and only when
+        # some prompt completed — never a logits block.
+        return self._launch(
+            self._prefill_batch_fn, "prefill", emit,
+            self._page_rows(seq_ids, cow), tokens, start_pos, n_valid,
+            active, self._rng_grouped(1_000_000 + self._step_counter),
+            *((slot, max_new) if self._run_ahead else ()),
+            fetch=any(c[-1] for c in chosen), lanes=lanes)
 
     def _draft(self, seq: _Seq, m: int) -> np.ndarray:
         """``m`` drafted tokens for ``seq`` by prompt lookup over its
@@ -1536,7 +1845,8 @@ class Engine:
                 seq.generated[len(idx) - seq.prompt_len:])
         return idx.draft(m)
 
-    def _run_decode_spec(self, decodable: list[_Seq]) -> int:
+    def _run_decode_spec(self, decodable: list[_Seq]
+                         ) -> _Launch | None:
         """One launch of the speculative multi-token decode program:
         every decodable slot carries [last sampled token, spec_k - 1
         drafted tokens], the program argmax-verifies all positions in
@@ -1582,11 +1892,9 @@ class Engine:
                 active[at] = True
                 stepped.append((s, at, n, draft))
         if not stepped:
-            return 0
-        out, now = self._launch(
-            self._decode_fn, self._page_rows(seq_ids, cow), tokens,
-            start_pos, n_valid, active, self._zero_rng)
-        with self._phase("emit"):
+            return None
+
+        def emit(now, out):
             total = 0
             for s, at, n, draft in stepped:
                 # out[at][j] is the verified argmax AFTER position
@@ -1594,40 +1902,59 @@ class Engine:
                 # previous token; every accepted position's argmax is
                 # then conditioned on true tokens only.
                 chain = out[at].tolist()
-                emit = chain[:1]
+                took = chain[:1]
                 j = 1
-                while j < n and int(draft[j - 1]) == emit[-1]:
-                    emit.append(chain[j])
+                while j < n and int(draft[j - 1]) == took[-1]:
+                    took.append(chain[j])
                     j += 1
-                if self.cfg.eos_id >= 0 and self.cfg.eos_id in emit:
+                if self.cfg.eos_id >= 0 and self.cfg.eos_id in took:
                     # Stop at the stop token: later accepted
                     # positions are conditioned on a sequence that
                     # already ended.
-                    emit = emit[:emit.index(self.cfg.eos_id) + 1]
-                self._emit(s, emit, now, "decode", len(emit),
-                           emitted=len(emit), budget=n)
-                total += len(emit)
+                    took = took[:took.index(self.cfg.eos_id) + 1]
+                self._emit(s, took, now, "decode", len(took),
+                           emitted=len(took), budget=n)
+                total += len(took)
             # One verification chunk a stepped slot: ``slot_iters``
             # is exact.
             self._step_counts.update(
                 slots_stepped=len(stepped), slot_iters=len(stepped),
                 spec_k=K,
                 spec_accepted_mean=round(total / len(stepped), 4))
-        return total
+            return total
 
-    def _run_decode_resident(self, decodable: list[_Seq]) -> int:
+        return self._launch(
+            self._decode_fn, "decode", emit,
+            self._page_rows(seq_ids, cow), tokens, start_pos, n_valid,
+            active, self._zero_rng)
+
+    def _run_decode_resident(self, decodable: list[_Seq]
+                             ) -> _Launch | None:
         """One BURST of the device-resident decode loop: every
-        decodable slot ships its full history row + a token budget,
-        the program runs up to ``resident_k`` chunk iterations
-        (drafting, verifying, stop-detecting and advancing its own
-        page cursor per slot ON DEVICE), and the host syncs ONCE for
-        the whole burst — ``(out, n_emitted, steps)``, one
-        ``_fetch_host`` call. Greedy token identity is preserved by
-        construction: each iteration emits exactly the argmax chain
-        the host spec path would (the same ``_chunk_hidden`` math),
-        so K only moves the sync cadence, never tokens. A burst is
-        atomic host-side — the cache advances only after the fetch —
-        so a preemption between bursts resubmits cleanly.
+        decodable slot ships its page rows and a token budget, the
+        program runs up to ``resident_k`` chunk iterations (drafting,
+        verifying, stop-detecting and advancing its own page cursor
+        per slot ON DEVICE, on the slot table it carries from the
+        launch before) and the host syncs ONCE for the whole burst —
+        ``(out, n_emitted, steps)``, one ``_fetch_host`` call, at its
+        retire. Greedy token identity is preserved by construction:
+        each iteration emits exactly the argmax chain the host spec
+        path would (the same ``_chunk_hidden`` math), so K only moves
+        the sync cadence, never tokens.
+
+        The burst is packed while the launch before it may be in
+        flight, from a projection: a slot's budget is what its request
+        has left beyond what that launch may emit (``_Seq.left``), and
+        its pages are claimed for the length that launch may reach
+        (``_Seq.kv_ahead``) plus the budget. The device holds the
+        truth and clamps: a slot that stopped early in the launch
+        before (the stop token, or ``spec_k > 1`` accepting less than
+        its budget) runs on from where it really is, or not at all,
+        and the pages claimed for tokens that never came go back at
+        the retire. A burst is atomic host-side — the cache advances
+        only at its retire, and everything that takes sequences away
+        retires first (``_settle``) — so a preemption resubmits
+        cleanly.
 
         The step record's ``slot_iters`` is the sum over the stepped
         slots of the loop iterations each was live in. The program
@@ -1640,52 +1967,48 @@ class Engine:
         with self._phase("pack"):
             G, B = self.dp_groups, self.batch_local
             T = self.cfg.resident_k * self.cfg.spec_k
-            L = self.cfg.max_seq_len
-            history = np.zeros((G, B, L), np.int32)
-            kv_len = np.zeros((G, B), np.int32)
             budget = np.zeros((G, B), np.int32)
             active = np.zeros((G, B), bool)
             seq_ids: list[list] = [[None] * B for _ in range(G)]
-            stepped: list[tuple[_Seq, tuple]] = []
+            stepped: list[tuple[_Seq, tuple, int]] = []
             cow: list = []
             for s in decodable:
-                length = self.cache.length(s.req.id)
-                remaining = s.req.max_new_tokens - len(s.generated)
+                length = s.kv_ahead
                 # The burst budget is clamped to the pages the slot
                 # could actually claim RIGHT NOW (its allocated pages
                 # + its group's free list): a tight pool degrades the
                 # burst toward one token — the all-slots-stall
                 # fallback — instead of stalling the slot outright.
                 cap = self.cache.token_capacity(s.req.id)
-                want = min(remaining, T, cap - length)
+                want = min(s.left, T, cap - length)
                 if want < 1:
                     continue  # zero headroom: wait for frees
                 at = self._claim(s, length + want, seq_ids, cow)
                 if at is None:
                     continue
-                hist = np.concatenate([
-                    np.array(s.req.prompt, np.int32),
-                    np.array(s.generated, np.int32)])
-                history[at][:hist.shape[0]] = hist
-                kv_len[at] = length
                 budget[at] = want
                 active[at] = True
-                stepped.append((s, at))
+                s.pending += want
+                stepped.append((s, at, want))
         if not stepped:
-            return 0
-        out, n_emitted, steps, now = self._launch(
-            self._decode_fn, self._page_rows(seq_ids, cow), history,
-            kv_len, budget, active)
-        with self._phase("emit"):
+            return None
+
+        def emit(now, out, n_emitted, steps):
             total = slot_iters = 0
-            for s, at in stepped:
+            for s, at, want in stepped:
+                if self.slots[s.slot] is not s:
+                    continue    # it ended in the launch before
+                s.pending -= want
                 e = int(n_emitted[at])
                 self._emit(s, out[at][:e].tolist(), now, "decode", e,
-                           emitted=e, budget=int(budget[at]))
+                           emitted=e, budget=want)
                 total += e
                 # A live iteration emits at least one token, and a
                 # slot is live in at most its group's iterations.
                 slot_iters += min(e, int(steps[at[0]]))
+                if e < want and self.slots[s.slot] is s:
+                    # Pages claimed for tokens that did not come.
+                    self.cache.trim(s.req.id, s.kv_ahead)
             g_steps = [int(steps[g]) for g in range(G)
                        if active[g].any()]
             mean_steps = sum(g_steps) / max(1, len(g_steps))
@@ -1693,9 +2016,14 @@ class Engine:
                 slots_stepped=len(stepped), slot_iters=slot_iters,
                 resident_k=self.cfg.resident_k,
                 resident_steps_per_launch=round(mean_steps, 4))
-        return total
+            return total
 
-    def _run_decode_token(self, decodable: list[_Seq]) -> int:
+        return self._launch(
+            self._decode_fn, "decode", emit,
+            self._page_rows(seq_ids, cow), budget, active)
+
+    def _run_decode_token(self, decodable: list[_Seq]
+                          ) -> _Launch | None:
         """One launch of the one-token decode program: every decodable
         slot feeds its last token and reads the next, sampled in the
         program (the argmax at ``temperature == 0``, else a draw from
@@ -1720,18 +2048,20 @@ class Engine:
                 active[at] = True
                 stepped.append((s, at))
         if not stepped:
-            return 0
-        rows = self._page_rows(seq_ids, cow)
-        nxt, now = self._launch(
-            self._decode_fn, tokens, positions, rows, active,
-            self._rng_grouped(self._step_counter))
-        with self._phase("emit"):
+            return None
+
+        def emit(now, nxt):
             for s, at in stepped:
                 self._emit(s, (int(nxt[at]),), now, "decode", 1,
                            emitted=1)
             self._step_counts.update(slots_stepped=len(stepped),
                                      slot_iters=len(stepped))
-        return len(stepped)
+            return len(stepped)
+
+        return self._launch(
+            self._decode_fn, "decode", emit, tokens, positions,
+            self._page_rows(seq_ids, cow), active,
+            self._rng_grouped(self._step_counter))
 
     def _maybe_finish(self, seq: _Seq) -> None:
         if not seq.done:
@@ -1845,6 +2175,7 @@ class Engine:
         from distributed_training_tpu.serving.disagg import (
             import_kv_batch)
 
+        self._settle()
         now = time.monotonic()
         staged = []
         try:
@@ -1913,6 +2244,7 @@ class Engine:
         — and a post-preemption resume still re-attaches with zero
         prefill. Page content is untouched by the frees (a page is
         never reused while held), so the retained KV stays valid."""
+        self._settle()
         lost: list[Request] = []
         now = time.monotonic()
         for i, s in enumerate(self.slots):
@@ -1999,6 +2331,10 @@ class Engine:
 
         from distributed_training_tpu.serving.disagg import (
             ProvenanceError)
+
+        # The launch in flight ran on the incumbent weights: its tokens
+        # are emitted, and tagged, before anything is installed.
+        self._settle()
 
         def _refuse(exc: Exception):
             self.swap_stats["refused"] += 1
@@ -2100,7 +2436,7 @@ class Engine:
         t0 = time.monotonic()
         n0 = len(self.completed)
         steps = 0
-        while self.in_flight and \
+        while (self.in_flight or self._flying is not None) and \
                 (deadline_s is None
                  or time.monotonic() - t0 < deadline_s):
             self.step()
@@ -2109,6 +2445,7 @@ class Engine:
                 raise RuntimeError(
                     "drain not converging after 200k steps "
                     f"(in_flight={self.in_flight})")
+        self._settle()
         persisted = self.export_in_flight() if self.in_flight \
             else {"adoptable": [], "requests": []}
         report = {
@@ -2145,6 +2482,7 @@ class Engine:
         from distributed_training_tpu.serving.disagg import (
             export_kv_batch)
 
+        self._settle()
         now = time.monotonic()
         seqs = [s for s in self.slots if s is not None]
         adoptable = [s for s in seqs
@@ -2171,6 +2509,7 @@ class Engine:
         successor engine: the per-request emitted-token high-water
         marks plus the live token listeners (callables — same-process
         transfer only, the serving supervisor's restart path)."""
+        self._settle()
         return {"hwm": dict(self._emit_hwm),
                 "listeners": dict(self._token_listeners)}
 
@@ -2415,9 +2754,55 @@ def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
     return nxt[None], counts[None], k_pages_g[None], v_pages_g[None]
 
 
-def _resident_program(params, k_pages, v_pages, page_rows, history,
-                      kv_len, budget, active, *, block, layouts, K, C,
-                      ngram, eos_id):
+def _prefill_slots_program(params, k_pages, v_pages, history, kv_len,
+                           left, page_rows, tokens, start_pos, n_valid,
+                           active, rng_data, slot, max_new, *, block,
+                           layouts, temperature, top_k, eos_id):
+    """Batched prefill (``_chunk_program``, ``emit="last"``) that also
+    writes the carried slot table, one dp group: lane s's chunk goes
+    into row ``slot[s]`` of ``history`` at its positions, and where the
+    chunk ends its prompt (``max_new[s] > 0``: the tokens the request
+    asked for; 0 on every other lane) the sampled token goes after it,
+    ``kv_len[slot]`` becomes the prompt's length and ``left[slot]``
+    what the request may still emit: ``max_new - 1``, or 0 where the
+    sample is the stop token. That is the decode invariant the
+    resident burst starts from (``history[kv_len]`` the newest token,
+    its KV unwritten), so the burst dispatched next needs nothing from
+    the host. Dead lanes and padding write nothing (out-of-range rows
+    are dropped); two lanes never share a slot.
+
+    history (1, B, Lmax); kv_len, left (1, B); slot, max_new (1, S);
+    the rest as ``_chunk_program``. Returns ``(next_tokens (1, S),
+    counts (1, n), history, kv_len, left, k_pages, v_pages)``."""
+    import jax.numpy as jnp
+
+    nxt, counts, k_pages, v_pages = _chunk_program(
+        params, k_pages, v_pages, page_rows, tokens, start_pos, n_valid,
+        active, rng_data, block=block, layouts=layouts,
+        temperature=temperature, top_k=top_k, emit="last")
+    hist, kvl, lft = history[0], kv_len[0], left[0]
+    B = hist.shape[0]
+    C = tokens.shape[-1]
+    idx = jnp.arange(C, dtype=jnp.int32)
+    valid = (idx[None, :] < n_valid[0][:, None]) & active[0][:, None]
+    hist = hist.at[jnp.where(valid, slot[0][:, None], B),
+                   start_pos[0][:, None] + idx[None, :]].set(
+                       tokens[0], mode="drop")
+    ends = active[0] & (max_new[0] > 0)
+    row = jnp.where(ends, slot[0], B)
+    end = start_pos[0] + n_valid[0]
+    hist = hist.at[row, end].set(nxt[0], mode="drop")
+    kvl = kvl.at[row].set(end, mode="drop")
+    stop = nxt[0] == eos_id if eos_id >= 0 else False
+    lft = lft.at[row].set(jnp.where(stop, 0, max_new[0] - 1),
+                          mode="drop")
+    return (nxt, counts, hist[None], kvl[None], lft[None], k_pages,
+            v_pages)
+
+
+def _resident_program(params, k_pages, v_pages, history, kv_len, left,
+                      page_rows, budget, active, *, block, layouts, K,
+                      C, ngram, eos_id):
     """Device-resident K-step decode for one dp group's slot table.
 
     A ``lax.while_loop`` of up to ``K`` iterations; each iteration
@@ -2433,27 +2818,34 @@ def _resident_program(params, k_pages, v_pages, page_rows, history,
     stopped (EOS or budget), so an all-slots-complete burst costs
     the iterations it used, not ``K``.
 
-    k_pages/v_pages (1, L, N, ps, lanes); page_rows (1, B, P);
-    history (1, B, Lmax) int32 — prompt + generated so far, with
-    ``history[kv_len]`` the last generated token (its KV not yet
-    written, exactly the host decode invariant); kv_len (1, B) —
-    each slot's committed KV length; budget (1, B) — max tokens this
-    burst may emit per slot (the host sized it against page
-    capacity: positions written never exceed ``kv_len + budget - 1``
-    because ``kv_len + remaining_budget`` is loop-invariant);
-    active (1, B) bool.
+    k_pages/v_pages (1, L, N, ps, lanes); page_rows (1, B, P).
+    The slot table, carried from launch to launch on the device
+    (donated in, returned): history (1, B, Lmax) int32 — prompt +
+    generated so far, with ``history[kv_len]`` the last generated
+    token (its KV not yet written, exactly the host decode
+    invariant); kv_len (1, B) — each slot's committed KV length;
+    left (1, B) — tokens the slot's request may still emit (0 once it
+    met the stop token). The table is the truth: the host packs this
+    burst before it has read the one before, from a projection.
+    budget (1, B) — max tokens this burst may emit per slot, clamped
+    here by ``left`` (the host sized it against page capacity at a
+    length no shorter than ``kv_len``: positions written never exceed
+    ``kv_len + budget - 1`` because ``kv_len + remaining_budget`` is
+    loop-invariant); active (1, B) bool. A slot the host did not pack
+    passes through unchanged.
 
     Returns ``(out (1, B, K*C) emitted tokens, n_emitted (1, B),
     steps (1,) loop iterations used, counts (1, n) the block's
-    counters over all iterations, k_pages, v_pages)``.
+    counters over all iterations, history, kv_len, left, k_pages,
+    v_pages)``.
     """
     import jax
     import jax.numpy as jnp
 
     kp, vp = k_pages[0], v_pages[0]
     page_rows_g = page_rows[0]
-    history_g, kv_len_g = history[0], kv_len[0]
-    budget_g, active_g = budget[0], active[0]
+    history_g, kv_len_g, left_g = history[0], kv_len[0], left[0]
+    budget_g = jnp.where(active[0], jnp.minimum(budget[0], left_g), 0)
     B, Lmax = history_g.shape
     T = K * C
     pos = jnp.arange(Lmax, dtype=jnp.int32)
@@ -2490,11 +2882,12 @@ def _resident_program(params, k_pages, v_pages, page_rows, history,
         return draft
 
     def cond(carry):
-        j, running = carry[0], carry[6]
+        j, running = carry[0], carry[7]
         return (j < K) & running.any()
 
     def body(carry):
-        j, out, n_em, kvl, bud, hist, running, counts, kp, vp = carry
+        (j, out, n_em, kvl, bud, lft, hist, running, counts, kp,
+         vp) = carry
         n = jnp.where(running, jnp.minimum(C, bud), 0).astype(
             jnp.int32)
         last = jnp.take_along_axis(hist, kvl[:, None], axis=1)[:, 0]
@@ -2545,17 +2938,17 @@ def _resident_program(params, k_pages, v_pages, page_rows, history,
         n_em = n_em + e
         kvl = kvl + e
         bud = bud - e
+        lft = jnp.where(any_eos, 0, lft - e)
         running = running & (bud > 0) & ~any_eos
-        return (j + 1, out, n_em, kvl, bud, hist, running, counts + c,
-                kp, vp)
+        return (j + 1, out, n_em, kvl, bud, lft, hist, running,
+                counts + c, kp, vp)
 
     init = (jnp.zeros((), jnp.int32),
             jnp.zeros((B, T), jnp.int32),
             jnp.zeros((B,), jnp.int32),
-            kv_len_g, budget_g, history_g,
-            active_g & (budget_g > 0),
+            kv_len_g, budget_g, left_g, history_g, budget_g > 0,
             jnp.zeros((len(block.counters),), jnp.int32), kp, vp)
-    j, out, n_em, _kvl, _bud, _hist, _run, counts, kp, vp = \
+    j, out, n_em, kvl, _bud, lft, hist, _run, counts, kp, vp = \
         jax.lax.while_loop(cond, body, init)
     return (out[None], n_em[None], jnp.reshape(j, (1,)), counts[None],
-            kp[None], vp[None])
+            hist[None], kvl[None], lft[None], kp[None], vp[None])

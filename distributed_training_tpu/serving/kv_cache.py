@@ -619,6 +619,29 @@ class PagedKVCache:
                 "— ensure() first")
         self._lengths[seq_id] = new_len
 
+    def trim(self, seq_id, n_tokens: int) -> int:
+        """Give back the pages at the end of ``seq_id``'s table that
+        ``n_tokens`` positions do not need: pages claimed ahead for
+        tokens that did not come (the engine claims a burst's pages
+        from a projection). Only pages this sequence alone holds go,
+        and none at or below its committed length. Returns the pages
+        released."""
+        table = self._tables[seq_id]
+        group = self._groups[seq_id]
+        refs = self._refs[group]
+        keep = -(-max(n_tokens, self._lengths[seq_id])
+                 // self.cfg.page_size)
+        released = []
+        while len(table) > keep and refs[table[-1]] == 1:
+            page = table.pop()
+            del refs[page]
+            self._invalidate(group, page)
+            released.append(page)
+        self._frees[group].extend(released)
+        if released:
+            self._emit("trim", seq_id)
+        return len(released)
+
     def free(self, seq_id) -> int:
         """Evict: drop one reference on each of the sequence's pages;
         pages whose LAST reference this was go back to the group's

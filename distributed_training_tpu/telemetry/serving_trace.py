@@ -194,7 +194,13 @@ def _mailbox_waits(traces) -> list[float]:
 def step_phases(events) -> dict | None:
     """Mean split of a launching step (``serving`` records whose
     ``op`` is not ``idle``) into its ``phase_s`` parts, in seconds;
-    ``other`` is what of ``dur_s`` no part covers. None when the
+    ``other`` is what of ``dur_s`` no part covers. A record's
+    ``dur_s`` runs from the retire before its launch's to its own, so
+    the parts are those of that stretch: the fetch and emit of its
+    launch and the admit, pack and launch of the one dispatched
+    meanwhile. ``ran_ahead_share`` is the share of the launches that
+    were dispatched while the launch before was un-retired (their
+    ``ran_ahead``; absent where no record carries it). None when the
     stream holds no such record."""
     steps = [e for e in events if isinstance(e, dict)
              and e.get("kind") == "serving" and e.get("op") != "idle"
@@ -206,7 +212,11 @@ def step_phases(events) -> dict | None:
             for k in steps[0]["phase_s"]}
     dur = sum(e.get("dur_s") or 0.0 for e in steps) / n
     mean["other"] = max(0.0, dur - sum(mean.values()))
-    return {"steps": n, "mean_dur_s": dur, "mean_phase_s": mean}
+    out = {"steps": n, "mean_dur_s": dur, "mean_phase_s": mean}
+    ran = [e["ran_ahead"] for e in steps if "ran_ahead" in e]
+    if ran:
+        out["ran_ahead_share"] = sum(ran) / len(ran)
+    return out
 
 
 def _tenant_report(traces, ttft_deadline_s, per_token_deadline_s
@@ -343,6 +353,11 @@ def render_serving_lines(rep: dict | None) -> list[str]:
             f"{ph['mean_dur_s'] * 1e3:.2f}ms): " + ", ".join(
                 f"{k} {v * 1e3:.2f}ms"
                 for k, v in ph["mean_phase_s"].items()))
+        if "ran_ahead_share" in ph:
+            lines.append(
+                f"  run-ahead: {ph['ran_ahead_share']:.1%} of the "
+                "launches dispatched before the launch before them "
+                "was fetched")
     return lines
 
 

@@ -376,12 +376,28 @@ def test_resident_decode_holds_no_copy_of_the_pool():
         lambda a: shape(a.shape, jnp.bfloat16),
         jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     B, P = 16, 64
+    carried = [shape((1, B, 1024), jnp.int32), shape((1, B), jnp.int32),
+               shape((1, B), jnp.int32)]
     compiled = E.build_resident_decode_fn(block, ecfg).lower(
-        params, *pools, shape((1, B, P), jnp.int32),
-        shape((1, B, 1024), jnp.int32), shape((1, B), jnp.int32),
+        params, *pools, *carried, shape((1, B, P), jnp.int32),
         shape((1, B), jnp.int32), shape((1, B), jnp.bool_)).compile()
+    # The carried slot table (history, lengths, tokens left) is donated
+    # like the pools: each parameter's buffer holds an output (which
+    # of the (1, B) ones is the compiler's pick among equal shapes),
+    # the history's its own, and the history is nowhere copied whole.
+    text = compiled.as_text()
+    first = len(jax.tree.leaves(params))
+    aliases = dict(
+        (int(p), int(o)) for o, p in re.findall(
+            r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+            re.search(r"input_output_alias=\{(.*?)\}, entry",
+                      text).group(1)))
+    assert set(aliases) == set(range(first, first + 5)), aliases
+    assert (aliases[first], aliases[first + 1], aliases[first + 2]) == (
+        7, 8, 4), aliases
+    assert not re.search(r"= s32\[1,16,1024\]\S* copy\(", text)
     pool_elems = math.prod(pools[0].shape)
-    whole = [line.strip()[:120] for line in compiled.as_text().splitlines()
+    whole = [line.strip()[:120] for line in text.splitlines()
              if (m := re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([0-9,]+)\]"
                                r"\S* copy\(", line))
              and math.prod(map(int, m.group(1).split(","))) == pool_elems]

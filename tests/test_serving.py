@@ -462,9 +462,17 @@ def test_batch_composition_independence(tiny_model):
     assert solo.generate(prompts[5], 8) == batched["r5"]
 
 
-def test_no_recompiles_across_join_evict_storm(tiny_model):
+@pytest.mark.parametrize("cadence", ["plain", "resident8",
+                                     "sampled_top1", "spec4"])
+def test_no_recompiles_across_join_evict_storm(tiny_model, cadence):
+    """``Engine.warmup`` compiles every program at the signature the
+    storm calls it with, in every cadence: the carried slot table
+    included, through the prefill program that writes it, the upload
+    for sequences no prefill launch wrote (a whole-prompt prefix hit,
+    a prompt that extends one) and a drain."""
     model, params = tiny_model
-    eng = _engine(model, params, max_batch=3, num_pages=96)
+    eng = _engine(model, params, max_batch=3, num_pages=96,
+                  **_CADENCES[cadence])
     counts = eng.warmup()
     rng = np.random.default_rng(5)
     for i in range(7):
@@ -478,6 +486,25 @@ def test_no_recompiles_across_join_evict_storm(tiny_model):
     assert len(eng.completed) == 7
     assert eng.compile_counts() == counts, \
         "join/evict changed a traced shape"
+    # A session turn, the same prompt again (every page of it is
+    # resident: no prefill launch) and a prompt that extends it.
+    turn = rng.integers(0, 256, size=16).astype(np.int32)
+    eng.submit(Request(id="t0", prompt=turn, max_new_tokens=6,
+                       session="s"))
+    eng.run_until_drained()
+    launches = eng.prefill_launches
+    eng.submit(Request(id="t1", prompt=turn, max_new_tokens=6))
+    eng.submit(Request(id="t2", prompt=np.concatenate(
+        [turn, turn[:5]]), max_new_tokens=6))
+    for _ in range(3):
+        eng.step()
+    eng.drain()
+    done = {r["id"]: r["tokens"] for r in eng.completed}
+    assert done["t1"] == done["t0"]
+    assert eng.prefill_launches == launches + 1     # t2's tail alone
+    assert eng.prefix_stats["hit_tokens"] == 32
+    assert eng.compile_counts() == counts, \
+        "a prefix hit or a drain changed a traced shape"
 
 
 def test_preempt_resume_is_token_transparent(tiny_model):
@@ -1930,7 +1957,7 @@ def test_resident_metrics_gauges(tiny_model, tmp_path):
 @pytest.mark.parametrize("over, programs", [
     (dict(resident_k=4, spec_k=2),
      ["serving_resident_decode", "serving_prefill_batch",
-      "serving_cow"]),
+      "serving_seed", "serving_cow"]),
     (dict(spec_k=2),
      ["serving_spec_decode", "serving_prefill_batch", "serving_cow"]),
     (dict(prefix_sharing=False),
@@ -1978,7 +2005,7 @@ def test_engine_reports_paged_form_per_program(tiny_model, tmp_path,
             "serving_decode": rule(4, 1),
             "serving_prefill_batch": rule(eng.prefill_local,
                                           eng.cfg.prefill_chunk),
-            "serving_cow": None}
+            "serving_seed": None, "serving_cow": None}
     assert forms == {p: want[p] for p in programs}
     warm = [r for r in records if r["kind"] == "serving_warmup"]
     assert len(warm) == 1
@@ -2597,13 +2624,20 @@ def test_step_records_carry_phases_and_counts(tiny_model, cadence):
     while not eng.idle:
         recs.append(eng.step())
     assert all(r["op"] != "idle" for r in recs)
-    for r in recs:
+    for r, after in zip(recs, recs[1:] + [None]):
         ph = r["phase_s"]
         assert tuple(ph) == ("admit", "pack", "launch", "fetch",
                              "emit")
         assert all(v >= 0.0 for v in ph.values())
         assert sum(ph.values()) <= r["dur_s"] + 1e-5
-        assert ph["launch"] > 0.0
+        # A record's stretch runs from the retire before to its own:
+        # it holds the dispatch of its own launch where nothing was in
+        # flight then, and of the launch after where that ran ahead.
+        dispatched = r["ran_ahead"] == 0 or (
+            after is not None and after["ran_ahead"] == 1)
+        assert (ph["launch"] > 0.0) == dispatched
+    assert {r["ran_ahead"] for r in recs} == (
+        {0, 1} if cadence == "resident8" else {0})
     decode = [r for r in recs if r["op"] == "decode"]
     prefill = [r for r in recs if r["op"] == "prefill"]
     assert decode and prefill
@@ -2637,7 +2671,8 @@ def test_engine_programs_have_distinct_stable_names(tiny_model,
     eng = _engine(model, params, **_CADENCES[cadence])
     names = [re.search(r"module @(\S+)",
                        fn.lower(*args).as_text()).group(1)
-             for fn, args in eng._warmup_calls()]
+             for fn, args in ((f, (*eng._state_of(f), *a))
+                              for f, a in eng._warmup_calls())]
     assert len(names) == len(eng.compile_counts()) >= 3
     assert len(set(names)) == len(names), names
     assert all(n.startswith("jit_serving_") for n in names), names
@@ -2685,6 +2720,235 @@ def test_stop_token_ends_a_request_in_every_cadence(tiny_model,
         assert run(eos) == want, eos
         assert any(len(t) < 12 and t[-1] == eos for t in want)
     assert run(free[1][0])[1] == [free[1][0]]
+
+
+def _run_ahead_prompts():
+    rng = np.random.default_rng(31)
+    return [rng.integers(0, 256, size=n).astype(np.int32)
+            for n in (3, 8, 13, 21, 6)]
+
+
+def _submit(eng, prompts, new, streams=None, ids=None):
+    for i, p in enumerate(prompts):
+        rid = ids[i] if ids else f"q{i}"
+        eng.submit(Request(id=rid, prompt=p, max_new_tokens=new[i]))
+        if streams is not None:
+            eng.add_token_listener(
+                rid, lambda tok, done, out=streams.setdefault(rid, []):
+                out.append(tok))
+
+
+def _step_until_flying(eng, least=3):
+    """A few steps in, stopped where a launch is dispatched and not
+    yet retired (the state every outside caller must settle)."""
+    for _ in range(least):
+        eng.step()
+    while eng._flying is None:
+        eng.step()
+    assert not eng.idle and eng.in_flight
+
+
+def _same_params(params):
+    return jax.tree.map(lambda x: jnp.array(np.asarray(x)), params)
+
+
+# What happens while a launch is in flight, by case: the engine's
+# overrides, the tokens asked a request, and what the test does to the
+# run-ahead engine mid-storm (None: nothing, it just drains).
+_RUN_AHEAD_CASES = {
+    "stop_token_mid_burst": (dict(resident_k=4), [12] * 5, None),
+    "spec_k_4": (dict(resident_k=4, spec_k=4), [12] * 5, None),
+    "budgets_end_mid_burst": (dict(resident_k=4), [1, 6, 7, 9, 11],
+                              None),
+    # Two slots, 15 usable pages of 4: the longest pair wants 10 + 8.
+    "pool_forces_short_budgets": (
+        dict(resident_k=8, max_batch=2, page_size=4, num_pages=16,
+             max_seq_len=40), [16] * 5, "watch_pool"),
+    "chunked_prompt_joins_mid_flight": (
+        dict(resident_k=4, prefill_chunk=4), [9] * 5, "late_prompt"),
+    "preempt": (dict(resident_k=4), [12] * 5, "preempt"),
+    "drain": (dict(resident_k=4), [12] * 5, "drain"),
+    "swap_weights": (dict(resident_k=4), [12] * 5, "swap"),
+    "export_in_flight": (dict(resident_k=4), [12] * 5, "export"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RUN_AHEAD_CASES))
+def test_run_ahead_is_the_one_token_cadence_token_for_token(
+        tiny_model, case):
+    """The engine that dispatches launch n+1 before it fetches launch
+    n (the resident cadence: slot state on the device, the host packing
+    from a projection) emits what the engine that retires every launch
+    at once emits, token for token and each token once: with slots that
+    meet the stop token mid-burst, at ``spec_k`` 4, with requests that
+    end mid-burst, on a pool too small for the budgets, with a prompt
+    of several chunks admitted while a burst is in flight, and with
+    ``preempt``, ``drain``, ``swap_weights`` and ``export_in_flight``
+    called while a launch is in flight."""
+    model, params = tiny_model
+    over, new, act = _RUN_AHEAD_CASES[case]
+    prompts = _run_ahead_prompts()
+    late = np.asarray(([7, 3, 9, 1] * 4)[:14], np.int32)
+    plain = {k: v for k, v in over.items()
+             if k not in ("resident_k", "spec_k")}
+    eos = -1
+    if case == "stop_token_mid_burst":
+        free = _engine(model, params, num_pages=96, **plain)
+        _submit(free, prompts, new)
+        free.run_until_drained()
+        # Position 5 with K=4 is the second token of a second burst.
+        eos = next(r for r in free.completed
+                   if r["id"] == "q2")["tokens"][5]
+
+    def drained(eng, streams):
+        eng.run_until_drained(max_steps=2000)
+        assert eng.cache.pages_used == 0 and eng._flying is None
+        done = {r["id"]: r["tokens"] for r in eng.completed}
+        for rid, toks in streams.items():
+            assert toks == done[rid], f"{rid} streamed twice or not"
+        return done
+
+    ref = _engine(model, params, eos_id=eos,
+                  **{"num_pages": 96, **plain})
+    ref_streams: dict = {}
+    _submit(ref, prompts, new, ref_streams)
+    if act == "late_prompt":
+        ref.submit(Request(id="late", prompt=late, max_new_tokens=9))
+    want = drained(ref, ref_streams)
+    if eos >= 0:
+        assert any(t[-1] == eos and len(t) < 12 for t in want.values())
+
+    eng = _engine(model, params, eos_id=eos,
+                  **{"num_pages": 96, **over})
+    streams: dict = {}
+    _submit(eng, prompts, new, streams)
+    if act is None:
+        assert drained(eng, streams) == want
+        return
+    if act == "watch_pool":
+        short = 0
+        while not eng.idle:
+            rec = eng.step()
+            short += rec["op"] == "decode" and any(
+                sp["ev"] == "decode" and sp["budget"] < min(
+                    8, 16 - len(s.generated) + sp["emitted"])
+                for s in eng.slots if s is not None
+                for sp in s.trace[-1:])
+        assert short, "the pool never cut a budget"
+        assert drained(eng, streams) == want
+        return
+    _step_until_flying(eng)
+    if act == "late_prompt":
+        eng.submit(Request(id="late", prompt=late, max_new_tokens=9))
+        chunks = 0
+        while not eng.idle:
+            rec = eng.step()
+            chunks += rec["op"] == "prefill" and rec["ran_ahead"]
+        assert chunks >= 4      # its chunks were dispatched ahead
+        assert drained(eng, streams) == want
+    elif act == "preempt":
+        lost = eng.preempt()
+        assert eng.cache.pages_used == 0 and eng._flying is None
+        assert not eng._token_listeners
+        for r in lost:
+            eng.submit(r)
+        assert drained(eng, {}) == want
+    elif act == "drain":
+        held = eng.in_flight
+        report = eng.drain()
+        assert eng._flying is None and eng.in_flight == 0
+        assert len(report["finished"]) >= held
+        eng.draining = False
+        assert drained(eng, streams) == want
+    elif act == "swap":
+        before = sum(len(s.generated) for s in eng.slots if s)
+        eng.swap_weights(_same_params(params), "v1")
+        # What the launch in flight emitted was emitted, and tagged,
+        # before the install.
+        assert eng._flying is None
+        settled = [s for s in eng.slots if s is not None]
+        assert sum(len(s.generated) for s in settled) > before
+        assert all(v == "v0" for s in settled for v, _n in s.versions)
+        assert drained(eng, streams) == want
+        assert {v for r in eng.completed
+                for v, _n in r["weights_versions"]} == {"v0", "v1"}
+    elif act == "export":
+        state = eng.export_emission_state()
+        export = eng.export_in_flight()
+        assert eng._flying is None and eng.cache.pages_used == 0
+        assert export["adoptable"]
+        queued = list(eng.queue)
+        heir = _engine(model, params, num_pages=96, **over)
+        heir.import_emission_state(state)
+        heir.adopt_batch(export["adoptable"])
+        for r in export["requests"] + queued:
+            heir.submit(r)
+        done = drained(heir, {})
+        done.update({r["id"]: r["tokens"] for r in eng.completed})
+        assert done == want
+        for rid, toks in streams.items():
+            assert toks == want[rid], f"{rid} streamed twice or not"
+
+
+def test_run_ahead_dispatches_before_it_fetches(tiny_model):
+    """The order itself, on the host: launch n+1 is dispatched before
+    launch n is fetched (so the device never waits for the host's
+    emit), every launch but the first of a filled pipeline says so in
+    ``ran_ahead``, ``Engine.idle`` is false while a launch is in
+    flight, and pages claimed for tokens that did not come (``spec_k``
+    4 accepts less than its budget on random weights) are back in the
+    free list after the retire."""
+    model, params = tiny_model
+    eng = _engine(model, params, resident_k=4, spec_k=4, page_size=4,
+                  num_pages=96)
+    eng.warmup()
+    order: list = []
+    fetch = eng._fetch_host
+
+    def fetching(*arrays):
+        order.append("fetch")
+        return fetch(*arrays)
+
+    def dispatching(fn):
+        def call(*args):
+            order.append("dispatch")
+            return fn(*args)
+        call.__wrapped__ = fn.__wrapped__
+        return call
+
+    eng._fetch_host = fetching
+    eng._decode_fn = dispatching(eng._decode_fn)
+    eng._prefill_batch_fn = dispatching(eng._prefill_batch_fn)
+    _submit(eng, _run_ahead_prompts()[:4], [24] * 4)
+    recs, trimmed = [], 0
+    ps = eng.cfg.page_size
+    while not eng.idle:
+        pages = {s.req.id: eng.cache.pages_of(s.req.id)
+                 for s in eng.slots if s is not None}
+        recs.append(eng.step())
+        assert recs[-1]["op"] != "idle"
+        if eng._flying is not None:
+            assert not eng.idle
+        for s in eng.slots:
+            if s is not None and s.prefill_done:
+                # No page beyond what its launch in flight may write.
+                held = eng.cache.pages_of(s.req.id)
+                assert held == -(-s.kv_ahead // ps)
+                trimmed += held < pages.get(s.req.id, 0)
+    assert trimmed, "no burst gave pages back"
+    assert eng.cache.pages_used == 0
+    assert eng.cache.free_pages_in(0) == eng.cache.cfg.usable_pages
+    # Two dispatches fill the pipeline; from then on every fetch has
+    # the next launch queued behind the one it waits for.
+    assert order[:3] == ["dispatch", "dispatch", "fetch"]
+    ahead = 0
+    for what in order:
+        ahead += 1 if what == "dispatch" else -1
+        assert 0 <= ahead <= 2
+    assert [r["ran_ahead"] for r in recs[:3]] == [0, 1, 1]
+    assert sum(r["ran_ahead"] for r in recs) >= len(recs) - 2
+    assert sum(r["host_syncs"] for r in recs) == eng.host_syncs \
+        == order.count("fetch")
 
 
 def test_server_request_trace_splits_mailbox_from_queue(tiny_model,

@@ -712,6 +712,39 @@ def test_serving_report_cli_pinned(tmp_path, capsys):
                  "--serving-report"]) == 1
 
 
+def test_serving_report_prints_the_run_ahead_share(tmp_path, capsys):
+    """The step records' ``ran_ahead`` reaches ``step_phases`` and the
+    report: the share of the launches that were dispatched while the
+    launch before was un-retired, over the records that launched; a
+    stream whose records carry none (a cadence that never runs ahead
+    writes 0, an older stream nothing) leaves the line out."""
+    from distributed_training_tpu.telemetry.serving_trace import (
+        step_phases)
+    from distributed_training_tpu.telemetry.summarize import main
+
+    def step(op, **more):
+        return {"kind": "serving", "op": op, "dur_s": 0.05,
+                "phase_s": {"admit": 0.001, "pack": 0.002,
+                            "launch": 0.003, "fetch": 0.03,
+                            "emit": 0.01}, **more}
+
+    steps = [step("prefill", ran_ahead=0), step("decode", ran_ahead=1),
+             step("decode", ran_ahead=1), step("prefill", ran_ahead=1),
+             step("idle")]
+    ph = step_phases(steps)
+    assert ph["steps"] == 4 and ph["ran_ahead_share"] == 0.75
+    assert ph["mean_phase_s"]["other"] == pytest.approx(0.004)
+    assert "ran_ahead_share" not in step_phases([step("decode")])
+    run_dir = _synthetic_serving_run(tmp_path)
+    with open(run_dir / "events.jsonl", "a") as f:
+        for rec in steps:
+            f.write(json.dumps(rec) + "\n")
+    assert main([str(run_dir), "--serving-report"]) == 0
+    out = capsys.readouterr().out
+    assert "step split (4 launching steps" in out
+    assert "run-ahead: 75.0% of the launches" in out
+
+
 def test_summarizer_includes_serving_section(tmp_path):
     """The plain summarizer report grows a serving section when the
     run dir holds serving_trace records — same analyzer as the
