@@ -211,11 +211,13 @@ def main(argv: list[str] | None = None) -> int:
         if total > max_len:
             paged = False
         else:
+            # One request: nothing to share a prefix with.
             eng = Engine(model, params, EngineConfig(
                 max_batch=1, page_size=page,
                 num_pages=-(-max_len // page) + 1,
                 max_seq_len=max_len,
-                prefill_chunk=min(64, max_len)))
+                prefill_chunk=min(64, max_len),
+                prefix_sharing=False))
             out_ids = np.asarray(
                 eng.generate(ids, args.max_new_tokens), np.int32)
     if not paged:

@@ -55,15 +55,9 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from distributed_training_tpu.models.base import normal_init
-
-# The int32 sums ``expert_layer`` returns: (token, expert) picks made,
-# picks that landed on an expert held here, the largest count on one
-# held expert, expert-layer calls that saw a token, and held experts
-# times those calls (what a mean load an expert is taken over).
-COUNTERS = ("moe_picks", "moe_picks_held", "moe_load_max",
-            "moe_layer_calls", "moe_expert_calls")
-
+from distributed_training_tpu.models.base import ApplyLM, normal_init
+from distributed_training_tpu.models.experts import (  # noqa: F401
+    COUNTERS, _cast, expert_layer, gated_mlp, rms_norm, route)
 
 @dataclass
 class LatentMoEConfig:
@@ -90,6 +84,10 @@ class LatentMoEConfig:
     max_seq_len: int = 4096
     dtype: str = "bfloat16"       # compute dtype
     param_dtype: str = "float32"
+
+    # How ``models/experts.py::expert_layer`` scores and activates.
+    router_score = "sigmoid"
+    expert_act = "silu"
 
     def __post_init__(self):
         if not 0 < self.n_dense_layers <= self.n_layers:
@@ -119,18 +117,6 @@ class LatentMoEConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
-
-
-def _cast(leaf, dt):
-    return leaf.astype(dt)
-
-
-def rms_norm(x, scale, eps):
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True)
-                          + eps)
-    return (y * scale).astype(dtype)
 
 
 def rope_interleaved(x, positions, theta):
@@ -191,61 +177,10 @@ def expanded_attention(q_nope, q_rope, c_kv, k_rope, a,
                                  causal=True, impl="naive")
 
 
-def gated_mlp(h, m, w=_cast):
-    dt = h.dtype
-    u = (jax.nn.silu(jnp.einsum("...d,df->...f", h, w(m["wg"], dt)))
-         * jnp.einsum("...d,df->...f", h, w(m["wu"], dt)))
-    return jnp.einsum("...f,fd->...d", u, w(m["wd"], dt))
-
-
-def route(h, m, c: LatentMoEConfig):
-    """``h (T, D)`` -> the ``top_k`` experts of each token, of all
-    ``n_routed_experts`` (``idx (T, k)``), and their gate weights
-    ``(T, k)`` float32. The product with the router and the scores are
-    float32, as published gates are."""
-    with jax.default_matmul_precision("highest"):
-        s = jax.nn.sigmoid(jnp.einsum(
-            "td,de->te", h.astype(jnp.float32),
-            m["router"].astype(jnp.float32)))
-    _, idx = jax.lax.top_k(s + m["router_bias"].astype(jnp.float32),
-                           c.moe_top_k)
-    g = jnp.take_along_axis(s, idx, axis=-1)
-    g = c.routed_scaling_factor * g / jnp.sum(g, -1, keepdims=True)
-    return idx, g
-
-
-def expert_layer(h, m, c: LatentMoEConfig, valid=None, w=_cast):
-    """The expert feed-forward on ``h (..., D)``: this rank's experts'
-    part of the routed sum plus the shared expert, and ``COUNTERS``
-    over the rows ``valid (...)`` marks (all, if None)."""
-    dt = h.dtype
-    lead = h.shape[:-1]
-    x = h.reshape(-1, h.shape[-1])
-    ok = (jnp.ones(x.shape[:1], bool) if valid is None
-          else valid.reshape(-1))
-    idx, g = route(x, m, c)
-    local = idx - c.expert_offset
-    # one_hot of an index outside [0, held) is the zero row: an expert
-    # that lies on another rank takes no weight here.
-    onehot = jax.nn.one_hot(local, c.experts_held, dtype=jnp.float32)
-    combine = jnp.einsum("tk,tke->te", g, onehot)
-    act = (jax.nn.silu(jnp.einsum("td,edf->tef", x, w(m["wg"], dt)))
-           * jnp.einsum("td,edf->tef", x, w(m["wu"], dt)))
-    y = jnp.einsum("tef,efd->td", act * combine.astype(dt)[..., None],
-                   w(m["wd"], dt))
-    y = y + gated_mlp(x, m["shared"], w)
-    load = jnp.sum(onehot * ok[:, None, None], axis=(0, 1))
-    counts = jnp.stack([
-        jnp.sum(ok) * c.moe_top_k, jnp.sum(load), jnp.max(load),
-        jnp.any(ok), jnp.any(ok) * c.experts_held]).astype(jnp.int32)
-    return y.reshape(lead + (h.shape[-1],)), counts
-
-
-class LatentMoE:
+class LatentMoE(ApplyLM):
     """Functional model: ``init``, ``apply`` (the full forward),
-    ``loss``, ``generate``, and ``serving_block`` for the engine."""
-
-    batch_keys: tuple[str, ...] = ("tokens",)
+    ``loss`` and ``generate`` by it (``ApplyLM``), and
+    ``serving_block`` for the engine."""
 
     def __init__(self, cfg: LatentMoEConfig):
         self.cfg = cfg
@@ -325,7 +260,11 @@ class LatentMoE:
     def feed_forward(self, layer, h, valid=None, w=_cast):
         """``FFN(h)`` of a layer of either kind, and its counts."""
         if "router" in layer["mlp"]:
-            return expert_layer(h, layer["mlp"], self.cfg, valid, w)
+            # ``route`` and ``gated_mlp`` by this module's names: the
+            # benchmark's controls patch them here
+            # (perfbench/tests/check_serving_sensitivity.py).
+            return expert_layer(h, layer["mlp"], self.cfg, valid, w,
+                                route=route, shared=gated_mlp)
         return (gated_mlp(h, layer["mlp"], w),
                 jnp.zeros((len(COUNTERS),), jnp.int32))
 
@@ -356,42 +295,6 @@ class LatentMoE:
                           params["lm_head"].astype(dt)
                           ).astype(jnp.float32)
 
-    def loss(self, params, batch, rng: jax.Array, train: bool = True):
-        """Mean next-token cross-entropy of ``batch["tokens"]``
-        (B, S + 1)."""
-        tokens = batch["tokens"]
-        logp = jax.nn.log_softmax(self.apply(params, tokens[:, :-1]))
-        nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], -1)
-        loss = jnp.mean(nll)
-        return loss, {"loss": loss}
-
-    def generate(self, params, prompt, max_new_tokens: int,
-                 temperature: float = 0.0, top_k: int = 0,
-                 rng: jax.Array | None = None):
-        """``prompt`` (1, S) -> the new tokens (1, max_new_tokens), by
-        the full forward over one padded row a token: the plain path
-        (``generate.py`` serves greedy requests through the engine)."""
-        total = prompt.shape[1] + max_new_tokens
-        if total > self.cfg.max_seq_len:
-            raise ValueError(f"{total} positions exceed max_seq_len "
-                             f"{self.cfg.max_seq_len}")
-        row = jnp.zeros((1, total), jnp.int32).at[:, :prompt.shape[1]
-                                                  ].set(prompt)
-        forward = jax.jit(self.apply)
-        for n in range(prompt.shape[1], total):
-            lg = forward(params, row)[0, n - 1]
-            if temperature <= 0:
-                tok = jnp.argmax(lg)
-            else:
-                lg = lg / temperature
-                if top_k:
-                    lg = jnp.where(lg < jax.lax.top_k(lg, top_k)[0][-1],
-                                   -jnp.inf, lg)
-                rng, key = jax.random.split(rng)
-                tok = jax.random.categorical(key, lg)
-            row = row.at[0, n].set(tok.astype(jnp.int32))
-        return row[:, prompt.shape[1]:]
-
     def serving_block(self):
         return LatentBlock(self)
 
@@ -421,6 +324,10 @@ class LatentBlock:
 
     def segments(self, params):
         return self.model.runs(params)
+
+    def at(self, layer):
+        del layer
+        return self
 
     def project(self, layer, x, positions):
         c = self.cfg

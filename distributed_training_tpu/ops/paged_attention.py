@@ -53,6 +53,19 @@ One entrypoint over keys and values:
   The ragged kernel that reads only the pages a sequence owns is the
   end state (ROADMAP S1).
 
+  Both forms take a ``window`` (a query sees the last ``window``
+  positions, itself included) and, for a window layer's pool, a table
+  that is a RING (``ring=True``, ``serving/kv_cache.py``): entry ``e``
+  holds the sequence's logical pages ``e, e + P, e + 2P, ...`` in turn,
+  so ring slot ``s`` (``s = e * ps + offset``) holds, of the positions
+  ``s, s + P * ps, ...``, the last one written. For a query at ``q``
+  that is the position ``q - ((q - s) mod (P * ps))``, unless a later
+  row of the same launch has already overwritten it: the ring is as
+  long as the window plus the most rows a launch writes before it
+  reads, so such a slot lies outside the window either way and the
+  mask ``(q - s) mod (P * ps) < window`` needs nothing but the query's
+  position and the slot's place.
+
 There is no switch between the forms. The form each compiled program
 took (``"pool"``, ``"gather"``) is seen at trace time by
 ``observe_forms`` and reported per program by ``Engine.paged_forms()``
@@ -103,8 +116,28 @@ def _masked_softmax(logits: jax.Array, visible: jax.Array
                      0.0)
 
 
+# Float32 logits one call of ``_tile_attention`` may hold before it
+# takes its queries a block at a time: a prompt chunk of 1024 against a
+# table of 16,384 slots is 1.9 GB of them a layer, beside the pools. No
+# shape of the engines the benchmark had before PR 32 reaches it.
+_LOGITS_LIMIT = 256 << 20
+
+
+def _query_blocks(shape, slots: int) -> int:
+    """How many blocks ``_tile_attention`` cuts the queries of q
+    ``(B, S, H, hd)`` into against ``slots`` keys: the smallest power
+    of two that divides ``S`` and brings the float32 logits under
+    ``_LOGITS_LIMIT`` (1: one pass, the only form before PR 32)."""
+    B, S, H, _ = shape
+    blocks = 1
+    while (B * (S // blocks) * H * slots * 4 > _LOGITS_LIMIT
+           and S % (2 * blocks) == 0):
+        blocks *= 2
+    return blocks
+
+
 def _tile_attention(layout, q: jax.Array, k: jax.Array,
-                    v: jax.Array, visible: jax.Array) -> jax.Array:
+                    v: jax.Array, visible) -> jax.Array:
     """GQA attention with an explicit visibility mask, on the pool's
     tiles as they lie (``serving/kv_cache.py::PoolLayout``).
 
@@ -117,7 +150,29 @@ def _tile_attention(layout, q: jax.Array, k: jax.Array,
     logits/softmax (ops/attention.py numerics contract), output in
     q.dtype. Rows with zero visible keys (inactive batch slots)
     produce zeros, not NaN — the engine masks their outputs anyway,
-    but NaN would poison debugging."""
+    but NaN would poison debugging.
+
+    ``visible`` is a function of the queries' rows ``(lo, n)`` (``lo``
+    None: all of them) giving the mask of those ``n`` queries ``(B, n,
+    Sk)``, so that where the
+    logits of all queries at once would be too many
+    (``_query_blocks``) the queries go a block at a time and neither
+    the logits nor the mask of all of them is ever held."""
+    B, S = q.shape[:2]
+    blocks = _query_blocks(q.shape, k.shape[-3])
+    if blocks > 1:
+        n = S // blocks
+
+        def one(lo):
+            rows = jax.lax.dynamic_slice_in_dim(q, lo, n, axis=1)
+            return _tile_attention_block(layout, rows, k, v,
+                                         visible(lo, n))
+        out = jax.lax.map(one, jnp.arange(blocks, dtype=jnp.int32) * n)
+        return out.transpose(1, 0, 2, 3, 4).reshape(q.shape)
+    return _tile_attention_block(layout, q, k, v, visible(None, S))
+
+
+def _tile_attention_block(layout, q, k, v, visible):
     qt = layout.spread(q)                     # (B, S, tiles, J, tile)
     kv = "bktl" if k.ndim == 4 else "ktl"
     # Batched over the tile with B*S*J rows a tile: an MXU dot in the
@@ -177,23 +232,51 @@ def chunk_form(q_shape, pool_shape, table_shape, itemsize: int) -> str:
     return "pool" if pool < gather else "gather"
 
 
+def _visible(q_positions: jax.Array, slot_pos: jax.Array,
+             window, ring_slots):
+    """The mask maker ``_tile_attention`` wants: queries at
+    ``q_positions (B, S)`` (negative: a dead query, sees nothing)
+    against slots that hold positions ``slot_pos (B or 1, Sk)``, or,
+    of a ring of ``ring_slots`` slots, are at places ``slot_pos`` in it
+    (``ring_slots`` and past: no slot of this sequence's). A query sees
+    the positions up to its own, the last ``window`` of them where one
+    is given."""
+    def mask(lo, n):
+        qp = (q_positions if lo is None else
+              jax.lax.dynamic_slice_in_dim(q_positions, lo, n, axis=1)
+              )[:, :, None]
+        at = slot_pos[:, None, :]
+        if ring_slots is None:
+            seen = at <= qp
+            if window:
+                seen &= at > qp - window
+        else:
+            back = jnp.mod(qp - at, ring_slots)   # rows behind the query
+            seen = (back < window) & (back <= qp) & (at < ring_slots)
+        return seen & (qp >= 0)
+    return mask
+
+
 def _gather_attention(q: jax.Array, k_pages, v_pages,
                       page_indices: jax.Array,
-                      q_positions: jax.Array) -> jax.Array:
-    """Gather form: each sequence's pages copied dense in logical
-    order (``B * P * ps`` slots, however few are live), then masked
+                      q_positions: jax.Array, window=None,
+                      ring: bool = False) -> jax.Array:
+    """Gather form: each sequence's pages copied dense in table order
+    (``B * P * ps`` slots, however few are live), then masked
     attention over the copy."""
     kd = k_pages.pages(page_indices)
     vd = v_pages.pages(page_indices)
-    slot = jnp.arange(kd.shape[1], dtype=jnp.int32)
-    visible = (slot[None, None, :] <= q_positions[:, :, None]) \
-        & (q_positions[:, :, None] >= 0)
-    return _tile_attention(k_pages.layout, q, kd, vd, visible)
+    slot = jnp.arange(kd.shape[1], dtype=jnp.int32)[None]
+    return _tile_attention(
+        k_pages.layout, q, kd, vd,
+        _visible(q_positions, slot, window,
+                 kd.shape[1] if ring else None))
 
 
 def _pool_attention(q: jax.Array, k_pages, v_pages,
                     page_indices: jax.Array,
-                    q_positions: jax.Array) -> jax.Array:
+                    q_positions: jax.Array, window=None,
+                    ring: bool = False) -> jax.Array:
     """Pool form: every query against the layer's WHOLE pool
     (``N * ps`` slots a head, read once for all sequences), the page
     table turned inside out into a visibility mask. No gather; the
@@ -218,10 +301,12 @@ def _pool_attention(q: jax.Array, k_pages, v_pages,
     slot_pos = (owner[:, :, None] * ps
                 + jnp.arange(ps, dtype=jnp.int32)[None, None, :]
                 ).reshape(B, N * ps)
-    visible = (slot_pos[:, None, :] <= q_positions[:, :, None]) \
-        & (q_positions[:, :, None] >= 0)             # (B, S, N*ps)
-    return _tile_attention(k_pages.layout, q, k_pages.slots(),
-                           v_pages.slots(), visible)
+    # In a ring the same arithmetic gives a slot's place in the ring,
+    # and ``P * ps`` and past where the sequence does not own the page.
+    return _tile_attention(
+        k_pages.layout, q, k_pages.slots(), v_pages.slots(),
+        _visible(q_positions, slot_pos, window,
+                 P * ps if ring else None))
 
 
 def _held(pages) -> tuple:
@@ -233,7 +318,8 @@ def _held(pages) -> tuple:
 
 def paged_attention_chunk(q: jax.Array, k_pages, v_pages,
                           page_indices: jax.Array,
-                          q_positions: jax.Array) -> jax.Array:
+                          q_positions: jax.Array, window=None,
+                          ring: bool = False) -> jax.Array:
     """Multi-query paged attention (every engine program's, decode
     at ``S = 1``).
 
@@ -243,13 +329,22 @@ def paged_attention_chunk(q: jax.Array, k_pages, v_pages,
     (b, s) attends logical positions ``<= q_positions[b, s]`` of
     sequence b (the chunk's own KV must already be written to the
     pool). Negative q_positions mark padding queries (zero output).
-    The form (``chunk_form``) follows from the static shapes.
+    ``window`` (None or 0: all of them) narrows that to the last
+    ``window`` positions, the query's own included; ``ring`` says the
+    table is a window layer's ring (see the module's text), which
+    needs a window no longer than the ring less the rows a launch
+    writes. The form (``chunk_form``) follows from the static shapes;
+    a call over a ring is reported as ``"<form>.window"``.
     """
+    if ring and not window:
+        raise ValueError("a ring table needs the window it was sized "
+                         "for")
     form = chunk_form(q.shape, _held(k_pages), page_indices.shape,
                       k_pages.dtype.itemsize)
-    _took(form)
+    _took(form + ".window" if ring else form)
     attend = _pool_attention if form == "pool" else _gather_attention
-    return attend(q, k_pages, v_pages, page_indices, q_positions)
+    return attend(q, k_pages, v_pages, page_indices, q_positions,
+                  window, ring)
 
 
 # How many of its own multiply-adds ((nope + v) * rank a gathered row a

@@ -11,11 +11,14 @@ A block has
 
 - ``cache``: the fields of ``PagedCacheConfig`` that the model decides
   (``n_layers``, ``n_kv_heads``, ``head_dim``, ``v_head_dim``,
-  ``kind``). The pool is always two arrays ``k_pages`` / ``v_pages``;
-  how they are stored is the cache's business
-  (``serving/kv_cache.py::PoolLayout``), what a row of each holds the
-  block's (keys and values a head for ``DenseBlock``; the latent row
-  and the rotary key for ``models/latent_moe.py``);
+  ``kind``; a model that mixes window and global layers adds
+  ``window``, ``window_layers`` and its own name as ``block``). There
+  are always two pools ``k_pages`` / ``v_pages``; how they are stored
+  is the cache's business (``serving/kv_cache.py::PoolLayout``; with
+  window layers each is a pool a kind of layer), what a row of each
+  holds the block's (keys and values a head for ``DenseBlock`` and
+  ``models/window_moe.py``; the latent row and the rotary key for
+  ``models/latent_moe.py``);
 - ``counters``: names of the int32 sums ``finish`` returns a layer
   (``()`` for a block that counts nothing). The programs add them up
   over layers and iterations and return them beside the tokens, so they
@@ -24,6 +27,11 @@ A block has
 - ``segments(params)`` -> the stacked layer parameters in layer order,
   one pytree a run of like layers (leading axis = layers). The engine
   scans each run with the pool's matching layers;
+- ``at(layer)`` -> the block as the run that starts at layer number
+  ``layer`` sees it: the object whose ``project`` / ``attend_chunk`` /
+  ``finish`` the engine calls in that run. A block whose layers are
+  all alike returns itself; one whose runs differ (positions on some,
+  a window on some) returns the run's view;
 - ``project(layer, x, positions)`` -> ``(q, k_new, v_new)``: the
   layer's normed input projected; ``k_new`` / ``v_new``
   ``(..., n_kv_heads, width)`` are the rows this token adds to the two
@@ -35,7 +43,10 @@ A block has
   ``kp`` / ``vp`` are the layer of the two carried pools, unread
   (``kv_cache.PoolLayer``: read through its ``slots()`` /
   ``pages(tables)``), and already hold the queries' own rows. The one
-  attention entry: the one-token decode program calls it at ``C = 1``;
+  attention entry: the one-token decode program calls it at ``C = 1``.
+  In a run of window layers ``page_rows`` are the sequences' RINGS in
+  the window pool and ``kp`` / ``vp`` that pool's layer
+  (``ops/paged_attention.py``: ``window=..., ring=True``);
 - ``finish(layer, x, attn, valid)`` -> ``(x, counts)``: the output
   projection, the residuals and the feed-forward; ``valid`` marks the
   rows that are real tokens (for counters only);
@@ -48,18 +59,19 @@ Leading shapes are free: ``(B,)`` rows in the decode program,
 from __future__ import annotations
 
 
-def rope_bhd(x, positions):
-    """RoPE on (..., H, hd) with per-row absolute positions (...) —
-    the same freqs/rotation as models.transformer._rope (parity with
-    the training stack is load-bearing: drift here is silent output
-    corruption, caught by the paged⇄dense test). The leading shape is
-    free: (B,) rows for the one-token decode, (S, C) lanes×positions
-    for the batched chunk program."""
+def rope_bhd(x, positions, theta: float = 10000.0):
+    """RoPE on (..., H, hd) with per-row absolute positions (...),
+    halves paired (``x[i]`` with ``x[i + hd/2]``), base ``theta`` —
+    at the default the same freqs/rotation as models.transformer._rope
+    (parity with the training stack is load-bearing: drift here is
+    silent output corruption, caught by the paged⇄dense test). The
+    leading shape is free: (B,) rows for the one-token decode, (S, C)
+    lanes×positions for the batched chunk program."""
     import jax.numpy as jnp
 
     D = x.shape[-1]
     half = D // 2
-    freqs = 1.0 / (10000 ** (jnp.arange(half, dtype=jnp.float32)
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32)
                              / half))
     angles = positions[..., None].astype(jnp.float32) * freqs
     cos = jnp.cos(angles)[..., None, :]
@@ -100,7 +112,11 @@ class DenseBlock:
     """GPT-2's block (``models/transformer.py::Transformer``):
     LayerNorm with bias, one key and one value a kv head in the pool,
     learned or rotary positions, a GELU MLP or, with
-    ``moe_impl="dense"``, experts that every token passes through."""
+    ``moe_impl="dense"``, experts that every token passes through.
+    ``attention_window`` is honoured: every layer masks to it over the
+    full table (the pages behind the window stay held; a ring is a
+    cache with GLOBAL layers beside the window ones,
+    ``models/window_moe.py``)."""
 
     counters: tuple = ()
 
@@ -131,6 +147,10 @@ class DenseBlock:
     def segments(self, params):
         return ({k: params[k] for k in ("ln1", "ln2", "attn", "mlp")},)
 
+    def at(self, layer):
+        del layer
+        return self
+
     def project(self, layer, x, positions):
         import jax.numpy as jnp
 
@@ -149,7 +169,9 @@ class DenseBlock:
         from distributed_training_tpu.ops.paged_attention import (
             paged_attention_chunk)
 
-        return paged_attention_chunk(q, kp, vp, page_rows, q_pos)
+        return paged_attention_chunk(
+            q, kp, vp, page_rows, q_pos,
+            window=self.cfg.attention_window or None)
 
     def finish(self, layer, x, attn, valid):
         import jax

@@ -290,7 +290,9 @@ def export_kv_batch(cache, seq_ids):
     one transfer per request (per-request ``export_kv`` is this with
     a batch of one, so the two can never produce different bytes).
     Returns ``(ks, vs)`` — parallel lists of (L, Hkv, len_i, hd)
-    arrays."""
+    arrays. Refuses a cache with window layers: a ring holds a
+    window's rows, not a sequence's."""
+    cache.full_tables_only("a dense KV hand-off (export)")
     pages_of, lens = [], []
     for sid in seq_ids:
         n = cache.length(sid)
@@ -347,7 +349,9 @@ def import_kv_batch(cache, items) -> None:
     aborts the WHOLE batch before the scatter: nothing is written
     and no cursor advances, but earlier items' pages are left
     allocated-and-empty (ensure() is atomic per sequence). Callers
-    must free every item and retry — ``Engine.adopt_batch`` does."""
+    must free every item and retry — ``Engine.adopt_batch`` does.
+    Refuses a cache with window layers, as ``export_kv_batch``."""
+    cache.full_tables_only("a dense KV hand-off (import)")
     todo = []
     ps = cache.cfg.page_size
     for seq_id, k, v in items:

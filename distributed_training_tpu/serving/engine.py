@@ -49,7 +49,8 @@ per step would starve the lane table it just paid for).
 
 Scheduling: pending prompt work runs before decode (lowest TTFT;
 decode tokens wait behind a prompt storm), and a prefill step that
-makes no progress falls through to decode so pages free up.
+makes no progress falls through to decode so pages free up. Prompts
+that wait for a prefill lane get it in the order they were admitted.
 
 ``prefill_chunk`` is the per-lane prefill token budget of a step;
 decode emits up to ``max_batch`` tokens per step (all groups fire in
@@ -85,10 +86,14 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from distributed_training_tpu.serving.kv_cache import (
+    GLOBAL,
+    WINDOW,
     PagedCacheConfig,
     PagedKVCache,
     copy_pages,
     kv_shards,
+    pool_of,
+    with_pool,
 )
 from distributed_training_tpu.telemetry import current, event, phase
 
@@ -196,6 +201,7 @@ class _Seq:
     ngram: "NgramIndex | None" = None  # lazy prompt-lookup index
     trace: list = field(default_factory=list)  # lifecycle spans
     queue_wait_s: float | None = None  # arrival -> admission
+    admitted_n: int = 0           # how many were admitted before it
     prefix_hit: int = 0           # prompt tokens served from cache
     # Per-token weight-version tags, run-length encoded as
     # ``[version, count]`` pairs in emission order — a sequence that
@@ -420,12 +426,39 @@ def _out_shardings(block, ecfg: EngineConfig, mesh):
     return grp, pool
 
 
-def _layouts(block, ecfg: EngineConfig, mesh):
-    """``(k_layout, v_layout)``: how the pools the programs of (block,
-    engine cfg, mesh) take are stored — the cache's own choice
-    (``PagedKVCache.layouts``), static in every program."""
-    return PagedKVCache.layouts(PagedCacheConfig(**block.cache),
-                                kv_shards(mesh, ecfg.kv_axis))
+def _cache_config(block, ecfg: EngineConfig, mesh,
+                  dtype: str = "float32") -> PagedCacheConfig:
+    """The cache of (block, engine cfg, mesh): the block's widths and
+    kinds of layer, the engine's geometry. The most rows a launch
+    writes of a sequence before it reads is the prefill chunk or the
+    speculative width, and the slots a group what a window pool holds a
+    ring each of."""
+    G = _dp_extent(mesh, ecfg.dp_axis)
+    return PagedCacheConfig(
+        **block.cache, page_size=ecfg.page_size,
+        num_pages=ecfg.num_pages, max_seq_len=ecfg.max_seq_len,
+        dtype=dtype, dp_groups=G,
+        max_write=max(ecfg.prefill_chunk, ecfg.spec_k),
+        slots=ecfg.max_batch // G)
+
+
+def _plan(block, ecfg: EngineConfig, mesh):
+    """What the programs of (block, engine cfg, mesh) know of the cache
+    when they are traced (``kv_cache.PoolPlan``): how the pools are
+    stored and which layers lie in which — the cache's own choice
+    (``PagedKVCache.plan``), static in every program."""
+    return PagedKVCache.plan(_cache_config(block, ecfg, mesh),
+                             kv_shards(mesh, ecfg.kv_axis))
+
+
+def _counters(block, plan) -> tuple:
+    """Names of the int32 sums every program returns beside its
+    tokens: the block's, then the engine's own —
+    ``window_bound_iters``, the slot-iterations (lanes of a chunk
+    launch, slots of a decode iteration) whose sequence was longer than
+    the window, where the block has window layers."""
+    return tuple(block.counters) + (
+        ("window_bound_iters",) if plan.window_layers else ())
 
 
 def _named(name: str, body):
@@ -520,7 +553,7 @@ def build_decode_fn(block, ecfg: EngineConfig, mesh=None):
 
     body = functools.partial(
         _decode_program, block=block,
-        layouts=_layouts(block, ecfg, mesh),
+        plan=_plan(block, ecfg, mesh),
         temperature=ecfg.temperature, top_k=ecfg.top_k)
     return _jit_program("serving_decode", body, block, ecfg, mesh,
                         n_grouped=7, n_results=2)
@@ -543,7 +576,7 @@ def _chunk_fn(block, ecfg: EngineConfig, emit: str, name: str,
 
     body = functools.partial(
         _chunk_program, block=block,
-        layouts=_layouts(block, ecfg, mesh),
+        plan=_plan(block, ecfg, mesh),
         temperature=ecfg.temperature, top_k=ecfg.top_k, emit=emit)
     return _jit_program(name, body, block, ecfg, mesh, n_grouped=8,
                         n_results=2)
@@ -578,7 +611,7 @@ def build_prefill_batch_fn(block, ecfg: EngineConfig, mesh=None):
 
     body = functools.partial(
         _prefill_slots_program, block=block,
-        layouts=_layouts(block, ecfg, mesh),
+        plan=_plan(block, ecfg, mesh),
         temperature=ecfg.temperature, top_k=ecfg.top_k,
         eos_id=ecfg.eos_id)
     return _jit_program("serving_prefill_batch", body, block, ecfg,
@@ -617,7 +650,7 @@ def build_resident_decode_fn(block, ecfg: EngineConfig,
 
     body = functools.partial(
         _resident_program, block=block,
-        layouts=_layouts(block, ecfg, mesh), K=ecfg.resident_k,
+        plan=_plan(block, ecfg, mesh), K=ecfg.resident_k,
         C=ecfg.spec_k, ngram=SPEC_NGRAM, eos_id=ecfg.eos_id)
     return _jit_program("serving_resident_decode", body, block, ecfg,
                         mesh, n_grouped=8, n_results=4, carried=True)
@@ -763,15 +796,18 @@ class Engine:
             getattr(x, "nbytes", 0)
             for x in jax.tree.leaves(params)))
         self.cache = PagedKVCache(
-            PagedCacheConfig(
-                **self.block.cache,
-                page_size=cfg.page_size,
-                num_pages=cfg.num_pages,
-                max_seq_len=cfg.max_seq_len,
-                dtype=model.cfg.dtype,
-                dp_groups=self.dp_groups),
+            _cache_config(self.block, cfg, mesh, model.cfg.dtype),
             mesh=mesh, kv_axis=cfg.kv_axis, dp_axis=cfg.dp_axis)
+        if cfg.prefix_sharing:
+            # Refused by name where the block has window layers.
+            self.cache.full_tables_only(
+                "EngineConfig.prefix_sharing (the prefix index, "
+                "copy-on-write and retained sessions; pass "
+                "prefix_sharing=False)")
+        self._counters = _counters(self.block,
+                                   _plan(self.block, cfg, mesh))
         self.queue: collections.deque[Request] = collections.deque()
+        self._admitted = 0            # admissions so far (_Seq.admitted_n)
         self.slots: list[_Seq | None] = [None] * cfg.max_batch
         self.completed: list[dict] = []
         self._step_counter = 0
@@ -926,7 +962,7 @@ class Engine:
         import jax.numpy as jnp
 
         G, B = self.dp_groups, self.batch_local
-        P = self.cache.cfg.pages_per_seq
+        P = self.cache.cfg.row_width
         C = self.cfg.prefill_chunk
         rng = jnp.zeros((G, 2), jnp.uint32)
 
@@ -993,7 +1029,7 @@ class Engine:
                         for name, form in self.paged_forms().items()],
               cache_kind=self.cache.cfg.kind,
               cache_bytes_per_token=self.cache.cfg.kv_bytes_per_token(),
-              **self.cache.footprint())
+              pools=self.cache.pools(), **self.cache.footprint())
         return self.compile_counts()
 
     # -- admission ---------------------------------------------------------
@@ -1004,6 +1040,9 @@ class Engine:
         if req.max_new_tokens < 1:
             raise ValueError(
                 f"request {req.id}: max_new_tokens must be >= 1")
+        if req.session is not None:
+            self.cache.full_tables_only(
+                f"request {req.id}: a retained session")
         total = req.prompt.shape[0] + req.max_new_tokens
         if total > self.cfg.max_seq_len:
             raise ValueError(
@@ -1036,6 +1075,8 @@ class Engine:
         request keeps its ORIGINAL arrival, so its second trace shows
         the full wait including the lost first pass."""
         now = time.monotonic()
+        seq.admitted_n = self._admitted
+        self._admitted += 1
         seq.trace.append({"ev": "queued", "t": 0.0})
         if seq.req.submitted is not None:
             seq.span("submitted", seq.req.submitted)
@@ -1408,8 +1449,14 @@ class Engine:
     # -- step --------------------------------------------------------------
 
     def _prefill_candidates(self) -> list[_Seq]:
-        return [s for s in self.slots
-                if s is not None and not s.prefill_done]
+        """Slots still in their prompt, in the order they were
+        admitted: the prefill lanes go first come, first served, and
+        not to whoever landed in the lowest slot (which slot a request
+        finds free is chance, so the order of two waiting prompts, and
+        with it every time after, would be too)."""
+        return sorted((s for s in self.slots
+                       if s is not None and not s.prefill_done),
+                      key=lambda s: s.admitted_n)
 
     def _decode_candidates(self) -> list[_Seq]:
         """Slots past their prompt that may be given a budget: not
@@ -1646,8 +1693,9 @@ class Engine:
 
     def _count(self, counts) -> None:
         """Add a launch's fetched ``counts`` (G, n), the block's
-        ``counters`` summed in the program, to the step record."""
-        for name, n in zip(self.block.counters, counts.sum(axis=0)):
+        ``counters`` and the engine's own (``_counters``) summed in the
+        program, to the step record."""
+        for name, n in zip(self._counters, counts.sum(axis=0)):
             self._step_counts[name] = (
                 self._step_counts.get(name, 0) + int(n))
 
@@ -2175,6 +2223,8 @@ class Engine:
         from distributed_training_tpu.serving.disagg import (
             import_kv_batch)
 
+        self.cache.full_tables_only("Engine.adopt_batch (a dense "
+                                    "KV hand-off)")
         self._settle()
         now = time.monotonic()
         staged = []
@@ -2485,8 +2535,11 @@ class Engine:
         self._settle()
         now = time.monotonic()
         seqs = [s for s in self.slots if s is not None]
+        # A block with window layers has no dense export (its window
+        # pages are a ring): everything restarts from its prompt.
         adoptable = [s for s in seqs
-                     if s.prefill_done and s.generated]
+                     if s.prefill_done and s.generated
+                     and not self.cache.cfg.window_layers]
         adopt_ids = {id(s) for s in adoptable}
         fresh = [s for s in seqs if id(s) not in adopt_ids]
         ks, vs = (export_kv_batch(self.cache,
@@ -2523,73 +2576,100 @@ class Engine:
 # ---------------------------------------------------------------------------
 # The compiled programs (pure functions of arrays + static model cfg).
 # Each body sees ONE dp group's block: pools (1, L, N, ps, lanes) as
-# the cache stores them (kv_cache.PoolLayout: ``layouts`` is the pair
-# of the two pools', the only thing that indexes them), batch arrays
-# with a leading group dim of 1 — under shard_map that is
-# the per-group shard; without a dp mesh it is the whole (only) group.
+# the cache stores them (``plan``, a ``kv_cache.PoolPlan``: its ``k`` /
+# ``v`` layouts are the only thing that indexes them; where the model
+# has window layers each of the two is a ``kv_cache.Pools``, a pool a
+# kind of layer), batch arrays with a leading group dim of 1 — under
+# shard_map that is the per-group shard; without a dp mesh it is the
+# whole (only) group.
 # ---------------------------------------------------------------------------
 
 
-def _write_kv(layouts, k_pages, v_pages, layer, k_new, v_new, page_ids,
+def _group0(pools):
+    """One group's pool(s) out of the grouped argument."""
+    import jax
+
+    return jax.tree.map(lambda p: p[0], pools)
+
+
+def _grouped(pools):
+    """``_group0`` the other way, for the result."""
+    import jax
+
+    return jax.tree.map(lambda p: p[None], pools)
+
+
+def _write_kv(plan, k_pages, v_pages, layer, k_new, v_new, page_ids,
               offsets):
     """Scatter per-row new KV into one layer of the group's pool, where
     the pool lies.
 
-    k_pages/v_pages the group's pools as stored, each with its layout
-    of ``layouts``; layer () int32; k_new/v_new (B, Hkv, width), each
+    k_pages/v_pages the group's pools as stored (of the layer's kind),
+    each with its layout of ``plan``; layer () int32, its number in
+    that pool; k_new/v_new (B, Hkv, width), each
     pool its own width; page_ids/offsets (B,) int32 — rows whose write
     must be dead point at the scratch page (id 0). Live rows never
     share a (page, slot) pair (pages are owned by exactly one
     sequence), so scatter order is immaterial; scratch-page collisions
     write garbage over garbage."""
-    kl, vl = layouts
-    return (kl.write(k_pages, layer, page_ids, offsets, k_new),
-            vl.write(v_pages, layer, page_ids, offsets, v_new))
+    return (plan.k.write(k_pages, layer, page_ids, offsets, k_new),
+            plan.v.write(v_pages, layer, page_ids, offsets, v_new))
 
 
-def _scan_layers(block, layouts, params, x, k_pages_g, v_pages_g,
-                 positions, page_ids, offsets, valid, attend):
+def _scan_layers(block, plan, params, x, k_pages_g, v_pages_g,
+                 positions, coords, valid, attend):
     """Every layer of the model's block on ``x``, THE layer body of
     every program: the block projects the layer's input
     (``positions`` shaped like ``x`` less its width), the new rows go
-    into the layer's pool at ``(page_ids, offsets)`` (shaped like
-    ``positions``: a whole lane table is one scatter, whose live
-    coordinates never collide), ``attend(layer, q, k_new, v_new, kp,
-    vp)`` is the program's own way to the block's attention, and the
-    block finishes the layer; ``valid`` marks real tokens for the
-    block's counters. One ``lax.scan`` a run of like layers
-    (``block.segments``) over the layers' parameters and numbers; the
-    pool (k_pages_g/v_pages_g, one group's, stored by ``layouts``) is
-    carried whole through all of them, so runs of unlike layers cost
-    no slice and no concatenation of it, and ``kp`` / ``vp`` are the
-    carried pool with the layer's number (``PoolLayer``), so attention
-    reads its layer where it lies. Returns ``(x, counts (n,) summed
-    over layers, k_pages_g, v_pages_g)``."""
+    into the layer's pool at its kind's ``coords[kind] = (page_ids,
+    offsets)`` (shaped like ``positions``: a whole lane table is one
+    scatter, whose live coordinates never collide), ``attend(kind,
+    run, layer, q, k_new, v_new, kp, vp)`` is the program's own way to
+    the block's attention, and the block finishes the layer; ``valid``
+    marks real tokens for the block's counters. One ``lax.scan`` a run
+    of like layers (``block.segments``; ``run = block.at(first
+    layer)`` is the block as that run sees it) over the layers'
+    parameters and their numbers in their kind's pool (``plan.run``:
+    global layers in the one pool, window layers in the other, where
+    the model has both); the pools (k_pages_g/v_pages_g, one group's)
+    are carried whole through all of them, so runs of unlike layers
+    cost no slice and no concatenation of them, and ``kp`` / ``vp`` are
+    the carried pool with the layer's number (``PoolLayer``), so
+    attention reads its layer where it lies. Returns ``(x, counts (n,)
+    summed over layers, k_pages_g, v_pages_g)``."""
     import jax
     import jax.numpy as jnp
 
-    kl, vl = layouts
+    def layer_body(kind, run):
+        page_ids, offsets = coords[kind]
 
-    def layer_body(carry, inp):
-        x, kg, vg = carry
-        layer, number = inp
-        q, k, v = block.project(layer, x, positions)
-        kg, vg = _write_kv(
-            layouts, kg, vg, number,
-            k.reshape((-1,) + k.shape[-2:]).astype(kg.dtype),
-            v.reshape((-1,) + v.shape[-2:]).astype(vg.dtype),
-            page_ids.reshape(-1), offsets.reshape(-1))
-        attn = attend(layer, q, k, v, kl.layer(kg, number),
-                      vl.layer(vg, number))
-        x, counts = block.finish(layer, x, attn, valid)
-        return (x, kg, vg), counts
+        def body(carry, inp):
+            x, kg, vg = carry
+            layer, number = inp
+            q, k, v = run.project(layer, x, positions)
+            kp, vp = pool_of(kg, kind), pool_of(vg, kind)
+            kp, vp = _write_kv(
+                plan, kp, vp, number,
+                k.reshape((-1,) + k.shape[-2:]).astype(kp.dtype),
+                v.reshape((-1,) + v.shape[-2:]).astype(vp.dtype),
+                page_ids.reshape(-1), offsets.reshape(-1))
+            attn = attend(kind, run, layer, q, k, v,
+                          plan.k.layer(kp, number),
+                          plan.v.layer(vp, number))
+            x, counts = run.finish(layer, x, attn, valid)
+            return (x, with_pool(kg, kind, kp),
+                    with_pool(vg, kind, vp)), counts
+        return body
 
     counts = jnp.zeros((len(block.counters),), jnp.int32)
     carry, lo = (x, k_pages_g, v_pages_g), 0
-    for run in block.segments(params):
-        hi = lo + jax.tree.leaves(run)[0].shape[0]
+    for layers in block.segments(params):
+        hi = lo + jax.tree.leaves(layers)[0].shape[0]
+        kind, first = plan.run(lo, hi)
         carry, c = jax.lax.scan(
-            layer_body, carry, (run, jnp.arange(lo, hi, dtype=jnp.int32)))
+            layer_body(kind, block.at(lo)), carry,
+            (layers, jnp.arange(first, first + hi - lo,
+                                dtype=jnp.int32)))
         counts = counts + c.sum(axis=0)
         lo = hi
     x, k_pages_g, v_pages_g = carry
@@ -2618,7 +2698,7 @@ def _sample(logits, active, rng_data, temperature, top_k):
 
 
 def _decode_program(params, k_pages, v_pages, tokens, positions,
-                    page_tables, active, rng_data, *, block, layouts,
+                    page_tables, active, rng_data, *, block, plan,
                     temperature, top_k):
     """One token for one dp group's slot table: a chunk of one a slot
     through ``_chunk_hidden``, then sampled.
@@ -2636,50 +2716,65 @@ def _decode_program(params, k_pages, v_pages, tokens, positions,
 
     active = active[0]
     x, _valid, counts, k_pages_g, v_pages_g = _chunk_hidden(
-        params, k_pages[0], v_pages[0], page_tables[0],
+        params, _group0(k_pages), _group0(v_pages), page_tables[0],
         tokens[0][:, None], positions[0],
-        jnp.ones_like(positions[0]), active, block=block,
-        layouts=layouts)
+        jnp.ones_like(positions[0]), active, block=block, plan=plan)
     nxt = _sample(block.logits(params, x[:, 0]), active, rng_data,
                   temperature, top_k)
-    return nxt[None], counts[None], k_pages_g[None], v_pages_g[None]
+    return (nxt[None], counts[None], _grouped(k_pages_g),
+            _grouped(v_pages_g))
 
 
 def _chunk_hidden(params, k_pages_g, v_pages_g, page_rows, tokens,
-                  start_pos, n_valid, active, *, block, layouts):
+                  start_pos, n_valid, active, *, block, plan):
     """The multi-lane chunk forward SHARED by ``_chunk_program``
     (batched prefill + speculative verification) and
     ``_resident_program`` (every resident loop iteration) — ONE
     implementation, so the device-resident path cannot drift from
     the host-verified chunk math. Operates on one group's UNPACKED
     block (no leading group dim): k_pages_g/v_pages_g
-    (L, N, ps, lanes); page_rows (S, P); tokens (S, C);
+    (L, N, ps, lanes), or with window layers a ``Pools`` of the two
+    kinds'; page_rows (S, P), the tables, with window layers followed
+    by the rings (``plan.rows``); tokens (S, C);
     start_pos, n_valid (S,); active (S,) bool. Writes every lane's
-    valid tokens' KV through one batched page-row scatter and returns
-    ``(x (S, C, D) final hidden states, valid (S, C), counts (n,),
+    valid tokens' KV through one batched page-row scatter a layer, a
+    global layer's at the table's coordinates and a window layer's at
+    the ring's (logical page ``p`` in entry ``p % ring_pages``), and
+    returns ``(x (S, C, D) final hidden states, valid (S, C), counts
+    (n,): the block's counters and the engine's (``_counters``),
     k_pages_g, v_pages_g)``."""
     import jax.numpy as jnp
 
     S, C = tokens.shape
-    P = page_rows.shape[1]
-    ps = layouts[0].page_size(k_pages_g)
+    ps = plan.k.page_size(pool_of(k_pages_g, GLOBAL))
     idx = jnp.arange(C, dtype=jnp.int32)
     abs_pos = start_pos[:, None] + idx[None, :]           # (S, C)
     valid = (idx[None, :] < n_valid[:, None]) & active[:, None]
     x = block.embed(params, tokens, abs_pos)              # (S, C, D)
-    # Page coordinates per (lane, position); dead writes → each
-    # group's scratch page 0 (page index clamped first: padding
-    # positions of a lane near max_seq_len could index past its row).
-    logical = jnp.minimum(abs_pos // ps, P - 1)
-    page_ids = jnp.where(
-        valid, jnp.take_along_axis(page_rows, logical, axis=1), 0)
-    offsets = jnp.where(valid, abs_pos % ps, 0)
+    rows = plan.rows(page_rows)
+    coords = {}
+    for kind, table in rows.items():
+        # Page coordinates per (lane, position); dead writes → each
+        # group's scratch page 0 (page index clamped first: padding
+        # positions of a lane near max_seq_len could index past its
+        # row).
+        P = table.shape[1]
+        logical = (abs_pos // ps % P if kind == WINDOW
+                   else jnp.minimum(abs_pos // ps, P - 1))
+        coords[kind] = (
+            jnp.where(valid, jnp.take_along_axis(table, logical,
+                                                 axis=1), 0),
+            jnp.where(valid, abs_pos % ps, 0))
     q_pos = jnp.where(valid, abs_pos, -1)                 # (S, C)
     x, counts, k_pages_g, v_pages_g = _scan_layers(
-        block, layouts, params, x, k_pages_g, v_pages_g, abs_pos, page_ids,
-        offsets, valid,
-        lambda layer, q, _k, _v, kp, vp: block.attend_chunk(
-            layer, q, kp, vp, page_rows, q_pos))
+        block, plan, params, x, k_pages_g, v_pages_g, abs_pos, coords,
+        valid,
+        lambda kind, run, layer, q, _k, _v, kp, vp: run.attend_chunk(
+            layer, q, kp, vp, rows[kind], q_pos))
+    if plan.window_layers:
+        bound = active & (start_pos + n_valid > plan.window)
+        counts = jnp.concatenate(
+            [counts, jnp.sum(bound, dtype=jnp.int32)[None]])
     return x, valid, counts, k_pages_g, v_pages_g
 
 
@@ -2696,7 +2791,7 @@ def _argmax_chain(block, params, x, valid):
 
 def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
                    start_pos, n_valid, active, rng_data, *, block,
-                   layouts, temperature, top_k, emit):
+                   plan, temperature, top_k, emit):
     """Multi-token chunks for a whole lane table, one dp group.
 
     The ONE program body behind both batched prefill (``emit="last"``,
@@ -2731,13 +2826,13 @@ def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
     """
     import jax.numpy as jnp
 
-    k_pages_g, v_pages_g = k_pages[0], v_pages[0]
+    k_pages_g, v_pages_g = _group0(k_pages), _group0(v_pages)
     page_rows, tokens = page_rows[0], tokens[0]
     start_pos, n_valid, active = start_pos[0], n_valid[0], active[0]
     S = tokens.shape[0]
     x, valid, counts, k_pages_g, v_pages_g = _chunk_hidden(
         params, k_pages_g, v_pages_g, page_rows, tokens,
-        start_pos, n_valid, active, block=block, layouts=layouts)
+        start_pos, n_valid, active, block=block, plan=plan)
     if emit == "all":
         # The verification chain: logits at EVERY position, argmax
         # only (spec decode is greedy by config contract).
@@ -2751,13 +2846,14 @@ def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
             axis=1)[:, 0]
         nxt = _sample(block.logits(params, x_last), active, rng_data,
                       temperature, top_k)
-    return nxt[None], counts[None], k_pages_g[None], v_pages_g[None]
+    return (nxt[None], counts[None], _grouped(k_pages_g),
+            _grouped(v_pages_g))
 
 
 def _prefill_slots_program(params, k_pages, v_pages, history, kv_len,
                            left, page_rows, tokens, start_pos, n_valid,
                            active, rng_data, slot, max_new, *, block,
-                           layouts, temperature, top_k, eos_id):
+                           plan, temperature, top_k, eos_id):
     """Batched prefill (``_chunk_program``, ``emit="last"``) that also
     writes the carried slot table, one dp group: lane s's chunk goes
     into row ``slot[s]`` of ``history`` at its positions, and where the
@@ -2778,7 +2874,7 @@ def _prefill_slots_program(params, k_pages, v_pages, history, kv_len,
 
     nxt, counts, k_pages, v_pages = _chunk_program(
         params, k_pages, v_pages, page_rows, tokens, start_pos, n_valid,
-        active, rng_data, block=block, layouts=layouts,
+        active, rng_data, block=block, plan=plan,
         temperature=temperature, top_k=top_k, emit="last")
     hist, kvl, lft = history[0], kv_len[0], left[0]
     B = hist.shape[0]
@@ -2801,7 +2897,7 @@ def _prefill_slots_program(params, k_pages, v_pages, history, kv_len,
 
 
 def _resident_program(params, k_pages, v_pages, history, kv_len, left,
-                      page_rows, budget, active, *, block, layouts, K,
+                      page_rows, budget, active, *, block, plan, K,
                       C, ngram, eos_id):
     """Device-resident K-step decode for one dp group's slot table.
 
@@ -2842,7 +2938,7 @@ def _resident_program(params, k_pages, v_pages, history, kv_len, left,
     import jax
     import jax.numpy as jnp
 
-    kp, vp = k_pages[0], v_pages[0]
+    kp, vp = _group0(k_pages), _group0(v_pages)
     page_rows_g = page_rows[0]
     history_g, kv_len_g, left_g = history[0], kv_len[0], left[0]
     budget_g = jnp.where(active[0], jnp.minimum(budget[0], left_g), 0)
@@ -2899,7 +2995,7 @@ def _resident_program(params, k_pages, v_pages, history, kv_len, left,
             tokens = last[:, None]
         x, valid, c, kp, vp = _chunk_hidden(
             params, kp, vp, page_rows_g, tokens, kvl, n, running,
-            block=block, layouts=layouts)
+            block=block, plan=plan)
         nxt = _argmax_chain(block, params, x, valid)    # (B, C)
         if C > 1:
             sl = jnp.arange(C - 1, dtype=jnp.int32)
@@ -2947,8 +3043,10 @@ def _resident_program(params, k_pages, v_pages, history, kv_len, left,
             jnp.zeros((B, T), jnp.int32),
             jnp.zeros((B,), jnp.int32),
             kv_len_g, budget_g, left_g, history_g, budget_g > 0,
-            jnp.zeros((len(block.counters),), jnp.int32), kp, vp)
+            jnp.zeros((len(_counters(block, plan)),), jnp.int32), kp,
+            vp)
     j, out, n_em, kvl, _bud, lft, hist, _run, counts, kp, vp = \
         jax.lax.while_loop(cond, body, init)
     return (out[None], n_em[None], jnp.reshape(j, (1,)), counts[None],
-            hist[None], kvl[None], lft[None], kp[None], vp[None])
+            hist[None], kvl[None], lft[None], _grouped(kp),
+            _grouped(vp))

@@ -31,6 +31,25 @@ pages from the group's free list, evict = return them — no copying, no
 compaction, and the device arrays never change shape, so the decode
 program never recompiles.
 
+**Two kinds of layer (PR 32).** A model may mix GLOBAL layers, which
+attend every earlier position, with WINDOW layers, which attend the
+last ``window`` (``cfg.window_layers``, from the model's serving block).
+The cache then holds a pool and an allocator a kind (``Pools``), and a
+sequence a page row a kind: the global row is the table above and grows
+with the sequence; the window row is a RING of ``cfg.ring_pages`` pages,
+taken as the sequence grows and never more, logical page ``p`` living
+in ring entry ``p % ring_pages`` (position ``p`` in ring slot ``p %
+(ring_pages * page_size)``: a row overwrites the row one ring before
+it, which no query can see any more because the ring holds the window
+AND the most rows one launch writes before it reads, ``max_write``).
+``page_row`` hands the programs both rows side by side, the global
+entries first. The window pool holds ``slots * ring_pages`` pages a
+group, its exact worst case, so it is never the pool a sequence waits
+on. Whatever moves pages by ONE table a sequence (the prefix index,
+``attach``, ``privatize``, ``rename``, ``read_pages`` / ``write_pages``)
+refuses such a cache by name (``full_tables_only``); a model without
+window layers gets exactly the single pool described above.
+
 **Page 0 of every group is that group's scratch page**: never
 allocated, the write target for inactive batch slots and padding
 positions (the jitted decode/prefill programs write unconditionally;
@@ -81,6 +100,7 @@ a session key for zero-prefill resume).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import jax
 import numpy as np
@@ -265,6 +285,78 @@ def copy_pages(pool, src, dst):
     return pool.at[:, dst].set(pool[:, src])
 
 
+class Pools(NamedTuple):
+    """The pools of a cache with two kinds of layer, in place of the
+    one array a uniform cache has: ``full`` holds the global layers
+    (tables that grow), ``ring`` the window layers (a ring a sequence).
+    A pytree, so it crosses ``jax.jit`` and is donated like the array
+    it stands for; ``delete`` frees both."""
+
+    full: object
+    ring: object
+
+    def delete(self) -> None:
+        self.full.delete()
+        self.ring.delete()
+
+
+GLOBAL, WINDOW = "global", "window"
+
+
+def pool_of(pools, kind: str):
+    """The pool of ``kind`` layers: ``pools`` itself where the cache is
+    uniform (one array)."""
+    if not isinstance(pools, Pools):
+        return pools
+    return pools.ring if kind == WINDOW else pools.full
+
+
+def with_pool(pools, kind: str, pool):
+    """``pools`` with the pool of ``kind`` layers replaced."""
+    if not isinstance(pools, Pools):
+        return pool
+    return pools._replace(**{"ring" if kind == WINDOW else "full": pool})
+
+
+@dataclass(frozen=True)
+class PoolPlan:
+    """What every program knows of the cache when it is traced: how the
+    two pools store a row (``k``, ``v``), how wide a sequence's table
+    is (``pages_per_seq``) and, where the model has window layers,
+    which they are, how wide the window and how many pages a ring
+    (``PagedKVCache.plan``). Static in every program."""
+
+    k: PoolLayout
+    v: PoolLayout
+    n_layers: int
+    pages_per_seq: int
+    window: int = 0
+    window_layers: tuple = ()
+    ring_pages: int = 0
+
+    def run(self, lo: int, hi: int) -> tuple:
+        """``(kind, first)`` for the run of like layers ``[lo, hi)``:
+        which pool holds them and the number of layer ``lo`` in it (a
+        pool's layers are its kind's, in model order)."""
+        inside = [n in self.window_layers for n in range(lo, hi)]
+        if any(inside) != all(inside):
+            raise ValueError(
+                f"layers {lo}..{hi - 1} are one run of the block's "
+                f"segments but mix window and global layers "
+                f"(window layers: {self.window_layers})")
+        if not inside[0]:
+            return GLOBAL, lo - sum(n < lo for n in self.window_layers)
+        return WINDOW, sum(n < lo for n in self.window_layers)
+
+    def rows(self, page_rows) -> dict:
+        """A launch's page rows ``(..., pages_per_seq + ring_pages)``
+        cut into each kind's: ``{kind: (..., width)}``."""
+        if not self.window_layers:
+            return {GLOBAL: page_rows}
+        return {GLOBAL: page_rows[..., :self.pages_per_seq],
+                WINDOW: page_rows[..., self.pages_per_seq:]}
+
+
 class PoolLayer:
     """One layer of a group's carried pool, handed to attention
     unread: the carried pool and the layer's number, so that a reader
@@ -333,10 +425,40 @@ class PagedCacheConfig:
     max_seq_len: int = 256
     dtype: str = "float32"
     dp_groups: int = 1            # leading pool dim / allocator shards
+    # Window layers (from the block; none = one uniform pool): which
+    # layers attend only the last ``window`` positions, the most rows
+    # one launch writes of a sequence before it reads (the engine's
+    # prefill chunk or speculative width), the slots a group (the
+    # window pool holds ``slots`` rings), and the block's name, for
+    # the refusals.
+    window: int = 0
+    window_layers: tuple = ()
+    max_write: int = 1
+    slots: int = 0
+    block: str = ""
 
     def __post_init__(self):
         if self.v_head_dim == 0:
             object.__setattr__(self, "v_head_dim", self.head_dim)
+        object.__setattr__(self, "window_layers",
+                           tuple(sorted(self.window_layers)))
+        if self.window_layers:
+            if self.window < 1:
+                raise ValueError("window layers need window >= 1, got "
+                                 f"{self.window}")
+            if not set(self.window_layers) <= set(range(self.n_layers)):
+                raise ValueError(
+                    f"window_layers {self.window_layers} not all in "
+                    f"[0, {self.n_layers})")
+            if len(self.window_layers) == self.n_layers:
+                raise ValueError(
+                    "every layer a window layer: the cache keeps the "
+                    "global pool for the sequence's table, so at least "
+                    "one layer must be global")
+            if self.slots < 1:
+                raise ValueError("a cache with window layers needs "
+                                 "slots (a group) >= 1: its window "
+                                 "pool holds one ring a slot")
         if self.page_size < 1:
             raise ValueError(f"page_size must be >= 1, got "
                              f"{self.page_size}")
@@ -355,6 +477,40 @@ class PagedCacheConfig:
     @property
     def pages_per_seq(self) -> int:
         return self.max_seq_len // self.page_size
+
+    @property
+    def ring_pages(self) -> int:
+        """Pages of a sequence's ring in the window pool: the window
+        and the rows one launch may write ahead of a query, never more
+        than a whole table; 0 without window layers."""
+        if not self.window_layers:
+            return 0
+        return min(self.pages_per_seq,
+                   -(-(self.window + self.max_write) // self.page_size))
+
+    @property
+    def row_width(self) -> int:
+        """Entries of a sequence's page row as the programs take it:
+        the table, then the ring."""
+        return self.pages_per_seq + self.ring_pages
+
+    @property
+    def window_num_pages(self) -> int:
+        """Pages a group of the window pool, scratch included: a ring
+        a slot, the exact worst case."""
+        return self.slots * self.ring_pages + 1 if self.window_layers \
+            else 0
+
+    @property
+    def window_usable_pages_total(self) -> int:
+        """Pages of the window pool a sequence can be given, all
+        groups: a ring a slot (0 without window layers)."""
+        return self.dp_groups * self.slots * self.ring_pages
+
+    @property
+    def global_layers(self) -> tuple:
+        return tuple(n for n in range(self.n_layers)
+                     if n not in self.window_layers)
 
     @property
     def usable_pages(self) -> int:
@@ -442,14 +598,24 @@ class PagedKVCache:
             return jax.device_put(z, sharding) \
                 if sharding is not None else z
 
-        self.k_pages, self.v_pages = map(
-            pool, self.pool_shapes(cfg, shards))
+        self.k_pages, self.v_pages = (
+            Pools(*map(pool, shapes)) if isinstance(shapes, Pools)
+            else pool(shapes)
+            for shapes in self.pool_shapes(cfg, shards))
         # Host allocator state, PER GROUP. Free lists are LIFO:
         # recently-freed pages are re-handed first (warm in cache, and
         # deterministic for the tests' join/evict permutations).
         self._frees: list[list[int]] = [
             list(range(cfg.num_pages - 1, 0, -1))
             for _ in range(cfg.dp_groups)]
+        # The window pool's allocator, a group (empty lists without
+        # window layers): a sequence's ring, entry ``e`` holding its
+        # logical pages ``e, e + ring_pages, ...`` in turn. Never
+        # shared, so no refcounts.
+        self._ring_frees: list[list[int]] = [
+            list(range(cfg.window_num_pages - 1, 0, -1))
+            for _ in range(cfg.dp_groups)]
+        self._rings: dict[object, list[int]] = {}
         self._tables: dict[object, list[int]] = {}
         self._lengths: dict[object, int] = {}
         self._groups: dict[object, int] = {}
@@ -482,40 +648,79 @@ class PagedKVCache:
                 PoolLayout(cfg.n_kv_heads, cfg.v_head_dim, shards))
 
     @classmethod
+    def plan(cls, cfg: PagedCacheConfig, shards: int = 1) -> PoolPlan:
+        """What the engine's programs are traced with (``PoolPlan``)."""
+        return PoolPlan(*cls.layouts(cfg, shards), cfg.n_layers,
+                        cfg.pages_per_seq, cfg.window,
+                        cfg.window_layers, cfg.ring_pages)
+
+    @classmethod
     def pool_shapes(cls, cfg: PagedCacheConfig,
                     shards: int = 1) -> tuple:
         """The shapes of ``k_pages`` and ``v_pages``, nothing
-        allocated (what an abstract lowering needs)."""
-        return tuple(
-            (cfg.dp_groups,) + lay.shape(cfg.n_layers, cfg.num_pages,
-                                         cfg.page_size)
-            for lay in cls.layouts(cfg, shards))
+        allocated (what an abstract lowering needs): a shape each, or
+        with window layers a ``Pools`` of two, the global layers in
+        ``num_pages`` pages and the window layers in a ring a slot."""
+        def shapes(lay):
+            if not cfg.window_layers:
+                return (cfg.dp_groups,) + lay.shape(
+                    cfg.n_layers, cfg.num_pages, cfg.page_size)
+            return Pools(*(
+                (cfg.dp_groups,) + lay.shape(len(layers), pages,
+                                             cfg.page_size)
+                for layers, pages in (
+                    (cfg.global_layers, cfg.num_pages),
+                    (cfg.window_layers, cfg.window_num_pages))))
+        return tuple(shapes(lay) for lay in cls.layouts(cfg, shards))
+
+    def pools(self) -> list:
+        """One entry a pool: its kind, its layers, its pages (all
+        groups, scratch included), the pages of a sequence's ring (0:
+        a table that grows), what it takes as stored and what a token
+        costs in it a layer (``serving_warmup``'s ``pools``)."""
+        cfg = self.cfg
+        itemsize = jax.numpy.dtype(cfg.dtype).itemsize
+        row = (self.k_layout.lanes + self.v_layout.lanes) * itemsize
+        kinds = ([(GLOBAL, cfg.global_layers, cfg.num_pages, 0),
+                  (WINDOW, cfg.window_layers, cfg.window_num_pages,
+                   cfg.ring_pages)] if cfg.window_layers else
+                 [(GLOBAL, tuple(range(cfg.n_layers)), cfg.num_pages,
+                   0)])
+        return [{"kind": kind, "layers": list(layers),
+                 "pages": cfg.dp_groups * pages, "ring_pages": ring,
+                 "bytes": cfg.dp_groups * pages * cfg.page_size
+                 * len(layers) * row,
+                 "bytes_per_token": len(layers) * row}
+                for kind, layers, pages, ring in kinds]
 
     def footprint(self) -> dict:
         """What the pools take: their stored shapes, the bytes of the
         rows alone (``pool_bytes``) and as stored, every row in whole
         128-lane tiles (``pool_bytes_tiled``). A program whose
         temporaries reach these holds a copy of the pool."""
-        itemsize = self.k_pages.dtype.itemsize
-        slots = (self.cfg.dp_groups * self.cfg.num_pages
-                 * self.cfg.page_size)
+        cfg = self.cfg
+        pools = self.pools()
+        rows = cfg.kv_bytes_per_token() // cfg.n_layers   # a layer's
         return {
-            "pool_shapes": [list(self.k_pages.shape),
-                            list(self.v_pages.shape)],
-            "pool_bytes": slots * self.cfg.kv_bytes_per_token(),
-            "pool_bytes_tiled": slots * self.cfg.n_layers * itemsize
-            * (self.k_layout.lanes + self.v_layout.lanes)}
+            "pool_shapes": [list(a.shape) for p in (self.k_pages,
+                                                    self.v_pages)
+                            for a in jax.tree.leaves(p)],
+            "pool_bytes": sum(p["pages"] * cfg.page_size
+                              * len(p["layers"]) * rows for p in pools),
+            "pool_bytes_tiled": sum(p["bytes"] for p in pools)}
 
     def read_pages(self, groups, pages) -> tuple:
         """Pages ``(groups[i], pages[i])`` of both pools, each ``(n,
         n_layers, n_kv_heads, page_size, width)``, still on the
         device: ONE slice a pool, so that the caller's fetch moves only
         the named pages."""
+        self.full_tables_only("reading pages out by table")
         return (self.k_layout.take_pages(self.k_pages, groups, pages),
                 self.v_layout.take_pages(self.v_pages, groups, pages))
 
     def write_pages(self, groups, pages, k_chunks, v_chunks) -> None:
         """``read_pages`` the other way: one scatter a pool."""
+        self.full_tables_only("writing pages in by table")
         self.update_pools(
             self.k_layout.put_pages(self.k_pages, groups, pages,
                                     k_chunks),
@@ -534,17 +739,51 @@ class PagedKVCache:
                 "free_pages_in(group)")
         return self._frees[0]
 
+    def full_tables_only(self, feature: str) -> None:
+        """Refuse ``feature``, which moves pages by ONE table a
+        sequence, on a cache with window layers: their pages are a
+        ring that is overwritten as the sequence grows, so a page
+        taken from it is not the prefix it once held."""
+        if self.cfg.window_layers:
+            raise NotImplementedError(
+                f"{feature}: {self.cfg.block or 'the block'} has "
+                f"window layers ({len(self.cfg.window_layers)} of "
+                f"{self.cfg.n_layers}, window {self.cfg.window}) whose "
+                f"pages are a ring of {self.cfg.ring_pages} a "
+                "sequence, and this moves pages by one full table a "
+                "sequence (ROADMAP M3)")
+
     def free_pages_in(self, group: int) -> int:
         return len(self._frees[group])
 
     @property
+    def pages_total(self) -> int:
+        """Pages a sequence can be given, all groups, both pools."""
+        return (self.cfg.usable_pages_total
+                + self.cfg.window_usable_pages_total)
+
+    @property
     def pages_used(self) -> int:
-        """Pages allocated across ALL groups."""
-        return self.cfg.usable_pages_total - sum(
-            len(f) for f in self._frees)
+        """Pages allocated across ALL groups, both pools."""
+        return self.pages_total - sum(
+            len(f) for f in self._frees + self._ring_frees)
 
     def pages_used_in(self, group: int) -> int:
         return self.cfg.usable_pages - len(self._frees[group])
+
+    def pages_by_kind(self) -> dict:
+        """``pages_used_<kind>`` / ``pages_total_<kind>`` of a cache
+        with window layers (step-record fields); nothing otherwise."""
+        if not self.cfg.window_layers:
+            return {}
+        out = {}
+        for kind, total, frees in (
+                (GLOBAL, self.cfg.usable_pages_total, self._frees),
+                (WINDOW, self.cfg.window_usable_pages_total,
+                 self._ring_frees)):
+            out[f"pages_used_{kind}"] = total - sum(map(len, frees))
+            out[f"pages_total_{kind}"] = total
+        return out
 
     @property
     def seqs(self) -> int:
@@ -557,14 +796,16 @@ class PagedKVCache:
         event("serving_kv", op=op, seq=str(seq_id),
               group=self._groups.get(seq_id, 0),
               pages_used=self.pages_used,
-              pages_total=self.cfg.usable_pages_total,
+              pages_total=self.pages_total,
               seqs=self.seqs)
 
     def can_admit(self, n_tokens: int, group: int = 0) -> bool:
         """Would ``ensure`` succeed for a NEW sequence of n_tokens in
-        ``group``?"""
+        ``group``? Both pools are counted."""
         need = -(-max(1, n_tokens) // self.cfg.page_size)
-        return need <= len(self._frees[group])
+        return (need <= len(self._frees[group])
+                and min(need, self.cfg.ring_pages)
+                <= len(self._ring_frees[group]))
 
     def join(self, seq_id, group: int = 0) -> None:
         if seq_id in self._tables:
@@ -574,6 +815,7 @@ class PagedKVCache:
                 f"group {group} out of range (pool has "
                 f"{self.cfg.dp_groups} dp group(s))")
         self._tables[seq_id] = []
+        self._rings[seq_id] = []
         self._lengths[seq_id] = 0
         self._groups[seq_id] = group
         self._emit("join", seq_id)
@@ -592,18 +834,25 @@ class PagedKVCache:
                 f"sequence {seq_id!r} needs {n_tokens} positions, "
                 f"pool max_seq_len is {self.cfg.max_seq_len}")
         table = self._tables[seq_id]
+        ring = self._rings[seq_id]
         group = self._groups[seq_id]
-        free = self._frees[group]
-        need = -(-n_tokens // self.cfg.page_size) - len(table)
-        if need <= 0:
+        free, ring_free = self._frees[group], self._ring_frees[group]
+        pages = -(-n_tokens // self.cfg.page_size)
+        need = pages - len(table)
+        # The ring takes pages as the sequence grows, and never more
+        # than ``ring_pages`` (0 without window layers).
+        ring_need = min(pages, self.cfg.ring_pages) - len(ring)
+        if need <= 0 and ring_need <= 0:
             return True
-        if need > len(free):
+        if need > len(free) or ring_need > len(ring_free):
             return False
         refs = self._refs[group]
         for _ in range(need):
             page = free.pop()
             refs[page] = 1
             table.append(page)
+        for _ in range(ring_need):
+            ring.append(ring_free.pop())
         self._emit("grow", seq_id)
         return True
 
@@ -638,6 +887,13 @@ class PagedKVCache:
             self._invalidate(group, page)
             released.append(page)
         self._frees[group].extend(released)
+        # A ring not yet full holds its logical pages in order like a
+        # table; a full one keeps all its pages.
+        ring = self._rings[seq_id]
+        while len(ring) > keep:
+            page = ring.pop()
+            self._ring_frees[group].append(page)
+            released.append(page)
         if released:
             self._emit("trim", seq_id)
         return len(released)
@@ -659,19 +915,26 @@ class PagedKVCache:
                 self._invalidate(group, page)
                 released.append(page)
         self._frees[group].extend(reversed(released))
+        ring = self._rings.pop(seq_id)
+        self._ring_frees[group].extend(reversed(ring))
         self._registered.pop(seq_id, None)
         self._emit("free", seq_id)
         del self._groups[seq_id]
-        return len(released)
+        return len(released) + len(ring)
 
     def length(self, seq_id) -> int:
         return self._lengths[seq_id]
 
     def pages_of(self, seq_id) -> int:
         """Pages in ``seq_id``'s table (shared pages count — they are
-        held, refcounted). The /debug/requests introspection read;
-        raises KeyError for unknown ids like every per-seq accessor."""
-        return len(self._tables[seq_id])
+        held, refcounted), its ring's included. The /debug/requests
+        introspection read; raises KeyError for unknown ids like every
+        per-seq accessor."""
+        return len(self._tables[seq_id]) + len(self._rings[seq_id])
+
+    def ring_pages_of(self, seq_id) -> int:
+        """Pages of ``seq_id``'s ring in the window pool."""
+        return len(self._rings[seq_id])
 
     # -- sharing: refcounted attach / COW / prefix index -------------------
 
@@ -682,6 +945,7 @@ class PagedKVCache:
         written. The pages must be live in the sequence's group —
         attaching a freed page is a hard error, not a silent
         corruption."""
+        self.full_tables_only("attaching a resident prefix")
         table = self._tables[seq_id]
         if table or self._lengths[seq_id]:
             raise RuntimeError(
@@ -707,7 +971,9 @@ class PagedKVCache:
         session key; resume renames them back."""
         if new_id in self._tables:
             raise KeyError(f"sequence {new_id!r} already joined")
+        self.full_tables_only("retaining a session's pages")
         self._tables[new_id] = self._tables.pop(old_id)
+        self._rings[new_id] = self._rings.pop(old_id)
         self._lengths[new_id] = self._lengths.pop(old_id)
         self._groups[new_id] = self._groups.pop(old_id)
         if old_id in self._registered:
@@ -724,6 +990,7 @@ class PagedKVCache:
         written (pages below it are fully committed and never written
         again), so this is at most one pair per call in practice; the
         loop keeps the invariant rather than assuming it."""
+        self.full_tables_only("copy-on-write of a shared page")
         table = self._tables[seq_id]
         group = self._groups[seq_id]
         refs = self._refs[group]
@@ -755,6 +1022,7 @@ class PagedKVCache:
         ``tokens`` (the sequence's token history) not yet registered.
         Keyed by the EXACT prefix bytes — matching is equality, not a
         lossy hash, so a hit can never alias two different prompts."""
+        self.full_tables_only("the prefix index")
         table = self._tables[seq_id]
         group = self._groups[seq_id]
         ps = self.cfg.page_size
@@ -806,12 +1074,17 @@ class PagedKVCache:
         out-write what ``ensure`` could cover."""
         g = self._groups[seq_id]
         pages = len(self._tables[seq_id]) + len(self._frees[g])
+        ring = len(self._rings[seq_id]) + len(self._ring_frees[g])
+        if ring < self.cfg.ring_pages:
+            # Short of a whole ring (it never is while the window pool
+            # holds a ring a slot): what there is, as a table.
+            pages = min(pages, ring)
         return min(pages * self.cfg.page_size, self.cfg.max_seq_len)
 
     def occupancy(self) -> dict:
         rec = {"pages_used": self.pages_used,
-               "pages_total": self.cfg.usable_pages_total,
-               "seqs": self.seqs}
+               "pages_total": self.pages_total,
+               "seqs": self.seqs, **self.pages_by_kind()}
         if self.cfg.dp_groups > 1:
             # Per-group occupancy rides the same record (additive —
             # the metrics observer folds these into the labeled
@@ -826,17 +1099,20 @@ class PagedKVCache:
     # -- device-side views -------------------------------------------------
 
     def page_row(self, seq_id) -> np.ndarray:
-        """(pages_per_seq,) int32 page-table row, scratch-padded."""
-        row = np.zeros((self.cfg.pages_per_seq,), np.int32)
+        """(row_width,) int32 page row, scratch-padded: the table's
+        ``pages_per_seq`` entries, then (window layers) the ring's."""
+        row = np.zeros((self.cfg.row_width,), np.int32)
         table = self._tables[seq_id]
         row[:len(table)] = table
+        ring = self._rings[seq_id]
+        P = self.cfg.pages_per_seq
+        row[P:P + len(ring)] = ring
         return row
 
     def page_rows(self, seq_ids: list) -> np.ndarray:
-        """(len(seq_ids), pages_per_seq) int32 table; ``None`` entries
+        """(len(seq_ids), row_width) int32 table; ``None`` entries
         (empty batch slots) become all-scratch rows."""
-        rows = np.zeros((len(seq_ids), self.cfg.pages_per_seq),
-                        np.int32)
+        rows = np.zeros((len(seq_ids), self.cfg.row_width), np.int32)
         for i, sid in enumerate(seq_ids):
             if sid is not None:
                 rows[i] = self.page_row(sid)
@@ -844,7 +1120,7 @@ class PagedKVCache:
 
     def page_rows_grouped(self, seq_ids_by_group: list,
                           width: int | None = None) -> np.ndarray:
-        """(dp_groups, width, pages_per_seq) int32 tables from a
+        """(dp_groups, width, row_width) int32 tables from a
         per-group nested id list — the batched programs' layout
         (group g's rows index ONLY group g's pool shard). Lists may
         be RAGGED (the batched prefill packs however many lanes each
@@ -853,8 +1129,8 @@ class PagedKVCache:
         decode path passes equal full-width lists)."""
         b = width if width is not None else max(
             (len(ids) for ids in seq_ids_by_group), default=0)
-        rows = np.zeros((self.cfg.dp_groups, b,
-                         self.cfg.pages_per_seq), np.int32)
+        rows = np.zeros((self.cfg.dp_groups, b, self.cfg.row_width),
+                        np.int32)
         for g, ids in enumerate(seq_ids_by_group):
             for i, sid in enumerate(ids):
                 if sid is not None:

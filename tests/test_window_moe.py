@@ -1,0 +1,638 @@
+"""The window-and-global expert model (models/window_moe.py) against the
+benchmark's plain reference (perfbench/reference/window_moe.py), on the
+CPU at tiny widths: the full forward, prefill then decode through the
+two-pool paged cache on every cadence of the engine past several turns
+of the ring, both forms of paged attention over a ring, the shares of
+an expert layer, the cache's two allocators, what moves pages, and the
+reference's constants against the configuration file."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from perfbench import common
+
+from distributed_training_tpu.models import build_model, experts
+from distributed_training_tpu.ops import paged_attention as pa
+from distributed_training_tpu.serving.engine import (Engine,
+                                                     EngineConfig,
+                                                     Request)
+from distributed_training_tpu.serving.kv_cache import (PagedCacheConfig,
+                                                       PagedKVCache,
+                                                       Pools, as_layer)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# Two periods of (global NoPE, three window RoPE layers); a window of
+# 32 in sequences of up to 200: the ring of (32 + 8) / 4 = 10 pages
+# turns five times.
+KW = dict(vocab_size=96, d_model=32, n_layers=8, n_heads=4, n_kv_heads=2,
+          head_dim=8, moe_d_ff=12, n_routed_experts=16, moe_top_k=3,
+          window=32, window_layout=(0, 1, 1, 1) * 2,
+          rope_layout=(0, 1, 1, 1) * 2, rope_theta=500.0, qk_std=0.2,
+          max_seq_len=256)
+REF = dict(N_KV_HEAD=2, HEAD_DIM=8, WINDOW=32,
+           WINDOW_LAYOUT=(0, 1, 1, 1) * 2, ROPE_LAYOUT=(0, 1, 1, 1) * 2,
+           ROPE_THETA=500.0, NUM_EXPERTS_PER_TOK=3, Q_BLOCK=16)
+ENGINE = dict(max_batch=3, page_size=4, num_pages=160, max_seq_len=256,
+              prefill_chunk=8, prefill_slots=2, prefix_sharing=False)
+
+
+def moved(params, seed=6):
+    """Norm scales are ones at init: move every leaf, so that a path
+    that dropped one would be caught."""
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    return jax.tree.unflatten(tree, [
+        x + 0.05 * jax.random.normal(k, x.shape, x.dtype)
+        for x, k in zip(leaves, keys)])
+
+
+def build(dtype="float32", **over):
+    model = build_model("window_moe", dtype=dtype, **{**KW, **over})
+    return model, moved(model.init(jax.random.PRNGKey(5)))
+
+
+@pytest.fixture()
+def ref(monkeypatch):
+    module = common.load_reference({"reference": "window_moe"})
+    for name, value in REF.items():
+        monkeypatch.setattr(module, name, value)
+    return module
+
+
+def ref_logits(ref, params, ids, rank=0):
+    ref.EP_RANK = rank
+    return np.asarray(ref.logits(ref.from_program(params),
+                                 jnp.asarray(ids, jnp.int32), 4))
+
+
+@pytest.mark.parametrize("ep_size,ep_rank", [(1, 0), (4, 0), (4, 2)])
+def test_apply_matches_the_reference(ref, ep_size, ep_rank):
+    model, params = build(ep_size=ep_size, ep_rank=ep_rank)
+    rows = np.random.default_rng(0).integers(0, 96, (2, 90))
+    got = np.asarray(model.apply(params, jnp.asarray(rows, jnp.int32)))
+    for row, lg in zip(rows, got):
+        # float32 against float32: only the order of summation differs.
+        np.testing.assert_allclose(
+            lg, ref_logits(ref, params, row, ep_rank), atol=2e-4,
+            rtol=2e-4)
+
+
+def test_loss_matches_the_reference(ref):
+    model, params = build(ep_size=4)
+    rows = jnp.asarray(np.random.default_rng(1).integers(0, 96, (3, 70)),
+                       jnp.int32)
+    got = model.loss(params, {"tokens": rows}, jax.random.PRNGKey(0))[0]
+    want = ref.loss(ref.from_program(params), rows, 4)
+    assert abs(float(got) - float(want)) < 1e-4
+
+
+@pytest.mark.parametrize("what", ["window", "rope", "router_input"])
+def test_the_reference_sees_each_mechanism(ref, monkeypatch, what):
+    """The reference with one mechanism taken away no longer agrees
+    with the model: the window on window layers, positions on RoPE
+    layers, the router reading the attention's input (fed the
+    feed-forward's instead). So the agreement above holds each."""
+    model, params = build(ep_size=4)
+    row = np.random.default_rng(2).integers(0, 96, 90)
+    got = np.asarray(model.apply(params, jnp.asarray(row[None])))[0]
+    if what == "window":
+        monkeypatch.setattr(ref, "WINDOW_LAYOUT", (0,) * 8)
+    elif what == "rope":
+        monkeypatch.setattr(ref, "ROPE_LAYOUT", (0,) * 8)
+    else:
+        def block(x, p, n_head, windowed, rotated):
+            x = x + ref.attention(ref.rms(x, p["ln_1"]), p, n_head,
+                                  windowed, rotated)
+            h2 = ref.rms(x, p["ln_2"])
+            return x + ref.experts(h2, h2 @ p["w_r"], p)
+        monkeypatch.setattr(ref, "block", block)
+    assert np.abs(got - ref_logits(ref, params, row)).max() > 1e-2
+
+
+def test_shares_add_up_to_the_whole_layer(ref):
+    """The routed parts of all four ranks are the uncut layer of the
+    reference, the router's logits handed in as the model hands them."""
+    whole, params = build(ep_size=1)
+    h = jax.random.normal(jax.random.PRNGKey(2), (24, 32), jnp.float32)
+    r = jax.random.normal(jax.random.PRNGKey(3), (24, 16), jnp.float32)
+    mlp = jax.tree.map(lambda a: a[0], params["runs"][1]["mlp"])
+    total = np.zeros((24, 32), np.float32)
+    picks_held = 0
+    for rank in range(4):
+        part, _ = build(ep_size=4, ep_rank=rank)
+        cut = dict(mlp)
+        for k in ("wg", "wu", "wd"):
+            cut[k] = mlp[k][rank * 4:(rank + 1) * 4]
+        y, counts = experts.expert_layer(h, cut, part.cfg, logits=r)
+        total += np.asarray(y)
+        picks_held += int(counts[1])
+        assert int(counts[0]) == 24 * 3
+    ref.EP_RANK = 0
+    layer = ref.from_program(params)["layers"][1]
+    want = np.asarray(ref.experts(h, r, layer))
+    np.testing.assert_allclose(total, want, atol=2e-5, rtol=2e-5)
+    # Every pick lands on exactly one rank, and the gates sum to 1.
+    assert picks_held == 24 * 3
+    _idx, g = experts.route(h, mlp, whole.cfg, logits=r)
+    np.testing.assert_allclose(np.asarray(g.sum(-1)), 1.0, atol=1e-6)
+
+
+CADENCES = {
+    "batched": dict(),
+    "sampled_top1": dict(temperature=0.7, top_k=1),
+    "spec": dict(spec_k=3),
+    "resident": dict(resident_k=4),
+    "resident_spec": dict(resident_k=3, spec_k=2),
+}
+PROMPTS = (150, 37, 5, 91)       # longer and shorter than the window
+NEW = 50
+
+
+def serve(model, params, cadence, prompts):
+    eng = Engine(model, params, EngineConfig(**ENGINE,
+                                             **CADENCES[cadence]))
+    for i, p in enumerate(prompts):
+        eng.submit(Request(id=f"r{i}", prompt=p, max_new_tokens=NEW))
+    records = []
+    for _ in range(2000):
+        if eng.idle:
+            break
+        records.append(eng.step())
+        # A sequence never holds more than a ring of window pages.
+        assert all(eng.cache.ring_pages_of(s.req.id)
+                   <= eng.cache.cfg.ring_pages
+                   for s in eng.slots if s is not None)
+    assert eng.idle
+    return eng, records, {d["id"]: d["tokens"] for d in eng.completed}
+
+
+def worst_gap(ref, params, prompts, done):
+    """The largest gap between the reference's top logit and its logit
+    of the streamed token, over every streamed token: logits, not
+    tokens."""
+    worst = 0.0
+    for i, p in enumerate(prompts):
+        toks = done[f"r{i}"]
+        seq = np.concatenate([p, np.asarray(toks, np.int32)])
+        rows = ref_logits(ref, params, seq[:-1])[len(p) - 1:]
+        assert len(rows) == len(toks) == NEW
+        worst = max(worst, max(float(row.max() - row[t])
+                               for row, t in zip(rows, toks)))
+    return worst
+
+
+def prompts_of(seed=7):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 96, n).astype(np.int32) for n in PROMPTS]
+
+
+@pytest.mark.parametrize("cadence", list(CADENCES))
+def test_engine_matches_the_reference(ref, cadence):
+    """Prefill in chunks of 8 (the prompt of 150 straddles the ring's
+    wrap at 40 rows three times), then 50 tokens of decode through the
+    two pools, up to 200 positions: five turns of the ring of 40. Every
+    streamed token is the argmax of the reference's full forward over
+    what came before it, to a logit gap that float32 rounding explains
+    (1e-4: the model's logits are of order 1 and float32 sums of a few
+    hundred terms differ in the sixth digit, so a streamed token's
+    logit lies that near the reference's largest or is it; the same
+    engine in bfloat16 reads 1.6e-3, below)."""
+    model, params = build(ep_size=4)
+    prompts = prompts_of()
+    eng, records, done = serve(model, params, cadence, prompts)
+    assert worst_gap(ref, params, prompts, done) < 1e-4
+    cfg = eng.cache.cfg
+    assert cfg.ring_pages == 10 and cfg.window_layers == (1, 2, 3, 5, 6, 7)
+    # Two pools: 2 global layers in the table's pages, 6 window layers
+    # in a ring a slot and a scratch page.
+    assert isinstance(eng.cache.k_pages, Pools)
+    assert eng.cache.k_pages.full.shape == (1, 2, 160, 4, 128)
+    assert eng.cache.v_pages.ring.shape == (1, 6, 3 * 10 + 1, 4, 128)
+    assert eng.cache.pages_used == 0          # free returned both kinds
+    decode = [r for r in records if r["op"] == "decode"]
+    assert decode and all(
+        r["pages_total_window"] == 30 and r["pages_total_global"] == 159
+        and r["pages_total"] == 189
+        and r["pages_used"] == (r["pages_used_window"]
+                                + r["pages_used_global"])
+        and 0 <= r["window_bound_iters"] <= r["slot_iters"]
+        and r["moe_picks"] > 0 for r in decode)
+    # The prompt of 150 is past the window from its first decode step.
+    assert sum(r["window_bound_iters"] for r in decode) > NEW
+    forms = eng.paged_forms()
+    assert all(set(f.split("+")) <= {"pool", "gather", "pool.window",
+                                     "gather.window"}
+               and any(part.endswith(".window") for part in f.split("+"))
+               for f in forms.values() if f), forms
+
+
+def test_bfloat16_fails_the_float32_tolerance(ref):
+    """The tolerance above is tight enough to see a lower precision:
+    the same engine in bfloat16 misses it tenfold."""
+    model, params = build(dtype="bfloat16", ep_size=4)
+    prompts = prompts_of()
+    _eng, _records, done = serve(model, params, "resident", prompts)
+    assert worst_gap(ref, params, prompts, done) > 1e-3
+
+
+def paged_logits(model, params, seq, sizes):
+    """The logits after every position of ``seq``, through the engine's
+    own chunk forward (``engine._chunk_hidden``: the two pools, the
+    ring's coordinates, the block, paged attention) fed ``sizes`` rows
+    at a time against an engine's cache: what the programs compute
+    before they take an argmax."""
+    from distributed_training_tpu.serving import engine as E
+
+    eng = Engine(model, params, EngineConfig(**ENGINE))
+    plan = E._plan(eng.block, eng.cfg, None)
+
+    @jax.jit
+    def forward(params, kp, vp, rows, tokens, start, n):
+        x, _valid, counts, kp, vp = E._chunk_hidden(
+            params, kp, vp, rows, tokens, start, n,
+            jnp.ones((1,), bool), block=eng.block, plan=plan)
+        return eng.block.logits(params, x), counts, kp, vp
+
+    cache, out, at = eng.cache, [], 0
+    kp, vp = (jax.tree.map(lambda p: p[0], pools)
+              for pools in (cache.k_pages, cache.v_pages))
+    cache.join("s")
+    for n in sizes:
+        assert cache.ensure("s", at + n)
+        width = max(sizes)
+        tokens = np.zeros((1, width), np.int32)
+        tokens[0, :n] = seq[at:at + n]
+        lg, counts, kp, vp = forward(
+            params, kp, vp, jnp.asarray(cache.page_rows(["s"])),
+            jnp.asarray(tokens), jnp.full((1,), at, jnp.int32),
+            jnp.full((1,), n, jnp.int32))
+        # The engine's own counter rides behind the block's five.
+        assert int(counts[-1]) == int(at + n > 32)
+        out.append(np.asarray(lg[0, :n], np.float32))
+        cache.advance("s", n)
+        at += n
+    assert at == len(seq)
+    return np.concatenate(out)
+
+
+FEEDS = {
+    # 24 chunks of 8, then one row at a time: the decode program's C = 1.
+    "chunks_then_one_token": [8] * 24 + [1] * 8,
+    # Chunks that start off a page and cross the ring's wrap unevenly.
+    "ragged_chunks": [5, 8, 3, 8, 8, 7] * 5 + [5],
+}
+
+
+@pytest.mark.parametrize("feed", list(FEEDS))
+def test_paged_logits_match_the_reference(ref, feed):
+    """Logits, not tokens: every position's, to what float32 rounding
+    explains (2e-4, as ``apply`` above), over 200 positions and five
+    turns of the ring; and the same in bfloat16 is off by a hundred
+    times that."""
+    sizes = FEEDS[feed]
+    seq = np.random.default_rng(11).integers(0, 96, sum(sizes))
+    model, params = build(ep_size=4)
+    want = ref_logits(ref, params, seq)
+    np.testing.assert_allclose(paged_logits(model, params, seq, sizes),
+                               want, atol=2e-4, rtol=2e-4)
+    low, _ = build(dtype="bfloat16", ep_size=4)
+    assert np.abs(paged_logits(low, params, seq, sizes) - want).max() \
+        > 2e-2
+
+
+def test_a_uniform_model_keeps_the_single_pool():
+    model = build_model("transformer", dtype="float32",
+                        attention_impl="naive", vocab_size=64, d_model=32,
+                        n_layers=2, n_heads=4, max_seq_len=32)
+    eng = Engine(model, model.init(jax.random.PRNGKey(0)), EngineConfig(
+        max_batch=2, page_size=4, num_pages=17, max_seq_len=32,
+        prefill_chunk=8))
+    assert not isinstance(eng.cache.k_pages, Pools)
+    assert eng.cache.cfg.ring_pages == 0 and eng.cache.cfg.row_width == 8
+    assert eng.cache.pages_total == 16 and eng.cache.pages_by_kind() == {}
+    assert eng._counters == ()
+
+
+@pytest.mark.parametrize("window", [0, 6])
+def test_dense_block_honours_its_attention_window(window):
+    """GPT-2's block served with ``attention_window``: every streamed
+    token is what the model's own windowed forward gives (the engine
+    used to drop the window without a word)."""
+    kw = dict(vocab_size=64, d_model=32, n_layers=2, n_heads=4,
+              max_seq_len=48, pos_encoding="rope",
+              attention_window=window)
+    model = build_model("transformer", dtype="float32",
+                        attention_impl="naive", **kw)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = Engine(model, params, EngineConfig(
+        max_batch=2, page_size=4, num_pages=25, max_seq_len=48,
+        prefill_chunk=8, resident_k=4))
+    prompt = np.arange(3, 24, dtype=np.int32)
+    got = np.asarray(eng.generate(prompt, 12))
+    full = build_model("transformer", dtype="float32",
+                       attention_impl="naive", **{**kw,
+                                                  "attention_window": 0})
+    seq, differs = prompt, False
+    for tok in got:
+        lg = model.apply(params, jnp.asarray(seq)[None])[0][0, -1]
+        assert float(lg.max() - lg[tok]) < 1e-4
+        other = full.apply(params, jnp.asarray(seq)[None])[0][0, -1]
+        differs |= bool(np.abs(np.asarray(lg - other)).max() > 1e-3)
+        seq = np.append(seq, tok)
+    assert differs == bool(window)
+
+
+def ring_case(B, S, R, window, last, seed=0, ps=4, H=4, Hkv=2, hd=8):
+    """``B`` sequences whose newest query is at position ``last[b]``,
+    ``S`` queries each, over rings of ``R`` pages holding the rows the
+    engine would have left there: position ``p`` in ring slot ``p %
+    (R * ps)``. Returns the call's arguments and the dense keys and
+    values by position."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    T = max(last) + 1
+    k = jax.random.normal(ks[0], (B, T, Hkv, hd), jnp.float32)
+    v = jax.random.normal(ks[1], (B, T, Hkv, hd), jnp.float32)
+    q = jax.random.normal(ks[2], (B, S, H, hd), jnp.float32)
+    N = B * R + 1
+    kp = np.zeros((Hkv, N, ps, hd), np.float32)
+    vp = np.zeros((Hkv, N, ps, hd), np.float32)
+    rows = np.arange(1, N, dtype=np.int32).reshape(B, R)
+    for b in range(B):
+        for p in range(last[b] + 1):        # later rows overwrite
+            page, off = rows[b, p // ps % R], p % ps
+            kp[:, page, off] = np.asarray(k[b, p])
+            vp[:, page, off] = np.asarray(v[b, p])
+    q_pos = np.stack([np.arange(n - S + 1, n + 1) for n in last]
+                     ).astype(np.int32)
+    q_pos[0, 0] = -1                        # a dead query
+    return (q, as_layer(jnp.asarray(kp)), as_layer(jnp.asarray(vp)),
+            jnp.asarray(rows), jnp.asarray(q_pos)), k, v
+
+
+@pytest.mark.parametrize("form", ["pool", "gather"])
+@pytest.mark.parametrize("S,last", [(1, (70, 9, 41)), (8, (70, 12, 43)),
+                                    (3, (39, 40, 41))])
+def test_ring_attention_forms_agree(monkeypatch, form, S, last):
+    """Each form over a ring of 10 pages of 4 against attention written
+    out over the dense keys: one query a slot, a chunk of 8 that
+    straddles the wrap (positions 36..43), sequences shorter than the
+    window and the ring, a dead query."""
+    window, R = 32, 10
+    args, k, v = ring_case(3, S, R, window, last)
+    q, _kp, _vp, _rows, q_pos = args
+    monkeypatch.setattr(pa, "chunk_form", lambda *a, **kw: form)
+    with pa.observe_forms() as seen:
+        got = pa.paged_attention_chunk(*args, window=window, ring=True)
+    assert seen == [form + ".window"]
+    T = k.shape[1]
+    kk, vv = (jnp.repeat(a, 2, axis=2) for a in (k, v))
+    scores = jnp.einsum("bshd,bkhd->bhsk", q, kk) / 8 ** 0.5
+    back = q_pos[:, :, None] - jnp.arange(T)[None, None, :]
+    seen_k = (back >= 0) & (back < window)
+    scores = jnp.where(seen_k[:, None], scores, -jnp.inf)
+    want = jnp.einsum("bhsk,bkhd->bshd", jax.nn.softmax(scores, -1), vv)
+    want = jnp.where((q_pos >= 0)[:, :, None, None], want, 0.0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_query_blocks_agree_with_one_pass(monkeypatch):
+    """Queries taken a block at a time (where all their logits at once
+    would be too many) give what one pass gives."""
+    args, _k, _v = ring_case(2, 8, 10, 32, (70, 43), seed=3)
+    one = pa.paged_attention_chunk(*args, window=32, ring=True)
+    monkeypatch.setattr(pa, "_LOGITS_LIMIT", 1 << 10)
+    assert pa._query_blocks(args[0].shape, 40) == 8
+    np.testing.assert_allclose(
+        np.asarray(pa.paged_attention_chunk(*args, window=32, ring=True)),
+        np.asarray(one), atol=1e-6)
+
+
+def test_query_blocks_leave_the_old_engines_alone():
+    """No shape of the cells the benchmark had reaches the limit."""
+    assert pa._query_blocks((4, 128, 25, 64), 64 * 16) == 1
+    assert pa._query_blocks((16, 1, 25, 64), 385 * 16) == 1
+    assert pa._query_blocks((1, 1024, 28, 128), 16384) == 8
+
+
+def cache(**over):
+    return PagedKVCache(PagedCacheConfig(**{**dict(
+        n_layers=4, n_kv_heads=2, head_dim=8, page_size=4, num_pages=40,
+        max_seq_len=64, window=8, window_layers=(1, 2, 3), max_write=4,
+        slots=2, block="WindowBlock"), **over}))
+
+
+def test_the_cache_counts_both_pools():
+    c = cache()
+    assert c.cfg.ring_pages == 3 and c.cfg.row_width == 16 + 3
+    assert c.cfg.window_num_pages == 2 * 3 + 1
+    assert c.pages_total == 39 + 6 and c.pages_used == 0
+    c.join("a")
+    assert c.ensure("a", 5)                 # 2 pages of each kind
+    assert (c.pages_of("a"), c.ring_pages_of("a")) == (4, 2)
+    assert c.ensure("a", 40)                # 10 pages; the ring stops at 3
+    assert (c.pages_of("a"), c.ring_pages_of("a")) == (13, 3)
+    row = c.page_row("a")
+    assert (row[:10] > 0).all() and (row[10:16] == 0).all()
+    assert (row[16:] > 0).all()
+    c.advance("a", 6)
+    assert c.trim("a", 6) == 8 + 1          # 2 pages of each kind stay
+    assert (c.pages_of("a"), c.ring_pages_of("a")) == (4, 2)
+    by_kind = c.pages_by_kind()
+    assert by_kind == {"pages_used_global": 2, "pages_total_global": 39,
+                       "pages_used_window": 2, "pages_total_window": 6}
+    assert c.occupancy()["pages_used"] == 4
+    c.join("b")
+    assert c.ensure("b", 64) and c.token_capacity("b") == 64
+    c.join("c")
+    # The window pool holds a ring a slot: a third sequence finds the
+    # global pool willing and the window pool short, and takes nothing.
+    assert not c.can_admit(8) and not c.ensure("c", 8)
+    assert c.pages_of("c") == 0
+    assert c.free("a") == 4 and c.free("b") == 16 + 3 and c.free("c") == 0
+    assert c.pages_used == 0
+    assert [p["kind"] for p in c.pools()] == ["global", "window"]
+    assert c.pools()[1]["ring_pages"] == 3
+    assert c.footprint()["pool_bytes_tiled"] == sum(
+        p["bytes"] for p in c.pools())
+
+
+@pytest.mark.parametrize("feature", [
+    "attach", "register_prefix", "privatize", "rename", "read_pages",
+    "write_pages", "prefix_sharing", "adopt_batch", "session",
+    "export_kv", "import_kv"])
+def test_what_moves_pages_by_one_table_refuses_window_layers(feature):
+    """Each names the block and what it is; none reads a ring as a
+    table."""
+    from distributed_training_tpu.serving import disagg
+
+    c = cache()
+    c.join("a")
+    c.ensure("a", 8)
+    c.advance("a", 8)
+    model, params = build(ep_size=4)
+    calls = {
+        "attach": lambda: c.attach("a", [1], 4),
+        "register_prefix": lambda: c.register_prefix("a", list(range(8))),
+        "privatize": lambda: c.privatize("a"),
+        "rename": lambda: c.rename("a", "b"),
+        "read_pages": lambda: c.read_pages(np.zeros(1, np.int32),
+                                           np.ones(1, np.int32)),
+        "write_pages": lambda: c.write_pages(
+            np.zeros(1, np.int32), np.ones(1, np.int32),
+            np.zeros((1, 4, 2, 4, 8), np.float32),
+            np.zeros((1, 4, 2, 4, 8), np.float32)),
+        "export_kv": lambda: disagg.export_kv(c, "a"),
+        "import_kv": lambda: disagg.import_kv(
+            c, "a", np.zeros((4, 2, 8, 8), np.float32),
+            np.zeros((4, 2, 8, 8), np.float32)),
+        "prefix_sharing": lambda: Engine(model, params, EngineConfig(
+            **{**ENGINE, "prefix_sharing": True})),
+        "adopt_batch": lambda: Engine(
+            model, params, EngineConfig(**ENGINE)).adopt_batch([]),
+        "session": lambda: Engine(
+            model, params, EngineConfig(**ENGINE)).submit(Request(
+                id="s", prompt=np.arange(4, dtype=np.int32),
+                max_new_tokens=2, session="chat")),
+    }
+    with pytest.raises(NotImplementedError,
+                       match="WindowBlock has window layers"):
+        calls[feature]()
+
+
+@pytest.mark.parametrize("how", ["preempt", "export_in_flight"])
+def test_preemption_round_trips(ref, how):
+    """What frees pages and starts again carries both rows: a storm
+    preempted (or exported: a block with window layers has no dense
+    export, so everything comes back as a fresh request) mid-decode
+    returns every page of both pools and, resubmitted, streams what an
+    undisturbed engine streams."""
+    model, params = build(ep_size=4)
+    prompts = prompts_of()[:2]
+    _eng, _records, want = serve(model, params, "resident", prompts)
+    eng = Engine(model, params, EngineConfig(**ENGINE, resident_k=4))
+    for i, p in enumerate(prompts):
+        eng.submit(Request(id=f"r{i}", prompt=p, max_new_tokens=NEW))
+    while not any(s is not None and len(s.generated) > 10
+                  for s in eng.slots):
+        eng.step()
+    if how == "preempt":
+        lost = eng.preempt()
+    else:
+        out = eng.export_in_flight()
+        assert out["adoptable"] == []
+        lost = out["requests"]
+    assert len(lost) == 2 and eng.cache.pages_used == 0
+    for req in lost:
+        eng.submit(req)
+    eng.run_until_drained()
+    got = {d["id"]: d["tokens"] for d in eng.completed}
+    assert got == want and eng.cache.pages_used == 0
+
+
+def test_generate_cli_path_serves_the_model():
+    """``generate.py``'s engine (one slot, no prefix sharing) gives the
+    full forward's argmax through ``build_model`` and ``Engine``."""
+    model, params = build(ep_size=4)
+    eng = Engine(model, params, EngineConfig(
+        max_batch=1, page_size=16, num_pages=9, max_seq_len=128,
+        prefill_chunk=64, prefix_sharing=False))
+    prompt = np.random.default_rng(4).integers(0, 96, 70).astype(np.int32)
+    got = eng.generate(prompt, 20)
+    plain = np.asarray(model.generate(params, jnp.asarray(prompt)[None],
+                                      20))[0]
+    assert got == plain.tolist()
+
+
+def test_reference_constants_are_the_configuration_files():
+    ref = common.load_reference({"reference": "window_moe"})
+    with open(os.path.join(ROOT, "perfbench", "configs",
+                           "smallthinker-21b-ep4.json")) as f:
+        conf = json.load(f)
+    for const, key in [("N_KV_HEAD", "num_key_value_heads"),
+                       ("HEAD_DIM", "head_dim"),
+                       ("WINDOW", "sliding_window_size"),
+                       ("ROPE_THETA", "rope_theta"),
+                       ("RMS_NORM_EPS", "rms_norm_eps"),
+                       ("NUM_EXPERTS_PER_TOK",
+                        "moe_num_active_primary_experts")]:
+        assert getattr(ref, const) == conf[key], const
+    layers = conf["num_hidden_layers"]
+    assert list(ref.WINDOW_LAYOUT) == conf["sliding_window_layout"][:layers]
+    assert list(ref.ROPE_LAYOUT) == conf["rope_layout"][:layers]
+    kw = conf["program"]["kwargs"]
+    assert ref.EP_RANK == kw["ep_rank"]
+    cfg = build_model(conf["program"]["build_model"], **kw).cfg
+    assert cfg.experts_held == conf["moe_num_primary_experts"] == 16
+    assert cfg.n_routed_experts == 64 and cfg.ep_size == 4
+    assert conf["n_head"] == cfg.n_heads == conf["num_attention_heads"]
+    assert conf["n_positions"] == cfg.max_seq_len \
+        == conf["max_position_embeddings"] \
+        == conf["serving"]["engine"]["max_seq_len"]
+    assert list(cfg.window_layout) == list(ref.WINDOW_LAYOUT)
+    assert list(cfg.rope_layout) == list(ref.ROPE_LAYOUT)
+    for ours, theirs in [("d_model", "hidden_size"),
+                         ("n_kv_heads", "num_key_value_heads"),
+                         ("head_dim", "head_dim"),
+                         ("moe_d_ff", "moe_ffn_hidden_size"),
+                         ("moe_top_k", "moe_num_active_primary_experts"),
+                         ("n_layers", "num_hidden_layers"),
+                         ("vocab_size", "vocab_size"),
+                         ("window", "sliding_window_size"),
+                         ("rope_theta", "rope_theta"),
+                         ("rms_norm_eps", "rms_norm_eps")]:
+        assert getattr(cfg, ours) == conf[theirs], ours
+    assert conf["program"]["token_vocab"] == cfg.vocab_size
+    # The yaml for generate.py and the server says the same.
+    import yaml
+    with open(os.path.join(ROOT, "conf", "model",
+                           "smallthinker_21b_ep4.yaml")) as f:
+        assert yaml.safe_load(f)["kwargs"] == kw
+    # The engine's two pools at the file's geometry.
+    from distributed_training_tpu.serving import engine as E
+    ccfg = E._cache_config(build_model(
+        conf["program"]["build_model"], **kw).serving_block(),
+        EngineConfig(**conf["serving"]["engine"]), None, "bfloat16")
+    assert ccfg.ring_pages == 320 and ccfg.window_num_pages == 10241
+    assert ccfg.num_pages == 32 * ccfg.pages_per_seq + 1
+    assert ccfg.kv_bytes_per_token() == 12 * 2048
+
+
+@pytest.mark.parametrize("resident_k", [1, 4])
+def test_prefill_lanes_go_first_come_first_served(resident_k):
+    """A prompt that is being prefilled keeps the one lane when a later
+    request lands in a lower slot: the lanes go by admission, not by
+    slot (which slot a request finds free is chance, and with it the
+    order of two waiting prompts and every time after)."""
+    model, params = build(ep_size=4)
+    eng = Engine(model, params, EngineConfig(
+        **{**ENGINE, "prefill_slots": 1}, resident_k=resident_k))
+    rng = np.random.default_rng(3)
+    short, long_, late = (rng.integers(0, 96, n).astype(np.int32)
+                          for n in (4, 40, 24))
+    firsts = []
+    for rid in ("short", "long", "late"):
+        eng.add_token_listener(
+            rid, lambda tok, done, rid=rid: rid in firsts
+            or firsts.append(rid))
+    eng.submit(Request(id="short", prompt=short, max_new_tokens=1))
+    eng.submit(Request(id="long", prompt=long_, max_new_tokens=2))
+    submitted = False
+    for _ in range(200):
+        eng.step()
+        if not submitted and eng.slots[0] is None:
+            # ``short`` is gone from slot 0 and ``long`` is in slot 1,
+            # somewhere in its five chunks: ``late`` lands in slot 0.
+            assert eng.slots[1] is not None \
+                and eng.slots[1].req.id == "long"
+            eng.submit(Request(id="late", prompt=late, max_new_tokens=2))
+            submitted = True
+        if submitted and eng.idle:
+            break
+    assert eng.idle and submitted
+    assert {d["id"] for d in eng.completed} == {"short", "long", "late"}
+    assert firsts == ["short", "long", "late"]
