@@ -7,8 +7,12 @@ call, at the shapes the serving engines trace (PERF.md section 6).
 
 Prints one JSON line a shape (``gather_ms``, ``pool_ms``, the form the
 rule takes, the faster form) and writes them to
-``chiprun_out/paged_form_table.json``. Times are of twenty calls after
-one, a layer alone: what decides between two forms, not a benchmark
+``chiprun_out/paged_form_table.json``. Then the many-query rows: the
+flash form (the kernel ``dtt_paged_prefill``) against the XLA form,
+queries a block at a time where one pass would not fit, at the shapes
+of a prompt chunk (``xla_ms``, ``flash_ms``): what decides whether
+``_LOGITS_LIMIT`` could come down. Times are of twenty calls after
+one, a layer alone: what decides between forms, not a benchmark
 result.
 """
 
@@ -41,6 +45,23 @@ SHAPES = {
     "xl.gqa_16x1": dict(B=16, S=1, H=25, Hkv=5, P=64, N=385),
 }
 
+# The flash form's rows: smallthinker-21b-ep4's chunk of 1024 (28 query
+# heads over 4 kv heads of 128) over a window layer's ring of 320 pages
+# after its first turn and over a global layer's table of 1024 pages at
+# a median and at the longest prompt, and gpt2-xl's 4 x 128 prefill
+# over 1024 slots, which stays under the rule.
+THINKER = dict(B=1, S=1024, H=28, Hkv=4, hd=128)
+MANY = {
+    "thinker.ring_1x1024": dict(**THINKER, P=320, N=10241, window=4096,
+                                ring=True, start=8192),
+    "thinker.table_1x1024_at_4096": dict(**THINKER, P=1024, N=32769,
+                                         start=4096),
+    "thinker.table_1x1024_at_11264": dict(**THINKER, P=1024, N=32769,
+                                          start=11264),
+    "xl.prefill_batch_4x128": dict(B=4, S=128, H=25, Hkv=25, hd=64,
+                                   P=64, N=385, start=512),
+}
+
 
 def main() -> int:
     import jax
@@ -56,6 +77,12 @@ def main() -> int:
         row = {"name": name, **chip_smoke.paged_forms_case(**shape)}
         row["faster"] = ("pool" if row["pool_ms"] < row["gather_ms"]
                          else "gather")
+        print(json.dumps(row), flush=True)
+        rows.append(row)
+    for name, shape in MANY.items():
+        row = {"name": name, **chip_smoke.paged_prefill_case(**shape)}
+        row["faster"] = ("flash" if row["flash_ms"] < row["xla_ms"]
+                         else "xla")
         print(json.dumps(row), flush=True)
         rows.append(row)
     out = os.path.join(REPO, "chiprun_out")

@@ -11,8 +11,9 @@ full width of gpt2_125m (12 layers, d_model 768, 12 heads of 64, vocab
    (not interpreted) and compared with the naive reference at bf16
    tolerance: flash forward + fused backward at S 1024 / D 64, the
    two-kernel split backward where the fused one does not fit VMEM, a
-   sliding window, GQA; and both forms of paged attention at two of
-   gpt2-xl's engine shapes against each other;
+   sliding window, GQA; both forms of paged attention at two of
+   gpt2-xl's engine shapes against each other, and its flash form (the
+   kernel of a prompt chunk) against the XLA form over a ring;
 2. trainer — ``distributed_training_tpu.train.cli.main`` takes a few
    steps at batch 32 / seq 1024 / bf16 / AdamW with the telemetry, the
    collectives audit and the checkpoint code a user gets;
@@ -188,6 +189,21 @@ def flash_case(B, H, Hkv, S, D, window=0, expect_fused=True):
     return make("flash"), make("naive"), inputs, label
 
 
+def _smoke_time(fn, args, reps: int):
+    """``fn`` compiled for ``args``: the compiled program, what one
+    call gives and the wall milliseconds a call of ``reps`` calls after
+    it."""
+    import jax
+
+    run = jax.jit(fn).lower(*args).compile()
+    out = jax.block_until_ready(run(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        last = run(*args)
+    jax.block_until_ready(last)
+    return run, out, (time.perf_counter() - t0) / reps * 1e3
+
+
 def paged_forms_case(B, S, H, Hkv, P, N, hd=64, ps=16, reps=20) -> dict:
     """Both forms of ``paged_attention_chunk`` (gather, pool) on one
     layer's bf16 pool at an engine's shapes, against each other: the
@@ -223,13 +239,7 @@ def paged_forms_case(B, S, H, Hkv, P, N, hd=64, ps=16, reps=20) -> dict:
     out, ms = {}, {}
     for form, fn in (("gather", pa._gather_attention),
                      ("pool", pa._pool_attention)):
-        run = jax.jit(fn).lower(*args).compile()
-        out[form] = jax.block_until_ready(run(*args))
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            last = run(*args)
-        jax.block_until_ready(last)
-        ms[form] = (time.perf_counter() - t0) / reps * 1e3
+        _run, out[form], ms[form] = _smoke_time(fn, args, reps)
     diff = float(jnp.abs(out["pool"].astype(jnp.float32)
                          - out["gather"].astype(jnp.float32)).max())
     band = _close("paged_forms", out["pool"], out["gather"])
@@ -244,6 +254,88 @@ def paged_forms_case(B, S, H, Hkv, P, N, hd=64, ps=16, reps=20) -> dict:
             f"forms differ beyond the bf16 band: {diff} ({band})")
     return {"ok": True, "shape": label, "gather_ms": ms["gather"],
             "pool_ms": ms["pool"], "max_abs_diff": diff, "rule": rule}
+
+
+def paged_prefill_case(B, S, H, Hkv, P, N, hd=128, ps=16, window=None,
+                       ring=False, start=0, reps=20, blocks=None) -> dict:
+    """The flash form of ``paged_attention_chunk`` (the Pallas kernel
+    ``dtt_paged_prefill``) against the XLA form it took the place of,
+    the queries a block at a time wherever the float32 logits of one
+    pass would not fit, on one layer's bf16 pool: a prompt chunk of
+    ``S`` rows from position ``start`` on, a sequence, over a table of
+    ``P`` pages or a ring of them that has turned ``start // (P * ps)``
+    times, the chunk's own rows written and the last sequence dead.
+    The worst absolute difference, each form's smoke time a call, and
+    the form the rule takes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_training_tpu.ops import paged_attention as pa
+    from distributed_training_tpu.serving.kv_cache import as_layer
+
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 3), 3)
+    q = jax.random.normal(ks[0], (B, S, H, hd), jnp.bfloat16)
+    kp, vp = (as_layer(jax.random.normal(k, (Hkv, N, ps, hd),
+                                         jnp.bfloat16))
+              for k in ks[1:])
+    rng = np.random.default_rng(SEED + 3)
+    tables = (rng.permutation(N - 1)[:B * P].reshape(B, P) + 1
+              ).astype(np.int32)
+    q_pos = np.tile(start + np.arange(S, dtype=np.int32), (B, 1))
+    if B > 1:
+        q_pos[-1] = -1
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(q_pos))
+    slots = P * ps
+
+    def by_blocks(q, kp, vp, tables, q_pos):
+        """PR 32's ``_tile_attention``: one pass, or a ``lax.map`` over
+        the smallest power-of-two number of query blocks whose logits
+        fit."""
+        n = S
+        while not pa._one_pass_fits((B, n, H, hd), slots) \
+                and n % 2 == 0:
+            n //= 2
+        kd, vd = kp.pages(tables), vp.pages(tables)
+        slot = jnp.arange(slots, dtype=jnp.int32)[None]
+
+        def one(lo):
+            rows, at = (jax.lax.dynamic_slice_in_dim(x, lo, n, axis=1)
+                        for x in (q, q_pos))
+            return pa._tile_attention(
+                kp.layout, rows, kd, vd,
+                pa._visible(at, slot, window, slots if ring else None))
+        out = jax.lax.map(one, jnp.arange(S // n, dtype=jnp.int32) * n)
+        return out.transpose(1, 0, 2, 3, 4).reshape(q.shape)
+
+    def flash(*args):
+        return pa._flash_attention(*args, window, ring, blocks)
+
+    out, ms = {}, {}
+    for form, fn in (("xla", by_blocks), ("flash", flash)):
+        run, out[form], ms[form] = _smoke_time(fn, args, reps)
+        if form == "flash" and "dtt_paged_prefill" not in run.as_text():
+            raise AssertionError("no dtt_paged_prefill custom call in "
+                                 "the compiled flash form")
+    diff = float(jnp.abs(out["flash"].astype(jnp.float32)
+                         - out["xla"].astype(jnp.float32)).max())
+    band = _close("paged_prefill", out["flash"], out["xla"])
+    with pa.observe_forms() as seen:
+        jax.eval_shape(lambda *a: pa.paged_attention_chunk(
+            *a, window=window, ring=ring), *args)
+    label = (f"{B} x {S}, H{H}/{Hkv} D{hd}, "
+             f"{'ring' if ring else 'table'} of {slots} slots"
+             + (f", window {window}" if window else "")
+             + f", from {start}")
+    say(f"  paged prefill [{label}]: xla {ms['xla']:.3f} ms, flash "
+        f"{ms['flash']:.3f} ms a call (smoke wall), worst |diff| "
+        f"{diff:.4f} ({band:.3f} of the bf16 band), rule -> {seen[0]}")
+    if band > 1.0:
+        raise AssertionError(
+            f"forms differ beyond the bf16 band: {diff} ({band})")
+    return {"ok": True, "shape": label, "xla_ms": ms["xla"],
+            "flash_ms": ms["flash"], "max_abs_diff": diff,
+            "rule": seen[0]}
 
 
 def compare_case(name: str, run, ref, inputs, label: str) -> dict:
@@ -287,6 +379,11 @@ def phase_kernels() -> dict:
          lambda: paged_forms_case(16, 1, 25, 25, P=64, N=385)),
         ("paged_forms_xl_16x4",
          lambda: paged_forms_case(16, 4, 25, 25, P=64, N=385)),
+        # smallthinker-21b-ep4's prompt chunk over a window layer's
+        # ring past its first turn: the flash form's kernel.
+        ("paged_prefill_ring_1x1024",
+         lambda: paged_prefill_case(1, 1024, 28, 4, P=320, N=10241,
+                                    window=4096, ring=True, start=8192)),
     ])
     results = {}
     for name, case in cases.items():

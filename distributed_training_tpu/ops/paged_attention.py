@@ -30,9 +30,9 @@ One entrypoint over keys and values:
   to logical positions ``<= its own position``; single-token decode is
   ``S = 1``. Every engine program calls it once a layer. Exact same
   numerics contract as ops/attention.py: fp32 logits/softmax, output
-  in q.dtype, GQA via hkv-major grouping. It has two forms with the
-  same mathematics, and ``chunk_form`` takes the cheaper from the
-  static shapes when the program is traced:
+  in q.dtype, GQA via hkv-major grouping. It has three forms with the
+  same mathematics. ``chunk_form`` takes the cheaper of the first two
+  from the static shapes when the program is traced:
 
   - *gather form* reads ``B * P * ps`` slots: every sequence's whole
     table row copied dense in logical order, whatever is live,
@@ -50,6 +50,18 @@ One entrypoint over keys and values:
     form's per-sequence dot has one row, and the TPU compiler lowers
     it to a float32 copy of the gathered block and a multiply-reduce.
 
+  Both hold the float32 logits of all their queries at once. Where
+  those would pass ``_LOGITS_LIMIT`` (a prompt chunk of 1024 against a
+  ring of 5,120 slots or a table of 16,384) the call takes the third:
+
+  - *flash form*: the gather form's copy, attended by one Pallas TPU
+    kernel (``dtt_paged_prefill``, ``_flash_attention``) that takes
+    the queries and the slots a block at a time and keeps the softmax
+    online as ``ops/flash_attention.py`` does for training, so no
+    logits are ever held in HBM, the mask is made in the kernel and the
+    key blocks no query of a block sees are skipped. The many-query
+    half of the ragged kernel.
+
   The ragged kernel that reads only the pages a sequence owns is the
   end state (ROADMAP S1).
 
@@ -66,18 +78,26 @@ One entrypoint over keys and values:
   mask ``(q - s) mod (P * ps) < window`` needs nothing but the query's
   position and the slot's place.
 
-There is no switch between the forms. The form each compiled program
-took (``"pool"``, ``"gather"``) is seen at trace time by
-``observe_forms`` and reported per program by ``Engine.paged_forms()``
-and the ``serving_warmup`` telemetry record (docs/observability.md).
+There is no switch between the forms: static shapes decide, so a
+compiled program takes a form always or never. The form each took
+(``"pool"``, ``"gather"``, ``"flash"``; ``".window"`` behind it over a
+ring) is seen at trace time by ``observe_forms`` and reported per
+program by ``Engine.paged_forms()`` and the ``serving_warmup`` telemetry
+record (docs/observability.md).
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from distributed_training_tpu.ops.flash_attention import (
+    NEG_INF, _platform_is_tpu)
 
 
 _observers: list[list[str]] = []
@@ -86,7 +106,7 @@ _observers: list[list[str]] = []
 @contextlib.contextmanager
 def observe_forms():
     """Collect, while open, the form every ``paged_attention_chunk``
-    (``"pool"``, ``"gather"``) and ``latent_attention_chunk``
+    (``"pool"``, ``"gather"``, ``"flash"``) and ``latent_attention_chunk``
     (``"absorbed"``, ``"expanded"``) call takes. The form follows from
     static shapes, so a call is seen when the program around it is
     TRACED: the engine opens this around each program's body
@@ -116,30 +136,26 @@ def _masked_softmax(logits: jax.Array, visible: jax.Array
                      0.0)
 
 
-# Float32 logits one call of ``_tile_attention`` may hold before it
-# takes its queries a block at a time: a prompt chunk of 1024 against a
-# table of 16,384 slots is 1.9 GB of them a layer, beside the pools. No
-# shape of the engines the benchmark had before PR 32 reaches it.
+# Float32 logits one call may hold in one pass. A prompt chunk of 1024
+# against a table of 16,384 slots is 1.9 GB of them a layer, beside the
+# pools: over the limit the call takes the flash form, which holds
+# none. No shape of the engines the benchmark had before PR 32 reaches
+# it.
 _LOGITS_LIMIT = 256 << 20
 
 
-def _query_blocks(shape, slots: int) -> int:
-    """How many blocks ``_tile_attention`` cuts the queries of q
-    ``(B, S, H, hd)`` into against ``slots`` keys: the smallest power
-    of two that divides ``S`` and brings the float32 logits under
-    ``_LOGITS_LIMIT`` (1: one pass, the only form before PR 32)."""
-    B, S, H, _ = shape
-    blocks = 1
-    while (B * (S // blocks) * H * slots * 4 > _LOGITS_LIMIT
-           and S % (2 * blocks) == 0):
-        blocks *= 2
-    return blocks
+def _one_pass_fits(q_shape, slots: int) -> bool:
+    """Whether the float32 logits of q ``(B, S, H, hd)`` against
+    ``slots`` keys a sequence stay under ``_LOGITS_LIMIT``: the one
+    static rule that says where the kernel runs."""
+    B, S, H, _ = q_shape
+    return B * S * H * slots * 4 <= _LOGITS_LIMIT
 
 
 def _tile_attention(layout, q: jax.Array, k: jax.Array,
-                    v: jax.Array, visible) -> jax.Array:
-    """GQA attention with an explicit visibility mask, on the pool's
-    tiles as they lie (``serving/kv_cache.py::PoolLayout``).
+                    v: jax.Array, visible: jax.Array) -> jax.Array:
+    """GQA attention with an explicit visibility mask in one pass, on
+    the pool's tiles as they lie (``serving/kv_cache.py::PoolLayout``).
 
     q (B, S, H, hd); k/v ``(B, Sk, tiles, tile)``, or ``(Sk, tiles,
     tile)`` shared by every sequence (the pool form); visible (B, S,
@@ -150,29 +166,7 @@ def _tile_attention(layout, q: jax.Array, k: jax.Array,
     logits/softmax (ops/attention.py numerics contract), output in
     q.dtype. Rows with zero visible keys (inactive batch slots)
     produce zeros, not NaN — the engine masks their outputs anyway,
-    but NaN would poison debugging.
-
-    ``visible`` is a function of the queries' rows ``(lo, n)`` (``lo``
-    None: all of them) giving the mask of those ``n`` queries ``(B, n,
-    Sk)``, so that where the
-    logits of all queries at once would be too many
-    (``_query_blocks``) the queries go a block at a time and neither
-    the logits nor the mask of all of them is ever held."""
-    B, S = q.shape[:2]
-    blocks = _query_blocks(q.shape, k.shape[-3])
-    if blocks > 1:
-        n = S // blocks
-
-        def one(lo):
-            rows = jax.lax.dynamic_slice_in_dim(q, lo, n, axis=1)
-            return _tile_attention_block(layout, rows, k, v,
-                                         visible(lo, n))
-        out = jax.lax.map(one, jnp.arange(blocks, dtype=jnp.int32) * n)
-        return out.transpose(1, 0, 2, 3, 4).reshape(q.shape)
-    return _tile_attention_block(layout, q, k, v, visible(None, S))
-
-
-def _tile_attention_block(layout, q, k, v, visible):
+    but NaN would poison debugging."""
     qt = layout.spread(q)                     # (B, S, tiles, J, tile)
     kv = "bktl" if k.ndim == 4 else "ktl"
     # Batched over the tile with B*S*J rows a tile: an MXU dot in the
@@ -233,28 +227,24 @@ def chunk_form(q_shape, pool_shape, table_shape, itemsize: int) -> str:
 
 
 def _visible(q_positions: jax.Array, slot_pos: jax.Array,
-             window, ring_slots):
-    """The mask maker ``_tile_attention`` wants: queries at
+             window, ring_slots) -> jax.Array:
+    """The mask ``_tile_attention`` wants, ``(B, S, Sk)``: queries at
     ``q_positions (B, S)`` (negative: a dead query, sees nothing)
     against slots that hold positions ``slot_pos (B or 1, Sk)``, or,
     of a ring of ``ring_slots`` slots, are at places ``slot_pos`` in it
     (``ring_slots`` and past: no slot of this sequence's). A query sees
     the positions up to its own, the last ``window`` of them where one
     is given."""
-    def mask(lo, n):
-        qp = (q_positions if lo is None else
-              jax.lax.dynamic_slice_in_dim(q_positions, lo, n, axis=1)
-              )[:, :, None]
-        at = slot_pos[:, None, :]
-        if ring_slots is None:
-            seen = at <= qp
-            if window:
-                seen &= at > qp - window
-        else:
-            back = jnp.mod(qp - at, ring_slots)   # rows behind the query
-            seen = (back < window) & (back <= qp) & (at < ring_slots)
-        return seen & (qp >= 0)
-    return mask
+    qp = q_positions[:, :, None]
+    at = slot_pos[:, None, :]
+    if ring_slots is None:
+        seen = at <= qp
+        if window:
+            seen &= at > qp - window
+    else:
+        back = jnp.mod(qp - at, ring_slots)   # rows behind the query
+        seen = (back < window) & (back <= qp) & (at < ring_slots)
+    return seen & (qp >= 0)
 
 
 def _gather_attention(q: jax.Array, k_pages, v_pages,
@@ -309,6 +299,187 @@ def _pool_attention(q: jax.Array, k_pages, v_pages,
                  P * ps if ring else None))
 
 
+# Rows of a query block times slots of a key block whose float32 logits
+# (4 MiB, with their mask and their exponentials beside them) and the
+# double-buffered blocks fit a v5e's VMEM: ``flash_attention.
+# default_blocks``' reading, the key block the larger.
+_FLASH_ROWS = 1024
+_FLASH_SLOTS = 1024
+
+
+def _flash_blocks(S: int, J: int, Sk: int) -> tuple:
+    """``(block_q, block_k)`` of ``_flash_attention`` for ``S`` queries
+    of ``J`` rows a tile against ``Sk`` slots: a power of two of
+    queries whose rows stay under ``_FLASH_ROWS`` (16 at least: a
+    bfloat16 block's sublanes), and the largest key block that divides
+    the slots once they are padded to whole 128s."""
+    block_q = 16
+    while block_q * 2 * J <= _FLASH_ROWS and block_q < S:
+        block_q *= 2
+    padded = -(-Sk // 128) * 128
+    block_k = next(b for b in (_FLASH_SLOTS, 512, 256, 128)
+                   if padded % b == 0)
+    return block_q, block_k
+
+
+def _flash_kernel(first_ref, count_ref, pos_ref, q_ref, k_ref, v_ref,
+                  o_ref, acc_ref, m_ref, l_ref, *, scale, block_k,
+                  slots, window, ring):
+    """One (sequence, tile, query block, key block) of the flash form:
+    ``ops/flash_attention.py::_fwd_kernel`` with ``_visible``'s
+    predicate for its mask. ``pos_ref`` holds each row's query
+    position and, for a ring, its place in the ring; ``first_ref`` /
+    ``count_ref`` the run of slots, from place ``first`` on and round
+    the ring's end, that some live query of the query block sees."""
+    b, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    k0 = ki * block_k
+    first, count = first_ref[b, qi], count_ref[b, qi]
+    needed = (k0 < first + count) & (k0 + block_k > first)
+    if ring:
+        needed |= k0 < first + count - slots
+    needed &= count > 0
+
+    @pl.when(needed)
+    def _compute():
+        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        qp = pos_ref[0, :, 0:1]                     # (rows, 1)
+        at = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+        # ``_visible``; a dead query (qp < 0) is behind every slot.
+        if ring:
+            back = pos_ref[0, :, 1:2] - at          # mod slots, below
+            back = jnp.where(back < 0, back + slots, back)
+            seen = (back < window) & (back <= qp) & (at < slots)
+        else:
+            seen = at <= qp
+            if window:
+                seen &= at > qp - window
+        s = jnp.where(seen, s, NEG_INF)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # A row that has seen nothing yet: exp(NEG_INF - 0) = 0, where
+        # exp(NEG_INF - NEG_INF) would count every masked slot.
+        m_use = jnp.where(m_new == NEG_INF, 0.0, m_new)
+        p = jnp.exp(s - m_use)
+        alpha = jnp.exp(m_prev - m_use)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _finalize():
+        lsum = l_ref[:]
+        o_ref[0, 0] = (acc_ref[:] / jnp.where(lsum == 0.0, 1.0, lsum)
+                       ).astype(o_ref.dtype)
+
+
+def _flash_attention(q: jax.Array, k_pages, v_pages,
+                     page_indices: jax.Array,
+                     q_positions: jax.Array, window=None,
+                     ring: bool = False, blocks=None) -> jax.Array:
+    """Flash form: the gather form's copy, attended by ONE Pallas
+    kernel (``dtt_paged_prefill``) that keeps the softmax online, so
+    no logits are held in HBM however many the queries.
+
+    Grid ``(B, tiles, query blocks, key blocks)``, the key blocks
+    innermost and sequential. A block is ``block_q`` queries spread
+    onto a tile's lanes (``block_q * J`` rows, zeros on tile-mates'
+    lanes: the kernel is the same for heads of 128 and of 64 and never
+    unpacks a head) against ``block_k`` slots of that tile on the MXU;
+    running maximum, normaliser and float32 accumulator in VMEM
+    scratch, the output written on the last key block. The mask is
+    ``_visible``'s predicate made in the kernel from the rows'
+    positions and an iota over the block's slots; key blocks that no
+    live query of a query block sees are skipped, decided from two
+    scalars a query block computed here and prefetched. Same
+    precisions as one pass: operands in their own dtype to both
+    products, float32 logits, maximum, exponential and sums, weights
+    cast to the pool's dtype before the second product (unnormalised:
+    the division comes last), output in ``q.dtype``; dead queries and
+    rows that see nothing give zeros. ``blocks``: ``(block_q,
+    block_k)`` in place of ``_flash_blocks``', for the tests and the
+    form table."""
+    layout = k_pages.layout
+    B, S = q.shape[:2]
+    # Tiles first: the order XLA gives the gathered copy for the one-
+    # pass contraction too, so a key block is ``block_k`` whole rows.
+    kd = k_pages.pages(page_indices).transpose(0, 2, 1, 3)
+    vd = v_pages.pages(page_indices).transpose(0, 2, 1, 3)
+    qt = layout.spread(q).transpose(0, 2, 1, 3, 4)  # (B, T, S, J, tile)
+    T, J, tile = qt.shape[1], qt.shape[3], qt.shape[4]
+    Sk = kd.shape[2]
+    block_q, block_k = blocks or _flash_blocks(S, J, Sk)
+    pad_q, pad_k = -S % block_q, -Sk % block_k
+    qt = jnp.pad(qt, ((0, 0), (0, 0), (0, pad_q), (0, 0), (0, 0)))
+    # Padding slots lie past every position a table holds, and past a
+    # ring's places.
+    kd, vd = (jnp.pad(x, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+              for x in (kd, vd))
+    qp = jnp.pad(q_positions, ((0, 0), (0, pad_q)), constant_values=-1)
+    nq, nk, rows = (S + pad_q) // block_q, (Sk + pad_k) // block_k, \
+        block_q * J
+    # The slots some live query of a block sees: positions ``first``
+    # to its highest live position, which lie at those places of a
+    # table and round a ring from place ``first % Sk`` on.
+    live = (qp >= 0).reshape(B, nq, block_q)
+    blocked = qp.reshape(B, nq, block_q)
+    top = jnp.max(blocked, axis=-1)                # -1: all dead
+    low = jnp.min(jnp.where(live, blocked, top[..., None]), axis=-1)
+    first = jnp.maximum(low - window + 1, 0) if window \
+        else jnp.zeros_like(low)
+    count = top - first + 1                        # 0 where all dead
+    if ring:
+        first, count = first % Sk, jnp.minimum(count, Sk)
+    pos = jnp.repeat(qp, J, axis=1)[:, :, None]
+    if ring:
+        pos = jnp.concatenate([pos, pos % Sk], axis=-1)
+
+    # Index maps: the grid's place, then the two prefetched arrays.
+    def of_queries(b, t, qi, ki, *_):
+        return b, t, qi, 0
+
+    def of_slots(b, t, qi, ki, *_):
+        return b, t, ki, 0
+
+    out = pl.pallas_call(
+        functools.partial(
+            _flash_kernel, scale=q.shape[-1] ** -0.5, block_k=block_k,
+            slots=Sk, window=window or 0, ring=ring),
+        name="dtt_paged_prefill",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, T, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, rows, pos.shape[-1]),
+                             lambda b, t, qi, ki, *_: (b, qi, 0)),
+                pl.BlockSpec((1, 1, rows, tile), of_queries),
+                pl.BlockSpec((1, 1, block_k, tile), of_slots),
+                pl.BlockSpec((1, 1, block_k, tile), of_slots),
+            ],
+            out_specs=pl.BlockSpec((1, 1, rows, tile), of_queries),
+            scratch_shapes=[pltpu.VMEM((rows, tile), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32),
+                            pltpu.VMEM((rows, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, T, nq * rows, tile), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
+        interpret=not _platform_is_tpu(),
+    )(first, count, pos, qt.reshape(B, T, nq * rows, tile), kd, vd)
+    out = out.reshape(B, T, S + pad_q, J, tile)[:, :, :S]
+    return layout.collect(out.transpose(0, 2, 1, 3, 4))
+
+
 def _held(pages) -> tuple:
     """``(Hkv, N, ps, hd)`` of a layer's view: what ``chunk_form``
     reasons from."""
@@ -333,16 +504,23 @@ def paged_attention_chunk(q: jax.Array, k_pages, v_pages,
     ``window`` positions, the query's own included; ``ring`` says the
     table is a window layer's ring (see the module's text), which
     needs a window no longer than the ring less the rows a launch
-    writes. The form (``chunk_form``) follows from the static shapes;
-    a call over a ring is reported as ``"<form>.window"``.
+    writes. The form follows from the static shapes: ``chunk_form``'s,
+    or ``"flash"`` where that form's logits would not fit one pass
+    (``_one_pass_fits``); a call over a ring is reported as
+    ``"<form>.window"``.
     """
     if ring and not window:
         raise ValueError("a ring table needs the window it was sized "
                          "for")
     form = chunk_form(q.shape, _held(k_pages), page_indices.shape,
                       k_pages.dtype.itemsize)
+    slots = k_pages.page_size * (k_pages.num_pages if form == "pool"
+                                 else page_indices.shape[1])
+    if not _one_pass_fits(q.shape, slots):
+        form = "flash"
     _took(form + ".window" if ring else form)
-    attend = _pool_attention if form == "pool" else _gather_attention
+    attend = {"pool": _pool_attention, "gather": _gather_attention,
+              "flash": _flash_attention}[form]
     return attend(q, k_pages, v_pages, page_indices, q_positions,
                   window, ring)
 

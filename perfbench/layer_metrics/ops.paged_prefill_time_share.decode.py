@@ -1,0 +1,27 @@
+"""Share of the traced window that device 0 spends in the kernel of a
+prompt chunk's paged attention: self time of the `tpu_custom_call`s that
+`ops/paged_attention.py::_flash_attention` names `dtt_paged_prefill`
+(the flash form: a chunk's queries against the gathered ring or table,
+softmax kept online). None where no operation bears the name (a program
+from before the kernel, or an engine whose shapes stay under the rule):
+a kernel that cannot be found is not a kernel that took no time."""
+
+import re
+
+LAYER = "kernels"
+UNIT = "%"
+BETTER = "lower"
+SOURCE = "device_trace"
+MOVES = "serve_out_tok_s"
+
+PATTERN = re.compile(
+    r"^dtt_paged_prefill\.\d+ custom-call:tpu_custom_call$")
+
+
+def read(obs):
+    t = obs["trace"]
+    found = [s for name, s in t["op_self_s"].items()
+             if PATTERN.match(name)]
+    if not found:
+        return None
+    return 100.0 * sum(found) / t["window_s"]

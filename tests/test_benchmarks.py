@@ -324,6 +324,76 @@ def test_decode_shaped_paged_attention_reads_pool_in_place(S):
     assert text.count(" convolution(") == 2     # both dots on the MXU
 
 
+@pytest.mark.parametrize("shape,heads,P,N,window,form", [
+    # smallthinker-21b-ep4's prompt chunk over a window layer's ring
+    # and over a global layer's table ...
+    ((1, 1024, 28, 128), 4, 320, 10241, 4096, "flash.window"),
+    ((1, 1024, 28, 128), 4, 1024, 32769, None, "flash"),
+    # ... and gpt2-xl's two programs, which the rule leaves alone.
+    ((4, 128, 25, 64), 25, 64, 385, None, "gather"),
+    ((16, 1, 25, 64), 25, 64, 385, None, "pool"),
+], ids=["ring_1x1024", "table_1x1024", "xl_4x128", "xl_16x1"])
+def test_prompt_chunk_attention_is_one_kernel_and_holds_no_logits(
+        monkeypatch, shape, heads, P, N, window, form):
+    """``paged_attention_chunk`` at ``smallthinker_ep4.serve_long``'s
+    two prefill shapes (bfloat16 pools at the cell's sizes) compiles
+    for a v5e, under Mosaic's VMEM check, to a program with the custom
+    call ``dtt_paged_prefill``, no ``reduce-window`` (the compiler had
+    turned the row maximum of ``f32[4,256,7,5120]`` into a window of
+    10,239 at every logit: three fusions, 40% of the cell's device
+    time, ledger, PR 32) and no float32 array as large as the logits of
+    one head-block; at ``gpt2-xl``'s shapes it holds no such call. The
+    name on the instruction is what ``ops.paged_prefill_time_share.
+    decode`` looks for."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_training_tpu.ops import paged_attention as pa
+    from distributed_training_tpu.serving.kv_cache import PoolLayout
+    from perfbench import common, trace_reduce
+
+    monkeypatch.setenv("DTT_ASSUME_TPU", "1")
+    try:
+        from distributed_training_tpu.runtime import topology_runtime
+        chip = SingleDeviceSharding(
+            topology_runtime(1, "v5e:2x2").mesh.devices.flat[0])
+    except Exception as e:  # pragma: no cover - no libtpu
+        pytest.skip(f"device-less TPU topology unavailable: {e}")
+
+    def struct(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    B, S, H, hd = shape
+    layout = PoolLayout(heads, hd)
+    pool = layout.layer(struct(layout.shape(1, N, 16), jnp.bfloat16), 0)
+    with pa.observe_forms() as seen:
+        text = jax.jit(lambda *a: pa.paged_attention_chunk(
+            *a, window=window, ring=bool(window))).lower(
+            struct(shape, jnp.bfloat16), pool, pool,
+            struct((B, P), jnp.int32),
+            struct((B, S), jnp.int32)).compile().as_text()
+    assert seen == [form]
+    calls = [line.strip() for line in text.splitlines()
+             if " custom-call(" in line
+             and 'custom_call_target="tpu_custom_call"' in line]
+    if "flash" not in form:
+        assert not calls
+        return
+    assert len(calls) == 1
+    assert re.match(r"%dtt_paged_prefill\.\d+ = ", calls[0])
+    pattern = common.load_file(
+        "layer_metrics", "ops.paged_prefill_time_share.decode").PATTERN
+    assert pattern.match(trace_reduce.short_name(calls[0])), calls[0]
+    assert "reduce-window" not in text
+    largest = max([math.prod(int(d) for d in dims.split(","))
+                   for dims in re.findall(r"f32\[([0-9,]+)\]", text)]
+                  or [0])
+    assert largest < S * H * P * 16, largest
+
+
 def test_resident_decode_holds_no_copy_of_the_pool():
     """``jit_serving_resident_decode`` at ``gpt2-xl``'s widths (25
     heads of 64, 16 slots, 385 pages, bfloat16; 4 layers of 48)

@@ -287,12 +287,18 @@ FEEDS = {
 }
 
 
-@pytest.mark.parametrize("feed", list(FEEDS))
-def test_paged_logits_match_the_reference(ref, feed):
+@pytest.mark.parametrize("feed,limit", [
+    ("chunks_then_one_token", None), ("ragged_chunks", None),
+    # Every call of the program (1 x 8 rows against a ring of 40 slots
+    # and a table of 256) through the flash form's kernel.
+    ("ragged_chunks", 1 << 10)], ids=lambda v: str(v))
+def test_paged_logits_match_the_reference(ref, monkeypatch, feed, limit):
     """Logits, not tokens: every position's, to what float32 rounding
     explains (2e-4, as ``apply`` above), over 200 positions and five
     turns of the ring; and the same in bfloat16 is off by a hundred
     times that."""
+    if limit:
+        monkeypatch.setattr(pa, "_LOGITS_LIMIT", limit)
     sizes = FEEDS[feed]
     seq = np.random.default_rng(11).integers(0, 96, sum(sizes))
     model, params = build(ep_size=4)
@@ -373,7 +379,7 @@ def ring_case(B, S, R, window, last, seed=0, ps=4, H=4, Hkv=2, hd=8):
             jnp.asarray(rows), jnp.asarray(q_pos)), k, v
 
 
-@pytest.mark.parametrize("form", ["pool", "gather"])
+@pytest.mark.parametrize("form", ["pool", "gather", "flash"])
 @pytest.mark.parametrize("S,last", [(1, (70, 9, 41)), (8, (70, 12, 43)),
                                     (3, (39, 40, 41))])
 def test_ring_attention_forms_agree(monkeypatch, form, S, last):
@@ -384,7 +390,10 @@ def test_ring_attention_forms_agree(monkeypatch, form, S, last):
     window, R = 32, 10
     args, k, v = ring_case(3, S, R, window, last)
     q, _kp, _vp, _rows, q_pos = args
-    monkeypatch.setattr(pa, "chunk_form", lambda *a, **kw: form)
+    monkeypatch.setattr(pa, "chunk_form", lambda *a, **kw: "gather"
+                        if form == "flash" else form)
+    if form == "flash":
+        monkeypatch.setattr(pa, "_LOGITS_LIMIT", 0)
     with pa.observe_forms() as seen:
         got = pa.paged_attention_chunk(*args, window=window, ring=True)
     assert seen == [form + ".window"]
@@ -398,25 +407,6 @@ def test_ring_attention_forms_agree(monkeypatch, form, S, last):
     want = jnp.where((q_pos >= 0)[:, :, None, None], want, 0.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
-
-
-def test_query_blocks_agree_with_one_pass(monkeypatch):
-    """Queries taken a block at a time (where all their logits at once
-    would be too many) give what one pass gives."""
-    args, _k, _v = ring_case(2, 8, 10, 32, (70, 43), seed=3)
-    one = pa.paged_attention_chunk(*args, window=32, ring=True)
-    monkeypatch.setattr(pa, "_LOGITS_LIMIT", 1 << 10)
-    assert pa._query_blocks(args[0].shape, 40) == 8
-    np.testing.assert_allclose(
-        np.asarray(pa.paged_attention_chunk(*args, window=32, ring=True)),
-        np.asarray(one), atol=1e-6)
-
-
-def test_query_blocks_leave_the_old_engines_alone():
-    """No shape of the cells the benchmark had reaches the limit."""
-    assert pa._query_blocks((4, 128, 25, 64), 64 * 16) == 1
-    assert pa._query_blocks((16, 1, 25, 64), 385 * 16) == 1
-    assert pa._query_blocks((1, 1024, 28, 128), 16384) == 8
 
 
 def cache(**over):
@@ -636,3 +626,145 @@ def test_prefill_lanes_go_first_come_first_served(resident_k):
     assert eng.idle and submitted
     assert {d["id"] for d in eng.completed} == {"short", "long", "late"}
     assert firsts == ["short", "long", "late"]
+
+
+def flash_case(B, S, H, Hkv, hd, P, positions, ps=4, seed=0):
+    """The arguments of one layer's call: ``B`` sequences of ``S``
+    queries at ``positions`` (a row a sequence), a float32 pool whose
+    every slot holds something, each sequence ``P`` pages of its
+    own."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    N = B * P + 1
+    q = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32)
+    kp, vp = (as_layer(jax.random.normal(k, (Hkv, N, ps, hd),
+                                         jnp.float32)) for k in ks[1:])
+    rows = np.random.default_rng(seed).permutation(N - 1)[:B * P] + 1
+    return (q, kp, vp, jnp.asarray(rows.reshape(B, P), jnp.int32),
+            jnp.asarray(np.asarray(positions, np.int32)))
+
+
+def run_of(start, n, S):
+    """Positions of a chunk of ``S`` rows, ``n`` of them live from
+    ``start`` on and the tail padding."""
+    return [start + i if i < n else -1 for i in range(S)]
+
+
+# name: (shape, window, ring, positions). Blocks of 16 queries against
+# 128 slots everywhere, so that every case has several of each and some
+# to skip.
+FLASH = {
+    # Tables of 256 slots: the first sequence sees one key block of two.
+    "table": (dict(B=2, S=32, H=4, Hkv=2, hd=16, P=64), None, False,
+              [run_of(90, 32, 32), run_of(200, 32, 32)]),
+    # ... and with a window the second no longer sees the first block.
+    "table_window": (dict(B=2, S=32, H=4, Hkv=2, hd=16, P=64), 48, False,
+                     [run_of(90, 32, 32), run_of(200, 32, 32)]),
+    # A ring of 160 slots for a window of 128 and chunks of 32, past
+    # five turns (837 = 5 * 160 + 37), the chunk's own rows in it: the
+    # seen slots wrap round the ring's end; and a sequence shorter than
+    # the window.
+    "ring_five_turns": (dict(B=2, S=32, H=4, Hkv=2, hd=16, P=40), 128,
+                        True, [run_of(837, 32, 32), run_of(50, 32, 32)]),
+    # Dead queries inside a chunk, and a sequence wholly dead.
+    "dead": (dict(B=3, S=32, H=4, Hkv=2, hd=16, P=40), 128, True,
+             [[-1 if i % 5 == 0 else 700 + i for i in range(32)],
+              [-1] * 32, run_of(170, 32, 32)]),
+    # smallthinker's heads: 7 query heads a kv head of 128, a tile each.
+    "gqa7_heads_of_128": (dict(B=1, S=32, H=14, Hkv=2, hd=128, P=64),
+                          None, False, [run_of(150, 32, 32)]),
+    # gpt2's: heads of 64 two a tile, the third kv head alone in its.
+    "two_heads_a_tile": (dict(B=2, S=32, H=6, Hkv=3, hd=64, P=40), 128,
+                         True, [run_of(400, 32, 32), run_of(0, 32, 32)]),
+    # 13 live rows of a chunk of 24, which is no whole number of query
+    # blocks either.
+    "padding_tail": (dict(B=2, S=24, H=4, Hkv=2, hd=16, P=64), None,
+                     False, [run_of(131, 13, 24), run_of(7, 24, 24)]),
+}
+
+
+@pytest.mark.parametrize("name", list(FLASH))
+def test_flash_kernel_agrees_with_one_pass(name):
+    """The flash form's kernel (interpreted here), float32 operands,
+    against ``_tile_attention`` in one pass over the same gathered
+    copy under ``_visible``'s mask: to float32 rounding, zeros where a
+    query is dead, never NaN."""
+    shape, window, ring, positions = FLASH[name]
+    args = flash_case(**shape, positions=positions)
+    want = pa._gather_attention(*args, window=window, ring=ring)
+    got = pa._flash_attention(*args, window=window, ring=ring,
+                              blocks=(16, 128))
+    assert got.shape == want.shape and got.dtype == want.dtype
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+    dead = np.asarray(positions) < 0
+    assert not got[dead].any() and np.abs(got[~dead]).max() > 0.1
+    # The blocks the shapes would get (one key block here) say the same.
+    np.testing.assert_allclose(
+        np.asarray(pa._flash_attention(*args, window=window, ring=ring)),
+        want, atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("shape,P,N,window,form", [
+    # gpt2-xl's two programs and smallthinker-21b-ep4's resident decode
+    # stay what they were ...
+    ((4, 128, 25, 64), 64, 385, None, "gather"),
+    ((16, 1, 25, 64), 64, 385, None, "pool"),
+    ((32, 1, 28, 128), 320, 10241, 4096, "gather.window"),
+    ((32, 1, 28, 128), 1024, 32769, None, "gather"),
+    # ... and its prompt chunk takes the kernel in both kinds of layer.
+    ((1, 1024, 28, 128), 320, 10241, 4096, "flash.window"),
+    ((1, 1024, 28, 128), 1024, 32769, None, "flash"),
+])
+def test_the_rule_that_says_where_the_kernel_runs(shape, P, N, window,
+                                                  form):
+    """``_LOGITS_LIMIT`` over the static shapes alone: no shape of the
+    cells the benchmark had before PR 32 reaches it, a chunk of 1,024
+    against 5,120 and 16,384 slots does."""
+    from distributed_training_tpu.serving.kv_cache import PoolLayout
+
+    B, S, H, hd = shape
+    layout = PoolLayout(H if hd == 64 else 4, hd)
+    pool = layout.layer(jax.ShapeDtypeStruct(
+        layout.shape(1, N, 16), jnp.bfloat16), 0)
+    assert pa._one_pass_fits(shape, P * 16) == ("flash" not in form)
+    with pa.observe_forms() as seen:
+        out = jax.eval_shape(
+            lambda *a: pa.paged_attention_chunk(
+                *a, window=window, ring=bool(window)),
+            jax.ShapeDtypeStruct(shape, jnp.bfloat16), pool, pool,
+            jax.ShapeDtypeStruct((B, P), jnp.int32),
+            jax.ShapeDtypeStruct((B, S), jnp.int32))
+    assert seen == [form] and out.shape == shape
+
+
+def test_engine_prefill_through_the_kernel_matches_the_reference(
+        ref, monkeypatch):
+    """With ``_LOGITS_LIMIT`` low enough that the prefill program (two
+    lanes of 8 rows) takes the kernel in both kinds of layer, every
+    streamed token is still the reference's argmax to 1e-4, past five
+    turns of the ring; the form is reported a program."""
+    monkeypatch.setattr(pa, "_LOGITS_LIMIT", 8000)
+    model, params = build(ep_size=4)
+    prompts = prompts_of()
+    eng, _records, done = serve(model, params, "resident", prompts)
+    assert worst_gap(ref, params, prompts, done) < 1e-4
+    assert eng.paged_forms()["serving_prefill_batch"] \
+        == "flash+flash.window"
+
+
+@pytest.mark.parametrize("ring", [False, True], ids=["table", "ring"])
+def test_the_form_tables_many_query_case_rehearses(ring):
+    """``chip_smoke.paged_prefill_case`` (the rows
+    ``benchmarks/paged_form_table.py`` times on the chip: the kernel
+    against the XLA form, queries a block at a time) at a tiny size on
+    the CPU, bfloat16 as there: the two agree within its band."""
+    import sys
+    sys.path.insert(0, ROOT)
+    import chip_smoke
+
+    row = chip_smoke.paged_prefill_case(
+        2, 16, 4, 2, N=81, hd=64, ps=4, reps=1, start=100,
+        **(dict(P=12, window=32, ring=True) if ring else dict(P=32)))
+    assert row["ok"] and row["max_abs_diff"] < 0.05
+    assert row["rule"] == ("gather.window" if ring else "gather")
