@@ -11,9 +11,12 @@ rule takes, the faster form) and writes them to
 flash form (the kernel ``dtt_paged_prefill``) against the XLA form,
 queries a block at a time where one pass would not fit, at the shapes
 of a prompt chunk (``xla_ms``, ``flash_ms``): what decides whether
-``_LOGITS_LIMIT`` could come down. Times are of twenty calls after
-one, a layer alone: what decides between forms, not a benchmark
-result.
+``_LOGITS_LIMIT`` could come down. Last, the latent rows (PR 34): one
+full layer under its learned selection against dense attention over
+the same table, and one window layer over its ring, at
+``dots3-note-ep8``'s widths (``sparse_ms``, ``dense_ms``, ``ring_ms``).
+Times are of twenty calls after one (ten for the latent rows), a layer
+alone: what decides between forms, not a benchmark result.
 """
 
 from __future__ import annotations
@@ -63,6 +66,19 @@ MANY = {
 }
 
 
+# dots3-note-ep8's two kinds of latent layer (chip_smoke.py::
+# sparse_latent_case): a full layer under its selection of 2,048
+# against dense attention over the same table, and a window layer over
+# its ring of 97 pages; a decode iteration and a prompt chunk, at 8k of
+# context.
+SPARSE = {
+    "dots3.full_32x1": dict(kind="full", B=32, S=1),
+    "dots3.full_1x1024": dict(kind="full", B=1, S=1024),
+    "dots3.window_32x1": dict(kind="window", B=32, S=1),
+    "dots3.window_1x1024": dict(kind="window", B=1, S=1024),
+}
+
+
 def main() -> int:
     import jax
 
@@ -83,6 +99,10 @@ def main() -> int:
         row = {"name": name, **chip_smoke.paged_prefill_case(**shape)}
         row["faster"] = ("flash" if row["flash_ms"] < row["xla_ms"]
                          else "xla")
+        print(json.dumps(row), flush=True)
+        rows.append(row)
+    for name, shape in SPARSE.items():
+        row = {"name": name, **chip_smoke.sparse_latent_case(**shape)}
         print(json.dumps(row), flush=True)
         rows.append(row)
     out = os.path.join(REPO, "chiprun_out")

@@ -338,6 +338,121 @@ def paged_prefill_case(B, S, H, Hkv, P, N, hd=128, ps=16, window=None,
             "rule": seen[0]}
 
 
+# dots3-note-ep8's two kinds of latent layer at their published widths
+# (perfbench/configs/dots3-note-ep8.json): H, rank, nope, rope, v, the
+# pages of a sequence's row (a table of 16,384 rows; a ring of 97
+# pages), and the full layer's indexer (heads, width, top-k).
+SPARSE_LATENT = {
+    "full": dict(H=128, rank=512, nope=128, rope=64, v=128, P=1024,
+                 index=(64, 128, 2048)),
+    "window": dict(H=64, rank=1024, nope=192, rope=64, v=128, P=97,
+                   window=513),
+}
+
+
+def sparse_latent_case(kind: str, B: int, S: int, context: int = 8192,
+                       ps: int = 16, reps: int = 10) -> dict:
+    """One layer's ``latent_attention_chunk`` of ``kind`` (``"full"``:
+    under the learned selection; ``"window"``: over a ring) at
+    dots3-note-ep8's widths, ``B`` sequences of ``S`` queries that end
+    at ``context``: its smoke time a call and, for a full layer, that
+    of dense attention over the same table (every earlier position
+    seen, queries a block at a time where the logits ask for it), so
+    that a ``perf_opt`` has a layer's time to start from. Checked: the
+    selection with room for every position gives what dense attention
+    gives (the chosen rows read through the page table, or the mask);
+    a ring no longer than its slots gives what the same pages give as a
+    table."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_training_tpu.ops import paged_attention as pa
+    from distributed_training_tpu.serving.kv_cache import PoolLayout
+
+    d = SPARSE_LATENT[kind]
+    bf = jnp.bfloat16
+    H, rank, P = d["H"], d["rank"], d["P"]
+    ks = jax.random.split(jax.random.PRNGKey(SEED + B * 31 + S), 9)
+    N = B * P + 1
+    zero = jnp.zeros((), jnp.int32)
+
+    def layer(key, width):
+        lay = PoolLayout(1, width)
+        return lay.layer(jax.random.normal(
+            key, (1, N, ps, lay.lanes), bf), zero)
+
+    rng = np.random.default_rng(SEED + B)
+    tables = (rng.permutation(N - 1).reshape(B, P) + 1).astype(np.int32)
+    ends = np.full(B, context) - rng.integers(0, 64, B)
+
+    def positions(ends):
+        return jnp.asarray(ends[:, None] - S + np.arange(S)[None, :],
+                           jnp.int32)
+
+    heads = (jax.random.normal(ks[0], (B, S, H, d["nope"]), bf),
+             jax.random.normal(ks[1], (B, S, H, d["rope"]), bf))
+    pools = (layer(ks[2], rank), layer(ks[3], d["rope"]),
+             jnp.asarray(tables))
+    up = (jax.random.normal(ks[4], (rank, H, d["nope"]), bf)
+          * rank ** -0.5,
+          jax.random.normal(ks[5], (rank, H, d["v"]), bf) * rank ** -0.5)
+    label = f"{kind} {B} x {S} at {context}, H{H} rank {rank}, P {P}"
+    ms, out = {}, {}
+    if kind == "full":
+        J, di, topk = d["index"]
+        iq = jax.random.normal(ks[6], (B, S, J, di), bf)
+        iw = jax.random.normal(ks[7], (B, S, J), jnp.float32)
+        ip = layer(ks[8], di)
+        Sk = P * ps
+
+        def sparse(k):
+            return lambda qn, qr, c, r, t, qp, uk, uv, iq, iw, ip: \
+                pa.latent_attention_chunk(
+                    qn, qr, c, r, t, qp, uk, uv,
+                    select=pa.Selection(iq, iw, ip, k))
+
+        def dense(qn, qr, c, r, t, qp, uk, uv):
+            return pa.latent_attention_chunk(qn, qr, c, r, t, qp, uk,
+                                             uv, window=Sk)
+        args = (*heads, *pools, positions(ends), *up)
+        with pa.observe_forms() as seen:
+            _r, out["sparse"], ms["sparse"] = _smoke_time(
+                sparse(topk), args + (iq, iw, ip), reps)
+        _r, out["dense"], ms["dense"] = _smoke_time(dense, args, reps)
+        _r, out["all_kept"], ms["all_kept"] = _smoke_time(
+            sparse(Sk), args + (iq, iw, ip), 1)
+        band = _close("sparse_latent", out["all_kept"], out["dense"])
+        extra = {"sparse_ms": ms["sparse"], "dense_ms": ms["dense"]}
+        say(f"  sparse latent [{label}]: selection of {topk} "
+            f"{ms['sparse']:.3f} ms, dense {ms['dense']:.3f} ms a call "
+            f"(smoke wall; {seen[0]}), all kept against dense "
+            f"{band:.3f} of the bf16 band")
+    else:
+        def ring(is_ring):
+            return lambda qn, qr, c, r, t, qp, uk, uv: \
+                pa.latent_attention_chunk(qn, qr, c, r, t, qp, uk, uv,
+                                          window=d["window"],
+                                          ring=is_ring)
+        args = (*heads, *pools, positions(ends), *up)
+        with pa.observe_forms() as seen:
+            _r, _o, ms["ring"] = _smoke_time(ring(True), args, reps)
+        # Before the ring's first turn a position is its ring slot.
+        short = (*heads, *pools,
+                 positions(np.minimum(ends, P * ps - 1)), *up)
+        _r, out["ring"], _ms = _smoke_time(ring(True), short, 1)
+        _r, out["table"], _ms = _smoke_time(ring(False), short, 1)
+        band = _close("sparse_latent", out["ring"], out["table"])
+        extra = {"ring_ms": ms["ring"]}
+        say(f"  sparse latent [{label}]: ring {ms['ring']:.3f} ms a "
+            f"call (smoke wall; {seen[0]}), ring against table "
+            f"{band:.3f} of the bf16 band")
+    if band > 1.0:
+        raise AssertionError(f"{label}: beyond the bf16 band ({band})")
+    return {"ok": True, "shape": label, "form": seen[0],
+            "err_over_bf16_band": band, **extra}
+
+
 def compare_case(name: str, run, ref, inputs, label: str) -> dict:
     """Compile ``run`` for the chip, require a Mosaic kernel in it, and
     compare what it computes with ``ref``."""
@@ -384,6 +499,16 @@ def phase_kernels() -> dict:
         ("paged_prefill_ring_1x1024",
          lambda: paged_prefill_case(1, 1024, 28, 4, P=320, N=10241,
                                     window=4096, ring=True, start=8192)),
+        # dots3-note-ep8's two kinds of latent layer, a decode
+        # iteration and a prompt chunk at 8k of context.
+        ("sparse_latent_full_32x1",
+         lambda: sparse_latent_case("full", 32, 1)),
+        ("sparse_latent_full_1x1024",
+         lambda: sparse_latent_case("full", 1, 1024)),
+        ("sparse_latent_window_32x1",
+         lambda: sparse_latent_case("window", 32, 1)),
+        ("sparse_latent_window_1x1024",
+         lambda: sparse_latent_case("window", 1, 1024)),
     ])
     results = {}
     for name, case in cases.items():

@@ -88,6 +88,10 @@ class LatentMoEConfig:
     # How ``models/experts.py::expert_layer`` scores and activates.
     router_score = "sigmoid"
     expert_act = "silu"
+    # What ``project`` multiplies the two latents by after their norms:
+    # nothing here (``models/sparse_latent_moe.py`` rescales).
+    q_scale = 1.0
+    kv_scale = 1.0
 
     def __post_init__(self):
         if not 0 < self.n_dense_layers <= self.n_layers:
@@ -137,21 +141,39 @@ def rope_interleaved(x, positions, theta):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def project(h, a, positions, c: LatentMoEConfig, w=_cast):
+def _scaled(x, scale: float):
+    return x if scale == 1.0 else x * jnp.asarray(scale, x.dtype)
+
+
+def query_latent(h, a, c, w=_cast):
+    """The query's latent ``c_q (..., q_lora_rank)`` of the normed
+    input ``h``: after its norm, times ``c.q_scale``."""
+    dt = h.dtype
+    return _scaled(
+        rms_norm(jnp.einsum("...d,dr->...r", h, w(a["wdq"], dt)),
+                 a["q_norm"], c.rms_norm_eps), c.q_scale)
+
+
+def project(h, a, positions, c, w=_cast, c_q=None):
     """The normed input ``h (..., D)`` at ``positions (...)`` ->
     ``q_nope (..., H, nope)``, ``q_rope (..., H, rope)`` after RoPE,
     and what the token leaves behind: ``c_kv (..., rank)`` after its
-    norm, ``k_rope (..., rope)`` after RoPE."""
+    norm (times ``c.kv_scale``), ``k_rope (..., rope)`` after RoPE.
+    ``c``: the sizes (``qk_nope_head_dim``, ``kv_lora_rank``,
+    ``rope_theta``, ``rms_norm_eps``, the two scales), a
+    ``LatentMoEConfig`` or one kind of layer of a model with several
+    (``models/sparse_latent_moe.py``); ``c_q``: ``query_latent``'s,
+    where the caller has made it for a second reader."""
     dt = h.dtype
-    c_q = rms_norm(jnp.einsum("...d,dr->...r", h, w(a["wdq"], dt)),
-                   a["q_norm"], c.rms_norm_eps)
+    if c_q is None:
+        c_q = query_latent(h, a, c, w)
     q = jnp.einsum("...r,rhk->...hk", c_q, w(a["wuq"], dt))
     q_nope = q[..., :c.qk_nope_head_dim]
     q_rope = rope_interleaved(q[..., c.qk_nope_head_dim:], positions,
                               c.rope_theta)
     ckv = jnp.einsum("...d,dr->...r", h, w(a["wdkv"], dt))
-    c_kv = rms_norm(ckv[..., :c.kv_lora_rank], a["kv_norm"],
-                    c.rms_norm_eps)
+    c_kv = _scaled(rms_norm(ckv[..., :c.kv_lora_rank], a["kv_norm"],
+                            c.rms_norm_eps), c.kv_scale)
     k_rope = rope_interleaved(ckv[..., c.kv_lora_rank:], positions,
                               c.rope_theta)
     return q_nope, q_rope, c_kv, k_rope

@@ -35,6 +35,11 @@ def build_model(name: str, loss: str = "auto", dtype: str = "float32",
             build_window_moe,
         )
         return build_window_moe(loss=loss, dtype=dtype, **kwargs)
+    if name == "sparse_latent_moe":
+        from distributed_training_tpu.models.sparse_latent_moe import (
+            build_sparse_latent_moe,
+        )
+        return build_sparse_latent_moe(loss=loss, dtype=dtype, **kwargs)
     if name in ("resnet", "resnet18"):
         from distributed_training_tpu.models.resnet import ResNet
         return ResNet(dtype=dtype, **kwargs)

@@ -22,7 +22,11 @@ join/evict. This module is the attention math over that layout:
 
 A second entrypoint, ``latent_attention_chunk``, attends over a LATENT
 cache (one shared row a token instead of keys and values a head), in an
-absorbed or an expanded form that ``latent_form`` takes from the shapes.
+absorbed or an expanded form that ``latent_form`` takes from the shapes;
+over a window layer's ring (``window=``, ``ring=``) and under a learned
+selection of positions (``select=``: the indexer's scores over the index
+pool's layer, ``index_scores``, and an exact top-k, ``select_topk``) as
+well.
 
 One entrypoint over keys and values:
 
@@ -80,8 +84,10 @@ One entrypoint over keys and values:
 
 There is no switch between the forms: static shapes decide, so a
 compiled program takes a form always or never. The form each took
-(``"pool"``, ``"gather"``, ``"flash"``; ``".window"`` behind it over a
-ring) is seen at trace time by ``observe_forms`` and reported per
+(``"pool"``, ``"gather"``, ``"flash"``, of latent attention
+``"absorbed"``, ``"expanded"``; ``".sparse"`` behind it under a
+selection, ``".window"`` over a ring) is seen at trace time by
+``observe_forms`` and reported per
 program by ``Engine.paged_forms()`` and the ``serving_warmup`` telemetry
 record (docs/observability.md).
 """
@@ -90,6 +96,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -107,7 +114,8 @@ _observers: list[list[str]] = []
 def observe_forms():
     """Collect, while open, the form every ``paged_attention_chunk``
     (``"pool"``, ``"gather"``, ``"flash"``) and ``latent_attention_chunk``
-    (``"absorbed"``, ``"expanded"``) call takes. The form follows from
+    (``"absorbed"``, ``"expanded"``; ``".sparse"``, ``".window"`` behind
+    either) call takes. The form follows from
     static shapes, so a call is seen when the program around it is
     TRACED: the engine opens this around each program's body
     (``serving/engine.py::_named``)."""
@@ -549,11 +557,140 @@ def latent_form(q_shape, dims) -> str:
             > _EXPAND_COST * (nope + v) * rank else "absorbed")
 
 
+# Float32 logits one pass of ``latent_attention_chunk`` may hold. The
+# widest call of the engines the benchmark had before PR 34 (a prompt
+# chunk of 1024 of 32 heads against a table of 4,096 rows) holds 512 MiB
+# and stays one pass; 128 heads against 16,384 rows are 8 GiB and go a
+# block of queries at a time.
+_LATENT_LOGITS_LIMIT = 640 << 20
+
+
+class Selection(NamedTuple):
+    """A learned selection's side of one call (DeepSeek's sparse
+    attention, arXiv 2512.02556): the indexer's queries ``q (B, S, J,
+    d)`` (J heads, RoPE applied) and their weights ``w (B, S, J)``
+    float32, the layer of the index pool that holds every position's
+    key (``PoolLayer``, one ``d``-wide head a token), and how many
+    positions a query keeps."""
+
+    q: jax.Array
+    w: jax.Array
+    pages: object
+    topk: int
+
+
+def index_scores(sel: Selection, keys: jax.Array) -> jax.Array:
+    """``I(t, s) = sum_j w[t, j] * relu(q[t, j] . keys[s])`` for the
+    queries of ``sel`` against ``keys (B, Sk, d)``: ``(B, S, Sk)``
+    float32, the products in the keys' dtype with float32
+    accumulation."""
+    dots = jnp.einsum("bsjd,bkd->bsjk", sel.q, keys,
+                      preferred_element_type=jnp.float32)
+    return jnp.einsum("bsjk,bsj->bsk", jax.nn.relu(dots),
+                      sel.w.astype(jnp.float32))
+
+
+def select_topk(scores: jax.Array, seen: jax.Array, k: int) -> tuple:
+    """The EXACT ``k`` largest of ``scores (..., Sk)`` among the
+    positions ``seen`` marks, all of them where they are no more than
+    ``k``; equal scores go to the lower position first
+    (``jax.lax.top_k``'s order). Returns ``(positions (..., k), kept
+    (..., k) bool, chosen (..., Sk) bool)``: the same set as indices
+    (``kept`` false where fewer than ``k`` were seen) and as a mask."""
+    scores = jnp.where(seen, scores, -jnp.inf)
+    top, positions = jax.lax.top_k(scores, k)
+    kept = top > -jnp.inf
+    # As a mask: above the k-th score, and of those equal to it the
+    # first few, as many as top_k took.
+    last = top[..., -1:]
+    above, equal = scores > last, scores == last
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    chosen = above | (equal & (jnp.cumsum(equal, axis=-1) <= room))
+    return positions, kept, chosen & seen
+
+
+def _latent_pass(form: str, q_nope, q_rope, cd, rd, visible, w_uk,
+                 w_uv, keys=None, values=None):
+    """One pass of latent attention: queries ``(B, S, H, .)`` against
+    the rows ``cd (B, Sk, rank)`` / ``rd (B, Sk, rope)`` under
+    ``visible`` (broadcast against ``(B, H, S, Sk)``), in ``form``.
+    ``keys`` / ``values``: the rows already expanded ``(B, Sk, H, .)``,
+    where the caller makes them once for many passes."""
+    f32 = jnp.float32
+    nope, rope = q_nope.shape[-1], q_rope.shape[-1]
+    scores = jnp.einsum("bshe,bke->bhsk", q_rope, rd,
+                        preferred_element_type=f32)
+    if form == "expanded":
+        if keys is None:
+            keys = jnp.einsum("bkr,rhn->bkhn", cd, w_uk)
+        scores = scores + jnp.einsum(
+            "bshn,bkhn->bhsk", q_nope, keys,
+            preferred_element_type=f32)
+    else:
+        scores = scores + jnp.einsum(
+            "bshr,bkr->bhsk",
+            jnp.einsum("bshn,rhn->bshr", q_nope, w_uk), cd,
+            preferred_element_type=f32)
+    probs = _masked_softmax(scores * (nope + rope) ** -0.5,
+                            visible).astype(cd.dtype)
+    if form == "expanded":
+        if values is None:
+            values = jnp.einsum("bkr,rhv->bkhv", cd, w_uv)
+        return jnp.einsum("bhsk,bkhv->bshv", probs, values,
+                          preferred_element_type=f32
+                          ).astype(q_nope.dtype)
+    # The heads stay where the softmax left them: with them moved inside
+    # this einsum's own output, XLA's CPU runtime has no bfloat16 dot to
+    # run it with.
+    ctx = jnp.einsum("bhsk,bkr->bhsr", probs, cd,
+                     preferred_element_type=f32).astype(q_nope.dtype)
+    return jnp.einsum("bhsr,rhv->bshv", ctx, w_uv)
+
+
+def _query_block(B: int, S: int, width: int) -> int:
+    """Queries a pass of ``latent_attention_chunk``, each holding
+    ``width`` float32 values (its heads' logits over the rows it
+    attends, or its indexer's scores over the table): all ``S`` where
+    they fit ``_LATENT_LOGITS_LIMIT``, else the largest power of two
+    that does (8 at least)."""
+    if B * S * width * 4 <= _LATENT_LOGITS_LIMIT:
+        return S
+    block = 8
+    while B * 2 * block * width * 4 <= _LATENT_LOGITS_LIMIT:
+        block *= 2
+    return block
+
+
+def _in_query_blocks(attend, block: int, q_positions, *by_query):
+    """``attend(q_positions, *by_query)`` with the arrays' query axis
+    ``(B, S, ...)`` cut into blocks of ``block`` queries, one
+    ``jax.lax.map`` step a block, the last block padded with dead
+    queries (position -1); all at once where ``block`` covers ``S``."""
+    B, S = q_positions.shape
+    if block >= S:
+        return attend(q_positions, *by_query)
+    pad = -S % block
+
+    def blocks(x, fill=0):
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2),
+                    constant_values=fill)
+        return jnp.moveaxis(x.reshape((B, -1, block) + x.shape[2:]),
+                            1, 0)
+
+    out = jax.lax.map(lambda a: attend(*a),
+                      (blocks(q_positions, -1), *map(blocks, by_query)))
+    out = jnp.moveaxis(out, 0, 1).reshape((B, S + pad) + out.shape[3:])
+    return out[:, :S]
+
+
 def latent_attention_chunk(q_nope: jax.Array, q_rope: jax.Array,
                            c_pages, r_pages,
                            page_indices: jax.Array,
                            q_positions: jax.Array, w_uk: jax.Array,
-                           w_uv: jax.Array) -> jax.Array:
+                           w_uv: jax.Array, window=None,
+                           ring: bool = False,
+                           select: Selection | None = None
+                           ) -> jax.Array:
     """Multi-query attention over a LATENT paged cache.
 
     q_nope (B, S, H, nope), q_rope (B, S, H, rope), RoPE applied;
@@ -573,39 +710,85 @@ def latent_attention_chunk(q_nope: jax.Array, q_rope: jax.Array,
     latent rows and ``W_uv`` is applied to it: no key or value a head is
     ever made. *Expanded*: the gathered rows are expanded into keys and
     values and attended as ordinary heads. Same mathematics, rounded in
-    another order; ``latent_form`` takes one from the shapes."""
+    another order; ``latent_form`` takes one from the shapes.
+
+    ``window`` / ``ring`` as in ``paged_attention_chunk``: a query sees
+    the last ``window`` positions, and the table is a window layer's
+    ring (reported ``"<form>.window"``).
+
+    ``select`` (``Selection``; reported ``"absorbed.sparse"``): a query
+    attends, of the positions up to its own, only the ``select.topk``
+    whose index keys its indexer scores highest (``index_scores``,
+    ``select_topk``: exact, no approximation and no blocks of keys),
+    all of them while they are no more than that, where the result
+    equals the dense one. Every index key of the table is scored; then
+    each query's chosen rows ALONE are read out of the two pools
+    (``PoolLayer.rows``, through the page table) and attended absorbed,
+    all heads on the one gathered row: a query reads ``topk`` rows
+    whatever the table holds, at decode and in a prompt chunk alike (a
+    chunk of 1,024 over a table of 16,384 makes an eighth of the logits
+    dense attention would).
+
+    Where the float32 arrays of all queries (their logits, or the
+    indexer's scores over the table) would pass
+    ``_LATENT_LOGITS_LIMIT`` the queries go a block at a time
+    (``_query_block``); without a selection the expanded keys and
+    values are made once for all blocks."""
     B, S, H, nope = q_nope.shape
-    rope, v = q_rope.shape[-1], w_uv.shape[-1]
-    form = latent_form((B, S, H), (c_pages.layout.width, nope, v))
-    _took(form)
-    f32 = jnp.float32
-    cd, rd = (p.layout.unpack(p.pages(page_indices))[:, :, 0]
-              for p in (c_pages, r_pages))   # (B, Sk, rank), (.., rope)
-    slot = jnp.arange(cd.shape[1], dtype=jnp.int32)
-    visible = ((slot[None, None, :] <= q_positions[:, :, None])
-               & (q_positions[:, :, None] >= 0))[:, None]
-    scores = jnp.einsum("bshe,bke->bhsk", q_rope, rd,
-                        preferred_element_type=f32)
-    if form == "expanded":
-        scores = scores + jnp.einsum(
-            "bshn,bkhn->bhsk", q_nope,
-            jnp.einsum("bkr,rhn->bkhn", cd, w_uk),
-            preferred_element_type=f32)
-    else:
-        scores = scores + jnp.einsum(
-            "bshr,bkr->bhsk",
-            jnp.einsum("bshn,rhn->bshr", q_nope, w_uk), cd,
-            preferred_element_type=f32)
-    probs = _masked_softmax(scores * (nope + rope) ** -0.5,
-                            visible).astype(cd.dtype)
-    if form == "expanded":
-        return jnp.einsum("bhsk,bkhv->bshv", probs,
-                          jnp.einsum("bkr,rhv->bkhv", cd, w_uv),
-                          preferred_element_type=f32
-                          ).astype(q_nope.dtype)
-    # The heads stay where the softmax left them: with them moved inside
-    # this einsum's own output, XLA's CPU runtime has no bfloat16 dot to
-    # run it with.
-    ctx = jnp.einsum("bhsk,bkr->bhsr", probs, cd,
-                     preferred_element_type=f32).astype(q_nope.dtype)
-    return jnp.einsum("bhsr,rhv->bshv", ctx, w_uv)
+    v = w_uv.shape[-1]
+    dims = (c_pages.layout.width, nope, v)
+    if ring and not window:
+        raise ValueError("a ring table needs the window it was sized "
+                         "for")
+    if ring and select is not None:
+        raise ValueError("a selection reads a table by position, not a "
+                         "ring")
+    Sk = page_indices.shape[1] * c_pages.page_size
+    slot = jnp.arange(Sk, dtype=jnp.int32)[None]
+    if select is None:
+        form = latent_form((B, S, H), dims)
+        _took(form + ".window" if ring else form)
+        cd, rd = (p.layout.unpack(p.pages(page_indices))[:, :, 0]
+                  for p in (c_pages, r_pages))  # (B, Sk, rank), (.., rope)
+        keys = values = None
+        if form == "expanded":
+            keys = jnp.einsum("bkr,rhn->bkhn", cd, w_uk)
+            values = jnp.einsum("bkr,rhv->bkhv", cd, w_uv)
+
+        def attend(qp, qn, qr):
+            seen = _visible(qp, slot, window, Sk if ring else None)
+            return _latent_pass(form, qn, qr, cd, rd, seen[:, None],
+                                w_uk, w_uv, keys, values)
+
+        return _in_query_blocks(attend, _query_block(B, S, H * Sk),
+                                q_positions, q_nope, q_rope)
+
+    _took("absorbed.sparse")
+    topk, ps = min(select.topk, Sk), c_pages.page_size
+    index_keys = select.pages.layout.unpack(
+        select.pages.pages(page_indices))[:, :, 0]
+
+    def attend(qp, qn, qr, sel_q, sel_w):
+        n = qn.shape[1]
+        positions, kept, _ = select_topk(
+            index_scores(select._replace(q=sel_q, w=sel_w), index_keys),
+            _visible(qp, slot, window, None), topk)
+        pages = jnp.take_along_axis(
+            page_indices, (positions // ps).reshape(B, -1), axis=1
+        ).reshape(positions.shape)
+        # Every query its own rows: a sequence of one query each.
+        cd, rd = (p.layout.unpack(p.rows(pages, positions % ps))
+                  [..., 0, :].reshape((B * n, topk, -1))
+                  for p in (c_pages, r_pages))
+        out = _latent_pass(
+            "absorbed", qn.reshape((B * n, 1) + qn.shape[2:]),
+            qr.reshape((B * n, 1) + qr.shape[2:]), cd, rd,
+            kept.reshape(B * n, 1, 1, -1), w_uk, w_uv)
+        return out.reshape(B, n, H, v)
+
+    # The larger of a query's two float32 rows: its heads' logits over
+    # the chosen rows, the indexer's heads' scores over the table.
+    widest = max(H * topk, select.q.shape[2] * Sk)
+    return _in_query_blocks(attend, _query_block(B, S, widest),
+                            q_positions, q_nope, q_rope, select.q,
+                            select.w)

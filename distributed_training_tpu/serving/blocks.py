@@ -12,7 +12,10 @@ A block has
 - ``cache``: the fields of ``PagedCacheConfig`` that the model decides
   (``n_layers``, ``n_kv_heads``, ``head_dim``, ``v_head_dim``,
   ``kind``; a model that mixes window and global layers adds
-  ``window``, ``window_layers`` and its own name as ``block``). There
+  ``window``, ``window_layers`` and its own name as ``block``, and
+  ``window_head_dim`` / ``window_v_head_dim`` where a window layer's
+  rows are of another width; one whose global layers keep an index key
+  a token adds ``index_dim`` and ``index_topk``). There
   are always two pools ``k_pages`` / ``v_pages``; how they are stored
   is the cache's business (``serving/kv_cache.py::PoolLayout``; with
   window layers each is a pool a kind of layer), what a row of each
@@ -32,12 +35,19 @@ A block has
   ``finish`` the engine calls in that run. A block whose layers are
   all alike returns itself; one whose runs differ (positions on some,
   a window on some) returns the run's view;
-- ``project(layer, x, positions)`` -> ``(q, k_new, v_new)``: the
-  layer's normed input projected; ``k_new`` / ``v_new``
-  ``(..., n_kv_heads, width)`` are the rows this token adds to the two
-  pools, ``q`` whatever the block's ``attend_chunk`` wants (an array
-  or a tuple of arrays, leading shapes as ``x``'s);
-- ``attend_chunk(layer, q, kp, vp, page_rows, q_pos)``: ``S`` lanes of
+- ``project(layer, x, positions)`` -> ``(q, k_new, v_new)`` or ``(q,
+  k_new, v_new, i_new)``: the layer's normed input projected; ``k_new``
+  / ``v_new`` ``(..., n_kv_heads, width)`` are the rows this token adds
+  to the two pools (each kind of layer at its own widths, where the
+  block's ``cache`` gives a window layer others: ``window_head_dim``,
+  ``window_v_head_dim``), ``q`` whatever the block's ``attend_chunk``
+  wants (an array or a tuple of arrays, leading shapes as ``x``'s). A
+  run of GLOBAL layers of a block whose ``cache`` has ``index_dim``
+  (and ``index_topk``) returns the fourth: ``i_new (..., 1,
+  index_dim)``, the token's index key, which the engine writes into
+  the index pool at the same page and slot (``models/
+  sparse_latent_moe.py``);
+- ``attend_chunk(layer, q, kp, vp, page_rows, q_pos[, ip])``: ``S`` lanes of
   ``C`` queries, each against its sequence's pages at positions up to
   its own (``q_pos`` ``(S, C)``, negative = a dead query, zero output).
   ``kp`` / ``vp`` are the layer of the two carried pools, unread
@@ -46,7 +56,10 @@ A block has
   attention entry: the one-token decode program calls it at ``C = 1``.
   In a run of window layers ``page_rows`` are the sequences' RINGS in
   the window pool and ``kp`` / ``vp`` that pool's layer
-  (``ops/paged_attention.py``: ``window=..., ring=True``);
+  (``ops/paged_attention.py``: ``window=..., ring=True``). ``ip``, given
+  where the run's ``project`` returned an index key, is the layer of
+  the index pool, unread like ``kp`` / ``vp`` and addressed by the same
+  ``page_rows``;
 - ``finish(layer, x, attn, valid)`` -> ``(x, counts)``: the output
   projection, the residuals and the feed-forward; ``valid`` marks the
   rows that are real tokens (for counters only);

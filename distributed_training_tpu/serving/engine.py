@@ -456,9 +456,13 @@ def _counters(block, plan) -> tuple:
     tokens: the block's, then the engine's own —
     ``window_bound_iters``, the slot-iterations (lanes of a chunk
     launch, slots of a decode iteration) whose sequence was longer than
-    the window, where the block has window layers."""
+    the window, where the block has window layers, and
+    ``sparse_bound_iters``, those whose sequence was longer than the
+    ``index_topk`` positions a learned selection keeps, where its
+    global layers have one."""
     return tuple(block.counters) + (
-        ("window_bound_iters",) if plan.window_layers else ())
+        ("window_bound_iters",) if plan.window_layers else ()) + (
+        ("sparse_bound_iters",) if plan.index_topk else ())
 
 
 def _named(name: str, body):
@@ -2599,21 +2603,21 @@ def _grouped(pools):
     return jax.tree.map(lambda p: p[None], pools)
 
 
-def _write_kv(plan, k_pages, v_pages, layer, k_new, v_new, page_ids,
-              offsets):
-    """Scatter per-row new KV into one layer of the group's pool, where
-    the pool lies.
+def _write_rows(layout, pool, layer, rows, page_ids, offsets):
+    """Scatter per-token new rows into one layer of a group's pool,
+    where the pool lies.
 
-    k_pages/v_pages the group's pools as stored (of the layer's kind),
-    each with its layout of ``plan``; layer () int32, its number in
-    that pool; k_new/v_new (B, Hkv, width), each
-    pool its own width; page_ids/offsets (B,) int32 — rows whose write
+    pool the group's pool as stored (of the layer's kind), with its
+    ``layout`` of the plan; layer () int32, its number in that pool;
+    rows (..., heads, width) in the pool's own width; page_ids/offsets
+    (...) int32, shaped like the rows' leading axes — rows whose write
     must be dead point at the scratch page (id 0). Live rows never
     share a (page, slot) pair (pages are owned by exactly one
     sequence), so scatter order is immaterial; scratch-page collisions
     write garbage over garbage."""
-    return (plan.k.write(k_pages, layer, page_ids, offsets, k_new),
-            plan.v.write(v_pages, layer, page_ids, offsets, v_new))
+    return layout.write(
+        pool, layer, page_ids.reshape(-1), offsets.reshape(-1),
+        rows.reshape((-1,) + rows.shape[-2:]).astype(pool.dtype))
 
 
 def _scan_layers(block, plan, params, x, k_pages_g, v_pages_g,
@@ -2623,8 +2627,11 @@ def _scan_layers(block, plan, params, x, k_pages_g, v_pages_g,
     (``positions`` shaped like ``x`` less its width), the new rows go
     into the layer's pool at its kind's ``coords[kind] = (page_ids,
     offsets)`` (shaped like ``positions``: a whole lane table is one
-    scatter, whose live coordinates never collide), ``attend(kind,
-    run, layer, q, k_new, v_new, kp, vp)`` is the program's own way to
+    scatter, whose live coordinates never collide; a block that
+    projects a third row, its index key, has it written at the same
+    coordinates into the index pool, ``kv_cache.Pools.index`` of
+    ``k_pages_g``), ``attend(kind, run, layer, q, k_new, v_new, kp,
+    vp[, ip])`` is the program's own way to
     the block's attention, and the block finishes the layer; ``valid``
     marks real tokens for the block's counters. One ``lax.scan`` a run
     of like layers (``block.segments``; ``run = block.at(first
@@ -2642,23 +2649,30 @@ def _scan_layers(block, plan, params, x, k_pages_g, v_pages_g,
 
     def layer_body(kind, run):
         page_ids, offsets = coords[kind]
+        k_layout, v_layout = plan.of(kind)
 
         def body(carry, inp):
             x, kg, vg = carry
             layer, number = inp
-            q, k, v = run.project(layer, x, positions)
-            kp, vp = pool_of(kg, kind), pool_of(vg, kind)
-            kp, vp = _write_kv(
-                plan, kp, vp, number,
-                k.reshape((-1,) + k.shape[-2:]).astype(kp.dtype),
-                v.reshape((-1,) + v.shape[-2:]).astype(vp.dtype),
-                page_ids.reshape(-1), offsets.reshape(-1))
+            q, k, v, *key = run.project(layer, x, positions)
+            kp = _write_rows(k_layout, pool_of(kg, kind), number, k,
+                             page_ids, offsets)
+            vp = _write_rows(v_layout, pool_of(vg, kind), number, v,
+                             page_ids, offsets)
+            kg, vg = with_pool(kg, kind, kp), with_pool(vg, kind, vp)
+            index = ()
+            if key:
+                # The third row: the index key, at the same page and
+                # slot of the index pool's layer of the same number.
+                ip = _write_rows(plan.index, kg.index, number, key[0],
+                                 page_ids, offsets)
+                kg = kg._replace(index=ip)
+                index = (plan.index.layer(ip, number),)
             attn = attend(kind, run, layer, q, k, v,
-                          plan.k.layer(kp, number),
-                          plan.v.layer(vp, number))
+                          k_layout.layer(kp, number),
+                          v_layout.layer(vp, number), *index)
             x, counts = run.finish(layer, x, attn, valid)
-            return (x, with_pool(kg, kind, kp),
-                    with_pool(vg, kind, vp)), counts
+            return (x, kg, vg), counts
         return body
 
     counts = jnp.zeros((len(block.counters),), jnp.int32)
@@ -2769,12 +2783,15 @@ def _chunk_hidden(params, k_pages_g, v_pages_g, page_rows, tokens,
     x, counts, k_pages_g, v_pages_g = _scan_layers(
         block, plan, params, x, k_pages_g, v_pages_g, abs_pos, coords,
         valid,
-        lambda kind, run, layer, q, _k, _v, kp, vp: run.attend_chunk(
-            layer, q, kp, vp, rows[kind], q_pos))
-    if plan.window_layers:
-        bound = active & (start_pos + n_valid > plan.window)
+        lambda kind, run, layer, q, _k, _v, kp, vp, *ip:
+        run.attend_chunk(layer, q, kp, vp, rows[kind], q_pos, *ip))
+    # The engine's own counters, in ``_counters``' order: sequences
+    # longer than the window, and than the selection's top-k.
+    for bound in ((plan.window,) if plan.window_layers else ()) + (
+            (plan.index_topk,) if plan.index_topk else ()):
+        over = active & (start_pos + n_valid > bound)
         counts = jnp.concatenate(
-            [counts, jnp.sum(bound, dtype=jnp.int32)[None]])
+            [counts, jnp.sum(over, dtype=jnp.int32)[None]])
     return x, valid, counts, k_pages_g, v_pages_g
 
 

@@ -50,6 +50,20 @@ on. Whatever moves pages by ONE table a sequence (the prefix index,
 refuses such a cache by name (``full_tables_only``); a model without
 window layers gets exactly the single pool described above.
 
+**Rows a kind, and a third row (PR 34).** The two kinds need not store
+rows of one width: a window layer's may be wider or narrower than a
+global layer's (``cfg.window_head_dim`` / ``window_v_head_dim``), so a
+layout belongs to a kind (``PagedKVCache.layouts(cfg, shards, kind)``,
+``PoolPlan.of(kind)``). And a global layer may keep a THIRD row a
+token, the key a learned selection scores every iteration
+(``cfg.index_dim`` wide, one a token): the INDEX pool, a member of
+``k_pages``' ``Pools`` beside the table it belongs to. It has the
+global pool's pages and is addressed by the global table, so one
+allocator a kind still serves: a page taken or freed is taken or freed
+in both, and the index pool is donated, carried and deleted with
+``k_pages``. Such a cache, too, refuses whatever moves pages by one
+table (``full_tables_only``): those movers copy two rows a token.
+
 **Page 0 of every group is that group's scratch page**: never
 allocated, the write target for inactive batch slots and padding
 positions (the jitted decode/prefill programs write unconditionally;
@@ -286,21 +300,26 @@ def copy_pages(pool, src, dst):
 
 
 class Pools(NamedTuple):
-    """The pools of a cache with two kinds of layer, in place of the
-    one array a uniform cache has: ``full`` holds the global layers
-    (tables that grow), ``ring`` the window layers (a ring a sequence).
-    A pytree, so it crosses ``jax.jit`` and is donated like the array
-    it stands for; ``delete`` frees both."""
+    """The pools of a cache with more than one, in place of the one
+    array a uniform cache has: ``full`` holds the global layers (tables
+    that grow), ``ring`` the window layers (a ring a sequence; None
+    without window layers), ``index`` the global layers' third row, the
+    key a learned selection scores (``k_pages`` only, at the global
+    table's pages; None where the model keeps none). A pytree, so it
+    crosses ``jax.jit`` and is donated like the array it stands for;
+    ``delete`` frees every member."""
 
     full: object
-    ring: object
+    ring: object = None
+    index: object = None
 
     def delete(self) -> None:
-        self.full.delete()
-        self.ring.delete()
+        for pool in self:
+            if pool is not None:
+                pool.delete()
 
 
-GLOBAL, WINDOW = "global", "window"
+GLOBAL, WINDOW, INDEX = "global", "window", "index"
 
 
 def pool_of(pools, kind: str):
@@ -321,10 +340,14 @@ def with_pool(pools, kind: str, pool):
 @dataclass(frozen=True)
 class PoolPlan:
     """What every program knows of the cache when it is traced: how the
-    two pools store a row (``k``, ``v``), how wide a sequence's table
-    is (``pages_per_seq``) and, where the model has window layers,
-    which they are, how wide the window and how many pages a ring
-    (``PagedKVCache.plan``). Static in every program."""
+    two pools store a global layer's row (``k``, ``v``) and a window
+    layer's (``ring_k``, ``ring_v``: ``of(kind)``), how wide a
+    sequence's table is (``pages_per_seq``), where the model has window
+    layers which they are, how wide the window and how many pages a
+    ring, and where its global layers keep an index key a token, how
+    the index pool stores it (``index``) and how many positions the
+    selection keeps (``index_topk``) (``PagedKVCache.plan``). Static in
+    every program."""
 
     k: PoolLayout
     v: PoolLayout
@@ -333,6 +356,16 @@ class PoolPlan:
     window: int = 0
     window_layers: tuple = ()
     ring_pages: int = 0
+    ring_k: PoolLayout | None = None
+    ring_v: PoolLayout | None = None
+    index: PoolLayout | None = None
+    index_topk: int = 0
+
+    def of(self, kind: str) -> tuple:
+        """``(k_layout, v_layout)`` of ``kind`` layers' rows."""
+        if kind == WINDOW:
+            return self.ring_k or self.k, self.ring_v or self.v
+        return self.k, self.v
 
     def run(self, lo: int, hi: int) -> tuple:
         """``(kind, first)`` for the run of like layers ``[lo, hi)``:
@@ -388,6 +421,13 @@ class PoolLayer:
         tiles = self.layout.tiled(self.pool[self.number, page_indices])
         return tiles.reshape(tiles.shape[:1] + (-1,) + tiles.shape[3:])
 
+    def rows(self, page_ids, offsets):
+        """The rows at ``(page_ids, offsets)`` (one shape, any), ``(...,
+        tiles, tile)``: the read of a selection, which takes of a table
+        the rows it chose and no page whole."""
+        return self.layout.tiled(
+            self.pool[self.number, page_ids, offsets])
+
 
 def as_layer(pages) -> PoolLayer:
     """Head-major keys or values ``(heads, num_pages, page_size,
@@ -413,7 +453,12 @@ class PagedCacheConfig:
     ``num_pages`` is PER GROUP (each dp group owns its own shard of
     ``num_pages`` pages, scratch included). ``head_dim`` is the width
     of a ``k_pages`` row, ``v_head_dim`` of a ``v_pages`` row (0 = the
-    same)."""
+    same); a window layer's rows have ``window_head_dim`` /
+    ``window_v_head_dim`` where those are given (0 = as the global
+    layers'). ``index_dim``: the width of the third row a token that
+    every global layer keeps in the index pool (0 = none), and
+    ``index_topk`` the positions its selection keeps, for the
+    engine's counter."""
 
     n_layers: int
     n_kv_heads: int
@@ -436,10 +481,24 @@ class PagedCacheConfig:
     max_write: int = 1
     slots: int = 0
     block: str = ""
+    window_head_dim: int = 0
+    window_v_head_dim: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
 
     def __post_init__(self):
         if self.v_head_dim == 0:
             object.__setattr__(self, "v_head_dim", self.head_dim)
+        if (self.window_head_dim or self.window_v_head_dim) \
+                and not self.window_layers:
+            raise ValueError("window_head_dim / window_v_head_dim are "
+                             "the widths of window layers' rows, and "
+                             "there are no window_layers")
+        if bool(self.index_dim) != bool(self.index_topk):
+            raise ValueError(
+                f"index_dim ({self.index_dim}) and index_topk "
+                f"({self.index_topk}) come together: the key a "
+                "selection scores and how many positions it keeps")
         object.__setattr__(self, "window_layers",
                            tuple(sorted(self.window_layers)))
         if self.window_layers:
@@ -520,13 +579,32 @@ class PagedCacheConfig:
     def usable_pages_total(self) -> int:
         return self.dp_groups * self.usable_pages
 
-    def kv_bytes_per_token(self) -> int:
-        """HBM cost of one cached token across all layers (a row of
-        each pool; lane padding not counted)."""
+    def widths(self, kind: str) -> tuple:
+        """``(k_pages row, v_pages row)`` widths a kv head of ``kind``
+        layers."""
+        if kind == WINDOW:
+            return (self.window_head_dim or self.head_dim,
+                    self.window_v_head_dim or self.v_head_dim)
+        return self.head_dim, self.v_head_dim
+
+    def row_bytes(self, kind: str) -> int:
+        """What one cached token costs in ONE layer of ``kind``
+        (``"index"``: its key in the index pool), lane padding not
+        counted."""
         # numpy alone has no bfloat16
         itemsize = jax.numpy.dtype(self.dtype).itemsize
-        return (self.n_layers * self.n_kv_heads
-                * (self.head_dim + self.v_head_dim) * itemsize)
+        if kind == INDEX:
+            return self.index_dim * itemsize
+        return self.n_kv_heads * sum(self.widths(kind)) * itemsize
+
+    def kv_bytes_per_token(self) -> int:
+        """HBM cost of one cached token across all layers: each kind's
+        layers at that kind's widths, and the index key of every global
+        layer that keeps one (a row of each pool; lane padding not
+        counted)."""
+        return (len(self.global_layers)
+                * (self.row_bytes(GLOBAL) + self.row_bytes(INDEX))
+                + len(self.window_layers) * self.row_bytes(WINDOW))
 
 
 def kv_shards(mesh, kv_axis: str | None) -> int:
@@ -599,8 +677,8 @@ class PagedKVCache:
                 if sharding is not None else z
 
         self.k_pages, self.v_pages = (
-            Pools(*map(pool, shapes)) if isinstance(shapes, Pools)
-            else pool(shapes)
+            Pools(*(s and pool(s) for s in shapes))
+            if isinstance(shapes, Pools) else pool(shapes)
             for shapes in self.pool_shapes(cfg, shards))
         # Host allocator state, PER GROUP. Free lists are LIFO:
         # recently-freed pages are re-handed first (warm in cache, and
@@ -639,59 +717,94 @@ class PagedKVCache:
     # -- the stored layout -------------------------------------------------
 
     @staticmethod
-    def layouts(cfg: PagedCacheConfig, shards: int = 1) -> tuple:
+    def layouts(cfg: PagedCacheConfig, shards: int = 1,
+                kind: str = GLOBAL) -> tuple:
         """``(k_layout, v_layout)``: how the two pools of ``cfg``
-        store a row, their heads over ``shards`` devices. The one
-        place a layout is chosen, from the widths alone; the engine's
-        programs take theirs from here as the cache does."""
-        return (PoolLayout(cfg.n_kv_heads, cfg.head_dim, shards),
-                PoolLayout(cfg.n_kv_heads, cfg.v_head_dim, shards))
+        store a row of a ``kind`` layer, their heads over ``shards``
+        devices. The one place a layout is chosen, from the widths
+        alone; the engine's programs take theirs from here as the
+        cache does."""
+        k, v = cfg.widths(kind)
+        return (PoolLayout(cfg.n_kv_heads, k, shards),
+                PoolLayout(cfg.n_kv_heads, v, shards))
+
+    @staticmethod
+    def index_layout(cfg: PagedCacheConfig):
+        """How the index pool stores a token's key (one a token, for
+        every head of the selection); None where ``cfg`` keeps none."""
+        return PoolLayout(1, cfg.index_dim) if cfg.index_dim else None
 
     @classmethod
     def plan(cls, cfg: PagedCacheConfig, shards: int = 1) -> PoolPlan:
         """What the engine's programs are traced with (``PoolPlan``)."""
+        ring = cls.layouts(cfg, shards, WINDOW) if cfg.window_layers \
+            else (None, None)
         return PoolPlan(*cls.layouts(cfg, shards), cfg.n_layers,
                         cfg.pages_per_seq, cfg.window,
-                        cfg.window_layers, cfg.ring_pages)
+                        cfg.window_layers, cfg.ring_pages, *ring,
+                        cls.index_layout(cfg), cfg.index_topk)
 
     @classmethod
     def pool_shapes(cls, cfg: PagedCacheConfig,
                     shards: int = 1) -> tuple:
         """The shapes of ``k_pages`` and ``v_pages``, nothing
         allocated (what an abstract lowering needs): a shape each, or
-        with window layers a ``Pools`` of two, the global layers in
-        ``num_pages`` pages and the window layers in a ring a slot."""
-        def shapes(lay):
-            if not cfg.window_layers:
-                return (cfg.dp_groups,) + lay.shape(
-                    cfg.n_layers, cfg.num_pages, cfg.page_size)
-            return Pools(*(
-                (cfg.dp_groups,) + lay.shape(len(layers), pages,
-                                             cfg.page_size)
-                for layers, pages in (
-                    (cfg.global_layers, cfg.num_pages),
-                    (cfg.window_layers, cfg.window_num_pages))))
-        return tuple(shapes(lay) for lay in cls.layouts(cfg, shards))
+        a ``Pools`` where there is more than one pool: with window
+        layers the global layers in ``num_pages`` pages and the window
+        layers in a ring a slot, each at its kind's widths, and in
+        ``k_pages`` the index pool at the global pool's pages where
+        ``cfg`` keeps an index key."""
+        def shape(lay, layers, pages):
+            return (cfg.dp_groups,) + lay.shape(len(layers), pages,
+                                                cfg.page_size)
+
+        def shapes(which):
+            full = shape(cls.layouts(cfg, shards)[which],
+                         cfg.global_layers, cfg.num_pages)
+            ring = shape(cls.layouts(cfg, shards, WINDOW)[which],
+                         cfg.window_layers, cfg.window_num_pages) \
+                if cfg.window_layers else None
+            index = shape(cls.index_layout(cfg), cfg.global_layers,
+                          cfg.num_pages) \
+                if cfg.index_dim and which == 0 else None
+            if ring is None and index is None:
+                return full
+            return Pools(full, ring, index)
+        return shapes(0), shapes(1)
 
     def pools(self) -> list:
         """One entry a pool: its kind, its layers, its pages (all
         groups, scratch included), the pages of a sequence's ring (0:
-        a table that grows), what it takes as stored and what a token
-        costs in it a layer (``serving_warmup``'s ``pools``)."""
+        a table that grows), the lanes a row takes in ``k_pages`` and
+        ``v_pages`` as stored (``row_lanes``), what a token costs in it
+        a layer before lane padding (``row_bytes``), and what the pool
+        takes as stored in all and a token (``serving_warmup``'s
+        ``pools``). The index pool, where there is one, is an entry of
+        its own with the global pool's pages."""
         cfg = self.cfg
         itemsize = jax.numpy.dtype(cfg.dtype).itemsize
-        row = (self.k_layout.lanes + self.v_layout.lanes) * itemsize
-        kinds = ([(GLOBAL, cfg.global_layers, cfg.num_pages, 0),
-                  (WINDOW, cfg.window_layers, cfg.window_num_pages,
-                   cfg.ring_pages)] if cfg.window_layers else
-                 [(GLOBAL, tuple(range(cfg.n_layers)), cfg.num_pages,
-                   0)])
-        return [{"kind": kind, "layers": list(layers),
-                 "pages": cfg.dp_groups * pages, "ring_pages": ring,
-                 "bytes": cfg.dp_groups * pages * cfg.page_size
-                 * len(layers) * row,
-                 "bytes_per_token": len(layers) * row}
-                for kind, layers, pages, ring in kinds]
+        kinds = [(GLOBAL, cfg.global_layers, cfg.num_pages, 0,
+                  self.layouts(cfg, self.k_layout.shards))]
+        if cfg.window_layers:
+            kinds.append((WINDOW, cfg.window_layers,
+                          cfg.window_num_pages, cfg.ring_pages,
+                          self.layouts(cfg, self.k_layout.shards,
+                                       WINDOW)))
+        if cfg.index_dim:
+            kinds.append((INDEX, cfg.global_layers, cfg.num_pages, 0,
+                          (self.index_layout(cfg),)))
+        out = []
+        for kind, layers, pages, ring, lays in kinds:
+            lanes = [lay.lanes for lay in lays]
+            row = sum(lanes) * itemsize
+            out.append({
+                "kind": kind, "layers": list(layers),
+                "pages": cfg.dp_groups * pages, "ring_pages": ring,
+                "row_lanes": lanes, "row_bytes": cfg.row_bytes(kind),
+                "bytes": cfg.dp_groups * pages * cfg.page_size
+                * len(layers) * row,
+                "bytes_per_token": len(layers) * row})
+        return out
 
     def footprint(self) -> dict:
         """What the pools take: their stored shapes, the bytes of the
@@ -700,13 +813,13 @@ class PagedKVCache:
         temporaries reach these holds a copy of the pool."""
         cfg = self.cfg
         pools = self.pools()
-        rows = cfg.kv_bytes_per_token() // cfg.n_layers   # a layer's
         return {
             "pool_shapes": [list(a.shape) for p in (self.k_pages,
                                                     self.v_pages)
                             for a in jax.tree.leaves(p)],
             "pool_bytes": sum(p["pages"] * cfg.page_size
-                              * len(p["layers"]) * rows for p in pools),
+                              * len(p["layers"]) * p["row_bytes"]
+                              for p in pools),
             "pool_bytes_tiled": sum(p["bytes"] for p in pools)}
 
     def read_pages(self, groups, pages) -> tuple:
@@ -741,9 +854,11 @@ class PagedKVCache:
 
     def full_tables_only(self, feature: str) -> None:
         """Refuse ``feature``, which moves pages by ONE table a
-        sequence, on a cache with window layers: their pages are a
-        ring that is overwritten as the sequence grows, so a page
-        taken from it is not the prefix it once held."""
+        sequence and two rows a token, on a cache with window layers
+        (their pages are a ring that is overwritten as the sequence
+        grows, so a page taken from it is not the prefix it once held)
+        or with an index pool (a page's third row would stay
+        behind)."""
         if self.cfg.window_layers:
             raise NotImplementedError(
                 f"{feature}: {self.cfg.block or 'the block'} has "
@@ -752,6 +867,13 @@ class PagedKVCache:
                 f"pages are a ring of {self.cfg.ring_pages} a "
                 "sequence, and this moves pages by one full table a "
                 "sequence (ROADMAP M3)")
+        if self.cfg.index_dim:
+            raise NotImplementedError(
+                f"{feature}: {self.cfg.block or 'the block'} keeps an "
+                f"index key a token ({self.cfg.index_dim} wide) in a "
+                "third pool beside its global layers' rows, and this "
+                "moves the two rows a token of k_pages and v_pages "
+                "(ROADMAP M3)")
 
     def free_pages_in(self, group: int) -> int:
         return len(self._frees[group])

@@ -404,7 +404,13 @@ class ServingServer:
         them: ``{"token": N}`` per sampled token, then a final
         ``{"done": True, "tokens", "ttft_s", "latency_s"}``. The
         tokens flow engine thread → per-request queue → this
-        generator, so a slow consumer never stalls decode."""
+        generator, so a slow consumer never stalls decode.
+        ``timeout`` bounds the wait for the NEXT item (the first
+        token, which waits for the queue and the prompt's prefill, or
+        the token after the last), not the whole request: a stream
+        that delivers is alive however long its output (1,024 tokens
+        from an engine whose 32 slots share 160 tokens/s are over 200
+        s), and a wedged engine still frees the handler thread."""
         arrival = time.monotonic()
         q: queue.Queue = queue.Queue()
         with self._lock:
@@ -427,6 +433,7 @@ class ServingServer:
                     ) from None
                 if kind == "token":
                     yield {"token": int(val)}
+                    deadline = time.monotonic() + timeout
                     continue
                 if "error" in val:
                     raise ValueError(val["error"])
