@@ -12,6 +12,13 @@ the rule takes, the faster form, the worst difference between the two)
 and writes them to ``chiprun_out/latent_form_table.json``. Times are of
 twenty calls after one, a layer alone: what decides between two forms,
 not a benchmark result.
+
+Then the table of ``sparse_form``: a full layer of ``dots3-note-ep8``
+under its selection of 2,048 (``chip_smoke.py::sparse_latent_case``), a
+prompt chunk of 1 x 1024 in its masked and its gather form at 4k, 8k
+and 16k of context over the cell's table of 16,384 rows, and over
+tables of 32k to 128k rows read to their end, where the two cross
+(``_ROW_GATHER``; ten calls after one).
 """
 
 from __future__ import annotations
@@ -79,6 +86,34 @@ def case(B: int, S: int, reps: int = 20) -> dict:
     return row
 
 
+# (pages of 16 a table, contexts): the cell's table, then longer ones
+# read to their end.
+SPARSE = [(1024, (4096, 8192, 16384)), (2048, (32768,)),
+          (4096, (65536,)), (8192, (131072,))]
+
+
+def sparse_rows(pages: int, contexts, B: int = 1, S: int = 1024,
+                **dims) -> list:
+    """One row a context: ``flash_ms`` and ``absorbed_ms`` (the masked
+    and the gather form) of a full layer's call over a table of
+    ``pages`` pages, the form the rule takes and the faster one."""
+    import chip_smoke
+    from distributed_training_tpu.ops import paged_attention as pa
+
+    dims = {**dims, "P": pages}
+    d = {**chip_smoke.SPARSE_LATENT["full"], **dims}
+    case = chip_smoke.sparse_latent_case(
+        "full", B, S, context=contexts[0], contexts=contexts[1:],
+        dense=False, **dims)
+    rule = pa.sparse_form((B, S, d["H"]), pages * 16, d["index"][2],
+                          (d["rank"], d["nope"], d["rope"], d["v"]))
+    return [{"B": B, "S": S, "table": pages * 16, "context": c,
+             "rule": rule, **row,
+             "faster": ("flash" if row["flash_ms"] < row["absorbed_ms"]
+                        else "absorbed")}
+            for c, row in case.get("forms_ms", {}).items()]
+
+
 def main() -> int:
     import jax
 
@@ -90,6 +125,10 @@ def main() -> int:
     for B, S in SHAPES:
         rows.append(case(B, S))
         print(json.dumps(rows[-1]), flush=True)
+    for pages, contexts in SPARSE:
+        for row in sparse_rows(pages, contexts):
+            rows.append(row)
+            print(json.dumps(row), flush=True)
     out = os.path.join(REPO, "chiprun_out")
     os.makedirs(out, exist_ok=True)
     with open(os.path.join(out, "latent_form_table.json"), "w") as f:

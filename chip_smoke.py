@@ -351,18 +351,22 @@ SPARSE_LATENT = {
 
 
 def sparse_latent_case(kind: str, B: int, S: int, context: int = 8192,
-                       ps: int = 16, reps: int = 10) -> dict:
+                       ps: int = 16, reps: int = 10, contexts=(),
+                       dense: bool = True, **dims) -> dict:
     """One layer's ``latent_attention_chunk`` of ``kind`` (``"full"``:
     under the learned selection; ``"window"``: over a ring) at
-    dots3-note-ep8's widths, ``B`` sequences of ``S`` queries that end
-    at ``context``: its smoke time a call and, for a full layer, that
-    of dense attention over the same table (every earlier position
-    seen, queries a block at a time where the logits ask for it), so
-    that a ``perf_opt`` has a layer's time to start from. Checked: the
-    selection with room for every position gives what dense attention
-    gives (the chosen rows read through the page table, or the mask);
-    a ring no longer than its slots gives what the same pages give as a
-    table."""
+    dots3-note-ep8's widths (``dims``: others, for a rehearsal or a
+    longer table), ``B`` sequences of ``S`` queries that end at
+    ``context``: its smoke time a call and, for a full layer, that of
+    dense attention over the same table (``dense``; every earlier
+    position seen, queries a block at a time where the logits ask for
+    it). Where a full layer's queries can share rows (``S * topk``
+    over the table's rows: a prompt chunk), its masked and its gather
+    form are timed side by side and held to each other, at ``context``
+    and at each of ``contexts``: the numbers ``sparse_form`` rests on.
+    Checked besides: the selection with room for every position gives
+    what dense attention gives; a ring no longer than its slots gives
+    what the same pages give as a table."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -370,7 +374,7 @@ def sparse_latent_case(kind: str, B: int, S: int, context: int = 8192,
     from distributed_training_tpu.ops import paged_attention as pa
     from distributed_training_tpu.serving.kv_cache import PoolLayout
 
-    d = SPARSE_LATENT[kind]
+    d = {**SPARSE_LATENT[kind], **dims}
     bf = jnp.bfloat16
     H, rank, P = d["H"], d["rank"], d["P"]
     ks = jax.random.split(jax.random.PRNGKey(SEED + B * 31 + S), 9)
@@ -384,7 +388,7 @@ def sparse_latent_case(kind: str, B: int, S: int, context: int = 8192,
 
     rng = np.random.default_rng(SEED + B)
     tables = (rng.permutation(N - 1).reshape(B, P) + 1).astype(np.int32)
-    ends = np.full(B, context) - rng.integers(0, 64, B)
+    back = rng.integers(0, 64, B)
 
     def positions(ends):
         return jnp.asarray(ends[:, None] - S + np.arange(S)[None, :],
@@ -397,6 +401,12 @@ def sparse_latent_case(kind: str, B: int, S: int, context: int = 8192,
     up = (jax.random.normal(ks[4], (rank, H, d["nope"]), bf)
           * rank ** -0.5,
           jax.random.normal(ks[5], (rank, H, d["v"]), bf) * rank ** -0.5)
+    ends = np.full(B, context) - back
+
+    def at(context):
+        return (*heads, *pools,
+                positions(np.full(B, context) - back), *up)
+    args = at(context)
     label = f"{kind} {B} x {S} at {context}, H{H} rank {rank}, P {P}"
     ms, out = {}, {}
     if kind == "full":
@@ -412,29 +422,58 @@ def sparse_latent_case(kind: str, B: int, S: int, context: int = 8192,
                     qn, qr, c, r, t, qp, uk, uv,
                     select=pa.Selection(iq, iw, ip, k))
 
-        def dense(qn, qr, c, r, t, qp, uk, uv):
+        def dense_call(qn, qr, c, r, t, qp, uk, uv):
             return pa.latent_attention_chunk(qn, qr, c, r, t, qp, uk,
                                              uv, window=Sk)
-        args = (*heads, *pools, positions(ends), *up)
         with pa.observe_forms() as seen:
             _r, out["sparse"], ms["sparse"] = _smoke_time(
                 sparse(topk), args + (iq, iw, ip), reps)
-        _r, out["dense"], ms["dense"] = _smoke_time(dense, args, reps)
-        _r, out["all_kept"], ms["all_kept"] = _smoke_time(
-            sparse(Sk), args + (iq, iw, ip), 1)
-        band = _close("sparse_latent", out["all_kept"], out["dense"])
-        extra = {"sparse_ms": ms["sparse"], "dense_ms": ms["dense"]}
+        extra = {"sparse_ms": ms["sparse"]}
+        band = 0.0
+        if dense:
+            _r, out["dense"], ms["dense"] = _smoke_time(dense_call, args,
+                                                        reps)
+            _r, out["all_kept"], _ms = _smoke_time(
+                sparse(Sk), args + (iq, iw, ip), 1)
+            band = _close("sparse_latent", out["all_kept"], out["dense"])
+            extra["dense_ms"] = ms["dense"]
         say(f"  sparse latent [{label}]: selection of {topk} "
-            f"{ms['sparse']:.3f} ms, dense {ms['dense']:.3f} ms a call "
-            f"(smoke wall; {seen[0]}), all kept against dense "
-            f"{band:.3f} of the bf16 band")
+            f"{ms['sparse']:.3f} ms a call (smoke wall; {seen[0]})"
+            + (f", dense {ms['dense']:.3f} ms, all kept against dense "
+               f"{band:.3f} of the bf16 band" if dense else ""))
+        if S * topk > Sk:
+            # Queries that can share rows: the masked form and the
+            # gather form of the same call, whichever the rule takes.
+            rule, forms = pa.sparse_form, {}
+            for c in dict.fromkeys((context, *contexts)):
+                got, row = {}, {}
+                for form in ("flash", "absorbed"):
+                    if c == context and seen[0] == form + ".sparse":
+                        got[form], row[form + "_ms"] = (out["sparse"],
+                                                        ms["sparse"])
+                        continue
+                    pa.sparse_form = lambda *a, _f=form: _f
+                    try:
+                        _r, got[form], row[form + "_ms"] = _smoke_time(
+                            sparse(topk), at(c) + (iq, iw, ip), reps)
+                    finally:
+                        pa.sparse_form = rule
+                row["err_over_bf16_band"] = _close(
+                    "sparse_latent", got["flash"], got["absorbed"])
+                band = max(band, row["err_over_bf16_band"])
+                forms[c] = row
+                say(f"  sparse latent [{label}] at {c}: masked "
+                    f"{row['flash_ms']:.3f} ms, gather "
+                    f"{row['absorbed_ms']:.3f} ms a call (smoke wall), "
+                    f"{row['err_over_bf16_band']:.3f} of the bf16 band "
+                    "apart")
+            extra["forms_ms"] = forms
     else:
         def ring(is_ring):
             return lambda qn, qr, c, r, t, qp, uk, uv: \
                 pa.latent_attention_chunk(qn, qr, c, r, t, qp, uk, uv,
                                           window=d["window"],
                                           ring=is_ring)
-        args = (*heads, *pools, positions(ends), *up)
         with pa.observe_forms() as seen:
             _r, _o, ms["ring"] = _smoke_time(ring(True), args, reps)
         # Before the ring's first turn a position is its ring slot.
@@ -500,11 +539,14 @@ def phase_kernels() -> dict:
          lambda: paged_prefill_case(1, 1024, 28, 4, P=320, N=10241,
                                     window=4096, ring=True, start=8192)),
         # dots3-note-ep8's two kinds of latent layer, a decode
-        # iteration and a prompt chunk at 8k of context.
+        # iteration and a prompt chunk at 8k of context; the full
+        # layer's chunk in its masked and its gather form at 4k, 8k
+        # and 16k.
         ("sparse_latent_full_32x1",
          lambda: sparse_latent_case("full", 32, 1)),
         ("sparse_latent_full_1x1024",
-         lambda: sparse_latent_case("full", 1, 1024)),
+         lambda: sparse_latent_case("full", 1, 1024,
+                                    contexts=(4096, 16384))),
         ("sparse_latent_window_32x1",
          lambda: sparse_latent_case("window", 32, 1)),
         ("sparse_latent_window_1x1024",

@@ -26,7 +26,12 @@ absorbed or an expanded form that ``latent_form`` takes from the shapes;
 over a window layer's ring (``window=``, ``ring=``) and under a learned
 selection of positions (``select=``: the indexer's scores over the index
 pool's layer, ``index_scores``, and an exact top-k, ``select_topk``) as
-well.
+well. Under a selection a query reads its chosen rows alone (the gather
+form, decode) or, where a sequence's queries choose more rows than its
+table has (a prompt chunk), the table is read once and one Pallas
+kernel (``dtt_sparse_prefill``, ``_sparse_flash_attention``) attends
+under the selection as a mask; ``sparse_form`` takes one from the
+shapes.
 
 One entrypoint over keys and values:
 
@@ -85,8 +90,9 @@ One entrypoint over keys and values:
 There is no switch between the forms: static shapes decide, so a
 compiled program takes a form always or never. The form each took
 (``"pool"``, ``"gather"``, ``"flash"``, of latent attention
-``"absorbed"``, ``"expanded"``; ``".sparse"`` behind it under a
-selection, ``".window"`` over a ring) is seen at trace time by
+``"absorbed"``, ``"expanded"``; under a selection ``"absorbed.sparse"``
+or ``"flash.sparse"``; ``".window"`` behind it over a ring) is seen at
+trace time by
 ``observe_forms`` and reported per
 program by ``Engine.paged_forms()`` and the ``serving_warmup`` telemetry
 record (docs/observability.md).
@@ -114,8 +120,9 @@ _observers: list[list[str]] = []
 def observe_forms():
     """Collect, while open, the form every ``paged_attention_chunk``
     (``"pool"``, ``"gather"``, ``"flash"``) and ``latent_attention_chunk``
-    (``"absorbed"``, ``"expanded"``; ``".sparse"``, ``".window"`` behind
-    either) call takes. The form follows from
+    (``"absorbed"``, ``"expanded"``, ``".window"`` behind either;
+    ``"absorbed.sparse"``, ``"flash.sparse"``) call takes. The form
+    follows from
     static shapes, so a call is seen when the program around it is
     TRACED: the engine opens this around each program's body
     (``serving/engine.py::_named``)."""
@@ -683,6 +690,208 @@ def _in_query_blocks(attend, block: int, q_positions, *by_query):
     return out[:, :S]
 
 
+# What one row read out of a pool by ``PoolLayer.rows`` costs a v5e, in
+# the multiply-adds the masked kernel does in that time: fitted to one
+# full layer's call at dots3-note-ep8's widths, 1 x 1024 queries over
+# tables of 16k to 128k rows (benchmarks/latent_form_table.py; PERF.md
+# section 6).
+_ROW_GATHER = 1.8e6
+
+# A query block and a key block of the masked kernel: the chunk is one
+# query block wherever it can be, because a key block's rows are
+# expanded once a query block.
+_SPARSE_ROWS = 1024
+_SPARSE_SLOTS = 512
+
+
+def _sparse_blocks(S: int, Sk: int) -> tuple:
+    """``(block_q, block_k)`` of ``_sparse_flash_attention``: a power of
+    two of queries from 32 (an 8-bit mask block's sublanes) to
+    ``_SPARSE_ROWS``, and the largest key block that divides the slots
+    once they are padded to whole 128s."""
+    block_q = 32
+    while block_q < min(S, _SPARSE_ROWS):
+        block_q *= 2
+    padded = -(-Sk // 128) * 128
+    block_k = next(b for b in (_SPARSE_SLOTS, 256, 128)
+                   if padded % b == 0)
+    return block_q, block_k
+
+
+def sparse_form(q_shape, slots: int, topk: int, dims) -> str:
+    """``"flash"`` or ``"absorbed"`` for ``latent_attention_chunk``
+    under a selection of ``topk`` of a table's ``slots`` rows, at q
+    ``(B, S, H)`` and widths ``dims = (rank, nope, rope, v)``. One
+    algorithm whose cost has two regimes. Gathering, a sequence reads
+    ``S * topk`` rows one at a time, each at a price that no width
+    moves (``_ROW_GATHER``); masked, it reads its table once and pays
+    dense attention's multiply-adds on the MXU, a key block's expansion
+    once a query block among them. So the mask wins where a sequence's
+    queries choose more rows than its table has (a prompt chunk; at
+    decode no query shares a row with another) and the table is not so
+    long that dense arithmetic loses to ``topk`` gathered rows."""
+    _B, S, H = q_shape
+    rank, nope, rope, v = dims
+    block_q, _ = _sparse_blocks(S, slots)
+    masked = H * slots * (S * (nope + rope + v)
+                          + -(-S // block_q) * (nope + v) * rank)
+    return ("flash" if S * topk > slots
+            and masked < S * topk * _ROW_GATHER else "absorbed")
+
+
+def _lane_pad(x: jax.Array) -> jax.Array:
+    """``x`` with its last axis zero-padded to whole 128 lanes."""
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1)
+                   + ((0, -x.shape[-1] % 128),))
+
+
+def _sparse_kernel(top_ref, qn_ref, qr_ref, c_ref, r_ref, uk_ref, uv_ref,
+                   chosen_ref, o_ref, acc_ref, m_ref, l_ref, *, scale,
+                   block_k):
+    """One (sequence, head, query block, key block) of the masked form:
+    ``_flash_kernel`` with the selection for its mask and the key
+    block's latent rows expanded here, in VMEM, into this head's keys
+    and values. ``top_ref`` holds each query block's highest live
+    position: key blocks past it are skipped."""
+    b, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    @pl.when(ki * block_k <= top_ref[b, qi])
+    def _compute():
+        c, r = c_ref[0], r_ref[0]
+        keys = jnp.dot(c, uk_ref[:], preferred_element_type=jnp.float32
+                       ).astype(c.dtype)
+        values = jnp.dot(c, uv_ref[:], preferred_element_type=jnp.float32
+                         ).astype(c.dtype)
+        across = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(qn_ref[0], keys, across,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr_ref[0], r, across,
+                                   preferred_element_type=jnp.float32)
+             ) * scale
+        # ``chosen`` holds only positions up to the query's own, and
+        # none for a dead query.
+        s = jnp.where(chosen_ref[0].astype(jnp.int32) != 0, s, NEG_INF)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # As ``_flash_kernel``: a row that has seen nothing yet.
+        m_use = jnp.where(m_new == NEG_INF, 0.0, m_new)
+        p = jnp.exp(s - m_use)
+        alpha = jnp.exp(m_prev - m_use)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+            p.astype(values.dtype), values,
+            preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _finalize():
+        lsum = l_ref[:]
+        o_ref[0] = (acc_ref[:] / jnp.where(lsum == 0.0, 1.0, lsum)
+                    ).astype(o_ref.dtype)
+
+
+def _sparse_flash_attention(q_nope: jax.Array, q_rope: jax.Array,
+                            c_pages, r_pages, page_indices: jax.Array,
+                            q_positions: jax.Array, chosen: jax.Array,
+                            w_uk: jax.Array, w_uv: jax.Array,
+                            blocks=None) -> jax.Array:
+    """The masked form of latent attention under a selection: each
+    sequence's table read once, dense in logical order, and ONE Pallas
+    kernel (``dtt_sparse_prefill``) that attends a block of queries
+    against a block of its rows where ``chosen (B, S, Sk)`` (8-bit,
+    ``select_topk``'s mask) is set, softmax online, no logits in HBM.
+
+    Grid ``(B, heads, query blocks, key blocks)``, key blocks innermost
+    and sequential. A step expands its key block's latent rows by the
+    head's ``W_uk`` / ``W_uv`` in VMEM (no key or value a head is ever
+    in HBM; once a query block, so a chunk is one query block where it
+    can be) and scores the head's queries against them and against the
+    shared rotary keys as they are stored, a 128-lane tile whose upper
+    lanes are zero. Key blocks past a query block's highest live
+    position are neither computed nor fetched (a scalar prefetched; the
+    index maps stay on the last block needed). Same precisions as
+    ``_latent_pass`` expanded: operands in the pool's dtype to every
+    product, float32 logits, maximum, exponential and sums, weights cast
+    to the pool's dtype before the last product, output in
+    ``q_nope.dtype``; dead queries and queries that chose nothing give
+    zeros. Widths are padded to whole lanes with zeros, which change no
+    sum (none at the published widths). ``blocks``: ``(block_q,
+    block_k)`` in place of ``_sparse_blocks``', for the tests."""
+    B, S, H, nope = q_nope.shape
+    v = w_uv.shape[-1]
+    cd, rd = (p.pages(page_indices)[:, :, 0] for p in (c_pages, r_pages))
+    Sk = cd.shape[1]
+    block_q, block_k = blocks or _sparse_blocks(S, Sk)
+    pad_q, pad_k = -S % block_q, -Sk % block_k
+    nq, nk = (S + pad_q) // block_q, (Sk + pad_k) // block_k
+    # A head's lanes side by side: ``(B, S, H * lanes)``, a block a head.
+    qn, qr = _lane_pad(q_nope), jnp.pad(
+        q_rope, ((0, 0),) * 3 + ((0, rd.shape[-1] - q_rope.shape[-1]),))
+    qn, qr = (jnp.pad(x, ((0, 0), (0, pad_q), (0, 0), (0, 0))
+                      ).reshape(B, S + pad_q, -1) for x in (qn, qr))
+    cd, rd = (jnp.pad(x, ((0, 0), (0, pad_k), (0, 0))) for x in (cd, rd))
+    # The expansions' rows as wide as the stored latent row.
+    uk, uv = (jnp.pad(_lane_pad(w), ((0, cd.shape[-1] - w.shape[0]),
+                                     (0, 0), (0, 0))
+                      ).reshape(cd.shape[-1], -1) for w in (w_uk, w_uv))
+    chosen = jnp.pad(chosen, ((0, 0), (0, pad_q), (0, pad_k)))
+    lanes_n, lanes_r, lanes_v = (qn.shape[-1] // H, rd.shape[-1],
+                                 uv.shape[-1] // H)
+    top = jnp.max(jnp.pad(q_positions, ((0, 0), (0, pad_q)),
+                          constant_values=-1).reshape(B, nq, block_q),
+                  axis=-1)                         # -1: all dead
+
+    # Index maps: the grid's place, then the prefetched array.
+    def of_queries(b, h, qi, ki, top):
+        return b, qi, h
+
+    def last(b, qi, ki, top):
+        return jnp.minimum(ki, jnp.maximum(top[b, qi], 0) // block_k)
+
+    def of_rows(b, h, qi, ki, top):
+        return b, last(b, qi, ki, top), 0
+
+    def of_head(b, h, qi, ki, top):
+        return 0, h
+
+    out = pl.pallas_call(
+        functools.partial(_sparse_kernel,
+                          scale=(nope + q_rope.shape[-1]) ** -0.5,
+                          block_k=block_k),
+        name="dtt_sparse_prefill",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, block_q, lanes_n), of_queries),
+                pl.BlockSpec((1, block_q, lanes_r), of_queries),
+                pl.BlockSpec((1, block_k, cd.shape[-1]), of_rows),
+                pl.BlockSpec((1, block_k, lanes_r), of_rows),
+                pl.BlockSpec((cd.shape[-1], lanes_n), of_head),
+                pl.BlockSpec((cd.shape[-1], lanes_v), of_head),
+                pl.BlockSpec((1, block_q, block_k),
+                             lambda b, h, qi, ki, top:
+                             (b, qi, last(b, qi, ki, top))),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, lanes_v), of_queries),
+            scratch_shapes=[pltpu.VMEM((block_q, lanes_v), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, S + pad_q, H * lanes_v),
+                                       q_nope.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
+        interpret=not _platform_is_tpu(),
+    )(top, qn, qr, cd, rd, uk, uv, chosen)
+    return out[:, :S].reshape(B, S, H, lanes_v)[..., :v]
+
+
 def latent_attention_chunk(q_nope: jax.Array, q_rope: jax.Array,
                            c_pages, r_pages,
                            page_indices: jax.Array,
@@ -716,24 +925,28 @@ def latent_attention_chunk(q_nope: jax.Array, q_rope: jax.Array,
     the last ``window`` positions, and the table is a window layer's
     ring (reported ``"<form>.window"``).
 
-    ``select`` (``Selection``; reported ``"absorbed.sparse"``): a query
-    attends, of the positions up to its own, only the ``select.topk``
-    whose index keys its indexer scores highest (``index_scores``,
-    ``select_topk``: exact, no approximation and no blocks of keys),
-    all of them while they are no more than that, where the result
-    equals the dense one. Every index key of the table is scored; then
-    each query's chosen rows ALONE are read out of the two pools
-    (``PoolLayer.rows``, through the page table) and attended absorbed,
-    all heads on the one gathered row: a query reads ``topk`` rows
-    whatever the table holds, at decode and in a prompt chunk alike (a
-    chunk of 1,024 over a table of 16,384 makes an eighth of the logits
-    dense attention would).
+    ``select`` (``Selection``): a query attends, of the positions up
+    to its own, only the ``select.topk`` whose index keys its indexer
+    scores highest (``index_scores``, ``select_topk``: exact, no
+    approximation and no blocks of keys), all of them while they are no
+    more than that, where the result equals the dense one. Every index
+    key of the table is scored. Then, by ``sparse_form``: each query's
+    chosen rows ALONE are read out of the two pools (``PoolLayer.rows``,
+    through the page table) and attended absorbed, all heads on the one
+    gathered row (``"absorbed.sparse"``: decode, where no query shares
+    a row with another, and tables far longer than ``topk``); or the
+    sequence's table is read once, as without a selection, and one
+    kernel attends under ``select_topk``'s mask
+    (``"flash.sparse"``, ``_sparse_flash_attention``: a prompt chunk,
+    whose queries' selections overlap). The same softmax over the same
+    rows either way.
 
     Where the float32 arrays of all queries (their logits, or the
     indexer's scores over the table) would pass
     ``_LATENT_LOGITS_LIMIT`` the queries go a block at a time
-    (``_query_block``); without a selection the expanded keys and
-    values are made once for all blocks."""
+    (``_query_block``; under the mask the selection alone, attended at
+    once); without a selection the expanded keys and values are made
+    once for all blocks."""
     B, S, H, nope = q_nope.shape
     v = w_uv.shape[-1]
     dims = (c_pages.layout.width, nope, v)
@@ -763,16 +976,31 @@ def latent_attention_chunk(q_nope: jax.Array, q_rope: jax.Array,
         return _in_query_blocks(attend, _query_block(B, S, H * Sk),
                                 q_positions, q_nope, q_rope)
 
-    _took("absorbed.sparse")
     topk, ps = min(select.topk, Sk), c_pages.page_size
+    form = sparse_form((B, S, H), Sk, topk,
+                       dims[:2] + (q_rope.shape[-1], v))
+    _took(form + ".sparse")
     index_keys = select.pages.layout.unpack(
         select.pages.pages(page_indices))[:, :, 0]
 
-    def attend(qp, qn, qr, sel_q, sel_w):
-        n = qn.shape[1]
-        positions, kept, _ = select_topk(
+    def choose(qp, sel_q, sel_w):
+        return select_topk(
             index_scores(select._replace(q=sel_q, w=sel_w), index_keys),
             _visible(qp, slot, window, None), topk)
+
+    if form == "flash":
+        # The selection a block of queries at a time, attended at once.
+        chosen = _in_query_blocks(
+            lambda *a: choose(*a)[2].astype(jnp.int8),
+            _query_block(B, S, select.q.shape[2] * Sk), q_positions,
+            select.q, select.w)
+        return _sparse_flash_attention(
+            q_nope, q_rope, c_pages, r_pages, page_indices, q_positions,
+            chosen, w_uk, w_uv)
+
+    def attend(qp, qn, qr, sel_q, sel_w):
+        n = qn.shape[1]
+        positions, kept, _ = choose(qp, sel_q, sel_w)
         pages = jnp.take_along_axis(
             page_indices, (positions // ps).reshape(B, -1), axis=1
         ).reshape(positions.shape)

@@ -394,6 +394,97 @@ def test_prompt_chunk_attention_is_one_kernel_and_holds_no_logits(
     assert largest < S * H * P * 16, largest
 
 
+@pytest.mark.parametrize("B,S,form", [
+    (1, 1024, "flash.sparse"), (32, 1, "absorbed.sparse"),
+], ids=["prefill_1x1024", "decode_32x1"])
+def test_a_chunk_under_the_selection_is_one_kernel_and_gathers_no_row(
+        monkeypatch, B, S, form):
+    """``latent_attention_chunk(select=)`` at ``dots3_ep8.serve_sparse``'s
+    two shapes (a full layer at the published widths, bfloat16 pools, a
+    table of 16,384 rows, top 2,048) compiles for a v5e: the prompt
+    chunk, under Mosaic's VMEM check, to a program with the one custom
+    call ``dtt_sparse_prefill``, the name ``ops.sparse_prefill_time_
+    share.decode`` looks for, and no array of the 262,144 gathered rows
+    a block of 128 queries read one at a time (1.48 s of the cell's 4.0
+    s trace: ledger, PR 34); the decode iteration to the gather form
+    and no such call."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_training_tpu.ops import paged_attention as pa
+    from distributed_training_tpu.serving.kv_cache import PoolLayout
+    from perfbench import common, trace_reduce
+
+    monkeypatch.setenv("DTT_ASSUME_TPU", "1")
+    try:
+        from distributed_training_tpu.runtime import topology_runtime
+        chip = SingleDeviceSharding(
+            topology_runtime(1, "v5e:2x2").mesh.devices.flat[0])
+    except Exception as e:  # pragma: no cover - no libtpu
+        pytest.skip(f"device-less TPU topology unavailable: {e}")
+
+    bf, H, P, topk = jnp.bfloat16, 128, 1024, 2048
+
+    def struct(dims, dtype=bf):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    def layer(width):
+        layout = PoolLayout(1, width)
+        return layout.layer(struct(layout.shape(1, B * P + 1, 16)), 0)
+
+    def call(qn, qr, c, r, t, qp, uk, uv, iq, iw, ip):
+        return pa.latent_attention_chunk(
+            qn, qr, c, r, t, qp, uk, uv,
+            select=pa.Selection(iq, iw, ip, topk))
+
+    with pa.observe_forms() as seen:
+        text = jax.jit(call).lower(
+            struct((B, S, H, 128)), struct((B, S, H, 64)), layer(512),
+            layer(64), struct((B, P), jnp.int32),
+            struct((B, S), jnp.int32), struct((512, H, 128)),
+            struct((512, H, 128)), struct((B, S, 64, 128)),
+            struct((B, S, 64), jnp.float32), layer(128)
+        ).compile().as_text()
+    assert seen == [form]
+    calls = [line.strip() for line in text.splitlines()
+             if " custom-call(" in line
+             and 'custom_call_target="tpu_custom_call"' in line]
+    gathered = re.findall(r"bf16\[(?:128,2048|262144),(?:1,)?512\]", text)
+    if form == "absorbed.sparse":
+        assert not calls
+        return
+    assert len(calls) == 1
+    assert re.match(r"%dtt_sparse_prefill\.\d+ = ", calls[0])
+    pattern = common.load_file(
+        "layer_metrics", "ops.sparse_prefill_time_share.decode").PATTERN
+    assert pattern.match(trace_reduce.short_name(calls[0])), calls[0]
+    assert not gathered, gathered[:3]
+
+
+def test_the_latent_form_tables_sparse_rows_rehearse():
+    """``benchmarks/latent_form_table.py::sparse_rows`` (the table
+    ``sparse_form``'s one constant was read from: a full layer's call
+    under its selection in its masked and its gather form, context by
+    context) at a tiny size on the CPU, bfloat16 as there: both forms
+    are timed, agree within ``chip_smoke.py``'s band, and the rule is
+    reported."""
+    sys.path.insert(0, REPO)             # chip_smoke.py, at the root
+    import latent_form_table
+
+    rows = latent_form_table.sparse_rows(
+        4, (40, 60), B=2, S=16, H=4, rank=128, nope=8, rope=4, v=8,
+        index=(2, 8, 8))
+    assert [r["context"] for r in rows] == [40, 60]
+    for row in rows:
+        assert row["table"] == 64 and row["rule"] == "flash"
+        assert row["flash_ms"] > 0 and row["absorbed_ms"] > 0
+        assert row["err_over_bf16_band"] < 1.0
+        assert row["faster"] in ("flash", "absorbed")
+
+
 def test_resident_decode_holds_no_copy_of_the_pool():
     """``jit_serving_resident_decode`` at ``gpt2-xl``'s widths (25
     heads of 64, 16 slots, 385 pages, bfloat16; 4 layers of 48)

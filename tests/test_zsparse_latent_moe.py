@@ -248,12 +248,18 @@ def test_engine_matches_the_reference(ref, cadence):
     assert sum(r["index_keys_kept"] for r in decode) < sum(
         r["index_keys_scored"] for r in decode)
     forms = eng.paged_forms()
-    sparse = {"absorbed.sparse"}
+    sparse = {"absorbed.sparse", "flash.sparse"}
     window = {"absorbed.window", "expanded.window"}
     assert all(set(f.split("+")) <= sparse | window
                and set(f.split("+")) & sparse
                and set(f.split("+")) & window
                for f in forms.values() if f), forms
+    # The masked form where a chunk's queries choose more rows than the
+    # table has (16 x 8 over 64), the gather form in every other
+    # program.
+    assert {name for name, f in forms.items()
+            if f and "flash.sparse" in f} == (
+        {"serving_prefill_batch"} if chunk == 16 else set()), forms
 
 
 def paged_logits(model, params, seq, sizes, **engine):
@@ -318,8 +324,12 @@ def test_paged_logits_match_the_reference(ref, monkeypatch, feed, limit):
     seq = np.random.default_rng(11).integers(0, 96, sum(sizes))
     model, params = build(ep_size=8)
     want = ref_logits(ref, params, seq)
-    got, counted = paged_logits(model, params, seq, sizes, **engine)
+    with pa.observe_forms() as forms:
+        got, counted = paged_logits(model, params, seq, sizes, **engine)
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+    # Chunks of 16 attend under the mask in one kernel (the selection
+    # made a block of 8 queries at a time where the limit says so).
+    assert ("flash.sparse" in forms) == (feed == "wide_chunks"), forms
     at = 0
     for n, counts in zip(sizes, counted):
         ends = np.arange(at + 1, at + n + 1)     # positions seen, a query
@@ -356,13 +366,17 @@ def test_select_topk_is_exact():
 
 
 def latent_case(B, S, last, ring_pages=None, seed=0, ps=4, H=4, rank=8,
-                nope=8, rope=4, v=8, J=2, d=8, table_pages=16):
+                nope=8, rope=4, v=8, J=2, d=8, table_pages=16,
+                scattered=False, ties=False, dtype=jnp.float32):
     """``B`` sequences whose newest query is at ``last[b]``, ``S``
     queries each, over a latent cache holding what the engine would
     have left: position ``p`` in table slot ``p``, or of a ring of
     ``ring_pages`` pages in ring slot ``p % (ring_pages * ps)``. Returns
     the call's arguments, the index pool's layer with the indexer's
-    queries and weights, and the dense rows by position."""
+    queries and weights, and the dense rows by position. ``scattered``:
+    the sequences' pages dealt out of order from one pool; ``ties``:
+    index keys, queries and weights whole numbers of a few values, so
+    that many scores are equal."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 9)
     T = max(last) + 1
     c = jax.random.normal(ks[0], (B, T, rank), jnp.float32)
@@ -374,21 +388,29 @@ def latent_case(B, S, last, ring_pages=None, seed=0, ps=4, H=4, rank=8,
     w_uv = jax.random.normal(ks[6], (rank, H, v), jnp.float32)
     qi = jax.random.normal(ks[7], (B, S, J, d), jnp.float32)
     wi = jax.random.normal(ks[8], (B, S, J), jnp.float32)
+    if ties:
+        ki, qi, wi = (jnp.round(x) for x in (ki, qi, wi))
     P = ring_pages or table_pages
     N = B * P + 1
     pools = [np.zeros((N, ps, 128), np.float32) for _ in range(3)]
-    rows = np.arange(1, N, dtype=np.int32).reshape(B, P)
+    rows = np.arange(1, N, dtype=np.int32)
+    if scattered:
+        rows = np.random.default_rng(seed).permutation(rows)
+    rows = rows.reshape(B, P)
     for b in range(B):
         for p in range(last[b] + 1):        # later rows overwrite
             page, off = rows[b, p // ps % P], p % ps
             for pool, x in zip(pools, (c, r, ki)):
                 pool[page, off, :x.shape[-1]] = np.asarray(x[b, p])
     zero = jnp.zeros((), jnp.int32)
-    layers = [PoolLayout(1, width).layer(jnp.asarray(pool)[None], zero)
-              for pool, width in zip(pools, (rank, rope, d))]
+    layers = [PoolLayout(1, width).layer(
+        jnp.asarray(pool, dtype)[None], zero)
+        for pool, width in zip(pools, (rank, rope, d))]
     q_pos = np.stack([np.arange(n - S + 1, n + 1) for n in last]
                      ).astype(np.int32)
     q_pos[0, 0] = -1                        # a dead query
+    q_nope, q_rope, w_uk, w_uv, qi = (
+        x.astype(dtype) for x in (q_nope, q_rope, w_uk, w_uv, qi))
     args = (q_nope, q_rope, layers[0], layers[1], jnp.asarray(rows),
             jnp.asarray(q_pos), w_uk, w_uv)
     return args, (qi, wi, layers[2]), (c, r, ki)
@@ -433,14 +455,15 @@ def test_latent_attention_over_a_ring(monkeypatch, S, last, blocked):
 @pytest.mark.parametrize("S,topk,form", [
     (1, 8, "absorbed.sparse"),        # decode: the chosen rows read alone
     (3, 8, "absorbed.sparse"),        # speculative verify
-    (16, 8, "absorbed.sparse"),       # a chunk: every query its rows
+    (8, 8, "absorbed.sparse"),        # a chunk: every query its rows
+    (16, 8, "flash.sparse"),          # a wider one: the table under a mask
 ])
 def test_sparse_equals_dense_up_to_index_topk(S, topk, form):
     """A selection of ``topk`` positions changes nothing while a
     sequence is no longer than ``topk`` (sequence 1, at 7), and attends
     exactly the chosen positions where it is longer, against attention
     written out with the selection as a mask."""
-    last = (40, 7, 25) if S < 16 else (40, 15, 25)
+    last = (40, 7, 25) if S <= 8 else (40, 15, 25)
     args, (qi, wi, ip), dense = latent_case(3, S, last)
     select = pa.Selection(qi, wi, ip, topk)
     with pa.observe_forms() as seen:
@@ -462,6 +485,132 @@ def test_sparse_equals_dense_up_to_index_topk(S, topk, form):
                                rtol=2e-5)
     assert np.abs(np.asarray(got)[~short]
                   - np.asarray(plain)[~short]).max() > 1e-2
+
+
+def forced(monkeypatch, form):
+    monkeypatch.setattr(pa, "sparse_form", lambda *a: form)
+
+
+MASKED = {
+    # A chunk past top-k in every sequence; the one query block is
+    # padded from 16 to 32 and holds a dead query.
+    "chunk": dict(S=16, last=(40, 31, 25)),
+    # Sequences shorter than top-k beside longer ones.
+    "fewer_than_topk_seen": dict(S=16, last=(15, 40, 17)),
+    # Whole-number scores: the edge of the selection lies inside a run
+    # of equal scores, which go to the lower positions.
+    "tied_scores": dict(S=16, last=(40, 31, 25), ties=True),
+    # Pages dealt out of order from the pool.
+    "scattered_pages": dict(S=16, last=(40, 31, 25), scattered=True),
+    # Blocks of 8 queries and 16 rows: the last query block is half
+    # padding, and contexts of 22 and 37 end inside a key block, the
+    # blocks past them skipped.
+    "small_blocks": dict(S=20, last=(63, 21, 36), blocks=(8, 16)),
+    # The selection made 8 queries at a time, attended at once.
+    "selection_in_blocks": dict(S=16, last=(40, 31, 25), limit=1 << 10),
+}
+
+
+@pytest.mark.parametrize("dtype,atol", [("float32", 5e-6),
+                                        ("bfloat16", 3e-2)])
+@pytest.mark.parametrize("case", list(MASKED))
+def test_the_masked_form_is_the_gather_form(monkeypatch, case, dtype,
+                                            atol):
+    """``dtt_sparse_prefill`` (interpreted) against the gather form of
+    the same call and against attention written out under the
+    selection's mask, as the reference's ``attend`` has it: the same
+    softmax over the same rows, in float32 to the order of summation
+    and in bfloat16 to the repo's band."""
+    kw = dict(MASKED[case])
+    S, last = kw.pop("S"), kw.pop("last")
+    blocks, limit = kw.pop("blocks", None), kw.pop("limit", None)
+    args, (qi, wi, ip), dense = latent_case(3, S, last,
+                                            dtype=jnp.dtype(dtype), **kw)
+    select = pa.Selection(qi, wi, ip, 8)
+    if limit:
+        monkeypatch.setattr(pa, "_LATENT_LOGITS_LIMIT", limit)
+    scores = pa.index_scores(select, ip.layout.unpack(
+        ip.pages(args[4]))[:, :, 0])
+    seen = pa._visible(args[5], jnp.arange(64)[None], None, None)
+    chosen = pa.select_topk(scores, seen, 8)[2]
+    if blocks:
+        masked = pa._sparse_flash_attention(
+            *args[:6], chosen.astype(jnp.int8), *args[6:], blocks=blocks)
+    else:
+        with pa.observe_forms() as took:
+            masked = pa.latent_attention_chunk(*args, select=select)
+        assert took == ["flash.sparse"]
+    forced(monkeypatch, "absorbed")
+    with pa.observe_forms() as took:
+        gathered = pa.latent_attention_chunk(*args, select=select)
+    assert took == ["absorbed.sparse"]
+    T = dense[0].shape[1]
+    want = dense_latent(args, dense, chosen[:, :, :T])
+    for got in (gathered, want):
+        np.testing.assert_allclose(
+            np.asarray(masked, np.float32), np.asarray(got, np.float32),
+            atol=atol * max(1.0, float(jnp.abs(want).max())), rtol=atol)
+    # A dead query gives zeros; the case is not an empty one.
+    assert not np.asarray(masked, np.float32)[0, 0].any()
+    kept = np.asarray(chosen).sum(-1)
+    assert kept.max() == 8 and (kept[np.asarray(args[5]) >= 8] == 8).all()
+    if case == "tied_scores":
+        s = np.where(np.asarray(seen), np.asarray(scores), -np.inf)
+        edge = np.sort(s, axis=-1)[..., -9:-7]       # 9th and 8th largest
+        assert (edge[..., 0] == edge[..., 1])[np.isfinite(
+            edge[..., 0])].mean() > 0.3
+    if case == "fewer_than_topk_seen":
+        np.testing.assert_allclose(
+            np.asarray(masked, np.float32)[np.asarray(args[5]) < 8],
+            np.asarray(pa.latent_attention_chunk(*args), np.float32)[
+                np.asarray(args[5]) < 8], atol=atol * 10, rtol=atol)
+
+
+DOTS3 = dict(rank=512, nope=128, rope=64, v=128)
+
+
+@pytest.mark.parametrize("B,S,pages,form", [
+    (32, 1, 1024, "absorbed.sparse"),    # the resident decode program
+    (1, 1024, 1024, "flash.sparse"),     # the prefill lane
+    (1, 16384, 1024, "flash.sparse"),    # a teacher-forced pass
+    (1, 4, 1024, "absorbed.sparse"),     # a speculative verify
+    # The published 524,288 positions: dense arithmetic over the table
+    # loses to 2,048 gathered rows a query.
+    (1, 1024, 32768, "absorbed.sparse"),
+], ids=["decode_32x1", "prefill_1x1024", "forced_1x16384", "spec_1x4",
+        "table_524288"])
+def test_the_form_under_a_selection_follows_the_shapes(B, S, pages, form):
+    """``sparse_form`` at the shapes of ``dots3-note-ep8``'s programs,
+    as ``observe_forms`` reports it when a call is traced (no program
+    is compiled): static shapes alone, no option."""
+    f32, H = jnp.float32, 128
+    N = B * pages + 1
+
+    def layer(width):
+        lay = PoolLayout(1, width)
+        return lay.layer(jax.ShapeDtypeStruct(lay.shape(1, N, 16), f32),
+                         0)
+
+    def call(qn, qr, c, r, t, qp, uk, uv, iq, iw, ip):
+        return pa.latent_attention_chunk(
+            qn, qr, c, r, t, qp, uk, uv,
+            select=pa.Selection(iq, iw, ip, 2048))
+
+    d = DOTS3
+    shapes = [(B, S, H, d["nope"]), (B, S, H, d["rope"])]
+    with pa.observe_forms() as took:
+        out = jax.eval_shape(
+            call, *(jax.ShapeDtypeStruct(x, f32) for x in shapes),
+            layer(d["rank"]), layer(d["rope"]),
+            jax.ShapeDtypeStruct((B, pages), jnp.int32),
+            jax.ShapeDtypeStruct((B, S), jnp.int32),
+            jax.ShapeDtypeStruct((d["rank"], H, d["nope"]), f32),
+            jax.ShapeDtypeStruct((d["rank"], H, d["v"]), f32),
+            jax.ShapeDtypeStruct((B, S, 64, 128), f32),
+            jax.ShapeDtypeStruct((B, S, 64), f32), layer(128))
+    assert took == [form] and out.shape == (B, S, H, d["v"])
+    assert pa.sparse_form((B, S, H), pages * 16, 2048,
+                          tuple(d.values())) == form.split(".")[0]
 
 
 def cache(**over):
