@@ -724,6 +724,7 @@ def phase_engine() -> dict:
     from distributed_training_tpu.serving.engine import (
         Engine, EngineConfig)
     from distributed_training_tpu.serving.server import ServingServer
+    from distributed_training_tpu.telemetry.op_scopes import UNSCOPED
 
     model, params, bf16, prompts = serving_fixture((211, 333, 450))
     ecfg = EngineConfig(max_batch=8, num_pages=8 * PAGES_PER_SEQ + 1,
@@ -734,6 +735,9 @@ def phase_engine() -> dict:
     tel.add_observer(lambda r: bursts.append(r)
                      if r.get("kind") == "serving"
                      and r.get("op") == "decode" else None)
+    maps: list = []     # HLO instruction -> dtt.* scope, a program
+    tel.add_observer(lambda r: maps.append(r)
+                     if r.get("kind") == "program_scopes" else None)
     t0 = time.perf_counter()
     eng = Engine(model, bf16, ecfg, mesh=None)
     counts = eng.warmup()
@@ -741,6 +745,16 @@ def phase_engine() -> dict:
     say(f"  engine warm (smoke wall {time.perf_counter() - t0:.1f}s): "
         f"compile_counts {counts}, paged forms {forms}, weights "
         f"{eng.weight_bytes / 1e6:.0f} MB bf16")
+    scope_counts = {
+        m["program"]: {"instructions": m["instructions"],
+                       UNSCOPED: len(m["scopes"].get(UNSCOPED, ())),
+                       "mixed": len(m["mixed"])} for m in maps}
+    say(f"  program_scopes (instructions, of them under no dtt.* scope, "
+        f"fusions that span scopes): {scope_counts}")
+    if set(scope_counts) != set(forms):
+        raise AssertionError(
+            f"programs without a scope map: "
+            f"{sorted(set(forms) - set(scope_counts))}")
     srv = ServingServer(eng, port=0)
     if srv.start() is None:
         raise AssertionError("ServingServer did not start")
@@ -811,7 +825,7 @@ def phase_engine() -> dict:
             f"the reference argmax (tolerance {TIE_TOL})")
     return {"tokens": streamed, "argmax_agreement": [exact, n_tok],
             "worst_logit_gap": worst, "compile_counts": after,
-            "paged_forms": forms}
+            "paged_forms": forms, "program_scopes": scope_counts}
 
 
 # -- driver ------------------------------------------------------------------

@@ -87,6 +87,13 @@ def route(h, m, c, logits=None):
     return idx, g
 
 
+def ffn_scope(layer) -> str:
+    """The ``jax.named_scope`` (``telemetry/op_scopes.py::SCOPES``) of
+    a layer's feed-forward with its norm and residual: an expert layer's
+    or a dense one's, by what its parameters hold."""
+    return "dtt.moe.experts" if "router" in layer["mlp"] else "dtt.mlp"
+
+
 def expert_layer(h, m, c, valid=None, w=_cast, logits=None,
                  route=route, shared=None):
     """The expert feed-forward on ``h (..., D)``: this rank's experts'
@@ -101,25 +108,31 @@ def expert_layer(h, m, c, valid=None, w=_cast, logits=None,
     x = h.reshape(-1, h.shape[-1])
     ok = (jnp.ones(x.shape[:1], bool) if valid is None
           else valid.reshape(-1))
-    if logits is not None:
-        logits = logits.reshape(-1, logits.shape[-1])
-    idx, g = route(x, m, c) if logits is None else route(x, m, c,
-                                                          logits)
-    local = idx - c.expert_offset
-    # one_hot of an index outside [0, held) is the zero row: an expert
-    # that lies on another rank takes no weight here.
-    onehot = jax.nn.one_hot(local, c.experts_held, dtype=jnp.float32)
-    combine = jnp.einsum("tk,tke->te", g, onehot)
-    act = (_ACTS[c.expert_act](
-        jnp.einsum("td,edf->tef", x, w(m["wg"], dt)))
-        * jnp.einsum("td,edf->tef", x, w(m["wu"], dt)))
-    y = jnp.einsum("tef,efd->td", act * combine.astype(dt)[..., None],
-                   w(m["wd"], dt))
-    if "shared" in m:
-        y = y + (shared(x, m["shared"], w) if shared is not None
-                 else gated_mlp(x, m["shared"], w, c.expert_act))
-    load = jnp.sum(onehot * ok[:, None, None], axis=(0, 1))
-    counts = jnp.stack([
-        jnp.sum(ok) * c.moe_top_k, jnp.sum(load), jnp.max(load),
-        jnp.any(ok), jnp.any(ok) * c.experts_held]).astype(jnp.int32)
+    with jax.named_scope("dtt.moe.route"):
+        if logits is not None:
+            logits = logits.reshape(-1, logits.shape[-1])
+        idx, g = route(x, m, c) if logits is None else route(x, m, c,
+                                                              logits)
+        local = idx - c.expert_offset
+        # one_hot of an index outside [0, held) is the zero row: an
+        # expert that lies on another rank takes no weight here.
+        onehot = jax.nn.one_hot(local, c.experts_held,
+                                dtype=jnp.float32)
+        combine = jnp.einsum("tk,tke->te", g, onehot)
+    with jax.named_scope("dtt.moe.experts"):
+        act = (_ACTS[c.expert_act](
+            jnp.einsum("td,edf->tef", x, w(m["wg"], dt)))
+            * jnp.einsum("td,edf->tef", x, w(m["wu"], dt)))
+        y = jnp.einsum("tef,efd->td",
+                       act * combine.astype(dt)[..., None],
+                       w(m["wd"], dt))
+        if "shared" in m:
+            y = y + (shared(x, m["shared"], w) if shared is not None
+                     else gated_mlp(x, m["shared"], w, c.expert_act))
+    with jax.named_scope("dtt.moe.route"):    # what the router chose
+        load = jnp.sum(onehot * ok[:, None, None], axis=(0, 1))
+        counts = jnp.stack([
+            jnp.sum(ok) * c.moe_top_k, jnp.sum(load), jnp.max(load),
+            jnp.any(ok), jnp.any(ok) * c.experts_held]
+        ).astype(jnp.int32)
     return y.reshape(lead + (h.shape[-1],)), counts

@@ -57,7 +57,7 @@ import jax.numpy as jnp
 
 from distributed_training_tpu.models.base import ApplyLM, normal_init
 from distributed_training_tpu.models.experts import (  # noqa: F401
-    COUNTERS, _cast, expert_layer, gated_mlp, rms_norm, route)
+    COUNTERS, _cast, expert_layer, ffn_scope, gated_mlp, rms_norm, route)
 
 @dataclass
 class LatentMoEConfig:
@@ -374,11 +374,14 @@ class LatentBlock:
 
     def finish(self, layer, x, attn, valid):
         c = self.cfg
-        x = x + jnp.einsum("...hk,hkd->...d", attn,
-                           self._w(layer["attn"]["wo"], x.dtype))
-        h = rms_norm(x, layer["ln2"], c.rms_norm_eps)
-        y, counts = self.model.feed_forward(layer, h, valid, self._w)
-        return x + y, counts
+        with jax.named_scope("dtt.attn.out"):
+            x = x + jnp.einsum("...hk,hkd->...d", attn,
+                               self._w(layer["attn"]["wo"], x.dtype))
+        with jax.named_scope(ffn_scope(layer)):
+            h = rms_norm(x, layer["ln2"], c.rms_norm_eps)
+            y, counts = self.model.feed_forward(layer, h, valid,
+                                                self._w)
+            return x + y, counts
 
     def logits(self, params, x):
         x = rms_norm(x, params["final_norm"], self.cfg.rms_norm_eps)

@@ -62,7 +62,7 @@ import jax.numpy as jnp
 
 from distributed_training_tpu.models.base import ApplyLM, normal_init
 from distributed_training_tpu.models.experts import (
-    COUNTERS, _cast, expert_layer, gated_mlp, rms_norm)
+    COUNTERS, _cast, expert_layer, ffn_scope, gated_mlp, rms_norm)
 from distributed_training_tpu.models.latent_moe import (
     project, query_latent, rope_interleaved)
 
@@ -498,19 +498,23 @@ class _Run:
         attn = latent_attention_chunk(
             q[0], q[1], kp, vp, page_rows, q_pos, w_uk, w_uv,
             select=Selection(q[3], q[4], ip, c.index_topk))
-        scored = jnp.maximum(q_pos + 1, 0)
-        return attn, q[2], jnp.stack(
-            [jnp.sum(scored), jnp.sum(jnp.minimum(scored, c.index_topk))]
-        ).astype(jnp.int32)
+        with jax.named_scope("dtt.attn.select"):
+            scored = jnp.maximum(q_pos + 1, 0)
+            return attn, q[2], jnp.stack(
+                [jnp.sum(scored),
+                 jnp.sum(jnp.minimum(scored, c.index_topk))]
+            ).astype(jnp.int32)
 
     def finish(self, layer, x, attn, valid):
         b = self.block
         attn, gate, index_counts = attn
-        x = x + jnp.einsum("...hk,hkd->...d", attn * gate[..., None],
-                           b._w(layer["attn"]["wo"], x.dtype))
-        h = rms_norm(x, layer["ln2"], b.cfg.rms_norm_eps)
-        y, counts = b.model.feed_forward(layer, h, valid, b._w)
-        return x + y, jnp.concatenate([counts, index_counts])
+        with jax.named_scope("dtt.attn.out"):
+            x = x + jnp.einsum("...hk,hkd->...d", attn * gate[..., None],
+                               b._w(layer["attn"]["wo"], x.dtype))
+        with jax.named_scope(ffn_scope(layer)):
+            h = rms_norm(x, layer["ln2"], b.cfg.rms_norm_eps)
+            y, counts = b.model.feed_forward(layer, h, valid, b._w)
+            return x + y, jnp.concatenate([counts, index_counts])
 
 
 def build_sparse_latent_moe(loss: str = "auto", dtype: str = "bfloat16",

@@ -294,8 +294,10 @@ class _Run:
         h = rms_norm(x, layer["ln1"], b.cfg.rms_norm_eps)
         q, k, v = project(h, layer["attn"], positions, self.rope, b.cfg,
                           b._w)
-        return (q, router_logits(h, b._w(layer["mlp"]["router"],
-                                         jnp.float32))), k, v
+        with jax.named_scope("dtt.moe.route"):
+            logits = router_logits(h, b._w(layer["mlp"]["router"],
+                                           jnp.float32))
+        return (q, logits), k, v
 
     def attend_chunk(self, layer, q, kp, vp, page_rows, q_pos):
         from distributed_training_tpu.ops.paged_attention import (
@@ -311,12 +313,14 @@ class _Run:
     def finish(self, layer, x, attn, valid):
         b = self.block
         attn, logits = attn
-        x = x + jnp.einsum("...hk,hkd->...d", attn,
-                           b._w(layer["attn"]["wo"], x.dtype))
-        h = rms_norm(x, layer["ln2"], b.cfg.rms_norm_eps)
-        y, counts = expert_layer(h, layer["mlp"], b.cfg, valid, b._w,
-                                 logits=logits)
-        return x + y, counts
+        with jax.named_scope("dtt.attn.out"):
+            x = x + jnp.einsum("...hk,hkd->...d", attn,
+                               b._w(layer["attn"]["wo"], x.dtype))
+        with jax.named_scope("dtt.moe.experts"):
+            h = rms_norm(x, layer["ln2"], b.cfg.rms_norm_eps)
+            y, counts = expert_layer(h, layer["mlp"], b.cfg, valid,
+                                     b._w, logits=logits)
+            return x + y, counts
 
 
 def build_window_moe(loss: str = "auto", dtype: str = "bfloat16",

@@ -297,15 +297,17 @@ def _pool_attention(q: jax.Array, k_pages, v_pages,
     B = q.shape[0]
     N, ps = k_pages.num_pages, k_pages.page_size
     P = page_indices.shape[1]
-    logical = jnp.arange(P, dtype=jnp.int32)[None, :, None]
-    owns = page_indices[:, :, None] \
-        == jnp.arange(N, dtype=page_indices.dtype)[None, None, :]
-    # (B, N): logical page of each physical page; P where not owned,
-    # which is past every position a table of P pages can hold.
-    owner = jnp.min(jnp.where(owns, logical, P), axis=1)
-    slot_pos = (owner[:, :, None] * ps
-                + jnp.arange(ps, dtype=jnp.int32)[None, None, :]
-                ).reshape(B, N * ps)
+    with jax.named_scope("dtt.kv.read"):   # the table, inside out
+        logical = jnp.arange(P, dtype=jnp.int32)[None, :, None]
+        owns = page_indices[:, :, None] \
+            == jnp.arange(N, dtype=page_indices.dtype)[None, None, :]
+        # (B, N): logical page of each physical page; P where not
+        # owned, which is past every position a table of P pages can
+        # hold.
+        owner = jnp.min(jnp.where(owns, logical, P), axis=1)
+        slot_pos = (owner[:, :, None] * ps
+                    + jnp.arange(ps, dtype=jnp.int32)[None, None, :]
+                    ).reshape(B, N * ps)
     # In a ring the same arithmetic gives a slot's place in the ring,
     # and ``P * ps`` and past where the sequence does not own the page.
     return _tile_attention(
@@ -429,8 +431,9 @@ def _flash_attention(q: jax.Array, k_pages, v_pages,
     B, S = q.shape[:2]
     # Tiles first: the order XLA gives the gathered copy for the one-
     # pass contraction too, so a key block is ``block_k`` whole rows.
-    kd = k_pages.pages(page_indices).transpose(0, 2, 1, 3)
-    vd = v_pages.pages(page_indices).transpose(0, 2, 1, 3)
+    with jax.named_scope("dtt.kv.read"):
+        kd = k_pages.pages(page_indices).transpose(0, 2, 1, 3)
+        vd = v_pages.pages(page_indices).transpose(0, 2, 1, 3)
     qt = layout.spread(q).transpose(0, 2, 1, 3, 4)  # (B, T, S, J, tile)
     T, J, tile = qt.shape[1], qt.shape[3], qt.shape[4]
     Sk = kd.shape[2]
@@ -439,8 +442,9 @@ def _flash_attention(q: jax.Array, k_pages, v_pages,
     qt = jnp.pad(qt, ((0, 0), (0, 0), (0, pad_q), (0, 0), (0, 0)))
     # Padding slots lie past every position a table holds, and past a
     # ring's places.
-    kd, vd = (jnp.pad(x, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-              for x in (kd, vd))
+    with jax.named_scope("dtt.kv.read"):
+        kd, vd = (jnp.pad(x, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+                  for x in (kd, vd))
     qp = jnp.pad(q_positions, ((0, 0), (0, pad_q)), constant_values=-1)
     nq, nk, rows = (S + pad_q) // block_q, (Sk + pad_k) // block_k, \
         block_q * J
@@ -825,7 +829,9 @@ def _sparse_flash_attention(q_nope: jax.Array, q_rope: jax.Array,
     block_k)`` in place of ``_sparse_blocks``', for the tests."""
     B, S, H, nope = q_nope.shape
     v = w_uv.shape[-1]
-    cd, rd = (p.pages(page_indices)[:, :, 0] for p in (c_pages, r_pages))
+    with jax.named_scope("dtt.kv.read"):
+        cd, rd = (p.pages(page_indices)[:, :, 0]
+                  for p in (c_pages, r_pages))
     Sk = cd.shape[1]
     block_q, block_k = blocks or _sparse_blocks(S, Sk)
     pad_q, pad_k = -S % block_q, -Sk % block_k
@@ -835,7 +841,9 @@ def _sparse_flash_attention(q_nope: jax.Array, q_rope: jax.Array,
         q_rope, ((0, 0),) * 3 + ((0, rd.shape[-1] - q_rope.shape[-1]),))
     qn, qr = (jnp.pad(x, ((0, 0), (0, pad_q), (0, 0), (0, 0))
                       ).reshape(B, S + pad_q, -1) for x in (qn, qr))
-    cd, rd = (jnp.pad(x, ((0, 0), (0, pad_k), (0, 0))) for x in (cd, rd))
+    with jax.named_scope("dtt.kv.read"):
+        cd, rd = (jnp.pad(x, ((0, 0), (0, pad_k), (0, 0)))
+                  for x in (cd, rd))
     # The expansions' rows as wide as the stored latent row.
     uk, uv = (jnp.pad(_lane_pad(w), ((0, cd.shape[-1] - w.shape[0]),
                                      (0, 0), (0, 0))
@@ -961,8 +969,10 @@ def latent_attention_chunk(q_nope: jax.Array, q_rope: jax.Array,
     if select is None:
         form = latent_form((B, S, H), dims)
         _took(form + ".window" if ring else form)
-        cd, rd = (p.layout.unpack(p.pages(page_indices))[:, :, 0]
-                  for p in (c_pages, r_pages))  # (B, Sk, rank), (.., rope)
+        with jax.named_scope("dtt.kv.read"):
+            cd, rd = (p.layout.unpack(p.pages(page_indices))[:, :, 0]
+                      for p in (c_pages, r_pages))
+            #           (B, Sk, rank), (B, Sk, rope)
         keys = values = None
         if form == "expanded":
             keys = jnp.einsum("bkr,rhn->bkhn", cd, w_uk)
@@ -980,20 +990,24 @@ def latent_attention_chunk(q_nope: jax.Array, q_rope: jax.Array,
     form = sparse_form((B, S, H), Sk, topk,
                        dims[:2] + (q_rope.shape[-1], v))
     _took(form + ".sparse")
-    index_keys = select.pages.layout.unpack(
-        select.pages.pages(page_indices))[:, :, 0]
+    with jax.named_scope("dtt.kv.read"):
+        index_keys = select.pages.layout.unpack(
+            select.pages.pages(page_indices))[:, :, 0]
 
     def choose(qp, sel_q, sel_w):
-        return select_topk(
-            index_scores(select._replace(q=sel_q, w=sel_w), index_keys),
-            _visible(qp, slot, window, None), topk)
+        with jax.named_scope("dtt.attn.select"):
+            return select_topk(
+                index_scores(select._replace(q=sel_q, w=sel_w),
+                             index_keys),
+                _visible(qp, slot, window, None), topk)
 
     if form == "flash":
         # The selection a block of queries at a time, attended at once.
-        chosen = _in_query_blocks(
-            lambda *a: choose(*a)[2].astype(jnp.int8),
-            _query_block(B, S, select.q.shape[2] * Sk), q_positions,
-            select.q, select.w)
+        with jax.named_scope("dtt.attn.select"):
+            chosen = _in_query_blocks(
+                lambda *a: choose(*a)[2].astype(jnp.int8),
+                _query_block(B, S, select.q.shape[2] * Sk), q_positions,
+                select.q, select.w)
         return _sparse_flash_attention(
             q_nope, q_rope, c_pages, r_pages, page_indices, q_positions,
             chosen, w_uk, w_uv)
@@ -1001,13 +1015,14 @@ def latent_attention_chunk(q_nope: jax.Array, q_rope: jax.Array,
     def attend(qp, qn, qr, sel_q, sel_w):
         n = qn.shape[1]
         positions, kept, _ = choose(qp, sel_q, sel_w)
-        pages = jnp.take_along_axis(
-            page_indices, (positions // ps).reshape(B, -1), axis=1
-        ).reshape(positions.shape)
-        # Every query its own rows: a sequence of one query each.
-        cd, rd = (p.layout.unpack(p.rows(pages, positions % ps))
-                  [..., 0, :].reshape((B * n, topk, -1))
-                  for p in (c_pages, r_pages))
+        with jax.named_scope("dtt.kv.read"):
+            pages = jnp.take_along_axis(
+                page_indices, (positions // ps).reshape(B, -1), axis=1
+            ).reshape(positions.shape)
+            # Every query its own rows: a sequence of one query each.
+            cd, rd = (p.layout.unpack(p.rows(pages, positions % ps))
+                      [..., 0, :].reshape((B * n, topk, -1))
+                      for p in (c_pages, r_pages))
         out = _latent_pass(
             "absorbed", qn.reshape((B * n, 1) + qn.shape[2:]),
             qr.reshape((B * n, 1) + qr.shape[2:]), cd, rd,

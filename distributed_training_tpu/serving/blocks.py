@@ -193,24 +193,31 @@ class DenseBlock:
         del valid
         c = self.cfg
         dt = x.dtype
-        x = x + jnp.einsum("...hk,hkd->...d", attn,
-                           weight(layer["attn"]["wo"], dt))
-        h = layer_norm(x, layer["ln2"]["scale"], layer["ln2"]["bias"])
+        with jax.named_scope("dtt.attn.out"):
+            x = x + jnp.einsum("...hk,hkd->...d", attn,
+                               weight(layer["attn"]["wo"], dt))
         m = layer["mlp"]
         if c.moe_num_experts > 0:
             from distributed_training_tpu.models.transformer import (
                 _moe_mlp_dense)
 
-            rows = h.reshape(1, -1, h.shape[-1])
-            out, _aux = _moe_mlp_dense(
-                rows, m, c, w=lambda p, d, path=None: weight(p, d))
-            x = x + out.reshape(h.shape)
+            with jax.named_scope("dtt.moe.experts"):
+                h = layer_norm(x, layer["ln2"]["scale"],
+                               layer["ln2"]["bias"])
+                rows = h.reshape(1, -1, h.shape[-1])
+                out, _aux = _moe_mlp_dense(
+                    rows, m, c, w=lambda p, d, path=None: weight(p, d))
+                x = x + out.reshape(h.shape)
         else:
-            u = jax.nn.gelu(jnp.einsum("...d,df->...f", h,
-                                       weight(m["wi"], dt))
-                            + m["bi"].astype(dt))
-            x = x + (jnp.einsum("...f,fd->...d", u, weight(m["wo"], dt))
-                     + m["bo"].astype(dt))
+            with jax.named_scope("dtt.mlp"):
+                h = layer_norm(x, layer["ln2"]["scale"],
+                               layer["ln2"]["bias"])
+                u = jax.nn.gelu(jnp.einsum("...d,df->...f", h,
+                                           weight(m["wi"], dt))
+                                + m["bi"].astype(dt))
+                x = x + (jnp.einsum("...f,fd->...d", u,
+                                    weight(m["wo"], dt))
+                         + m["bo"].astype(dt))
         return x, jnp.zeros((0,), jnp.int32)
 
     def logits(self, params, x):

@@ -96,6 +96,8 @@ from distributed_training_tpu.serving.kv_cache import (
     with_pool,
 )
 from distributed_training_tpu.telemetry import current, event, phase
+from distributed_training_tpu.telemetry.op_scopes import (
+    UNSCOPED, own_metadata, scope_map)
 
 logger = logging.getLogger(__name__)
 
@@ -665,11 +667,13 @@ def _seed_program(history, kv_len, left, rows, kv, left_new, live):
     the host uploads: ``rows`` (G, B, Lmax) their histories, ``kv`` /
     ``left_new`` (G, B) their committed lengths and tokens left. Every
     other slot passes through."""
+    import jax
     import jax.numpy as jnp
 
-    return (jnp.where(live[..., None], rows, history),
-            jnp.where(live, kv, kv_len),
-            jnp.where(live, left_new, left))
+    with jax.named_scope("dtt.engine"):
+        return (jnp.where(live[..., None], rows, history),
+                jnp.where(live, kv, kv_len),
+                jnp.where(live, left_new, left))
 
 
 def build_seed_fn(block, ecfg: EngineConfig, mesh=None):
@@ -691,9 +695,12 @@ def _cow_program(k_pages, v_pages, src, dst):
     collectives (pages never cross a group shard). Unused lanes ride
     as (0 -> 0): a scratch-to-scratch identity copy, the same
     dead-write trick as the decode program's inactive slots."""
+    import jax
+
     s, d = src[0], dst[0]
-    return (copy_pages(k_pages[0], s, d)[None],
-            copy_pages(v_pages[0], s, d)[None])
+    with jax.named_scope("dtt.kv.write"):
+        return (copy_pages(k_pages[0], s, d)[None],
+                copy_pages(v_pages[0], s, d)[None])
 
 
 def build_cow_fn(block, ecfg: EngineConfig, mesh=None):
@@ -1010,23 +1017,39 @@ class Engine:
         stored (``PagedKVCache.footprint``). Where a sink records it, each
         program is compiled once more ahead of time for its
         ``temp_bytes`` (``compiled.memory_analysis()``: a program whose
-        temporaries reach the pool's bytes holds a copy of it); with no
-        sink nothing is compiled twice. Returns compile_counts()."""
+        temporaries reach the pool's bytes holds a copy of it) and for
+        one ``program_scopes`` record, the map from its HLO instructions
+        to the ``dtt.*`` scopes they lie in
+        (``telemetry/op_scopes.py::scope_map`` of
+        ``compiled.as_text()``; a text that names no scope, an
+        executable that kept no metadata, gives no record), both
+        compiles under ``own_metadata``; with no sink nothing is
+        compiled twice and nothing is parsed. Returns
+        compile_counts()."""
         import jax
 
         temp_bytes = {}
         for fn, args in self._warmup_calls():
-            if current().enabled:
-                # Shapes, taken before the call donates the state.
-                shapes = jax.tree.map(
-                    lambda a: jax.ShapeDtypeStruct(
-                        a.shape, a.dtype, sharding=a.sharding)
-                    if isinstance(a, jax.Array) else a,
-                    (*self._state_of(fn), *args))
-                analysis = fn.lower(*shapes).compile().memory_analysis()
-                temp_bytes[fn.__wrapped__.__name__] = getattr(
-                    analysis, "temp_size_in_bytes", None)
-            self._call(fn, *args)
+            if not current().enabled:
+                self._call(fn, *args)
+                continue
+            # Shapes, taken before the call donates the state.
+            shapes = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=a.sharding)
+                if isinstance(a, jax.Array) else a,
+                (*self._state_of(fn), *args))
+            name = fn.__wrapped__.__name__
+            # The text and the executable that runs are one compile's,
+            # this tree's, whatever a shared compile cache holds.
+            with own_metadata():
+                compiled = fn.lower(*shapes).compile()
+                self._call(fn, *args)
+            temp_bytes[name] = getattr(
+                compiled.memory_analysis(), "temp_size_in_bytes", None)
+            scopes = scope_map(compiled.as_text())
+            if set(scopes["scopes"]) - {UNSCOPED}:
+                event("program_scopes", program=name, **scopes)
         event("serving_warmup",
               programs=[{"program": name, "paged_form": form,
                          "temp_bytes": temp_bytes.get(name)}
@@ -1593,8 +1616,8 @@ class Engine:
         # by "op". ``phase_s`` splits ``dur_s`` into the five
         # ``serving.*`` parts; what is left of it is the scheduling
         # glue between them. A launching step also carries what its
-        # ``_run_*`` path left in ``_step_counts``: ``slots_stepped``
-        # and ``slot_iters`` (decode), with ``spec_k`` and
+        # ``_run_*`` path left in ``_step_counts``: ``slots_stepped``,
+        # ``slot_iters`` and ``iters`` (decode), with ``spec_k`` and
         # ``spec_accepted_mean`` or ``resident_k`` and
         # ``resident_steps_per_launch`` by cadence; ``first_tokens``
         # (prefill); and ``ran_ahead``: 1 when the launch was
@@ -1971,7 +1994,7 @@ class Engine:
             # is exact.
             self._step_counts.update(
                 slots_stepped=len(stepped), slot_iters=len(stepped),
-                spec_k=K,
+                iters=1, spec_k=K,
                 spec_accepted_mean=round(total / len(stepped), 4))
             return total
 
@@ -2064,8 +2087,11 @@ class Engine:
             g_steps = [int(steps[g]) for g in range(G)
                        if active[g].any()]
             mean_steps = sum(g_steps) / max(1, len(g_steps))
+            # ``iters``: the iterations the launch ran, its longest
+            # group's (the groups run side by side).
             self._step_counts.update(
                 slots_stepped=len(stepped), slot_iters=slot_iters,
+                iters=max(g_steps, default=0),
                 resident_k=self.cfg.resident_k,
                 resident_steps_per_launch=round(mean_steps, 4))
             return total
@@ -2107,7 +2133,7 @@ class Engine:
                 self._emit(s, (int(nxt[at]),), now, "decode", 1,
                            emitted=1)
             self._step_counts.update(slots_stepped=len(stepped),
-                                     slot_iters=len(stepped))
+                                     slot_iters=len(stepped), iters=1)
             return len(stepped)
 
         return self._launch(
@@ -2654,23 +2680,31 @@ def _scan_layers(block, plan, params, x, k_pages_g, v_pages_g,
         def body(carry, inp):
             x, kg, vg = carry
             layer, number = inp
-            q, k, v, *key = run.project(layer, x, positions)
-            kp = _write_rows(k_layout, pool_of(kg, kind), number, k,
-                             page_ids, offsets)
-            vp = _write_rows(v_layout, pool_of(vg, kind), number, v,
-                             page_ids, offsets)
-            kg, vg = with_pool(kg, kind, kp), with_pool(vg, kind, vp)
-            index = ()
-            if key:
-                # The third row: the index key, at the same page and
-                # slot of the index pool's layer of the same number.
-                ip = _write_rows(plan.index, kg.index, number, key[0],
+            with jax.named_scope("dtt.attn.project"):
+                q, k, v, *key = run.project(layer, x, positions)
+            with jax.named_scope("dtt.kv.write"):
+                kp = _write_rows(k_layout, pool_of(kg, kind), number, k,
                                  page_ids, offsets)
-                kg = kg._replace(index=ip)
-                index = (plan.index.layer(ip, number),)
-            attn = attend(kind, run, layer, q, k, v,
-                          k_layout.layer(kp, number),
-                          v_layout.layer(vp, number), *index)
+                vp = _write_rows(v_layout, pool_of(vg, kind), number, v,
+                                 page_ids, offsets)
+                kg, vg = with_pool(kg, kind, kp), with_pool(vg, kind, vp)
+                index = ()
+                if key:
+                    # The third row: the index key, at the same page and
+                    # slot of the index pool's layer of the same number.
+                    ip = _write_rows(plan.index, kg.index, number,
+                                     key[0], page_ids, offsets)
+                    kg = kg._replace(index=ip)
+                    index = (plan.index.layer(ip, number),)
+            # What ``ops/paged_attention.py`` does not name more closely
+            # (``dtt.kv.read``, ``dtt.attn.select``) is the attention
+            # itself.
+            with jax.named_scope("dtt.attn.core"):
+                attn = attend(kind, run, layer, q, k, v,
+                              k_layout.layer(kp, number),
+                              v_layout.layer(vp, number), *index)
+            # ``dtt.attn.out``, then ``dtt.mlp`` or ``dtt.moe.*``: the
+            # block's.
             x, counts = run.finish(layer, x, attn, valid)
             return (x, kg, vg), counts
         return body
@@ -2680,11 +2714,14 @@ def _scan_layers(block, plan, params, x, k_pages_g, v_pages_g,
     for layers in block.segments(params):
         hi = lo + jax.tree.leaves(layers)[0].shape[0]
         kind, first = plan.run(lo, hi)
-        carry, c = jax.lax.scan(
-            layer_body(kind, block.at(lo)), carry,
-            (layers, jnp.arange(first, first + hi - lo,
-                                dtype=jnp.int32)))
-        counts = counts + c.sum(axis=0)
+        # The loop and what it carries are the engine's; the body names
+        # its parts (``telemetry/op_scopes.py::SCOPES``).
+        with jax.named_scope("dtt.engine"):
+            carry, c = jax.lax.scan(
+                layer_body(kind, block.at(lo)), carry,
+                (layers, jnp.arange(first, first + hi - lo,
+                                    dtype=jnp.int32)))
+            counts = counts + c.sum(axis=0)
         lo = hi
     x, k_pages_g, v_pages_g = carry
     return x, counts, k_pages_g, v_pages_g
@@ -2726,6 +2763,7 @@ def _decode_program(params, k_pages, v_pages, tokens, positions,
     Inactive slots compute garbage into the scratch page and their
     sampled token is 0.
     """
+    import jax
     import jax.numpy as jnp
 
     active = active[0]
@@ -2733,8 +2771,9 @@ def _decode_program(params, k_pages, v_pages, tokens, positions,
         params, _group0(k_pages), _group0(v_pages), page_tables[0],
         tokens[0][:, None], positions[0],
         jnp.ones_like(positions[0]), active, block=block, plan=plan)
-    nxt = _sample(block.logits(params, x[:, 0]), active, rng_data,
-                  temperature, top_k)
+    with jax.named_scope("dtt.head"):
+        nxt = _sample(block.logits(params, x[:, 0]), active, rng_data,
+                      temperature, top_k)
     return (nxt[None], counts[None], _grouped(k_pages_g),
             _grouped(v_pages_g))
 
@@ -2759,13 +2798,18 @@ def _chunk_hidden(params, k_pages_g, v_pages_g, page_rows, tokens,
     k_pages_g, v_pages_g)``."""
     import jax.numpy as jnp
 
+    import jax
+
     S, C = tokens.shape
     ps = plan.k.page_size(pool_of(k_pages_g, GLOBAL))
-    idx = jnp.arange(C, dtype=jnp.int32)
-    abs_pos = start_pos[:, None] + idx[None, :]           # (S, C)
-    valid = (idx[None, :] < n_valid[:, None]) & active[:, None]
-    x = block.embed(params, tokens, abs_pos)              # (S, C, D)
-    rows = plan.rows(page_rows)
+    with jax.named_scope("dtt.engine"):
+        idx = jnp.arange(C, dtype=jnp.int32)
+        abs_pos = start_pos[:, None] + idx[None, :]       # (S, C)
+        valid = (idx[None, :] < n_valid[:, None]) & active[:, None]
+    with jax.named_scope("dtt.embed"):
+        x = block.embed(params, tokens, abs_pos)          # (S, C, D)
+    with jax.named_scope("dtt.engine"):
+        rows = plan.rows(page_rows)
     coords = {}
     for kind, table in rows.items():
         # Page coordinates per (lane, position); dead writes → each
@@ -2773,13 +2817,15 @@ def _chunk_hidden(params, k_pages_g, v_pages_g, page_rows, tokens,
         # positions of a lane near max_seq_len could index past its
         # row).
         P = table.shape[1]
-        logical = (abs_pos // ps % P if kind == WINDOW
-                   else jnp.minimum(abs_pos // ps, P - 1))
-        coords[kind] = (
-            jnp.where(valid, jnp.take_along_axis(table, logical,
-                                                 axis=1), 0),
-            jnp.where(valid, abs_pos % ps, 0))
-    q_pos = jnp.where(valid, abs_pos, -1)                 # (S, C)
+        with jax.named_scope("dtt.kv.write"):
+            logical = (abs_pos // ps % P if kind == WINDOW
+                       else jnp.minimum(abs_pos // ps, P - 1))
+            coords[kind] = (
+                jnp.where(valid, jnp.take_along_axis(table, logical,
+                                                     axis=1), 0),
+                jnp.where(valid, abs_pos % ps, 0))
+    with jax.named_scope("dtt.engine"):
+        q_pos = jnp.where(valid, abs_pos, -1)             # (S, C)
     x, counts, k_pages_g, v_pages_g = _scan_layers(
         block, plan, params, x, k_pages_g, v_pages_g, abs_pos, coords,
         valid,
@@ -2787,11 +2833,12 @@ def _chunk_hidden(params, k_pages_g, v_pages_g, page_rows, tokens,
         run.attend_chunk(layer, q, kp, vp, rows[kind], q_pos, *ip))
     # The engine's own counters, in ``_counters``' order: sequences
     # longer than the window, and than the selection's top-k.
-    for bound in ((plan.window,) if plan.window_layers else ()) + (
-            (plan.index_topk,) if plan.index_topk else ()):
-        over = active & (start_pos + n_valid > bound)
-        counts = jnp.concatenate(
-            [counts, jnp.sum(over, dtype=jnp.int32)[None]])
+    with jax.named_scope("dtt.engine"):
+        for bound in ((plan.window,) if plan.window_layers else ()) + (
+                (plan.index_topk,) if plan.index_topk else ()):
+            over = active & (start_pos + n_valid > bound)
+            counts = jnp.concatenate(
+                [counts, jnp.sum(over, dtype=jnp.int32)[None]])
     return x, valid, counts, k_pages_g, v_pages_g
 
 
@@ -2800,10 +2847,13 @@ def _argmax_chain(block, params, x, valid):
     after EVERY position (position c's argmax is the verified next
     token given tokens[:c+1]) — greedy only, by the spec/resident
     config contract. Invalid positions emit 0."""
+    import jax
     import jax.numpy as jnp
 
-    nxt = jnp.argmax(block.logits(params, x), axis=-1).astype(jnp.int32)
-    return jnp.where(valid, nxt, 0)
+    with jax.named_scope("dtt.head"):
+        nxt = jnp.argmax(block.logits(params, x), axis=-1).astype(
+            jnp.int32)
+        return jnp.where(valid, nxt, 0)
 
 
 def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
@@ -2841,6 +2891,7 @@ def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
 
     Inactive lanes' outputs are 0.
     """
+    import jax
     import jax.numpy as jnp
 
     k_pages_g, v_pages_g = _group0(k_pages), _group0(v_pages)
@@ -2857,12 +2908,13 @@ def _chunk_program(params, k_pages, v_pages, page_rows, tokens,
     else:
         # emit == "last": each lane's LAST VALID position only — the
         # vocab-sized logits never leave the program.
-        last = jnp.maximum(n_valid - 1, 0)[:, None, None]  # (S, 1, 1)
-        x_last = jnp.take_along_axis(
-            x, jnp.broadcast_to(last, (S, 1, x.shape[-1])),
-            axis=1)[:, 0]
-        nxt = _sample(block.logits(params, x_last), active, rng_data,
-                      temperature, top_k)
+        with jax.named_scope("dtt.head"):
+            last = jnp.maximum(n_valid - 1, 0)[:, None, None]  # (S, 1, 1)
+            x_last = jnp.take_along_axis(
+                x, jnp.broadcast_to(last, (S, 1, x.shape[-1])),
+                axis=1)[:, 0]
+            nxt = _sample(block.logits(params, x_last), active,
+                          rng_data, temperature, top_k)
     return (nxt[None], counts[None], _grouped(k_pages_g),
             _grouped(v_pages_g))
 
@@ -2887,28 +2939,30 @@ def _prefill_slots_program(params, k_pages, v_pages, history, kv_len,
     history (1, B, Lmax); kv_len, left (1, B); slot, max_new (1, S);
     the rest as ``_chunk_program``. Returns ``(next_tokens (1, S),
     counts (1, n), history, kv_len, left, k_pages, v_pages)``."""
+    import jax
     import jax.numpy as jnp
 
     nxt, counts, k_pages, v_pages = _chunk_program(
         params, k_pages, v_pages, page_rows, tokens, start_pos, n_valid,
         active, rng_data, block=block, plan=plan,
         temperature=temperature, top_k=top_k, emit="last")
-    hist, kvl, lft = history[0], kv_len[0], left[0]
-    B = hist.shape[0]
-    C = tokens.shape[-1]
-    idx = jnp.arange(C, dtype=jnp.int32)
-    valid = (idx[None, :] < n_valid[0][:, None]) & active[0][:, None]
-    hist = hist.at[jnp.where(valid, slot[0][:, None], B),
-                   start_pos[0][:, None] + idx[None, :]].set(
-                       tokens[0], mode="drop")
-    ends = active[0] & (max_new[0] > 0)
-    row = jnp.where(ends, slot[0], B)
-    end = start_pos[0] + n_valid[0]
-    hist = hist.at[row, end].set(nxt[0], mode="drop")
-    kvl = kvl.at[row].set(end, mode="drop")
-    stop = nxt[0] == eos_id if eos_id >= 0 else False
-    lft = lft.at[row].set(jnp.where(stop, 0, max_new[0] - 1),
-                          mode="drop")
+    with jax.named_scope("dtt.engine"):
+        hist, kvl, lft = history[0], kv_len[0], left[0]
+        B = hist.shape[0]
+        C = tokens.shape[-1]
+        idx = jnp.arange(C, dtype=jnp.int32)
+        valid = (idx[None, :] < n_valid[0][:, None]) & active[0][:, None]
+        hist = hist.at[jnp.where(valid, slot[0][:, None], B),
+                       start_pos[0][:, None] + idx[None, :]].set(
+                           tokens[0], mode="drop")
+        ends = active[0] & (max_new[0] > 0)
+        row = jnp.where(ends, slot[0], B)
+        end = start_pos[0] + n_valid[0]
+        hist = hist.at[row, end].set(nxt[0], mode="drop")
+        kvl = kvl.at[row].set(end, mode="drop")
+        stop = nxt[0] == eos_id if eos_id >= 0 else False
+        lft = lft.at[row].set(jnp.where(stop, 0, max_new[0] - 1),
+                              mode="drop")
     return (nxt, counts, hist[None], kvl[None], lft[None], k_pages,
             v_pages)
 
@@ -2958,7 +3012,9 @@ def _resident_program(params, k_pages, v_pages, history, kv_len, left,
     kp, vp = _group0(k_pages), _group0(v_pages)
     page_rows_g = page_rows[0]
     history_g, kv_len_g, left_g = history[0], kv_len[0], left[0]
-    budget_g = jnp.where(active[0], jnp.minimum(budget[0], left_g), 0)
+    with jax.named_scope("dtt.engine"):
+        budget_g = jnp.where(active[0],
+                             jnp.minimum(budget[0], left_g), 0)
     B, Lmax = history_g.shape
     T = K * C
     pos = jnp.arange(Lmax, dtype=jnp.int32)
@@ -3056,14 +3112,17 @@ def _resident_program(params, k_pages, v_pages, history, kv_len, left,
         return (j + 1, out, n_em, kvl, bud, lft, hist, running,
                 counts + c, kp, vp)
 
-    init = (jnp.zeros((), jnp.int32),
-            jnp.zeros((B, T), jnp.int32),
-            jnp.zeros((B,), jnp.int32),
-            kv_len_g, budget_g, left_g, history_g, budget_g > 0,
-            jnp.zeros((len(_counters(block, plan)),), jnp.int32), kp,
-            vp)
-    j, out, n_em, kvl, _bud, lft, hist, _run, counts, kp, vp = \
-        jax.lax.while_loop(cond, body, init)
+    # The loop, its draft, its stop conditions and what it appends are
+    # the engine's; ``_chunk_hidden`` inside names the model's parts.
+    with jax.named_scope("dtt.engine"):
+        init = (jnp.zeros((), jnp.int32),
+                jnp.zeros((B, T), jnp.int32),
+                jnp.zeros((B,), jnp.int32),
+                kv_len_g, budget_g, left_g, history_g, budget_g > 0,
+                jnp.zeros((len(_counters(block, plan)),), jnp.int32),
+                kp, vp)
+        j, out, n_em, kvl, _bud, lft, hist, _run, counts, kp, vp = \
+            jax.lax.while_loop(cond, body, init)
     return (out[None], n_em[None], jnp.reshape(j, (1,)), counts[None],
             hist[None], kvl[None], lft[None], _grouped(kp),
             _grouped(vp))

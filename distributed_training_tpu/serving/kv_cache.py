@@ -410,23 +410,28 @@ class PoolLayer:
     def slots(self):
         """Every slot of the layer in physical order, ``(num_pages *
         page_size, tiles, tile)``: the pool form's read."""
-        tiles = self.layout.tiled(self.pool[self.number])
-        return tiles.reshape((-1,) + tiles.shape[2:])
+        with jax.named_scope("dtt.kv.read"):
+            tiles = self.layout.tiled(self.pool[self.number])
+            return tiles.reshape((-1,) + tiles.shape[2:])
 
     def pages(self, page_indices):
         """The pages of ``page_indices (B, P)`` dense in table order,
         ``(B, P * page_size, tiles, tile)``: the gather form's read,
         indexed ``(layer, page)`` out of the carried pool. Slot ``s``
         of row ``b`` is logical position ``s`` of the sequence."""
-        tiles = self.layout.tiled(self.pool[self.number, page_indices])
-        return tiles.reshape(tiles.shape[:1] + (-1,) + tiles.shape[3:])
+        with jax.named_scope("dtt.kv.read"):
+            tiles = self.layout.tiled(
+                self.pool[self.number, page_indices])
+            return tiles.reshape(
+                tiles.shape[:1] + (-1,) + tiles.shape[3:])
 
     def rows(self, page_ids, offsets):
         """The rows at ``(page_ids, offsets)`` (one shape, any), ``(...,
         tiles, tile)``: the read of a selection, which takes of a table
         the rows it chose and no page whole."""
-        return self.layout.tiled(
-            self.pool[self.number, page_ids, offsets])
+        with jax.named_scope("dtt.kv.read"):
+            return self.layout.tiled(
+                self.pool[self.number, page_ids, offsets])
 
 
 def as_layer(pages) -> PoolLayer:
