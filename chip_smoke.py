@@ -204,13 +204,56 @@ def _smoke_time(fn, args, reps: int):
     return run, out, (time.perf_counter() - t0) / reps * 1e3
 
 
-def paged_forms_case(B, S, H, Hkv, P, N, hd=64, ps=16, reps=20) -> dict:
-    """Both forms of ``paged_attention_chunk`` (gather, pool) on one
-    layer's bf16 pool at an engine's shapes, against each other: the
-    worst absolute difference, each form's smoke time a call, and the
-    form the rule takes. The sequences share the pool as the engine
+def _in_one_program(fn, inner: int):
+    """``fn(q, kp, vp, tables, q_pos)`` called ``inner`` times in ONE
+    program, each call's layer number and page table fed a zero that
+    the call before computed (so the compiler can neither hoist a
+    gather or a layer's slice out of the loop nor drop a call): the
+    device's time a call, without the 0.2 ms of wall that a dispatch
+    of its own costs, which is most of a small call's. As the engine
+    runs it: a layer's call inside a loop, the layer's number carried."""
+    import jax
+    import jax.numpy as jnp
+
+    if inner == 1:
+        return fn
+
+    def run(q, kp, vp, tables, q_pos):
+        def body(_, carry):
+            zero, _out = carry
+            k, v = (p.layout.layer(p.pool, p.number + zero)
+                    for p in (kp, vp))
+            out = fn(q, k, v, tables + zero, q_pos)
+            return (out[0, 0, 0, 0] > 1e30).astype(jnp.int32), out
+        return jax.lax.fori_loop(
+            0, inner, body, (jnp.int32(0), jnp.zeros_like(q)))[1]
+    return run
+
+
+def _forms_row(label, ms, walked, kp, diff, rule) -> dict:
+    """A paged case's result: each form's ``<form>_ms``, the live
+    ``pages`` the ragged form walks and their nominal ``bytes`` in the
+    two pools (what a layer of ``kp`` holds, as ``chunk_form`` counts
+    it), the worst difference and the rule's form."""
+    page = kp.page_size * 2 * kp.layout.heads * kp.layout.width \
+        * kp.dtype.itemsize
+    return {"ok": True, "shape": label,
+            **{f"{f}_ms": t for f, t in ms.items()}, "pages": walked,
+            "bytes": walked * page, "max_abs_diff": diff, "rule": rule}
+
+
+def paged_forms_case(B, S, H, Hkv, P, N, hd=64, ps=16, reps=20,
+                     inner=1) -> dict:
+    """The forms of ``paged_attention_chunk`` (gather, pool, and where
+    the call's query rows are few enough to be offered it, ragged) on
+    one layer's bf16 pool at an engine's shapes, against the gather
+    form: the worst absolute difference, each form's smoke time a
+    call, the live pages the ragged form walks, and the form the rule
+    takes. The sequences share the pool as the engine
     deals it: distinct pages out of order, ragged lengths, the unused
-    tail of every row on scratch page 0, one inactive row."""
+    tail of every row on scratch page 0, one inactive row. ``inner``
+    calls a program (``_in_one_program``): above 1 the times are the
+    device's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -236,24 +279,114 @@ def paged_forms_case(B, S, H, Hkv, P, N, hd=64, ps=16, reps=20) -> dict:
     q_pos = lengths[:, None] - S + np.arange(S)[None, :]
     q_pos = np.where(lengths[:, None] > 0, q_pos, -1).astype(np.int32)
     args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(q_pos))
+    forms = {"gather": pa._gather_attention,
+             "pool": pa._pool_attention}
+    # The ragged form where the rule may offer it (few query rows).
+    tiles, rows = kp.layout.spread(q).shape[2:4]
+    if tiles * S * rows <= pa._RAGGED_ROWS:
+        forms["ragged"] = pa._ragged_attention
     out, ms = {}, {}
-    for form, fn in (("gather", pa._gather_attention),
-                     ("pool", pa._pool_attention)):
-        _run, out[form], ms[form] = _smoke_time(fn, args, reps)
-    diff = float(jnp.abs(out["pool"].astype(jnp.float32)
-                         - out["gather"].astype(jnp.float32)).max())
-    band = _close("paged_forms", out["pool"], out["gather"])
+    for form, fn in forms.items():
+        _run, out[form], ms[form] = _smoke_time(
+            _in_one_program(fn, inner), args, reps)
+        ms[form] /= inner
+    diff, band = 0.0, 0.0
+    for form in set(forms) - {"gather"}:
+        diff = max(diff, float(jnp.abs(
+            out[form].astype(jnp.float32)
+            - out["gather"].astype(jnp.float32)).max()))
+        band = max(band, _close("paged_forms", out[form],
+                                out["gather"]))
     rule = pa.chunk_form(q.shape, (Hkv, N, ps, hd), tables.shape,
                          kp.dtype.itemsize)
+    walked = int(sum(-(-int(n) // ps) for n in lengths))
     label = f"{B} x {S}, H{H}/{Hkv} D{hd}, pool {N} x {ps}, P {P}"
-    say(f"  paged forms [{label}]: gather {ms['gather']:.3f} ms, pool "
-        f"{ms['pool']:.3f} ms a call (smoke wall), worst |diff| "
+    say(f"  paged forms [{label}]: "
+        + ", ".join(f"{f} {t:.3f} ms" for f, t in ms.items())
+        + f" a call (smoke wall), {walked} pages live, worst |diff| "
         f"{diff:.4f} ({band:.3f} of the bf16 band), rule -> {rule}")
     if band > 1.0:
         raise AssertionError(
             f"forms differ beyond the bf16 band: {diff} ({band})")
-    return {"ok": True, "shape": label, "gather_ms": ms["gather"],
-            "pool_ms": ms["pool"], "max_abs_diff": diff, "rule": rule}
+    return _forms_row(label, ms, walked, kp, diff, rule)
+
+
+def paged_decode_case(B, S, H, Hkv, P, N, hd=128, ps=16, window=None,
+                      ring=False, context=None, pool=False,
+                      layers=1, reps=20, inner=1) -> dict:
+    """The ragged form of ``paged_attention_chunk`` (the Pallas kernel
+    ``dtt_paged_decode``, which walks a sequence's live pages where
+    they lie in the carried pool) against the gather form (and the
+    pool form, ``pool=True``) on one layer's bf16 pool at a decode
+    program's shapes (the pool ``layers`` deep, the layer read in the
+    middle): ``B`` sequences of ``context`` tokens each (all
+    of its pages unless given; of a ring, which has turned where the
+    context is longer), the ``S`` queries the last positions, pages
+    out of order, table tails on scratch page 0, the last sequence
+    dead. The worst absolute difference, each form's smoke time a
+    call (the device's where ``inner`` calls share a program:
+    ``_in_one_program``), the pages and bytes the kernel walks, and
+    the form the rule takes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_training_tpu.ops import paged_attention as pa
+    from distributed_training_tpu.serving.kv_cache import as_layer
+
+    ks = jax.random.split(jax.random.PRNGKey(SEED + 4), 3)
+    q = jax.random.normal(ks[0], (B, S, H, hd), jnp.bfloat16)
+    kp, vp = (as_layer(jax.random.normal(k, (Hkv, N, ps, hd),
+                                         jnp.bfloat16))
+              for k in ks[1:])
+    if layers > 1:      # the layer named in the middle of a deeper pool
+        kp, vp = (p.layout.layer(jnp.tile(p.pool, (layers, 1, 1, 1)),
+                                 p.number + layers // 2)
+                  for p in (kp, vp))
+    rng = np.random.default_rng(SEED + 4)
+    own = min(P, (N - 1) // B)
+    context = context or own * ps
+    held = own if ring else min(own, -(-context // ps))
+    tables = np.zeros((B, P), np.int32)
+    tables[:, :held] = (rng.permutation(N - 1)[:B * held] + 1
+                        ).reshape(B, held)
+    q_pos = np.tile(context - S + np.arange(S, dtype=np.int32), (B, 1))
+    if B > 1:
+        q_pos[-1] = -1
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(q_pos))
+    forms = {"gather": pa._gather_attention,
+             "ragged": pa._ragged_attention}
+    if pool:
+        forms["pool"] = pa._pool_attention
+    out, ms = {}, {}
+    for form, fn in forms.items():
+        run, out[form], ms[form] = _smoke_time(_in_one_program(
+            lambda *a, fn=fn: fn(*a, window, ring), inner), args, reps)
+        ms[form] /= inner
+        if form == "ragged" and "dtt_paged_decode" not in run.as_text():
+            raise AssertionError("no dtt_paged_decode custom call in "
+                                 "the compiled ragged form")
+    diff = float(jnp.abs(out["ragged"].astype(jnp.float32)
+                         - out["gather"].astype(jnp.float32)).max())
+    band = _close("paged_decode", out["ragged"], out["gather"])
+    with pa.observe_forms() as seen:
+        jax.eval_shape(lambda *a: pa.paged_attention_chunk(
+            *a, window=window, ring=ring), *args)
+    # What the kernel walks: the pages that hold the seen slots.
+    lo = max(context - S - window + 1, 0) if window else 0
+    walked = (B - (B > 1)) * ((context - 1) // ps - lo // ps + 1)
+    label = (f"{B} x {S}, H{H}/{Hkv} D{hd}, "
+             f"{'ring' if ring else 'table'} of {P} pages in {N}"
+             + (f", window {window}" if window else "")
+             + f", context {context}")
+    say(f"  paged decode [{label}]: "
+        + ", ".join(f"{f} {ms[f]:.3f} ms" for f in ms)
+        + f" a call (smoke wall), {walked} pages walked, worst |diff| "
+        f"{diff:.4f} ({band:.3f} of the bf16 band), rule -> {seen[0]}")
+    if band > 1.0:
+        raise AssertionError(
+            f"forms differ beyond the bf16 band: {diff} ({band})")
+    return _forms_row(label, ms, walked, kp, diff, seen[0])
 
 
 def paged_prefill_case(B, S, H, Hkv, P, N, hd=128, ps=16, window=None,
@@ -538,6 +671,12 @@ def phase_kernels() -> dict:
         ("paged_prefill_ring_1x1024",
          lambda: paged_prefill_case(1, 1024, 28, 4, P=320, N=10241,
                                     window=4096, ring=True, start=8192)),
+        # ... and its resident decode call over a ring that has turned:
+        # the ragged form's kernel against the gather form.
+        ("paged_decode_ring_32x1",
+         lambda: paged_decode_case(32, 1, 28, 4, P=320, N=10241,
+                                   window=4096, ring=True,
+                                   context=10243)),
         # dots3-note-ep8's two kinds of latent layer, a decode
         # iteration and a prompt chunk at 8k of context; the full
         # layer's chunk in its masked and its gather form at 4k, 8k

@@ -39,9 +39,9 @@ One entrypoint over keys and values:
   to logical positions ``<= its own position``; single-token decode is
   ``S = 1``. Every engine program calls it once a layer. Exact same
   numerics contract as ops/attention.py: fp32 logits/softmax, output
-  in q.dtype, GQA via hkv-major grouping. It has three forms with the
-  same mathematics. ``chunk_form`` takes the cheaper of the first two
-  from the static shapes when the program is traced:
+  in q.dtype, GQA via hkv-major grouping. It has four forms with the
+  same mathematics. ``chunk_form`` takes the cheapest of the first
+  three from the static shapes when the program is traced:
 
   - *gather form* reads ``B * P * ps`` slots: every sequence's whole
     table row copied dense in logical order, whatever is live,
@@ -52,16 +52,31 @@ One entrypoint over keys and values:
     sequences and scores every query against all of them under a mask
     made from the page table turned inside out. No gather; what XLA
     still copies is the layer, once, to put the slots on the lanes
-    for the contraction (ROADMAP S1). Right when queries are few (the
-    resident decode loop 16 x 1, speculative verify 16 x 4, the
-    per-token decode program), where it also keeps the contraction on
-    the MXU in the pool's dtype: with one query a sequence the gather
-    form's per-sequence dot has one row, and the TPU compiler lowers
-    it to a float32 copy of the gathered block and a multiply-reduce.
+    for the contraction (ROADMAP S1). Right when queries are few and
+    the pool is small beside the tables (speculative verify and the
+    per-token decode program of a pool that the tables cover several
+    times over), where it also keeps the contraction on the MXU in
+    the pool's dtype: with one query a sequence the gather form's
+    per-sequence dot has one row, and the TPU compiler lowers it to a
+    float32 copy of the gathered block and a multiply-reduce;
+  - *ragged form*: ONE Pallas TPU kernel (``dtt_paged_decode``,
+    ``_ragged_attention``) that is handed the CARRIED pools as they
+    are stored, left in HBM, and walks, a sequence, only the pages
+    some query of the call sees, where they lie: a page one contiguous
+    DMA into VMEM, several a step, the next step's in flight while
+    this one's are contracted, the softmax online. Nothing is
+    gathered or re-laid, no layer is sliced out of a pool, no logits
+    touch HBM, and what it reads goes with what is live, not with the
+    table's width. It spreads every query over a whole stored row,
+    so it is offered where a call's query rows are few (``_RAGGED_ROWS``:
+    the resident decode loop, speculative verify) and the pool's heads
+    are on one device; its cost is the bytes of the pages the tables
+    can name and a constant a page (the DMAs), both fitted on the chip.
 
-  Both hold the float32 logits of all their queries at once. Where
-  those would pass ``_LOGITS_LIMIT`` (a prompt chunk of 1024 against a
-  ring of 5,120 slots or a table of 16,384) the call takes the third:
+  The first two hold the float32 logits of all their queries at once.
+  Where those would pass ``_LOGITS_LIMIT`` (a prompt chunk of 1024
+  against a ring of 5,120 slots or a table of 16,384) the call takes
+  the fourth:
 
   - *flash form*: the gather form's copy, attended by one Pallas TPU
     kernel (``dtt_paged_prefill``, ``_flash_attention``) that takes
@@ -69,10 +84,8 @@ One entrypoint over keys and values:
     online as ``ops/flash_attention.py`` does for training, so no
     logits are ever held in HBM, the mask is made in the kernel and the
     key blocks no query of a block sees are skipped. The many-query
-    half of the ragged kernel.
-
-  The ragged kernel that reads only the pages a sequence owns is the
-  end state (ROADMAP S1).
+    half of the ragged kernel: reading a prompt chunk's pages in place
+    with the ragged kernel's walk is what is left (ROADMAP S1).
 
   Both forms take a ``window`` (a query sees the last ``window``
   positions, itself included) and, for a window layer's pool, a table
@@ -89,7 +102,7 @@ One entrypoint over keys and values:
 
 There is no switch between the forms: static shapes decide, so a
 compiled program takes a form always or never. The form each took
-(``"pool"``, ``"gather"``, ``"flash"``, of latent attention
+(``"pool"``, ``"gather"``, ``"ragged"``, ``"flash"``, of latent attention
 ``"absorbed"``, ``"expanded"``; under a selection ``"absorbed.sparse"``
 or ``"flash.sparse"``; ``".window"`` behind it over a ring) is seen at
 trace time by
@@ -119,7 +132,8 @@ _observers: list[list[str]] = []
 @contextlib.contextmanager
 def observe_forms():
     """Collect, while open, the form every ``paged_attention_chunk``
-    (``"pool"``, ``"gather"``, ``"flash"``) and ``latent_attention_chunk``
+    (``"pool"``, ``"gather"``, ``"ragged"``, ``"flash"``) and
+    ``latent_attention_chunk``
     (``"absorbed"``, ``"expanded"``, ``".window"`` behind either;
     ``"absorbed.sparse"``, ``"flash.sparse"``) call takes. The form
     follows from
@@ -206,39 +220,65 @@ def _tile_attention(layout, q: jax.Array, k: jax.Array,
 # Nominal sizes mislead by these factors, which is why they are here.
 _GATHER_COPY = 3.8       # gathered KV: the gather out of the carried
 #                          pool and the copy that puts the tiles first
-_GATHER_COPY_ROW = 32.0  # ... with ONE query row a tile (S * group *
-#                          heads a tile == 1: heads of 128 or wider) the
-#                          contraction is no dot: a float32 copy of the
-#                          block and a multiply-reduce (PR 26's reading;
-#                          no shape of the table has one row any more)
 _POOL_READ = 1.9         # the layer sliced out of the carried pool and
 #                          copied tiles first, then read by the dots
 _LOGITS = 2.8            # float32 logits: written, masked, softmaxed,
 #                          cast to the values' dtype, read (both forms)
+# The ragged form, fitted the same way at the resident decode shapes of
+# smallthinker-21b-ep4 and gpt2-xl with a quarter, a half and all of a
+# table live (PERF.md section 6, PR 37): what a byte of a walked page
+# costs, and a page's two DMAs (keys, values) on top of its bytes,
+# which is what makes many short sequences dear. Pages of 20 to 102 kB
+# were timed, and over them the fit trades one constant for the other
+# (1.21 / 10.8e3 and 1.29 / 4.4e3 on two runs of the table, within 8% of
+# each other at every page timed): a page of a few kB, which no engine
+# on a chip has, is priced by the first pair, under which its call's
+# floor (0.03 ms, not in the rule) keeps it off the kernel.
+_RAGGED_READ = 1.2
+_RAGGED_PAGE = 11e3
+
+# Query rows one ragged call may spread over a stored row's lanes (tiles
+# x queries x rows a tile): decode and speculative verify of every
+# engine here; a prompt chunk's thousands keep the gather or flash form.
+_RAGGED_ROWS = 128
 
 
-def chunk_form(q_shape, pool_shape, table_shape, itemsize: int) -> str:
-    """``"pool"`` or ``"gather"``: the form of ``paged_attention_chunk``
-    that moves fewer bytes for q ``(B, S, H, hd)``, a layer of the
-    pool of ``pool_shape = (Hkv, N, ps, hd)`` (kv heads, pages, slots a
-    page, a head's width: what it holds, not how it is stored) in
-    ``itemsize``-byte elements and a table ``(B, P)``.
+def chunk_form(q_shape, pool_shape, table_shape, itemsize: int,
+               ragged: bool = True) -> str:
+    """``"pool"``, ``"gather"`` or ``"ragged"``: the form of
+    ``paged_attention_chunk`` that moves fewer bytes for q ``(B, S, H,
+    hd)``, a layer of the pool of ``pool_shape = (Hkv, N, ps, hd)`` (kv
+    heads, pages, slots a page, a head's width: what it holds, not how
+    it is stored) in ``itemsize``-byte elements and a table ``(B, P)``.
     Shapes are static, so this runs when a program is traced: one
     algorithm whose cost crosses over with the shape. The gather form
     copies ``B * P * ps`` slots whatever is live and scores them; the
     pool form reads ``N * ps`` slots once for all sequences and scores
-    every query against all of them, so it wins while queries are few
-    (decode, speculative verify) and loses by its logits when they are
-    many (prefill chunks)."""
+    every query against all of them, so it wins over the gather form
+    while queries are few (decode, speculative verify) and loses by its
+    logits when they are many (prefill chunks); the ragged form reads
+    at most the pages the tables name, ``min(B * P, N)`` of them (a
+    sequence's live ones: the rule cannot see how few), once, a DMA
+    pair a page, and holds no logits, but spreads every query over a
+    whole stored row, so it is offered (``ragged``: the caller's, false
+    for a pool whose heads are sharded, which one chip's kernel cannot
+    read) only where the call's rows stay under ``_RAGGED_ROWS``: never
+    to a prompt chunk."""
     B, S, H, hd = q_shape
     Hkv, N, ps, _ = pool_shape
     P = table_shape[1]
     kv_slot = 2 * Hkv * hd * itemsize           # keys and values
-    rows = S * (H // Hkv) * max(1, 128 // hd)   # query rows a tile
-    copy = _GATHER_COPY_ROW if rows == 1 else _GATHER_COPY
-    gather = B * P * ps * (copy * kv_slot + _LOGITS * S * H * 4)
-    pool = N * ps * (_POOL_READ * kv_slot + _LOGITS * B * S * H * 4)
-    return "pool" if pool < gather else "gather"
+    per = max(1, 128 // hd)                     # kv heads a tile
+    rows = S * (H // Hkv) * per                 # query rows a tile
+    cost = {
+        "gather": B * P * ps * (_GATHER_COPY * kv_slot
+                                + _LOGITS * S * H * 4),
+        "pool": N * ps * (_POOL_READ * kv_slot
+                          + _LOGITS * B * S * H * 4)}
+    if ragged and -(-Hkv // per) * rows <= _RAGGED_ROWS:
+        cost["ragged"] = min(B * P, N) * (
+            _RAGGED_READ * ps * kv_slot + _RAGGED_PAGE)
+    return min(cost, key=cost.get)
 
 
 def _visible(q_positions: jax.Array, slot_pos: jax.Array,
@@ -339,6 +379,27 @@ def _flash_blocks(S: int, J: int, Sk: int) -> tuple:
     return block_q, block_k
 
 
+def _online_update(s, v, acc_ref, m_ref, l_ref) -> None:
+    """One block of an online softmax, in a kernel: the masked float32
+    logits ``s (rows, slots)`` (``NEG_INF`` where unseen) and the
+    block's values ``v (slots, lanes)`` folded into the running
+    maximum, normaliser and unnormalised float32 accumulator (VMEM
+    scratch); the weights go to the second product in the values'
+    dtype."""
+    m_prev = m_ref[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    # A row that has seen nothing yet: exp(NEG_INF - 0) = 0, where
+    # exp(NEG_INF - NEG_INF) would count every masked slot.
+    m_use = jnp.where(m_new == NEG_INF, 0.0, m_new)
+    p = jnp.exp(s - m_use)
+    alpha = jnp.exp(m_prev - m_use)
+    l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[:] = m_new
+
+
 def _flash_kernel(first_ref, count_ref, pos_ref, q_ref, k_ref, v_ref,
                   o_ref, acc_ref, m_ref, l_ref, *, scale, block_k,
                   slots, window, ring):
@@ -380,19 +441,8 @@ def _flash_kernel(first_ref, count_ref, pos_ref, q_ref, k_ref, v_ref,
             seen = at <= qp
             if window:
                 seen &= at > qp - window
-        s = jnp.where(seen, s, NEG_INF)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # A row that has seen nothing yet: exp(NEG_INF - 0) = 0, where
-        # exp(NEG_INF - NEG_INF) would count every masked slot.
-        m_use = jnp.where(m_new == NEG_INF, 0.0, m_new)
-        p = jnp.exp(s - m_use)
-        alpha = jnp.exp(m_prev - m_use)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+        _online_update(jnp.where(seen, s, NEG_INF), v, acc_ref, m_ref,
+                       l_ref)
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _finalize():
@@ -499,6 +549,293 @@ def _flash_attention(q: jax.Array, k_pages, v_pages,
     return layout.collect(out.transpose(0, 2, 1, 3, 4))
 
 
+# Bytes of keys (and as many of values) a step of the walk copies into
+# one of its two VMEM buffers: 64 pages of (16, 512) bfloat16 lanes,
+# 1,024 slots a contraction; 16 of gpt2-xl's (16, 1664). A step's chain
+# of product, maximum, exponential, sum and product is latency, not
+# work, at a few hundred slots: 16 pages a step ran at 55% of the 64's
+# rate (PERF.md section 6, PR 37).
+_RAGGED_STEP_BYTES = 1 << 20
+
+
+# Page copies an iteration of the loop that starts (or waits for) a
+# step's DMAs, with no branch between them. What is unrolled is traced:
+# the kernel's body is Python run once a kind of layer at every warm-up.
+_RAGGED_UNROLL = 4
+
+
+def _ragged_pages(table_pages: int, page_bytes: int) -> int:
+    """Pages a step of the ragged walk: a power of two whose bytes stay
+    under ``_RAGGED_STEP_BYTES``, no more than the table has, 8 at
+    least."""
+    pages = 8
+    while pages * 2 * page_bytes <= _RAGGED_STEP_BYTES \
+            and pages < table_pages:
+        pages *= 2
+    return pages
+
+
+def _ragged_walks(first, count, ps: int, pages: int):
+    """A sequence's walk from its run of seen slots (``count`` of them
+    from slot ``first`` on, each ``(B,)``), five numbers a sequence
+    flat ``(5 * B,)`` int32: the first page, the slots of it before
+    the run, the run's end as an offset from that page's first slot
+    (the run: offsets ``[lead, end)``), the pages it touches (none for
+    an empty run) and the steps of ``pages`` pages that takes."""
+    page0 = first // ps
+    lead = first - page0 * ps
+    end = lead + count
+    n_pages = jnp.where(count > 0, -(-end // ps), 0)
+    return jnp.stack([page0, lead, end, n_pages, -(-n_pages // pages)]
+                     ).astype(jnp.int32).reshape(-1)
+
+
+def _ragged_kernel(layers_ref, tables_ref, walks_ref, pos_ref, q_ref,
+                   k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, flying,
+                   acc_ref, m_ref, l_ref, *, scale, pages, table_pages,
+                   window, ring):
+    """One sequence of the ragged form: its LIVE pages walked where they
+    lie in the carried pools (``k_hbm`` / ``v_hbm``, left in HBM),
+    ``pages`` of them a step copied into VMEM one DMA a page, the next
+    step's in flight while this one's are contracted
+    (``_flash_kernel``'s online softmax, ``_visible``'s predicate), and
+    the NEXT sequence's first step in flight during this one's last.
+    ``walks_ref``: a sequence's walk (``_ragged_walks``: the run of
+    slots some live query of it sees, as pages and steps);
+    ``pos_ref`` each row's query position and, for a ring, its place in
+    it; ``layers_ref`` the layer's number in either pool; ``flying``
+    (SMEM, kept from one sequence to the next): the buffer the next
+    step to be contracted is copied into, and whether this sequence's
+    first step is in flight already."""
+    b, seqs = pl.program_id(0), pl.num_programs(0)
+    ps = k_buf.shape[1] // pages
+    slots = table_pages * ps
+    layers = layers_ref[0], layers_ref[1]
+
+    def walk(b):
+        """``_ragged_walks``' five numbers of sequence ``b``."""
+        return tuple(walks_ref[n * seqs + b] for n in range(5))
+
+    def copies(b, step, slot, start):
+        """Start, or wait for, the DMAs of the live pages of sequence
+        ``b``'s ``step`` into buffer ``slot``: ``_RAGGED_UNROLL`` pages
+        an iteration with no branch a page, then the few that are left
+        one by one."""
+        page0, _lead, _end, n_pages, _n = walk(b)
+        live = jnp.clip(n_pages - step * pages, 0, pages)
+        unrolled = min(_RAGGED_UNROLL, pages)
+        entry0, row = page0 + step * pages, b * table_pages
+
+        def page(j):
+            rows = pl.ds(pl.multiple_of(j * ps, ps), ps)
+            if start:
+                entry = entry0 + j
+                if ring:               # page0 < P, and P pages at most
+                    entry = jax.lax.select(entry >= table_pages,
+                                           entry - table_pages, entry)
+                at = tables_ref[row + entry]
+            for n, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                            (v_hbm, v_buf))):
+                if start:
+                    pltpu.make_async_copy(
+                        hbm.at[layers[n], at], buf.at[slot, rows],
+                        sems.at[n, slot]).start()
+                else:                  # a wait is for a page's bytes
+                    pltpu.make_async_copy(
+                        hbm.at[0, 0], buf.at[slot, rows],
+                        sems.at[n, slot]).wait()
+
+        def group(g, carry):
+            for j in range(unrolled):
+                page(g * unrolled + j)
+            return carry
+
+        whole = jax.lax.div(live, unrolled)
+        jax.lax.fori_loop(0, whole, group, None)
+        jax.lax.fori_loop(whole * unrolled, live,
+                          lambda j, carry: page(j), None)
+
+    @pl.when(b == 0)
+    def _finite():
+        # A step's slots past the run's pages keep what an earlier step
+        # left there: weight zero times that, which must be finite.
+        v_buf[...] = jnp.zeros_like(v_buf)
+        flying[0] = 0
+        flying[1] = 0
+
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    page0, lead, end, _n_pages, n_steps = walk(b)
+    slot0 = flying[0]
+
+    @pl.when((n_steps > 0) & (flying[1] == 0))
+    def _first():
+        copies(b, 0, slot0, True)
+
+    # The sequence after this one, whose first step goes out during
+    # this one's last (none after the last, none for a dead one).
+    after = jnp.minimum(b + 1, seqs - 1)
+    follows = (b < seqs - 1) & (walk(after)[4] > 0)
+
+    def step(i, carry):
+        slot = jax.lax.rem(slot0 + i, 2)
+        more = i + 1 < n_steps
+
+        @pl.when(more | follows)
+        def _next():    # this sequence's next step, or the next's first
+            copies(jax.lax.select(more, b, after),
+                   jax.lax.select(more, i + 1, 0), 1 - slot, True)
+
+        copies(b, i, slot, False)
+        q, k, v = q_ref[0], k_buf[slot], v_buf[slot]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        qp = pos_ref[0, :, 0:1]                     # (rows, 1)
+        nth = i * (pages * ps) + jax.lax.broadcasted_iota(
+            jnp.int32, (1, pages * ps), 1)          # of the walk
+        at = page0 * ps + nth                       # place, position
+        # ``_visible``, of the run's slots alone: a ring whose run
+        # starts inside a page comes by that page twice.
+        if ring:
+            at = jnp.where(at >= slots, at - slots, at)
+            back = pos_ref[0, :, 1:2] - at          # mod slots, below
+            back = jnp.where(back < 0, back + slots, back)
+            seen = (back < window) & (back <= qp)
+        else:
+            seen = at <= qp
+            if window:
+                seen &= at > qp - window
+        seen &= (nth >= lead) & (nth < end)
+        _online_update(jnp.where(seen, s, NEG_INF), v, acc_ref, m_ref,
+                       l_ref)
+        return carry
+
+    jax.lax.fori_loop(0, n_steps, step, None)
+
+    @pl.when(n_steps > 0)
+    def _hand_over():
+        flying[0] = jax.lax.rem(slot0 + n_steps, 2)
+        flying[1] = follows.astype(jnp.int32)
+
+    lsum = l_ref[:]
+    o_ref[0] = (acc_ref[:] / jnp.where(lsum == 0.0, 1.0, lsum)
+                ).astype(o_ref.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _ragged_call(B, rows, lanes, cols, P, ps, pages, k_dtype, v_dtype,
+                 q_dtype, scale, window, ring, interpret):
+    """The ``pl.pallas_call`` of the ragged form for one set of static
+    shapes, made once: a program calls it a run of like layers (six
+    times in ``smallthinker-21b-ep4``'s resident decode), and the
+    kernel's body, a thousand operations to trace, is then traced once
+    a kind of layer and not once a run."""
+    def of_rows(b, *_):
+        return b, 0, 0
+
+    return pl.pallas_call(
+        functools.partial(_ragged_kernel, scale=scale, pages=pages,
+                          table_pages=P, window=window, ring=ring),
+        name="dtt_paged_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, rows, cols), of_rows),
+                pl.BlockSpec((1, rows, lanes), of_rows),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, rows, lanes), of_rows),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * ps, lanes), k_dtype),
+                pltpu.VMEM((2, pages * ps, lanes), v_dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.VMEM((rows, lanes), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, rows, lanes), q_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret)
+
+
+def _ragged_attention(q: jax.Array, k_pages, v_pages,
+                      page_indices: jax.Array,
+                      q_positions: jax.Array, window=None,
+                      ring: bool = False, pages=None) -> jax.Array:
+    """Ragged form: ONE Pallas kernel (``dtt_paged_decode``) that takes
+    the carried pools as they are stored and reads, a sequence, the
+    pages some query of the call sees, where they lie. Nothing is
+    gathered or re-laid, no layer is sliced out of a pool, no logits
+    touch HBM.
+
+    Grid ``(B,)``, a sequence a step. The page table, the layer's
+    number and the walk of the sequence's run of seen slots (``first``,
+    ``count``: positions of a table, places round a ring,
+    ``_flash_attention``'s arithmetic with the call one query block;
+    ``_ragged_walks``) are prefetched scalars;
+    the kernel walks the run's pages ``pages`` a step (``_ragged_pages``'
+    unless given), a page one contiguous DMA ``(page_size, lanes)`` out
+    of ``pool[number, page_indices[b, j]]`` into one of two VMEM
+    buffers, a sequence's first step during the last of the sequence
+    before. The queries are spread over the whole stored row (a row a
+    tile, query and tile-row: its head on its kv head's lanes of its
+    tile, zeros on every other lane), so one product of ``tiles * S *
+    J`` rows against a step's slots contracts every tile on the lanes
+    as they lie, and of the weighted sum a row keeps its own tile's
+    lanes (``layout.collect``). Same precisions as ``_flash_attention``:
+    operands in the pool's dtype to both products, float32 logits,
+    maximum, exponentials and sums, weights cast to the pool's dtype
+    before the second product, the division last, output in
+    ``q.dtype``; a dead query walks nothing and gives zeros."""
+    layout = k_pages.layout
+    B, S = q.shape[:2]
+    P, ps = page_indices.shape[1], k_pages.page_size
+    pages = pages or _ragged_pages(
+        P, ps * layout.lanes * k_pages.dtype.itemsize)
+    qt = layout.spread(q).transpose(0, 2, 1, 3, 4)  # (B, T, S, J, tile)
+    T, J, tile = qt.shape[1], qt.shape[3], qt.shape[4]
+    rows, lanes = T * S * J, T * tile
+    pad = -rows % 16                   # a bfloat16 block's sublanes
+    full = jnp.einsum("btsjl,tu->btsjul", qt, jnp.eye(T, dtype=q.dtype))
+    full = jnp.pad(full.reshape(B, rows, lanes), ((0, 0), (0, pad), (0, 0)))
+    with jax.named_scope("dtt.kv.read"):
+        # The slots some live query sees: positions ``first`` to the
+        # highest live position, round a ring from place ``first % Sk``.
+        live = q_positions >= 0
+        top = jnp.max(q_positions, axis=-1)          # -1: all dead
+        low = jnp.min(jnp.where(live, q_positions, top[:, None]), axis=-1)
+        first = jnp.maximum(low - window + 1, 0) if window \
+            else jnp.zeros_like(low)
+        count = top - first + 1                      # 0 where all dead
+        if ring:
+            first, count = first % (P * ps), jnp.minimum(count, P * ps)
+        walks = _ragged_walks(first, count, ps, pages)
+        pos = jnp.broadcast_to(q_positions[:, None, :, None],
+                               (B, T, S, J)).reshape(B, rows, 1)
+        pos = jnp.pad(pos, ((0, 0), (0, pad), (0, 0)), constant_values=-1)
+        if ring:
+            pos = jnp.concatenate([pos, pos % (P * ps)], axis=-1)
+        layers = jnp.stack([k_pages.number, v_pages.number]
+                           ).astype(jnp.int32)
+        tables = page_indices.reshape(-1).astype(jnp.int32)
+
+    with jax.named_scope("dtt.attn.core"):
+        out = _ragged_call(
+            B, rows + pad, lanes, pos.shape[-1], P, ps, pages,
+            k_pages.dtype, v_pages.dtype, q.dtype, q.shape[-1] ** -0.5,
+            window or 0, ring, not _platform_is_tpu(),
+        )(layers, tables, walks, pos, full, k_pages.pool, v_pages.pool)
+    # Of every row its own tile's lanes.
+    out = jnp.einsum("btsjtl->bstjl", out[:, :rows].reshape(
+        B, T, S, J, T, tile))
+    return layout.collect(out)
+
+
 def _held(pages) -> tuple:
     """``(Hkv, N, ps, hd)`` of a layer's view: what ``chunk_form``
     reasons from."""
@@ -523,23 +860,26 @@ def paged_attention_chunk(q: jax.Array, k_pages, v_pages,
     ``window`` positions, the query's own included; ``ring`` says the
     table is a window layer's ring (see the module's text), which
     needs a window no longer than the ring less the rows a launch
-    writes. The form follows from the static shapes: ``chunk_form``'s,
-    or ``"flash"`` where that form's logits would not fit one pass
-    (``_one_pass_fits``); a call over a ring is reported as
+    writes. The form follows from the static shapes: ``chunk_form``'s
+    (the ragged form not offered to a pool whose heads are sharded), or
+    ``"flash"`` where the pool or gather form's logits would not fit
+    one pass (``_one_pass_fits``); a call over a ring is reported as
     ``"<form>.window"``.
     """
     if ring and not window:
         raise ValueError("a ring table needs the window it was sized "
                          "for")
     form = chunk_form(q.shape, _held(k_pages), page_indices.shape,
-                      k_pages.dtype.itemsize)
+                      k_pages.dtype.itemsize,
+                      ragged=k_pages.layout.shards == 1)
     slots = k_pages.page_size * (k_pages.num_pages if form == "pool"
                                  else page_indices.shape[1])
-    if not _one_pass_fits(q.shape, slots):
+    if form != "ragged" and not _one_pass_fits(q.shape, slots):
         form = "flash"
     _took(form + ".window" if ring else form)
     attend = {"pool": _pool_attention, "gather": _gather_attention,
-              "flash": _flash_attention}[form]
+              "flash": _flash_attention,
+              "ragged": _ragged_attention}[form]
     return attend(q, k_pages, v_pages, page_indices, q_positions,
                   window, ring)
 

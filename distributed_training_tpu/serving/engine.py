@@ -815,8 +815,8 @@ class Engine:
                 "EngineConfig.prefix_sharing (the prefix index, "
                 "copy-on-write and retained sessions; pass "
                 "prefix_sharing=False)")
-        self._counters = _counters(self.block,
-                                   _plan(self.block, cfg, mesh))
+        self._plan = _plan(self.block, cfg, mesh)
+        self._counters = _counters(self.block, self._plan)
         self.queue: collections.deque[Request] = collections.deque()
         self._admitted = 0            # admissions so far (_Seq.admitted_n)
         self.slots: list[_Seq | None] = [None] * cfg.max_batch
@@ -1803,6 +1803,39 @@ class Engine:
                        ran_ahead=int(self._flying is not None),
                        lanes=lanes)
 
+    def _walked(self, runs: list, calls: int) -> dict:
+        """``kv_pages_walked`` / ``kv_pages_tabled`` of a decode
+        launch's step record, where its program reads a kind of layer
+        in the ragged form (``paged_form`` of the traced program; else
+        nothing): the pages the kernel walked, and the pages the
+        gather form would have copied. ``runs``: ``(low, top)``, the
+        lowest and highest query position of every live slot of every
+        iteration; ``calls``: the slots of every iteration, dead ones
+        too, whose whole table rows a gather copies. A kind's layers
+        walk the pages that hold positions ``low - window + 1`` (0
+        without a window) to ``top``: host arithmetic on positions the
+        retire holds, no device sync."""
+        forms = (self._decode_fn.__wrapped__.paged_form or "").split("+")
+        plan, ps = self._plan, self.cfg.page_size
+        windowed = len(plan.window_layers)
+        kinds = []              # (layers, pages a table row, window)
+        if "ragged" in forms:
+            # A block may mask its full tables to a window of its own.
+            kinds.append((plan.n_layers - windowed, plan.pages_per_seq,
+                          getattr(self.block.cfg, "attention_window",
+                                  0) or 0))
+        if "ragged.window" in forms:
+            kinds.append((windowed, plan.ring_pages, plan.window))
+        if not kinds:
+            return {}
+        walked = sum(
+            layers * (top // ps - (max(low - window + 1, 0)
+                                   if window else 0) // ps + 1)
+            for layers, _row, window in kinds for low, top in runs)
+        return {"kv_pages_walked": walked,
+                "kv_pages_tabled": calls * sum(
+                    layers * row for layers, row, _w in kinds)}
+
     def _emit(self, s: _Seq, toks, now: float, ev: str, advance: int,
               **fields) -> None:
         """The back of every launch, for one sequence: the cache
@@ -1971,6 +2004,8 @@ class Engine:
 
         def emit(now, out):
             total = 0
+            runs = [(int(start_pos[at]), int(start_pos[at]) + n - 1)
+                    for _s, at, n, _d in stepped]
             for s, at, n, draft in stepped:
                 # out[at][j] is the verified argmax AFTER position
                 # j. Accept draft j while it equals the chain's
@@ -1995,7 +2030,8 @@ class Engine:
             self._step_counts.update(
                 slots_stepped=len(stepped), slot_iters=len(stepped),
                 iters=1, spec_k=K,
-                spec_accepted_mean=round(total / len(stepped), 4))
+                spec_accepted_mean=round(total / len(stepped), 4),
+                **self._walked(runs, G * B))
             return total
 
         return self._launch(
@@ -2070,17 +2106,25 @@ class Engine:
 
         def emit(now, out, n_emitted, steps):
             total = slot_iters = 0
+            runs, K = [], self.cfg.spec_k
             for s, at, want in stepped:
                 if self.slots[s.slot] is not s:
                     continue    # it ended in the launch before
                 s.pending -= want
                 e = int(n_emitted[at])
+                start = self.cache.length(s.req.id)
                 self._emit(s, out[at][:e].tolist(), now, "decode", e,
                            emitted=e, budget=want)
                 total += e
                 # A live iteration emits at least one token, and a
                 # slot is live in at most its group's iterations.
-                slot_iters += min(e, int(steps[at[0]]))
+                live = min(e, int(steps[at[0]]))
+                slot_iters += live
+                # Iteration i's queries: exact at ``spec_k == 1``, the
+                # accepted tokens spread evenly otherwise.
+                runs += [(start + i * e // live,
+                          start + i * e // live + K - 1)
+                         for i in range(live)]
                 if e < want and self.slots[s.slot] is s:
                     # Pages claimed for tokens that did not come.
                     self.cache.trim(s.req.id, s.kv_ahead)
@@ -2093,7 +2137,8 @@ class Engine:
                 slots_stepped=len(stepped), slot_iters=slot_iters,
                 iters=max(g_steps, default=0),
                 resident_k=self.cfg.resident_k,
-                resident_steps_per_launch=round(mean_steps, 4))
+                resident_steps_per_launch=round(mean_steps, 4),
+                **self._walked(runs, sum(g_steps) * B))
             return total
 
         return self._launch(
@@ -2132,8 +2177,11 @@ class Engine:
             for s, at in stepped:
                 self._emit(s, (int(nxt[at]),), now, "decode", 1,
                            emitted=1)
-            self._step_counts.update(slots_stepped=len(stepped),
-                                     slot_iters=len(stepped), iters=1)
+            self._step_counts.update(
+                slots_stepped=len(stepped), slot_iters=len(stepped),
+                iters=1, **self._walked(
+                    [(int(positions[at]),) * 2 for _s, at in stepped],
+                    G * B))
             return len(stepped)
 
         return self._launch(

@@ -281,7 +281,8 @@ def test_flash_kernels_keep_their_names_under_tpu_compiler(
 @pytest.mark.parametrize("S", [1, 4], ids=["resident", "spec4"])
 def test_decode_shaped_paged_attention_reads_pool_in_place(S):
     """The decode-shaped call of ``gpt2xl.serve_decode`` (16 slots x
-    ``S`` queries, 25 heads of 64, a 385-page pool, bfloat16) compiles
+    ``S`` queries, 25 heads of 64, a 385-page pool, bfloat16) in the
+    pool form compiles
     for a v5e to a program that gathers nothing and holds no float32
     array the size of the gathered block. The gather form compiled to
     ``f32[1024,25,16,64]`` converts and multiply-reduces that held 74%
@@ -310,12 +311,14 @@ def test_decode_shaped_paged_attention_reads_pool_in_place(S):
 
     layout = PoolLayout(H, hd)
     pool = layout.layer(shape(layout.shape(1, N, ps), jnp.bfloat16), 0)
-    with pa.observe_forms() as seen:
-        text = jax.jit(pa.paged_attention_chunk).lower(
-            shape((B, S, H, hd), jnp.bfloat16), pool, pool,
-            shape((B, P), jnp.int32),
-            shape((B, S), jnp.int32)).compile().as_text()
-    assert seen == ["pool"]
+    # The form a pool with sharded heads takes at these shapes; on one
+    # chip the rule now hands them to the ragged form's kernel (PR 37).
+    assert pa.chunk_form((B, S, H, hd), (H, N, ps, hd), (B, P), 2,
+                         ragged=False) == "pool"
+    text = jax.jit(pa._pool_attention).lower(
+        shape((B, S, H, hd), jnp.bfloat16), pool, pool,
+        shape((B, P), jnp.int32),
+        shape((B, S), jnp.int32)).compile().as_text()
     assert " gather(" not in text
     gathered = B * P * ps * H * hd
     largest = max(math.prod(int(d) for d in dims.split(","))
@@ -329,10 +332,11 @@ def test_decode_shaped_paged_attention_reads_pool_in_place(S):
     # and over a global layer's table ...
     ((1, 1024, 28, 128), 4, 320, 10241, 4096, "flash.window"),
     ((1, 1024, 28, 128), 4, 1024, 32769, None, "flash"),
-    # ... and gpt2-xl's two programs, which the rule leaves alone.
+    # ... and gpt2-xl's prefill program and a verify chunk of 8, which
+    # the rule leaves to XLA.
     ((4, 128, 25, 64), 25, 64, 385, None, "gather"),
-    ((16, 1, 25, 64), 25, 64, 385, None, "pool"),
-], ids=["ring_1x1024", "table_1x1024", "xl_4x128", "xl_16x1"])
+    ((16, 8, 25, 64), 25, 64, 385, None, "pool"),
+], ids=["ring_1x1024", "table_1x1024", "xl_4x128", "xl_16x8"])
 def test_prompt_chunk_attention_is_one_kernel_and_holds_no_logits(
         monkeypatch, shape, heads, P, N, window, form):
     """``paged_attention_chunk`` at ``smallthinker_ep4.serve_long``'s
@@ -392,6 +396,81 @@ def test_prompt_chunk_attention_is_one_kernel_and_holds_no_logits(
                    for dims in re.findall(r"f32\[([0-9,]+)\]", text)]
                   or [0])
     assert largest < S * H * P * 16, largest
+
+
+@pytest.mark.parametrize("shape,heads,P,N,layers,window", [
+    # smallthinker-21b-ep4's resident decode over a global layer's
+    # table and a window layer's ring, the carried pools whole ...
+    ((32, 1, 28, 128), 4, 1024, 32769, 3, None),
+    ((32, 1, 28, 128), 4, 320, 10241, 9, 4096),
+    # ... and gpt2-xl's, resident decode and speculative verify.
+    ((16, 1, 25, 64), 25, 64, 385, 48, None),
+    ((16, 4, 25, 64), 25, 64, 385, 48, None),
+], ids=["thinker_table_32x1", "thinker_ring_32x1", "xl_16x1", "xl_16x4"])
+def test_decode_attention_is_one_kernel_over_the_carried_pool(
+        monkeypatch, shape, heads, P, N, layers, window):
+    """The ragged form at the resident decode shapes of
+    ``smallthinker_ep4.serve_long`` and ``gpt2xl.serve_decode`` (bfloat16
+    pools at the cells' sizes, every layer of them) compiles for a v5e,
+    under Mosaic's VMEM and SMEM checks, to a program whose one custom
+    call ``dtt_paged_decode`` takes the WHOLE pools as operands: no
+    gather, no slice or copy of a layer (the six ``bf16[32768,16,512]``
+    gathers, six ``bf16[10240,16,512]`` and twelve
+    ``bf16[32,16384,4,128]`` copies that were 70.8% of the first cell's
+    device time: ledger, PR 36), no ``reduce-window``, no float32 array
+    an eighth the size of the gathered block (what is there is the
+    queries' spread), no temporaries to speak of. The name
+    on the instruction is what ``ops.paged_decode_time_share.decode``
+    looks for."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_training_tpu.ops import paged_attention as pa
+    from distributed_training_tpu.serving.kv_cache import PoolLayout
+    from perfbench import common, trace_reduce
+
+    monkeypatch.setenv("DTT_ASSUME_TPU", "1")
+    try:
+        from distributed_training_tpu.runtime import topology_runtime
+        chip = SingleDeviceSharding(
+            topology_runtime(1, "v5e:2x2").mesh.devices.flat[0])
+    except Exception as e:  # pragma: no cover - no libtpu
+        pytest.skip(f"device-less TPU topology unavailable: {e}")
+
+    def struct(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    B, S, H, hd = shape
+    layout = PoolLayout(heads, hd)
+    pool = layout.layer(struct(layout.shape(layers, N, 16), jnp.bfloat16),
+                        struct((), jnp.int32))
+    compiled = jax.jit(lambda *a: pa._ragged_attention(
+        *a, window=window, ring=bool(window))).lower(
+        struct(shape, jnp.bfloat16), pool, pool,
+        struct((B, P), jnp.int32), struct((B, S), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if " custom-call(" in line
+             and 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert re.match(r"%dtt_paged_decode\.\d+ = ", calls[0])
+    pattern = common.load_file(
+        "layer_metrics", "ops.paged_decode_time_share.decode").PATTERN
+    assert pattern.match(trace_reduce.short_name(calls[0])), calls[0]
+    # Both pools go in whole, as the program's own parameters.
+    whole = "bf16[%s]" % ",".join(map(str, pool.pool.shape))
+    assert calls[0].count(whole) == 2, calls[0]
+    assert " gather(" not in text and "reduce-window" not in text
+    assert not re.search(r"bf16\[[0-9,]*,16,%d\]\S* (copy|dynamic-slice)"
+                         % layout.lanes, text)
+    largest = max([math.prod(int(d) for d in dims.split(","))
+                   for dims in re.findall(r"f32\[([0-9,]+)\]", text)]
+                  or [0])
+    assert largest < B * P * 16 * heads * hd / 8, largest
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 @pytest.mark.parametrize("B,S,form", [

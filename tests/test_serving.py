@@ -250,37 +250,165 @@ def test_paged_chunk_forms_match_dense_reference(S, group, dtype):
                                atol=tol)
 
 
+def _ragged_case(S, H, Hkv, hd, P, tops, dtype, ps=4, ring=False,
+                 dead=(), seed=0):
+    """One layer's call for the ragged form against the gather form:
+    a pool whose EVERY slot holds something (so a mask that lets one
+    too many through shows), scratch page 0 large finite garbage, a
+    sequence the ``S`` queries up to position ``tops[b]`` (negative:
+    a dead sequence), ``dead`` queries ``(b, s)`` among the live. A
+    table row holds the pages of its positions and its unused tail is
+    scratch page 0; a ring row holds all ``P`` of its pages."""
+    rng = np.random.default_rng(seed)
+    B = len(tops)
+    N = B * P + 1
+    kp, vp = (rng.standard_normal((Hkv, N, ps, hd)).astype(np.float32)
+              for _ in range(2))
+    kp[:, 0], vp[:, 0] = 1e4, -1e4
+    tables = (rng.permutation(N - 1)[:B * P] + 1).reshape(B, P)
+    q_pos = np.asarray([[top - S + 1 + s if top >= 0 else -1
+                         for s in range(S)] for top in tops], np.int32)
+    for b, s in dead:
+        q_pos[b, s] = -1
+    if not ring:
+        for b, top in enumerate(tops):
+            tables[b, top // ps + 1 if top >= 0 else 0:] = 0
+    q = rng.standard_normal((B, S, H, hd)).astype(np.float32)
+    return (jnp.asarray(q, dtype), _as_layer(kp, dtype),
+            _as_layer(vp, dtype), jnp.asarray(tables, jnp.int32),
+            jnp.asarray(q_pos))
+
+
+# name: (shape, window, ring, dead queries). Pages of 4 in tables and
+# rings of 8: lengths 1, one under / at / one over a page boundary, a
+# whole table, a dead sequence; a ring of 32 slots for a window of 20
+# whose query sits at ``ring + 3`` and later turns.
+_LENGTHS = [0, 2, 3, 4, 31, -1]
+_RAGGED = {
+    "table": (dict(S=1, H=4, Hkv=2, hd=16, P=8, tops=_LENGTHS),
+              None, False, ()),
+    "table_window": (dict(S=1, H=4, Hkv=2, hd=16, P=8, tops=_LENGTHS),
+                     6, False, ()),
+    "table_spec4": (dict(S=4, H=4, Hkv=2, hd=16, P=8,
+                         tops=[3, 4, 5, 18, 31, -1]), None, False,
+                    ((0, 0), (3, 3))),
+    "table_window_spec4": (dict(S=4, H=4, Hkv=2, hd=16, P=8,
+                                tops=[3, 4, 5, 18, 31, -1]), 6, False,
+                           ((3, 0),)),
+    "ring_turned": (dict(S=1, H=4, Hkv=2, hd=16, P=8,
+                         tops=[35, 0, 18, 19, 20, 167, -1]), 20, True,
+                    ()),
+    "ring_spec4": (dict(S=4, H=4, Hkv=2, hd=16, P=8,
+                        tops=[35, 3, 21, 34, 129, -1]), 20, True,
+                   ((0, 1), (4, 0))),
+    # smallthinker's heads: 7 query heads a kv head of 128, a tile each.
+    "gqa7_heads_of_128": (dict(S=1, H=14, Hkv=2, hd=128, P=8,
+                               tops=[0, 4, 30]), None, False, ()),
+    "gqa7_heads_of_128_ring": (dict(S=4, H=14, Hkv=2, hd=128, P=8,
+                                    tops=[35, 7, 99]), 20, True, ()),
+    # gpt2's: heads of 64 two a tile, the third kv head alone in its.
+    "two_heads_a_tile": (dict(S=1, H=3, Hkv=3, hd=64, P=8,
+                              tops=[3, 4, 31, -1]), None, False, ()),
+    "two_heads_a_tile_spec4": (dict(S=4, H=6, Hkv=3, hd=64, P=8,
+                                    tops=[3, 17, 31]), 9, False,
+                               ((1, 3),)),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", list(_RAGGED))
+def test_ragged_form_agrees_with_the_gather_form(name, dtype):
+    """The ragged form's kernel (``dtt_paged_decode``, interpreted
+    here: a sequence's live pages walked where they lie in the pool, a
+    DMA a page, the softmax online) against the gather form over the
+    same pool under ``_visible``'s mask: float32 to rounding, bfloat16
+    to the forms' band; zeros where a query is dead, never NaN, though
+    the layers around the one named are NaN and scratch page 0 is
+    1e4. Two pages a step, so that runs end inside a step, at its end
+    and one page into the next; then the pages the shapes would get."""
+    from distributed_training_tpu.ops import paged_attention as pa
+
+    shape, window, ring, dead = _RAGGED[name]
+    args = _ragged_case(**shape, dtype=jnp.dtype(dtype), ring=ring,
+                        dead=dead)
+    want = pa._gather_attention(*args, window=window, ring=ring)
+    tol = 2e-4 if dtype == "float32" else 2e-2
+    q_pos = np.asarray(args[4])
+    for pages in (2, None):
+        got = jax.jit(lambda *a, pages=pages: pa._ragged_attention(
+            *a, window=window, ring=ring, pages=pages))(*args)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        got = np.asarray(got.astype(jnp.float32))
+        assert np.isfinite(got).all(), pages
+        np.testing.assert_allclose(
+            got, np.asarray(want.astype(jnp.float32)), atol=tol,
+            rtol=tol, err_msg=str(pages))
+        assert not got[q_pos < 0].any()
+        assert np.abs(got[q_pos >= 0]).max() > 0.1
+
+
 # name: (q (B, S, H, hd), pool (Hkv, N, ps, hd), P), the form PERF.md
-# section 6 (PR 29) records as the faster on the chip: the thirteen
-# shapes of benchmarks/paged_form_table.py.
+# section 6 (PR 37; PR 29 before the ragged form) records as the faster
+# on the chip: the thirteen shapes of benchmarks/paged_form_table.py,
+# then its decode rows (smallthinker-21b-ep4's table and ring, gpt2-xl's
+# resident decode and speculative verify) and two prompt chunks, which
+# are never offered the ragged form.
 _XL, _SMALL = (25, 385, 16, 64), (12, 3073, 16, 64)
+_THINKER = (32, 1, 28, 128)
 _CALIBRATION = {
-    "xl.resident_16x1": ((16, 1, 25, 64), _XL, 64, "pool"),
+    "xl.resident_16x1": ((16, 1, 25, 64), _XL, 64, "ragged"),
     "xl.prefill_batch_4x128": ((4, 128, 25, 64), _XL, 64, "gather"),
     "xl.prefill_cont_1x128": ((1, 128, 25, 64), _XL, 64, "gather"),
-    "xl.spec_16x4": ((16, 4, 25, 64), _XL, 64, "pool"),
-    "small.resident_64x1": ((64, 1, 12, 64), _SMALL, 64, "pool"),
+    "xl.spec_16x4": ((16, 4, 25, 64), _XL, 64, "ragged"),
+    "small.resident_64x1": ((64, 1, 12, 64), _SMALL, 64, "ragged"),
     "xl.16x8": ((16, 8, 25, 64), _XL, 64, "pool"),
     "xl.16x16": ((16, 16, 25, 64), _XL, 64, "gather"),
     "xl.16x32": ((16, 32, 25, 64), _XL, 64, "gather"),
     "xl.4x32": ((4, 32, 25, 64), _XL, 64, "gather"),
-    "small.spec_64x4": ((64, 4, 12, 64), _SMALL, 64, "gather"),
+    "small.spec_64x4": ((64, 4, 12, 64), _SMALL, 64, "ragged"),
     "small.prefill_batch_8x128": ((8, 128, 12, 64), _SMALL, 64,
                                   "gather"),
-    "small.16x1": ((16, 1, 12, 64), _SMALL, 64, "gather"),
-    "xl.gqa_16x1": ((16, 1, 25, 64), (5, 385, 16, 64), 64, "pool"),
+    "small.16x1": ((16, 1, 12, 64), _SMALL, 64, "ragged"),
+    "xl.gqa_16x1": ((16, 1, 25, 64), (5, 385, 16, 64), 64, "ragged"),
+    "thinker.table_32x1": (_THINKER, (4, 32769, 16, 128), 1024,
+                           "ragged"),
+    "thinker.ring_32x1": (_THINKER, (4, 10241, 16, 128), 320, "ragged"),
+    "thinker.table_spec_32x4": ((32, 4, 28, 128), (4, 32769, 16, 128),
+                                1024, "ragged"),
+    "thinker.table_1x1024": ((1, 1024, 28, 128), (4, 32769, 16, 128),
+                             1024, "gather"),
+    "thinker.ring_1x1024": ((1, 1024, 28, 128), (4, 10241, 16, 128),
+                            320, "gather"),
 }
 
 
 @pytest.mark.parametrize("name", sorted(_CALIBRATION))
 def test_chunk_form_rule_at_calibration_shapes(name):
-    """The rule alone: the thirteen shapes it was calibrated on give
-    the forms the chip found faster (PERF.md section 6, PR 29)."""
+    """The rule alone: the shapes it was calibrated on give the forms
+    the chip found faster (PERF.md section 6, PR 37 and PR 29)."""
     from distributed_training_tpu.ops.paged_attention import (
         chunk_form)
 
     q_shape, pool_shape, P, want = _CALIBRATION[name]
     assert chunk_form(q_shape, pool_shape, (q_shape[0], P), 2) == want
+
+
+@pytest.mark.parametrize("name", ["xl.resident_16x1", "xl.spec_16x4",
+                                  "small.16x1", "thinker.table_32x1",
+                                  "thinker.ring_32x1"])
+def test_chunk_form_keeps_a_sharded_pool_off_the_kernel(name):
+    """A pool whose heads are sharded is not offered the ragged form
+    (one chip's kernel cannot read it): the rule then gives what it
+    gave before the form was there, PR 29's table."""
+    from distributed_training_tpu.ops.paged_attention import (
+        chunk_form)
+
+    q_shape, pool_shape, P, _ragged = _CALIBRATION[name]
+    want = {"xl.resident_16x1": "pool", "xl.spec_16x4": "pool",
+            "small.16x1": "gather", "thinker.table_32x1": "gather",
+            "thinker.ring_32x1": "gather"}[name]
+    assert chunk_form(q_shape, pool_shape, (q_shape[0], P), 2,
+                      ragged=False) == want
 
 
 def test_observe_forms_sees_the_form_when_traced_not_when_run():
@@ -2025,6 +2153,92 @@ def test_engine_reports_paged_form_per_program(tiny_model, tmp_path,
     # (XLA's CPU copy-on-write program does hold a copy.)
     assert all(p["temp_bytes"] < warm[0]["pool_bytes_tiled"]
                for p in warm[0]["programs"] if p["paged_form"])
+
+
+@functools.lru_cache(maxsize=None)
+def _wide_head_model():
+    """Two kv heads of 64, a 128-lane tile: with pages of 16 a page of
+    a layer is 8 KB, and ``chunk_form`` takes the ragged form for a
+    decode program's few queries (a page of the other tiny models is
+    under 1 KB, where a page's DMAs outweigh its bytes)."""
+    cfg = TransformerConfig(
+        vocab_size=256, d_model=128, n_layers=2, n_heads=2,
+        n_kv_heads=2, max_seq_len=128, dtype="float32",
+        param_dtype="float32", pos_encoding="rope",
+        tie_embeddings=False)
+    model = Transformer(cfg)
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+_RAGGED_ENGINE = dict(page_size=16, num_pages=64, max_seq_len=64)
+
+
+@pytest.mark.parametrize("cadence", ["plain", "spec4", "resident8"])
+def test_engine_in_the_ragged_form_emits_what_the_gather_form_emits(
+        monkeypatch, cadence):
+    """A tiny dense engine whose decode program the rule gives the
+    ragged form (the kernel ``dtt_paged_decode``, interpreted here)
+    streams token for token what the same engine streams with
+    ``chunk_form`` forced to ``"gather"``, on every decode cadence;
+    ``Engine.paged_forms()`` names the form, and the decode step
+    records count the pages the kernel walked beside the pages the
+    gather form copies."""
+    from distributed_training_tpu.ops import paged_attention as pa
+
+    model, params = _wide_head_model()
+    over = {**_RAGGED_ENGINE, **_LAYOUT_CADENCES[cadence]}
+    decode = ("serving_resident_decode" if cadence == "resident8" else
+              "serving_spec_decode" if cadence == "spec4" else
+              "serving_decode")
+    prompts = _ragged_prompts()
+
+    def run():
+        eng = _engine(model, params, **over)
+        for i, p in enumerate(prompts):
+            eng.submit(Request(id=f"r{i}", prompt=p, max_new_tokens=12))
+        records = []
+        while not eng.idle:
+            records.append(eng.step())
+        return eng, records, {r["id"]: r["tokens"]
+                              for r in eng.completed}
+
+    eng, records, done = run()
+    # (A prompt chunk of 8 is few queries too: the rule gives it the
+    # same form. A real chunk's hundreds of rows are never offered it.)
+    assert eng.paged_forms()[decode] == "ragged"
+    steps = [r for r in records if r["op"] == "decode"]
+    # Every slot's whole table row a layer an iteration, against the
+    # pages that hold what a live slot's query sees: at most 32
+    # positions here, two pages of a row's four.
+    P, L, B = 64 // 16, model.cfg.n_layers, eng.cfg.max_batch
+    assert steps and all(
+        r["kv_pages_tabled"] == r["iters"] * B * L * P
+        and L * r["slot_iters"] <= r["kv_pages_walked"]
+        <= 2 * L * r["slot_iters"] for r in steps)
+    monkeypatch.setattr(pa, "chunk_form", lambda *a, **kw: "gather")
+    forced, forced_records, want = run()
+    assert forced.paged_forms()[decode] == "gather"
+    assert not any("kv_pages_walked" in r for r in forced_records)
+    assert done == want and all(len(t) == 12 for t in done.values())
+
+
+def test_a_lone_requests_walk_is_its_positions_pages():
+    """``kv_pages_walked`` of the one-token cadence, one request alone:
+    a decode launch at position ``p`` walks ``p // page_size + 1``
+    pages a layer, and tables every slot's whole row."""
+    model, params = _wide_head_model()
+    eng = _engine(model, params, **_RAGGED_ENGINE)
+    eng.submit(Request(id="r", prompt=np.arange(13, dtype=np.int32),
+                       max_new_tokens=8))
+    records = []
+    while not eng.idle:
+        records.append(eng.step())
+    steps = [r for r in records if r["op"] == "decode"]
+    # The first token comes with the prompt; seven launches at
+    # positions 13..19, the page boundary at 16.
+    assert [r["kv_pages_walked"] for r in steps] == [
+        2 * (p // 16 + 1) for p in range(13, 20)]
+    assert {r["kv_pages_tabled"] for r in steps} == {4 * 2 * 4}
 
 
 def test_serving_r04_ledger_committed_and_coherent():

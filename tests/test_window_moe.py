@@ -682,15 +682,30 @@ FLASH = {
 }
 
 
+@pytest.mark.parametrize("kernel", ["flash", "ragged"])
 @pytest.mark.parametrize("name", list(FLASH))
-def test_flash_kernel_agrees_with_one_pass(name):
+def test_flash_kernel_agrees_with_one_pass(name, kernel):
     """The flash form's kernel (interpreted here), float32 operands,
     against ``_tile_attention`` in one pass over the same gathered
     copy under ``_visible``'s mask: to float32 rounding, zeros where a
-    query is dead, never NaN."""
+    query is dead, never NaN. And the ragged form's kernel over the
+    same cases, chunks of 24 and 32 queries where the rule would never
+    offer it: the walk of a sequence's seen pages eight a step (tables
+    a third seen, rings past five turns, a sequence wholly dead),
+    which must give what the copy gives."""
     shape, window, ring, positions = FLASH[name]
     args = flash_case(**shape, positions=positions)
     want = pa._gather_attention(*args, window=window, ring=ring)
+    if kernel == "ragged":
+        got = pa._ragged_attention(*args, window=window, ring=ring,
+                                   pages=8)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        got, want = np.asarray(got), np.asarray(want)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+        dead = np.asarray(positions) < 0
+        assert not got[dead].any() and np.abs(got[~dead]).max() > 0.1
+        return
     got = pa._flash_attention(*args, window=window, ring=ring,
                               blocks=(16, 128))
     assert got.shape == want.shape and got.dtype == want.dtype
@@ -706,13 +721,14 @@ def test_flash_kernel_agrees_with_one_pass(name):
 
 
 @pytest.mark.parametrize("shape,P,N,window,form", [
-    # gpt2-xl's two programs and smallthinker-21b-ep4's resident decode
-    # stay what they were ...
+    # gpt2-xl's prefill program stays what it was, the two resident
+    # decode programs read their pages in place (PR 37) ...
     ((4, 128, 25, 64), 64, 385, None, "gather"),
-    ((16, 1, 25, 64), 64, 385, None, "pool"),
-    ((32, 1, 28, 128), 320, 10241, 4096, "gather.window"),
-    ((32, 1, 28, 128), 1024, 32769, None, "gather"),
-    # ... and its prompt chunk takes the kernel in both kinds of layer.
+    ((16, 1, 25, 64), 64, 385, None, "ragged"),
+    ((32, 1, 28, 128), 320, 10241, 4096, "ragged.window"),
+    ((32, 1, 28, 128), 1024, 32769, None, "ragged"),
+    # ... and smallthinker-21b-ep4's prompt chunk takes the flash kernel
+    # in both kinds of layer.
     ((1, 1024, 28, 128), 320, 10241, 4096, "flash.window"),
     ((1, 1024, 28, 128), 1024, 32769, None, "flash"),
 ])
@@ -753,6 +769,58 @@ def test_engine_prefill_through_the_kernel_matches_the_reference(
         == "flash+flash.window"
 
 
+@pytest.mark.parametrize("cadence", ["spec", "resident"])
+def test_engine_in_the_ragged_form_emits_what_the_gather_form_emits(
+        monkeypatch, cadence):
+    """The toy at heads of 128 and pages of 16 (a page of a layer 16 KB
+    in float32), where the rule gives the decode program the ragged
+    form in BOTH kinds of layer (the kernel ``dtt_paged_decode``,
+    interpreted here): prompts longer and shorter than the window,
+    decode past two turns of the ring of 3 pages, every streamed token
+    what the same engine streams with ``chunk_form`` forced to
+    ``"gather"``; the form is reported a program, and the decode step
+    records count the walk of both pools."""
+    model, params = build(ep_size=4, head_dim=128)
+    geometry = {**ENGINE, "page_size": 16, "num_pages": 64}
+    program = {"spec": "serving_spec_decode",
+               "resident": "serving_resident_decode"}[cadence]
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, 96, n).astype(np.int32)
+               for n in (70, 37, 5)]
+
+    def run():
+        eng = Engine(model, params, EngineConfig(**geometry,
+                                                 **CADENCES[cadence]))
+        for i, p in enumerate(prompts):
+            eng.submit(Request(id=f"r{i}", prompt=p, max_new_tokens=24))
+        records = []
+        for _ in range(1000):
+            if eng.idle:
+                break
+            records.append(eng.step())
+        assert eng.idle
+        return eng, records, {d["id"]: d["tokens"]
+                              for d in eng.completed}
+
+    eng, records, done = run()
+    assert eng.cache.cfg.ring_pages == 3      # (32 + 8) rows of 16
+    assert eng.paged_forms()[program] == "ragged+ragged.window"
+    steps = [r for r in records if r["op"] == "decode"]
+    # Two global layers' tables of 16 pages and six window layers'
+    # rings of 3, every slot; a live slot walks at most 6 pages of a
+    # table (94 positions) and all 3, or 4 by a run that starts inside
+    # a page, of a ring.
+    assert steps and all(
+        r["kv_pages_tabled"] == r["iters"] * 3 * (2 * 16 + 6 * 3)
+        and 8 * r["slot_iters"] <= r["kv_pages_walked"]
+        <= (2 * 6 + 6 * 4) * r["slot_iters"] for r in steps)
+    monkeypatch.setattr(pa, "chunk_form", lambda *a, **kw: "gather")
+    forced, forced_records, want = run()
+    assert forced.paged_forms()[program] == "gather+gather.window"
+    assert not any("kv_pages_walked" in r for r in forced_records)
+    assert done == want and all(len(t) == 24 for t in done.values())
+
+
 @pytest.mark.parametrize("ring", [False, True], ids=["table", "ring"])
 def test_the_form_tables_many_query_case_rehearses(ring):
     """``chip_smoke.paged_prefill_case`` (the rows
@@ -768,3 +836,40 @@ def test_the_form_tables_many_query_case_rehearses(ring):
         **(dict(P=12, window=32, ring=True) if ring else dict(P=32)))
     assert row["ok"] and row["max_abs_diff"] < 0.05
     assert row["rule"] == ("gather.window" if ring else "gather")
+
+
+@pytest.mark.parametrize("case", ["table", "ring", "forms"])
+def test_the_form_tables_few_query_cases_rehearse(case):
+    """``chip_smoke.paged_decode_case`` (the decode rows
+    ``benchmarks/paged_form_table.py`` times on the chip: the ragged
+    form's kernel against the gather and the pool form, tables part
+    live and a ring that has turned) and ``paged_forms_case``'s ragged
+    column, at a tiny size on the CPU, bfloat16 as there: the forms
+    agree within the band, the walked pages are the live ones, and the
+    fit of the rule's two constants reads rows like these."""
+    import sys
+    sys.path.insert(0, ROOT)
+    import chip_smoke
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    import paged_form_table
+
+    if case == "forms":
+        row = chip_smoke.paged_forms_case(4, 1, 4, 2, P=8, N=41, hd=64,
+                                          ps=4, reps=1)
+        assert {"gather_ms", "pool_ms", "ragged_ms"} <= set(row)
+    else:
+        row = chip_smoke.paged_decode_case(
+            3, 2, 4, 2, N=40, hd=64, ps=4, reps=1, pool=True,
+            **(dict(P=12, window=16, ring=True, context=67)
+               if case == "ring" else dict(P=13, context=30)))
+        # Two live sequences (the last is dead): positions 28 and 29
+        # see pages 0..7 of a table, 13..16 of the ring's turns.
+        assert row["pages"] == 2 * (5 if case == "ring" else 8)
+        assert row["bytes"] == row["pages"] * 4 * 2 * 2 * 64 * 2
+    assert row["ok"] and row["max_abs_diff"] < 0.05
+    fit = paged_form_table.fit_ragged(
+        [row, {**row, "ragged_ms": 2 * row["ragged_ms"],
+               "pages": 3 * row["pages"]},
+         {**row, "ragged_ms": 3 * row["ragged_ms"],
+          "bytes": 2 * row["bytes"]}, {"name": "a row without it"}])
+    assert fit["rows"] == 3 and np.isfinite(fit["_RAGGED_READ"])
