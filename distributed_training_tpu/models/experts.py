@@ -1,17 +1,26 @@
 """Routed experts held in part: the one expert feed-forward of the
 models that have one (``models/latent_moe.py``,
-``models/window_moe.py``), with what differs between them as
-parameters of the model's configuration ``c``:
+``models/window_moe.py``, ``models/parallel_moe.py``), with what
+differs between them as parameters of the model's configuration ``c``
+and as what the layer's parameters ``m`` hold:
 
 - ``c.router_score``: ``"sigmoid"`` — every expert scored by a sigmoid,
-  the ``moe_top_k`` largest of score + selection bias chosen, the gates
-  the chosen scores over their sum times ``c.routed_scaling_factor``
-  (DeepSeek-V3's ``noaux_tc``); ``"softmax"`` — the ``moe_top_k``
-  largest router logits chosen, the gates a softmax over the chosen
-  (SmallThinker's primary router; they sum to 1);
+  the ``moe_top_k`` largest chosen, the gates the chosen scores over
+  their sum. Where ``m`` holds a ``router_bias`` the choice is by score
+  + that selection bias (the gates by the scores alone), and where
+  ``c`` has a ``routed_scaling_factor`` the gates are times it
+  (DeepSeek-V3's ``noaux_tc``: ``latent_moe``); with neither, the
+  choice is by the scores and the gates sum to 1 (``parallel_moe``: no
+  zeros array and no factor of 1 stands in). ``"softmax"`` — the
+  ``moe_top_k`` largest router logits chosen, the gates a softmax over
+  the chosen (SmallThinker's primary router; they sum to 1);
 - ``c.expert_act``: the gate's activation, ``"silu"`` or ``"relu"``;
-- a shared expert that every token passes through, if the layer's
-  parameters hold one (``m["shared"]``).
+- shared experts that every token passes through, if the layer's
+  parameters hold any (``m["shared"]``): one gated product, added to
+  the routed sum. Several shared experts whose outputs are AVERAGED are
+  one such product too, the experts stacked on the hidden axis and the
+  result over their number: the model's ``shared=``
+  (``parallel_moe.shared_mean``).
 
 **Experts held.** The layer routes over all ``c.n_routed_experts`` and
 holds ``c.experts_held`` of them, the contiguous block that starts at
@@ -80,10 +89,12 @@ def route(h, m, c, logits=None):
         top, idx = jax.lax.top_k(logits, c.moe_top_k)
         return idx, jax.nn.softmax(top, axis=-1)
     s = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(s + m["router_bias"].astype(jnp.float32),
-                           c.moe_top_k)
+    chosen_by = (s + m["router_bias"].astype(jnp.float32)
+                 if "router_bias" in m else s)
+    _, idx = jax.lax.top_k(chosen_by, c.moe_top_k)
     g = jnp.take_along_axis(s, idx, axis=-1)
-    g = c.routed_scaling_factor * g / jnp.sum(g, -1, keepdims=True)
+    g = (getattr(c, "routed_scaling_factor", 1.0) * g
+         / jnp.sum(g, -1, keepdims=True))
     return idx, g
 
 

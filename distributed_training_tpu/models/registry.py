@@ -35,6 +35,11 @@ def build_model(name: str, loss: str = "auto", dtype: str = "float32",
             build_window_moe,
         )
         return build_window_moe(loss=loss, dtype=dtype, **kwargs)
+    if name == "parallel_moe":
+        from distributed_training_tpu.models.parallel_moe import (
+            build_parallel_moe,
+        )
+        return build_parallel_moe(loss=loss, dtype=dtype, **kwargs)
     if name == "sparse_latent_moe":
         from distributed_training_tpu.models.sparse_latent_moe import (
             build_sparse_latent_moe,

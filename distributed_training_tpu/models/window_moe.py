@@ -72,9 +72,11 @@ class WindowMoEConfig:
     dtype: str = "bfloat16"       # compute dtype
     param_dtype: str = "float32"
 
-    # How ``models/experts.py::expert_layer`` scores and activates.
+    # How ``models/experts.py::expert_layer`` scores and activates,
+    # and which elements of a head RoPE pairs (``project``).
     router_score = "softmax"
     expert_act = "relu"
+    rope_pairs = "halves"
 
     def __post_init__(self):
         self.window_layout = tuple(int(f) for f in self.window_layout)
@@ -125,8 +127,11 @@ class WindowMoEConfig:
 def project(h, a, positions, rope: bool, c: WindowMoEConfig, w=_cast):
     """The normed input ``h (..., D)`` at ``positions (...)`` -> ``q
     (..., H, hd)``, ``k`` and ``v (..., Hkv, hd)``, rotated where the
-    layer has positions (RoPE with the halves paired, ``x[i]`` with
-    ``x[i + hd/2]``, base ``rope_theta``)."""
+    layer has positions: RoPE at base ``rope_theta`` with
+    ``c.rope_pairs`` ``"halves"`` (``x[i]`` with ``x[i + hd/2]``) or
+    ``"adjacent"`` (``x[2i]`` with ``x[2i + 1]``)."""
+    from distributed_training_tpu.models.latent_moe import (
+        rope_interleaved)
     from distributed_training_tpu.serving.blocks import rope_bhd
 
     dt = h.dtype
@@ -134,8 +139,10 @@ def project(h, a, positions, rope: bool, c: WindowMoEConfig, w=_cast):
     k = jnp.einsum("...d,dhk->...hk", h, w(a["wk"], dt))
     v = jnp.einsum("...d,dhk->...hk", h, w(a["wv"], dt))
     if rope:
-        q = rope_bhd(q, positions, c.rope_theta)
-        k = rope_bhd(k, positions, c.rope_theta)
+        turn = {"halves": rope_bhd,
+                "adjacent": rope_interleaved}[c.rope_pairs]
+        q = turn(q, positions, c.rope_theta)
+        k = turn(k, positions, c.rope_theta)
     return q, k, v
 
 
@@ -208,23 +215,35 @@ class WindowMoE(ApplyLM):
         for layers, (_lo, _n, window, rope) in zip(params["runs"],
                                                    c.runs):
             def body(x, layer, window=window, rope=rope):
-                h = rms_norm(x, layer["ln1"], c.rms_norm_eps)
-                r = router_logits(h, layer["mlp"]["router"])
-                q, k, v = project(h, layer["attn"], positions, rope, c)
-                # Naive: the flash kernels want tile-friendly shapes,
-                # and this is the plain path.
-                attn = dot_product_attention(
-                    q, k, v, causal=True, impl="naive",
-                    window=c.window if window else 0)
-                x = x + jnp.einsum("...hk,hkd->...d", attn,
-                                   layer["attn"]["wo"].astype(dt))
-                h = rms_norm(x, layer["ln2"], c.rms_norm_eps)
-                return x + expert_layer(h, layer["mlp"], c,
-                                        logits=r)[0], None
+                def attend(q, k, v):
+                    # Naive: the flash kernels want tile-friendly
+                    # shapes, and this is the plain path.
+                    return dot_product_attention(
+                        q, k, v, causal=True, impl="naive",
+                        window=c.window if window else 0)
+                return self.layer(layer, x, positions, rope,
+                                  attend), None
             x, _ = jax.lax.scan(body, x, layers)
-        x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        return self.head(params, x)
+
+    def layer(self, layer, x, positions, rope: bool, attend):
+        """One layer of the full forward on ``x (B, S, D)``;
+        ``attend(q, k, v)`` is the layer's attention over the
+        sequence itself."""
+        c = self.cfg
+        h = rms_norm(x, layer["ln1"], c.rms_norm_eps)
+        r = router_logits(h, layer["mlp"]["router"])
+        attn = attend(*project(h, layer["attn"], positions, rope, c))
+        x = x + jnp.einsum("...hk,hkd->...d", attn,
+                           layer["attn"]["wo"].astype(x.dtype))
+        h = rms_norm(x, layer["ln2"], c.rms_norm_eps)
+        return x + expert_layer(h, layer["mlp"], c, logits=r)[0]
+
+    def head(self, params, x, w=_cast):
+        """Final hidden states -> float32 logits over the rows held."""
+        x = rms_norm(x, params["final_norm"], self.cfg.rms_norm_eps)
         return jnp.einsum("...d,dv->...v", x,
-                          params["lm_head"].astype(dt)
+                          w(params["lm_head"], x.dtype)
                           ).astype(jnp.float32)
 
     def serving_block(self):
@@ -260,8 +279,11 @@ class WindowBlock:
             window_layers=tuple(n for n, f in enumerate(c.window_layout)
                                 if f),
             block=type(self).__name__)
-        self._views = {lo: _Run(self, bool(window), bool(rope))
+        self._views = {lo: self.run_view(bool(window), bool(rope))
                        for lo, _n, window, rope in c.runs}
+
+    def run_view(self, window: bool, rope: bool):
+        return _Run(self, window, rope)
 
     def embed(self, params, tokens, positions):
         del positions
@@ -275,10 +297,7 @@ class WindowBlock:
         return self._views[layer]
 
     def logits(self, params, x):
-        x = rms_norm(x, params["final_norm"], self.cfg.rms_norm_eps)
-        return jnp.einsum("...d,dv->...v", x,
-                          self._w(params["lm_head"], x.dtype)
-                          ).astype(jnp.float32)
+        return self.model.head(params, x, self._w)
 
 
 class _Run:

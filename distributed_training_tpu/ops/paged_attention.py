@@ -240,6 +240,13 @@ _RAGGED_PAGE = 11e3
 # Query rows one ragged call may spread over a stored row's lanes (tiles
 # x queries x rows a tile): decode and speculative verify of every
 # engine here; a prompt chunk's thousands keep the gather or flash form.
+# Which shapes sit where: gpt2-xl's decode is 13 tiles x 1 query x 2
+# heads a tile = 26 rows (104 at four queries a slot),
+# smallthinker-21b-ep4's 4 tiles x 7 query heads a kv head = 28, and
+# command-a-plus-ep16's 8 tiles x 16 = 128, AT the limit: one query a
+# slot takes the kernel (contracting 128 rows over all 1,024 lanes of a
+# stored row, zeros on seven tiles of eight), two a slot (speculative
+# verify at these head counts) would not (ROADMAP S1).
 _RAGGED_ROWS = 128
 
 

@@ -95,7 +95,9 @@ def rope_bhd(x, positions, theta: float = 10000.0):
                            axis=-1).astype(x.dtype)
 
 
-def layer_norm(x, scale, bias):
+def layer_norm(x, scale, bias=None, eps: float = 1e-5):
+    """LayerNorm over the last axis, float32 inside; ``bias`` None: a
+    norm with a scale only (``models/parallel_moe.py``)."""
     import jax
     import jax.numpy as jnp
 
@@ -103,8 +105,8 @@ def layer_norm(x, scale, bias):
     x = x.astype(jnp.float32)
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
-    y = (x - mu) * jax.lax.rsqrt(var + 1e-5)
-    return (y * scale + bias).astype(dtype)
+    y = (x - mu) * jax.lax.rsqrt(var + eps) * scale
+    return (y if bias is None else y + bias).astype(dtype)
 
 
 def weight(leaf, dt):

@@ -42,6 +42,8 @@ SCOPES = (
     "dtt.mlp",           # a dense feed-forward with its norm
     "dtt.moe.route",     # router product, top-k, gates
     "dtt.moe.experts",   # held experts' products, shared expert, combine
+    "dtt.moe.shared",    # shared experts where a model names them apart
+    #                      (inside ``dtt.moe.experts``: the innermost wins)
     "dtt.head",          # final norm, logits, sampling, the verify chain
     "dtt.engine",        # the programs' own bookkeeping: positions,
     #                      history rows, lengths, stop conditions,

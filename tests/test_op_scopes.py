@@ -118,7 +118,7 @@ def test_every_scope_in_the_program_is_in_the_one_vocabulary():
     assert len(set(SCOPES)) == len(SCOPES) and UNSCOPED not in SCOPES
 
 
-# -- tiny engines of the four blocks --------------------------------------
+# -- tiny engines of the five blocks --------------------------------------
 
 COMMON = {"dtt.embed", "dtt.attn.project", "dtt.kv.write", "dtt.kv.read",
           "dtt.attn.core", "dtt.attn.out", "dtt.head", "dtt.engine"}
@@ -171,8 +171,19 @@ def sparse():
         COMMON | MOE | {"dtt.mlp", "dtt.attn.select"}
 
 
+def parallel():
+    # The shared experts under a scope of their own, inside the experts'.
+    return build_model(
+        "parallel_moe", dtype="float32", vocab_size=96, d_model=32,
+        n_layers=4, n_heads=4, n_kv_heads=2, head_dim=8, moe_d_ff=12,
+        n_routed_experts=16, moe_top_k=3, n_shared_experts=4, window=16,
+        window_layout=(1, 1, 1, 0), rope_layout=(1, 1, 1, 0),
+        rope_theta=500.0, qk_std=0.2, max_seq_len=64), \
+        COMMON | MOE | {"dtt.moe.shared"}
+
+
 BLOCKS = {"gpt2": gpt2, "latent": latent, "window": window,
-          "sparse_latent": sparse}
+          "sparse_latent": sparse, "parallel": parallel}
 
 
 def engine_of(name):
