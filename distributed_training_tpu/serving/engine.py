@@ -3138,20 +3138,15 @@ def _resident_program(params, k_pages, v_pages, history, kv_len, left,
             any_eos = jnp.zeros((B,), jnp.bool_)
         # scatter this iteration's accepted tokens into the output
         # block at each slot's emission cursor, and append them to
-        # the history row right after its current last token.
-        rel = (jnp.arange(T, dtype=jnp.int32)[None, :]
-               - n_em[:, None])
-        sel = (rel >= 0) & (rel < e[:, None])
-        vals = jnp.take_along_axis(nxt, jnp.clip(rel, 0, C - 1),
-                                   axis=1)
-        out = jnp.where(sel, vals, out)
-        hrel = pos[None, :] - (kvl + 1)[:, None]
-        hsel = (hrel >= 0) & (hrel < e[:, None])
-        hist = jnp.where(
-            hsel,
-            jnp.take_along_axis(nxt, jnp.clip(hrel, 0, C - 1),
-                                axis=1),
-            hist)
+        # the history row right after its current last token: B x C
+        # values, the unaccepted columns (and any position past the
+        # row) sent out of range and dropped.
+        acc = cl[None, :] < e[:, None]
+        rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+        out = out.at[rows, jnp.where(acc, n_em[:, None] + cl, T)].set(
+            nxt, mode="drop")
+        hist = hist.at[rows, jnp.where(acc, (kvl + 1)[:, None] + cl,
+                                       Lmax)].set(nxt, mode="drop")
         n_em = n_em + e
         kvl = kvl + e
         bud = bud - e

@@ -647,6 +647,68 @@ def test_resident_decode_holds_no_copy_of_the_pool():
     assert temp < pool_bytes / 2, (temp, pool_bytes)
 
 
+def test_resident_decode_appends_without_a_whole_row_pass():
+    """``jit_serving_resident_decode`` at the serving cells' slot table
+    (32 slots of 16,384 positions, ``smallthinker_ep4.serve_long`` and
+    ``dots3_ep8.serve_sparse``) on a tiny block compiles for a v5e with
+    no ``gather`` and no ``select`` whose result is the whole history,
+    and no copy of it. The loop appended an iteration's tokens by
+    gathering from them at every position of every row and selecting
+    the result into the row: 7 ns an element on the chip, 3.7 ms of a
+    14.5 ms iteration (ledger, PR 38). It now scatters B x C values;
+    a change that brings the whole-row pass back fails here."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_training_tpu.models import build_model
+    from distributed_training_tpu.serving import engine as E
+    from distributed_training_tpu.serving.kv_cache import (
+        PagedCacheConfig, PagedKVCache)
+
+    try:
+        from distributed_training_tpu.runtime import topology_runtime
+        chip = SingleDeviceSharding(
+            topology_runtime(1, "v5e:2x2").mesh.devices.flat[0])
+    except Exception as e:  # pragma: no cover - no libtpu
+        pytest.skip(f"device-less TPU topology unavailable: {e}")
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=Format(
+            Layout(major_to_minor=tuple(range(len(dims)))), chip))
+
+    B, L, ps, N = 32, 16384, 16, 64
+    model = build_model(
+        "gpt2", dtype="bfloat16", vocab_size=512, d_model=128,
+        n_layers=1, n_heads=2, max_seq_len=L, pos_encoding="learned",
+        tie_embeddings=True)
+    ecfg = E.EngineConfig(
+        max_batch=B, num_pages=N, page_size=ps, max_seq_len=L,
+        prefill_chunk=128, resident_k=8, prefill_slots=4)
+    block = model.serving_block()
+    pools = [shape(dims, jnp.bfloat16)
+             for dims in PagedKVCache.pool_shapes(PagedCacheConfig(
+                 **block.cache, page_size=ps, num_pages=N,
+                 max_seq_len=L))]
+    params = jax.tree.map(
+        lambda a: shape(a.shape, jnp.bfloat16),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    text = E.build_resident_decode_fn(block, ecfg).lower(
+        params, *pools, shape((1, B, L), jnp.int32),
+        shape((1, B), jnp.int32), shape((1, B), jnp.int32),
+        shape((1, B, L // ps), jnp.int32), shape((1, B), jnp.int32),
+        shape((1, B), jnp.bool_)).compile().as_text()
+    whole = [f"{op} {name}" for name, dims, op in re.findall(
+        r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([0-9,]+)\]\S* "
+        r"(gather|select|copy)\(", text, re.M)
+        if math.prod(map(int, dims.split(","))) == B * L]
+    assert not whole, whole
+    assert re.search(r"= s32\[32,16384\]\S* scatter\(", text)
+
+
 def test_collectives_report_counts_pallas_calls():
     """The `collectives` event says which kernels the compiled step
     runs: ``pallas_calls`` counts Mosaic custom calls in the HLO text

@@ -1777,6 +1777,115 @@ def test_resident_decode_token_identity(tiny_model):
         assert syncs < base_syncs
 
 
+def _greedy_streams(model, params, first, n, width):
+    """Each row's greedy continuation of its one token ``first[b]``,
+    ``n`` tokens, the whole context re-run every token at one padded
+    ``width`` (causal: a position's logits see nothing after it)."""
+    ids = np.zeros((len(first), width), np.int32)
+    ids[:, 0] = first
+    apply = jax.jit(model.apply)
+    for t in range(n):
+        logits, _aux = apply(params, jnp.asarray(ids))
+        ids[:, t + 1] = np.asarray(jnp.argmax(logits[:, t], axis=-1))
+    return ids
+
+
+def _appended(hist, kv, left, budget, active, streams, eos):
+    """The resident loop's append as NumPy: each packed slot emits its
+    stream's next ``min(budget, left)`` tokens, cut after the first
+    ``eos``, written at ``kv + 1`` on; nothing else of the row moves.
+    Returns ``(emitted per slot, history, kv_len, left)``."""
+    hist, kv, left = hist.copy(), kv.copy(), left.copy()
+    emitted = []
+    for b in range(len(kv)):
+        m = min(budget[b], left[b]) if active[b] else 0
+        toks = list(streams[b, kv[b] + 1:kv[b] + 1 + m])
+        stop = eos >= 0 and eos in toks
+        if stop:
+            toks = toks[:toks.index(eos) + 1]
+        hist[b, kv[b] + 1:kv[b] + 1 + len(toks)] = toks
+        left[b] = 0 if stop else left[b] - len(toks)
+        kv[b] += len(toks)
+        emitted.append(toks)
+    return emitted, hist, kv, left
+
+
+_APPEND_CASES = ("eos_mid_burst", "budget_ends_burst", "not_packed",
+                 "reaches_max_seq_len")
+
+
+@pytest.mark.parametrize("C", (1, 4))
+@pytest.mark.parametrize("case", _APPEND_CASES)
+def test_resident_append_writes_only_accepted_tokens(tiny_model, case,
+                                                     C):
+    """``_resident_program``'s history append, driven directly: two
+    bursts on one carried slot table (the first writes each slot's
+    prompt and its KV, the second is the case) leave every row as a
+    NumPy model of the append says: its tokens up to ``kv_len``, then
+    the tokens emitted, each the full-context greedy one, and every
+    position past the new ``kv_len`` as it came in. Slot 0 is the
+    case: the stop token three tokens into a burst, a budget that
+    ends its burst before the loop's ``K`` does, a slot not packed,
+    and a row written to ``max_seq_len - 1`` (the scatter's columns
+    past the accepted ones then all fall out of range and are
+    dropped). Slot 1's budget is cut by what its request has left."""
+    model, params = tiny_model
+    B, Lmax, ps = 4, 64, 8
+    streams = _greedy_streams(model, params, [3, 50, 100, 200],
+                              Lmax - 1, Lmax)
+    prompt = np.asarray([6, 9, 4, 10])
+    eos = -1
+    if case == "eos_mid_burst":
+        # a token of slot 0's stream that it has not emitted before
+        q = max(i for i in range(3, 24)
+                if streams[0, i] not in streams[0, :i])
+        eos, prompt[0] = int(streams[0, q]), q - 3
+    eng = _engine(model, params, max_batch=B, page_size=ps,
+                  max_seq_len=Lmax, resident_k=Lmax, spec_k=C,
+                  eos_id=eos)
+    rng = np.random.default_rng(0)
+    hist = rng.integers(0, 256, (B, Lmax)).astype(np.int32)
+    hist[:, 0] = streams[:, 0]
+    kv = np.zeros(B, np.int32)
+    left = np.full(B, 100, np.int32)
+    P = Lmax // ps
+    rows = (1 + np.arange(B * P, dtype=np.int32)).reshape(1, B, P)
+    pools = (eng.cache.k_pages, eng.cache.v_pages)
+    budget2 = np.asarray([8, 8, 5, 8])
+    active2 = np.ones(B, bool)
+    if case == "budget_ends_burst":
+        budget2[0] = 5
+    elif case == "not_packed":
+        active2[0] = False
+    elif case == "reaches_max_seq_len":
+        budget2[0] = Lmax - 1 - prompt[0]
+    for budget, active in ((prompt, np.ones(B, bool)),
+                           (budget2, active2)):
+        out, n_em, _steps, _counts, *table, k, v = eng._decode_fn(
+            eng.params, *pools, hist[None], kv[None], left[None],
+            rows, budget[None].astype(np.int32), active[None])
+        pools = (k, v)
+        emitted, want, want_kv, want_left = _appended(
+            hist, kv, left, budget, active, streams, eos)
+        hist, kv, left = (np.array(a)[0] for a in table)
+        np.testing.assert_array_equal(np.asarray(n_em)[0],
+                                      [len(t) for t in emitted])
+        for b, toks in enumerate(emitted):
+            assert list(np.asarray(out)[0, b, :len(toks)]) == toks, b
+        np.testing.assert_array_equal(hist, want)
+        np.testing.assert_array_equal(kv, want_kv)
+        np.testing.assert_array_equal(left, want_left)
+        left[1] = 3
+    if case == "eos_mid_burst":
+        assert emitted[0][-1] == eos and len(emitted[0]) == 3
+    elif case == "budget_ends_burst":
+        assert len(emitted[0]) == 5
+    elif case == "not_packed":
+        assert emitted[0] == []
+    else:
+        assert kv[0] == Lmax - 1
+
+
 def test_resident_decode_eos_stops_mid_burst(tiny_model):
     """Per-slot stop detection INSIDE the loop: when the stop token
     lands at step j < K the slot's burst ends there — the emitted
