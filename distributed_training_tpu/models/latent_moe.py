@@ -38,12 +38,10 @@ rank ``ep_rank``: it computes its own experts' part of ``y`` (and the
 shared expert, which every rank computes alike) and nothing for the
 experts it lacks. ``ep_size=1`` is the whole layer. The gate weights
 are normalised over all chosen experts, held or not, so the parts of
-all ranks add up to the uncut layer. No token is dropped: every held
-expert sees every token and its output is weighted by the token's gate
-for it, which is 0 where the token did not choose it. That costs
-``held x tokens`` expert products instead of ``top_k x tokens / ep_size``
-but reads each held expert's weights once, which is what a decode
-iteration is bound by (32 tokens meet 64 experts 64 times).
+all ranks add up to the uncut layer. No token is dropped: each held
+expert runs over the tokens that picked it, one grouped product for all
+of them (``models/experts.py``), and its output is weighted by the
+token's gate for it.
 
 The multi-token-prediction module of the published family is not here.
 """

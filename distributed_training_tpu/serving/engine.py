@@ -2710,7 +2710,8 @@ def _scan_layers(block, plan, params, x, k_pages_g, v_pages_g,
     marks real tokens for the block's counters. One ``lax.scan`` a run
     of like layers (``block.segments``; ``run = block.at(first
     layer)`` is the block as that run sees it) over the layers'
-    parameters and their numbers in their kind's pool (``plan.run``:
+    parameters (the body takes its layer by ``models/experts.py::
+    layer_of``) and their numbers in their kind's pool (``plan.run``:
     global layers in the one pool, window layers in the other, where
     the model has both); the pools (k_pages_g/v_pages_g, one group's)
     are carried whole through all of them, so runs of unlike layers
@@ -2721,13 +2722,16 @@ def _scan_layers(block, plan, params, x, k_pages_g, v_pages_g,
     import jax
     import jax.numpy as jnp
 
-    def layer_body(kind, run):
+    from distributed_training_tpu.models import experts
+
+    def layer_body(kind, run, layers):
         page_ids, offsets = coords[kind]
         k_layout, v_layout = plan.of(kind)
 
         def body(carry, inp):
             x, kg, vg = carry
-            layer, number = inp
+            i, number = inp
+            layer = experts.layer_of(layers, i)
             with jax.named_scope("dtt.attn.project"):
                 q, k, v, *key = run.project(layer, x, positions)
             with jax.named_scope("dtt.kv.write"):
@@ -2766,9 +2770,9 @@ def _scan_layers(block, plan, params, x, k_pages_g, v_pages_g,
         # its parts (``telemetry/op_scopes.py::SCOPES``).
         with jax.named_scope("dtt.engine"):
             carry, c = jax.lax.scan(
-                layer_body(kind, block.at(lo)), carry,
-                (layers, jnp.arange(first, first + hi - lo,
-                                    dtype=jnp.int32)))
+                layer_body(kind, block.at(lo), layers), carry,
+                (jnp.arange(hi - lo, dtype=jnp.int32),
+                 jnp.arange(first, first + hi - lo, dtype=jnp.int32)))
             counts = counts + c.sum(axis=0)
         lo = hi
     x, k_pages_g, v_pages_g = carry

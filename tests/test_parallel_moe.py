@@ -306,6 +306,32 @@ def test_engine_matches_the_reference(ref, cadence):
                for f in forms.values() if f), forms
 
 
+@pytest.mark.parametrize("cadence", ["batched", "resident"])
+def test_grouped_prefill_streams_the_dense_forms_tokens(ref, monkeypatch,
+                                                        cadence):
+    """Every streamed token of the engine, whose prompt chunks and
+    decode iterations run the held experts as one grouped product, is
+    the token of the same engine with the dense form (every held expert
+    over every row) in its place, and the reference's to float32
+    rounding; ``moe_rows_computed`` of every record that carries counts
+    is the rows of the tiles the kernel visited."""
+    model, params = build(ep_size=4)
+    prompts = prompts_of()
+    _, records, done = serve(model, params, cadence, prompts)
+    with monkeypatch.context() as m:
+        m.setattr(experts, "_routed", lambda act, x, g, local, mine,
+                  load, *held: experts._dense(act, x, g, local, mine,
+                                              *held))
+        _, _, dense = serve(model, params, cadence, prompts)
+    assert done == dense
+    assert worst_gap(ref, params, prompts, done) < 1e-4
+    counted = [r for r in records if "moe_picks_held" in r]
+    assert {r["op"] for r in counted} >= {"prefill", "decode"}
+    assert all(r["moe_rows_computed"] % experts._TILE_ROWS == 0
+               and (r["moe_rows_computed"] > 0) == (r["moe_picks_held"] > 0)
+               for r in counted)
+
+
 def test_bfloat16_fails_the_float32_tolerance(ref):
     """The tolerance above is tight enough to see a lower precision:
     the same engine in bfloat16 misses it tenfold."""
