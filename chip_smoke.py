@@ -571,7 +571,8 @@ def sparse_latent_case(kind: str, B: int, S: int, context: int = 8192,
             band = _close("sparse_latent", out["all_kept"], out["dense"])
             extra["dense_ms"] = ms["dense"]
         say(f"  sparse latent [{label}]: selection of {topk} "
-            f"{ms['sparse']:.3f} ms a call (smoke wall; {seen[0]})"
+            f"{ms['sparse']:.3f} ms a call (smoke wall; "
+            f"{'+'.join(seen)})"
             + (f", dense {ms['dense']:.3f} ms, all kept against dense "
                f"{band:.3f} of the bf16 band" if dense else ""))
         if S * topk > Sk:
@@ -622,6 +623,7 @@ def sparse_latent_case(kind: str, B: int, S: int, context: int = 8192,
     if band > 1.0:
         raise AssertionError(f"{label}: beyond the bf16 band ({band})")
     return {"ok": True, "shape": label, "form": seen[0],
+            "select": [f for f in seen if f.startswith("select.")],
             "err_over_bf16_band": band, **extra}
 
 

@@ -25,7 +25,8 @@ cache (one shared row a token instead of keys and values a head), in an
 absorbed or an expanded form that ``latent_form`` takes from the shapes;
 over a window layer's ring (``window=``, ``ring=``) and under a learned
 selection of positions (``select=``: the indexer's scores over the index
-pool's layer, ``index_scores``, and an exact top-k, ``select_topk``) as
+pool's layer, ``index_scores``, and an exact top-k, ``select_topk``,
+found by counting in one Pallas kernel, ``dtt_select_topk``) as
 well. Under a selection a query reads its chosen rows alone (the gather
 form, decode) or, where a sequence's queries choose more rows than its
 table has (a prompt chunk), the table is read once and one Pallas
@@ -104,7 +105,8 @@ There is no switch between the forms: static shapes decide, so a
 compiled program takes a form always or never. The form each took
 (``"pool"``, ``"gather"``, ``"ragged"``, ``"flash"``, of latent attention
 ``"absorbed"``, ``"expanded"``; under a selection ``"absorbed.sparse"``
-or ``"flash.sparse"``; ``".window"`` behind it over a ring) is seen at
+or ``"flash.sparse"``, and ``"select.threshold"`` where its top-k was
+found by counting; ``".window"`` behind it over a ring) is seen at
 trace time by
 ``observe_forms`` and reported per
 program by ``Engine.paged_forms()`` and the ``serving_warmup`` telemetry
@@ -135,8 +137,8 @@ def observe_forms():
     (``"pool"``, ``"gather"``, ``"ragged"``, ``"flash"``) and
     ``latent_attention_chunk``
     (``"absorbed"``, ``"expanded"``, ``".window"`` behind either;
-    ``"absorbed.sparse"``, ``"flash.sparse"``) call takes. The form
-    follows from
+    ``"absorbed.sparse"``, ``"flash.sparse"``) call takes, and every
+    ``select_topk`` call (``"select.threshold"``). The form follows from
     static shapes, so a call is seen when the program around it is
     TRACED: the engine opens this around each program's body
     (``serving/engine.py::_named``)."""
@@ -948,23 +950,178 @@ def index_scores(sel: Selection, keys: jax.Array) -> jax.Array:
                       sel.w.astype(jnp.float32))
 
 
+_UNSEEN = -(1 << 31)
+
+# A block of query rows of ``dtt_select_topk`` (an 8-bit mask tile's 32
+# sublanes: 2 MiB of keys at 16,384 positions) and the lanes one step
+# of its counts reads.
+_SELECT_ROWS = 32
+_SELECT_LANES = 512
+
+
+def _order_keys(scores: jax.Array, seen: jax.Array) -> jax.Array:
+    """int32 keys in the order of float32 ``scores``: ``-0.0`` made
+    ``+0.0`` (equal under ``==``, so one key), then the low 31 bits of
+    a negative flipped. Unseen positions take ``_UNSEEN``, below every
+    score's key, ``-inf``'s included."""
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores).astype(jnp.float32),
+        jnp.int32)
+    return jnp.where(seen, bits ^ ((bits >> 31) & 0x7FFFFFFF), _UNSEEN)
+
+
+def _select_kernel(live_ref, key_ref, out_ref, *, k, lanes, pos_bits):
+    """One block of rows of ``_threshold_mask``: each row's ``k``-th
+    largest key ``t`` by bisection on its 32 bits (the largest ``t``
+    with ``count(key >= t) >= k``), then, where keys equal to ``t`` are
+    more than the room left above it, the cutoff ``p`` by bisection on
+    the position (the largest ``p`` with ``count(key == t, pos < p) <=
+    room``), and the mask ``key > t | key == t & pos < p`` of the seen.
+    A count reads ``lanes`` keys a step and only the steps up to the
+    block's highest seen position (``live_ref``); the keys past it are
+    unseen."""
+    rows = key_ref.shape[0]
+    steps = live_ref[pl.program_id(0)]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
+
+    def count(pred):
+        def step(j, acc):
+            at = pl.multiple_of(j * lanes, lanes)
+            return acc + jnp.where(pred(key_ref[:, pl.ds(at, lanes)], at),
+                                   1, 0)
+        return jnp.sum(jax.lax.fori_loop(
+            0, steps, step, jnp.zeros((rows, lanes), jnp.int32)),
+            axis=1, keepdims=True)
+
+    # ``t`` is held with its sign bit flipped, so that bisection from
+    # the top bit down runs over the keys in order.
+    def key_bit(b, carry):
+        t, at_t = carry
+        bit = t | jnp.left_shift(jnp.int32(1), 31 - b)
+        n = count(lambda key, _: key >= bit ^ _UNSEEN)
+        return jnp.where(n >= k, bit, t), jnp.where(n >= k, n, at_t)
+
+    zero = jnp.zeros((rows, 1), jnp.int32)
+    t, at_t = jax.lax.fori_loop(0, 32, key_bit, (zero, zero + steps * lanes))
+    t = t ^ _UNSEEN
+    room = k - count(lambda key, _: key > t)
+
+    def pos_bit(b, p):
+        bit = p | jnp.left_shift(jnp.int32(1), pos_bits - 1 - b)
+        n = count(lambda key, at: (key == t) & (lane < bit - at))
+        return jnp.where(n <= room, bit, p)
+
+    # Rows that see fewer than k have ``t`` unseen and take every seen
+    # position: no cut.
+    split = (at_t - (k - room) > room) & (t != _UNSEEN)
+    everyone = zero + ((1 << pos_bits) - 1)
+    p = jax.lax.cond(
+        jnp.max(jnp.where(split, 1, 0)) > 0,
+        lambda: jax.lax.fori_loop(0, pos_bits, pos_bit, zero),
+        lambda: everyone)
+
+    def emit(j, carry):
+        at = pl.multiple_of(j * lanes, lanes)
+        key = key_ref[:, pl.ds(at, lanes)]
+        chosen = (key > t) | ((key == t) & (lane < p - at))
+        out_ref[:, pl.ds(at, lanes)] = jnp.where(
+            chosen & (key != _UNSEEN), 1, 0).astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, key_ref.shape[1] // lanes, emit, 0)
+
+
+def _threshold_mask(keys: jax.Array, extent: jax.Array, k: int
+                    ) -> jax.Array:
+    """The top-``k`` mask (8-bit) of ``keys (R, Sk)`` (``_order_keys``)
+    by ONE Pallas kernel, ``dtt_select_topk``, over blocks of
+    ``_SELECT_ROWS`` rows held in VMEM: the keys read once, counted
+    there (``_select_kernel``), and the mask written. ``extent (R,)``:
+    past it a row sees nothing, so a block counts up to its rows'
+    largest."""
+    R, Sk = keys.shape
+    lanes = min(_SELECT_LANES, -(-Sk // 128) * 128)
+    keys = jnp.pad(keys, ((0, -R % _SELECT_ROWS), (0, -Sk % lanes)),
+                   constant_values=_UNSEEN)
+    width = keys.shape[1]
+    live = -(-jnp.max(jnp.pad(extent, (0, -R % _SELECT_ROWS)).reshape(
+        -1, _SELECT_ROWS), axis=1) // lanes)
+    rows = pl.BlockSpec((_SELECT_ROWS, width), lambda i, live: (i, 0))
+    mask = pl.pallas_call(
+        functools.partial(_select_kernel, k=k, lanes=lanes,
+                          pos_bits=width.bit_length()),
+        name="dtt_select_topk",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(keys.shape[0] // _SELECT_ROWS,),
+            in_specs=[rows], out_specs=rows),
+        out_shape=jax.ShapeDtypeStruct(keys.shape, jnp.int8),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=not _platform_is_tpu(),
+    )(live.astype(jnp.int32), keys)
+    return mask[:R, :Sk]
+
+
 def select_topk(scores: jax.Array, seen: jax.Array, k: int) -> tuple:
-    """The EXACT ``k`` largest of ``scores (..., Sk)`` among the
-    positions ``seen`` marks, all of them where they are no more than
-    ``k``; equal scores go to the lower position first
+    """The EXACT ``k`` largest of ``scores (..., Sk)`` (float32, not
+    NaN) among the positions ``seen`` marks, all of them where they are
+    no more than ``k``; equal scores go to the lower position first
     (``jax.lax.top_k``'s order). Returns ``(positions (..., k), kept
-    (..., k) bool, chosen (..., Sk) bool)``: the same set as indices
-    (``kept`` false where fewer than ``k`` were seen) and as a mask."""
-    scores = jnp.where(seen, scores, -jnp.inf)
-    top, positions = jax.lax.top_k(scores, k)
-    kept = top > -jnp.inf
-    # As a mask: above the k-th score, and of those equal to it the
-    # first few, as many as top_k took.
-    last = top[..., -1:]
-    above, equal = scores > last, scores == last
-    room = k - jnp.sum(above, axis=-1, keepdims=True)
-    chosen = above | (equal & (jnp.cumsum(equal, axis=-1) <= room))
-    return positions, kept, chosen & seen
+    (..., k) bool, chosen (..., Sk) bool)``: the same set as indices, in
+    position order (``kept`` false where fewer than ``k`` were seen),
+    and as a mask.
+
+    Found by counting (``_threshold_mask``, reported
+    ``"select.threshold"``), not by a sort, which on a TPU sorts every
+    row whole at ``k`` in the thousands; the positions are compacted out
+    of the mask (``_compact``)."""
+    _took("select.threshold")
+    Sk = scores.shape[-1]
+    seen = jnp.broadcast_to(seen, scores.shape)
+    extent = jnp.max(jnp.where(seen, jnp.arange(1, Sk + 1), 0), axis=-1)
+    chosen = _threshold_mask(_order_keys(scores, seen).reshape(-1, Sk),
+                             extent.reshape(-1), k
+                             ).reshape(scores.shape) != 0
+    positions, kept = _compact(chosen, k)
+    return positions, kept, chosen
+
+
+def _compact(chosen: jax.Array, k: int) -> tuple:
+    """``(positions (..., k), kept (..., k))``: the positions ``chosen
+    (..., Sk)`` marks, in position order, at most ``k`` of them (0 and
+    not kept past the last). No scatter and no sort, whose cost goes
+    with the ``Sk`` positions one at a time: the positions in lanes of
+    128, each lane's rank among the chosen of its group of 128 a product
+    with a triangle of ones, and the ``j``-th chosen found by counting,
+    first its group (the groups whose running total is at most ``j``),
+    then its lane in the group's row of ranks, picked by a product with
+    a one-hot. Every count and rank is a whole number under 2 ** 14,
+    exact in the products' float32 sums."""
+    *lead, Sk = chosen.shape
+    lanes = 128
+    n = -(-Sk // lanes)
+    f32 = jnp.float32
+    marks = jnp.pad(chosen, [(0, 0)] * len(lead) + [(0, n * lanes - Sk)]
+                    ).reshape(*lead, n, lanes).astype(jnp.bfloat16)
+    lane = jnp.arange(lanes)
+    # ranks[..., g, l]: the chosen among lanes 0..l of group g.
+    ranks = jnp.einsum("...gl,lm->...gm", marks,
+                       (lane[:, None] <= lane[None]).astype(jnp.bfloat16),
+                       preferred_element_type=f32)
+    counts = ranks[..., -1]
+    total = jnp.cumsum(counts, axis=-1)
+    j = jnp.arange(k, dtype=f32)[:, None]
+    before = total[..., None, :] <= j                  # (..., k, n)
+    group = jnp.sum(before, axis=-1)
+    rank = j[:, 0] - jnp.sum(jnp.where(before, counts[..., None, :], 0.0),
+                             axis=-1)
+    row = jnp.einsum(
+        "...jg,...gl->...jl",
+        (group[..., None] == jnp.arange(n)).astype(jnp.bfloat16),
+        ranks.astype(jnp.bfloat16), preferred_element_type=f32)
+    at = group * lanes + jnp.sum(row <= rank[..., None], axis=-1)
+    kept = jnp.arange(k) < total[..., -1:]
+    return jnp.where(kept, at, 0).astype(jnp.int32), kept
 
 
 def _latent_pass(form: str, q_nope, q_rope, cd, rd, visible, w_uk,

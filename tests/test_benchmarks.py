@@ -481,12 +481,14 @@ def test_a_chunk_under_the_selection_is_one_kernel_and_gathers_no_row(
     """``latent_attention_chunk(select=)`` at ``dots3_ep8.serve_sparse``'s
     two shapes (a full layer at the published widths, bfloat16 pools, a
     table of 16,384 rows, top 2,048) compiles for a v5e: the prompt
-    chunk, under Mosaic's VMEM check, to a program with the one custom
+    chunk, under Mosaic's VMEM check, to a program with the one attention
     call ``dtt_sparse_prefill``, the name ``ops.sparse_prefill_time_
     share.decode`` looks for, and no array of the 262,144 gathered rows
     a block of 128 queries read one at a time (1.48 s of the cell's 4.0
     s trace: ledger, PR 34); the decode iteration to the gather form
-    and no such call."""
+    and no such call. In both the selection is found by counting, one
+    ``dtt_select_topk`` and no sort (sorts were a quarter of the cell's
+    device time)."""
     import re
 
     import jax
@@ -527,10 +529,15 @@ def test_a_chunk_under_the_selection_is_one_kernel_and_gathers_no_row(
             struct((512, H, 128)), struct((B, S, 64, 128)),
             struct((B, S, 64), jnp.float32), layer(128)
         ).compile().as_text()
-    assert seen == [form]
+    assert seen == [form, "select.threshold"]
     calls = [line.strip() for line in text.splitlines()
              if " custom-call(" in line
              and 'custom_call_target="tpu_custom_call"' in line]
+    # The exact top-k by counting, in its own kernel, and no sort.
+    selects = [c for c in calls if re.match(r"%dtt_select_topk\.\d+ = ", c)]
+    assert len(selects) == 1
+    assert not re.search(r"= \S+ sort\(", text)
+    calls = [c for c in calls if c not in selects]
     gathered = re.findall(r"bf16\[(?:128,2048|262144),(?:1,)?512\]", text)
     if form == "absorbed.sparse":
         assert not calls
@@ -562,6 +569,28 @@ def test_the_latent_form_tables_sparse_rows_rehearse():
         assert row["flash_ms"] > 0 and row["absorbed_ms"] > 0
         assert row["err_over_bf16_band"] < 1.0
         assert row["faster"] in ("flash", "absorbed")
+
+
+def test_the_select_form_table_rehearses(monkeypatch):
+    """``benchmarks/select_form_table.py`` (the selection's forms at a
+    prompt chunk's query block and a resident iteration, context by
+    context) at a tiny size on the CPU: every form and width is timed
+    and chooses the set the sort chooses, the kernel's rows past their
+    seen positions included."""
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    import select_form_table as table
+
+    for name, value in dict(SLOTS=512, TOPK=64, HEADS=4, DIM=8,
+                            INPUTS=2).items():
+        monkeypatch.setattr(table, name, value)
+    rows = table.table(1, contexts=(100, 512))
+    assert [(r["S"], r["context"]) for r in rows] == [
+        (128, 100), (128, 512), (1, 100), (1, 512)]
+    for row in rows:
+        assert row["same_set"], row
+        assert row["sort_ms"] > 0 and row["threshold_ms"] > 0
+        if row["S"] > 1:
+            assert row["xla_ms"] > 0 and row["threshold_lanes256_ms"] > 0
 
 
 def test_resident_decode_holds_no_copy_of_the_pool():

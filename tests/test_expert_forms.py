@@ -392,3 +392,55 @@ def test_engine_programs_run_the_kernel_compiled_for_a_v5e(
             rf"= bf16\[(1,)?({E},{D},{F}|{E},{F},{D})\]", text), name_
     assert (kernels["serving_resident_decode"]
             == kernels["serving_prefill_batch"] == len(runs)), kernels
+
+
+def test_sparse_programs_select_by_counting_compiled_for_a_v5e(
+        monkeypatch):
+    """The engine's programs of a tiny learned-selection model compiled
+    for a v5e with no chip: the prefill program (a chunk of 16 over a
+    table of 64: the masked form) and the resident decode program each
+    find their exact top-k in ``dtt_select_topk``, lowered by Mosaic,
+    hold no sort, and report ``select.threshold`` beside their attention
+    form."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_training_tpu.serving.engine import (Engine,
+                                                         EngineConfig)
+
+    try:
+        from distributed_training_tpu.runtime import topology_runtime
+        chip = SingleDeviceSharding(
+            topology_runtime(1, "v5e:2x2").mesh.devices.flat[0])
+    except Exception as e:  # pragma: no cover - no libtpu
+        pytest.skip(f"device-less TPU topology unavailable: {e}")
+    model = build_model("sparse_latent_moe", dtype="bfloat16",
+                        **{**BLOCKS["sparse_latent_moe"], "ep_size": 4})
+    params = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                          model.init(jax.random.PRNGKey(0)))
+    monkeypatch.setenv("DTT_ASSUME_TPU", "1")
+    eng = Engine(model, params, EngineConfig(
+        max_batch=4, page_size=8, num_pages=40, max_seq_len=64,
+        prefill_chunk=16, prefill_slots=1, resident_k=4,
+        prefix_sharing=False))
+
+    def shape(a):
+        if not isinstance(a, jax.Array):
+            return a
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=Format(
+            Layout(major_to_minor=tuple(range(a.ndim))), chip))
+
+    kernels = {}
+    for fn, args in eng._warmup_calls():
+        name = fn.__wrapped__.__name__
+        text = fn.lower(*jax.tree.map(
+            shape, (*eng._state_of(fn), *args))).compile().as_text()
+        kernels[name] = len(re.findall(
+            r"%(dtt_select_topk\.\d+) = .*tpu_custom_call", text))
+        assert not re.search(r"= \S+ sort\(", text), name
+    forms = eng.paged_forms()
+    assert kernels["serving_resident_decode"] >= 1, kernels
+    assert kernels["serving_prefill_batch"] >= 1, kernels
+    assert "flash.sparse" in forms["serving_prefill_batch"], forms
+    for name in ("serving_resident_decode", "serving_prefill_batch"):
+        assert forms[name].split("+").count("select.threshold") == 1, forms

@@ -250,7 +250,8 @@ def test_engine_matches_the_reference(ref, cadence):
     forms = eng.paged_forms()
     sparse = {"absorbed.sparse", "flash.sparse"}
     window = {"absorbed.window", "expanded.window"}
-    assert all(set(f.split("+")) <= sparse | window
+    assert all(set(f.split("+")) <= sparse | window | {"select.threshold"}
+               and "select.threshold" in f.split("+")
                and set(f.split("+")) & sparse
                and set(f.split("+")) & window
                for f in forms.values() if f), forms
@@ -346,23 +347,79 @@ def test_paged_logits_match_the_reference(ref, monkeypatch, feed, limit):
                       - want).max() > 2e-2
 
 
-def test_select_topk_is_exact():
-    """Against a sort by hand, ties and rows with fewer than k seen
-    included: equal scores go to the lower position."""
+def _topk_case(case: str):
+    """``(scores (4, 8, Sk) float32, seen, k)`` of one case of
+    ``test_select_topk_is_exact``: 32 query rows, one block of the
+    kernel's."""
     rng = np.random.default_rng(3)
-    scores = rng.integers(0, 6, (5, 7, 40)).astype(np.float32)  # ties
-    seen = rng.random((5, 7, 40)) < 0.6
-    seen[0, 0] = False
-    seen[1, 1, 3:] = False
-    k = 9
-    positions, kept, chosen = map(np.asarray, pa.select_topk(
-        jnp.asarray(scores), jnp.asarray(seen), k))
-    for idx in np.ndindex(5, 7):
+    Sk, k = 40, 9
+    if case == "past_the_live_blocks":
+        Sk = 512
+    scores = rng.integers(0, 6, (4, 8, Sk)).astype(np.float32)
+    seen = rng.random((4, 8, Sk)) < 0.6
+    seen[0, 0] = False                      # a row with none seen
+    seen[1, 1, 3:] = False                  # fewer than k seen
+    if case == "ties":
+        # Five values over 40: the k-th place lies inside a run of
+        # equal scores in every row that sees more than k.
+        pass
+    elif case == "negative":
+        scores = rng.normal(size=scores.shape).astype(np.float32) - 1.0
+        scores[2, :, ::3] = scores[2, :, :1]        # ties among them
+    elif case == "signed_zeros":
+        # -0.0 and +0.0 are one score: the lower position goes first.
+        scores = np.where(rng.random(scores.shape) < 0.5, -0.0, 0.0
+                          ).astype(np.float32)
+        scores[:, :, ::5] = rng.normal(size=scores[:, :, ::5].shape)
+        assert np.signbit(scores[scores == 0]).any()
+    elif case == "fewer_than_k_and_dead":
+        seen[:, :4] = False                 # dead queries
+        seen[2] &= np.arange(Sk) < 5        # fewer than k
+        scores[3, 4:, :7] = -np.inf         # seen, and lowest of all
+    elif case == "k_is_one":
+        k = 1
+    elif case == "k_is_every_position":
+        k = Sk
+    elif case == "past_the_live_blocks":
+        # Every row sees only the first 100 positions of 512: the key
+        # blocks past them are never counted, and ties straddle the
+        # k-th place.
+        seen &= np.arange(Sk) < 100
+        k = 30
+    return scores, seen, k
+
+
+@pytest.mark.parametrize("case", [
+    "ties", "negative", "signed_zeros", "fewer_than_k_and_dead",
+    "k_is_one", "k_is_every_position", "past_the_live_blocks"])
+def test_select_topk_is_exact(monkeypatch, case):
+    """Against a sort by hand (``dtt_select_topk`` interpreted, the
+    positions compacted out of its mask): ties, negative scores,
+    ``-0.0`` beside ``+0.0``, rows with fewer than k seen or none, dead
+    queries, k of 1 and of every position, and a block whose seen
+    positions end before its last key blocks. Equal scores go to the
+    lower position; the positions come in position order."""
+    scores, seen, k = _topk_case(case)
+    if case == "past_the_live_blocks":
+        monkeypatch.setattr(pa, "_SELECT_LANES", 128)
+    with pa.observe_forms() as took:
+        positions, kept, chosen = map(np.asarray, pa.select_topk(
+            jnp.asarray(scores), jnp.asarray(seen), k))
+    assert took == ["select.threshold"]
+    for idx in np.ndindex(*scores.shape[:2]):
         order = sorted(np.flatnonzero(seen[idx]),
                        key=lambda s: (-scores[idx][s], s))[:k]
-        assert sorted(positions[idx][kept[idx]]) == sorted(order)
-        assert sorted(np.flatnonzero(chosen[idx])) == sorted(order)
+        assert list(positions[idx][kept[idx]]) == sorted(order), idx
+        assert sorted(np.flatnonzero(chosen[idx])) == sorted(order), idx
         assert kept[idx].sum() == min(k, seen[idx].sum())
+        assert not positions[idx][~kept[idx]].any()
+    if case in ("ties", "past_the_live_blocks"):
+        # The k-th place inside a run of equal scores: the cutoff by
+        # position decided it.
+        s = np.where(seen, scores, -np.inf)
+        edge = -np.sort(-s, axis=-1)[..., k - 1:k + 1]
+        assert (edge[..., 0] == edge[..., 1])[np.isfinite(edge[..., 1])
+                                              ].mean() > 0.5
 
 
 def latent_case(B, S, last, ring_pages=None, seed=0, ps=4, H=4, rank=8,
@@ -468,9 +525,9 @@ def test_sparse_equals_dense_up_to_index_topk(S, topk, form):
     select = pa.Selection(qi, wi, ip, topk)
     with pa.observe_forms() as seen:
         got = pa.latent_attention_chunk(*args, select=select)
-    assert seen == [form]
-    plain = pa.latent_attention_chunk(*args)
     T = dense[0].shape[1]
+    assert seen == [form, "select.threshold"]
+    plain = pa.latent_attention_chunk(*args)
     back = args[5][:, :, None] - jnp.arange(T)[None, None]
     scores = jnp.einsum("bsjk,bsj->bsk", jax.nn.relu(jnp.einsum(
         "bsjd,bkd->bsjk", qi, dense[2])), wi)
@@ -539,11 +596,11 @@ def test_the_masked_form_is_the_gather_form(monkeypatch, case, dtype,
     else:
         with pa.observe_forms() as took:
             masked = pa.latent_attention_chunk(*args, select=select)
-        assert took == ["flash.sparse"]
+        assert took == ["flash.sparse", "select.threshold"]
     forced(monkeypatch, "absorbed")
     with pa.observe_forms() as took:
         gathered = pa.latent_attention_chunk(*args, select=select)
-    assert took == ["absorbed.sparse"]
+    assert took == ["absorbed.sparse", "select.threshold"]
     T = dense[0].shape[1]
     want = dense_latent(args, dense, chosen[:, :, :T])
     for got in (gathered, want):
@@ -608,7 +665,8 @@ def test_the_form_under_a_selection_follows_the_shapes(B, S, pages, form):
             jax.ShapeDtypeStruct((d["rank"], H, d["v"]), f32),
             jax.ShapeDtypeStruct((B, S, 64, 128), f32),
             jax.ShapeDtypeStruct((B, S, 64), f32), layer(128))
-    assert took == [form] and out.shape == (B, S, H, d["v"])
+    assert took == [form, "select.threshold"]
+    assert out.shape == (B, S, H, d["v"])
     assert pa.sparse_form((B, S, H), pages * 16, 2048,
                           tuple(d.values())) == form.split(".")[0]
 
